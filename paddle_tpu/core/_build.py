@@ -11,10 +11,17 @@ from __future__ import annotations
 import ctypes
 import os
 import subprocess
-import tempfile
 
 _LIB = None
 _TRIED = False
+
+# Everything the program builds at run time (this library, JAX's compile
+# cache) lands under one git-ignored directory of the checkout — fixed, so a
+# second run finds what the first one built. Defined here, with no import
+# from the rest of the package: worker processes load paddle_tpu.core alone.
+CACHE_ROOT = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".cache")
 
 _SRC_FILES = ("tcp_store.cc", "workqueue.cc", "host_tracer.cc",
               "ckpt_writer.cc")
@@ -37,9 +44,8 @@ def _prebuilt_path():
 
 
 def _cache_dir():
-    d = os.environ.get("PADDLE_TPU_CACHE",
-                       os.path.join(tempfile.gettempdir(),
-                                    "paddle_tpu_native"))
+    """Fixed build directory inside the checkout (git-ignored)."""
+    d = os.path.join(CACHE_ROOT, "native")
     os.makedirs(d, exist_ok=True)
     return d
 
@@ -61,7 +67,10 @@ def build_library(verbose=False):
     sources = [os.path.join(csrc, f) for f in _SRC_FILES]
     if not all(os.path.exists(s) for s in sources):
         return None
-    lib_path = os.path.join(_cache_dir(), "libpaddle_tpu_core.so")
+    try:
+        lib_path = os.path.join(_cache_dir(), "libpaddle_tpu_core.so")
+    except OSError:                     # read-only install location
+        return None
     if not _needs_rebuild(lib_path, sources):
         return lib_path
     # compile to a private temp name and atomically rename so a concurrent

@@ -85,7 +85,6 @@ class ProcessGroupXLA:
         if fn is not None:
             return fn
         mesh = self.mesh
-        from ..framework.jax_compat import shard_map
 
         red = {ReduceOp.SUM: jax.lax.psum, ReduceOp.MAX: jax.lax.pmax,
                ReduceOp.MIN: jax.lax.pmin,
@@ -96,13 +95,15 @@ class ProcessGroupXLA:
         if kind == "all_reduce":
             def body(x):
                 return red(x, "g")
-            fn = jax.jit(shard_map(body, mesh=mesh, in_specs=P("g"),
-                                   out_specs=P("g")))
+            fn = jax.jit(jax.shard_map(body, mesh=mesh,
+                                       in_specs=P("g"),
+                                       out_specs=P("g")))
         elif kind == "all_gather":
             def body(x):
                 return jax.lax.all_gather(x, "g", tiled=True)
-            fn = jax.jit(shard_map(body, mesh=mesh, in_specs=P("g"),
-                                   out_specs=P("g")))
+            fn = jax.jit(jax.shard_map(body, mesh=mesh,
+                                       in_specs=P("g"),
+                                       out_specs=P("g")))
         elif kind == "reduce_scatter":
             # block [1, n, chunk...] -> each device keeps its reduced chunk
             def body(x):
@@ -111,8 +112,9 @@ class ProcessGroupXLA:
                                                 scatter_dimension=0)[None]
                 y = red(x[0], "g")                       # [n, chunk...]
                 return jnp.take(y, jax.lax.axis_index("g"), axis=0)[None]
-            fn = jax.jit(shard_map(body, mesh=mesh, in_specs=P("g"),
-                                   out_specs=P("g")))
+            fn = jax.jit(jax.shard_map(body, mesh=mesh,
+                                       in_specs=P("g"),
+                                       out_specs=P("g")))
         elif kind == "broadcast":
             src = kw["src_index"]
 
@@ -120,22 +122,25 @@ class ProcessGroupXLA:
                 from_src = jax.lax.all_gather(x, "g")[src]
                 return from_src
 
-            fn = jax.jit(shard_map(body, mesh=mesh, in_specs=P("g"),
-                                   out_specs=P("g")))
+            fn = jax.jit(jax.shard_map(body, mesh=mesh,
+                                       in_specs=P("g"),
+                                       out_specs=P("g")))
         elif kind == "alltoall":
             # block [1, n, chunk...]: row j goes to device j
             def body(x):
                 return jax.lax.all_to_all(x[0], "g", split_axis=0,
                                           concat_axis=0)[None]
-            fn = jax.jit(shard_map(body, mesh=mesh, in_specs=P("g"),
-                                   out_specs=P("g")))
+            fn = jax.jit(jax.shard_map(body, mesh=mesh,
+                                       in_specs=P("g"),
+                                       out_specs=P("g")))
         elif kind == "p2p":
             perm = kw["perm"]
 
             def body(x):
                 return jax.lax.ppermute(x, "g", list(perm))
-            fn = jax.jit(shard_map(body, mesh=mesh, in_specs=P("g"),
-                                   out_specs=P("g")))
+            fn = jax.jit(jax.shard_map(body, mesh=mesh,
+                                       in_specs=P("g"),
+                                       out_specs=P("g")))
         else:
             raise ValueError(kind)
         self._cache[key] = fn

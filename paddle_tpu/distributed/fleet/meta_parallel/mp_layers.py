@@ -17,7 +17,6 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ....framework.core import Tensor
-from ....framework.jax_compat import axis_size
 from ....nn.layer_base import Layer
 from ....nn.initializer_util import materialize_parameter
 from ....nn import initializer as I
@@ -188,7 +187,7 @@ class ParallelCrossEntropy(Layer):
 
         def fn(logits, lab_v):
             # shard-local logits: [.., V/mp]; global softmax via psum
-            n = axis_size("model")
+            n = jax.lax.axis_size("model")
             idx = jax.lax.axis_index("model")
             vshard = logits.shape[-1]
             local_max = jnp.max(logits, axis=-1, keepdims=True)

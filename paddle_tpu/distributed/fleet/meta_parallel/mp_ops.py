@@ -14,7 +14,6 @@ import jax
 import jax.numpy as jnp
 
 from ....framework.core import Tensor
-from ....framework.jax_compat import axis_size
 from ....ops._helpers import ensure_tensor, call_op
 from ....ops.dispatch import mark_collective
 
@@ -30,7 +29,7 @@ def _mp_collective_key(kind, *extra):
     None (→ the explicit unkeyable marker, so the poison is attributed
     instead of silent) when the axis size cannot be read."""
     try:
-        return (kind, MODEL_AXIS, int(axis_size(MODEL_AXIS))) + extra
+        return (kind, MODEL_AXIS, int(jax.lax.axis_size(MODEL_AXIS))) + extra
     except Exception:
         return None
 
@@ -39,15 +38,14 @@ def in_spmd_axis(axis_name=MODEL_AXIS):
     """True when called inside a shard_map/pmap trace binding `axis_name`
     with more than one shard. A bound size-1 axis carries no sharding —
     collectives over it are identities — so it does not count: this keeps
-    dispatch decisions (ring attention, mp collectives) correct under the
-    jax_compat all-manual shard_map emulation, which binds EVERY mesh axis
-    including degenerate ones."""
+    dispatch decisions (ring attention, mp collectives) correct when a
+    shard_map binds every mesh axis, degenerate ones included."""
     try:
         jax.lax.axis_index(axis_name)
     except (NameError, KeyError, TypeError, Exception):
         return False
     try:
-        return axis_size(axis_name) > 1
+        return jax.lax.axis_size(axis_name) > 1
     except Exception:
         return True
 
@@ -117,7 +115,7 @@ def _c_split(tensor, group=None):
         return t
 
     def fn(v):
-        n = axis_size(MODEL_AXIS)
+        n = jax.lax.axis_size(MODEL_AXIS)
         idx = jax.lax.axis_index(MODEL_AXIS)
         chunk = v.shape[-1] // n
         return jax.lax.dynamic_slice_in_dim(v, idx * chunk, chunk,

@@ -28,7 +28,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from ....framework.jax_compat import axis_size
 from .mp_ops import in_spmd_axis
 
 __all__ = ["ring_attention", "ulysses_attention", "sep_attention", "SEP_AXIS"]
@@ -67,7 +66,7 @@ def ring_attention(q, k, v, axis_name=SEP_AXIS, causal=False, scale=None):
     device i along `axis_name` holds contiguous positions [i*n, (i+1)*n).
     Returns shard-local [B, n, H, D].
     """
-    s = axis_size(axis_name)
+    s = jax.lax.axis_size(axis_name)
     i = jax.lax.axis_index(axis_name)
     b, n, h, d = q.shape
     mblk = k.shape[1]                # kv shard length (> n with caches)
@@ -107,7 +106,7 @@ def ulysses_attention(q, k, v, axis_name=SEP_AXIS, causal=False, scale=None,
     q/k/v: shard-local [B, n, H, D] with H % sep_degree == 0. Two all-to-alls
     per tensor (in + out) replace the ring's (sep-1) ppermute rounds.
     """
-    s = axis_size(axis_name)
+    s = jax.lax.axis_size(axis_name)
     b, n, h, d = q.shape
     if h % s != 0:
         raise ValueError(f"ulysses needs heads ({h}) divisible by sep ({s})")

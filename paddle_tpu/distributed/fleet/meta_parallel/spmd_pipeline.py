@@ -212,9 +212,8 @@ def spmd_pipeline(stage_fn, stage_params, x, *, mesh, axis="pipe", key=None,
         return outs[None]
 
     pspec = P(axis) if V == 1 else P(None, axis)
-    from ....framework.jax_compat import shard_map
-    mapped = shard_map(per_device, mesh=mesh, axis_names={axis},
-                       in_specs=(pspec, P(axis)), out_specs=P(axis))
+    mapped = jax.shard_map(per_device, mesh=mesh, axis_names={axis},
+                           in_specs=(pspec, P(axis)), out_specs=P(axis))
     x_tiled = jnp.broadcast_to(x[None], (S,) + x.shape)
     stacked = mapped(stage_params, x_tiled)
     # only the last stage's buffer is real: select it outside the shard_map

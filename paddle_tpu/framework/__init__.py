@@ -1,4 +1,3 @@
-from . import jax_compat  # noqa: F401  (installs jax version-compat shims)
 from .dtype import (  # noqa: F401
     DType, convert_dtype, set_default_dtype, get_default_dtype,
     uint8, int8, int16, int32, int64, float16, bfloat16, float32, float64,

@@ -16,8 +16,13 @@ import ml_dtypes
 # jax needs x64 enabled for them. Kernels pick their compute dtype explicitly
 # (bf16/f32 on TPU), so this only widens what users may request. NOTE: this is
 # a process-wide jax config change — bare jnp.ones(...) elsewhere becomes
-# float64 (which TPUs reject). Set PADDLE_TPU_X64=0 to opt out and forfeit
-# float64 tensor support.
+# float64, which the TPU compiles but emulates (a float64 sort+cumsum over
+# [8, 50304] took 110 s to compile for a v5e). It stays on because the
+# main-path programs are free of 64-bit device types all the same:
+# chip_smoke.py fails if the TrainStep program text holds an f64 (PR 21:
+# none in the 124M/355M train steps, nor in the engine's decode and prefill
+# programs compiled for the chip). Set PADDLE_TPU_X64=0 to opt out and
+# forfeit float64 tensor support.
 import os as _os
 
 if _os.environ.get("PADDLE_TPU_X64", "1") != "0":

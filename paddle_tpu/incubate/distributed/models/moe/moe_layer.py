@@ -26,7 +26,6 @@ import jax
 import jax.numpy as jnp
 
 from .....framework.core import Tensor
-from .....framework.jax_compat import axis_size
 from .....nn.layer_base import Layer
 from .....nn import initializer as I
 from .....nn.initializer_util import materialize_parameter, ParamAttr
@@ -115,7 +114,7 @@ class MoELayer(Layer):
             # bucket tokens per (expert, capacity slot): [E, C, M]
             buckets = jnp.einsum("tec,tm->ecm", disp, tokens)
             if spmd:
-                ep = axis_size(axis)
+                ep = jax.lax.axis_size(axis)
                 e_local = w1.shape[0]
                 if e_local * ep != e_total:
                     raise ValueError(

@@ -196,7 +196,8 @@ class GPTAttention(Layer):
         # pins the variant it resolved at construction.
         variant = cache.kernel
         if variant is None:
-            variant = resolve_paged_kernel(head_dim=self.head_dim,
+            variant = resolve_paged_kernel(num_heads=self.num_heads,
+                                           head_dim=self.head_dim,
                                            block_size=block_size)
 
         quantized = cache.k_scales is not None

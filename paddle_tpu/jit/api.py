@@ -374,14 +374,9 @@ def save(layer, path, input_spec=None, **configs):
                                  ["__tensor__"] * len(specs), {})
             values_spec = [jax.ShapeDtypeStruct(v._value.shape, v._value.dtype)
                           for v in params + buffers] + list(specs)
-            from ..framework.jax_compat import export_key_form
-            key_form = export_key_form()
-            key_spec = jax.ShapeDtypeStruct((), jax.random.key(0).dtype) \
-                if key_form == "typed" \
-                else jax.ShapeDtypeStruct((2,), jnp.uint32)
+            key_spec = jax.ShapeDtypeStruct((), jax.random.key(0).dtype)
             exported = jexport.export(jax.jit(pure))(values_spec, key_spec)
             payload["stablehlo"] = exported.serialize()
-            payload["export_key_form"] = key_form
         except Exception as e:  # serialization is best-effort
             payload["stablehlo_error"] = repr(e)
     fsave(payload, path if path.endswith(".pdmodel") or "." in path.split("/")[-1]
@@ -452,12 +447,8 @@ class TranslatedLayer:
                                          else ""))
         vals = [a._value if isinstance(a, Tensor) else jnp.asarray(a)
                 for a in args]
-        # the key form is an artifact property, not an env property: call
-        # with whatever the export was traced with (see jax_compat)
-        key = jax.random.key(0) \
-            if self._payload.get("export_key_form", "typed") == "typed" \
-            else jax.random.PRNGKey(0)
-        out = self._exported.call(self._param_values + vals, key)
+        out = self._exported.call(self._param_values + vals,
+                                  jax.random.key(0))
         if isinstance(out, (list, tuple)):
             n_buf = self._payload.get("n_buffer_outputs", 0)
             model_out = list(out[:len(out) - n_buf]) if n_buf else list(out)

@@ -122,29 +122,44 @@ class TrainStep:
             donate = ()
         self._jitted = jax.jit(step, donate_argnums=donate)
 
-    def __call__(self, *args):
+    def _state_args(self, args):
+        """The step program's leading arguments (params, slots, buffers,
+        inputs, lr) as they stand now; builds the program on first use."""
         arg_vals = [a._value if isinstance(a, Tensor) else jnp.asarray(a)
                     for a in args]
         if self._jitted is None:
             self._build(arg_vals)
         params = self._params
         opt = self.optimizer
-        acc_names = self._acc_names
         opt._create_accumulators(params)
-        if not hasattr(opt, "_step_count"):
-            opt._step_count = 0
-        opt._step_count += 1
-
         pvals = [p._value for p in params]
-        accs = [[opt._accumulators[n].get(p.name) for n in acc_names]
+        accs = [[opt._accumulators[n].get(p.name) for n in self._acc_names]
                 for p in params]
         bvals = [b._value for b in self._buffers]
         lr = jnp.asarray(opt.get_lr(), jnp.float32)
+        return pvals, accs, bvals, arg_vals, lr
+
+    def lower(self, *args):
+        """`jax.jit(...).lower` of the program `__call__` would run on
+        these arguments: nothing executes and neither the optimizer's step
+        count nor the RNG stream moves. `.compile()` the result to read
+        the program text or `memory_analysis()`."""
+        state = self._state_args(args)
+        key = jax.eval_shape(lambda: jax.random.key(0))
+        return self._jitted.lower(*state, jnp.asarray(1, jnp.int32), key)
+
+    def __call__(self, *args):
+        state = self._state_args(args)
+        params = self._params
+        opt = self.optimizer
+        acc_names = self._acc_names
+        if not hasattr(opt, "_step_count"):
+            opt._step_count = 0
+        opt._step_count += 1
         step_count = jnp.asarray(opt._step_count, jnp.int32)
         key = _random.get_rng_key()
 
-        loss, new_p, new_accs, new_b = self._jitted(
-            pvals, accs, bvals, arg_vals, lr, step_count, key)
+        loss, new_p, new_accs, new_b = self._jitted(*state, step_count, key)
         from ..framework.flags import _FLAGS
         if _FLAGS.get("FLAGS_check_nan_inf") and \
                 not bool(jnp.isfinite(loss)):

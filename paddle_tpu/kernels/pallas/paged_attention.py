@@ -10,11 +10,11 @@ resident at a time — the dense context never exists.
 
 Two implementations with identical semantics:
 
-  * `pallas_paged_attention` — the TPU kernel. Grid ``(S*H, M)``; the
+  * `pallas_paged_attention` — the TPU kernel. Grid ``(S, M)``; the
     block table and (effective) lengths ride as scalar-prefetch
     arguments, so each grid cell's BlockSpec index map picks its pool
     block ``tables[s, j]`` directly — the DMA engine walks the page
-    table, the kernel body only ever sees one ``[bs, D]`` tile in VMEM.
+    table, the kernel body only ever sees one ``[bs, H, D]`` block in VMEM.
     int8 pools dequantize inside the load (`q * scale / 127`), so the
     fp values exist only in VMEM. Length masking keeps the null-block
     branch-free contract: padded/inactive table entries read block 0 and
@@ -65,7 +65,15 @@ _CHUNK_TARGET_BYTES = 256 * 1024
 _CHUNK_TOKENS_MAX = 512
 
 
-def is_eligible(head_dim, block_size):
+# Largest [block_size, H, D] pool block (in elements, H and D padded to
+# the (8, 128) tile) the v5e compiler accepted for every pool dtype: the
+# kernel keeps K and V double-buffered plus their fp32 copies in VMEM,
+# and a 2x larger fp32 block ran out of it. tests/test_tpu_compile.py
+# compiles both sides of this bound.
+_MAX_BLOCK_ELEMS = 512 * 1024
+
+
+def is_eligible(num_heads, head_dim, block_size):
     """Can the Pallas kernel run compiled (non-interpret) here?
     Returns (ok, why) — `why` is the attribution detail for the
     `kernel.fallback` flight-recorder event when not."""
@@ -73,12 +81,11 @@ def is_eligible(head_dim, block_size):
         return False, "no_pallas"
     if not _on_tpu():
         return False, "not_on_tpu"
-    if head_dim is None or head_dim % 64 != 0:
-        # the [bs, D] tiles want lane-aligned head dims; odd heads take
-        # the blockwise path (same math, no Mosaic constraints)
-        return False, "head_dim_unaligned"
-    if block_size is None or block_size % 8 != 0:
-        return False, "block_size_unaligned"
+    if None in (num_heads, head_dim, block_size):
+        return False, "shape_unknown"
+    padded = block_size * -(-num_heads // 8) * 8 * -(-head_dim // 128) * 128
+    if padded > _MAX_BLOCK_ELEMS:
+        return False, "block_exceeds_vmem"
     return True, None
 
 
@@ -161,18 +168,17 @@ def blockwise_paged_attention(q, k_pool, v_pool, block_tables, lens,
 
 
 # ---------------------------------------------------------------------------
-# Pallas TPU kernel: one grid cell per (slot*head, table entry)
+# Pallas TPU kernel: one grid cell per (slot, table entry), all heads at once
 # ---------------------------------------------------------------------------
 
 def _decode_kernel(tab_ref, lens_ref, q_ref, k_ref, v_ref, *rest,
-                   block_size, heads, quantized):
+                   block_size, quantized):
     if quantized:
         ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
     else:
         o_ref, acc_ref, m_ref, l_ref = rest
-    sh = pl.program_id(0)
+    s = pl.program_id(0)
     j = pl.program_id(1)
-    s = jax.lax.div(sh, jnp.int32(heads))
 
     @pl.when(j == 0)
     def _init():
@@ -180,34 +186,32 @@ def _decode_kernel(tab_ref, lens_ref, q_ref, k_ref, v_ref, *rest,
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    qv = q_ref[...].astype(jnp.float32)                # [1, D] (pre-scaled)
-    k = k_ref[:, 0, :].astype(jnp.float32)             # [bs, D]
-    v = v_ref[:, 0, :].astype(jnp.float32)
+    # heads sit on sublanes and head_dim on lanes throughout ([bs, H, D]
+    # tiles, [H, 1] statistics), so nothing below relayouts
+    qv = q_ref[...].astype(jnp.float32)                # [H, D] (pre-scaled)
+    k = k_ref[...].astype(jnp.float32)                 # [bs, H, D]
+    v = v_ref[...].astype(jnp.float32)
     if quantized:
         # dequant fused into the block load: fp K/V exist only in VMEM
-        k = k * (ks_ref[0, 0] * (1.0 / _QMAX))
-        v = v * (vs_ref[0, 0] * (1.0 / _QMAX))
-    scores = jax.lax.dot_general(
-        k, qv, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)            # [bs, 1]
+        k = k * (ks_ref[...] * (1.0 / _QMAX))          # scales: [H, 1]
+        v = v * (vs_ref[...] * (1.0 / _QMAX))
+    scores = jnp.sum(k * qv, axis=-1, keepdims=True)   # [bs, H, 1]
     pos = j * jnp.int32(block_size) + jax.lax.broadcasted_iota(
         jnp.int32, scores.shape, 0)
     valid = pos <= lens_ref[s]
     scores = jnp.where(valid, scores, jnp.float32(_NEG_INF))
-    m_prev = m_ref[0, 0]
-    m_new = jnp.maximum(m_prev, jnp.max(scores))
-    p = jnp.where(valid, jnp.exp(scores - m_new), 0.0)  # [bs, 1]
+    m_prev = m_ref[...]                                # [H, 1]
+    m_new = jnp.maximum(m_prev, jnp.max(scores, axis=0))
+    p = jnp.where(valid, jnp.exp(scores - m_new), 0.0)  # [bs, H, 1]
     alpha = jnp.exp(m_prev - m_new)
-    l_ref[0, 0] = alpha * l_ref[0, 0] + jnp.sum(p)
-    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-        p, v, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)             # [1, D]
-    m_ref[0, 0] = m_new
+    l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=0)
+    acc_ref[...] = acc_ref[...] * alpha + jnp.sum(p * v, axis=0)  # [H, D]
+    m_ref[...] = m_new
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _flush():
         o_ref[...] = (acc_ref[...]
-                      / jnp.maximum(l_ref[0, 0], 1e-30)).astype(o_ref.dtype)
+                      / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
 def pallas_paged_attention(q, k_pool, v_pool, block_tables, lens,
@@ -221,41 +225,40 @@ def pallas_paged_attention(q, k_pool, v_pool, block_tables, lens,
     m = block_tables.shape[1]
     quant = k_scales is not None
     zero = _ZERO
-    qf = (q.astype(jnp.float32) * (1.0 / math.sqrt(d))).reshape(s * h, d)
+    qf = q.astype(jnp.float32) * (1.0 / math.sqrt(d))
     tables = block_tables.astype(jnp.int32)
     lens32 = lens.astype(jnp.int32)
 
-    # index maps receive (grid ids..., scalar-prefetch refs): the block
-    # table IS the page table the DMA walks
-    in_specs = [
-        pl.BlockSpec((1, d), lambda sh, j, t, l: (sh, zero)),
-        pl.BlockSpec((None, bs, 1, d),
-                     lambda sh, j, t, l: (t[sh // h, j], zero, sh % h,
-                                          zero)),
-        pl.BlockSpec((None, bs, 1, d),
-                     lambda sh, j, t, l: (t[sh // h, j], zero, sh % h,
-                                          zero)),
-    ]
+    # Every block spans ALL heads of its slot, so its last two dimensions
+    # equal the array's — the only shape the TPU lowering accepts when H
+    # or D is not a multiple of the (8, 128) tile. Index maps receive
+    # (grid ids..., scalar-prefetch refs): the block table IS the page
+    # table the DMA walks.
+    pool_spec = pl.BlockSpec((None, bs, h, d),
+                             lambda si, j, t, l: (t[si, j], zero, zero, zero))
+    slot_spec = pl.BlockSpec((None, h, d), lambda si, j, t, l: (si, zero, zero))
+    in_specs = [slot_spec, pool_spec, pool_spec]
     args = [tables, lens32, qf, k_pool, v_pool]
     if quant:
-        spec = pl.BlockSpec((None, 1, 1),
-                            lambda sh, j, t, l: (t[sh // h, j], sh % h,
-                                                 zero))
+        spec = pl.BlockSpec((None, h, 1),
+                            lambda si, j, t, l: (t[si, j], zero, zero))
         in_specs += [spec, spec]
         args += [k_scales[..., None], v_scales[..., None]]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(s * h, m),
+        grid=(s, m),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, d), lambda sh, j, t, l: (sh, zero)),
-        scratch_shapes=[pltpu.VMEM((1, d), jnp.float32),
-                        pltpu.VMEM((1, 1), jnp.float32),
-                        pltpu.VMEM((1, 1), jnp.float32)])
-    kernel = functools.partial(_decode_kernel, block_size=bs, heads=h,
+        out_specs=slot_spec,
+        scratch_shapes=[pltpu.VMEM((h, d), jnp.float32),
+                        pltpu.VMEM((h, 1), jnp.float32),
+                        pltpu.VMEM((h, 1), jnp.float32)])
+    kernel = functools.partial(_decode_kernel, block_size=bs,
                                quantized=quant)
-    out = pl.pallas_call(
+    return pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s * h, d), q.dtype),
-        interpret=interpret)(*args)
-    return out.reshape(s, h, d)
+        out_shape=jax.ShapeDtypeStruct((s, h, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+        name="paged_decode_attention")(*args)

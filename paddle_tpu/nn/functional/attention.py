@@ -30,6 +30,54 @@ class _ShapeMeta:
         self.shape = shape
 
 
+_FLASH_SPEC = jax.sharding.PartitionSpec(("data", "sharding"), None, "model",
+                                         None)
+
+
+def _flash_partition(batch, heads):
+    """How a [B, N, H, D] Mosaic kernel has to be called here. The TPU
+    lowering refuses to partition a Mosaic kernel automatically, so under
+    a global multi-device mesh the kernel runs per shard through
+    `shard_map`: batch over the data axes, heads over "model" (the
+    Megatron split `shard_gpt` gives qkv). Returns None to call it
+    directly (no mesh, or already inside a manual region that binds every
+    sharded axis), the mesh's key to wrap it, or False when it cannot run
+    (shapes that do not divide, or a partly manual region)."""
+    from ...distributed.fleet.meta_parallel.mp_ops import in_spmd_axis
+    from ...distributed.mesh import current_mesh, mesh_key
+    mesh = current_mesh()
+    if mesh is None or mesh.size == 1:
+        return None
+    sharded = [a for a in mesh.axis_names if mesh.shape[a] > 1]
+    bound = [a for a in sharded if in_spmd_axis(a)]
+    if len(bound) == len(sharded):
+        return None
+    if bound or batch % (mesh.shape["data"] * mesh.shape["sharding"]) \
+            or heads % mesh.shape["model"]:
+        return False
+    return mesh_key(mesh)
+
+
+def _run_flash(q, k, v, causal, scale, mkey):
+    """The flash kernel, per shard of the mesh keyed `mkey` (None: as is).
+    The key, not the mesh, rides in the op's closure so the op stays
+    keyable; the mesh is read back here, at trace time."""
+    from ...kernels import flash_attention as fa
+
+    def kernel(qq, kk, vv):
+        return fa.flash_attention_bnhd(qq, kk, vv, causal=causal,
+                                       scale=scale)
+    if mkey is None:
+        return kernel(q, k, v)
+    from ...distributed.mesh import current_mesh, mesh_key
+    mesh = current_mesh()
+    if mesh_key(mesh) != mkey:
+        raise RuntimeError("the global mesh changed between the dispatch "
+                           "of flash attention and its trace")
+    return jax.shard_map(kernel, mesh=mesh, in_specs=(_FLASH_SPEC,) * 3,
+                         out_specs=_FLASH_SPEC, check_vma=False)(q, k, v)
+
+
 def _plain_attention(q, k, v, mask, is_causal, scale, dropout_p=0.0,
                      dropout_key=None):
     # q,k,v: [B, N, H, D] (paddle layout: batch, seq, heads, head_dim)
@@ -110,10 +158,11 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     if use_flash_attention is not False and \
             fa.is_eligible(_shape_of(q), _shape_of(k), _shape_of(v), mask_t,
                            eff_dropout, is_causal=is_causal):
-        def fn(qq, kk, vv):
-            return fa.flash_attention_bnhd(qq, kk, vv, causal=is_causal,
-                                           scale=scale)
-        return call_op("flash_attention", fn, (q, k, v))
+        mkey = _flash_partition(q.shape[0], q.shape[2])
+        if mkey is not False:
+            def fn(qq, kk, vv):
+                return _run_flash(qq, kk, vv, is_causal, scale, mkey)
+            return call_op("flash_attention", fn, (q, k, v))
 
     # the mask AND the dropout key are dispatch INPUTS (not closure
     # captures): closing over a per-batch array — or a per-call PRNG key —
@@ -153,8 +202,8 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
 PAGED_KERNELS = ("pallas", "blockwise", "reference")
 
 
-def resolve_paged_kernel(kernel=None, head_dim=None, block_size=None,
-                         interpret=False):
+def resolve_paged_kernel(kernel=None, num_heads=None, head_dim=None,
+                         block_size=None, interpret=False):
     """Resolve the serving attention variant: the request (explicit
     `kernel` or FLAGS_serve_attention_kernel) -> the variant that will
     actually run. An ineligible request falls back to `blockwise` (same
@@ -175,14 +224,15 @@ def resolve_paged_kernel(kernel=None, head_dim=None, block_size=None,
             # interpret mode still needs the pallas import itself
             actual, why = "blockwise", "no_pallas"
         elif not interpret:
-            ok, why = _pk.is_eligible(head_dim, block_size)
+            ok, why = _pk.is_eligible(num_heads, head_dim, block_size)
             if not ok:
                 actual = "blockwise"
     if actual != req:
         _EVENTS.emit("kernel.fallback", "paged_decode_attention",
                      reason="kernel_fallback",
                      detail={"requested": req, "actual": actual,
-                             "why": why, "head_dim": head_dim,
+                             "why": why, "num_heads": num_heads,
+                             "head_dim": head_dim,
                              "block_size": block_size})
     return actual
 
@@ -247,8 +297,7 @@ def paged_decode_attention(q, k_new, v_new, k_pool, v_pool, block_tables,
     Returns ``(out [S, 1, H, D], new_k_pool, new_v_pool)`` — plus
     ``(new_k_scales, new_v_scales)`` in int8 mode.
     """
-    s = q.shape[0]
-    head_dim = q.shape[-1]
+    s, _, num_heads, head_dim = q.shape
     quantized = k_scales is not None
     lens = jnp.where(active, seq_lens, 0).astype(jnp.int32)
     rows = jnp.arange(s, dtype=jnp.int32)
@@ -270,7 +319,7 @@ def paged_decode_attention(q, k_new, v_new, k_pool, v_pool, block_tables,
         v_pool = v_pool.at[write_block, write_off].set(
             v_new[:, 0].astype(v_pool.dtype))
 
-    variant = resolve_paged_kernel(kernel, head_dim, block_size,
+    variant = resolve_paged_kernel(kernel, num_heads, head_dim, block_size,
                                    interpret=interpret)
     qh = q[:, 0]                                       # [S, H, D]
     if variant == "reference":

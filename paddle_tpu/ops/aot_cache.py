@@ -262,7 +262,6 @@ def env_fingerprint() -> dict:
             platform, kind = dev.platform, getattr(dev, "device_kind", "?")
         except Exception:
             platform, kind = "?", "?"
-        from ..framework.jax_compat import export_key_form
         fp = {
             "schema": _SCHEMA,
             "jax": jax.__version__,
@@ -270,7 +269,6 @@ def env_fingerprint() -> dict:
             "numpy": np.__version__,
             "platform": platform,
             "device_kind": kind,
-            "key_form": export_key_form(),
             "mesh": _mesh_mod.topology_token(),
             "flags": tuple(sorted(
                 [(k, bool(_FLAGS.get(k)))

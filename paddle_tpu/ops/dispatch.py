@@ -212,6 +212,12 @@ def _token_of(v, depth):
         return _UNKEYABLE
     if isinstance(v, _SAFE_SCALARS) or isinstance(v, enum.Enum):
         return v
+    if isinstance(v, types.ModuleType):
+        # a module in a closure cell (`from ...kernels import
+        # flash_attention as fa` inside the op wrapper) is the same stable
+        # singleton as a module global, which _globals_token keys by
+        # identity
+        return v
     if isinstance(v, slice):
         # slice objects are unhashable (3.10) but value-like: token their
         # (start, stop, step) so indexing ops (ops/manipulation.py slice /

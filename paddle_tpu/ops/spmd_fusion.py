@@ -316,6 +316,16 @@ def sharded_single_update(plan, k, opt, pv, gv, acc_dict, lr, step_count):
     return jax.lax.all_gather(np_s, "sharding", axis=dim, tiled=True), na
 
 
+def _shard_map(local, mesh, in_specs, out_specs):
+    """The promoted bodies were recorded from eager ops that know nothing
+    of varying-axis types (a `jnp.ones` cotangent seeds a loss that
+    varies over "data"), so the manual region runs without the
+    varying-axis check; replication is what the explicit pmean/all_gather
+    in the body establish, and probation verifies it numerically."""
+    return jax.shard_map(local, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
+
+
 def compile_step(plan, step_fn, n_params, n_scaler, n_extras,
                  donate_argnums):
     """Wrap the (local-semantics) step body in shard_map over the plan's
@@ -323,7 +333,6 @@ def compile_step(plan, step_fn, n_params, n_scaler, n_extras,
     call signature is identical to the plain lowering (pvals, ext, accs,
     lr, step_count[, scale, good, bad]), so the firing hook and the
     donation argnums are shared verbatim."""
-    from ..framework.jax_compat import shard_map
     P0 = P()
     acc_layout = plan.acc_layout
     in_specs = (
@@ -347,8 +356,7 @@ def compile_step(plan, step_fn, n_params, n_scaler, n_extras,
         return (out[0], tuple(out[1]), tuple(out[2]),
                 tuple(tuple(r) for r in out[3])) + tuple(out[4:])
 
-    smapped = shard_map(local, mesh=plan.mesh, in_specs=in_specs,
-                        out_specs=out_specs)
+    smapped = _shard_map(local, plan.mesh, in_specs, out_specs)
 
     def wrapper(pvals, ext, accs, lr, step_count, *sargs):
         flat = tuple(a for row in accs for a in row if a is not None)
@@ -399,7 +407,6 @@ def compile_accum(plan, sub_fn, n_params, n_tail):
     accumulator, NO gradient collective (only the scalar loss pmean the
     sub body emits). `n_tail` counts replicated scalar tail args (hoisted
     RNG + the running fwd-finite predicate)."""
-    from ..framework.jax_compat import shard_map
     P0 = P()
     sspec = _stack_spec(plan)
     in_specs = (
@@ -417,8 +424,7 @@ def compile_accum(plan, sub_fn, n_params, n_tail):
     # the program checks, signalled by the builder via an fn attribute
     n_extra = 1 if getattr(sub_fn, "_returns_fwd_ok", False) else 0
     specs = (P0, (sspec,) * n_params) + (P0,) * n_extra
-    m = shard_map(local, mesh=plan.mesh, in_specs=in_specs,
-                  out_specs=specs)
+    m = _shard_map(local, plan.mesh, in_specs, specs)
 
     def wrapper(pvals, ext, acc, *tail):
         return m(tuple(pvals), tuple(ext), tuple(acc), *tail)
@@ -431,7 +437,6 @@ def compile_update(plan, upd_fn, n_params, n_tail, n_extras,
     pmean region over the accumulated gradient sums (inside `upd_fn`),
     then the same clip/update/guardian/scaler weave as the whole-step
     lowering — sharded (ZeRO) slots update their local 1/Nth."""
-    from ..framework.jax_compat import shard_map
     P0 = P()
     sspec = _stack_spec(plan)
     acc_layout = plan.acc_layout
@@ -456,8 +461,7 @@ def compile_update(plan, upd_fn, n_params, n_tail, n_extras,
         return (tuple(out[0]), tuple(out[1]),
                 tuple(tuple(r) for r in out[2])) + tuple(out[3:])
 
-    smapped = shard_map(local, mesh=plan.mesh, in_specs=in_specs,
-                        out_specs=out_specs)
+    smapped = _shard_map(local, plan.mesh, in_specs, out_specs)
 
     def wrapper(pvals, accs, gsum, lr, step_count, *tail):
         flat = tuple(a for row in accs for a in row if a is not None)

@@ -64,7 +64,15 @@ class Optimizer:
         if key not in self._accumulators[name]:
             shp = shape if shape is not None else param._value.shape
             dt = dtype if dtype is not None else param._value.dtype
-            self._accumulators[name][key] = jnp.full(shp, fill_value, dt)
+            # a slot shaped like a sharded parameter is created with the
+            # parameter's placement: made on the default device it would
+            # put the whole optimizer state on device 0 first
+            sharding = getattr(param._value, "sharding", None)
+            if tuple(shp) != tuple(param._value.shape) or sharding is None \
+                    or len(sharding.device_set) <= 1:
+                sharding = None
+            self._accumulators[name][key] = jnp.full(shp, fill_value, dt,
+                                                     device=sharding)
         return self._accumulators[name][key]
 
     def _get_accumulator(self, name, param):

@@ -48,7 +48,7 @@ from . import sentinel as _sentinel
 
 __all__ = ["GoodputAccountant", "ACCOUNTANT", "on_step", "on_fused_fire",
            "mark", "note_stall", "estimate_cycle_flops",
-           "peak_flops_per_chip", "goodput_snapshot",
+           "CHIP_PEAKS", "peak_flops_per_chip", "goodput_snapshot",
            "format_step_ranges"]
 
 # rolling throughput window (steps): big enough to smooth scheduler
@@ -79,21 +79,30 @@ def format_step_ranges(indices):
     return ", ".join(out)
 
 
+# Published peaks per chip, keyed by jax's `device_kind` — the one table
+# every utilization in the repo divides by. A device that is not here has
+# no peak: that is an error, never a default.
+CHIP_PEAKS = {
+    "TPU v5 lite": {
+        "bf16_flops": 197e12, "hbm_bytes_per_s": 819e9,
+        "source": 'Google Cloud documentation, "TPU v5e": 197 TFLOP/s '
+                  'bf16, 819 GB/s HBM',
+    },
+}
+
+
 def peak_flops_per_chip():
-    """bf16 peak for the local chip — the single source of truth shared
-    with bench.py (TPU v5 lite / v5e: 197 TFLOP/s)."""
+    """bf16 peak FLOP/s of the local chip, from `CHIP_PEAKS`. Raises
+    LookupError for a device kind without a published entry (the CPU
+    included) — pass `peak=` to `set_flops_per_step` to supply one."""
     import jax
-    d = jax.devices()[0]
-    kind = getattr(d, "device_kind", "").lower()
-    if "v5 lite" in kind or "v5e" in kind:
-        return 197e12
-    if "v4" in kind:
-        return 275e12
-    if "v5p" in kind or "v5" in kind:
-        return 459e12
-    if "v6" in kind or "trillium" in kind:
-        return 918e12
-    return 197e12  # conservative default
+    kind = jax.devices()[0].device_kind
+    entry = CHIP_PEAKS.get(kind)
+    if entry is None:
+        raise LookupError(
+            f"no published peak for device kind {kind!r}; known: "
+            f"{sorted(CHIP_PEAKS)}")
+    return entry["bf16_flops"]
 
 
 # ---------------------------------------------------------------------------
@@ -493,10 +502,13 @@ class GoodputAccountant:
             if self._peak is None:
                 try:
                     self._peak = peak_flops_per_chip()
-                except Exception:
-                    self._peak = 197e12
-            T.mfu._default.set_raw(
-                sps * self._flops_per_step / self._peak)
+                except LookupError:
+                    # no published peak for this device: MFU is not
+                    # reported (the gauge stays unset), never guessed
+                    self._peak = False
+            if self._peak:
+                T.mfu._default.set_raw(
+                    sps * self._flops_per_step / self._peak)
         if self._tokens_per_step:
             T.tokens_per_s._default.set_raw(sps * self._tokens_per_step)
         total = sum(self.buckets.values())

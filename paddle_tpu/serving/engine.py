@@ -331,7 +331,8 @@ class LLMEngine:
         # affects engines built after it
         from ..nn.functional.attention import resolve_paged_kernel
         self._attn_kernel = resolve_paged_kernel(
-            attention_kernel, head_dim=head_dim, block_size=self.block_size)
+            attention_kernel, num_heads=cfg.num_attention_heads,
+            head_dim=head_dim, block_size=self.block_size)
         if self._kv_quantized:
             _EVENTS.emit("kernel.quantized", "serve.decode",
                          reason="kv_quantized",

@@ -5,7 +5,9 @@ from __future__ import annotations
 
 import os
 
-__all__ = ["get_include", "get_lib"]
+from .core._build import CACHE_ROOT
+
+__all__ = ["get_include", "get_lib", "CACHE_ROOT"]
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 

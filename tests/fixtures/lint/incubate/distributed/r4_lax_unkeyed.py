@@ -4,7 +4,7 @@ closure scan cannot key the axis binding, so every cycle containing the
 op poisons. shard_map-only bodies never reach the funnel and are clean."""
 import jax
 
-from paddle_tpu.framework.jax_compat import shard_map
+from jax import shard_map
 from paddle_tpu.ops.dispatch import call_op, mark_collective
 
 

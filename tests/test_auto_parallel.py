@@ -275,7 +275,7 @@ class TestCompletion:
 
 class TestPlannerValidation:
     """The planner's analytic ordering vs MEASURED step times on the
-    virtual mesh (VERDICT round-3 item 4: relative ordering, not absolute;
+    virtual mesh (relative ordering, not absolute;
     the virtual CPU mesh timeshares cores, so only well-separated pairs are
     asserted)."""
 

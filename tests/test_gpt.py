@@ -107,7 +107,7 @@ def test_kv_cache_decode_matches_full_forward():
     np.testing.assert_allclose(decoded, full, atol=2e-4, rtol=2e-3)
 
 
-# ---- serving decode (VERDICT r2 item 10) ------------------------------------
+# ---- serving decode ---------------------------------------------------------
 
 def test_generate_matches_eager_greedy_loop():
     """model.generate (one compiled program: prefill + lax.scan over static

@@ -427,7 +427,8 @@ class TestKernelKeying:
         set_flags({"FLAGS_profiler_events": True})
         clear_fusion_events()
         try:
-            got = resolve_paged_kernel("pallas", head_dim=64, block_size=16)
+            got = resolve_paged_kernel("pallas", num_heads=12, head_dim=64,
+                                       block_size=16)
         finally:
             set_flags(prev)
         assert got == "blockwise"

@@ -670,7 +670,7 @@ class TestGoodput:
         float(step(x, y))                          # compile
         set_flags({"FLAGS_metrics": True})
         fpt = model.flops_per_token(seq, training=True)
-        peak = pg.peak_flops_per_chip()
+        peak = 1e12         # the CPU has no published peak: supply one
         pg.ACCOUNTANT.reset(warm=True)
         pg.ACCOUNTANT.set_flops_per_step(fpt * batch * seq,
                                          tokens=batch * seq, peak=peak)
@@ -757,7 +757,19 @@ class TestGoodput:
         assert snap["flops_source"] == "cycle"
         expect = 3 * 2 * 16 * 32 * 32              # the matmul term
         assert expect <= snap["flops_per_step"] <= expect * 1.25
-        assert snap["mfu"] > 0
+        # FLOPs are counted, but the CPU has no published peak to divide
+        # by: MFU is not reported rather than guessed from a default
+        assert snap["mfu"] == 0
+
+    def test_unknown_device_has_no_peak(self):
+        """A device kind outside CHIP_PEAKS is an error, not 197e12: a
+        utilization over a guessed denominator reads like a measurement."""
+        with pytest.raises(LookupError, match="no published peak"):
+            pg.peak_flops_per_chip()
+        v5e = pg.CHIP_PEAKS["TPU v5 lite"]
+        assert v5e["bf16_flops"] == 197e12
+        assert v5e["hbm_bytes_per_s"] == 819e9
+        assert "Google Cloud" in v5e["source"]
 
     def test_explain_serving_cites_live_metrics(self, smodel):
         """Satellite: a degraded engine's doctor report carries the live

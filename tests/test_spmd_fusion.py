@@ -13,9 +13,8 @@ fires); mesh relayout mid-run (`mesh_mismatch` split + re-promotion on
 the new mesh); collective keying in the dispatch funnel (mesh-keyed
 groups key, pg-less groups poison as `collective_unkeyed` and the doctor
 names it); the AOT env fingerprint's mesh-topology token; and the
-jax_compat shard_map shim regressions the promoter leans on (psum over
-donated buffers, the partial-auto `axis_names` emulation, axis_size /
-pcast) on jax 0.4.x.
+`jax.shard_map` behaviours the promoter leans on (psum over donated
+buffers, partial-manual `axis_names`, axis_size / pcast).
 """
 import json
 import os
@@ -33,7 +32,6 @@ import paddle_tpu as paddle
 import paddle_tpu.nn.functional as F
 import paddle_tpu.distributed as dist
 from paddle_tpu.framework.flags import set_flags
-from paddle_tpu.framework.jax_compat import axis_size, pcast, shard_map
 from paddle_tpu.distributed.mesh import (build_mesh, mesh_key,
                                          set_global_mesh, topology_token,
                                          value_mesh_and_spec)
@@ -549,28 +547,27 @@ class TestCollectiveKeying:
 
 
 # ---------------------------------------------------------------------------
-# jax_compat shard_map shim regressions (the promoter leans on these)
+# jax.shard_map behaviours the promoter leans on
 # ---------------------------------------------------------------------------
 
 @needs_mesh
-class TestJaxCompatShims:
+class TestShardMapContract:
     def _mesh(self):
         return build_mesh(dp=4, pp=1, sharding=2, sep=1, mp=1)
 
     def test_psum_over_donated_buffers(self):
         """The fused SPMD step donates its optimizer-slot buffers into a
         jit(shard_map(psum ...)) program — the exact shape the promoter
-        compiles. Donation must not perturb the collective's result on
-        jax 0.4.x (check_rep=False path)."""
+        compiles. Donation must not perturb the collective's result."""
         mesh = self._mesh()
 
         def body(x, acc):
             s = jax.lax.pmean(x, ("data", "sharding"))
             return s, acc + s
 
-        fn = jax.jit(shard_map(body, mesh=mesh,
-                               in_specs=(P(("data", "sharding")), P()),
-                               out_specs=(P(), P())),
+        fn = jax.jit(jax.shard_map(body, mesh=mesh,
+                                   in_specs=(P(("data", "sharding")), P()),
+                                   out_specs=(P(), P())),
                      donate_argnums=(1,))
         xs = np.arange(16, dtype=np.float32).reshape(16, 1)
         x = jax.device_put(xs, NamedSharding(mesh, P(("data", "sharding"))))
@@ -583,19 +580,18 @@ class TestJaxCompatShims:
         np.testing.assert_allclose(np.asarray(acc), 3 * expected,
                                    rtol=1e-6)
 
-    def test_partial_auto_axis_names_emulation(self):
-        """axis_names={"data"} (partial-manual) on 0.4.x maps every axis
-        manually with replication over the unnamed ones — numerically
-        identical to real partial-auto for specs that never mention
-        them."""
+    def test_partial_manual_axis_names(self):
+        """axis_names={"data"} maps only that axis manually; the unnamed
+        axis stays with the partitioner and the result is what mapping
+        every axis with replication over the unnamed ones would give."""
         mesh = self._mesh()
 
         def body(x):
             return jax.lax.psum(x, "data")
 
-        fn = jax.jit(shard_map(body, mesh=mesh, in_specs=P("data"),
-                               out_specs=P("data"),
-                               axis_names={"data"}))
+        fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("data"),
+                                   out_specs=P("data"),
+                                   axis_names={"data"}))
         x = np.arange(8, dtype=np.float32).reshape(4, 2)
         out = np.asarray(fn(x))
         expected = np.tile(x.sum(axis=0, keepdims=True), (4, 1))
@@ -603,19 +599,22 @@ class TestJaxCompatShims:
 
     def test_axis_names_validated_against_mesh(self):
         mesh = self._mesh()
-        with pytest.raises(ValueError, match="not in mesh axes"):
-            shard_map(lambda x: x, mesh=mesh, in_specs=P(),
-                      out_specs=P(), axis_names={"bogus"})
+        with pytest.raises(ValueError, match="subset of mesh.axis_names"):
+            jax.shard_map(lambda x: x, mesh=mesh, in_specs=P(),
+                          out_specs=P(), axis_names={"bogus"})(
+                              np.ones(4, np.float32))
 
     def test_axis_size_and_pcast_inside_manual_region(self):
         mesh = self._mesh()
 
         def body(x):
-            n = axis_size("data")
-            return pcast(x * n, "data", to="varying")
+            # axis_size is a python int; the constant built from it is
+            # replicated until pcast marks it varying like the shard
+            n = jnp.full((1, 2), jax.lax.axis_size("data"), jnp.float32)
+            return x * jax.lax.pcast(n, "data", to="varying")
 
-        fn = jax.jit(shard_map(body, mesh=mesh, in_specs=P("data"),
-                               out_specs=P("data")))
+        fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=P("data"),
+                                   out_specs=P("data")))
         x = np.ones((4, 2), np.float32)
         np.testing.assert_allclose(np.asarray(fn(x)), 4 * x, rtol=1e-6)
 

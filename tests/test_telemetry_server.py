@@ -375,7 +375,7 @@ class TestHealth:
         assert good["step_indices"].get("stalled"), good["step_indices"]
 
     def test_idle_busy_engine_goes_stale_without_steps(self, smodel):
-        """The blind-tunnel shape: requests pending but the driver never
+        """The blind-hang shape: requests pending but the driver never
         steps (wedged outside the engine entirely) — /healthz flips once
         the heartbeat passes the window; an IDLE engine never does."""
         from paddle_tpu.serving import LLMEngine

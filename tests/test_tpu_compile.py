@@ -1,0 +1,139 @@
+"""The main path's Pallas kernels, compiled for a TPU v5e that is described
+and not attached.
+
+The TPU's compiler is installed beside the CPU backend and compiles for a
+topology description, so what it refuses — a block shape off the (8, 128)
+tiling, more VMEM than a kernel may use — is found here at no chip time.
+Interpret mode hides all of that: the paged-attention kernel passed every
+interpret-mode parity test while the compiler refused its `(1, d)` blocks.
+Shapes are those of `chip_smoke.py`. A compile that passes is not a chip
+run: nothing executes and nothing here is a device number.
+"""
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+# several test processes (pytest-xdist workers) may each describe a chip:
+# no hardware is opened, so libtpu's one-process lockfile has nothing to guard
+os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from paddle_tpu.kernels import cross_entropy, flash_attention, fused_ln
+from paddle_tpu.kernels.pallas import paged_attention
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """One described v5e chip; the persistent compile cache stays off
+    around these compiles (an entry written for a described chip cannot be
+    read back without one, and the next compile would warn)."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e topology: {e}")
+    assert topo.devices[0].device_kind == "TPU v5 lite"
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", prev)
+    compilation_cache.reset_cache()
+
+
+def compile_for(chip, fn, *shapes):
+    """Compile `fn` for the described chip; returns the program text."""
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+            for shape, dtype in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+    return text
+
+
+def test_flash_attention_fwd_bwd(v5e):
+    """GPT-2 124M training shape: batch 16, seq 1024, 12 heads of 64."""
+    qkv = ((16, 1024, 12, 64), jnp.bfloat16)
+
+    def loss(q, k, v):
+        out = flash_attention.flash_attention_bnhd(q, k, v, True, None)
+        return out.astype(jnp.float32).sum()
+
+    text = compile_for(v5e, jax.grad(loss, argnums=(0, 1, 2)), qkv, qkv, qkv)
+    assert text.count("tpu_custom_call") >= 3      # fwd, dq, dkv
+
+
+def test_fused_layer_norm(v5e):
+    rows, d = 16 * 1024, 768
+    assert fused_ln._pick_block_r(d) is not None
+    act, vec = ((rows, d), jnp.bfloat16), ((d,), jnp.bfloat16)
+    compile_for(v5e, fused_ln.fused_bias_residual_layer_norm,
+                act, act, vec, vec, vec)
+
+
+def test_fused_cross_entropy_fwd_bwd(v5e):
+    """The lm-head's [tokens, vocab] logits (the flag is off by default;
+    the kernel must still be one the chip accepts)."""
+    rows, vocab = 16 * 1024, 50304
+
+    def loss(logits, labels):
+        return cross_entropy.fused_softmax_cross_entropy(logits,
+                                                         labels).sum()
+
+    compile_for(v5e, jax.grad(loss), ((rows, vocab), jnp.bfloat16),
+                ((rows,), jnp.int32))
+
+
+# the serving shape of chip_smoke.py: 8 slots, block 16, a pool that lets
+# every slot reach 1024 tokens
+SLOTS, BLOCK, TABLE, POOL = 8, 16, 64, 513
+
+
+def paged_shapes(heads, head_dim, block, pool_dtype):
+    shapes = [((SLOTS, heads, head_dim), jnp.bfloat16),
+              ((POOL, block, heads, head_dim), pool_dtype),
+              ((POOL, block, heads, head_dim), pool_dtype),
+              ((SLOTS, TABLE), jnp.int32), ((SLOTS,), jnp.int32)]
+    if pool_dtype == jnp.int8:
+        shapes += [((POOL, heads), jnp.float32)] * 2
+    return shapes
+
+
+@pytest.mark.parametrize("pool_dtype", [jnp.bfloat16, jnp.int8],
+                         ids=["bf16", "int8"])
+@pytest.mark.parametrize("heads", [12, 16], ids=["124m", "355m"])
+def test_paged_decode_attention(v5e, heads, pool_dtype):
+    def decode(q, k, v, tables, lens, *scales):
+        return paged_attention.pallas_paged_attention(
+            q, k, v, tables, lens, BLOCK, *scales)
+
+    compile_for(v5e, decode, *paged_shapes(heads, 64, BLOCK, pool_dtype))
+
+
+def test_paged_eligibility_is_what_the_compiler_accepts(v5e, monkeypatch):
+    """`is_eligible` draws its line at the largest pool block the compiler
+    takes for every pool dtype; a float32 block twice that size runs out
+    of VMEM."""
+    monkeypatch.setattr(paged_attention, "_on_tpu", lambda: True)
+
+    def decode(block):
+        return lambda q, k, v, tables, lens: \
+            paged_attention.pallas_paged_attention(q, k, v, tables, lens,
+                                                   block)
+
+    heads, head_dim = 32, 128
+    assert paged_attention.is_eligible(heads, head_dim, 128) == (True, None)
+    compile_for(v5e, decode(128),
+                *paged_shapes(heads, head_dim, 128, jnp.float32))
+    assert paged_attention.is_eligible(heads, head_dim, 256) == (
+        False, "block_exceeds_vmem")
+    with pytest.raises(Exception, match="vmem"):
+        compile_for(v5e, decode(256),
+                    *paged_shapes(heads, head_dim, 256, jnp.float32))
+    # the shapes the engine serves today are far inside the line
+    assert paged_attention.is_eligible(12, 64, 16) == (True, None)
+    assert paged_attention.is_eligible(16, 64, 16) == (True, None)

@@ -1301,8 +1301,8 @@ def fleet_child_main(args):
         step += 1
     ev = EVENTS.snapshot()
     try:
-        # bench.py's dp2x2 leg lifts this into its own record (the
-        # restamp pattern the serve legs use); chaos scenarios ignore it
+        # a harness that embeds this child's report can restamp the leg
+        # name (the pattern the serve legs use); chaos scenarios ignore it
         from paddle_tpu.profiler.sentinel import capture_record
         sentinel = capture_record("fleet_child")
     except Exception:

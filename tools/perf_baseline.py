@@ -27,7 +27,7 @@ Usage:
 
 Record files may be a single record object, a list, a JSON-lines stream
 (bench.py output), or any nested document — every dict carrying the
-record shape is extracted, so `--check BENCH_r06.json` just works.
+record shape is extracted, so `--check <bench output>.json` just works.
 """
 from __future__ import annotations
 
