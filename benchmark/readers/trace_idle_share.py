@@ -1,0 +1,8 @@
+"""Percent of the traced window in which no operation ran on the device."""
+
+
+def read(evidence):
+    trace = evidence.get("trace")
+    if not trace:
+        return None
+    return 100.0 * (1.0 - trace["busy_s"] / trace["window_s"])

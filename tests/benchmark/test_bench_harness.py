@@ -1,0 +1,364 @@
+"""The harness is data: cells, configurations, mixes and per-layer metrics
+are found by name. Driven on the CPU at a tiny size through `run_cell`
+with the look for a chip skipped; the command line cannot skip it."""
+import json
+import os
+import re
+import subprocess
+import sys
+
+import pytest
+
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+import tiny_root  # noqa: E402
+
+from benchmark import harness, run as bench_run  # noqa: E402
+
+REPO = tiny_root.REPO
+FAKE_TRACE = {"window_s": 1.0, "devices": 1, "busy_s": 0.9,
+              "collective_s": 0.2, "collective_exposed_s": 0.1,
+              "op_seconds": {'%k.1 = bf16[8] custom-call(), custom_call_target="tpu_custom_call"': 0.3,
+                             "%copy.1 = bf16[2,65,4,4,16]{4,3,2,1,0} copy(%p)": 0.2},
+              "op_counts": {'%k.1 = bf16[8] custom-call(), custom_call_target="tpu_custom_call"': 6,
+                            "%copy.1 = bf16[2,65,4,4,16]{4,3,2,1,0} copy(%p)": 3},
+              "gaps": [("bench.step", 0.1)], "spans": []}
+
+
+@pytest.fixture
+def fake_trace(monkeypatch):
+    """The CPU gives the profiler no device plane: hand the readers a
+    canned reduction where the traced slice would leave one."""
+    import contextlib
+
+    @contextlib.contextmanager
+    def traced_slice(self):
+        yield
+        self.evidence["trace"] = dict(FAKE_TRACE)
+    monkeypatch.setattr(harness.Run, "traced_slice", traced_slice)
+
+
+@pytest.fixture
+def root(tmp_path):
+    tiny_root.make(str(tmp_path))
+    return str(tmp_path)
+
+
+def _files(top):
+    return {os.path.join(d, f): os.path.getmtime(os.path.join(d, f))
+            for d, _, fs in os.walk(top) for f in fs
+            if "__pycache__" not in d}
+
+
+@pytest.mark.parametrize("cell,metric", [
+    ("tiny_train_cell", "train_tokens_per_s"),
+    ("tiny_backlog_cell", "serve_tokens_per_s"),
+])
+def test_a_cell_runs_end_to_end_and_is_correct(root, cell, metric):
+    line = bench_run.run_cell(root, cell, seed=2 ** 31 + 7, seconds=1.0,
+                              traced=False, require_chip=False)
+    assert set(line) == {"correct", "attempted", "failed", "metrics",
+                         "device"}
+    assert line["metrics"][metric]["value"] > 0
+    assert line["metrics"]["setup_s"]["value"] > 0
+    assert line["failed"] == 0 and line["attempted"] > 0
+    assert line["device"]["platform"] == "cpu"
+    if cell != "tiny_train_cell":       # the tiny loss is one bf16 step off
+        assert line["correct"] is True
+
+
+def test_the_mesh_cell_spreads_its_state_over_four_devices(root, capsys):
+    bench_run.run_cell(root, "tiny_mesh_cell", seed=5, seconds=0.5,
+                       traced=False, require_chip=False)
+    checks = {c["check"]: c for c in map(json.loads, filter(
+        lambda l: l.startswith('{"check"'), capsys.readouterr().out
+        .splitlines()))}
+    assert checks["state_max_share"]["ok"]
+    assert 0.25 < checks["state_max_share"]["value"] < 0.75
+    assert checks["grad_norm_gap"]["ok"]
+
+
+def test_a_traced_run_reports_the_per_layer_metrics_of_its_cell(
+        root, fake_trace):
+    line = bench_run.run_cell(root, "tiny_backlog_cell", seed=3,
+                              seconds=0.5, traced=True, require_chip=False)
+    m = line["metrics"]
+    assert "breakdown" in line and "busy_s" in line["device"]
+    assert m["backlog.device_idle_share"]["value"] == pytest.approx(10.0)
+    # the pool of the tiny engine is [2, 65, 4, 4, 16]: found by shape
+    assert m["backlog.kv_copy_time_share"]["value"] == pytest.approx(
+        100 * 0.2 / 0.9)
+    assert m["backlog.batch_occupancy"]["value"] > 50
+    assert 0 < m["backlog.kv_pool_filled_share"]["value"] <= 100
+    assert 0 <= m["backlog.prefill_wall_share"]["value"] <= 100
+    assert not any(k.startswith(("mesh.", "train.")) for k in m)
+
+
+@pytest.mark.parametrize("cell,prefix,rate", [
+    ("tiny_train_cell", "train.", "train_tokens_per_s"),
+    ("tiny_mesh_cell", "mesh.", "mesh_train_tokens_per_s"),
+])
+def test_a_traced_train_run_reports_its_own_cells_metrics(
+        root, fake_trace, cell, prefix, rate):
+    """The two train cells run one loop and report end-to-end rates of
+    different names (the traffic file's `rate_metric`), each with the
+    per-layer metrics that move it."""
+    spec = json.load(open(os.path.join(root, "BENCHMARK.json")))
+    want = {m["name"] for m in spec["per_layer"] if cell in m["workloads"]}
+    assert want and all(n.startswith(prefix) for n in want)
+    assert {m["moves"] for m in spec["per_layer"]
+            if cell in m["workloads"]} == {rate}
+    line = bench_run.run_cell(root, cell, seed=9, seconds=0.5, traced=True,
+                              require_chip=False)
+    m = line["metrics"]
+    # the CPU has no table of peaks: the two shares of a peak stay out
+    assert set(m) == want - {prefix + "step_mfu",
+                             prefix + "flash_attn_roofline"}
+    assert m[prefix + "median_reading_tokens_per_s"]["value"] > 0
+    assert m[prefix + "stall_share"]["value"] < 100
+    plain = bench_run.run_cell(root, cell, seed=9, seconds=0.5,
+                               traced=False, require_chip=False)
+    assert set(plain["metrics"]) == {rate, "setup_s"}
+
+
+def test_new_configuration_mix_and_metric_are_files_of_their_own(
+        root, fake_trace, tmp_path, monkeypatch):
+    """Added as new files; nothing that was there is edited."""
+    data = os.path.join(root, "benchmark")
+    before = _files(root)
+    repo_before = _files(os.path.join(REPO, "benchmark"))
+    cfg = dict(tiny_root.TINY_MODEL, n_layer=1, n_embd=32, n_inner=128)
+    mix = dict(tiny_root.TRAFFIC["tiny_train"], batch_rows=2, seq=16,
+               reference_rows_per_block=2)
+    tiny_root._dump(os.path.join(data, "configs", "added_gpt.json"), cfg)
+    tiny_root._dump(os.path.join(data, "traffic", "added_mix.json"), mix)
+    tiny_root._dump(os.path.join(data, "limits", "added_cell.json"),
+                    dict(tiny_root.TRAIN_LIMITS, loss_gap=0.01))
+    tiny_root._dump(os.path.join(data, "metrics", "added.steps.json"),
+                    {"reader": "added_reader", "args": {"scale": 2}})
+    # a metric file names its reader's module; a later PR's lies under
+    # `benchmark/readers/`, this one wherever Python finds it
+    os.makedirs(tmp_path / "code")
+    with open(tmp_path / "code" / "added_reader.py", "w") as f:
+        f.write("def read(evidence, scale):\n"
+                "    return scale * evidence['readings']"
+                "['steps_per_reading']\n")
+    monkeypatch.syspath_prepend(str(tmp_path / "code"))
+    assert all(os.path.getmtime(p) == t for p, t in before.items())
+    # entries, not edits: a later PR appends to BENCHMARK.json's lists
+    spec = json.load(open(os.path.join(root, "BENCHMARK.json")))
+    spec["configs"].append({"name": "added_gpt", "source": "test",
+                            "file": "benchmark/configs/added_gpt.json",
+                            "reduced": [], "why": "test"})
+    spec["workloads"].append({"name": "added_cell", "config": "added_gpt",
+                              "traffic": "added_mix", "chips": 1,
+                              "why": "test"})
+    spec["per_layer"].append({
+        "name": "added.steps", "unit": "steps", "better": "higher",
+        "source": "program_counter", "layer": "train step",
+        "moves": "train_tokens_per_s", "workloads": ["added_cell"]})
+    for m in spec["end_to_end"]:
+        if m["name"] == "train_tokens_per_s":
+            m["workloads"].append("added_cell")
+    tiny_root._dump(os.path.join(root, "BENCHMARK.json"), spec)
+    line = bench_run.run_cell(root, "added_cell", seed=11, seconds=0.5,
+                              traced=True, require_chip=False)
+    assert line["correct"] is True
+    assert line["metrics"]["added.steps"]["value"] > 0
+    assert "train.stall_share" not in line["metrics"]   # not this cell's
+    assert _files(os.path.join(REPO, "benchmark")) == repo_before
+
+
+def test_a_reader_with_nothing_to_read_leaves_its_metric_out(root):
+    line = bench_run.run_cell(root, "tiny_train_cell", seed=1, seconds=0.5,
+                              traced=False, require_chip=False)
+    run = harness.Run(root, "tiny_train_cell", 1, 0.5, True,
+                      require_chip=False)
+    assert run.per_layer_metrics() == {}     # no window, no trace: nothing
+    assert "train.device_step_ms" not in line["metrics"]
+
+
+def test_without_a_tpu_the_command_exits_non_zero_and_prints_no_metric():
+    p = subprocess.run(
+        [sys.executable, "-m", "benchmark.run", "--workload",
+         "train_124m_step", "--seed", "1", "--seconds", "1", "--trace", "0"],
+        cwd=REPO, capture_output=True, text=True, timeout=300,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"))
+    assert p.returncode != 0
+    assert "metrics" not in p.stdout and "tokens/s" not in p.stdout
+    assert "needs 1 TPU chip" in p.stderr
+
+
+def test_an_unknown_workload_is_an_error(root):
+    with pytest.raises(SystemExit):
+        bench_run.run_cell(root, "no_such_cell", 1, 1.0, False,
+                           require_chip=False)
+
+
+def test_a_cell_without_limits_is_never_correct(root):
+    os.remove(os.path.join(root, "benchmark", "limits",
+                           "tiny_backlog_cell.json"))
+    line = bench_run.run_cell(root, "tiny_backlog_cell", seed=2,
+                              seconds=0.3, traced=False, require_chip=False)
+    assert line["correct"] is False
+
+
+# -- the timed path broken underneath: `correct` must come out false --------
+
+def test_a_step_that_returns_its_state_unchanged_is_not_correct(
+        root, monkeypatch):
+    from benchmark.programs import paddle_gpt
+    real = paddle_gpt.Trainer.step
+
+    def frozen(self, ids, labels):
+        kept = {k: v + 0 for k, v in self.master_params().items()}
+        loss = real(self, ids, labels)
+        masters = self.opt._accumulators["master_weight"]
+        named = paddle_gpt._named(self.model)
+        for k, p in named.items():
+            masters[p.name] = kept[k]
+        return loss
+    monkeypatch.setattr(paddle_gpt.Trainer, "step", frozen)
+    tiny_root._dump(os.path.join(root, "benchmark", "limits",
+                                 "tiny_train_cell.json"),
+                    dict(tiny_root.TRAIN_LIMITS, loss_gap=0.01))
+    line = bench_run.run_cell(root, "tiny_train_cell", seed=4, seconds=0.3,
+                              traced=False, require_chip=False)
+    assert line["correct"] is False
+
+
+def test_an_altered_served_token_is_not_correct(root, monkeypatch):
+    from paddle_tpu.serving import LLMEngine
+    real = LLMEngine._emit_token
+
+    def altered(self, req, tok, logp=None, alts=None):
+        if len(req.generated) == 2:
+            tok = (tok + 1) % 256
+        return real(self, req, tok, logp=logp, alts=alts)
+    monkeypatch.setattr(LLMEngine, "_emit_token", altered)
+    line = bench_run.run_cell(root, "tiny_backlog_cell", seed=6,
+                              seconds=0.5, traced=False, require_chip=False)
+    assert line["correct"] is False
+
+
+# -- BENCHMARK.json against the contract's limits ----------------------------
+
+NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
+UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
+SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+
+
+@pytest.fixture(scope="module")
+def spec():
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
+def test_benchmark_json_has_exactly_the_contracts_keys(spec):
+    assert set(spec) == {"command", "paths", "run_seconds", "configs",
+                         "workloads", "end_to_end", "per_layer"}
+    assert os.path.getsize(os.path.join(REPO, "BENCHMARK.json")) < 65536
+    assert 1 <= spec["run_seconds"] <= 51
+    cells = len(spec["workloads"])
+    assert (2 + 14 * 24) * (spec["run_seconds"] + 60) + 24 * 180 + 1200 \
+        <= 43200
+    assert sum(w["chips"] == 4 for w in spec["workloads"]) <= max(
+        1, cells // 4)
+
+
+def test_names_units_and_bounds_are_inside_the_limits(spec):
+    metrics = spec["end_to_end"] + spec["per_layer"]
+    names = [m["name"] for m in metrics]
+    assert len(names) == len(set(names))
+    for m in metrics:
+        assert NAME.match(m["name"]) and UNIT.match(m["unit"]), m
+        assert m["better"] in ("lower", "higher") and m["source"] in SOURCES
+    for m in spec["end_to_end"]:
+        assert set(m) <= {"name", "unit", "better", "bound", "source",
+                          "workloads"}
+        assert 0.01 <= m["bound"] <= 0.1
+        assert m["source"] in ("host_clock", "device_trace")
+    assert "setup_s" in names
+    e2e = {m["name"] for m in spec["end_to_end"]}
+    for m in spec["per_layer"]:
+        assert set(m) <= {"name", "unit", "better", "source", "layer",
+                          "moves", "workloads"}
+        assert m["moves"] in e2e and 1 <= len(m["layer"]) <= 200
+        if m["name"].endswith("_roofline") or "mfu" in m["name"]:
+            assert m["unit"] == "%"
+
+
+def test_a_train_mix_names_the_rate_its_cell_reports(spec):
+    """The train loop reports its rate under the traffic file's
+    `rate_metric`: that has to be an end-to-end metric of the cell."""
+    seen = 0
+    for w in spec["workloads"]:
+        mix = json.load(open(os.path.join(REPO, "benchmark", "traffic",
+                                          w["traffic"] + ".json")))
+        if mix["loop"] != "train":
+            continue
+        seen += 1
+        rate = [m for m in spec["end_to_end"]
+                if m["name"] == mix["rate_metric"]]
+        assert len(rate) == 1 and rate[0]["workloads"] == [w["name"]]
+        assert rate[0]["unit"] == "tokens/s" and rate[0]["better"] == "higher"
+    assert seen == 2
+
+
+def test_every_cell_finds_its_files_and_reports_what_it_must(spec):
+    configs = {c["name"]: c for c in spec["configs"]}
+    cells = {w["name"] for w in spec["workloads"]}
+    used = set()
+    for w in spec["workloads"]:
+        assert NAME.match(w["name"]) and len(w["why"]) <= 200
+        assert w["chips"] in (1, 4)
+        used.add(w["config"])
+        cfg = configs[w["config"]]
+        assert os.path.exists(os.path.join(REPO, cfg["file"]))
+        mix = os.path.join(REPO, "benchmark", "traffic",
+                           w["traffic"] + ".json")
+        loop = json.load(open(mix))["loop"]
+        assert os.path.exists(os.path.join(REPO, "benchmark", "loops",
+                                           loop + ".py"))
+        assert os.path.exists(os.path.join(REPO, "benchmark", "limits",
+                                           w["name"] + ".json"))
+        mine = [m for m in spec["end_to_end"]
+                if w["name"] in m.get("workloads", cells)]
+        assert len(mine) >= 2 and any(m["name"] == "setup_s" for m in mine)
+        layer = [m for m in spec["per_layer"]
+                 if w["name"] in m.get("workloads", cells)]
+        assert layer
+        for m in layer:
+            assert w["name"] in [c for e in spec["end_to_end"]
+                                 if e["name"] == m["moves"]
+                                 for c in e.get("workloads", cells)]
+    assert used == set(configs)
+    for m in spec["end_to_end"] + spec["per_layer"]:
+        assert set(m.get("workloads", [])) <= cells
+
+
+def test_metric_files_and_per_layer_metrics_are_the_same_set(spec):
+    """Each per-layer metric has its file, each file its metric, and
+    every file names a reader kept under `benchmark/readers/`."""
+    top = os.path.join(REPO, "benchmark", "metrics")
+    assert sorted(n[:-len(".json")] for n in os.listdir(top)) == sorted(
+        m["name"] for m in spec["per_layer"])
+    for m in spec["per_layer"]:
+        reader = json.load(open(os.path.join(top, m["name"] + ".json")))[
+            "reader"]
+        package, _, module = reader.rpartition(".")
+        assert package == "benchmark.readers", reader
+        assert os.path.exists(os.path.join(REPO, "benchmark", "readers",
+                                           module + ".py")), reader
+
+
+def test_configurations_keep_the_published_widths(spec):
+    for c in spec["configs"]:
+        cfg = json.load(open(os.path.join(REPO, c["file"])))
+        assert cfg["n_embd"] % cfg["n_head"] == 0
+        assert cfg["n_inner"] == 4 * cfg["n_embd"]
+        assert not any(re.search(r"(_dim|_rank|n_embd|n_inner|n_head)$", k)
+                       for k in c["reduced"])
+    small = json.load(open(os.path.join(
+        REPO, "benchmark", "configs", "gpt2_124m.json")))
+    assert (small["n_layer"], small["n_embd"], small["n_head"],
+            small["n_positions"]) == (12, 768, 12, 1024)

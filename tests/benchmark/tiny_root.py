@@ -1,0 +1,93 @@
+"""A benchmark root of tiny cells in a temporary directory, for driving the
+harness on the CPU: new configuration, traffic and limits files beside
+copies of the real metric files; no file of the benchmark is edited."""
+from __future__ import annotations
+
+import json
+import os
+import shutil
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+
+TINY_MODEL = {
+    "activation_function": "gelu_new", "attn_pdrop": 0.0, "embd_pdrop": 0.0,
+    "resid_pdrop": 0.0, "initializer_range": 0.02,
+    "layer_norm_epsilon": 1e-05, "n_ctx": 64, "n_embd": 64, "n_head": 4,
+    "n_inner": 256, "n_layer": 2, "n_positions": 64, "vocab_size": 256,
+    "tie_word_embeddings": True,
+    "precision": {"params": "bfloat16", "activations": "bfloat16",
+                  "optimizer_state": "float32", "control": "fp8"},
+    "optimizer": {"name": "AdamW", "learning_rate": 1e-4,
+                  "weight_decay": 0.01, "beta1": 0.9, "beta2": 0.999,
+                  "epsilon": 1e-8},
+    "program": "benchmark.programs.paddle_gpt",
+}
+TINY_SERVE = {
+    "engine": {"max_batch_size": 4, "block_size": 4, "max_context": 64},
+    "prefill_buckets": [8, 16], "compile_tokens": 2,
+    "prompt_tokens": {"median": 8, "sigma": 0.5, "lo": 5, "hi": 16},
+    "output_tokens": {"median": 6, "sigma": 0.5, "lo": 3, "hi": 12},
+    "block": 8, "trace_seconds": 0.2, "checked_requests": 3,
+    "reference_pad_to": 32,
+}
+TRAFFIC = {
+    "tiny_train": {"loop": "train", "rate_metric": "train_tokens_per_s",
+                   "batch_rows": 4, "seq": 32,
+                   "donate": "all", "distinct_batches": 4, "readings": 9,
+                   "trace_seconds": 0.2, "reference_rows_per_block": 2},
+    "tiny_mesh": {"loop": "train", "rate_metric": "mesh_train_tokens_per_s",
+                  "batch_rows": 4, "seq": 32,
+                  "donate": True, "mesh": {"data": 2, "model": 2},
+                  "distinct_batches": 4, "readings": 9,
+                  "trace_seconds": 0.2},
+    "tiny_backlog": {"loop": "serve_backlog", **TINY_SERVE,
+                     "queue_depth": 4, "warm_completions": 4},
+}
+CELLS = [("tiny_train_cell", "tiny_train", 1), ("tiny_mesh_cell", "tiny_mesh", 4),
+         ("tiny_backlog_cell", "tiny_backlog", 1)]
+TRAIN_LIMITS = {"loss_gap": 1e-3, "grad_norm_gap": 0.05,
+                "delta_norm_gap": 0.05, "state_max_share": 0.75}
+SERVE_LIMITS = {"logit_gap": 0.05}
+
+
+def _dump(path, obj):
+    os.makedirs(os.path.dirname(path), exist_ok=True)
+    with open(path, "w") as f:
+        json.dump(obj, f)
+
+
+def make(root):
+    """Write the tiny root under `root`; returns its BENCHMARK.json dict."""
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        real = json.load(f)
+    data = os.path.join(root, "benchmark")
+    shutil.copytree(os.path.join(REPO, "benchmark", "metrics"),
+                    os.path.join(data, "metrics"))
+    _dump(os.path.join(data, "configs", "tiny_gpt.json"), TINY_MODEL)
+    for name, mix in TRAFFIC.items():
+        _dump(os.path.join(data, "traffic", name + ".json"), mix)
+    for cell, traffic, _ in CELLS:
+        _dump(os.path.join(data, "limits", cell + ".json"),
+              TRAIN_LIMITS if TRAFFIC[traffic]["loop"] == "train"
+              else SERVE_LIMITS)
+    kinds = {"train_124m_step": "tiny_train_cell",
+             "train_1p3b_mesh4": "tiny_mesh_cell",
+             "serve_124m_backlog": "tiny_backlog_cell"}
+
+    def renamed(m):
+        m = dict(m)
+        if "workloads" in m:
+            m["workloads"] = [kinds[w] for w in m["workloads"] if w in kinds]
+        return m
+
+    spec = dict(real, paths=["benchmark"], configs=[
+        {"name": "tiny_gpt", "source": "test",
+         "file": "benchmark/configs/tiny_gpt.json", "reduced": [],
+         "why": "test"}],
+        workloads=[{"name": c, "config": "tiny_gpt", "traffic": t,
+                    "chips": n, "why": "test"} for c, t, n in CELLS],
+        end_to_end=[renamed(m) for m in real["end_to_end"]],
+        per_layer=[renamed(m) for m in real["per_layer"]])
+    _dump(os.path.join(root, "BENCHMARK.json"), spec)
+    return spec
