@@ -348,12 +348,13 @@ class GPTForCausalLM(Layer):
             hidden = self.gpt(input_ids, position_ids)
         else:
             hidden, caches = self.gpt(input_ids, position_ids, caches)
-        if self.lm_head is not None:
-            logits = self.lm_head(hidden)
-        else:
-            # tied: logits = hidden @ wte^T
-            logits = F.linear(hidden,
-                              manip.transpose(self.gpt.wte.weight, [1, 0]))
+        with jax.named_scope("lm_head"):
+            if self.lm_head is not None:
+                logits = self.lm_head(hidden)
+            else:
+                # tied: logits = hidden @ wte^T
+                logits = F.linear(
+                    hidden, manip.transpose(self.gpt.wte.weight, [1, 0]))
         return logits if caches is None else (logits, caches)
 
     def num_params(self, include_embeddings=True):
@@ -538,6 +539,7 @@ class GPTPretrainingCriterion(Layer):
         super().__init__()
         self.ignore_index = ignore_index
 
+    @jax.named_scope("lm_loss")
     def forward(self, logits, labels):
         b, n, v = logits.shape
         flat = manip.reshape(logits, [b * n, v])

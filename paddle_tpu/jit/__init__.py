@@ -1,7 +1,7 @@
 from .api import (  # noqa: F401
     to_static, not_to_static, ignore_module, TracedLayer, TranslatedLayer,
     save, load, InputSpec)
-from .train_step import TrainStep  # noqa: F401
+from .train_step import TrainStep, train_step_stats  # noqa: F401
 
 
 class ProgramTranslator:

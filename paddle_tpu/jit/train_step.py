@@ -13,14 +13,55 @@ model's wrapper tensors are refreshed after each call so eager inspection
 """
 from __future__ import annotations
 
+import itertools
+import weakref
+
 import jax
 import jax.numpy as jnp
 
 from ..framework.core import Tensor
 from ..framework import random as _random
 from ..framework.autograd import set_grad_enabled
+from ..profiler import RecordEvent
+from ..profiler.metrics import LogHistogram
 
-__all__ = ["TrainStep", "bake_decay_flags", "donation_argnums"]
+__all__ = ["TrainStep", "TrainStepStats", "train_step_stats",
+           "bake_decay_flags", "donation_argnums"]
+
+# every TrainStep alive in the process, in order of creation, for readers
+# that hold no handle to one (`train_step_stats`; held weakly, the pattern
+# of telemetry_server.register_engine)
+_LIVE = weakref.WeakValueDictionary()
+_SERIAL = itertools.count()
+
+
+def train_step_stats():
+    """`stats()` of every live `TrainStep`, oldest first."""
+    return [step.stats() for step in list(_LIVE.values())]
+
+
+class TrainStepStats:
+    """Host-side counters of one `TrainStep`: the seconds of each phase of
+    `__call__` in a bounded histogram keyed by the phase's span, which
+    feeds it, and the programs the jitted step has traced (bumped INSIDE
+    the traced function, which runs only while tracing)."""
+
+    # spans that own a histogram (`train_step.build` is for the trace alone)
+    PHASES = ("train_step.call", "train_step.gather_state",
+              "train_step.dispatch", "train_step.write_back")
+
+    def __init__(self):
+        self.compiles = 0
+        self.phase = {name: LogHistogram() for name in self.PHASES}
+
+    def snapshot(self):
+        out = {"steps": self.phase["train_step.call"].count,
+               "compiles": self.compiles}
+        for name, hist in self.phase.items():
+            short = name.split(".", 1)[1]
+            out[f"{short}_p50_ms"] = hist.percentile(50) * 1e3
+            out[f"{short}_p99_ms"] = hist.percentile(99) * 1e3
+        return out
 
 
 def bake_decay_flags(opt, params):
@@ -55,6 +96,18 @@ class TrainStep:
         self._params = None
         self._acc_names = None
         self._donate = donate
+        self._stats = TrainStepStats()
+        _LIVE[next(_SERIAL)] = self
+
+    def stats(self):
+        """{"steps", "compiles", "<phase>_p50_ms", "<phase>_p99_ms"} for
+        the phases `call`, `gather_state`, `dispatch`, `write_back`."""
+        return self._stats.snapshot()
+
+    def _span(self, name):
+        """The span `name` of this step; its seconds go to the phase
+        histogram of that name where `TrainStepStats` keeps one."""
+        return RecordEvent(name, hist=self._stats.phase.get(name))
 
     def _build(self, example_args):
         model = self.model
@@ -97,7 +150,10 @@ class TrainStep:
         # bake per-param decay flags for AdamW/Lamb before tracing
         bake_decay_flags(opt, params)
 
+        stats = self._stats
+
         def step(pvals, accs, bvals, args, lr, step_count, key):
+            stats.compiles += 1         # runs only while jit traces
             (loss, new_b), grads = jax.value_and_grad(
                 pure_loss, has_aux=True)(pvals, bvals, args, key)
             new_p, new_accs = [], []
@@ -128,7 +184,8 @@ class TrainStep:
         arg_vals = [a._value if isinstance(a, Tensor) else jnp.asarray(a)
                     for a in args]
         if self._jitted is None:
-            self._build(arg_vals)
+            with self._span("train_step.build"):
+                self._build(arg_vals)
         params = self._params
         opt = self.optimizer
         opt._create_accumulators(params)
@@ -149,17 +206,24 @@ class TrainStep:
         return self._jitted.lower(*state, jnp.asarray(1, jnp.int32), key)
 
     def __call__(self, *args):
-        state = self._state_args(args)
-        params = self._params
-        opt = self.optimizer
-        acc_names = self._acc_names
-        if not hasattr(opt, "_step_count"):
-            opt._step_count = 0
-        opt._step_count += 1
-        step_count = jnp.asarray(opt._step_count, jnp.int32)
-        key = _random.get_rng_key()
+        with self._span("train_step.call"):
+            return self._call(args)
 
-        loss, new_p, new_accs, new_b = self._jitted(*state, step_count, key)
+    def _call(self, args):
+        opt = self.optimizer
+        with self._span("train_step.gather_state"):
+            state = self._state_args(args)
+            if not hasattr(opt, "_step_count"):
+                opt._step_count = 0
+            opt._step_count += 1
+            step_count = jnp.asarray(opt._step_count, jnp.int32)
+            key = _random.get_rng_key()
+        params = self._params
+        acc_names = self._acc_names
+
+        with self._span("train_step.dispatch"):
+            loss, new_p, new_accs, new_b = self._jitted(*state, step_count,
+                                                        key)
         from ..framework.flags import _FLAGS
         if _FLAGS.get("FLAGS_check_nan_inf") and \
                 not bool(jnp.isfinite(loss)):
@@ -175,16 +239,18 @@ class TrainStep:
                 "(FLAGS_check_nan_inf); parameters were NOT updated "
                 "(optimizer accumulators were) — re-run the step eagerly "
                 "to locate the offending op")
-        for p, v in zip(params, new_p):
-            p._value = v
-        for p, ac in zip(params, new_accs):
-            for n, v in zip(acc_names, ac):
-                if v is not None:
-                    opt._accumulators[n][p.name] = v
-        for b, v in zip(self._buffers, new_b):
-            b._value = v
-        # goodput accountant (profiler/goodput.py): the explicit fused
-        # TrainStep never crosses Optimizer.step, so the boundary is here
-        from ..profiler import goodput as _goodput
-        _goodput.on_step(opt)
+        with self._span("train_step.write_back"):
+            for p, v in zip(params, new_p):
+                p._value = v
+            for p, ac in zip(params, new_accs):
+                for n, v in zip(acc_names, ac):
+                    if v is not None:
+                        opt._accumulators[n][p.name] = v
+            for b, v in zip(self._buffers, new_b):
+                b._value = v
+            # goodput accountant (profiler/goodput.py): the explicit fused
+            # TrainStep never crosses Optimizer.step, so the boundary is
+            # here
+            from ..profiler import goodput as _goodput
+            _goodput.on_step(opt)
         return Tensor(loss)

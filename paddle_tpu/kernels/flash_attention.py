@@ -177,6 +177,7 @@ def _flash_fwd(q, k, v, causal, scale, block_q=None, block_k=None,
             jax.ShapeDtypeStruct((b * h, n, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_attention_fwd",
     )(qf, kf, vf)
     return out.reshape(b, h, n, d).swapaxes(1, 2), lse
 
@@ -309,6 +310,7 @@ def _flash_bwd(q, k, v, out, lse, g, causal, scale,
                                lambda bh, qi: (bh, qi, zero)),
         out_shape=jax.ShapeDtypeStruct((b * h, n, d), q.dtype),
         interpret=interpret,
+        name="flash_attention_dq",
     )(qf, kf, vf, gf, lse, delta)
 
     dkv_kernel = functools.partial(_dkv_kernel, causal=causal, scale=scale,
@@ -333,6 +335,7 @@ def _flash_bwd(q, k, v, out, lse, g, causal, scale,
             jax.ShapeDtypeStruct((b * h, m, d), v.dtype),
         ],
         interpret=interpret,
+        name="flash_attention_dkv",
     )(qf, kf, vf, gf, lse, delta)
 
     def unfold(t, nn):

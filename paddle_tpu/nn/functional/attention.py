@@ -307,35 +307,41 @@ def paged_decode_attention(q, k_new, v_new, k_pool, v_pool, block_tables,
     write_block = jnp.where(
         active, block_tables[rows, lens // block_size], 0).astype(jnp.int32)
     write_off = lens % block_size
-    if quantized:
-        from ...quantization.kv_cache import quantize_block_write
-        k_pool, k_scales = quantize_block_write(
-            k_pool, k_scales, k_new[:, 0], write_block, write_off)
-        v_pool, v_scales = quantize_block_write(
-            v_pool, v_scales, v_new[:, 0], write_block, write_off)
-    else:
-        k_pool = k_pool.at[write_block, write_off].set(
-            k_new[:, 0].astype(k_pool.dtype))
-        v_pool = v_pool.at[write_block, write_off].set(
-            v_new[:, 0].astype(v_pool.dtype))
+    # the scopes name the two parts in the compiled program's op names, so
+    # a device trace can tell them from the rest of the decode step
+    with jax.named_scope("paged_kv_write"):
+        if quantized:
+            from ...quantization.kv_cache import quantize_block_write
+            k_pool, k_scales = quantize_block_write(
+                k_pool, k_scales, k_new[:, 0], write_block, write_off)
+            v_pool, v_scales = quantize_block_write(
+                v_pool, v_scales, v_new[:, 0], write_block, write_off)
+        else:
+            k_pool = k_pool.at[write_block, write_off].set(
+                k_new[:, 0].astype(k_pool.dtype))
+            v_pool = v_pool.at[write_block, write_off].set(
+                v_new[:, 0].astype(v_pool.dtype))
 
     variant = resolve_paged_kernel(kernel, num_heads, head_dim, block_size,
                                    interpret=interpret)
     qh = q[:, 0]                                       # [S, H, D]
-    if variant == "reference":
-        out = _dense_gather_attention(qh, k_pool, v_pool, block_tables,
-                                      lens, block_size, k_scales, v_scales)
-    elif variant == "blockwise":
-        from ...kernels.pallas.paged_attention import (
-            blockwise_paged_attention)
-        out = blockwise_paged_attention(qh, k_pool, v_pool, block_tables,
-                                        lens, block_size, k_scales,
-                                        v_scales)
-    else:
-        from ...kernels.pallas.paged_attention import pallas_paged_attention
-        out = pallas_paged_attention(qh, k_pool, v_pool, block_tables,
-                                     lens, block_size, k_scales, v_scales,
-                                     interpret=interpret)
+    with jax.named_scope("paged_attention"):
+        if variant == "reference":
+            out = _dense_gather_attention(
+                qh, k_pool, v_pool, block_tables, lens, block_size,
+                k_scales, v_scales)
+        elif variant == "blockwise":
+            from ...kernels.pallas.paged_attention import (
+                blockwise_paged_attention)
+            out = blockwise_paged_attention(
+                qh, k_pool, v_pool, block_tables, lens, block_size,
+                k_scales, v_scales)
+        else:
+            from ...kernels.pallas.paged_attention import (
+                pallas_paged_attention)
+            out = pallas_paged_attention(
+                qh, k_pool, v_pool, block_tables, lens, block_size,
+                k_scales, v_scales, interpret=interpret)
     if quantized:
         return out[:, None], k_pool, v_pool, k_scales, v_scales
     return out[:, None], k_pool, v_pool

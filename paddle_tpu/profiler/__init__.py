@@ -2,9 +2,14 @@
 (Profiler, ProfilerState, export_chrome_tracing) over platform/profiler/ C++
 tracers (HostTracer + CudaTracer/CUPTI).
 
-TPU-first: host events are recorded by a lightweight in-process recorder
-(HostTracer analog); device timeline comes from the jax/XLA profiler
-(xplane → TensorBoard/perfetto), the CUPTI analog. `timer` provides the
+TPU-first: the device timeline comes from the jax/XLA profiler (xplane →
+TensorBoard/perfetto), the CUPTI analog, and `RecordEvent` — the program's
+one span class — writes every host span into that same trace, on its
+clock, as a `jax.profiler.TraceAnnotation`: "tracing on" means a
+`jax.profiler` session is open (`Profiler(targets=[TPU])` opens one).
+While a `Profiler` records, spans also land in its chrome-trace lane (the
+HostTracer analog); with neither open a span costs an inactive TraceMe and,
+where its owner gave it a histogram, one observation. `timer` provides the
 ips/tokens-per-second benchmark hooks (reference: profiler/timer.py).
 """
 from __future__ import annotations
@@ -15,6 +20,8 @@ import os
 import threading
 import time
 from enum import Enum
+
+import jax
 
 from .dispatch import (DispatchStats, dispatch_cache_stats,
                        reset_dispatch_cache_stats)
@@ -137,33 +144,52 @@ _active_profiler = None
 
 
 class RecordEvent:
-    """Scoped host event (reference: profiler/event_tracing.h RecordEvent +
-    python profiler/utils.py RecordEvent)."""
+    """Scoped host span (reference: profiler/event_tracing.h RecordEvent +
+    python profiler/utils.py RecordEvent).
 
-    def __init__(self, name, event_type=None):
+    Clock and gating: entering always opens a `jax.profiler.TraceAnnotation`
+    of the same name, so inside a `jax.profiler` session the span is on the
+    host plane of the `.xplane.pb` that holds the device's `XLA Ops`, nested
+    by time under the caller's span; with no session it is an inactive
+    TraceMe. `hist` is a bounded histogram (`LogHistogram`) held by the
+    object whose work is timed: the span's seconds are observed there on
+    exit, session or not. A parent's self time is its histogram's sum less
+    its children's. Only while a `Profiler` records does the span also go
+    to its chrome-trace lane (native host tracer, else the in-process
+    recorder); otherwise neither is touched, so a hot path neither builds
+    the native library nor grows a list nobody drains."""
+
+    __slots__ = ("name", "_hist", "_begin", "_annotation")
+
+    def __init__(self, name, event_type=None, hist=None):
         self.name = name
+        self._hist = hist
         self._begin = None
+        self._annotation = None
 
     def begin(self):
-        from ..core import host_tracer
-        if host_tracer.is_native:
-            self._begin = host_tracer.now_ns()
-        else:
-            self._begin = time.perf_counter_ns()
+        self._annotation = jax.profiler.TraceAnnotation(self.name)
+        self._annotation.__enter__()
+        # CLOCK_MONOTONIC, which is also the native host tracer's clock
+        self._begin = time.perf_counter_ns()
 
     def end(self):
         if self._begin is None:
             return
-        from ..core import host_tracer
-        if host_tracer.is_native:
-            # hot path: one ctypes call into the native recorder
-            host_tracer.span(self.name, self._begin, host_tracer.now_ns())
-        else:
-            now = time.perf_counter_ns()
-            _recorder.add(self.name, self._begin / 1000.0,
-                          (now - self._begin) / 1000.0,
-                          threading.get_ident())
+        begin, now = self._begin, time.perf_counter_ns()
         self._begin = None
+        self._annotation.__exit__(None, None, None)
+        self._annotation = None
+        if self._hist is not None:
+            self._hist.observe((now - begin) * 1e-9)
+        if _active_profiler is not None:
+            from ..core import host_tracer
+            if host_tracer.is_native:
+                host_tracer.span(self.name, begin, now)
+            else:
+                _recorder.add(self.name, begin / 1000.0,
+                              (now - begin) / 1000.0,
+                              threading.get_ident())
 
     def __enter__(self):
         self.begin()
@@ -309,8 +335,10 @@ class Profiler:
 
     def export(self, path, format="json"):
         """Chrome-trace JSON: host lane(s) + one synthetic lane per fusion
-        tier (dispatch/chain/step), loadable in perfetto next to the XLA
-        xplane device profile (`jax_trace_dir`). The raw event dicts also
+        tier (dispatch/chain/step). With `targets=[TPU]` the same host
+        spans are also IN the XLA xplane under `jax_trace_dir`, on the
+        device operations' clock (`RecordEvent`); this JSON is the lane
+        that needs no device profile. The raw event dicts also
         ride along under `fusion_events` so `load_profiler_result`
         round-trips without loss (the lane projection is lossy: chrome
         args stringify keys)."""

@@ -36,6 +36,7 @@ from __future__ import annotations
 
 from collections import deque
 
+import jax
 import jax.numpy as jnp
 
 __all__ = ["BlockAllocator", "PagedKVCache", "PagedCacheView",
@@ -234,6 +235,7 @@ def num_blocks_for_bytes(budget_bytes, num_layers, num_heads, head_dim,
     return max(2, int(budget_bytes) // per)
 
 
+@jax.named_scope("scatter_prefill")
 def scatter_prefill(k_pools, v_pools, k_layers, v_layers, block_row,
                     length, block_size, k_scales=None, v_scales=None):
     """Bulk-insert a prefilled prompt's K/V into the pools.

@@ -105,6 +105,7 @@ import jax.numpy as jnp
 from ..framework.core import Tensor
 from ..framework.autograd import set_grad_enabled
 from ..framework.flags import _FLAGS
+from ..profiler import RecordEvent
 from ..profiler.events import EVENTS as _EVENTS
 from ..profiler.metrics import LogHistogram, SERVE as _M, \
     enabled as _metrics_on
@@ -141,7 +142,15 @@ class ServeStats:
     rest of the process's life. `step_times_s` survives as a short
     recent-sample list (the admission-time wait estimate reads it)."""
 
+    # spans of the engine that own a histogram, fed on exit (the others
+    # are for the trace alone); the shares of `snapshot()` come from sums
+    PHASES = ("engine.step", "engine.prefill", "engine.decode",
+              "engine.decode.dispatch", "engine.stream")
+
     def __init__(self):
+        # first calls of programs (`engine.compile`): what the process
+        # paid since the engine was built, so no window resets it
+        self.compile_hist = LogHistogram()
         self.reset()
 
     def reset(self):
@@ -149,6 +158,8 @@ class ServeStats:
         closures hold a reference to this object (that is how
         decode_compiles counts real traces), so a bench warmup resets the
         window without losing retrace visibility."""
+        self.phase = {name: LogHistogram() for name in self.PHASES}
+        self.phase["engine.compile"] = self.compile_hist
         self.steps = 0
         self.tokens_generated = 0
         self.prefills = 0
@@ -216,6 +227,13 @@ class ServeStats:
         elapsed = None
         if self.wall_t0 is not None and self.wall_t1 is not None:
             elapsed = self.wall_t1 - self.wall_t0
+
+        def share(seconds):
+            return seconds / elapsed if elapsed else 0.0
+
+        phase = self.phase
+        inside = sum(phase[n].sum for n in (
+            "engine.prefill", "engine.decode", "engine.stream"))
         return {
             "steps": self.steps,
             "tokens_generated": self.tokens_generated,
@@ -264,6 +282,18 @@ class ServeStats:
                 self.queue_wait_hist.percentile(50) * 1e3,
             "queue_wait_p99_ms":
                 self.queue_wait_hist.percentile(99) * 1e3,
+            # host phases of step(), from the engine's own spans
+            "prefill_p50_ms": phase["engine.prefill"].percentile(50) * 1e3,
+            "decode_dispatch_p50_ms":
+                phase["engine.decode.dispatch"].percentile(50) * 1e3,
+            "prefill_share": share(phase["engine.prefill"].sum),
+            "decode_share": share(phase["engine.decode"].sum),
+            "stream_share": share(phase["engine.stream"].sum),
+            # step() outside those three: admission bookkeeping, the
+            # scheduler, KV growth, housekeeping
+            "step_self_share": share(
+                max(0.0, phase["engine.step"].sum - inside)),
+            "compile_s": self.compile_hist.sum,
             "elapsed_s": elapsed,
             "tokens_per_sec": (self.tokens_generated / elapsed
                                if elapsed else 0.0),
@@ -454,6 +484,7 @@ class LLMEngine:
         # /healthz widens its staleness window during the compile so a
         # supervisor never kills a replica for legitimately compiling
         self._compile_grace_ns = None
+        self._decode_called = None      # the decode program last called
         _telemetry.maybe_start_from_flags()
         _telemetry.register_engine(self)
         _sentinel.maybe_arm_from_flags()
@@ -712,12 +743,27 @@ class LLMEngine:
         if self._stats.wall_t0 is None:
             self._stats.wall_t0 = time.perf_counter()
         self._hb_ns = time.perf_counter_ns()
-        sched = self.scheduler
         self._stepping = True
         try:
-            return self._step_locked()
+            with self._span("engine.step"):
+                return self._step_locked()
         finally:
             self._stepping = False
+            self._stats.wall_t1 = time.perf_counter()
+
+    def _span(self, name):
+        """The span `name` of this engine; its seconds go to the phase
+        histogram of that name where `ServeStats` keeps one."""
+        return RecordEvent(name, hist=self._stats.phase.get(name))
+
+    def _call_program(self, name, fn, args, first):
+        """`fn(*args)` under the dispatch span `name`; a program's first
+        call, which traces and compiles it, under `engine.compile` too."""
+        if first:
+            with self._span("engine.compile"), self._span(name):
+                return fn(*args)
+        with self._span(name):
+            return fn(*args)
 
     def _step_locked(self):
         sched = self.scheduler
@@ -727,68 +773,65 @@ class LLMEngine:
         # -- cancel/deadline sweep + admission (token boundary) --------
         self._boundary_housekeeping()
         hook = self._prefix_hook if self._prefix is not None else None
-        while True:
-            # expire a dead head BEFORE admission assigns it a slot —
-            # it never ran, and the serve.expire where=queued/running
-            # split must stay truthful for queue-sizing diagnosis
-            while sched.waiting and sched.waiting[0].expired():
-                self._expire(sched.waiting[0])
-            req = sched.try_admit(prefix_hook=hook)
-            if req is None:
-                # the pool may be dry only because the prefix index is
-                # hoarding cold entries — release those and retry before
-                # giving up on this boundary (only when a slot is
-                # actually free: batch pressure is not block pressure)
-                if (self._prefix is not None and sched.waiting
-                        and None in sched.slots
-                        and self._reclaim_prefix(
-                            sched.blocks_needed(
-                                sched.waiting[0].context_len)
-                            + sched.watermark_blocks)):
-                    continue
-                break
-            self._admit(req)
-        if not sched.running:
-            if self._pipeline:
-                self._flush_inflight()
-            self._stats.wall_t1 = time.perf_counter()
-            return bool(sched.waiting)
-        # -- KV growth, preempting (newest first) when the pool is dry --
-        for req in sorted(list(sched.running),
-                          key=lambda r: r.admit_seq):
-            if req.state != RUNNING:
-                continue
-            need = sched.blocks_needed(req.cached_len)
-            while len(req.blocks) < need and req.state == RUNNING:
-                if sched.grow(req):
-                    self._sync_slot(req)
-                    continue
-                if self._prefix is not None and self._reclaim_prefix(1):
-                    continue    # cold prefix entries go before tenants
-                victim = sched.preempt_victim(exclude=req)
-                if victim is not None:
-                    self._evict(victim)
-                    continue
-                if not sched.protected(req):
-                    # aging guard: every other tenant is protected —
-                    # the grower steps aside (requeued, not failed)
-                    self._evict(req)
+        with self._span("engine.admit"):
+            while True:
+                # expire a dead head BEFORE admission assigns it a slot —
+                # it never ran, and the serve.expire where=queued/running
+                # split must stay truthful for queue-sizing diagnosis
+                while sched.waiting and sched.waiting[0].expired():
+                    self._expire(sched.waiting[0])
+                req = sched.try_admit(prefix_hook=hook)
+                if req is None:
+                    # the pool may be dry only because the prefix index is
+                    # hoarding cold entries — release those and retry
+                    # before giving up on this boundary (only when a slot
+                    # is actually free: batch pressure is not block
+                    # pressure)
+                    if (self._prefix is not None and sched.waiting
+                            and None in sched.slots
+                            and self._reclaim_prefix(
+                                sched.blocks_needed(
+                                    sched.waiting[0].context_len)
+                                + sched.watermark_blocks)):
+                        continue
                     break
-                self._fail(req, "kv_exhausted")
-                break
+                self._admit(req)
         if not sched.running:
             if self._pipeline:
                 self._flush_inflight()
-            self._stats.wall_t1 = time.perf_counter()
             return bool(sched.waiting)
-        # -- copy-on-write boundary: privatize shared write targets ----
-        if self._prefix is not None:
-            self._cow_sweep()
-            if not sched.running:
-                if self._pipeline:
-                    self._flush_inflight()
-                self._stats.wall_t1 = time.perf_counter()
-                return bool(sched.waiting)
+        with self._span("engine.kv_grow"):
+            # -- KV growth, preempting (newest first) on a dry pool ----
+            for req in sorted(list(sched.running),
+                              key=lambda r: r.admit_seq):
+                if req.state != RUNNING:
+                    continue
+                need = sched.blocks_needed(req.cached_len)
+                while len(req.blocks) < need and req.state == RUNNING:
+                    if sched.grow(req):
+                        self._sync_slot(req)
+                        continue
+                    if self._prefix is not None \
+                            and self._reclaim_prefix(1):
+                        continue    # cold prefix entries go before tenants
+                    victim = sched.preempt_victim(exclude=req)
+                    if victim is not None:
+                        self._evict(victim)
+                        continue
+                    if not sched.protected(req):
+                        # aging guard: every other tenant is protected —
+                        # the grower steps aside (requeued, not failed)
+                        self._evict(req)
+                        break
+                    self._fail(req, "kv_exhausted")
+                    break
+            # -- copy-on-write boundary: privatize shared write targets -
+            if sched.running and self._prefix is not None:
+                self._cow_sweep()
+        if not sched.running:
+            if self._pipeline:
+                self._flush_inflight()
+            return bool(sched.waiting)
         # -- software-pipelined tail: launch N+1, commit N (lag 1) -----
         if self._pipeline:
             return self._step_pipelined()
@@ -796,7 +839,8 @@ class LLMEngine:
         demand = sched.demand
         n_active = len(sched.running)
         t0 = time.perf_counter()
-        out = self._decode_step()
+        with self._span("engine.decode"):
+            out = self._decode_step()
         if out is None:
             # ladder rung 3 / eager fallback retired the batch; the
             # engine stays serviceable for queued + new work. Any stall
@@ -804,7 +848,6 @@ class LLMEngine:
             # from the NEXT (unrelated) productive step's time
             if _metrics_on():
                 _goodput.ACCOUNTANT.drop_stall_carry()
-            self._stats.wall_t1 = time.perf_counter()
             return bool(sched.running or sched.waiting)
         dt = time.perf_counter() - t0
         self._stats.observe_step(n_active, self.max_batch_size, demand, dt)
@@ -834,32 +877,33 @@ class LLMEngine:
                          detail={"recovered": True})
         # -- stream + retire -------------------------------------------
         toks, logps, aids, alps = out
-        for req in list(sched.running):
-            if req.finished or req.slot is None:
-                # retired mid-loop (a streaming callback cancelled it);
-                # its token from this launch is dropped on the floor
-                continue
-            slot = req.slot
-            req.cached_len += 1
-            self._lens[slot] = req.cached_len
-            if req.chew:
-                # prefix-hit warm-up: the next context token is already
-                # KNOWN — feed it as the next decode input and drop the
-                # prediction (made from a mid-context position, it is
-                # not this stream's next output token)
-                t = req.chew.pop(0)
-                self._tokens[slot] = t
+        with self._span("engine.stream"):
+            for req in list(sched.running):
+                if req.finished or req.slot is None:
+                    # retired mid-loop (a streaming callback cancelled
+                    # it); its token from this launch is dropped on the
+                    # floor
+                    continue
+                slot = req.slot
+                req.cached_len += 1
+                self._lens[slot] = req.cached_len
+                if req.chew:
+                    # prefix-hit warm-up: the next context token is
+                    # already KNOWN — feed it as the next decode input and
+                    # drop the prediction (made from a mid-context
+                    # position, it is not this stream's next output token)
+                    t = req.chew.pop(0)
+                    self._tokens[slot] = t
+                    if req.cached_len < self.max_context:
+                        self._history[slot, req.cached_len] = t
+                    continue
+                tok = int(toks[slot])
+                self._tokens[slot] = tok
                 if req.cached_len < self.max_context:
-                    self._history[slot, req.cached_len] = t
-                continue
-            tok = int(toks[slot])
-            self._tokens[slot] = tok
-            if req.cached_len < self.max_context:
-                self._history[slot, req.cached_len] = tok
-            self._emit_token(req, tok, logp=float(logps[slot]),
-                             alts=((aids[slot], alps[slot])
-                                   if self._logprobs_topk else None))
-        self._stats.wall_t1 = time.perf_counter()
+                    self._history[slot, req.cached_len] = tok
+                self._emit_token(req, tok, logp=float(logps[slot]),
+                                 alts=((aids[slot], alps[slot])
+                                       if self._logprobs_topk else None))
         return bool(sched.running or sched.waiting)
 
     # ------------------------------------------------------------------
@@ -877,7 +921,8 @@ class LLMEngine:
         demand = sched.demand
         n_active = len(sched.running)
         t0 = time.perf_counter()
-        launched = self._launch_decode()
+        with self._span("engine.decode"):
+            launched = self._launch_decode()
         ok = self._commit_inflight()
         if not ok:
             # destructive recovery fired mid-window: the launch just
@@ -887,11 +932,9 @@ class LLMEngine:
             self._reset_pipeline()
             if _metrics_on():
                 _goodput.ACCOUNTANT.drop_stall_carry()
-            self._stats.wall_t1 = time.perf_counter()
             return bool(sched.running or sched.waiting)
         self._inflight = launched
         if launched is None:
-            self._stats.wall_t1 = time.perf_counter()
             return bool(sched.running or sched.waiting)
         dt = time.perf_counter() - t0
         self._stats.observe_step(n_active, self.max_batch_size, demand,
@@ -914,7 +957,6 @@ class LLMEngine:
             self.degraded = False
             _EVENTS.emit("serve.degrade", "engine",
                          detail={"recovered": True})
-        self._stats.wall_t1 = time.perf_counter()
         return bool(sched.running or sched.waiting
                     or self._inflight is not None)
 
@@ -966,7 +1008,7 @@ class LLMEngine:
         if self._tenant:
             base = base + (self._decode_aux(),)
         base = base + self._sampler_args()
-        res = self._decode_fn(*self._kv_args(
+        res = self._call_decode(self._kv_args(
             *(base + (self._k_pools, self._v_pools))))
         # adopt the launch's pool lineage NOW: any prefill issued before
         # the commit must consume THESE outputs, so XLA's dataflow
@@ -999,15 +1041,40 @@ class LLMEngine:
         deterministically at lag 1, costing each departed stream exactly
         its one speculative token. Returns False when destructive
         recovery (hang rung 3 / decode fault) retired the batch."""
-        from ..ops import guardian
         inf, self._inflight = self._inflight, None
         if inf is None:
             return True
+        with self._span("engine.decode"):
+            out = self._await_launch(inf)
+        if out is None:
+            return False
+        toks, logps, aids, alps = out
+        with self._span("engine.stream"):
+            for req, slot, pos, aseq in inf["records"]:
+                if (req.state != RUNNING or req.slot != slot
+                        or req.admit_seq != aseq):
+                    self._rollback(req, slot)
+                    continue
+                tok = int(toks[slot])
+                self._tokens[slot] = tok
+                if pos < self.max_context:
+                    self._history[slot, pos] = tok
+                self._emit_token(req, tok, logp=float(logps[slot]),
+                                 alts=((aids[slot], alps[slot])
+                                       if self._logprobs_topk else None))
+        self._maybe_store_decode()
+        return True
+
+    def _await_launch(self, inf):
+        """The monitored wait for a launch and its four results on the
+        host, or None when destructive recovery retired the batch."""
+        from ..ops import guardian
         res = inf["res"]
         attempt = 1
         while True:
             try:
-                self._monitor.wait(res, "decode", attempt)
+                with self._span("engine.decode.wait"):
+                    self._monitor.wait(res, "decode", attempt)
                 break
             except StepHang:
                 self._stats.hangs += 1
@@ -1035,7 +1102,7 @@ class LLMEngine:
                         self._reset_kv_state()
                     self._compile_grace_ns = time.perf_counter_ns()
                     self._decode_fn = self._build_decode(use_aot=False)
-                    return False
+                    return None
                 self._degrade("step_hang", {"rung": "retry",
                                             "phase": "commit"})
                 attempt += 1
@@ -1045,32 +1112,17 @@ class LLMEngine:
                 self._discard_records(inf)
                 self._reset_pipeline()
                 self._recover_with_fallback(rebuild=True)
-                return False
+                return None
         if guardian.poll_fault("serve.decode",
                                ("nan_output", "raise")) is not None:
             self._degrade("decode_fault", {"injected": True})
             self._discard_records(inf)
             self._reset_pipeline()
             self._recover_with_fallback(rebuild=False)
-            return False
-        toks = np.asarray(res[0])
-        logps = np.asarray(res[1])
-        aids = np.asarray(res[2])
-        alps = np.asarray(res[3])
-        for req, slot, pos, aseq in inf["records"]:
-            if (req.state != RUNNING or req.slot != slot
-                    or req.admit_seq != aseq):
-                self._rollback(req, slot)
-                continue
-            tok = int(toks[slot])
-            self._tokens[slot] = tok
-            if pos < self.max_context:
-                self._history[slot, pos] = tok
-            self._emit_token(req, tok, logp=float(logps[slot]),
-                             alts=((aids[slot], alps[slot])
-                                   if self._logprobs_topk else None))
-        self._maybe_store_decode()
-        return True
+            return None
+        with self._span("engine.decode.fetch"):
+            return (np.asarray(res[0]), np.asarray(res[1]),
+                    np.asarray(res[2]), np.asarray(res[3]))
 
     def _has_pending(self, req, slot):
         inf = self._inflight
@@ -1186,58 +1238,60 @@ class LLMEngine:
             self._note_prefix_rate()
             _EVENTS.emit("serve.prefix_miss", req.rid,
                          detail={"context_len": len(ctx)})
-        bucket = self._bucket_for(len(ctx))
-        fn = self._prefill_fns.get(bucket)
-        new_bucket = fn is None
-        if new_bucket:
-            # the XLA trace runs on this bucket's FIRST call below —
-            # grace the liveness window for it
-            self._compile_grace_ns = time.perf_counter_ns()
-            fn = self._build_prefill(bucket)
-            self._prefill_fns[bucket] = fn
-        self._stats.admitted += 1
-        self._stats.prefills += 1
-        _EVENTS.emit("serve.admit", req.rid,
-                     reason="bucket_retrace" if new_bucket else None,
-                     detail={"context_len": len(ctx), "bucket": bucket,
-                             "blocks": len(req.blocks),
-                             "resumed": bool(req.generated)})
-        now = time.perf_counter_ns()
-        if req.admit_ns is None:
-            req.admit_ns = now
-            wait_s = (now - req.enqueue_ns) / 1e9
-            self._stats.queue_wait_hist.observe(wait_s)
-            if _metrics_on():
-                _M.queue_wait_s.observe(wait_s)
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, :len(ctx)] = ctx
-        row = np.zeros(self.max_blocks_per_seq, np.int32)
-        row[:len(req.blocks)] = req.blocks
-        res = self._prefill_step(fn, padded, np.int32(len(ctx)), row, req)
-        if res is None:
-            return            # watchdog failed the request, slot is clear
-        nxt, logp, aids, alps = res[0], res[1], res[2], res[3]
-        self._k_pools, self._v_pools = res[4], res[5]
-        if self._kv_quantized:
-            self._k_scales, self._v_scales = res[6], res[7]
-        req.cached_len = len(ctx)
-        self._sync_slot(req)
-        self._set_adapter_slot(req)
-        if self._prefix is not None:
-            # index this prompt's blocks for the NEXT tenant sharing it;
-            # a resume's partial tail holds generated-token KV, which
-            # must never be served as prompt KV
-            self._prefix.publish(ctx, req.blocks,
-                                 include_tail=not req.generated)
-        tok = int(np.asarray(nxt))
-        # the prefill's sampled token is the next decode step's input
-        self._tokens[req.slot] = tok
-        if req.cached_len < self.max_context:
-            self._history[req.slot, req.cached_len] = tok
-        self._override[req.slot] = True
-        self._emit_token(req, tok, logp=float(np.asarray(logp)),
-                         alts=((aids, alps) if self._logprobs_topk
-                               else None))
+        with self._span("engine.prefill"):
+            bucket = self._bucket_for(len(ctx))
+            fn = self._prefill_fns.get(bucket)
+            new_bucket = fn is None
+            if new_bucket:
+                # the XLA trace runs on this bucket's FIRST call below —
+                # grace the liveness window for it
+                self._compile_grace_ns = time.perf_counter_ns()
+                fn = self._build_prefill(bucket)
+                self._prefill_fns[bucket] = fn
+            self._stats.admitted += 1
+            self._stats.prefills += 1
+            _EVENTS.emit("serve.admit", req.rid,
+                         reason="bucket_retrace" if new_bucket else None,
+                         detail={"context_len": len(ctx), "bucket": bucket,
+                                 "blocks": len(req.blocks),
+                                 "resumed": bool(req.generated)})
+            now = time.perf_counter_ns()
+            if req.admit_ns is None:
+                req.admit_ns = now
+                wait_s = (now - req.enqueue_ns) / 1e9
+                self._stats.queue_wait_hist.observe(wait_s)
+                if _metrics_on():
+                    _M.queue_wait_s.observe(wait_s)
+            padded = np.zeros((1, bucket), np.int32)
+            padded[0, :len(ctx)] = ctx
+            row = np.zeros(self.max_blocks_per_seq, np.int32)
+            row[:len(req.blocks)] = req.blocks
+            res = self._prefill_step(fn, padded, np.int32(len(ctx)), row,
+                                     req, first=new_bucket)
+            if res is None:
+                return            # watchdog failed the request, slot is clear
+            nxt, logp, aids, alps = res[0], res[1], res[2], res[3]
+            self._k_pools, self._v_pools = res[4], res[5]
+            if self._kv_quantized:
+                self._k_scales, self._v_scales = res[6], res[7]
+            req.cached_len = len(ctx)
+            self._sync_slot(req)
+            self._set_adapter_slot(req)
+            if self._prefix is not None:
+                # index this prompt's blocks for the NEXT tenant sharing it;
+                # a resume's partial tail holds generated-token KV, which
+                # must never be served as prompt KV
+                self._prefix.publish(ctx, req.blocks,
+                                     include_tail=not req.generated)
+            tok = int(np.asarray(nxt))
+            # the prefill's sampled token is the next decode step's input
+            self._tokens[req.slot] = tok
+            if req.cached_len < self.max_context:
+                self._history[req.slot, req.cached_len] = tok
+            self._override[req.slot] = True
+            self._emit_token(req, tok, logp=float(np.asarray(logp)),
+                             alts=((aids, alps) if self._logprobs_topk
+                                   else None))
 
     def _admit_prefix_hit(self, req, ctx):
         """Prefix-hit admission: the aliased blocks already hold the
@@ -1297,10 +1351,11 @@ class LLMEngine:
                 _M.adapter_switches.inc()
         self._aslots[req.slot] = idx
 
-    def _prefill_step(self, fn, padded, length, row, req):
-        """One monitored prefill fire. The ladder is per-request (a hung
-        prefill only has one tenant): retry once, then fail the request
-        with `step_hang` — the decode batch never waits on it."""
+    def _prefill_step(self, fn, padded, length, row, req, first=False):
+        """One monitored prefill fire; `first` marks the program's first
+        call. The ladder is per-request (a hung prefill only has one
+        tenant): retry once, then fail the request with `step_hang` — the
+        decode batch never waits on it."""
         attempt = 1
         while True:
             try:
@@ -1314,9 +1369,17 @@ class LLMEngine:
                                np.float32(req.top_p),
                                np.float32(req.repetition_penalty),
                                np.uint32(req.seed or 0))
-                res = fn(*self._kv_args(*(base + (self._k_pools,
-                                                  self._v_pools))))
-                self._monitor.wait(res, "prefill", attempt)
+                res = self._call_program(
+                    "engine.prefill.dispatch", fn,
+                    self._kv_args(*(base + (self._k_pools,
+                                            self._v_pools))),
+                    first and attempt == 1)
+                with self._span("engine.prefill.wait"):
+                    self._monitor.wait(res, "prefill", attempt)
+                    # the sampled token on the host (kept on the array for
+                    # `_admit`): with the watchdog disarmed THIS is where
+                    # the host waits for the program
+                    np.asarray(res[0])
                 return res
             except StepHang:
                 self._stats.hangs += 1
@@ -1507,9 +1570,10 @@ class LLMEngine:
                 if self._tenant:
                     base = base + (self._decode_aux(),)
                 base = base + self._sampler_args()
-                res = self._decode_fn(*self._kv_args(
+                res = self._call_decode(self._kv_args(
                     *(base + (self._k_pools, self._v_pools))))
-                self._monitor.wait(res, "decode", attempt)
+                with self._span("engine.decode.wait"):
+                    self._monitor.wait(res, "decode", attempt)
             except StepHang:
                 if not self._on_hang(attempt):
                     return None
@@ -1537,8 +1601,16 @@ class LLMEngine:
             if self._kv_quantized:
                 self._k_scales, self._v_scales = res[6], res[7]
             self._maybe_store_decode()
-            return (np.asarray(nxt), np.asarray(res[1]),
-                    np.asarray(res[2]), np.asarray(res[3]))
+            with self._span("engine.decode.fetch"):
+                return (np.asarray(nxt), np.asarray(res[1]),
+                        np.asarray(res[2]), np.asarray(res[3]))
+
+    def _call_decode(self, args):
+        fn = self._decode_fn
+        res = self._call_program("engine.decode.dispatch", fn, args,
+                                 fn is not self._decode_called)
+        self._decode_called = fn
+        return res
 
     def _sampler_args(self):
         """The decode signature's per-slot sampler VALUE inputs, in

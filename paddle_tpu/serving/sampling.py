@@ -126,6 +126,7 @@ def apply_top_p(logits, top_p):
     return jnp.where(keep, logits, _NEG_INF)
 
 
+@jax.named_scope("sample_tokens")
 def sample_tokens(logits, temperature, top_k, top_p, repetition_penalty,
                   seeds, positions, history, valid, logprobs_topk=0):
     """The sampler head. All inputs are per-slot value arrays over a fixed
