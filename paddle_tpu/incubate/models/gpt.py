@@ -201,17 +201,20 @@ class GPTAttention(Layer):
                                            block_size=block_size)
 
         quantized = cache.k_scales is not None
+        # the view holds the STACKED pools and this layer's index: the op
+        # writes and reads them at `layer` and returns them whole
+        layer = cache.layer
 
         def fn(qv, kv, vv, kp, vp, tab, lens, act, ksc=None, vsc=None):
             return paged_decode_attention(
-                qv, kv, vv, kp, vp, tab, lens, act, block_size,
+                qv, kv, vv, kp, vp, layer, tab, lens, act, block_size,
                 k_scales=ksc, v_scales=vsc, kernel=variant)
 
         # int8 KV: the scale side-tables are dispatch INPUTS (never
         # closure captures) and flow back out with the pools — the
         # differing arity also keys the two modes apart in the cache
         inputs = (ensure_tensor(q), ensure_tensor(k), ensure_tensor(v),
-                  ensure_tensor(cache.k_pool), ensure_tensor(cache.v_pool),
+                  ensure_tensor(cache.k_pools), ensure_tensor(cache.v_pools),
                   ensure_tensor(cache.block_tables),
                   ensure_tensor(cache.seq_lens), ensure_tensor(cache.active))
         if quantized:
@@ -310,6 +313,14 @@ class GPTModel(Layer):
             for block in self.h:
                 x = block(x)
             return self.ln_f(x)
+        if paged:
+            # ONE view over the stacked pools is threaded through the
+            # layers: each writes in place at its own index and hands the
+            # pools on, so the returned view holds the step's pools
+            cache = caches[0]
+            for block in self.h:
+                x, cache = block(x, cache)
+            return self.ln_f(x), [cache]
         new_caches = []
         for block, cache in zip(self.h, caches):
             x, c = block(x, cache)

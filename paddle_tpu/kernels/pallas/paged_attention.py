@@ -8,13 +8,19 @@ PagedAttention's memory model: stream the pool's KV blocks through the
 block table with ONLINE (streaming) softmax, fp32 accumulators, one block
 resident at a time — the dense context never exists.
 
-Two implementations with identical semantics:
+Both read ONE pool layout, ``[L, num_blocks, bs, H*D]`` (serving/cache.py),
+at a layer index, and only ever split GATHERED rows into heads: the pool
+is never reshaped or sliced per layer, so a program that donates it
+updates and reads it where it lies. Two implementations with identical
+semantics:
 
   * `pallas_paged_attention` — the TPU kernel. Grid ``(S, M)``; the
     block table and (effective) lengths ride as scalar-prefetch
     arguments, so each grid cell's BlockSpec index map picks its pool
-    block ``tables[s, j]`` directly — the DMA engine walks the page
-    table, the kernel body only ever sees one ``[bs, H, D]`` block in VMEM.
+    block ``(layer, tables[s, j])`` of the stacked pool directly — the
+    DMA engine walks the page table, the kernel body only ever sees one
+    ``[bs, H*D]`` block in VMEM (a token a sublane, its heads side by
+    side on the lanes) and reduces per head inside it.
     int8 pools dequantize inside the load (`q * scale / 127`), so the
     fp values exist only in VMEM. Length masking keeps the null-block
     branch-free contract: padded/inactive table entries read block 0 and
@@ -39,6 +45,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 try:
     from jax.experimental import pallas as pl
@@ -65,12 +72,12 @@ _CHUNK_TARGET_BYTES = 256 * 1024
 _CHUNK_TOKENS_MAX = 512
 
 
-# Largest [block_size, H, D] pool block (in elements, H and D padded to
-# the (8, 128) tile) the v5e compiler accepted for every pool dtype: the
-# kernel keeps K and V double-buffered plus their fp32 copies in VMEM,
-# and a 2x larger fp32 block ran out of it. tests/test_tpu_compile.py
-# compiles both sides of this bound.
-_MAX_BLOCK_ELEMS = 512 * 1024
+# Largest [block_size, H*D] pool block (in elements, the row padded to whole
+# 128-lane tiles) the v5e compiler accepted for every pool dtype: the kernel
+# keeps K and V double-buffered plus their fp32 copies and products in
+# VMEM, and a 2x larger int8 block ran out of it.
+# tests/test_tpu_compile.py compiles both sides of this bound.
+_MAX_BLOCK_ELEMS = 256 * 1024
 
 
 def is_eligible(num_heads, head_dim, block_size):
@@ -83,7 +90,7 @@ def is_eligible(num_heads, head_dim, block_size):
         return False, "not_on_tpu"
     if None in (num_heads, head_dim, block_size):
         return False, "shape_unknown"
-    padded = block_size * -(-num_heads // 8) * 8 * -(-head_dim // 128) * 128
+    padded = block_size * -(-num_heads * head_dim // 128) * 128
     if padded > _MAX_BLOCK_ELEMS:
         return False, "block_exceeds_vmem"
     return True, None
@@ -93,16 +100,20 @@ def is_eligible(num_heads, head_dim, block_size):
 # pure-JAX blockwise reference path (lax.scan over block chunks)
 # ---------------------------------------------------------------------------
 
-def blockwise_paged_attention(q, k_pool, v_pool, block_tables, lens,
-                              block_size, k_scales=None, v_scales=None,
+def blockwise_paged_attention(q, k_pools, v_pools, layer, block_tables,
+                              lens, block_size, k_scales=None, v_scales=None,
                               chunk_blocks=None):
     """Online-softmax paged attention, one KV chunk at a time.
 
-    q: ``[S, H, D]`` this step's queries; k_pool/v_pool:
-    ``[num_blocks, bs, H, D]`` (fp, or int8 with `k_scales`/`v_scales`
-    ``[num_blocks, H]``); block_tables: ``[S, M]`` int32; lens: ``[S]``
-    int32 EFFECTIVE lengths (position p attends iff p <= lens[s];
-    inactive slots pass 0). Returns ``[S, H, D]`` in q's dtype.
+    q: ``[S, H, D]`` this step's queries; k_pools/v_pools:
+    ``[L, num_blocks, bs, H*D]`` (fp, or int8 with `k_scales`/`v_scales`
+    ``[L, num_blocks, H]``) and `layer` the one to read; block_tables:
+    ``[S, M]`` int32; lens: ``[S]`` int32 EFFECTIVE lengths (position p
+    attends iff p <= lens[s]; inactive slots pass 0). Returns
+    ``[S, H, D]`` in q's dtype. Each scan step gathers
+    ``pool[layer, block ids]`` and splits the GATHERED rows into heads:
+    neither a layer of the pool nor the pool in another shape is ever a
+    value, so the program reads a donated pool where it lies.
     """
     s, h, d = q.shape
     m = block_tables.shape[1]
@@ -131,11 +142,12 @@ def blockwise_paged_attention(q, k_pool, v_pool, block_tables, lens,
     def step(carry, xs):
         acc, mx, l = carry
         ci, bids = xs                                   # [], [S, C]
-        kc = k_pool[bids]                               # [S, C, bs, H, D]
-        vc = v_pool[bids]
+        kc = k_pools[layer, bids]                       # [S, C, bs, H*D]
+        vc = v_pools[layer, bids]
         if quant:
-            kc = _dequant(kc, k_scales[bids])
-            vc = _dequant(vc, v_scales[bids])
+            split = (s, chunk_blocks, bs, h, d)
+            kc = _dequant(kc.reshape(split), k_scales[layer, bids])
+            vc = _dequant(vc.reshape(split), v_scales[layer, bids])
         else:
             kc = kc.astype(jnp.float32)
             vc = vc.astype(jnp.float32)
@@ -171,7 +183,7 @@ def blockwise_paged_attention(q, k_pool, v_pool, block_tables, lens,
 # Pallas TPU kernel: one grid cell per (slot, table entry), all heads at once
 # ---------------------------------------------------------------------------
 
-def _decode_kernel(tab_ref, lens_ref, q_ref, k_ref, v_ref, *rest,
+def _decode_kernel(tab_ref, lens_ref, q_ref, seg_ref, k_ref, v_ref, *rest,
                    block_size, quantized):
     if quantized:
         ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
@@ -186,26 +198,41 @@ def _decode_kernel(tab_ref, lens_ref, q_ref, k_ref, v_ref, *rest,
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    # heads sit on sublanes and head_dim on lanes throughout ([bs, H, D]
-    # tiles, [H, 1] statistics), so nothing below relayouts
-    qv = q_ref[...].astype(jnp.float32)                # [H, D] (pre-scaled)
-    k = k_ref[...].astype(jnp.float32)                 # [bs, H, D]
+    # A pool block is [bs, H*D]: a token a sublane, its heads side by side
+    # on the lanes. `seg` [H*D, H] is 1 where a lane belongs to a head, so
+    # two small matrix products (exact: float32 contraction, and a lane
+    # belongs to one head) take a row to per-head values and back; the
+    # softmax state lives on the lanes, every head's value repeated over
+    # its D lanes, so nothing below reshapes or relayouts.
+    seg = seg_ref[...]
+    exact = dict(precision=jax.lax.Precision.HIGHEST,
+                 preferred_element_type=jnp.float32)
+
+    def per_head(x):                                   # [bs, H*D] -> [bs, H]
+        return jnp.dot(x, seg, **exact)
+
+    def per_lane(x):                                   # [bs, H] -> [bs, H*D]
+        return jax.lax.dot_general(x, seg, (((1,), (1,)), ((), ())), **exact)
+
+    k = k_ref[...].astype(jnp.float32)                 # [bs, H*D]
     v = v_ref[...].astype(jnp.float32)
     if quantized:
         # dequant fused into the block load: fp K/V exist only in VMEM
-        k = k * (ks_ref[...] * (1.0 / _QMAX))          # scales: [H, 1]
-        v = v * (vs_ref[...] * (1.0 / _QMAX))
-    scores = jnp.sum(k * qv, axis=-1, keepdims=True)   # [bs, H, 1]
+        heads = (k.shape[0], seg.shape[1])
+        k = k * per_lane(jnp.broadcast_to(ks_ref[...] * (1.0 / _QMAX), heads))
+        v = v * per_lane(jnp.broadcast_to(vs_ref[...] * (1.0 / _QMAX), heads))
+    scores = per_lane(per_head(k * q_ref[...]))        # q is pre-scaled
     pos = j * jnp.int32(block_size) + jax.lax.broadcasted_iota(
         jnp.int32, scores.shape, 0)
     valid = pos <= lens_ref[s]
     scores = jnp.where(valid, scores, jnp.float32(_NEG_INF))
-    m_prev = m_ref[...]                                # [H, 1]
-    m_new = jnp.maximum(m_prev, jnp.max(scores, axis=0))
-    p = jnp.where(valid, jnp.exp(scores - m_new), 0.0)  # [bs, H, 1]
+    m_prev = m_ref[...]                                # [1, H*D]
+    m_new = jnp.maximum(m_prev, jnp.max(scores, axis=0, keepdims=True))
+    p = jnp.where(valid, jnp.exp(scores - m_new), 0.0)
     alpha = jnp.exp(m_prev - m_new)
-    l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=0)
-    acc_ref[...] = acc_ref[...] * alpha + jnp.sum(p * v, axis=0)  # [H, D]
+    l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=0, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha \
+        + jnp.sum(p * v, axis=0, keepdims=True)
     m_ref[...] = m_new
 
     @pl.when(j == pl.num_programs(1) - 1)
@@ -214,51 +241,59 @@ def _decode_kernel(tab_ref, lens_ref, q_ref, k_ref, v_ref, *rest,
                       / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
 
 
-def pallas_paged_attention(q, k_pool, v_pool, block_tables, lens,
+def pallas_paged_attention(q, k_pools, v_pools, layer, block_tables, lens,
                            block_size, k_scales=None, v_scales=None,
                            interpret=False):
     """The Pallas kernel: same contract as `blockwise_paged_attention`.
     `interpret=True` runs the kernel through the Pallas interpreter on
     any backend (the CPU parity path)."""
     s, h, d = q.shape
+    hd = h * d
     bs = int(block_size)
     m = block_tables.shape[1]
     quant = k_scales is not None
     zero = _ZERO
-    qf = q.astype(jnp.float32) * (1.0 / math.sqrt(d))
+    layer = np.int32(layer)      # index maps emit i32 (kernels/_common.py)
+    qf = (q.astype(jnp.float32) * (1.0 / math.sqrt(d))).reshape(s, 1, hd)
+    seg = (jnp.arange(hd, dtype=jnp.int32)[:, None] // d
+           == jnp.arange(h, dtype=jnp.int32)[None, :]).astype(jnp.float32)
     tables = block_tables.astype(jnp.int32)
     lens32 = lens.astype(jnp.int32)
 
-    # Every block spans ALL heads of its slot, so its last two dimensions
-    # equal the array's — the only shape the TPU lowering accepts when H
-    # or D is not a multiple of the (8, 128) tile. Index maps receive
-    # (grid ids..., scalar-prefetch refs): the block table IS the page
-    # table the DMA walks.
-    pool_spec = pl.BlockSpec((None, bs, h, d),
-                             lambda si, j, t, l: (t[si, j], zero, zero, zero))
-    slot_spec = pl.BlockSpec((None, h, d), lambda si, j, t, l: (si, zero, zero))
-    in_specs = [slot_spec, pool_spec, pool_spec]
-    args = [tables, lens32, qf, k_pool, v_pool]
+    # The pool is read where it lies: a block is one [bs, H*D] row group
+    # of the STACKED pool, picked by (layer, table entry) in the index map
+    # — the block table IS the page table the DMA walks. Index maps
+    # receive (grid ids..., scalar-prefetch refs). Every block's last two
+    # dimensions equal its array's, the shape the TPU lowering accepts
+    # whatever H and D are.
+    pool_spec = pl.BlockSpec(
+        (None, None, bs, hd),
+        lambda si, j, t, l: (layer, t[si, j], zero, zero))
+    slot_spec = pl.BlockSpec((None, 1, hd),
+                             lambda si, j, t, l: (si, zero, zero))
+    seg_spec = pl.BlockSpec((hd, h), lambda si, j, t, l: (zero, zero))
+    in_specs = [slot_spec, seg_spec, pool_spec, pool_spec]
+    args = [tables, lens32, qf, seg, k_pools, v_pools]
     if quant:
-        spec = pl.BlockSpec((None, h, 1),
-                            lambda si, j, t, l: (t[si, j], zero, zero))
+        spec = pl.BlockSpec(
+            (None, None, 1, h),
+            lambda si, j, t, l: (layer, t[si, j], zero, zero))
         in_specs += [spec, spec]
-        args += [k_scales[..., None], v_scales[..., None]]
+        args += [k_scales[:, :, None], v_scales[:, :, None]]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(s, m),
         in_specs=in_specs,
         out_specs=slot_spec,
-        scratch_shapes=[pltpu.VMEM((h, d), jnp.float32),
-                        pltpu.VMEM((h, 1), jnp.float32),
-                        pltpu.VMEM((h, 1), jnp.float32)])
+        scratch_shapes=[pltpu.VMEM((1, hd), jnp.float32)] * 3)
     kernel = functools.partial(_decode_kernel, block_size=bs,
                                quantized=quant)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((s, 1, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="paged_decode_attention")(*args)
+    return out.reshape(s, h, d)

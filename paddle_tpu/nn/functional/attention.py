@@ -237,21 +237,22 @@ def resolve_paged_kernel(kernel=None, num_heads=None, head_dim=None,
     return actual
 
 
-def _dense_gather_attention(qh, k_pool, v_pool, block_tables, lens,
+def _dense_gather_attention(qh, k_pools, v_pools, layer, block_tables, lens,
                             block_size, k_scales=None, v_scales=None):
-    """The reference oracle: gather-by-block-table into a dense
-    ``[S, T, H, D]`` context, full softmax. Scores and the softmax/PV
-    accumulation run in fp32 (matching `_plain_attention`) so bf16
-    serving keeps its tail tokens; only the output casts back."""
+    """The reference oracle: gather `layer`'s blocks by block table into a
+    dense ``[S, T, H, D]`` context, full softmax. Scores and the
+    softmax/PV accumulation run in fp32 (matching `_plain_attention`) so
+    bf16 serving keeps its tail tokens; only the output casts back."""
     s, h, d = qh.shape
     m = block_tables.shape[1]
     t_max = m * block_size
-    kg = k_pool[block_tables]                          # [S, M, bs, H, D]
-    vg = v_pool[block_tables]
+    # the GATHERED rows are split into heads, never the pool
+    kg = k_pools[layer, block_tables].reshape(s, m, block_size, h, d)
+    vg = v_pools[layer, block_tables].reshape(s, m, block_size, h, d)
     if k_scales is not None:
         from ...quantization.kv_cache import dequantize
-        kg = dequantize(kg, k_scales[block_tables])
-        vg = dequantize(vg, v_scales[block_tables])
+        kg = dequantize(kg, k_scales[layer, block_tables])
+        vg = dequantize(vg, v_scales[layer, block_tables])
     else:
         kg = kg.astype(jnp.float32)
         vg = vg.astype(jnp.float32)
@@ -266,18 +267,23 @@ def _dense_gather_attention(qh, k_pool, v_pool, block_tables, lens,
     return jnp.einsum("sht,sthd->shd", probs, vals).astype(qh.dtype)
 
 
-def paged_decode_attention(q, k_new, v_new, k_pool, v_pool, block_tables,
-                           seq_lens, active, block_size,
+def paged_decode_attention(q, k_new, v_new, k_pools, v_pools, layer,
+                           block_tables, seq_lens, active, block_size,
                            k_scales=None, v_scales=None, kernel=None,
                            interpret=False):
-    """One decode step of attention against a paged block-pool KV cache
-    (the PagedAttention memory model; serving/cache.py).
+    """One layer's decode step of attention against a paged block-pool KV
+    cache (the PagedAttention memory model; serving/cache.py).
 
     q/k_new/v_new: ``[S, 1, H, D]`` — this step's projections for every
     batch slot (S is the engine's fixed max-batch slot count).
-    k_pool/v_pool: ``[num_blocks, block_size, H, D]`` — one layer's pool
-    (fp, or int8 with per-block-per-head `k_scales`/`v_scales`
-    ``[num_blocks, H]``; quantization/kv_cache.py).
+    k_pools/v_pools: ``[L, num_blocks, block_size, H*D]`` — the pools of
+    ALL layers (fp, or int8 with per-block-per-head `k_scales`/`v_scales`
+    ``[L, num_blocks, H]``; quantization/kv_cache.py); `layer` (a Python
+    int) is the one this call writes and reads. The token is written at
+    ``(layer, block, offset)`` of the stacked pool and attention gathers
+    ``pool[layer, block ids]``, so the layer's pool is never a value of
+    its own: a program that threads donated pools through its layers
+    updates them where they lie.
     block_tables: ``[S, max_blocks]`` int32 — per-slot ordered block ids;
     gathered position ``t`` of slot ``s`` is token position ``t`` of that
     sequence (tables are dense prefixes, padded with the null block).
@@ -294,8 +300,9 @@ def paged_decode_attention(q, k_new, v_new, k_pool, v_pool, block_tables,
     compiled program serves every token of every tenant mix —
     join/leave/evict is a table edit, never a retrace.
 
-    Returns ``(out [S, 1, H, D], new_k_pool, new_v_pool)`` — plus
-    ``(new_k_scales, new_v_scales)`` in int8 mode.
+    Returns ``(out [S, 1, H, D], new_k_pools, new_v_pools)`` — plus
+    ``(new_k_scales, new_v_scales)`` in int8 mode. Every other layer of
+    the returned pools is what came in.
     """
     s, _, num_heads, head_dim = q.shape
     quantized = k_scales is not None
@@ -312,15 +319,17 @@ def paged_decode_attention(q, k_new, v_new, k_pool, v_pool, block_tables,
     with jax.named_scope("paged_kv_write"):
         if quantized:
             from ...quantization.kv_cache import quantize_block_write
-            k_pool, k_scales = quantize_block_write(
-                k_pool, k_scales, k_new[:, 0], write_block, write_off)
-            v_pool, v_scales = quantize_block_write(
-                v_pool, v_scales, v_new[:, 0], write_block, write_off)
+            k_pools, k_scales = quantize_block_write(
+                k_pools, k_scales, layer, k_new[:, 0], write_block,
+                write_off)
+            v_pools, v_scales = quantize_block_write(
+                v_pools, v_scales, layer, v_new[:, 0], write_block,
+                write_off)
         else:
-            k_pool = k_pool.at[write_block, write_off].set(
-                k_new[:, 0].astype(k_pool.dtype))
-            v_pool = v_pool.at[write_block, write_off].set(
-                v_new[:, 0].astype(v_pool.dtype))
+            k_pools = k_pools.at[layer, write_block, write_off].set(
+                k_new[:, 0].reshape(s, -1).astype(k_pools.dtype))
+            v_pools = v_pools.at[layer, write_block, write_off].set(
+                v_new[:, 0].reshape(s, -1).astype(v_pools.dtype))
 
     variant = resolve_paged_kernel(kernel, num_heads, head_dim, block_size,
                                    interpret=interpret)
@@ -328,23 +337,23 @@ def paged_decode_attention(q, k_new, v_new, k_pool, v_pool, block_tables,
     with jax.named_scope("paged_attention"):
         if variant == "reference":
             out = _dense_gather_attention(
-                qh, k_pool, v_pool, block_tables, lens, block_size,
+                qh, k_pools, v_pools, layer, block_tables, lens, block_size,
                 k_scales, v_scales)
         elif variant == "blockwise":
             from ...kernels.pallas.paged_attention import (
                 blockwise_paged_attention)
             out = blockwise_paged_attention(
-                qh, k_pool, v_pool, block_tables, lens, block_size,
+                qh, k_pools, v_pools, layer, block_tables, lens, block_size,
                 k_scales, v_scales)
         else:
             from ...kernels.pallas.paged_attention import (
                 pallas_paged_attention)
             out = pallas_paged_attention(
-                qh, k_pool, v_pool, block_tables, lens, block_size,
+                qh, k_pools, v_pools, layer, block_tables, lens, block_size,
                 k_scales, v_scales, interpret=interpret)
     if quantized:
-        return out[:, None], k_pool, v_pool, k_scales, v_scales
-    return out[:, None], k_pool, v_pool
+        return out[:, None], k_pools, v_pools, k_scales, v_scales
+    return out[:, None], k_pools, v_pools
 
 
 __all__ += ["paged_decode_attention", "resolve_paged_kernel",

@@ -8,8 +8,11 @@ pairs with raw-speed kernels: int8 KV halves the bytes every cached token
 costs, so the same pool admits ~2x the streams before the scheduler's
 watermark starts refusing (`kv_exhausted`).
 
-Granularity: ONE fp32 scale per (pool block, head) — `[num_blocks, H]`
-beside each `[num_blocks, block_size, H, D]` int8 pool. Per-block-per-head
+Granularity: ONE fp32 scale per (layer, pool block, head) — `[L, num_blocks,
+H]` beside each `[L, num_blocks, block_size, H*D]` int8 pool (serving/cache.py:
+a layer's heads side by side in one row). Both write paths below address the
+STACKED pool and its side-table at `(layer, block, offset)`, so a donated pool
+is updated where it lies and no layer of it is ever replaced. Per-block-per-head
 is the natural write granularity of the paged cache (prefill lands whole
 blocks; decode appends into exactly one block per slot per step) and
 keeps the scale side-table negligible (H floats per block vs bs*H*D
@@ -59,21 +62,23 @@ def dequantize(values, scales):
         * (scales * (1.0 / QMAX))[..., None, :, None]
 
 
-def quantize_block_write(pool, scales, new_vec, write_block, write_off):
-    """Append one token per slot into its int8 block, re-quantizing the
-    block under the updated per-block-per-head scale.
+def quantize_block_write(pools, scales, layer, new_vec, write_block,
+                         write_off):
+    """Append one token per slot into its int8 block of `layer`,
+    re-quantizing the block under the updated per-block-per-head scale.
 
-    pool: ``[num_blocks, bs, H, D]`` int8; scales: ``[num_blocks, H]``
-    fp32; new_vec: ``[S, H, D]`` fp; write_block/write_off: ``[S]`` int32
-    (inactive slots all target the null block — duplicate writes there
-    are fine, its content is never unmasked).
+    pools: ``[L, num_blocks, bs, H*D]`` int8; scales: ``[L, num_blocks,
+    H]`` fp32; new_vec: ``[S, H, D]`` fp; write_block/write_off: ``[S]``
+    int32 (inactive slots all target the null block — duplicate writes
+    there are fine, its content is never unmasked).
 
-    Returns (pool, scales). Traceable and shape-static.
+    Returns (pools, scales). Traceable and shape-static.
     """
-    s = new_vec.shape[0]
-    bs = pool.shape[1]
+    s, h, d = new_vec.shape
+    bs = pools.shape[2]
     rows = jnp.arange(s, dtype=jnp.int32)
-    blk = dequantize(pool[write_block], scales[write_block])  # [S, bs, H, D]
+    blk = dequantize(pools[layer, write_block].reshape(s, bs, h, d),
+                     scales[layer, write_block])             # [S, bs, H, D]
     blk = blk.at[rows, write_off].set(new_vec.astype(jnp.float32))
     # offsets past the write position are stale (a freed block's previous
     # tenant, or prefill padding): zero them so they never inflate the
@@ -83,23 +88,26 @@ def quantize_block_write(pool, scales, new_vec, write_block, write_off):
     blk = jnp.where(live[:, :, None, None], blk, 0.0)
     new_sc = jnp.maximum(jnp.max(jnp.abs(blk), axis=(1, 3)), SCALE_EPS)
     q = jnp.clip(jnp.round(blk * (QMAX / new_sc)[:, None, :, None]),
-                 -QMAX, QMAX).astype(pool.dtype)
-    return pool.at[write_block].set(q), scales.at[write_block].set(new_sc)
+                 -QMAX, QMAX).astype(pools.dtype)
+    return (pools.at[layer, write_block].set(q.reshape(s, bs, h * d)),
+            scales.at[layer, write_block].set(new_sc))
 
 
-def quantize_scatter(pool, scales, tok_vals, blocks, offs, block_row,
-                     length):
-    """Bulk-quantize a prefilled prompt's per-token K or V into the int8
-    pool (the quantized leg of serving/cache.py `scatter_prefill`).
+def quantize_scatter(pools, scales, layer, tok_vals, blocks, offs,
+                     block_row, length):
+    """Bulk-quantize a prefilled prompt's per-token K or V into `layer` of
+    the int8 pools (the quantized leg of serving/cache.py
+    `scatter_prefill`).
 
-    tok_vals: ``[T, H, D]`` fp (right-padded to the prefill bucket);
+    pools: ``[L, num_blocks, bs, H*D]`` int8; scales: ``[L, num_blocks,
+    H]`` fp32; tok_vals: ``[T, H, D]`` fp (right-padded to the prefill bucket);
     blocks/offs: ``[T]`` int32 per-token targets (padded tokens route to
     the null block); block_row: ``[max_blocks]`` int32 — the sequence's
     block table, used to RESET the touched blocks' scales before the
     scatter-max (a freed block keeps its previous tenant's scale
     otherwise); length: scalar int32 true prompt length.
 
-    Returns (pool, scales).
+    Returns (pools, scales).
     """
     t = tok_vals.shape[0]
     vals = tok_vals.astype(jnp.float32)
@@ -110,9 +118,9 @@ def quantize_scatter(pool, scales, tok_vals, blocks, offs, block_row,
     amax = jnp.where((jnp.arange(t, dtype=jnp.int32)
                       < length)[:, None],
                      jnp.maximum(amax, SCALE_EPS), 0.0)
-    scales = scales.at[block_row].set(0.0)
-    scales = scales.at[blocks].max(amax)
-    sc_t = jnp.maximum(scales[blocks], SCALE_EPS)             # [T, H]
+    scales = scales.at[layer, block_row].set(0.0)
+    scales = scales.at[layer, blocks].max(amax)
+    sc_t = jnp.maximum(scales[layer, blocks], SCALE_EPS)      # [T, H]
     q = jnp.clip(jnp.round(vals * (QMAX / sc_t)[..., None]),
-                 -QMAX, QMAX).astype(pool.dtype)
-    return pool.at[blocks, offs].set(q), scales
+                 -QMAX, QMAX).astype(pools.dtype)
+    return pools.at[layer, blocks, offs].set(q.reshape(t, -1)), scales

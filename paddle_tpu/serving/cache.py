@@ -8,9 +8,12 @@ document cost the same HBM and a new request of a different length means a
 new buffer (and on TPU a new compiled shape). This module is the
 PagedAttention memory model (vLLM, SOSP'23) rebuilt TPU-native:
 
-  * ONE preallocated block pool per layer, shape
-    ``[num_blocks, block_size, H, D]`` — total KV memory is fixed at
-    engine construction, independent of how many sequences share it;
+  * ONE preallocated block pool, shape
+    ``[L, num_blocks, block_size, H*D]`` (a layer's heads side by side in
+    one row, so a block is whole (sublane, lane) tiles and the device
+    keeps the array row-major: a donated pool is written where it lies)
+    — total KV memory is fixed at engine construction, independent of
+    how many sequences share it;
   * each sequence owns an ordered list of block ids (its *block table*);
     token position ``p`` of a sequence lives at
     ``(table[p // block_size], p % block_size)``;
@@ -135,11 +138,16 @@ class BlockAllocator:
 
 
 class PagedCacheView:
-    """One layer's paged cache as seen from INSIDE the compiled decode
-    step: the layer's pools plus the batch's block tables / lengths /
-    active mask (jnp arrays or tracers). `GPTAttention` detects this view
-    by its `block_tables` attribute and routes to the paged decode path;
-    `updated()` threads the written pools back out of the model.
+    """The paged cache as one layer sees it from INSIDE the compiled
+    decode step: the STACKED pools ``[L, num_blocks, block_size, H*D]``,
+    the index of the layer that reads and writes them next, plus the
+    batch's block tables / lengths / active mask (jnp arrays or tracers).
+    `GPTAttention` detects this view by its `block_tables` attribute and
+    routes to the paged decode path. The model threads ONE view through
+    its layers: each writes its token at ``(layer, block, offset)`` of the
+    stacked pool and `updated()` hands the written pools to the next
+    layer, so no per-layer slice of a pool is ever a value of the program
+    and the view the last layer returns holds the step's pools.
 
     int8 mode carries the per-block-per-head scale side-tables
     (`k_scales`/`v_scales`, quantization/kv_cache.py); `kernel` pins the
@@ -147,13 +155,15 @@ class PagedCacheView:
     (nn/functional/attention.resolve_paged_kernel), so a mid-run flag
     flip never re-keys a live engine's compiled decode step."""
 
-    __slots__ = ("k_pool", "v_pool", "block_tables", "seq_lens", "active",
-                 "block_size", "k_scales", "v_scales", "kernel")
+    __slots__ = ("k_pools", "v_pools", "layer", "block_tables", "seq_lens",
+                 "active", "block_size", "k_scales", "v_scales", "kernel")
 
-    def __init__(self, k_pool, v_pool, block_tables, seq_lens, active,
-                 block_size, k_scales=None, v_scales=None, kernel=None):
-        self.k_pool = k_pool
-        self.v_pool = v_pool
+    def __init__(self, k_pools, v_pools, layer, block_tables, seq_lens,
+                 active, block_size, k_scales=None, v_scales=None,
+                 kernel=None):
+        self.k_pools = k_pools
+        self.v_pools = v_pools
+        self.layer = int(layer)
         self.block_tables = block_tables
         self.seq_lens = seq_lens
         self.active = active
@@ -162,11 +172,12 @@ class PagedCacheView:
         self.v_scales = v_scales
         self.kernel = kernel
 
-    def updated(self, k_pool, v_pool, k_scales=None, v_scales=None):
-        return PagedCacheView(k_pool, v_pool, self.block_tables,
-                              self.seq_lens, self.active, self.block_size,
-                              k_scales=k_scales, v_scales=v_scales,
-                              kernel=self.kernel)
+    def updated(self, k_pools, v_pools, k_scales=None, v_scales=None):
+        """The view for the NEXT layer, over the pools this one wrote."""
+        return PagedCacheView(k_pools, v_pools, self.layer + 1,
+                              self.block_tables, self.seq_lens, self.active,
+                              self.block_size, k_scales=k_scales,
+                              v_scales=v_scales, kernel=self.kernel)
 
 
 def _is_int8(dtype):
@@ -176,9 +187,12 @@ def _is_int8(dtype):
 class PagedKVCache:
     """The device pools + the allocator, sized once at engine start.
 
-    Pools are stacked over layers — ``[L, num_blocks, block_size, H, D]``
+    Pools are stacked over layers — ``[L, num_blocks, block_size, H*D]``
     — so the compiled decode/prefill programs donate exactly two buffers
-    regardless of depth. Sizing policy (blocks per context length, the
+    regardless of depth, and every program updates them in place: the two
+    minor dimensions are whole tiles (16 bf16 sublanes, H*D lanes), so
+    the device layout is the row-major one that scatters and gathers by
+    block id work on. Sizing policy (blocks per context length, the
     admission budget) lives in ONE place: serving/scheduler.py.
 
     ``dtype=jnp.int8`` turns on the quantized KV mode
@@ -198,7 +212,7 @@ class PagedKVCache:
         self.quantized = _is_int8(dtype)
         self.dtype = jnp.int8 if self.quantized else dtype
         shape = (self.num_layers, self.num_blocks, self.block_size,
-                 self.num_heads, self.head_dim)
+                 self.num_heads * self.head_dim)
         self.k_pools = jnp.zeros(shape, self.dtype)
         self.v_pools = jnp.zeros(shape, self.dtype)
         if self.quantized:
@@ -252,8 +266,10 @@ def scatter_prefill(k_pools, v_pools, k_layers, v_layers, block_row,
     scales (quantization/kv_cache.py `quantize_scatter`) and the call
     returns ``(k_pools, v_pools, k_scales, v_scales)``.
 
-    Traceable (runs inside the jitted prefill program). Returns the
-    updated pools.
+    Every write is a scatter of flat ``[T, H*D]`` rows at ``(layer, block,
+    offset)`` of the stacked ``[L, num_blocks, block_size, H*D]`` pools,
+    so a donated pool is updated where it lies. Traceable (runs inside
+    the jitted prefill program). Returns the updated pools.
     """
     t_bucket = k_layers.shape[1]
     pidx = jnp.arange(t_bucket, dtype=jnp.int32)
@@ -265,20 +281,16 @@ def scatter_prefill(k_pools, v_pools, k_layers, v_layers, block_row,
     if k_scales is not None:
         from ..quantization.kv_cache import quantize_scatter
         for layer in range(num_layers):
-            kp, ks = quantize_scatter(k_pools[layer], k_scales[layer],
-                                      k_layers[layer], blocks, offs,
-                                      block_row, length)
-            vp, vs = quantize_scatter(v_pools[layer], v_scales[layer],
-                                      v_layers[layer], blocks, offs,
-                                      block_row, length)
-            k_pools = k_pools.at[layer].set(kp)
-            v_pools = v_pools.at[layer].set(vp)
-            k_scales = k_scales.at[layer].set(ks)
-            v_scales = v_scales.at[layer].set(vs)
+            k_pools, k_scales = quantize_scatter(
+                k_pools, k_scales, layer, k_layers[layer], blocks, offs,
+                block_row, length)
+            v_pools, v_scales = quantize_scatter(
+                v_pools, v_scales, layer, v_layers[layer], blocks, offs,
+                block_row, length)
         return k_pools, v_pools, k_scales, v_scales
+    k_rows = k_layers.reshape(num_layers, t_bucket, -1).astype(k_pools.dtype)
+    v_rows = v_layers.reshape(num_layers, t_bucket, -1).astype(v_pools.dtype)
     for layer in range(num_layers):
-        k_pools = k_pools.at[layer, blocks, offs].set(
-            k_layers[layer].astype(k_pools.dtype))
-        v_pools = v_pools.at[layer, blocks, offs].set(
-            v_layers[layer].astype(v_pools.dtype))
+        k_pools = k_pools.at[layer, blocks, offs].set(k_rows[layer])
+        v_pools = v_pools.at[layer, blocks, offs].set(v_rows[layer])
     return k_pools, v_pools

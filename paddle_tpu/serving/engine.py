@@ -1908,6 +1908,9 @@ class LLMEngine:
                 ("decode", type(self._model).__qualname__,
                  tuple(sorted(cfg.items())), self.max_batch_size,
                  self.block_size, self._num_blocks,
+                 # the pool's shape is the donated arguments' signature:
+                 # an artifact traced for another layout never replays
+                 tuple(self.cache.k_pools.shape),
                  self.max_blocks_per_seq, str(self._dtype),
                  # the kernel tier re-keys the artifact: a blockwise
                  # executable must never replay as the pallas one, and an
@@ -1960,7 +1963,6 @@ class LLMEngine:
             # tenant replicas always trace once at start
             return self._build_decode_tenant()
         model = self._model
-        num_layers = model.config.num_hidden_layers
         block_size = self.block_size
         stats = self._stats
         variant = self._attn_kernel
@@ -1970,18 +1972,16 @@ class LLMEngine:
                    rpens, seeds, history, k_pools, v_pools,
                    k_scales=None, v_scales=None):
             stats.decode_compiles += 1   # runs only while tracing
-            views = [PagedCacheView(
-                k_pools[l], v_pools[l], tables, lens, active, block_size,
-                k_scales=None if k_scales is None else k_scales[l],
-                v_scales=None if v_scales is None else v_scales[l],
-                kernel=variant)
-                for l in range(num_layers)]
+            # ONE view over the stacked (donated) pools: every layer
+            # writes at its own index and hands them on, so the pools
+            # the last layer returns are the step's, updated in place
+            view = PagedCacheView(
+                k_pools, v_pools, 0, tables, lens, active, block_size,
+                k_scales=k_scales, v_scales=v_scales, kernel=variant)
             with set_grad_enabled(False):
-                logits, new_views = model(
+                logits, (view,) = model(
                     Tensor(tokens[:, None], stop_gradient=True),
-                    caches=views)
-            new_k = jnp.stack([v.k_pool for v in new_views])
-            new_v = jnp.stack([v.v_pool for v in new_views])
+                    caches=[view])
             # the in-graph history scatter: the input token enters the
             # context at index `lens` — under pipelined decode it may
             # exist ONLY on-device (feedback), so the host mirror cannot
@@ -1997,12 +1997,10 @@ class LLMEngine:
             nxt, logp, alt_ids, alt_lps = sample_tokens(
                 logits._value[:, -1, :], temps, topks, topps, rpens,
                 seeds, lens + 1, hist, valid, logprobs_topk=lp_topk)
+            written = (view.k_pools, view.v_pools)
             if k_scales is not None:
-                new_ks = jnp.stack([v.k_scales for v in new_views])
-                new_vs = jnp.stack([v.v_scales for v in new_views])
-                return (nxt, logp, alt_ids, alt_lps, new_k, new_v,
-                        new_ks, new_vs)
-            return nxt, logp, alt_ids, alt_lps, new_k, new_v
+                written += (view.k_scales, view.v_scales)
+            return (nxt, logp, alt_ids, alt_lps) + written
 
         donate = (10, 11, 12, 13) if self._kv_quantized else (10, 11)
         jitted = jax.jit(decode, donate_argnums=self._donate(donate))
@@ -2035,7 +2033,6 @@ class LLMEngine:
         inputs. Compiles exactly once per engine, like the base
         program."""
         model = self._model
-        num_layers = model.config.num_hidden_layers
         block_size = self.block_size
         stats = self._stats
         variant = self._attn_kernel
@@ -2057,25 +2054,19 @@ class LLMEngine:
                 holder["active"] = AdapterSet.trace_ctx(
                     aux["adapters"], slots=aux["aslots"])
             try:
-                views = [PagedCacheView(
-                    k_pools[l], v_pools[l], tables, lens, active,
-                    block_size,
-                    k_scales=None if k_scales is None else k_scales[l],
-                    v_scales=None if v_scales is None else v_scales[l],
-                    kernel=variant)
-                    for l in range(num_layers)]
+                view = PagedCacheView(
+                    k_pools, v_pools, 0, tables, lens, active, block_size,
+                    k_scales=k_scales, v_scales=v_scales, kernel=variant)
                 with set_grad_enabled(False):
-                    logits, new_views = model(
+                    logits, (view,) = model(
                         Tensor(tokens[:, None], stop_gradient=True),
-                        caches=views)
+                        caches=[view])
             finally:
                 if saved is not None:
                     for pp, vv in zip(params, saved):
                         pp._value = vv
                 if holder is not None:
                     holder["active"] = None
-            new_k = jnp.stack([v.k_pool for v in new_views])
-            new_v = jnp.stack([v.v_pool for v in new_views])
             rows = jnp.arange(tokens.shape[0], dtype=jnp.int32)
             idx = jnp.clip(lens, 0, history.shape[1] - 1)
             hist = history.at[rows, idx].set(tokens)
@@ -2084,12 +2075,10 @@ class LLMEngine:
             nxt, logp, alt_ids, alt_lps = sample_tokens(
                 logits._value[:, -1, :], temps, topks, topps, rpens,
                 seeds, lens + 1, hist, valid, logprobs_topk=lp_topk)
+            written = (view.k_pools, view.v_pools)
             if k_scales is not None:
-                new_ks = jnp.stack([v.k_scales for v in new_views])
-                new_vs = jnp.stack([v.v_scales for v in new_views])
-                return (nxt, logp, alt_ids, alt_lps, new_k, new_v,
-                        new_ks, new_vs)
-            return nxt, logp, alt_ids, alt_lps, new_k, new_v
+                written += (view.k_scales, view.v_scales)
+            return (nxt, logp, alt_ids, alt_lps) + written
 
         donate = (11, 12, 13, 14) if self._kv_quantized else (11, 12)
         return jax.jit(decode, donate_argnums=self._donate(donate))

@@ -42,7 +42,8 @@ import jax.numpy as jnp
 import paddle_tpu as paddle
 from paddle_tpu.framework.flags import get_flags, set_flags
 from paddle_tpu.incubate.models import GPTConfig, GPTForCausalLM
-from paddle_tpu.nn.functional.attention import (paged_decode_attention,
+from paddle_tpu.nn.functional.attention import (_dense_gather_attention,
+                                                paged_decode_attention,
                                                 resolve_paged_kernel,
                                                 PAGED_KERNELS)
 from paddle_tpu.quantization.kv_cache import (QMAX, quantize_scatter,
@@ -87,17 +88,23 @@ def _ref(model, prompt, n):
     return _REF_CACHE[key]
 
 
+# the stacked pools of the hand-built states hold LAYERS layers; the
+# attention under test writes and reads LAYER, the others must not move
+LAYERS, LAYER = 3, 1
+
+
 def _paged_state(S=4, H=3, D=16, bs=4, M=6, lens=(0, 4, 8, 23),
                  active=(True, True, True, True), seed=0,
                  dtype=jnp.float32):
     """A filled paged-cache state: per-slot dense-prefix block tables over
-    disjoint pool blocks, pools populated with random history."""
+    disjoint pool blocks, stacked pools ``[L, num_blocks, bs, H*D]``
+    populated with random history in every layer."""
     rng = np.random.default_rng(seed)
     nb = S * M + 1
     mk = lambda sh: jnp.asarray(
         rng.standard_normal(sh).astype(np.float32)).astype(dtype)
     q, kn, vn = mk((S, 1, H, D)), mk((S, 1, H, D)), mk((S, 1, H, D))
-    kp, vp = mk((nb, bs, H, D)), mk((nb, bs, H, D))
+    kp, vp = mk((LAYERS, nb, bs, H * D)), mk((LAYERS, nb, bs, H * D))
     tables = jnp.asarray(np.stack(
         [1 + s * M + np.arange(M) for s in range(S)]).astype(np.int32))
     return (q, kn, vn, kp, vp, tables,
@@ -108,9 +115,20 @@ def _paged_state(S=4, H=3, D=16, bs=4, M=6, lens=(0, 4, 8, 23),
 def _run(variant, state, bs, **kw):
     q, kn, vn, kp, vp, tables, lens, active = state
     interpret = variant == "pallas"
-    return paged_decode_attention(q, kn, vn, kp, vp, tables, lens, active,
-                                  bs, kernel=variant, interpret=interpret,
-                                  **kw)
+    return paged_decode_attention(q, kn, vn, kp, vp, LAYER, tables, lens,
+                                  active, bs, kernel=variant,
+                                  interpret=interpret, **kw)
+
+
+def _quantized(pools, heads):
+    """int8 pools + per-block-per-head scales holding `pools`' values to
+    half a quant step."""
+    nl, nb, bs, hd = pools.shape
+    vals = np.asarray(pools, np.float32).reshape(nl, nb, bs, heads, -1)
+    scales = np.maximum(np.abs(vals).max(axis=(2, 4)), 1e-8)
+    q = np.clip(np.round(vals * (QMAX / scales)[:, :, None, :, None]),
+                -QMAX, QMAX).astype(np.int8)
+    return jnp.asarray(q.reshape(pools.shape)), jnp.asarray(scales)
 
 
 # ---------------------------------------------------------------------------
@@ -118,6 +136,36 @@ def _run(variant, state, bs, **kw):
 # ---------------------------------------------------------------------------
 
 class TestVariantParity:
+    @pytest.mark.parametrize("pool", ("bf16", "int8"))
+    @pytest.mark.parametrize("variant", VARIANTS)
+    def test_stacked_pool_with_layer_index(self, variant, pool):
+        """The attention works on the STACKED pool and a layer index: the
+        output is the dense oracle's over that layer of the written pool,
+        and every OTHER layer of the returned pools (and scale tables) is
+        bit-identical to what came in."""
+        bs, H = 4, 3
+        q, kn, vn, kp, vp, tables, lens, active = _paged_state(
+            bs=bs, H=H, dtype=jnp.bfloat16)
+        scales = {}
+        if pool == "int8":
+            (kp, ks), (vp, vs) = _quantized(kp, H), _quantized(vp, H)
+            scales = {"k_scales": ks, "v_scales": vs}
+        state = (q, kn, vn, kp, vp, tables, lens, active)
+        out, *new = _run(variant, state, bs, **scales)
+        eff = jnp.where(active, lens, 0).astype(jnp.int32)
+        oracle = _dense_gather_attention(q[:, 0], new[0], new[1], LAYER,
+                                         tables, eff, bs, *new[2:])
+        np.testing.assert_allclose(
+            np.asarray(out[:, 0], np.float32),
+            np.asarray(oracle, np.float32), rtol=0.0, atol=2e-2)
+        others = [l for l in range(LAYERS) if l != LAYER]
+        for got, came in zip(new, (kp, vp) + tuple(scales.values())):
+            assert got.shape == came.shape and got.dtype == came.dtype
+            assert np.array_equal(np.asarray(got)[others],
+                                  np.asarray(came)[others])
+            assert not np.array_equal(np.asarray(got)[LAYER],
+                                      np.asarray(came)[LAYER])
+
     def test_blockwise_and_pallas_match_dense_oracle(self):
         """Core parity: identical semantics across the three variants to
         fp32 tolerance (the Pallas kernel runs interpret=True on CPU),
@@ -151,8 +199,8 @@ class TestVariantParity:
                 # the boundary write must land at (table[len//bs], 0)
                 tables = np.asarray(state[5])
                 blk = tables[0, length // bs]
-                written = np.asarray(k_ref)[blk, 0]
-                expect = np.asarray(state[1])[0, 0]
+                written = np.asarray(k_ref)[LAYER, blk, 0]
+                expect = np.asarray(state[1])[0, 0].reshape(-1)
                 np.testing.assert_allclose(written, expect, rtol=1e-6)
                 continue
             out, k_pool, _ = _run(variant, state, bs)
@@ -174,10 +222,10 @@ class TestVariantParity:
         # slot shape)
         tables = tables.at[1].set(0)
         out, new_k, new_v = paged_decode_attention(
-            q, kn, vn, kp, vp, tables, lens, active, bs, kernel=variant,
-            interpret=(variant == "pallas"))
+            q, kn, vn, kp, vp, LAYER, tables, lens, active, bs,
+            kernel=variant, interpret=(variant == "pallas"))
         solo = paged_decode_attention(
-            q, kn, vn, kp, vp, tables,
+            q, kn, vn, kp, vp, LAYER, tables,
             lens, jnp.asarray([True, True, True]), bs, kernel="reference")
         # active rows agree with a run where slot 1's table is unchanged
         np.testing.assert_allclose(np.asarray(out)[[0, 2]],
@@ -186,7 +234,7 @@ class TestVariantParity:
         assert np.isfinite(np.asarray(out)[[0, 2]]).all()
         # only the null block and the two active write targets changed
         diff = np.where(np.any(np.asarray(new_k) != np.asarray(kp),
-                               axis=(1, 2, 3)))[0]
+                               axis=(0, 2, 3)))[0]
         tables_np = np.asarray(tables)
         allowed = {0, int(tables_np[0, 7 // bs]), int(tables_np[2, 5 // bs])}
         assert set(diff.tolist()) <= allowed
@@ -243,17 +291,19 @@ class TestInt8KV:
         T = 24
         vals = jnp.asarray(rng.standard_normal((T, H, D)).astype(np.float32)
                            * rng.uniform(0.1, 10.0, (T, 1, 1)))
-        pool = jnp.zeros((nb, bs, H, D), jnp.int8)
-        scales = jnp.full((nb, H), 7.7, jnp.float32)  # stale tenant scale
+        pool = jnp.zeros((LAYERS, nb, bs, H * D), jnp.int8)
+        scales = jnp.full((LAYERS, nb, H), 7.7,       # stale tenant scale
+                          jnp.float32)
         block_row = jnp.asarray([1, 2, 3, 4, 5, 6, 0, 0], jnp.int32)
         pidx = np.arange(T)
         blocks = jnp.asarray(np.where(pidx < 22, block_row[pidx // bs], 0)
                              .astype(np.int32))
         offs = jnp.asarray((pidx % bs).astype(np.int32))
-        pool, scales = quantize_scatter(pool, scales, vals, blocks, offs,
-                                        block_row, jnp.int32(22))
-        deq = np.asarray(dequantize(pool, scales))
-        sc = np.asarray(scales)
+        pool, scales = quantize_scatter(pool, scales, LAYER, vals, blocks,
+                                        offs, block_row, jnp.int32(22))
+        deq = np.asarray(dequantize(
+            pool[LAYER].reshape(nb, bs, H, D), scales[LAYER]))
+        sc = np.asarray(scales)[LAYER]
         for t in range(22):
             b, o = int(blocks[t]), int(offs[t])
             err = np.abs(deq[b, o] - np.asarray(vals)[t])
@@ -267,18 +317,19 @@ class TestInt8KV:
         running per-head amax."""
         rng = np.random.default_rng(4)
         bs, H, D = 8, 2, 4
-        pool = jnp.zeros((3, bs, H, D), jnp.int8)
-        scales = jnp.zeros((3, H), jnp.float32)
+        pool = jnp.zeros((LAYERS, 3, bs, H * D), jnp.int8)
+        scales = jnp.zeros((LAYERS, 3, H), jnp.float32)
         written = []
         for i in range(bs):
             vec = jnp.asarray(
                 rng.standard_normal((1, H, D)).astype(np.float32) * (i + 1))
             written.append(np.asarray(vec)[0])
             pool, scales = quantize_block_write(
-                pool, scales, vec, jnp.asarray([1], jnp.int32),
+                pool, scales, LAYER, vec, jnp.asarray([1], jnp.int32),
                 jnp.asarray([i], jnp.int32))
-        deq = np.asarray(dequantize(pool, scales))[1]       # [bs, H, D]
-        sc = np.asarray(scales)[1]                          # [H]
+        deq = np.asarray(dequantize(pool[LAYER, 1].reshape(bs, H, D),
+                                    scales[LAYER, 1]))      # [bs, H, D]
+        sc = np.asarray(scales)[LAYER, 1]                   # [H]
         amax = np.abs(np.stack(written)).max(axis=(0, 2))
         np.testing.assert_allclose(sc, amax, rtol=1e-5)
         for i, vec in enumerate(written):
@@ -358,13 +409,13 @@ class TestKernelKeying:
         attn = model.gpt.h[0].attn
         S, bs, M = 2, 4, 4
         nb = S * M + 1
-        head_dim = cfg.hidden_size // cfg.num_attention_heads
         rng = np.random.default_rng(5)
         x = Tensor(jnp.asarray(rng.standard_normal(
             (S, 1, cfg.hidden_size)).astype(np.float32)),
             stop_gradient=True)
         pools = jnp.asarray(rng.standard_normal(
-            (nb, bs, cfg.num_attention_heads, head_dim)).astype(np.float32))
+            (cfg.num_hidden_layers, nb, bs,
+             cfg.hidden_size)).astype(np.float32))
         tables = jnp.asarray(np.stack(
             [1 + s * M + np.arange(M) for s in range(S)]).astype(np.int32))
         lens = jnp.asarray([3, 5], jnp.int32)
@@ -376,8 +427,8 @@ class TestKernelKeying:
         try:
             for variant in ("reference", "blockwise",
                             "reference", "blockwise"):
-                view = PagedCacheView(pools, pools, tables, lens, active,
-                                      bs, kernel=variant)
+                view = PagedCacheView(pools, pools, 0, tables, lens,
+                                      active, bs, kernel=variant)
                 attn(x, cache=view)
         finally:
             set_flags(prev)
@@ -485,13 +536,13 @@ class TestPerfFloors:
         state = _paged_state(S=S, H=H, D=D, bs=bs, M=M,
                              lens=(1000,) * S, active=(True,) * S)
         q, kn, vn, kp, vp, tables, lens, active = state
-        assert kp.shape[0] == nb
+        assert kp.shape[1] == nb
 
         def jit_of(kernel):
             @jax.jit
             def f(q, kn, vn, kp, vp):
                 return paged_decode_attention(
-                    q, kn, vn, kp, vp, tables, lens, active, bs,
+                    q, kn, vn, kp, vp, LAYER, tables, lens, active, bs,
                     kernel=kernel)[0]
             f(q, kn, vn, kp, vp).block_until_ready()
             return f

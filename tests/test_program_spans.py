@@ -330,13 +330,13 @@ def test_the_decode_attention_and_its_kv_write_are_scoped(variant):
     from paddle_tpu.nn.functional.attention import paged_decode_attention
     jnp = jax.numpy
     q = jnp.zeros((2, 1, 2, 64), jnp.float32)
-    pool = jnp.zeros((5, 16, 2, 64), jnp.float32)
+    pool = jnp.zeros((2, 5, 16, 2 * 64), jnp.float32)
     tables = jnp.zeros((2, 2), jnp.int32)
     lens = jnp.asarray([3, 0], jnp.int32)
     active = jnp.asarray([True, False])
 
     def fn(q, pool):
-        return paged_decode_attention(q, q, q, pool, pool, tables, lens,
+        return paged_decode_attention(q, q, q, pool, pool, 1, tables, lens,
                                       active, 16, kernel=variant,
                                       interpret=True)
     text = jax.jit(fn).lower(q, pool).as_text(debug_info=True)

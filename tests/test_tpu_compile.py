@@ -8,8 +8,14 @@ Interpret mode hides all of that: the paged-attention kernel passed every
 interpret-mode parity test while the compiler refused its `(1, d)` blocks.
 Shapes are those of `chip_smoke.py`. A compile that passes is not a chip
 run: nothing executes and nothing here is a device number.
+
+The serving programs' KV pool is held to the same compiler: at the backlog
+cell's geometry the donated pool has to stay in one row-major layout and be
+updated where it lies (no pool-shaped `copy`, `concatenate` or `pad`,
+temporaries under one layer of one pool).
 """
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 # several test processes (pytest-xdist workers) may each describe a chip:
@@ -23,6 +29,8 @@ from jax.sharding import SingleDeviceSharding
 
 from paddle_tpu.kernels import cross_entropy, flash_attention, fused_ln
 from paddle_tpu.kernels.pallas import paged_attention
+from paddle_tpu.nn.functional.attention import paged_decode_attention
+from paddle_tpu.serving.cache import scatter_prefill
 
 
 @pytest.fixture(scope="module")
@@ -89,17 +97,16 @@ def test_fused_cross_entropy_fwd_bwd(v5e):
 
 
 # the serving shape of chip_smoke.py: 8 slots, block 16, a pool that lets
-# every slot reach 1024 tokens
-SLOTS, BLOCK, TABLE, POOL = 8, 16, 64, 513
+# every slot reach 1024 tokens; the kernel reads layer 1 of a stack of two
+SLOTS, BLOCK, TABLE, POOL, LAYERS = 8, 16, 64, 513, 2
 
 
 def paged_shapes(heads, head_dim, block, pool_dtype):
-    shapes = [((SLOTS, heads, head_dim), jnp.bfloat16),
-              ((POOL, block, heads, head_dim), pool_dtype),
-              ((POOL, block, heads, head_dim), pool_dtype),
+    pool = ((LAYERS, POOL, block, heads * head_dim), pool_dtype)
+    shapes = [((SLOTS, heads, head_dim), jnp.bfloat16), pool, pool,
               ((SLOTS, TABLE), jnp.int32), ((SLOTS,), jnp.int32)]
     if pool_dtype == jnp.int8:
-        shapes += [((POOL, heads), jnp.float32)] * 2
+        shapes += [((LAYERS, POOL, heads), jnp.float32)] * 2
     return shapes
 
 
@@ -109,31 +116,107 @@ def paged_shapes(heads, head_dim, block, pool_dtype):
 def test_paged_decode_attention(v5e, heads, pool_dtype):
     def decode(q, k, v, tables, lens, *scales):
         return paged_attention.pallas_paged_attention(
-            q, k, v, tables, lens, BLOCK, *scales)
+            q, k, v, 1, tables, lens, BLOCK, *scales)
 
     compile_for(v5e, decode, *paged_shapes(heads, 64, BLOCK, pool_dtype))
 
 
 def test_paged_eligibility_is_what_the_compiler_accepts(v5e, monkeypatch):
     """`is_eligible` draws its line at the largest pool block the compiler
-    takes for every pool dtype; a float32 block twice that size runs out
-    of VMEM."""
+    takes for every pool dtype; an int8 block twice that size runs out of
+    VMEM."""
     monkeypatch.setattr(paged_attention, "_on_tpu", lambda: True)
 
     def decode(block):
-        return lambda q, k, v, tables, lens: \
-            paged_attention.pallas_paged_attention(q, k, v, tables, lens,
-                                                   block)
+        return lambda q, k, v, tables, lens, *scales: \
+            paged_attention.pallas_paged_attention(q, k, v, 1, tables, lens,
+                                                   block, *scales)
 
     heads, head_dim = 32, 128
-    assert paged_attention.is_eligible(heads, head_dim, 128) == (True, None)
-    compile_for(v5e, decode(128),
-                *paged_shapes(heads, head_dim, 128, jnp.float32))
-    assert paged_attention.is_eligible(heads, head_dim, 256) == (
+    assert paged_attention.is_eligible(heads, head_dim, 64) == (True, None)
+    for pool_dtype in (jnp.float32, jnp.int8):
+        compile_for(v5e, decode(64),
+                    *paged_shapes(heads, head_dim, 64, pool_dtype))
+    assert paged_attention.is_eligible(heads, head_dim, 128) == (
         False, "block_exceeds_vmem")
     with pytest.raises(Exception, match="vmem"):
-        compile_for(v5e, decode(256),
-                    *paged_shapes(heads, head_dim, 256, jnp.float32))
+        compile_for(v5e, decode(128),
+                    *paged_shapes(heads, head_dim, 128, jnp.int8))
     # the shapes the engine serves today are far inside the line
     assert paged_attention.is_eligible(12, 64, 16) == (True, None)
     assert paged_attention.is_eligible(16, 64, 16) == (True, None)
+
+
+# The backlog cell's serving geometry (benchmark/traffic/backlog_mixed.json:
+# 128 slots, block 16, 64 table entries, GPT-2 124M's 12 heads of 64); two
+# layers of 2,049 blocks stand for the cell's 12 layers of 8,193.
+CELL_SLOTS, CELL_TABLE, CELL_HEADS, CELL_HEAD_DIM = 128, 64, 12, 64
+CELL_LAYERS, CELL_BLOCKS, CELL_BUCKET = 2, 2049, 256
+CELL_POOL = (CELL_LAYERS, CELL_BLOCKS, BLOCK, CELL_HEADS * CELL_HEAD_DIM)
+
+
+def _decode_layers(variant):
+    """The decode step's KV write and attention, threaded through the
+    layers as the engine's program threads them: a layer's K and V come
+    from the layer before, so its write cannot move ahead of that
+    layer's reads."""
+    def decode(q, k_new, v_new, tables, lens, active, k_pools, v_pools):
+        for layer in range(CELL_LAYERS):
+            out, k_pools, v_pools = paged_decode_attention(
+                q, q + k_new, q + v_new, k_pools, v_pools, layer, tables,
+                lens, active, BLOCK, kernel=variant)
+            q = q + out
+        return q, k_pools, v_pools
+
+    token = ((CELL_SLOTS, 1, CELL_HEADS, CELL_HEAD_DIM), jnp.bfloat16)
+    return decode, [token, token, token,
+                    ((CELL_SLOTS, CELL_TABLE), jnp.int32),
+                    ((CELL_SLOTS,), jnp.int32), ((CELL_SLOTS,), jnp.bool_)]
+
+
+def _prefill_scatter():
+    def prefill(k_layers, v_layers, block_row, length, k_pools, v_pools):
+        return scatter_prefill(k_pools, v_pools, k_layers, v_layers,
+                               block_row, length, BLOCK)
+
+    prompt = ((CELL_LAYERS, CELL_BUCKET, CELL_HEADS, CELL_HEAD_DIM),
+              jnp.bfloat16)
+    return prefill, [prompt, prompt, ((CELL_TABLE,), jnp.int32),
+                     ((), jnp.int32)]
+
+
+@pytest.mark.parametrize("program", ["decode_blockwise", "decode_pallas",
+                                     "prefill_256"])
+def test_the_donated_kv_pool_is_updated_where_it_lies(v5e, monkeypatch,
+                                                      program):
+    """No serving program copies a whole KV pool: the pools come in
+    row-major, go out in the buffers they came in, and between the two no
+    instruction of a pool's shape is a `copy`, a `concatenate` or a `pad`
+    (the relayouts and the slice-and-stack the 5-D pool cost); what the
+    program keeps besides is less than one layer of one pool."""
+    monkeypatch.setattr(paged_attention, "_on_tpu", lambda: True)
+    if program == "prefill_256":
+        fn, shapes = _prefill_scatter()
+    else:
+        fn, shapes = _decode_layers(program[len("decode_"):])
+    shapes += [(CELL_POOL, jnp.bfloat16)] * 2
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+            for shape, dtype in shapes]
+    pools = (len(args) - 2, len(args) - 1)
+    compiled = jax.jit(fn, donate_argnums=pools).lower(*args).compile()
+    text = compiled.as_text()
+    assert ("tpu_custom_call" in text) == (program == "decode_pallas")
+
+    pool = "bf16[" + ",".join(map(str, CELL_POOL)) + "]"
+    made = re.findall(r"= " + re.escape(pool) + r"\{([\d,]*)\S* ([\w-]+)\(",
+                      text)
+    layouts = {layout for layout, opcode in made if opcode == "parameter"}
+    assert layouts == {"3,2,1,0"}, layouts
+    opcodes = {opcode for _, opcode in made}
+    assert "scatter" in opcodes
+    assert not opcodes & {"copy", "concatenate", "pad"}, opcodes
+
+    one_layer = 2 * CELL_BLOCKS * BLOCK * CELL_HEADS * CELL_HEAD_DIM
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 2 * CELL_LAYERS * one_layer
+    assert memory.temp_size_in_bytes < one_layer, memory.temp_size_in_bytes

@@ -734,7 +734,8 @@ def main() -> int:
     kmk = lambda sh: jnp.asarray(krng.standard_normal(sh).astype(np.float32))
     kq, kkn, kvn = kmk((KS, 1, KH, KD)), kmk((KS, 1, KH, KD)), \
         kmk((KS, 1, KH, KD))
-    kkp, kvp = kmk((knb, KBS, KH, KD)), kmk((knb, KBS, KH, KD))
+    # the engine's pool shape: [layers, blocks, block, heads * head_dim]
+    kkp, kvp = kmk((1, knb, KBS, KH * KD)), kmk((1, knb, KBS, KH * KD))
     ktables = jnp.asarray(np.stack(
         [1 + i * KM + np.arange(KM) for i in range(KS)]).astype(np.int32))
     klens = jnp.full((KS,), KM * KBS - KBS, jnp.int32)
@@ -743,7 +744,7 @@ def main() -> int:
     def _paged_fn(kernel):
         @jax.jit
         def f(q, kn, vn, kp, vp):
-            return paged_decode_attention(q, kn, vn, kp, vp, ktables,
+            return paged_decode_attention(q, kn, vn, kp, vp, 0, ktables,
                                           klens, kactive, KBS,
                                           kernel=kernel)[0]
         f(kq, kkn, kvn, kkp, kvp).block_until_ready()
