@@ -130,13 +130,28 @@ def train_numbers(got, want):
 
 # -- serving ----------------------------------------------------------------
 
+def gap_numbers(name, gaps):
+    """What a serve cell reads of the gaps at all the sampled positions:
+    the widest; the mean over every served token of the sample (a sum over
+    tokens, not a mean of the requests' means); and the 99th percentile,
+    printed for the record and compared with nothing. The widest is set
+    by one position: where the model makes no discrete choice it catches
+    one wrong token. Where it makes one (top-k of a router) one rounding
+    flips it, in a sound program as in one of lower precision: only the
+    mean tells those two apart, and neither number is shown to catch one
+    wrong token there (`PERF.md` section 4, "a model that routes")."""
+    gaps = np.concatenate([np.asarray(g, np.float64) for g in gaps])
+    return {name: float(np.max(gaps)), name + "_mean": float(np.mean(gaps)),
+            name + "_p99": float(np.percentile(gaps, 99))}
+
+
 def served_gaps(cfg, seed, streams, pad_to, control=None):
     """For each (prompt ids, served ids): run the reference once over
     prompt + served tokens and read, at every served position, how far the
     served token's logit lies below the reference's best. With `control`
     (a precision) also how far below the best lies the token that the
-    reference computed in that precision puts first. Returns the widest
-    of each."""
+    reference computed in that precision puts first. Returns
+    `gap_numbers` of each, over all served tokens of all the streams."""
     ref = reference_of(cfg)
     weights = weight_maker(cfg, seed)()
 
@@ -151,9 +166,7 @@ def served_gaps(cfg, seed, streams, pad_to, control=None):
                 logits, first[:, None], -1)[:, 0]
         return out
 
-    worst = {"logit_gap": 0.0, "tokens": 0}
-    if control:
-        worst["control_logit_gap"] = 0.0
+    gaps, control_gaps = [], []
     for prompt, served in streams:
         total = len(prompt) + len(served)
         ids = np.zeros((1, pad_to), np.int32)
@@ -163,12 +176,11 @@ def served_gaps(cfg, seed, streams, pad_to, control=None):
         at = np.arange(len(prompt) - 1, total - 1)
         tok = np.asarray(served, np.int64)
         chosen = np.asarray(out["logits"][at][np.arange(len(at)), tok])
-        best = np.asarray(out["best"][at])
-        worst["logit_gap"] = max(worst["logit_gap"],
-                                 float(np.max(best - chosen)))
+        gaps.append(np.asarray(out["best"][at]) - chosen)
         if control:
-            worst["control_logit_gap"] = max(
-                worst["control_logit_gap"],
-                float(np.max(np.asarray(out["control_gap"][at]))))
-        worst["tokens"] += len(served)
-    return worst
+            control_gaps.append(np.asarray(out["control_gap"][at]))
+    numbers = gap_numbers("logit_gap", gaps)
+    if control:
+        numbers.update(gap_numbers("control_logit_gap", control_gaps))
+    numbers["tokens"] = sum(len(g) for g in gaps)
+    return numbers

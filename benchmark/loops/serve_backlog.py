@@ -63,6 +63,11 @@ def serve(run):
     print(json.dumps({"window_s": t_close - t_open, "window_tokens": tokens,
                       "finished_in_window": len(ended),
                       "pool_blocks_held_mean": sum(held) / len(held),
+                      "engine_step_s": run.evidence["engine_step_s"],
+                      "longest_engine_steps": [
+                          {"start_s": a, "seconds": d} for a, d in sorted(
+                              run.evidence["engine_steps"],
+                              key=lambda step: -step[1])[:5]],
                       "engine": {k: run.evidence["engine_stats"][k] for k in
                                  ("steps", "prefills", "occupancy_mean",
                                   "p50_step_ms", "evictions", "failed")}}),

@@ -131,22 +131,31 @@ def released(run):
             for d in run.devices)}}), flush=True)
 
 
+# what a serve cell compares, each number against its own limit: a
+# cell's limits file holds both or the cell is never correct
+COMPARED = ("logit_gap", "logit_gap_mean")
+
+
 def check_served(run, streams, control=None):
     """Hold the sampled streams [(prompt ids, served ids)] to the
-    reference (and, for the seed check, read the control's gap at the
-    same positions)."""
+    reference: the widest gap and the mean gap over all their served
+    tokens (`correct.gap_numbers`; and, for the seed check, the control's
+    at the same positions)."""
     if not streams:
-        run.check("logit_gap", float("nan"))
+        for name in COMPARED:
+            run.check(name, float("nan"))
         return
     with run.reference_time():
         t0 = time.perf_counter()
-        worst = correct.served_gaps(run.config, run.seed, streams,
-                                    run.traffic["reference_pad_to"], control)
-        worst["reference_s"] = time.perf_counter() - t0
-    print(json.dumps({"checked_requests": len(streams), **worst}),
+        numbers = correct.served_gaps(run.config, run.seed, streams,
+                                      run.traffic["reference_pad_to"],
+                                      control)
+        numbers["reference_s"] = time.perf_counter() - t0
+    print(json.dumps({"checked_requests": len(streams), **numbers}),
           flush=True)
-    run.check("logit_gap", worst["logit_gap"])
-    return worst
+    for name in COMPARED:
+        run.check(name, numbers[name])
+    return numbers
 
 
 def window_evidence(run, program, engine, t_open, t_close):
@@ -156,6 +165,9 @@ def window_evidence(run, program, engine, t_open, t_close):
              if n == "bench.engine_step" and t_open <= a and b <= t_close]
     run.evidence.update({
         "engine_stats": stats,
+        # (start after the window opened, seconds) of every engine step,
+        # on the host's clock: a window that loses time says where
+        "engine_steps": [(a - t_open, b - a) for a, b in steps],
         "engine_step_s": sum(b - a for a, b in steps),
         "decode_s": program.decode_seconds(engine),
         "window": (t_open, t_close)})

@@ -20,6 +20,30 @@ A reference module gives, with `cfg` the configuration file as loaded:
       blocks of rows so that one block's logits are held at a time. A
       configuration that only serves may leave it out.
 
+What the checks read of it. A train cell follows the compiled step's
+first three steps: losses, the first gradient's norm and the parameters'
+change by the worst leaf. A serve cell runs `forward` once over each
+sampled request's prompt and served tokens and compares TWO numbers of
+the gaps by which a served token's logit lies below the reference's best
+(`correct.gap_numbers`): the widest, which one wrong token fails in a
+model that makes no discrete choice, and the mean over all served tokens
+of the sample, each against its own limit in the cell's limits file.
+Both, because of models with a discrete choice in them. Top-k of a router's scores is discontinuous: a sound program and
+this reference choose another k-th expert wherever two scores lie within
+rounding of each other, and at that position a whole expert's share of
+the layer's output is replaced. The check is teacher-forced, so the flip
+does not travel along the sequence, but the widest gap over some thousand
+tokens is set by the worst flip, in a sound program as in one of lower
+precision: it cannot tell them apart, and the mean can (`PERF.md`
+section 4, "a model that routes"). One wrong token is then caught by
+neither number, except by chance: its gap is of the size of a flip's, and
+it moves the mean by its share of the sample. So a reference with such a choice in
+it computes the choice ITSELF, in float32 and from its own activations,
+in every `precision` (programs keep their routers in float32 too): it is
+never handed the program's choice, and has no argument through which it
+could be. A reference that followed the program's experts would agree
+with a program that routes wrongly.
+
 All of it is straightforward float32 `jax.numpy` under
 `jax.default_matmul_precision("highest")`: no kernel, no cache, no
 batching trick. It imports nothing of the program and takes nothing the

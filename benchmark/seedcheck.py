@@ -8,7 +8,14 @@ the program's place, computed in the precision below the configuration's).
 
 Not part of a benchmark run. Train cells build a fresh step for every seed
 (weights and batches from it). Serving cells keep one engine, whose weights
-come from the first seed, and draw each seed's traffic anew."""
+come from the first seed, and draw each seed's traffic anew; a row holds
+both numbers a serve cell compares, `logit_gap` and `logit_gap_mean` (and
+the 99th percentile), and on a control seed the control's under
+`control_...`, so one set of runs sets both limits. Every number compared
+goes through `Run.check` against the cell's limits file as it stands, the
+control's too: a row holds each under `checks` beside its limit, and the
+verdicts `correct` and, on a control seed, `control_correct`, which has
+to read false."""
 from __future__ import annotations
 
 import argparse
@@ -66,10 +73,31 @@ def serve(run, seeds, control_seeds, seconds, emit):
         serve_backlog.serve_for(engine, client, filler, mix["queue_depth"],
                                 seconds)
         ended = [r for r in client.log[first:] if serving.Client.done(r)]
-        worst = serving.check_served(
+        controlled = seed in control_seeds
+        mark = len(run.checks)
+        numbers = serving.check_served(
             run, serving.sample_streams(run, ended),
-            cfg["precision"]["control"] if seed in control_seeds else None)
-        emit({"seed": seed, "finished": len(ended), **(worst or {})})
+            cfg["precision"]["control"] if controlled else None) or {}
+        row = {"seed": seed, "finished": len(ended), **numbers,
+               "correct": verdict(run.checks[mark:])}
+        if controlled and numbers:
+            # the control in the program's place: its own two numbers
+            # against the same limits, by the same comparison
+            at = len(run.checks)
+            for name in serving.COMPARED:
+                run.check("control_" + name, numbers["control_" + name],
+                          limit_key=name)
+            row["control_correct"] = verdict(run.checks[at:])
+        row["checks"] = {
+            name: {"value": value if value == value else None,
+                   "limit": limit, "ok": ok}
+            for name, value, limit, ok in run.checks[mark:]}
+        emit(row)
+
+
+def verdict(checks):
+    """`correct` as `Run.result` decides it, of some of a run's checks."""
+    return bool(checks) and all(ok for *_, ok in checks)
 
 
 def main(argv=None):

@@ -14,6 +14,7 @@ import config_rules  # noqa: E402
 import tiny_root  # noqa: E402
 
 from benchmark import harness, run as bench_run  # noqa: E402
+from benchmark.loops import serving  # noqa: E402
 
 REPO = tiny_root.REPO
 FAKE_TRACE = {"window_s": 1.0, "devices": 1, "busy_s": 0.9,
@@ -36,12 +37,6 @@ def fake_trace(monkeypatch):
         yield
         self.evidence["trace"] = dict(FAKE_TRACE)
     monkeypatch.setattr(harness.Run, "traced_slice", traced_slice)
-
-
-@pytest.fixture
-def root(tmp_path):
-    tiny_root.make(str(tmp_path))
-    return str(tmp_path)
 
 
 def _files(top):
@@ -173,17 +168,6 @@ def test_new_configuration_mix_and_metric_are_files_of_their_own(
     assert _files(os.path.join(REPO, "benchmark")) == repo_before
 
 
-OTHER_MODEL = {
-    "hidden_size": 32, "intermediate_size": 48, "num_hidden_layers": 2,
-    "num_attention_heads": 4, "rms_norm_eps": 1e-6, "vocab_size": 128,
-    "initializer_range": 0.02, "tie_word_embeddings": False,
-    "precision": {"params": "float32", "activations": "float32",
-                  "optimizer_state": "float32", "control": "bfloat16"},
-    "optimizer": tiny_root.TINY_MODEL["optimizer"],
-    "program": "other_program", "reference": "other_reference",
-}
-
-
 @pytest.fixture
 def other_model(root, monkeypatch):
     """A configuration of ANOTHER model, added to the tiny root as files
@@ -192,25 +176,12 @@ def other_model(root, monkeypatch):
     appended to `train_tokens_per_s`'s `workloads`."""
     monkeypatch.syspath_prepend(os.path.join(os.path.dirname(
         os.path.abspath(__file__)), "other_model"))
-    data = os.path.join(root, "benchmark")
-    tiny_root._dump(os.path.join(data, "configs", "other.json"), OTHER_MODEL)
-    tiny_root._dump(os.path.join(data, "traffic", "other_mix.json"),
-                    dict(tiny_root.TRAFFIC["tiny_train"], batch_rows=2,
-                         seq=16))
-    tiny_root._dump(os.path.join(data, "limits", "other_cell.json"),
-                    tiny_root.TRAIN_LIMITS)
-    spec = json.load(open(os.path.join(root, "BENCHMARK.json")))
-    spec["configs"].append({"name": "other", "source": "test",
-                            "file": "benchmark/configs/other.json",
-                            "reduced": [], "why": "test"})
-    spec["workloads"].append({"name": "other_cell", "config": "other",
-                              "traffic": "other_mix", "chips": 1,
-                              "why": "test"})
-    for m in spec["end_to_end"] + spec["per_layer"]:
-        if m["name"] in ("train_tokens_per_s", "train.stall_share"):
-            m["workloads"].append("other_cell")
-    tiny_root._dump(os.path.join(root, "BENCHMARK.json"), spec)
-    return OTHER_MODEL
+    tiny_root.add_cell(
+        root, "other_cell", ("other", tiny_root.OTHER_MODEL),
+        ("other_mix", dict(tiny_root.TRAFFIC["tiny_train"], batch_rows=2,
+                           seq=16)),
+        tiny_root.TRAIN_LIMITS, ("train_tokens_per_s", "train.stall_share"))
+    return tiny_root.OTHER_MODEL
 
 
 def test_a_configuration_of_another_model_arrives_as_files_and_entries(
@@ -361,6 +332,9 @@ def test_an_altered_served_token_is_not_correct(root, monkeypatch):
     line = bench_run.run_cell(root, "tiny_backlog_cell", seed=6,
                               seconds=0.5, traced=False, require_chip=False)
     assert line["correct"] is False
+    # one wrong token in a stream: the widest gap is what catches it
+    widest = line["checks"]["logit_gap"]
+    assert widest["value"] > widest["limit"]
 
 
 # -- BENCHMARK.json against the contract's limits ----------------------------
@@ -443,8 +417,13 @@ def test_every_cell_finds_its_files_and_reports_what_it_must(spec):
         loop = json.load(open(mix))["loop"]
         assert os.path.exists(os.path.join(REPO, "benchmark", "loops",
                                            loop + ".py"))
-        assert os.path.exists(os.path.join(REPO, "benchmark", "limits",
-                                           w["name"] + ".json"))
+        limits = json.load(open(os.path.join(REPO, "benchmark", "limits",
+                                             w["name"] + ".json")))
+        if loop.startswith("serve"):
+            # a serve cell compares both numbers, each against its own
+            # limit: a file without either makes the cell incorrect
+            assert set(serving.COMPARED) <= set(limits), w["name"]
+            assert 0 < limits["logit_gap_mean"] < limits["logit_gap"]
         mine = [m for m in spec["end_to_end"]
                 if w["name"] in m.get("workloads", cells)]
         assert len(mine) >= 2 and any(m["name"] == "setup_s" for m in mine)

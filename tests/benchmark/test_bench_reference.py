@@ -165,6 +165,9 @@ def test_served_tokens_are_the_references_choice(served):
     # the reference's best logit (logits here are below 1 in magnitude)
     assert worst["logit_gap"] < 0.02
     assert worst["control_logit_gap"] > 3 * max(worst["logit_gap"], 1e-3)
+    # the mean over the 64 tokens, the other number compared: the engine's
+    # tokens are all the reference's best, one of the control's is not
+    assert worst["control_logit_gap_mean"] > worst["logit_gap_mean"] == 0.0
 
 
 def test_an_altered_token_is_caught(served):

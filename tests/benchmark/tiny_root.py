@@ -24,6 +24,17 @@ TINY_MODEL = {
     "program": "benchmark.programs.paddle_gpt",
     "reference": "benchmark.reference.gpt",
 }
+# ANOTHER model than GPT (its reference and program are kept in
+# `other_model/`): other key names, float32, so its control is bfloat16
+OTHER_MODEL = {
+    "hidden_size": 32, "intermediate_size": 48, "num_hidden_layers": 2,
+    "num_attention_heads": 4, "rms_norm_eps": 1e-6, "vocab_size": 128,
+    "initializer_range": 0.02, "tie_word_embeddings": False,
+    "precision": {"params": "float32", "activations": "float32",
+                  "optimizer_state": "float32", "control": "bfloat16"},
+    "optimizer": TINY_MODEL["optimizer"],
+    "program": "other_program", "reference": "other_reference",
+}
 TINY_SERVE = {
     "engine": {"max_batch_size": 4, "block_size": 4, "max_context": 64},
     "prefill_buckets": [8, 16], "compile_tokens": 2,
@@ -49,7 +60,7 @@ CELLS = [("tiny_train_cell", "tiny_train", 1), ("tiny_mesh_cell", "tiny_mesh", 4
          ("tiny_backlog_cell", "tiny_backlog", 1)]
 TRAIN_LIMITS = {"loss_gap": 1e-3, "grad_norm_gap": 0.05,
                 "delta_norm_gap": 0.05, "state_max_share": 0.75}
-SERVE_LIMITS = {"logit_gap": 0.05}
+SERVE_LIMITS = {"logit_gap": 0.05, "logit_gap_mean": 0.005}
 
 
 def _dump(path, obj):
@@ -92,3 +103,25 @@ def make(root):
         per_layer=[renamed(m) for m in real["per_layer"]])
     _dump(os.path.join(root, "BENCHMARK.json"), spec)
     return spec
+
+
+def add_cell(root, cell, config, mix, limits, metrics):
+    """One more cell in the root under `root`, added as a later PR adds
+    one: files of its own and entries appended, nothing there edited.
+    `config` and `mix` are (name, dict); `metrics` names the end-to-end
+    and per-layer metrics whose `workloads` gain the cell."""
+    data = os.path.join(root, "benchmark")
+    _dump(os.path.join(data, "configs", config[0] + ".json"), config[1])
+    _dump(os.path.join(data, "traffic", mix[0] + ".json"), mix[1])
+    _dump(os.path.join(data, "limits", cell + ".json"), limits)
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        spec = json.load(f)
+    spec["configs"].append({
+        "name": config[0], "source": "test", "reduced": [], "why": "test",
+        "file": f"benchmark/configs/{config[0]}.json"})
+    spec["workloads"].append({"name": cell, "config": config[0],
+                              "traffic": mix[0], "chips": 1, "why": "test"})
+    for m in spec["end_to_end"] + spec["per_layer"]:
+        if m["name"] in metrics:
+            m["workloads"].append(cell)
+    _dump(os.path.join(root, "BENCHMARK.json"), spec)
