@@ -24,14 +24,26 @@ semantics:
     int8 pools dequantize inside the load (`q * scale / 127`), so the
     fp values exist only in VMEM. Length masking keeps the null-block
     branch-free contract: padded/inactive table entries read block 0 and
-    their scores are masked, never branched on. Runs under
+    their scores are masked, never branched on: this kernel visits all
+    ``S x M`` entries whatever the lengths. Runs under
     ``interpret=True`` on CPU for the fused-vs-reference parity tests.
-  * `blockwise_paged_attention` — pure-JAX `lax.scan` over block chunks
-    with the same online-softmax recurrence. This is the CPU/parity
-    fallback AND a standalone win: it replaces the dense gather's
-    ``[S, T, H, D]`` materialization with cache-resident chunks, so it
-    beats the gather on the serve CPU legs from seq ~1k up
-    (tools/perf_smoke.py leg j guards the floor).
+  * `blockwise_paged_attention` — a pure-JAX loop over block chunks with
+    the same online-softmax recurrence, BOUNDED BY THE LENGTHS: the
+    loop stops after the chunk that holds the longest slot's newest
+    token, and with enough slots they are ordered longest first and a
+    step reads its chunk only for the first half, quarter, ... of them
+    when no other slot reaches that chunk (a trip count and a branch
+    index the device reads; one program whatever the lengths). Table
+    entries a step leaves out are not read at all; inside a step,
+    positions past a slot's own length and the entries of an inactive
+    slot's one chunk still read what the table names (the null block,
+    once cleared) and are masked. This is the
+    default variant on every platform, the CPU/parity fallback AND a
+    standalone win: it replaces the dense gather's ``[S, T, H, D]``
+    materialization with cache-resident chunks, so it beats the gather
+    on the serve CPU legs from seq ~1k up (tools/perf_smoke.py leg j
+    guards the floor). `blockwise_streamed_entries` is the host's count
+    of what that loop reads, from the same plan and step widths.
 
 Numerics: scores, the softmax recurrence, and the output accumulator are
 fp32 regardless of the query/pool dtype; only the final output casts back
@@ -57,19 +69,28 @@ except Exception:  # pragma: no cover
 from .._common import ZERO as _ZERO, on_tpu as _on_tpu
 from ...quantization.kv_cache import QMAX as _QMAX, dequantize as _dequant
 
-__all__ = ["blockwise_paged_attention", "pallas_paged_attention",
-           "is_eligible"]
+__all__ = ["blockwise_paged_attention", "blockwise_streamed_entries",
+           "pallas_paged_attention", "is_eligible"]
 
 _NEG_INF = -1e30
 
-# blockwise scan chunking: gather KV per scan step in chunks targeting
-# this many BYTES per pool side (multiple pool blocks per step when
-# block_size is small) — big enough to amortize the scan-iteration
+# blockwise chunking: gather KV per loop step in chunks targeting this
+# many BYTES per pool side and slot (multiple pool blocks per step when
+# block_size is small) — big enough to amortize the loop-iteration
 # overhead, small enough to stay cache-resident instead of
-# re-materializing the dense context. Tokens are capped so tiny-head
-# shapes don't degenerate into one dense chunk
+# re-materializing the dense context, and the grain of the loop's bound
+# (it streams whole chunks). Tokens are capped so tiny-head shapes don't
+# degenerate into one dense chunk
 _CHUNK_TARGET_BYTES = 256 * 1024
 _CHUNK_TOKENS_MAX = 512
+
+# blockwise widths: a loop step reads its chunk for all the slots or for
+# the first half, quarter, ... of them (ordered longest first), whichever
+# is the narrowest that holds every slot with a token in that chunk; no
+# width is under this many slots. Each width is one more traced copy of
+# the step, and a narrow step pays the step's fixed cost for few bytes;
+# PERF.md section 7 has the chip readings that chose it.
+_MIN_WIDTH_SLOTS = 64
 
 
 # Largest [block_size, H*D] pool block (in elements, the row padded to whole
@@ -97,65 +118,145 @@ def is_eligible(num_heads, head_dim, block_size):
 
 
 # ---------------------------------------------------------------------------
-# pure-JAX blockwise reference path (lax.scan over block chunks)
+# pure-JAX blockwise path: a length-bounded loop over block chunks
 # ---------------------------------------------------------------------------
+
+def _blockwise_plan(num_slots, table_entries, block_size, num_heads,
+                    head_dim, chunk_blocks=None):
+    """The static shape of the blockwise loop, from the call's shapes
+    alone: ``(widths, chunk_blocks, n_chunks)``. `widths` are the slot
+    counts a loop step can run at, widest first: all the slots, then
+    halves (rounded up) while a half still holds `_MIN_WIDTH_SLOTS`: so
+    one width, and a loop that only stops at the longest context, up
+    to about twice that many slots."""
+    if chunk_blocks is None:
+        per_token = num_heads * head_dim * jnp.dtype(jnp.float32).itemsize
+        tokens = min(max(_CHUNK_TARGET_BYTES // per_token, block_size),
+                     _CHUNK_TOKENS_MAX)
+        chunk_blocks = max(1, int(tokens) // block_size)
+    chunk_blocks = min(int(chunk_blocks), table_entries)
+    n_chunks = -(-table_entries // chunk_blocks)
+    widths = [num_slots]
+    while -(-widths[-1] // 2) >= _MIN_WIDTH_SLOTS:
+        widths.append(-(-widths[-1] // 2))
+    return tuple(widths), chunk_blocks, n_chunks
+
+
+def _step_widths(lens, widths, chunk_tokens, n_chunks, xp):
+    """What each loop step runs at, in `xp` (jax.numpy inside the program,
+    numpy for the host's count, so the two cannot drift). lens: ``[S]``,
+    SORTED longest first where there is more than one width. Returns
+    ``(trips, which)``: `trips` the chunks the loop streams (those that
+    reach the longest slot's newest token, at least one), `which`
+    ``[n_chunks]`` the index into `widths` of the narrowest width that
+    holds every slot with a token in that chunk (slot r has one in
+    chunk c iff ``lens[r] // chunk_tokens >= c``; the slots are sorted,
+    so those are the first so many)."""
+    last = lens.astype(xp.int32) // chunk_tokens        # [S] last chunk
+    trips = xp.clip(last.max() + 1, 1, n_chunks)
+    chunks = xp.arange(n_chunks, dtype=xp.int32)
+    # chunk 0 is every slot's (an empty slot reads the null block there)
+    need = xp.where(chunks == 0, lens.shape[0],
+                    (last[None, :] >= chunks[:, None]).sum(axis=1))
+    fits = xp.asarray(widths, dtype=xp.int32)[None, :] >= need[:, None]
+    which = fits.sum(axis=1) - 1                        # widths descend
+    return trips.astype(xp.int32), which.astype(xp.int32)
+
+
+def blockwise_streamed_entries(lens, active, table_entries, block_size,
+                               num_heads, head_dim):
+    """The host's count of one decode step's attention, in table entries
+    summed over the slots: ``(streamed, held)``. `streamed` is what
+    `blockwise_paged_attention`'s loop reads for these lengths (the same
+    plan and the same step widths as the program's), `held` the entries
+    that hold a token some slot attends to. Both are at most
+    ``len(lens) * table_entries``, what a loop over the whole table reads.
+    lens/active: numpy ``[S]``, as the engine keeps them."""
+    active = np.asarray(active, bool)
+    eff = np.where(active, np.asarray(lens, np.int64), 0)
+    widths, chunk_blocks, n_chunks = _blockwise_plan(
+        eff.shape[0], table_entries, block_size, num_heads, head_dim)
+    trips, which = _step_widths(-np.sort(-eff), widths,
+                                chunk_blocks * block_size, n_chunks, np)
+    # the last chunk may reach past the table: those entries are fill
+    entries = np.minimum(chunk_blocks, table_entries
+                         - np.arange(n_chunks) * chunk_blocks)
+    streamed = int((np.asarray(widths)[which] * entries)[:trips].sum())
+    held = int((eff // block_size + 1)[active].sum())
+    return streamed, held
+
 
 def blockwise_paged_attention(q, k_pools, v_pools, layer, block_tables,
                               lens, block_size, k_scales=None, v_scales=None,
                               chunk_blocks=None):
-    """Online-softmax paged attention, one KV chunk at a time.
+    """Online-softmax paged attention, one KV chunk at a time, over the
+    chunks and the slots that hold tokens.
 
     q: ``[S, H, D]`` this step's queries; k_pools/v_pools:
     ``[L, num_blocks, bs, H*D]`` (fp, or int8 with `k_scales`/`v_scales`
     ``[L, num_blocks, H]``) and `layer` the one to read; block_tables:
     ``[S, M]`` int32; lens: ``[S]`` int32 EFFECTIVE lengths (position p
     attends iff p <= lens[s]; inactive slots pass 0). Returns
-    ``[S, H, D]`` in q's dtype. Each scan step gathers
+    ``[S, H, D]`` in q's dtype. Each loop step gathers
     ``pool[layer, block ids]`` and splits the GATHERED rows into heads:
     neither a layer of the pool nor the pool in another shape is ever a
     value, so the program reads a donated pool where it lies.
+
+    The loop stops after the chunk that holds the longest slot's newest
+    token. Where `_blockwise_plan` gives more than one width, the slots
+    are ordered longest first and a step reads its chunk for the first
+    W of them only, W the narrowest of those static widths that holds
+    every slot with a token in that chunk (`_step_widths`, read on the
+    device: the shapes, and so the compiled program, do not depend on
+    the lengths). A slot's chunk that is not read adds exactly nothing
+    to the recurrence, so every slot's output is what a loop over the
+    whole table gives. Inside a step, positions past a slot's own length
+    are still gathered and masked; an inactive slot reads the null block
+    through one chunk.
     """
     s, h, d = q.shape
     m = block_tables.shape[1]
     bs = int(block_size)
     quant = k_scales is not None
-    if chunk_blocks is None:
-        per_token = h * d * jnp.dtype(jnp.float32).itemsize
-        tokens = min(max(_CHUNK_TARGET_BYTES // per_token, bs),
-                     _CHUNK_TOKENS_MAX)
-        chunk_blocks = max(1, int(tokens) // bs)
-    chunk_blocks = min(int(chunk_blocks), m)
-    n_chunks = -(-m // chunk_blocks)
-    pad = n_chunks * chunk_blocks - m
-    tables = block_tables
-    if pad:
-        # padded entries read the null block; their positions exceed
-        # every possible length, so the mask kills them
-        tables = jnp.pad(tables, ((0, 0), (0, pad)))
-    # [n_chunks, S, C]: scan consumes chunks along the leading axis
-    tabs = jnp.swapaxes(
-        tables.reshape(s, n_chunks, chunk_blocks), 0, 1)
-    q32 = q.astype(jnp.float32) * (1.0 / math.sqrt(d))
+    widths, chunk_blocks, n_chunks = _blockwise_plan(
+        s, m, bs, h, d, chunk_blocks)
     t_chunk = chunk_blocks * bs
+    lens = lens.astype(jnp.int32)
+    q32 = q.astype(jnp.float32) * (1.0 / math.sqrt(d))
+    tables = block_tables
+    order = None
+    if len(widths) > 1:
+        order = jnp.argsort(-lens, stable=True).astype(jnp.int32)
+        q32, lens, tables = q32[order], lens[order], tables[order]
+    trips, which = _step_widths(lens, widths, t_chunk, n_chunks, jnp)
+    # table entries past M (the last chunk's fill) read the null block;
+    # their positions exceed every length, so the mask kills them.
+    # Chunks along the leading axis: [n_chunks, S, C]
+    tabs = jnp.swapaxes(
+        jnp.pad(tables, ((0, 0), (0, n_chunks * chunk_blocks - m)))
+        .reshape(s, n_chunks, chunk_blocks), 0, 1)
     offs = jnp.arange(t_chunk, dtype=jnp.int32)
 
-    def step(carry, xs):
-        acc, mx, l = carry
-        ci, bids = xs                                   # [], [S, C]
-        kc = k_pools[layer, bids]                       # [S, C, bs, H*D]
+    def attend(w, ci, bids, carry):
+        """Chunk `ci`, whose block ids are `bids` [S, C], of the first
+        `w` slots (a static count)."""
+        acc_all, mx_all, l_all = carry
+        acc, mx, l = acc_all[:w], mx_all[:w], l_all[:w]
+        bids = bids[:w]                                 # [w, C]
+        kc = k_pools[layer, bids]                       # [w, C, bs, H*D]
         vc = v_pools[layer, bids]
         if quant:
-            split = (s, chunk_blocks, bs, h, d)
+            split = (w, chunk_blocks, bs, h, d)
             kc = _dequant(kc.reshape(split), k_scales[layer, bids])
             vc = _dequant(vc.reshape(split), v_scales[layer, bids])
         else:
             kc = kc.astype(jnp.float32)
             vc = vc.astype(jnp.float32)
-        kc = kc.reshape(s, t_chunk, h, d)
-        vc = vc.reshape(s, t_chunk, h, d)
-        scores = jnp.einsum("shd,sthd->sht", q32, kc)
+        kc = kc.reshape(w, t_chunk, h, d)
+        vc = vc.reshape(w, t_chunk, h, d)
+        scores = jnp.einsum("shd,sthd->sht", q32[:w], kc)
         pos = ci * t_chunk + offs
-        valid = pos[None, :] <= lens[:, None]           # [S, t]
+        valid = pos[None, :] <= lens[:w, None]          # [w, t]
         scores = jnp.where(valid[:, None, :], scores,
                            jnp.float32(_NEG_INF))
         m_new = jnp.maximum(mx, jnp.max(scores, axis=-1))
@@ -167,15 +268,27 @@ def blockwise_paged_attention(q, k_pools, v_pools, layer, block_tables,
         l = alpha * l + jnp.sum(p, axis=-1)
         acc = acc * alpha[..., None] \
             + jnp.einsum("sht,sthd->shd", p, vc)
-        return (acc, m_new, l), None
+        return (acc_all.at[:w].set(acc), mx_all.at[:w].set(m_new),
+                l_all.at[:w].set(l))
+
+    branches = [functools.partial(attend, w) for w in widths]
+
+    def step(ci, carry):
+        if order is None:                               # one width
+            return branches[0](ci, tabs[ci], carry)
+        return jax.lax.switch(which[ci], branches, ci, tabs[ci], carry)
 
     acc0 = jnp.zeros((s, h, d), jnp.float32)
     m0 = jnp.full((s, h), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((s, h), jnp.float32)
-    (acc, _, l), _ = jax.lax.scan(
-        step, (acc0, m0, l0),
-        (jnp.arange(n_chunks, dtype=jnp.int32), tabs))
+    # a traced bound: a while loop whose trip count the device reads
+    acc, _, l = jax.lax.fori_loop(0, trips, step, (acc0, m0, l0))
     out = acc / jnp.maximum(l, 1e-30)[..., None]
+    if order is not None:
+        # back to the callers' slot order
+        back = jnp.zeros_like(order).at[order].set(
+            jnp.arange(s, dtype=jnp.int32))
+        out = out[back]
     return out.astype(q.dtype)
 
 

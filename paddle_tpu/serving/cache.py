@@ -27,7 +27,15 @@ PagedAttention memory model (vLLM, SOSP'23) rebuilt TPU-native:
 Block 0 is reserved as the *null block*: inactive batch slots and padded
 table entries point at it, so in-graph gathers/scatters never need a
 branch — garbage goes to (and comes from) block 0 and is masked out of
-the attention softmax.
+the attention softmax. How much of a table a decode step READS is the
+attention variant's affair: the blockwise loop
+(kernels/pallas/paged_attention.py) stops at the batch's longest context
+and, with enough slots, leaves the shorter half of them out of the
+chunks only longer ones reach, so those entries are never gathered;
+entries it does read that lie past a slot's own length, and the one
+chunk an inactive slot is read through, still come from the table (the
+null block for padding) and are masked. The Pallas kernel and the dense
+reference read every entry and mask.
 
 The device side of the design lives in
 `nn/functional/attention.py::paged_decode_attention` (gather-by-block-table

@@ -112,6 +112,7 @@ from ..profiler.metrics import LogHistogram, SERVE as _M, \
 from ..profiler import goodput as _goodput
 from ..profiler import telemetry_server as _telemetry
 from ..profiler import sentinel as _sentinel
+from ..kernels.pallas.paged_attention import blockwise_streamed_entries
 from .cache import PagedKVCache, PagedCacheView, scatter_prefill, _is_int8
 from .scheduler import (Request, Scheduler, QUEUED, RUNNING, FINISHED,
                         FAILED, CANCELLED, EXPIRED)
@@ -198,6 +199,13 @@ class ServeStats:
         # expired / preempted / finished between launch and commit
         self.sampled_tokens = 0
         self.commit_rollbacks = 0
+        # decode attention, in block-table entries summed over the decode
+        # launches: what the attention's loops read, what held a token,
+        # and slots x entries (kernels/pallas/paged_attention.py
+        # blockwise_streamed_entries, counted on the host every launch)
+        self.attn_entries_streamed = 0
+        self.attn_entries_held = 0
+        self.attn_entries_total = 0
         # recent raw samples only (the admission wait estimate averages
         # the tail); percentiles live in the windowed histograms below
         self.step_times_s = []
@@ -262,6 +270,15 @@ class ServeStats:
             "weight_swaps": self.weight_swaps,
             "sampled_tokens": self.sampled_tokens,
             "commit_rollbacks": self.commit_rollbacks,
+            # share of the block table the decode attention read / that
+            # held tokens (streamed == held is the ideal, 1.0 a loop over
+            # the whole table)
+            "attn_streamed_share": (
+                self.attn_entries_streamed / self.attn_entries_total
+                if self.attn_entries_total else 0.0),
+            "attn_held_share": (
+                self.attn_entries_held / self.attn_entries_total
+                if self.attn_entries_total else 0.0),
             "occupancy_mean": (self.occupancy_sum / self.steps
                                if self.steps else 0.0),
             "occupancy_saturated": (
@@ -1606,11 +1623,26 @@ class LLMEngine:
                         np.asarray(res[2]), np.asarray(res[3]))
 
     def _call_decode(self, args):
+        self._count_attention(args[2], args[3])
         fn = self._decode_fn
         res = self._call_program("engine.decode.dispatch", fn, args,
                                  fn is not self._decode_called)
         self._decode_called = fn
         return res
+
+    def _count_attention(self, lens, active):
+        """One decode launch's attention in block-table entries, from
+        the lengths and the mask the launch is given. Only the blockwise
+        loop follows the lengths; the other variants read every entry."""
+        streamed, held = blockwise_streamed_entries(
+            lens, active, self.max_blocks_per_seq, self.block_size,
+            self.cache.num_heads, self.cache.head_dim)
+        total = self.max_batch_size * self.max_blocks_per_seq
+        stats = self._stats
+        stats.attn_entries_streamed += (
+            streamed if self._attn_kernel == "blockwise" else total)
+        stats.attn_entries_held += held
+        stats.attn_entries_total += total
 
     def _sampler_args(self):
         """The decode signature's per-slot sampler VALUE inputs, in

@@ -23,6 +23,11 @@ Contracts pinned here:
     env fingerprint / decode digest (kernel flips never deserialize a
     stale artifact); kernel fallbacks are attributed `kernel.fallback`
     events, never silent;
+  * the length-bounded blockwise loop (ISSUE 29) — bitwise the same
+    recurrence run over every table entry, whatever the mix of
+    lengths; entries a step leaves out are not read (NaN behind them
+    stays there); the engine's host-side count of streamed entries
+    is the device loop's own trip count and step widths;
   * perf floors (perf_smoke) — blockwise beats the dense gather at
     seq >= 1k on CPU, and an int8 engine compiles decode exactly once
     under churn.
@@ -46,6 +51,10 @@ from paddle_tpu.nn.functional.attention import (_dense_gather_attention,
                                                 paged_decode_attention,
                                                 resolve_paged_kernel,
                                                 PAGED_KERNELS)
+from paddle_tpu.kernels.pallas import paged_attention
+from paddle_tpu.kernels.pallas.paged_attention import (
+    _blockwise_plan, _step_widths, blockwise_paged_attention,
+    blockwise_streamed_entries)
 from paddle_tpu.quantization.kv_cache import (QMAX, quantize_scatter,
                                               quantize_block_write,
                                               dequantize)
@@ -391,6 +400,294 @@ class TestInt8KV:
         n_fp32 = admitted(None, jnp.float32)
         n_int8 = admitted("int8", jnp.int8)
         assert n_int8 >= 1.8 * n_fp32, (n_int8, n_fp32)
+
+
+# ---------------------------------------------------------------------------
+# the blockwise loop is bounded by the lengths it is given (ISSUE 29)
+# ---------------------------------------------------------------------------
+
+def _every_entry_attention(q, k_pools, v_pools, layer, block_tables, lens,
+                           block_size, k_scales=None, v_scales=None,
+                           chunk_blocks=1):
+    """The online-softmax recurrence run over EVERY table entry of every
+    slot, whatever the lengths: `blockwise_paged_attention` as it was
+    before its loop was bounded, kept here as the yardstick the bounded
+    loop is bitwise equal to at the same chunk size."""
+    s, h, d = q.shape
+    m = block_tables.shape[1]
+    bs = int(block_size)
+    n_chunks = -(-m // chunk_blocks)
+    tables = jnp.pad(block_tables,
+                     ((0, 0), (0, n_chunks * chunk_blocks - m)))
+    tabs = jnp.swapaxes(tables.reshape(s, n_chunks, chunk_blocks), 0, 1)
+    q32 = q.astype(jnp.float32) * (1.0 / np.sqrt(d)).astype(np.float32)
+    t_chunk = chunk_blocks * bs
+    offs = jnp.arange(t_chunk, dtype=jnp.int32)
+
+    def step(carry, xs):
+        acc, mx, l = carry
+        ci, bids = xs
+        kc, vc = k_pools[layer, bids], v_pools[layer, bids]
+        if k_scales is not None:
+            split = (s, chunk_blocks, bs, h, d)
+            kc = dequantize(kc.reshape(split), k_scales[layer, bids])
+            vc = dequantize(vc.reshape(split), v_scales[layer, bids])
+        kc = kc.astype(jnp.float32).reshape(s, t_chunk, h, d)
+        vc = vc.astype(jnp.float32).reshape(s, t_chunk, h, d)
+        scores = jnp.einsum("shd,sthd->sht", q32, kc)
+        valid = (ci * t_chunk + offs)[None, :] <= lens[:, None]
+        scores = jnp.where(valid[:, None, :], scores, jnp.float32(-1e30))
+        m_new = jnp.maximum(mx, jnp.max(scores, axis=-1))
+        p = jnp.where(valid[:, None, :],
+                      jnp.exp(scores - m_new[..., None]), 0.0)
+        alpha = jnp.exp(mx - m_new)
+        l = alpha * l + jnp.sum(p, axis=-1)
+        acc = acc * alpha[..., None] + jnp.einsum("sht,sthd->shd", p, vc)
+        return (acc, m_new, l), None
+
+    init = (jnp.zeros((s, h, d), jnp.float32),
+            jnp.full((s, h), -1e30, jnp.float32),
+            jnp.zeros((s, h), jnp.float32))
+    (acc, _, l), _ = jax.lax.scan(
+        step, init, (jnp.arange(n_chunks, dtype=jnp.int32), tabs))
+    return (acc / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype)
+
+
+# table of 12 entries of 4 tokens, read in chunks of 2 entries = 8 tokens:
+# six chunks, so a bound has something to cut
+BOUND_BS, BOUND_M, BOUND_CHUNK = 4, 12, 2
+BOUND_MAX = BOUND_BS * BOUND_M - 1          # a slot at max_context
+
+# name -> (lens, active); None = every slot active
+LENGTH_MIXES = {
+    "all_inactive": ((5, 17, 0, 40, 9, 9, 31, 2), (False,) * 8),
+    "one_at_max_context_among_short": (
+        (3, 1, 6, 2) * 4 + (BOUND_MAX,) + (4, 0, 7) * 5, None),
+    "block_and_chunk_boundaries": (
+        tuple(b * BOUND_BS + e for b in (1, 2, 3, 4, 6, 11)
+              for e in (-1, 0)) + (0, 1, 7, 8, 9, 15, 16, 47) * 3, None),
+    "odd_slot_count_some_inactive": (
+        tuple(int(x) for x in np.random.default_rng(29).integers(
+            0, BOUND_MAX + 1, 37)),
+        tuple(bool(x) for x in np.random.default_rng(30).random(37) < 0.8)),
+    "one_slot": ((21,), None),
+    "eight_slots": ((0, 4, 8, 23, 31, 5, 12, 40), None),
+    "the_cells_128_slots": (
+        tuple(int(x) for x in np.random.default_rng(31).integers(
+            0, BOUND_MAX + 1, 128) // np.random.default_rng(32).integers(
+                1, 5, 128)), None),
+}
+
+
+def _bounded_case(lens, active, pool, seed=0):
+    active = (True,) * len(lens) if active is None else active
+    H = 3
+    q, _, _, kp, vp, tables, lens, active = _paged_state(
+        S=len(lens), H=H, bs=BOUND_BS, M=BOUND_M, lens=lens, active=active,
+        seed=seed, dtype=jnp.bfloat16)
+    scales = ()
+    if pool == "int8":
+        (kp, ks), (vp, vs) = _quantized(kp, H), _quantized(vp, H)
+        scales = (ks, vs)
+    eff = jnp.where(active, lens, 0).astype(jnp.int32)
+    return q[:, 0], kp, vp, tables, eff, scales
+
+
+@pytest.fixture(params=[None, 8], ids=["widths_as_shipped", "widths_to_8"])
+def min_width(request, monkeypatch):
+    """The narrowest step as shipped (one width under 128 slots), and 8:
+    three and four widths at the tests' slot counts."""
+    if request.param is not None:
+        monkeypatch.setattr(paged_attention, "_MIN_WIDTH_SLOTS",
+                            request.param)
+    return paged_attention._MIN_WIDTH_SLOTS
+
+
+class TestLengthBoundedLoop:
+    @pytest.mark.parametrize("pool", ("bf16", "int8"))
+    @pytest.mark.parametrize("mix", sorted(LENGTH_MIXES))
+    def test_bounded_loop_is_the_every_entry_recurrence(self, mix, pool,
+                                                        min_width):
+        """Whatever the mix of lengths, the loop that leaves out what no
+        slot holds gives BITWISE what the same recurrence gives over
+        every table entry at the same chunk size (a chunk that is not
+        read adds exactly nothing), and the dense oracle's values."""
+        q, kp, vp, tables, eff, scales = _bounded_case(
+            *LENGTH_MIXES[mix], pool)
+        args = (q, kp, vp, LAYER, tables, eff, BOUND_BS) + scales
+        got = blockwise_paged_attention(*args, chunk_blocks=BOUND_CHUNK)
+        every = _every_entry_attention(*args, chunk_blocks=BOUND_CHUNK)
+        assert got.dtype == q.dtype and got.shape == q.shape
+        assert np.array_equal(np.asarray(got, np.float32),
+                              np.asarray(every, np.float32))
+        oracle = _dense_gather_attention(*args)
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(oracle, np.float32),
+                                   rtol=0.0, atol=2e-2)
+        # another chunk size: the same values in another summation order
+        other = blockwise_paged_attention(*args, chunk_blocks=5)
+        np.testing.assert_allclose(np.asarray(other, np.float32),
+                                   np.asarray(every, np.float32),
+                                   rtol=0.0, atol=2e-2)
+
+    def test_the_plan_follows_the_shapes(self, monkeypatch):
+        """One width for the 8-slot engines (the loop only stops at the
+        longest context), all the slots or the longer half at the backlog
+        cell's 128, the chunk the head shape gave before."""
+        assert _blockwise_plan(8, 64, 16, 12, 64) == ((8,), 5, 13)
+        assert _blockwise_plan(126, 64, 16, 12, 64) == ((126,), 5, 13)
+        assert _blockwise_plan(128, 64, 16, 12, 64) == ((128, 64), 5, 13)
+        assert _blockwise_plan(130, 12, 4, 3, 16, 2) == ((130, 65), 2, 6)
+        assert _blockwise_plan(1000, 12, 4, 3, 16, 99)[0] == (
+            1000, 500, 250, 125)
+        monkeypatch.setattr(paged_attention, "_MIN_WIDTH_SLOTS", 8)
+        assert _blockwise_plan(37, 12, 4, 3, 16, 2) == ((37, 19, 10), 2, 6)
+        assert _blockwise_plan(1, 12, 4, 3, 16, 2) == ((1,), 2, 6)
+        assert _blockwise_plan(31, 12, 4, 3, 16, 99) == ((31, 16, 8), 12, 1)
+
+    @pytest.mark.parametrize("pool", ("bf16", "int8"))
+    def test_entries_a_step_leaves_out_are_not_read(self, pool):
+        """NaN in the V rows of pool blocks that only unread entries point
+        to (a slot's chunks past the loop's bound, and its chunks in steps
+        narrower than its rank): the bounded loop never gathers them, so
+        its output is finite and bitwise the clean pool's; the loop over
+        every entry multiplies them by a zero probability and gets NaN."""
+        S = 128                     # widths 128 and 64, as the cell's
+        lens = tuple(i % 11 for i in range(64)) + tuple(
+            17 + i % 20 for i in range(64))
+        q, kp, vp, tables, eff, scales = _bounded_case(lens, None, pool)
+        widths, chunk, n_chunks = _blockwise_plan(
+            S, BOUND_M, BOUND_BS, 3, 16, BOUND_CHUNK)
+        assert widths == (128, 64)
+        order = np.argsort(-np.asarray(eff), kind="stable")
+        trips, which = _step_widths(np.asarray(eff)[order], widths,
+                                    chunk * BOUND_BS, n_chunks, np)
+        # 36 // 8 + 1 chunks; from the third on only the 64 longest
+        assert int(trips) == 5
+        assert [widths[i] for i in which[:trips]] == [128, 128, 64, 64, 64]
+        read = np.zeros((S, n_chunks), bool)
+        for ci in range(int(trips)):
+            read[order[:widths[which[ci]]], ci] = True
+        poisoned = np.zeros(kp.shape[1], bool)
+        t = np.asarray(tables).reshape(S, n_chunks, chunk)
+        poisoned[t[~read]] = True
+        assert poisoned.sum() == (64 * 4 + 64 * 1) * chunk
+        bad = jnp.asarray(np.where(poisoned[None, :, None, None],
+                                   np.nan if pool == "bf16" else 0,
+                                   np.asarray(vp, np.float32))).astype(
+                                       vp.dtype)
+        bad_scales = scales
+        if pool == "int8":
+            # an int8 pool holds no NaN: poison the blocks' scales
+            bad = vp
+            bad_scales = (scales[0], jnp.where(
+                jnp.asarray(poisoned)[None, :, None], jnp.nan, scales[1]))
+        run = lambda fn, v, sc: np.asarray(fn(
+            q, kp, v, LAYER, tables, eff, BOUND_BS, *sc,
+            chunk_blocks=BOUND_CHUNK), np.float32)
+        clean = run(blockwise_paged_attention, vp, scales)
+        got = run(blockwise_paged_attention, bad, bad_scales)
+        assert np.isfinite(got).all()
+        assert np.array_equal(got, clean)
+        assert not np.isfinite(
+            run(_every_entry_attention, bad, bad_scales)).all()
+
+    @pytest.mark.parametrize("slots", (1, 8, 37, 128, 200))
+    def test_host_count_is_the_device_loops_trip_count(self, slots,
+                                                       min_width):
+        """`blockwise_streamed_entries` (numpy, the engine's counter) and
+        the program (jax.numpy, traced) share `_blockwise_plan` and
+        `_step_widths`: for the same lengths the trip count and every
+        step's width are equal, and the streamed entries are those steps'
+        slots x entries, between the held entries and the whole table."""
+        M, bs, H, D = 64, 16, 12, 64
+        widths, chunk, n_chunks = _blockwise_plan(slots, M, bs, H, D)
+        on_device = jax.jit(lambda lens: _step_widths(
+            lens, widths, chunk * bs, n_chunks, jnp))
+        rng = np.random.default_rng(slots)
+        for draw in range(6):
+            lens = rng.integers(0, M * bs, slots).astype(np.int32)
+            lens //= rng.integers(1, 6, slots).astype(np.int32)
+            active = rng.random(slots) < (0.0, 0.5, 0.9, 1, 1, 1)[draw]
+            if draw == 5:
+                lens[:] = M * bs - 1
+            eff = -np.sort(-np.where(active, lens, 0).astype(np.int32))
+            trips_d, which_d = on_device(jnp.asarray(eff))
+            trips_h, which_h = _step_widths(eff, widths, chunk * bs,
+                                            n_chunks, np)
+            assert int(trips_d) == int(trips_h) == eff[0] // (chunk * bs) + 1
+            assert np.asarray(which_d).tolist() == which_h.tolist()
+            assert which_h[0] == 0          # the first chunk is every slot's
+            for c in range(1, int(trips_h)):
+                reach = int((eff // (chunk * bs) >= c).sum())
+                assert widths[which_h[c]] >= reach
+                assert which_h[c] == len(widths) - 1 or \
+                    widths[which_h[c] + 1] < reach
+            streamed, held = blockwise_streamed_entries(
+                lens, active, M, bs, H, D)
+            entries = [min(chunk, M - c * chunk) for c in range(n_chunks)]
+            assert streamed == sum(widths[which_h[c]] * entries[c]
+                                   for c in range(int(trips_h)))
+            assert held == sum(int(n) // bs + 1
+                               for n in np.where(active, lens, 0)[active])
+            assert held <= streamed <= slots * M
+            if draw == 5:
+                assert streamed == held == slots * M
+
+    def test_engine_reports_streamed_and_held_share(self):
+        """`stats()` sums the helper's counts over the decode launches:
+        streamed lies between held and 1, `reset_stats()` zeroes the
+        window, and the same requests stream a smaller share of a table
+        twice as long."""
+        paddle.seed(0)
+        cfg = GPTConfig(vocab_size=VOCAB, hidden_size=32,
+                        num_hidden_layers=1, num_attention_heads=4,
+                        intermediate_size=64, max_position_embeddings=2048,
+                        hidden_dropout_prob=0.0,
+                        attention_probs_dropout_prob=0.0,
+                        use_flash_attention=False)
+        model = GPTForCausalLM(cfg)
+        model.eval()
+        prompts = [_prompt(n, seed=29) for n in (5, 30, 12, 21, 9)]
+        shares, streams = {}, {}
+        for ctx in (1024, 2048):
+            engine = LLMEngine(model, max_batch_size=4, block_size=16,
+                               max_context=ctx, num_blocks=64)
+            assert engine.stats()["attn_streamed_share"] == 0.0
+            streams[ctx] = engine.generate(prompts, max_new_tokens=4)
+            st = engine.stats()
+            assert st["attention_kernel"] == "blockwise"
+            assert 0.0 < st["attn_held_share"] < st["attn_streamed_share"] < 1.0
+            shares[ctx] = (st["attn_streamed_share"], st["attn_held_share"])
+            engine.reset_stats()
+            st = engine.stats()
+            assert st["attn_streamed_share"] == st["attn_held_share"] == 0.0
+        assert streams[1024] == streams[2048]
+        # chunks of 512 tokens: one of two, then one of four
+        assert shares[1024][0] == pytest.approx(0.5)
+        assert shares[2048][0] == pytest.approx(0.25)
+        assert shares[2048][1] == pytest.approx(shares[1024][1] / 2)
+        # a variant that reads the whole table says so
+        engine = LLMEngine(model, max_batch_size=4, block_size=16,
+                           max_context=1024, num_blocks=64,
+                           attention_kernel="reference")
+        assert engine.generate(prompts, max_new_tokens=4) == streams[1024]
+        st = engine.stats()
+        assert st["attn_streamed_share"] == 1.0
+        assert st["attn_held_share"] == pytest.approx(shares[1024][1])
+
+    def test_engine_at_two_widths_serves_generates_tokens(self, model):
+        """128 slots run at two widths: the ordered decode step serves
+        exactly `model.generate`'s tokens, compiled once."""
+        prompts = [_prompt(3 + (i * 5) % 23, seed=29) for i in range(140)]
+        engine = LLMEngine(model, max_batch_size=128, block_size=4)
+        assert _blockwise_plan(128, engine.max_blocks_per_seq, 4, 4, 8)[0] \
+            == (128, 64)
+        outs = engine.generate(prompts, max_new_tokens=6)
+        for p, out in zip(prompts, outs):
+            assert out == _ref(model, p, 6)
+        st = engine.stats()
+        assert st["decode_compiles"] == 1 and st["completed"] == 140
 
 
 # ---------------------------------------------------------------------------

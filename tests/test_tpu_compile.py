@@ -12,8 +12,11 @@ run: nothing executes and nothing here is a device number.
 The serving programs' KV pool is held to the same compiler: at the backlog
 cell's geometry the donated pool has to stay in one row-major layout and be
 updated where it lies (no pool-shaped `copy`, `concatenate` or `pad`,
-temporaries under one layer of one pool).
+temporaries under one layer of one pool), and the blockwise decode
+attention, whose loops stop at the lengths, holds nothing of a whole
+context's size.
 """
+import math
 import os
 import re
 
@@ -215,6 +218,17 @@ def test_the_donated_kv_pool_is_updated_where_it_lies(v5e, monkeypatch,
     opcodes = {opcode for _, opcode in made}
     assert "scatter" in opcodes
     assert not opcodes & {"copy", "concatenate", "pad"}, opcodes
+
+    if program == "decode_blockwise":
+        # the length-bounded loop gathers a chunk of one group of slots
+        # at a time: nothing in the program is as large as the slots'
+        # whole contexts [S, M x bs, H x D], in any shape or dtype
+        context = CELL_SLOTS * CELL_TABLE * BLOCK * CELL_HEADS * CELL_HEAD_DIM
+        largest = max(
+            (math.prod(map(int, dims.split(","))), dims)
+            for dims in re.findall(r"\w+\[([\d,]+)\]", text)
+            if dims != ",".join(map(str, CELL_POOL)))
+        assert largest[0] < context // 8, largest
 
     one_layer = 2 * CELL_BLOCKS * BLOCK * CELL_HEADS * CELL_HEAD_DIM
     memory = compiled.memory_analysis()
