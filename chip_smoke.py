@@ -127,7 +127,7 @@ def make_optimizer(model):
 
 
 def make_trainer(model, donate):
-    """The TrainStep of bench.py's GPT legs."""
+    """The TrainStep the train cells run (`benchmark/programs/paddle_gpt.py`)."""
     from paddle_tpu.incubate.models import GPTPretrainingCriterion
     from paddle_tpu.jit import TrainStep
     opt = make_optimizer(model)

@@ -4,7 +4,7 @@ The rules (paddle_tpu/analysis/rules/) need four capabilities beyond a
 raw `ast.walk`:
 
   * project loading — the default scan set is the package source plus
-    tools/ and bench.py (never tests/, never fixtures), each file parsed
+    tools/ (never tests/, never fixtures), each file parsed
     once and shared across rules;
   * scope/closure resolution — for a `fn` passed into the dispatch
     funnel, which names does it CAPTURE from the enclosing op wrapper
@@ -103,8 +103,8 @@ class Project:
                 if getattr(m, "parse_error", None)]
 
 
-_DEFAULT_SCAN = ("paddle_tpu", "tools", "bench.py")
-_SKIP_DIRS = {"__pycache__", "tests", "bench_traces", ".git"}
+_DEFAULT_SCAN = ("paddle_tpu", "tools")
+_SKIP_DIRS = {"__pycache__", "tests", ".git"}
 
 
 def _repo_root():

@@ -1,8 +1,8 @@
 """R7 perf-contract: new compiled-path surface area must stay visible to
 the performance accounting plane.
 
-The regression sentinel (profiler/sentinel.py) and its checked-in bands
-(tools/perf_baselines.json) are only as good as two inputs:
+The regression sentinel (profiler/sentinel.py) and the bands an operator
+keeps for it are only as good as two inputs:
 
   * the analytic FLOPs estimator (`goodput.estimate_cycle_flops`) — an
     op that does matmul-class work but falls through to the O(numel)

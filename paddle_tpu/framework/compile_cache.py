@@ -1,8 +1,8 @@
 """Where JAX keeps compiled programs between processes.
 
-One rule for every entry point that compiles (chip_smoke.py, bench.py's
-children, tests/conftest.py): the cache directory is part of the cache's
-key, so it is either where `JAX_COMPILATION_CACHE_DIR` says — JAX reads
+One rule for every entry point that compiles (chip_smoke.py, the
+benchmark's programs, tests/conftest.py): the cache directory is part of
+the cache's key, so it is either where `JAX_COMPILATION_CACHE_DIR` says — JAX reads
 that variable itself, and nothing here overrides it — or one fixed
 directory inside the checkout. Never a temporary name, a pid or the time:
 a directory that moves never hits.

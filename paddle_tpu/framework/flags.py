@@ -110,12 +110,6 @@ define_flag("FLAGS_use_fused_cross_entropy", False,
             "for memory-bound cases (very large vocab or long sequence)")
 define_flag("FLAGS_use_fused_layer_norm", True,
             "route eligible bias+residual+LN through the Pallas row kernel")
-define_flag("FLAGS_allocator_strategy", "xla",
-            "memory is managed by XLA/PJRT (informational)")
-define_flag("FLAGS_cudnn_deterministic", False, "determinism hint")
-define_flag("FLAGS_embedding_deterministic", 0, "determinism hint")
-define_flag("FLAGS_max_inplace_grad_add", 0, "compat no-op")
-define_flag("FLAGS_eager_delete_tensor_gb", 0.0, "compat no-op (XLA GC)")
 
 # Compiled eager dispatch (ops/dispatch.py). The cache key is
 # (op name, fn token, input (shape, dtype, weak_type) avals, diff mask,
@@ -123,8 +117,7 @@ define_flag("FLAGS_eager_delete_tensor_gb", 0.0, "compat no-op (XLA GC)")
 # forward+vjp executables, so a repeated eager op sequence stops re-tracing
 # after its first iteration. Telemetry — hits, misses, bypasses, retraces,
 # evictions, cumulative dispatch wall time — is read with
-# paddle_tpu.profiler.dispatch_cache_stats() and lands in bench.py's
-# headline record as the `dispatch_cache` block in `extra`.
+# paddle_tpu.profiler.dispatch_cache_stats().
 define_flag("FLAGS_eager_op_cache", True,
             "per-op executable cache in eager dispatch: repeated ops reuse "
             "compiled forward and VJP executables instead of re-tracing. "
@@ -158,9 +151,9 @@ define_flag("FLAGS_eager_op_cache_donate", False,
 # fused executable fires when the chain completes; any mid-chain mismatch
 # or an intermediate escaping the chain (a `.numpy()`, an unrelated op, a
 # mutated stop_gradient) splits the chain back onto the per-op cached
-# path with identical numerics. Telemetry:
-# paddle_tpu.profiler.chain_fusion_stats(); bench.py embeds it as the
-# `chain_fusion` block.
+# path (the same values up to the rounding by which a program compiled
+# whole may differ from its ops run one by one). Telemetry:
+# paddle_tpu.profiler.chain_fusion_stats().
 define_flag("FLAGS_eager_chain_fusion", True,
             "fuse repeated eager op sequences into single compiled chain "
             "executables on top of the per-op cache. Chains are keyed by "
@@ -199,11 +192,10 @@ define_flag("FLAGS_eager_chain_stitching", True,
 # update) with donated optimizer-slot buffers: the auto-TrainStep. Replay
 # is speculative and transactional exactly like chain fusion — any
 # cycle-shape mismatch, a mid-step value peek, a changed optimizer/param
-# set, or an execution fault splits back to chain/per-op dispatch with
-# bitwise-identical numerics. The LR-schedule value and the optimizer step
+# set, or an execution fault splits back to chain/per-op dispatch (same
+# values up to that rounding). The LR-schedule value and the optimizer step
 # count are hoisted to scalar arguments, so schedulers never split.
-# Telemetry: paddle_tpu.profiler.step_fusion_stats(); bench.py embeds it
-# as the `step_fusion` block.
+# Telemetry: paddle_tpu.profiler.step_fusion_stats().
 define_flag("FLAGS_eager_step_fusion", True,
             "promote a stable eager fwd+bwd+optimizer cycle to one fused "
             "whole-step executable (auto-TrainStep). Falls back to "
@@ -271,8 +263,7 @@ define_flag("FLAGS_metrics", False,
             "record production metrics (counters/gauges/histograms) into "
             "the in-process registry (profiler/metrics.py) and run the "
             "live MFU/goodput accountant (profiler/goodput.py). Off by "
-            "default: every site is one flag check "
-            "(tools/perf_smoke.py guards <3%/step off, <5%/step on)")
+            "default: every site is one flag check")
 define_flag("FLAGS_metrics_window", 100_000,
             "sliding-window size (observations) of the registry's "
             "streaming histograms: percentiles are computed over the "
@@ -319,9 +310,9 @@ define_flag("FLAGS_telemetry_stale_s", 120.0,
 # Performance regression sentinel (profiler/sentinel.py). Disarmed by
 # default: every tick site costs one module-bool check. Armed, the
 # sentinel snapshots the goodput accountant / metrics registry once per
-# evaluation window, classifies drift against a checked-in per-leg
-# baseline (tools/perf_baselines.json) — or against its own first clean
-# window when no leg is named — and flips the /readyz degraded latch
+# evaluation window, classifies drift against a named leg of the
+# operator's baseline file — or against its own first clean window when
+# no leg is named — and flips the /readyz degraded latch
 # with the finding attached.
 define_flag("FLAGS_sentinel", False,
             "arm the performance regression sentinel "
@@ -336,12 +327,12 @@ define_flag("FLAGS_sentinel_window_s", 10.0,
             "window), so smaller windows detect faster but judge "
             "noisier statistics")
 define_flag("FLAGS_sentinel_baseline", "",
-            "path to the per-leg perf baseline JSON for the sentinel "
-            "and tools/perf_baseline.py; empty = the checked-in "
-            "tools/perf_baselines.json")
+            "path to the operator's per-leg perf baseline JSON "
+            "(sentinel.PerfBaseline) for the sentinel; none is "
+            "shipped, and a named leg is refused without it")
 define_flag("FLAGS_sentinel_leg", "",
-            "baseline leg name the live sentinel compares against "
-            "(e.g. 'fused', 'serve_8'); empty = self-calibrate: the "
+            "leg of FLAGS_sentinel_baseline the live sentinel "
+            "compares against; empty = self-calibrate: the "
             "first completed clean window becomes the reference band")
 
 define_flag("FLAGS_aot_cache", False,

@@ -28,7 +28,8 @@ A `PredictorPool` of per-request predictors (the reference's serving
 pattern) freezes batch composition for a request's lifetime; `LLMEngine`
 re-forms the batch at every token boundary — that is the difference
 between one-user latency and millions-of-users throughput. See the
-README "Serving" section and `tools/serve_bench.py`."""
+README "Serving" section; its speed is the `serve_124m_backlog` cell's
+(`PERF.md`)."""
 from __future__ import annotations
 
 import numpy as np

@@ -40,9 +40,10 @@ semantics:
     once cleared) and are masked. This is the
     default variant on every platform, the CPU/parity fallback AND a
     standalone win: it replaces the dense gather's ``[S, T, H, D]``
-    materialization with cache-resident chunks, so it beats the gather
-    on the serve CPU legs from seq ~1k up (tools/perf_smoke.py leg j
-    guards the floor). `blockwise_streamed_entries` is the host's count
+    materialization with cache-resident chunks
+    (tests/test_kernel_tier.py reads the traced program for it; its
+    speed is the `serve_124m_backlog` cell's).
+    `blockwise_streamed_entries` is the host's count
     of what that loop reads, from the same plan and step widths.
 
 Numerics: scores, the softmax recurrence, and the output accumulator are

@@ -50,8 +50,9 @@ input grads` that recomputes the forward inside the backward. The warm
 process pays one extra forward FLOP per op during its single observation
 cycle — after which the whole step replays as the ONE restored fused-step
 program and the per-op path is idle — in exchange for zero Python-level
-retraces at restart. Telemetry: profiler/aot.py counters (`aot_cache`
-block in bench.py) + `aot.{hit,miss,store,corrupt,version_skew,evict}`
+retraces at restart. Telemetry: profiler/aot.py counters
+(`profiler.aot_cache_stats()`) +
+`aot.{hit,miss,store,corrupt,version_skew,evict}`
 flight-recorder events.
 """
 from __future__ import annotations

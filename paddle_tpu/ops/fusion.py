@@ -34,8 +34,7 @@ failing to replay are deactivated.
 
 Telemetry: profiler/chain_fusion.py (chains detected, fused replays,
 fallback splits, escapes, launches saved, estimated wall time saved),
-surfaced by `paddle_tpu.profiler.chain_fusion_stats()` and embedded in
-bench.py headline records as the `chain_fusion` block.
+surfaced by `paddle_tpu.profiler.chain_fusion_stats()`.
 """
 from __future__ import annotations
 

@@ -184,8 +184,7 @@ def reset_thread_state():
 # device: at depth N, a non-finite finding surfaces at most N boundaries
 # after the op ran — the params were already protected in-graph by the
 # skip-step rescue, so the delay costs attribution latency, not safety,
-# and it keeps the async dispatch pipeline intact (a hard sync per step
-# would cost >100% on the smoke loop; see tools/perf_smoke.py)
+# and it keeps the async dispatch pipeline intact (no hard sync per step)
 _PIPELINE_DEPTH = 2
 
 

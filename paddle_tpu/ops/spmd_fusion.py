@@ -538,7 +538,7 @@ class _PipelineProgram:
         self.fires = 0
         self.n_launches = n_launches
         # goodput.on_fused_fire introspection surface (no recorded cycle:
-        # bench legs pin exact FLOPs for pipeline programs)
+        # a caller pins exact FLOPs for pipeline programs)
         self.chain = None
         self.entries = ()
         self.spmd_plan = None

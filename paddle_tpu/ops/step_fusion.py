@@ -52,8 +52,7 @@ How it works:
             deactivated.
 
 Telemetry: profiler/step_fusion.py, surfaced by
-`paddle_tpu.profiler.step_fusion_stats()` and embedded in bench.py
-headline records as the `step_fusion` block.
+`paddle_tpu.profiler.step_fusion_stats()`.
 """
 from __future__ import annotations
 

@@ -3,7 +3,7 @@ cache (ops/aot_cache.py).
 
 Mirrors the dispatch/chain/step counter structs: plain attribute bumps on
 the hot path (GIL-protected enough for telemetry), a locked snapshot for
-readers. `bench.py` embeds the snapshot as the `aot_cache` block; the
+readers. The
 flight recorder carries the per-decision story (`aot.{hit,miss,store,
 corrupt,version_skew,evict}` events).
 
@@ -71,7 +71,7 @@ STATS = AotCacheStats()
 
 def aot_cache_stats() -> dict:
     """Current AOT executable-store counters (see module docstring for
-    field semantics). `bench.py` embeds this as the `aot_cache` block."""
+    field semantics)."""
     return STATS.snapshot()
 
 

@@ -3,8 +3,7 @@
 The fusion layer (ops/fusion.py) sits on top of the per-op executable cache
 (ops/dispatch.py, counters in profiler/dispatch.py) and replaces N per-op
 XLA launches of a hot op sequence with one fused launch. These counters make
-that visible in bench output (`chain_fusion` block in the headline record's
-`extra`) and in the perf smoke guard (tools/perf_smoke.py).
+that visible; tests/test_chain_fusion.py holds the launches they count.
 
 Counter semantics:
   chains_detected   distinct op sequences that crossed the hotness threshold
@@ -137,7 +136,7 @@ CHAIN_STATS = ChainFusionStats()
 
 def chain_fusion_stats(per_chain: bool = False) -> dict:
     """Current chain-fusion counters (see module docstring for field
-    semantics). `bench.py` embeds this as the `chain_fusion` block."""
+    semantics)."""
     return CHAIN_STATS.snapshot(per_chain)
 
 

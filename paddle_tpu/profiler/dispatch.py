@@ -3,8 +3,7 @@
 Reference analog: the reference tracked per-op dispatch cost with
 operators/benchmark/op_tester.cc + profiler/timer.py; here the eager funnel
 (ops/dispatch.py) records cache behavior directly so retrace regressions
-show up in bench output (`dispatch_cache` block in the headline record's
-`extra`) without a profiler run.
+show up (`dispatch_cache_stats()`) without a profiler run.
 
 Counter semantics:
   hits       cache key found — dispatch reused a compiled executable
@@ -101,7 +100,7 @@ STATS = DispatchStats()
 
 def dispatch_cache_stats(per_op: bool = False) -> dict:
     """Current eager-dispatch cache counters (see module docstring for the
-    field semantics). `bench.py` embeds this as the `dispatch_cache` block."""
+    field semantics)."""
     return STATS.snapshot(per_op)
 
 

@@ -47,8 +47,7 @@ blocked a chain, which cycle position poisoned) live in the event's
 `detail` dict.
 
 Cost contract: gated by FLAGS_profiler_events; when off, `emit()` is one
-dict lookup and a return (tools/perf_smoke.py guards the disabled path at
-<3% of the fused smoke-loop step). When on, an emission is a tuple build
+dict lookup and a return. When on, an emission is a tuple build
 plus a lock-guarded seq increment + deque append (unique seq across
 threads is what the Profiler's drain dedup keys on) — the ring
 (FLAGS_profiler_events_capacity) never grows unbounded. Events are drained into chrome-trace lanes by the
@@ -120,7 +119,7 @@ CATEGORIES = frozenset({
 })
 
 # Machine-readable causes. Stable across releases: the fusion doctor, the
-# perf-smoke "no unexplained splits" guard, and downstream trace tooling
+# tests' "no unexplained splits" assertions, and downstream trace tooling
 # key on these strings.
 REASON_CODES = frozenset({
     # -- why a dispatch bypassed the executable cache ----------------------
@@ -351,7 +350,7 @@ def fusion_events_enabled():
 
 def events_summary(events=None):
     """Aggregate a list of event dicts (default: the live ring) into the
-    compact shape bench.py embeds and perf_smoke.py guards on:
+    compact shape `tools/fusion_doctor.py` and the tests read:
     per-category counts plus (category, reason) split/bypass attribution."""
     if events is None:
         events = EVENTS.snapshot()

@@ -6,7 +6,7 @@ structured report answering the one question the counter structs cannot:
 names the op, the reason code, and the multiplicity — "step never
 promoted: `dropout` re-keys every call (rng_rekey ×40)" — and
 `format_report()` renders it for humans. `tools/fusion_doctor.py` is the
-CLI wrapper; `bench.py` embeds the compact dict in its headline extra.
+CLI wrapper.
 
 Works on any list of event dicts: the live ring (default), a Profiler
 window (`prof._fusion_events`), or a re-loaded chrome trace

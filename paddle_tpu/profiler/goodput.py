@@ -1,7 +1,7 @@
 """Live training accountant: rolling MFU, tokens/s, and goodput.
 
-bench.py's MFU was computed OFFLINE (tokens/s x flops_per_token / chip
-peak, after the run); production had no number at all. This module is the
+An MFU computed OFFLINE (tokens/s x flops_per_token / chip peak, after the
+run) leaves production with no number at all. This module is the
 always-on version: a process-global :class:`GoodputAccountant` fed one
 call per optimizer-step boundary (hooks in optimizer/optimizer.py and
 jit/train_step.py — both the eager and the fused auto-TrainStep paths
@@ -22,8 +22,7 @@ here directly), publishing into the profiler/metrics.py registry:
     boundary), and `other`.
 
 Analytic FLOPs/step come from (in priority order): an explicit
-``set_flops_per_step()`` (what bench.py uses, so bench numbers and
-production numbers are definitionally the same computation),
+``set_flops_per_step()`` (the caller knows its model),
 ``set_model()`` (a model exposing ``flops_per_token``/``flops_per_image``,
 or counted via the hapi/dynamic_flops machinery), or — automatically at
 promotion — :func:`estimate_cycle_flops` over the recorded fused cycle's
@@ -304,8 +303,7 @@ class GoodputAccountant:
 
     def set_flops_per_step(self, flops, tokens=None, peak=None):
         """Pin the analytic FLOPs (and optionally tokens) per training
-        step — the bench.py path, making live and offline MFU the same
-        computation by construction."""
+        step (a caller that knows its model's count)."""
         self._flops_per_step = float(flops)
         if tokens is not None:
             self._tokens_per_step = int(tokens)
@@ -467,7 +465,7 @@ class GoodputAccountant:
 
     def finalize(self):
         """Close the measurement window after the caller's final blocking
-        read (bench.py): the tail device time of the last step joins the
+        read: the tail device time of the last step joins the
         productive bucket instead of silently vanishing."""
         now = time.perf_counter()
         dt = now - self._t_last
@@ -529,8 +527,8 @@ class GoodputAccountant:
             T.step_index.labels(bucket=b).set_raw(last)
 
     def snapshot(self):
-        """JSON-able accountant view (bench.py embeds this; the MFU/
-        tokens-per-second here IS the registry computation)."""
+        """JSON-able accountant view (the MFU/tokens-per-second here IS
+        the registry computation)."""
         self.publish()
         T = _metrics.TRAIN
         sps, span = self._rolling()
@@ -592,7 +590,7 @@ def on_fused_fire(program, rounds=1):
     `rounds` is the micro-batch count of a super-cycle fire (grad
     accumulation): one optimizer step spans rounds× the segment's
     FLOPs. The derivation is memoized per program, so a later k change
-    keeps the first fire's estimate — bench legs pin exact FLOPs when
+    keeps the first fire's estimate — a caller pins exact FLOPs when
     that matters."""
     if not _FLAGS.get("FLAGS_metrics"):
         return

@@ -2,8 +2,8 @@
 
 The fusion stack's counter structs (profiler/{dispatch,chain_fusion,
 step_fusion,aot}.py, ops/guardian.py, serving ServeStats) say how often
-things happened *inside one subsystem*; bench.py computes MFU *offline*;
-nothing in the system is an always-on, queryable metrics plane a
+things happened *inside one subsystem*;
+nothing there is an always-on, queryable metrics plane a
 production operator could scrape. This module is that plane:
 
   * **Counter / Gauge / LogHistogram** metric types, optionally labeled
@@ -26,9 +26,8 @@ production operator could scrape. This module is that plane:
 
 Cost contract (the flight recorder's proven discipline): everything is
 gated by ``FLAGS_metrics``. When off, ``inc()``/``observe()``/``set()``
-is ONE dict lookup and a return — tools/perf_smoke.py guards the
-disabled path at <3%/step and the enabled path at <5%/step on the fused
-train loop and the serve_8 workload. ``METRIC_NAMES`` is a public
+is ONE dict lookup and a return (tests/test_metrics.py: off, every
+metric stays at zero). ``METRIC_NAMES`` is a public
 contract like ``REASON_CODES``: dashboards and the fusion doctor key on
 the exact strings, and tests/test_metrics.py freezes the set.
 

@@ -6,13 +6,12 @@ this module proves "it STAYS fast". Three pieces share one vocabulary:
   * a **record** — the JSON-able per-leg perf shape (goodput bucket
     distribution, split/bypass reason histogram, compile/retrace counts,
     step-time / serve p50/p99, tokens/sec) captured either over a whole
-    bench/perf_smoke leg (`capture_record`) or over one live evaluation
-    window (the watcher below);
+    run (`capture_record`) or over one live evaluation window (the
+    watcher below);
   * a **baseline** — per-leg tolerance bands derived from a record
-    (`bands_from_record`) and checked in beside the lint baseline
-    (tools/perf_baselines.json), with the same add/match/expire/
-    `--write-baseline` hygiene (`PerfBaseline`, driven by
-    tools/perf_baseline.py);
+    (`bands_from_record`), kept in a JSON file the operator names
+    (`PerfBaseline`: add/match/expire like the lint baseline). The
+    repository ships no such file;
   * a **verdict** — `classify(record, bands)` names every band the
     record violates with a REASON_CODES entry: `perf_drift` (goodput /
     throughput floor), `split_regression` (a reason outside the baseline
@@ -22,16 +21,16 @@ this module proves "it STAYS fast". Three pieces share one vocabulary:
 The live watcher (`SENTINEL`, armed via FLAGS_sentinel or
 `fusion_doctor --watch`) snapshots the accountant/registry once per
 FLAGS_sentinel_window_s, classifies the window's delta-record against
-the named baseline leg — or against its own first clean window when no
-leg is configured — emits `sentinel.check` / `sentinel.drift` /
+the named leg of the operator's baseline file — or against its own
+first clean window when no leg is configured — emits
+`sentinel.check` / `sentinel.drift` /
 `sentinel.recover` events, and holds a degraded latch that
 telemetry_server's /readyz folds in (503 with the finding attached).
 
 Cost discipline (the telemetry-plane rule): disarmed, every tick site
 is one module-bool check; armed, a tick is one perf_counter read until
 the window edge, and the per-window evaluation drains only the events
-since the previous window (perf_smoke leg (q) holds the <3%/step
-budget on fused train AND serve_8).
+since the previous window.
 """
 import json
 import os
@@ -43,17 +42,13 @@ from ..framework.flags import _FLAGS, set_flags
 from . import metrics as _metrics
 
 __all__ = [
-    "SENTINEL", "Sentinel", "PerfBaseline", "DEFAULT_PERF_BASELINE",
-    "capture_record", "bands_from_record", "classify", "arm", "disarm",
+    "SENTINEL", "Sentinel", "PerfBaseline", "capture_record",
+    "bands_from_record", "classify", "arm", "disarm",
     "tick", "sentinel_report", "sentinel_ready", "publish_metrics",
     "maybe_arm_from_flags",
 ]
 
 RECORD_VERSION = 1
-
-DEFAULT_PERF_BASELINE = os.path.join(
-    os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__)))), "tools", "perf_baselines.json")
 
 # The demotion/interruption surface a steady window is judged on. The
 # benign lifecycle categories (serve.admit, serve.sample, aot.hit,
@@ -193,11 +188,10 @@ _ZERO_PROBE = {"t": 0.0, "steps": 0, "buckets": {}, "dispatch": 0,
 
 
 def capture_record(leg, kind=None):
-    """Whole-run record for a bench / perf_smoke leg: absolute counters
-    since the (freshly reset) process start, plus the watched reason
-    histogram of the full flight-recorder ring. The caller owns slate
-    hygiene (bench runs each config in a child process; perf_smoke
-    resets the recorder per leg)."""
+    """Whole-run record: absolute counters since the (freshly reset)
+    process start, plus the watched reason histogram of the full
+    flight-recorder ring. The caller owns slate hygiene (a fresh
+    process, or a reset recorder)."""
     p = _probe()
     p0 = dict(_ZERO_PROBE)
     from .goodput import ACCOUNTANT
@@ -215,10 +209,9 @@ def capture_record(leg, kind=None):
 
 def bands_from_record(record, slack=25.0):
     """Tolerance bands a future record of the same leg must sit inside.
-    `slack` scales the latency/throughput windows (25x for the first
-    CPU-smoke capture — CI machines vary wildly; the band-tightening
-    policy in the README drops it toward 1.25x on the first real-TPU
-    pass). The structural bands are slack-independent: the reason
+    `slack` scales the latency/throughput windows (wide across
+    machines, 4x for the watcher's own same-process calibration). The
+    structural bands are slack-independent: the reason
     histogram is closed over what the clean leg emitted, decode/prefill
     rebuilds get NO headroom (a steady engine never re-traces), and the
     goodput floor is half the observed fraction."""
@@ -311,7 +304,7 @@ def classify(record, bands):
 
 
 # ---------------------------------------------------------------------------
-# the checked-in per-leg baseline (tools/perf_baselines.json)
+# the per-leg baseline file (the operator's; none is shipped)
 # ---------------------------------------------------------------------------
 
 class PerfBaseline:
@@ -325,7 +318,7 @@ class PerfBaseline:
         self.policy = policy
 
     @classmethod
-    def load(cls, path=DEFAULT_PERF_BASELINE):
+    def load(cls, path):
         if not os.path.exists(path):
             return cls()
         with open(path, "r", encoding="utf-8") as f:
@@ -336,7 +329,7 @@ class PerfBaseline:
                 f"in {path}")
         return cls(doc.get("legs") or {}, doc.get("policy") or "")
 
-    def save(self, path=DEFAULT_PERF_BASELINE):
+    def save(self, path):
         doc = {"version": 1, "policy": self.policy,
                "legs": {k: self.legs[k] for k in sorted(self.legs)}}
         tmp = path + ".tmp"
@@ -441,12 +434,31 @@ class Sentinel:
     def arm(self, leg=None, baseline=None, window_s=None):
         """Arm the watcher. Needs the accountant and the flight recorder:
         both flags are raised if off and restored on disarm (the Profiler
-        window discipline). With a named leg the bands come from the
-        checked-in baseline; otherwise the first non-idle window
-        self-calibrates a reference band (slack 4x: same host, same
-        process — much tighter than the cross-machine file)."""
+        window discipline). A named leg takes its bands from the
+        baseline file the operator gives (`baseline=` or
+        FLAGS_sentinel_baseline) and is refused without one; with no
+        leg the first non-idle window self-calibrates a reference band
+        (slack 4x: same host, same process)."""
         global _TICKING
         from .events import EVENTS
+        leg = leg if leg is not None \
+            else str(_FLAGS.get("FLAGS_sentinel_leg") or "")
+        path = baseline if baseline is not None \
+            else str(_FLAGS.get("FLAGS_sentinel_baseline") or "")
+        bands = None
+        if leg:
+            # refused BEFORE any flag is borrowed or state is reset
+            if not path:
+                raise ValueError(
+                    f"sentinel leg {leg!r} needs a baseline file: pass "
+                    "baseline= or set FLAGS_sentinel_baseline (no file "
+                    "is shipped; with no leg the sentinel calibrates on "
+                    "its own first clean window)")
+            entry = PerfBaseline.load(path).match(leg)
+            if entry is None:
+                raise ValueError(
+                    f"no baseline entry for leg {leg!r} in {path}")
+            bands = entry["bands"]
         with self._lock:
             restore = {}
             for fl in ("FLAGS_metrics", "FLAGS_profiler_events"):
@@ -456,11 +468,8 @@ class Sentinel:
                 set_flags({k: True for k in restore})
             self.reset()
             self._restore_flags = restore
-            self.leg = leg if leg is not None \
-                else str(_FLAGS.get("FLAGS_sentinel_leg") or "")
-            self.baseline_path = baseline if baseline is not None \
-                else (str(_FLAGS.get("FLAGS_sentinel_baseline") or "")
-                      or DEFAULT_PERF_BASELINE)
+            self.leg = leg
+            self.baseline_path = path
             try:
                 self.window_s = float(
                     window_s if window_s is not None
@@ -468,15 +477,8 @@ class Sentinel:
             except (TypeError, ValueError):
                 self.window_s = 10.0
             self.window_s = max(0.05, self.window_s)
-            if self.leg:
-                entry = PerfBaseline.load(self.baseline_path).match(
-                    self.leg)
-                if entry is None:
-                    raise ValueError(
-                        f"no baseline entry for leg {self.leg!r} in "
-                        f"{self.baseline_path} (run tools/perf_baseline.py "
-                        "--write-baseline)")
-                self.bands = entry["bands"]
+            if bands is not None:
+                self.bands = bands
                 self.band_source = "baseline"
             self.armed = True
             self._probe0 = _probe()

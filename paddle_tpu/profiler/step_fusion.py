@@ -4,9 +4,8 @@ The step-fusion layer (ops/step_fusion.py) sits above chain fusion
 (counters in profiler/chain_fusion.py) and replaces an entire eager
 training cycle — every forward launch, every per-node backward launch, and
 the optimizer's fused update launch — with ONE whole-step executable.
-These counters make that visible in bench output (`step_fusion` block in
-the headline record's `extra`) and in the perf smoke guard
-(tools/perf_smoke.py).
+These counters make that visible; tests/test_step_fusion.py holds the
+launches they count.
 
 Counter semantics:
   steps_promoted    distinct per-step cycles that stayed identical for
@@ -125,7 +124,7 @@ STEP_STATS = StepFusionStats()
 
 def step_fusion_stats(per_step: bool = False) -> dict:
     """Current whole-step fusion counters (see module docstring for field
-    semantics). `bench.py` embeds this as the `step_fusion` block."""
+    semantics)."""
     return STEP_STATS.snapshot(per_step)
 
 

@@ -53,10 +53,8 @@ start, both by design).
 
 Cost contract: everything rides existing snapshots; the server thread
 only works while a scraper is connected. ``beat()`` is a module-bool
-check + dict store, called once per optimizer boundary / decode step;
-tools/perf_smoke.py leg (l) guards the off cost (<3%/step) and the
-armed+scraped-at-100ms cost (<5%/step on the fused train loop and the
-serve_8 workload). Kill-9 mid-scrape can never wedge a restart:
+check + dict store, called once per optimizer boundary / decode step.
+Kill-9 mid-scrape can never wedge a restart:
 `allow_reuse_address` is set, so the replacement process rebinds the
 port immediately (tests/test_telemetry_server.py proves it).
 """
@@ -79,7 +77,7 @@ __all__ = ["TelemetryServer", "start", "stop", "maybe_start_from_flags",
 
 def probe_endpoint(url, timeout=10):
     """GET one telemetry endpoint: (status, parsed body). The client
-    counterpart every prober shares (bench autopsy, chaos, tests) so the
+    counterpart every prober shares (chaos, the doctor, tests) so the
     endpoint contract has ONE reader: 4xx/5xx JSON bodies (healthz 503)
     are parsed and returned as data, JSON is decoded, /metrics text
     comes back as a string. Network errors propagate to the caller."""
@@ -151,8 +149,7 @@ def _engine_window_s():
 
 def health_report():
     """Liveness view: heartbeat ages vs their windows. `healthy` is the
-    conjunction; `last_heartbeat_age_s` is the freshest signal (what the
-    bench harness reports in a timeout autopsy)."""
+    conjunction; `last_heartbeat_age_s` is the freshest signal."""
     now = time.perf_counter()
     stale_s = _stale_window_s()
     healthy = True

@@ -28,8 +28,8 @@ Quick start::
     outs = engine.generate([[5, 3, 9], [7, 1]], max_new_tokens=32)
 
 Telemetry: `serve.*` events in the fusion flight recorder
-(`FLAGS_profiler_events`), `engine.stats()`, `tools/serve_bench.py`, and
-the `fusion_doctor` serving section.
+(`FLAGS_profiler_events`), `engine.stats()` and the `fusion_doctor`
+serving section; speed: the `serve_124m_backlog` cell (`PERF.md`).
 """
 from __future__ import annotations
 

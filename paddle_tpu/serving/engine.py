@@ -16,8 +16,8 @@ millions of users"), combining:
     new_pools)`` with the pools donated. Requests joining or leaving the
     batch only change the *values* of the integer inputs, never a shape:
     the decode program compiles exactly once and then serves every token
-    of every stream (`stats()["decode_compiles"]`, guarded by
-    tools/perf_smoke.py);
+    of every stream (`stats()["decode_compiles"]`, held at 1 by
+    tests/test_serving.py);
   * **bucketed prefill**: prompts are right-padded to power-of-two
     length buckets, so admitting a new request compiles at most
     ``log2(max_context)`` prefill programs ever — and never touches the
@@ -90,8 +90,7 @@ Telemetry rides the PR 4 fusion flight recorder: `serve.*` events
 resume) with reason codes `kv_exhausted` / `bucket_retrace` /
 `client_cancel` / `deadline_expired` / `queue_full` /
 `deadline_infeasible` / `step_hang` / `decode_fault` / `crash_resume`,
-aggregated by `profiler.explain` / `tools/fusion_doctor` and benched by
-`tools/serve_bench.py` + the bench.py `serve` legs.
+aggregated by `profiler.explain` / `tools/fusion_doctor`.
 """
 from __future__ import annotations
 
@@ -1025,6 +1024,12 @@ class LLMEngine:
         if self._tenant:
             base = base + (self._decode_aux(),)
         base = base + self._sampler_args()
+        # the launch is asynchronous and reads its host arguments when it
+        # runs, while this method goes on to edit `_lens` (and the next
+        # admission `_tables`, the sampler buffers) in place: hand it
+        # copies, or a slow dispatch reads the NEXT step's values
+        base = tuple(a.copy() if isinstance(a, np.ndarray) else a
+                     for a in base)
         res = self._call_decode(self._kv_args(
             *(base + (self._k_pools, self._v_pools))))
         # adopt the launch's pool lineage NOW: any prefill issued before

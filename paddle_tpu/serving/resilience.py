@@ -21,9 +21,9 @@ makes it a "millions of users" component:
     sleeps once a step is clearly slow. The yield is the load-bearing
     part: a hard spin competes with XLA's own compute threads and taxes
     the very step it watches (measured ~30%/step on a 2-core box),
-    while yield-polling benchmarks AT or BELOW the cost of the plain
-    blocking read it replaces — the <3%/step perf_smoke guard pins
-    this. No waiter threads: a cross-thread handoff costs 2+ context
+    while yield-polling costs about what the plain blocking read it
+    replaces does. No waiter threads: a cross-thread handoff costs 2+
+    context
     switches per step (~10x the guard budget) and a wedged waiter could
     not be cancelled anyway. Chaos hang faults
     (`guardian.inject_fault("hang", op="serve.decode")`) short-circuit

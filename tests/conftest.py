@@ -32,6 +32,20 @@ import numpy as np
 import pytest
 
 
+@pytest.fixture(autouse=True, scope="module")
+def _no_mesh_outlives_its_module():
+    """A worker runs many test files in one process, in an order that
+    load decides (`--dist loadfile`). A global mesh left set by one file
+    is read by the next: it is part of the AOT store's environment
+    fingerprint, so a file that compares this process with FRESH child
+    processes (tests/test_aot_cache.py) then misses every artifact they
+    wrote. That is what failed `test_same_and_disjoint_key_races` under
+    six workers and never alone."""
+    yield
+    from paddle_tpu.distributed.mesh import set_global_mesh
+    set_global_mesh(None)
+
+
 @pytest.fixture(autouse=True)
 def _seed_everything():
     np.random.seed(0)

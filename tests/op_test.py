@@ -13,6 +13,35 @@ import paddle_tpu as paddle
 from paddle_tpu.framework.core import Tensor
 
 
+def assert_within_roundings(actual, reference, roundings=8, err_msg="",
+                            scale=None):
+    """`actual` equals `reference` up to `roundings` roundings of the
+    reference's LARGEST magnitude: |a - b| <= roundings * eps * max|b|.
+    `scale` replaces max|b| where the compared array is the END of a
+    sum of larger terms (a parameter after several optimizer steps: the
+    largest magnitude it took on the way).
+
+    The claim that holds between two DIFFERENT executables of the same
+    arithmetic (a program compiled whole against the same ops dispatched
+    one by one: XLA fuses, contracts and reorders, so the last bits
+    differ). Absolute in the array's scale, not per element: an element
+    near zero carries the rounding of the large terms that cancelled
+    into it. Two runs of the SAME executable are held to
+    `np.testing.assert_array_equal` instead."""
+    a, b = np.asarray(actual), np.asarray(reference)
+    assert a.shape == b.shape and a.dtype == b.dtype, \
+        f"{err_msg}: {a.dtype}{a.shape} against {b.dtype}{b.shape}"
+    if scale is None:
+        scale = float(np.max(np.abs(b))) if b.size else 0.0
+    bound = roundings * float(np.finfo(b.dtype).eps) * scale
+    worst = float(np.max(np.abs(a.astype(np.float64)
+                                - b.astype(np.float64)))) if b.size else 0.0
+    assert worst <= bound, (
+        f"{err_msg}: largest difference {worst:.3e} is "
+        f"{worst / (bound / roundings):.2f} roundings of max|reference| = "
+        f"{scale:.6g} (bound {roundings})")
+
+
 def check_forward(op_fn, np_fn, inputs, atol=1e-5, rtol=1e-5, **op_kwargs):
     """inputs: list of np arrays. Compares op_fn(*tensors) to np_fn(*arrays)."""
     tensors = [paddle.to_tensor(a) for a in inputs]

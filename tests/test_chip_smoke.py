@@ -100,15 +100,8 @@ class TestNoChip:
         assert res.returncode != 0
         assert '"ok"' not in res.stdout
 
-    def test_bench_leg_fails_without_a_chip(self):
-        """A leg measures the chip or fails; there is no CPU version of a
-        device metric to fall back to."""
-        res = run_python("bench.py", "--config", "gpt2_train")
-        assert res.returncode != 0
-        assert '"metric"' not in res.stdout
-        assert "needs a TPU" in res.stderr
-
-    @pytest.mark.parametrize("path", ["bench.py", "tools/serve_bench.py",
+    @pytest.mark.parametrize("path", ["chip_smoke.py",
+                                      "paddle_tpu/framework/compile_cache.py",
                                       "paddle_tpu/profiler/goodput.py",
                                       "paddle_tpu/kernels/_common.py"])
     def test_measurement_paths_hold_no_downgrade(self, path):

@@ -4,7 +4,6 @@ Reference analog: python/paddle/distributed/__init__.py __all__ (38 names),
 python/paddle/reader/decorator.py tests (reader decorators), dataset
 reader-creator contract, cost_model/cost_model.py.
 """
-import os
 
 import numpy as np
 import pytest
@@ -191,9 +190,11 @@ def test_dataset_cifar_uci_imdb_imikolov():
     assert len(gram) == 5
 
 
-def test_dataset_common_split_and_cluster(tmp_path):
+def test_dataset_common_split_and_cluster(tmp_path, monkeypatch):
     import paddle_tpu.dataset.common as common
-    os.chdir(tmp_path)
+    # restored at teardown: a worker's later test files spawn children
+    # that find `paddle_tpu` through the working directory
+    monkeypatch.chdir(tmp_path)
 
     def r():
         return iter(range(10))

@@ -438,7 +438,6 @@ class TestProfilerIntegration:
 
 
 class TestDoctorCLI:
-    @pytest.mark.perf_smoke
     def test_demo_dropout_promotes_cleanly(self):
         """Universal promotion acceptance: the dropout GPT demo — the
         historical rng_rekey fixture — now reports clean_promotion."""
@@ -455,7 +454,6 @@ class TestDoctorCLI:
         rep = json.loads(out.stdout)
         assert rep["verdict"] == "clean_promotion", rep["headline"]
 
-    @pytest.mark.perf_smoke
     def test_demo_accum_promotes_cleanly(self):
         """Universal promotion acceptance: the k=4 grad-accumulation GPT
         demo promotes as a super-cycle with no rng_rekey /
@@ -476,7 +474,6 @@ class TestDoctorCLI:
         assert "rng_rekey" not in text
         assert "unpromotable_cycle" not in text
 
-    @pytest.mark.perf_smoke
     def test_demo_masked_promotes_cleanly(self):
         import subprocess
         import sys

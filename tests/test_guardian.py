@@ -739,7 +739,6 @@ class TestChaos:
         finally:
             inj.remove()
 
-    @pytest.mark.perf_smoke
     def test_kill9_resume_matches_uninterrupted_run(self):
         chaos = _load_chaos()
         res = chaos.scenario_kill(epochs=3, steps=6)

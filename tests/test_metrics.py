@@ -17,7 +17,7 @@ Contracts pinned here:
   * serving requests report TTFT / inter-token / queue-wait percentiles
     per engine AND per completed handle, emit per-request chrome-trace
     spans, and the doctor's serving verdict cites live latency;
-  * the goodput accountant reports live MFU within 2% of bench.py's
+  * the goodput accountant reports live MFU within 2% of the
     offline computation and attributes injected guardian skips and
     watchdog stalls to the right wall-time buckets.
 """
@@ -524,7 +524,6 @@ class TestServingLatency:
         assert lat["inter_token_p50_ms"] > 0
         assert lat["inter_token_p99_ms"] >= lat["inter_token_p50_ms"]
 
-    @pytest.mark.perf_smoke
     def test_64_stream_churn_metrics_on_decode_compiles_once(self,
                                                             smodel):
         """Acceptance: under 64-stream churn with the telemetry plane
@@ -641,8 +640,7 @@ class TestGoodput:
     def test_live_mfu_within_2pct_of_offline(self):
         """Acceptance: the registry-read MFU/tokens-per-second must match
         the pre-PR 12 offline computation (tokens x flops / elapsed /
-        peak) on the same run — the exact TrainStep shape bench.py
-        measures."""
+        peak) on the same run, through a `TrainStep`."""
         import jax.numpy as jnp
         from paddle_tpu.incubate.models import (GPTConfig, GPTForCausalLM,
                                                 GPTPretrainingCriterion)
@@ -798,10 +796,9 @@ class TestGoodput:
 
 
 # ---------------------------------------------------------------------------
-# perf_smoke-marked mirrors of CLI leg (k)'s non-timing guards
+# the telemetry plane's counted guards
 # ---------------------------------------------------------------------------
 
-@pytest.mark.perf_smoke
 class TestPerfGuards:
     def test_off_gate_is_silent_and_histogram_bounded(self):
         assert not pm.enabled()

@@ -443,6 +443,31 @@ class TestLogprobs:
 # ---------------------------------------------------------------------------
 
 class TestPipelined:
+    def test_launch_hands_the_program_copies_of_its_host_buffers(
+            self, model):
+        """A pipelined launch is asynchronous and the engine edits
+        `_lens`, `_tables` and the sampler buffers in place right after
+        it: an argument that shares their memory lets a slow dispatch
+        read the NEXT step's values (under load the sampled streams then
+        differ from run to run — what failed the parity test below on a
+        busy box). Every host array the program is given is a copy."""
+        eng = LLMEngine(model, max_batch_size=4, block_size=4,
+                        pipeline_decode=True)
+        eng.add_request(_prompt(9, seed=64), max_new_tokens=8,
+                        temperature=0.7, seed=11)
+        mine = [a for a in vars(eng).values() if isinstance(a, np.ndarray)]
+        seen, call = [], eng._call_decode
+
+        def spy(args):
+            seen.extend(a for a in args if isinstance(a, np.ndarray))
+            return call(args)
+
+        eng._call_decode = spy
+        eng.run()
+        assert seen, "no host array reached the decode program"
+        for a in seen:
+            assert not any(np.shares_memory(a, m) for m in mine)
+
     def test_pipelined_parity_with_unpipelined(self, model):
         """pipeline_decode=True must change WHEN tokens are committed,
         never WHICH tokens: mixed greedy+sampled streams are bitwise

@@ -12,13 +12,12 @@ Contracts pinned here:
     half the observed fraction, latency/throughput scale with `slack`,
     the reason histogram is closed, decode/prefill rebuilds get NO
     headroom;
-  * `PerfBaseline` keeps tools/perf_baselines.json honest: add requires
-    a note, save/load round-trips, split() three-ways records into
-    violations/passed/unbaselined, stale/expire retire dead legs, and
-    the checked-in file actually covers the bench + perf_smoke legs;
-  * `tools/perf_baseline.py --check` exits 0 on records inside their
-    bands, 1 on a violating or unbaselined record (naming the finding),
-    and --write-baseline seeds a loadable file;
+  * `PerfBaseline` keeps an operator's baseline file honest: add
+    requires a note, save/load round-trips, split() three-ways records
+    into violations/passed/unbaselined, stale/expire retire dead legs;
+  * no baseline file is shipped and none is looked for: a named leg
+    (`arm(leg=...)`, FLAGS_sentinel_leg, `fleet_metrics --leg`) without
+    a path the operator gave is refused with a message that says so;
   * the live watcher self-calibrates on its first active window, flags
     an injected stall storm as split_regression and a fresh engine's
     decode rebuild as compile_storm, recovers on the next clean window,
@@ -52,7 +51,6 @@ from paddle_tpu.profiler.sentinel import (PerfBaseline, bands_from_record,
                                           capture_record, classify)
 
 _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-_CLI = os.path.join(_ROOT, "tools", "perf_baseline.py")
 
 _DEFAULT_FLAGS = {
     "FLAGS_metrics": False,
@@ -239,7 +237,7 @@ class TestBands:
 
 
 # ---------------------------------------------------------------------------
-# the checked-in baseline
+# the baseline file
 # ---------------------------------------------------------------------------
 
 class TestPerfBaseline:
@@ -289,88 +287,6 @@ class TestPerfBaseline:
         path.write_text('{"version": 99, "legs": {}}')
         with pytest.raises(ValueError, match="version"):
             PerfBaseline.load(str(path))
-
-    def test_checked_in_baseline_covers_the_legs(self):
-        bl = PerfBaseline.load()
-        assert bl.policy, "checked-in baseline needs a policy line"
-        need = {"perf_smoke", "gpt2_train", "accum4", "dp8", "pp2",
-                "moe8", "serve_1", "serve_8", "serve_64",
-                "serve_8_prefix", "serve_8_sampled"}
-        missing = need - set(bl.legs)
-        assert not missing, f"unbaselined legs: {sorted(missing)}"
-        for leg, entry in bl.legs.items():
-            assert entry["note"], f"{leg} entry has no note"
-            assert "bands" in entry and "captured" in entry
-
-
-# ---------------------------------------------------------------------------
-# the CLI gate
-# ---------------------------------------------------------------------------
-
-def _cli(args, **kw):
-    return subprocess.run(
-        [sys.executable, _CLI] + args, capture_output=True, text=True,
-        env=dict(os.environ, JAX_PLATFORMS="cpu"), **kw)
-
-
-class TestPerfBaselineCLI:
-    def _seed(self, tmp_path):
-        recfile = tmp_path / "rec.json"
-        recfile.write_text(json.dumps(
-            {"extra": {"sentinel_record": _clean_record()}}))
-        blfile = tmp_path / "pb.json"
-        w = _cli(["--write-baseline", str(recfile), "--baseline",
-                  str(blfile), "--note", "unit seed", "--slack", "5"])
-        assert w.returncode == 0, w.stderr + w.stdout
-        return recfile, blfile
-
-    def test_write_then_check_passes(self, tmp_path):
-        recfile, blfile = self._seed(tmp_path)
-        assert os.path.exists(blfile)
-        r = _cli(["--check", str(recfile), "--baseline", str(blfile)])
-        assert r.returncode == 0, r.stdout + r.stderr
-        assert "0 violating" in r.stdout and "1 clean" in r.stdout
-
-    def test_violating_record_exits_1_and_names_the_finding(
-            self, tmp_path):
-        _, blfile = self._seed(tmp_path)
-        bad = tmp_path / "bad.json"
-        bad.write_text(json.dumps(_clean_record(goodput=0.1)))
-        r = _cli(["--check", str(bad), "--baseline", str(blfile)])
-        assert r.returncode == 1
-        assert "perf_drift" in r.stdout
-        assert "goodput" in r.stdout
-
-    def test_unbaselined_record_exits_1(self, tmp_path):
-        _, blfile = self._seed(tmp_path)
-        unk = tmp_path / "unk.json"
-        unk.write_text(json.dumps(_clean_record(leg="mystery")))
-        r = _cli(["--check", str(unk), "--baseline", str(blfile)])
-        assert r.returncode == 1
-        assert "mystery" in r.stdout
-
-    def test_garbage_input_exits_2(self, tmp_path):
-        bad = tmp_path / "garbage.json"
-        bad.write_text("not json at all {")
-        r = _cli(["--check", str(bad)])
-        assert r.returncode == 2
-
-    def test_checked_in_tree_is_clean_against_itself(self, tmp_path):
-        """The acceptance gate: a record rebuilt from every checked-in
-        entry's captured shape must pass --check against the file."""
-        bl = PerfBaseline.load()
-        recs = []
-        for leg, entry in bl.legs.items():
-            rec = dict(entry["captured"])
-            rec.update(leg=leg, kind=entry.get("kind") or "train",
-                       version=1)
-            rec.setdefault("buckets_s",
-                           {"productive": rec.get("window_s") or 1.0})
-            recs.append(rec)
-        f = tmp_path / "tree.json"
-        f.write_text(json.dumps(recs))
-        r = _cli(["--check", str(f)])
-        assert r.returncode == 0, r.stdout + r.stderr
 
 
 # ---------------------------------------------------------------------------
@@ -493,9 +409,59 @@ class TestLiveWatcher:
         assert not _FLAGS.get("FLAGS_metrics")
         assert not _FLAGS.get("FLAGS_profiler_events")
 
-    def test_arm_with_unknown_leg_refuses(self):
+    def test_arm_with_unknown_leg_refuses(self, tmp_path):
+        path = str(tmp_path / "pb.json")
+        bl = PerfBaseline()
+        bl.add(_clean_record(), note="n")
+        bl.save(path)
         with pytest.raises(ValueError, match="no baseline entry"):
-            snt.arm(leg="never_a_leg")
+            snt.arm(leg="never_a_leg", baseline=path)
+
+    @pytest.mark.parametrize("via", ["argument", "flag"])
+    def test_named_leg_without_a_baseline_path_refuses(self, via):
+        """No file is shipped: a leg needs the operator's path, and the
+        refusal borrows no flag and arms nothing."""
+        from paddle_tpu.framework.flags import _FLAGS
+        assert not _FLAGS.get("FLAGS_sentinel_baseline")
+        try:
+            with pytest.raises(ValueError, match="needs a baseline file"):
+                if via == "flag":
+                    set_flags({"FLAGS_sentinel_leg": "unit"})
+                    snt.arm()
+                else:
+                    snt.arm(leg="unit")
+        finally:
+            set_flags({"FLAGS_sentinel_leg": ""})
+        assert not snt.SENTINEL.armed
+        assert not _FLAGS.get("FLAGS_metrics")
+        assert not _FLAGS.get("FLAGS_profiler_events")
+
+    def test_named_leg_arms_from_the_operators_file(self, tmp_path):
+        path = str(tmp_path / "pb.json")
+        bl = PerfBaseline()
+        entry = bl.add(_clean_record(), note="n")
+        bl.save(path)
+        snt.arm(leg="unit", baseline=path, window_s=5.0)
+        try:
+            assert snt.SENTINEL.band_source == "baseline"
+            assert snt.SENTINEL.bands == entry["bands"]
+        finally:
+            snt.disarm()
+
+    def test_no_default_baseline_file_is_looked_for(self):
+        assert not hasattr(snt, "DEFAULT_PERF_BASELINE")
+        with pytest.raises(TypeError):
+            PerfBaseline.load()
+        with pytest.raises(TypeError):
+            PerfBaseline().save()
+        r = subprocess.run(
+            [sys.executable, os.path.join(_ROOT, "tools",
+                                          "fleet_metrics.py"),
+             "--sink", "nothing.jsonl", "--leg", "unit"],
+            capture_output=True, text=True,
+            env=dict(os.environ, JAX_PLATFORMS="cpu"))
+        assert r.returncode == 2
+        assert "--leg needs --baseline" in r.stderr
 
     def test_stall_storm_flips_split_regression_then_recovers(
             self, smodel):

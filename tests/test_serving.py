@@ -311,9 +311,8 @@ class TestZeroRetrace:
         for i, out in enumerate(outs):
             assert out == refs[lengths[i % len(lengths)]], f"stream {i}"
 
-    @pytest.mark.perf_smoke
     def test_churn_occupancy_saturated(self, model):
-        """perf_smoke guard (mirrors tools/perf_smoke.py leg e): under
+        """Under
         saturation (demand >= slots) continuous batching must keep the
         slots >= 75% full, and the decode program must not retrace."""
         prompts = [_prompt(3 + (i % 9), seed=8) for i in range(24)]
@@ -834,12 +833,10 @@ class TestCrashResume:
         with pytest.raises(CheckpointCorruptError, match="refusing"):
             ck.restore()
 
-    @pytest.mark.perf_smoke
     def test_decode_compiles_once_under_lifecycle_churn(self, model):
         """The acceptance criterion: cancel/expire/refuse/resume are
         VALUE edits to the fixed slot layout — the decode executable
-        compiles exactly once through all of it (mirrors
-        tools/perf_smoke.py leg g)."""
+        compiles exactly once through all of it."""
         set_flags({"FLAGS_serve_step_timeout_ms": 2000})
         engine = LLMEngine(model, max_batch_size=4, block_size=4,
                            max_queue_depth=6)

@@ -20,7 +20,6 @@ import json
 import os
 import subprocess
 import sys
-import time
 
 import numpy as np
 import jax
@@ -620,53 +619,69 @@ class TestShardMapContract:
 
 
 # ---------------------------------------------------------------------------
-# perf guard + doctor fixture
+# counted guard + doctor fixture
 # ---------------------------------------------------------------------------
+
+def _dp_loop():
+    """A dp=N data-parallel MLP loop: batch sharded over a mesh spanning
+    every device. With step fusion on, the cycle must promote through
+    the SPMD lowering (ops/spmd_fusion.py): ONE shard_map executable per
+    step."""
+    set_flags({"FLAGS_eager_chain_fusion_min_count": 4,
+               "FLAGS_eager_step_fusion_min_count": 5})
+    clear_dispatch_cache()
+    mesh = build_mesh(dp=N_DEV, pp=1, sharding=1, sep=1, mp=1)
+    set_global_mesh(mesh)
+    sharding = NamedSharding(mesh, P("data"))
+    rng = np.random.default_rng(0)
+    x = paddle.Tensor(jax.device_put(
+        rng.standard_normal((8 * N_DEV, 32)).astype(np.float32), sharding),
+        stop_gradient=True)
+    y = paddle.Tensor(jax.device_put(
+        rng.standard_normal((8 * N_DEV, 16)).astype(np.float32), sharding),
+        stop_gradient=True)
+    w1 = paddle.to_tensor(
+        (rng.standard_normal((32, 64)) * 0.1).astype(np.float32),
+        stop_gradient=False)
+    b1 = paddle.to_tensor(np.zeros(64, np.float32), stop_gradient=False)
+    w2 = paddle.to_tensor(
+        (rng.standard_normal((64, 16)) * 0.1).astype(np.float32),
+        stop_gradient=False)
+    opt = paddle.optimizer.Momentum(learning_rate=1e-3, momentum=0.9,
+                                    parameters=[w1, b1, w2])
+
+    def step():
+        h = F.relu(paddle.add(paddle.matmul(x, w1), b1))
+        out = paddle.matmul(h, w2)
+        diff = paddle.subtract(out, y)
+        loss = paddle.mean(paddle.multiply(diff, diff))
+        loss.backward()
+        opt.step()
+        opt.clear_grad()
+
+    return step
+
 
 @needs_mesh
 class TestPerfGuards:
-    @pytest.mark.perf_smoke
-    def test_promoted_dp_step_beats_eager_collectives(self):
-        """The perf_smoke leg (i) as a pytest: zero retraces after
-        promotion and ≥1.3x over the unfused eager-collective loop."""
-        sys.path.insert(0, os.path.join(os.path.dirname(__file__),
-                                        os.pardir, "tools"))
-        import perf_smoke
-
-        def timed(step):
-            best = float("inf")
-            for _ in range(3):
-                t0 = time.perf_counter()
-                for _ in range(perf_smoke.MEASURE):
-                    step()
-                step.sync()
-                best = min(best,
-                           (time.perf_counter() - t0) / perf_smoke.MEASURE)
-            return best
-
-        step = perf_smoke._dp_loop(step_fused=False)
-        for _ in range(perf_smoke.WARMUP):
+    def test_promoted_dp_step_is_one_executable_without_retraces(self):
+        """Zero retraces after warm-up and ONE promoted executable, the
+        SPMD one, fired once a step. (Its speed against eager collectives
+        is a mesh cell's to say, on the chips.)"""
+        step, warmup, measure = _dp_loop(), 14, 40
+        for _ in range(warmup):
             step()
-        step.sync()
-        t_eager = timed(step)
-        step = perf_smoke._dp_loop(step_fused=True)
-        for _ in range(perf_smoke.WARMUP):
-            step()
-        step.sync()
         s0 = step_fusion_stats()
-        t_fused = timed(step)
+        for _ in range(measure):
+            step()
         s1 = step_fusion_stats()
         assert s1["retraces"] == s0["retraces"], "post-promotion retrace"
-        assert s1["fused_steps"] > s0["fused_steps"]
-        assert next((p["spmd"] for p in step_cache_info()["programs"]
-                     if p["spmd"]), None) == f"data{N_DEV}"
-        speedup = t_eager / t_fused
-        assert speedup >= perf_smoke.DP_SPEEDUP_GUARD, (
-            f"promoted DP step speedup {speedup:.2f}x below "
-            f"{perf_smoke.DP_SPEEDUP_GUARD}x (eager {t_eager*1e6:.0f}us "
-            f"vs fused {t_fused*1e6:.0f}us)")
+        assert s1["fused_steps"] - s0["fused_steps"] == measure
+        assert s1["steps_promoted"] == 1
+        assert s1["fallback_splits"] == s0["fallback_splits"]
+        assert [p["spmd"] for p in step_cache_info()["programs"]
+                if p["spmd"]] == [f"data{N_DEV}"]
 
-    @pytest.mark.perf_smoke
     def test_doctor_demo_dp_names_collective_unkeyed(self):
         out = subprocess.run(
             [sys.executable,

@@ -402,7 +402,6 @@ class TestHealth:
 # ---------------------------------------------------------------------------
 
 class TestScrapeChurn:
-    @pytest.mark.perf_smoke
     def test_100hz_scrape_under_64_stream_churn(self, smodel):
         """Satellite: a scraper hammering /metrics + /doctor at ~100 Hz
         while 64 mixed streams churn must leave decode_compiles == 1 and
@@ -771,7 +770,7 @@ class TestFleetGenerations:
 
 
 # ---------------------------------------------------------------------------
-# fusion_doctor --url + bench autopsy probe
+# fusion_doctor --url
 # ---------------------------------------------------------------------------
 
 class TestRemoteDoctor:
@@ -802,19 +801,3 @@ class TestRemoteDoctor:
                                  "--json"])
         assert rc == 1
         assert "could not reach" in capsys.readouterr().err
-
-    def test_bench_autopsy_probe_reads_live_child(self):
-        """Satellite: the bench harness's timeout autopsy helper reads
-        last_heartbeat_age_s + the live goodput snapshot off a child's
-        telemetry server (what rounds 3-4 were missing)."""
-        set_flags({"FLAGS_metrics": True})
-        srv = ts.start(port=0)
-        _train_loop(3)
-        sys.path.insert(0, _ROOT)
-        import bench
-        autopsy = bench._probe_child_health(srv.port)
-        assert autopsy["healthz"]["last_heartbeat_age_s"] is not None
-        assert autopsy["goodput"]["steps"] == 3
-        # an unreachable child degrades to a note, never a raise
-        dead = bench._probe_child_health(bench._alloc_port())
-        assert "unreachable" in dead["healthz"]

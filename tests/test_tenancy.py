@@ -769,11 +769,10 @@ class TestTenantCrashResume:
 # ---------------------------------------------------------------------------
 
 class TestCombined:
-    @pytest.mark.perf_smoke
     def test_prefix_adapters_swap_one_executable(self):
         """Scaled-down ISSUE 17 acceptance: streams over mixed tenants
         with a shared prefix, a mid-run weight swap — ONE decode
-        compile through all of it (mirrors tools/perf_smoke.py leg o)."""
+        compile through all of it."""
         m1 = _make_model(seed=0)
         m2 = _make_model(seed=1)
         w2 = [np.asarray(p._value) for p in m2.parameters()]

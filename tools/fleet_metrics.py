@@ -370,26 +370,25 @@ def main(argv=None) -> int:
                     help="print the full fleet view as JSON")
     ap.add_argument("--leg", default=None,
                     help="classify every host against this perf-baseline "
-                         "leg's tolerance bands (tools/perf_baselines."
-                         "json) — cross-host straggler detection with "
-                         "the regression sentinel's own classify()")
+                         "leg's tolerance bands (needs --baseline) — "
+                         "cross-host straggler detection with the "
+                         "regression sentinel's own classify()")
     ap.add_argument("--baseline", default=None, metavar="FILE",
-                    help="with --leg: the perf baseline file (default: "
-                         "tools/perf_baselines.json)")
+                    help="with --leg: the perf baseline file "
+                         "(sentinel.PerfBaseline JSON; none is shipped)")
     args = ap.parse_args(argv)
     if not args.url and not args.sink:
         ap.error("at least one --url or --sink is required")
+    if args.leg and not args.baseline:
+        ap.error("--leg needs --baseline FILE (no baseline is shipped)")
 
     bands = None
     if args.leg:
-        from paddle_tpu.profiler.sentinel import (DEFAULT_PERF_BASELINE,
-                                                  PerfBaseline)
-        bl = PerfBaseline.load(args.baseline or DEFAULT_PERF_BASELINE)
-        entry = bl.match(args.leg)
+        from paddle_tpu.profiler.sentinel import PerfBaseline
+        entry = PerfBaseline.load(args.baseline).match(args.leg)
         if entry is None:
             print(f"fleet_metrics: no perf-baseline entry for leg "
-                  f"{args.leg!r} (run tools/perf_baseline.py --list)",
-                  file=sys.stderr)
+                  f"{args.leg!r} in {args.baseline}", file=sys.stderr)
             return 1
         bands = entry.get("bands") or {}
 

@@ -51,7 +51,7 @@ def main(argv=None) -> int:
                     "contracts (R1-R6) before anything runs")
     ap.add_argument("paths", nargs="*",
                     help="files/directories to scan (default: the "
-                         "package + tools + bench.py)")
+                         "package + tools)")
     ap.add_argument("--root", default=None,
                     help="repo root for relative paths/reporting "
                          "(default: the checkout containing this tool)")
