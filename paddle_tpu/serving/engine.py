@@ -11,9 +11,11 @@ millions of users"), combining:
     shared by every sequence, per-sequence block tables, admission /
     eviction / preemption as integer-table edits;
   * a **compiled decode step**: a single `jax.jit` executable over a
-    fixed max-batch slot layout — ``(tokens [S], block_tables [S, M],
-    seq_lens [S], active [S], k_pools, v_pools) -> (next_tokens,
-    new_pools)`` with the pools donated. Requests joining or leaving the
+    fixed max-batch slot layout — ``(tokens [S], feedback [S],
+    override [S], block_tables [S, M], seq_lens [S], active [S], k_pools,
+    v_pools) -> (next_tokens, new_pools)`` with the pools donated (a
+    slot's input is the host's token where `override` is set, else the
+    launch before's, still on the device). Requests joining or leaving the
     batch only change the *values* of the integer inputs, never a shape:
     the decode program compiles exactly once and then serves every token
     of every stream (`stats()["decode_compiles"]`, held at 1 by
@@ -27,8 +29,10 @@ millions of users"), combining:
     free-block watermark admission, LIFO preempt-resume via block
     tables, join/leave at token boundaries;
   * **streaming detokenization**: per-request `on_token` callbacks fire
-    the moment a token is produced (optionally through a tokenizer's
-    `decode`), not when the request completes;
+    when a token is committed (optionally through a tokenizer's
+    `decode`), not when the request completes: one `step()` after the
+    token's decode launch, the loop being pipelined at lag 1 (see
+    `LLMEngine`);
   * a **kernel tier** (PR 11): the decode step's paged attention runs
     blockwise streaming softmax over the block table
     (kernels/pallas/paged_attention.py — Pallas on TPU, a `lax.scan`
@@ -54,8 +58,10 @@ Resilience (PR 7, serving/resilience.py) rides every one of those layers:
   * **hung-step watchdog** — decode/prefill fires resolve through a
     monitored completion bounded by `FLAGS_serve_step_timeout_ms`; a
     stuck step emits `serve.hang`, marks the engine degraded, and climbs
-    a recovery ladder (retry -> rebuild the decode executable -> fail
-    the active requests with attributed reasons) instead of wedging;
+    a recovery ladder instead of wedging: the pipelined loop, which
+    waits for a launch at its lag-1 commit, retries the wait and then
+    fails the active requests with attributed reasons; the serial loop
+    retries the step, rebuilds the decode executable, then fails them;
   * **degraded-mode fallback** — a faulting/poisoned compiled decode
     finishes its in-flight streams per-request through the eager
     `generate()` path, token-identically, then rebuilds;
@@ -198,6 +204,11 @@ class ServeStats:
         # expired / preempted / finished between launch and commit
         self.sampled_tokens = 0
         self.commit_rollbacks = 0
+        # decode launches, and those issued while the launch before them
+        # was still uncommitted: the host's turn after such a launch ran
+        # beside the device (the serial tail never has one in flight)
+        self.launches = 0
+        self.launches_overlapped = 0
         # decode attention, in block-table entries summed over the decode
         # launches: what the attention's loops read, what held a token,
         # and slots x entries (kernels/pallas/paged_attention.py
@@ -269,6 +280,9 @@ class ServeStats:
             "weight_swaps": self.weight_swaps,
             "sampled_tokens": self.sampled_tokens,
             "commit_rollbacks": self.commit_rollbacks,
+            "pipelined_launch_share": (
+                self.launches_overlapped / self.launches
+                if self.launches else 0.0),
             # share of the block table the decode attention read / that
             # held tokens (streamed == held is the ideal, 1.0 a loop over
             # the whole table)
@@ -338,6 +352,22 @@ class LLMEngine:
     `enable_prefix_cache=True` adds shared-prefix KV block aliasing with
     copy-on-write — N streams sharing a system prompt pay its prefill
     and its KV bytes once.
+
+    The loop is pipelined at lag 1 (`pipeline_decode=True`, the default):
+    a `step()` launches decode N+1 from launch N's tokens where they are,
+    on the device, and only then commits launch N, so the host's turn
+    (callbacks, retirement, the caller's own work between steps, the next
+    admission) runs beside the decode program and not after it. For a
+    caller that means: a decoded token's `on_token` runs one `step()`
+    after the step that launched it (a request's first token, sampled by
+    its prefill, still arrives in the step that admits it); a stream that
+    is cancelled, expires or is preempted loses at most the one token it
+    had in flight (`stats()["commit_rollbacks"]`), and one that ends by
+    `max_new_tokens` loses none; a finished request's slot is refilled one
+    boundary later; `step()` keeps returning True until the last launch
+    is committed. WHICH tokens are served does not change: streams are
+    token-identical to `pipeline_decode=False`, the serial loop (launch,
+    wait, commit inside one step), which the tests compare them with.
     """
 
     def __init__(self, model, max_batch_size=8, block_size=16,
@@ -346,7 +376,7 @@ class LLMEngine:
                  aging_max_preemptions=3, kv_dtype=None,
                  attention_kernel=None, enable_prefix_cache=False,
                  max_adapters=0, adapter_rank=4, hot_swap=False,
-                 logprobs_topk=0, pipeline_decode=False):
+                 logprobs_topk=0, pipeline_decode=True):
         cfg = model.config
         model.eval()
         self._model = model
@@ -457,12 +487,16 @@ class LLMEngine:
         # its own input token at index `lens` in-graph, so the one token
         # the host has not committed yet (pipelined mode) is still seen
         self._history = np.zeros((s, self.max_context), np.int32)
-        # -- software-pipelined decode (PR 18) --------------------------
+        # -- software-pipelined decode (PR 18; the default loop) --------
         # launch step N+1 against device-fed tokens while step N's host
-        # commit overlaps: `_inflight` holds the un-committed launch,
-        # `_feedback` the device next-token array it will consume, and
-        # `_override[slot]` marks slots whose HOST token (admission /
-        # chew / restore) must win over the device feedback
+        # commit overlaps: `_inflight` holds the un-committed launch
+        # (its result arrays, and by slot the request, position and
+        # admission it decoded for), `_feedback` the device next-token
+        # array the next launch will consume, and `_override[slot]` marks
+        # slots whose HOST token (admission / chew / restore) must win
+        # over the device feedback: the decode program selects. The
+        # serial tail never clears a mark, so it feeds every slot from
+        # the host
         self._pipeline = bool(pipeline_decode)
         self._inflight = None
         self._feedback = None
@@ -751,11 +785,14 @@ class LLMEngine:
             self._expire(req)
 
     def step(self):
-        """One engine iteration: expire/cancel at the boundary, admit,
-        grow/evict for KV headroom, run the ONE compiled decode step
-        under the watchdog, stream the produced tokens, retire finished
-        requests. Returns True while any request is running or
-        waiting."""
+        """One engine iteration: expire/cancel at the boundary, admit
+        (one prefill a request), grow/evict for KV headroom, LAUNCH the
+        ONE compiled decode step for every running slot, then stream the
+        tokens of the launch BEFORE it and retire finished requests while
+        the new one runs (`pipeline_decode=False`: wait for this step's
+        own launch and stream that). Both waits are the watchdog's.
+        Returns True while any request is running or waiting, or a
+        launch is uncommitted."""
         if self._stats.wall_t0 is None:
             self._stats.wall_t0 = time.perf_counter()
         self._hb_ns = time.perf_counter_ns()
@@ -989,49 +1026,32 @@ class LLMEngine:
         if self._decode_fn is None:
             self._compile_grace_ns = time.perf_counter_ns()
             self._decode_fn = self._build_decode()
-        launch_active = self._active.copy()
+        # the launch is asynchronous and reads its host arguments when it
+        # runs, while this method goes on to edit `_lens`, `_tokens` and
+        # `_override` (and the next admission `_tables`, the sampler
+        # buffers) in place: hand it copies, or a slow dispatch reads the
+        # NEXT step's values
+        args = tuple(a.copy() if isinstance(a, np.ndarray) else a
+                     for a in self._decode_args())
+        launch_active = args[self._ARG_ACTIVE]
+        pending = self._inflight["records"] if self._inflight else {}
         plan = []
         for req in list(sched.running):
             if req.state != RUNNING or req.slot is None:
                 continue
-            slot = req.slot
-            pending = 1 if self._has_pending(req, slot) else 0
+            rec = pending.get(req.slot)
+            in_flight = rec is not None and rec[0] is req
             if (not req.chew
-                    and len(req.generated) + pending
+                    and len(req.generated) + in_flight
                     >= req.max_new_tokens):
                 # every remaining token is committed or in flight —
                 # launching this slot could only overshoot max_new
-                launch_active[slot] = False
+                launch_active[req.slot] = False
                 continue
-            plan.append((req, slot))
+            plan.append(req)
         if not plan:
             return None
-        tokens_in = self._tokens
-        if self._feedback is not None and not self._override.all():
-            if self._override.any():
-                # mixed: device feedback for slots whose last token
-                # exists only on-device, host-authored tokens
-                # (admission/chew/restore) win via the override mask
-                tokens_in = jnp.where(jnp.asarray(self._override),
-                                      jnp.asarray(self._tokens),
-                                      self._feedback).astype(jnp.int32)
-            else:
-                # steady state (no joins/chew since the last launch):
-                # the previous launch's output feeds straight back in —
-                # zero host round-trip, zero extra dispatches
-                tokens_in = self._feedback
-        base = (tokens_in, self._tables, self._lens, launch_active)
-        if self._tenant:
-            base = base + (self._decode_aux(),)
-        base = base + self._sampler_args()
-        # the launch is asynchronous and reads its host arguments when it
-        # runs, while this method goes on to edit `_lens` (and the next
-        # admission `_tables`, the sampler buffers) in place: hand it
-        # copies, or a slow dispatch reads the NEXT step's values
-        base = tuple(a.copy() if isinstance(a, np.ndarray) else a
-                     for a in base)
-        res = self._call_decode(self._kv_args(
-            *(base + (self._k_pools, self._v_pools))))
+        res = self._call_decode(args)
         # adopt the launch's pool lineage NOW: any prefill issued before
         # the commit must consume THESE outputs, so XLA's dataflow
         # orders the speculative KV write before the reuse
@@ -1039,8 +1059,11 @@ class LLMEngine:
         if self._kv_quantized:
             self._k_scales, self._v_scales = res[6], res[7]
         self._feedback = res[0]
-        records = []
-        for req, slot in plan:
+        # by slot, what the commit needs to know the token is still
+        # wanted: the request, its position and its admission
+        records = {}
+        for req in plan:
+            slot = req.slot
             req.cached_len += 1
             self._lens[slot] = req.cached_len
             if req.chew:
@@ -1050,8 +1073,9 @@ class LLMEngine:
                     self._history[slot, req.cached_len] = t
                 self._override[slot] = True
             else:
-                records.append((req, slot, req.cached_len,
-                                req.admit_seq))
+                # the slot's next input exists only on the device, as
+                # this launch's result
+                records[slot] = (req, req.cached_len, req.admit_seq)
                 self._override[slot] = False
         return {"res": res, "records": records}
 
@@ -1072,7 +1096,7 @@ class LLMEngine:
             return False
         toks, logps, aids, alps = out
         with self._span("engine.stream"):
-            for req, slot, pos, aseq in inf["records"]:
+            for slot, (req, pos, aseq) in inf["records"].items():
                 if (req.state != RUNNING or req.slot != slot
                         or req.admit_seq != aseq):
                     self._rollback(req, slot)
@@ -1146,15 +1170,8 @@ class LLMEngine:
             return (np.asarray(res[0]), np.asarray(res[1]),
                     np.asarray(res[2]), np.asarray(res[3]))
 
-    def _has_pending(self, req, slot):
-        inf = self._inflight
-        if inf is None:
-            return False
-        return any(r is req and s == slot
-                   for r, s, _p, _a in inf["records"])
-
     def _discard_records(self, inf):
-        for req, slot, _pos, _aseq in inf["records"]:
+        for slot, (req, _pos, _aseq) in inf["records"].items():
             self._rollback(req, slot)
 
     def _rollback(self, req, slot):
@@ -1397,7 +1414,12 @@ class LLMEngine:
                                             self._v_pools))),
                     first and attempt == 1)
                 with self._span("engine.prefill.wait"):
-                    self._monitor.wait(res, "prefill", attempt)
+                    # behind an uncommitted decode launch the prefill
+                    # waits for that program too: two steps' budget, or a
+                    # healthy prefill reads as hung
+                    self._monitor.wait(
+                        res, "prefill", attempt,
+                        programs=1 + (self._inflight is not None))
                     # the sampled token on the host (kept on the array for
                     # `_admit`): with the watchdog disarmed THIS is where
                     # the host waits for the program
@@ -1587,13 +1609,7 @@ class LLMEngine:
         attempt = 1
         while True:
             try:
-                base = (self._tokens, self._tables, self._lens,
-                        self._active)
-                if self._tenant:
-                    base = base + (self._decode_aux(),)
-                base = base + self._sampler_args()
-                res = self._call_decode(self._kv_args(
-                    *(base + (self._k_pools, self._v_pools))))
+                res = self._call_decode(self._decode_args())
                 with self._span("engine.decode.wait"):
                     self._monitor.wait(res, "decode", attempt)
             except StepHang:
@@ -1627,12 +1643,39 @@ class LLMEngine:
                 return (np.asarray(nxt), np.asarray(res[1]),
                         np.asarray(res[2]), np.asarray(res[3]))
 
+    # where `_decode_args` puts the lengths and the mask of active slots
+    _ARG_LENS, _ARG_ACTIVE = 4, 5
+
+    def _decode_args(self):
+        """The decode program's positional arguments, from the engine's
+        own buffers — the single source of truth shared by both tails,
+        the AOT spec builder and the tests that lower the program.
+        `feedback` is the previous launch's sampled tokens where they are,
+        on the device; the program takes a slot's input from it unless
+        `override` says the host wrote the slot's token (admission, chew,
+        restore). With no launch to feed from every slot is overridden
+        and the host's tokens stand in for it."""
+        feedback = self._tokens if self._feedback is None \
+            else self._feedback
+        base = (self._tokens, feedback, self._override, self._tables,
+                self._lens, self._active)
+        if self._tenant:
+            base = base + (self._decode_aux(),)
+        return self._kv_args(*(base + (
+            self._temps, self._topks, self._topps, self._rpens,
+            self._seeds, self._history, self._k_pools, self._v_pools)))
+
     def _call_decode(self, args):
-        self._count_attention(args[2], args[3])
         fn = self._decode_fn
+        stats = self._stats
+        stats.launches += 1
+        stats.launches_overlapped += self._inflight is not None
         res = self._call_program("engine.decode.dispatch", fn, args,
                                  fn is not self._decode_called)
         self._decode_called = fn
+        # counted behind the dispatch, beside the device
+        self._count_attention(args[self._ARG_LENS],
+                              args[self._ARG_ACTIVE])
         return res
 
     def _count_attention(self, lens, active):
@@ -1648,13 +1691,6 @@ class LLMEngine:
             streamed if self._attn_kernel == "blockwise" else total)
         stats.attn_entries_held += held
         stats.attn_entries_total += total
-
-    def _sampler_args(self):
-        """The decode signature's per-slot sampler VALUE inputs, in
-        positional order — the single source of truth shared by the live
-        call, the AOT spec builder, and the pipelined launch."""
-        return (self._temps, self._topks, self._topps, self._rpens,
-                self._seeds, self._history)
 
     def _pools_consumed(self):
         deleted = getattr(self._k_pools, "is_deleted", None)
@@ -1958,7 +1994,9 @@ class LLMEngine:
                  # version, the static logprob panel width and the
                  # history buffer width all change the executable
                  ("sampler", SAMPLER_VERSION, self._logprobs_topk,
-                  self.max_context)))
+                  self.max_context),
+                 # the input select (tokens, feedback, override)
+                 "feedback"))
         except Exception:
             dg = None
         self._aot_digest_cache = dg or ""
@@ -1976,11 +2014,7 @@ class LLMEngine:
         if not _aot.enabled() or _aot.has_artifact("decode", digest):
             return
         try:
-            specs = tuple(_aot._spec_of(a) for a in self._kv_args(
-                self._tokens, self._tables, self._lens, self._active,
-                self._temps, self._topks, self._topps, self._rpens,
-                self._seeds, self._history,
-                self._k_pools, self._v_pools))
+            specs = tuple(_aot._spec_of(a) for a in self._decode_args())
             blobs = [_aot.export_bytes(jitted, specs)]
         except Exception as e:
             from ..profiler.aot import STATS as _ASTATS
@@ -2005,10 +2039,13 @@ class LLMEngine:
         variant = self._attn_kernel
         lp_topk = self._logprobs_topk
 
-        def decode(tokens, tables, lens, active, temps, topks, topps,
-                   rpens, seeds, history, k_pools, v_pools,
-                   k_scales=None, v_scales=None):
+        def decode(tokens, feedback, override, tables, lens, active,
+                   temps, topks, topps, rpens, seeds, history, k_pools,
+                   v_pools, k_scales=None, v_scales=None):
             stats.decode_compiles += 1   # runs only while tracing
+            # a slot's input is the token the launch before sampled for
+            # it, still on the device, unless the host wrote one
+            tokens = jnp.where(override, tokens, feedback)
             # ONE view over the stacked (donated) pools: every layer
             # writes at its own index and hands them on, so the pools
             # the last layer returns are the step's, updated in place
@@ -2039,7 +2076,7 @@ class LLMEngine:
                 written += (view.k_scales, view.v_scales)
             return (nxt, logp, alt_ids, alt_lps) + written
 
-        donate = (10, 11, 12, 13) if self._kv_quantized else (10, 11)
+        donate = (12, 13, 14, 15) if self._kv_quantized else (12, 13)
         jitted = jax.jit(decode, donate_argnums=self._donate(donate))
         from ..ops import aot_cache as _aot
         if use_aot and _aot.enabled():
@@ -2077,10 +2114,11 @@ class LLMEngine:
         holder = self._holder
         lp_topk = self._logprobs_topk
 
-        def decode(tokens, tables, lens, active, aux, temps, topks,
-                   topps, rpens, seeds, history, k_pools, v_pools,
-                   k_scales=None, v_scales=None):
+        def decode(tokens, feedback, override, tables, lens, active, aux,
+                   temps, topks, topps, rpens, seeds, history, k_pools,
+                   v_pools, k_scales=None, v_scales=None):
             stats.decode_compiles += 1   # runs only while tracing
+            tokens = jnp.where(override, tokens, feedback)
             pvals = aux.get("params")
             saved = None
             if pvals is not None:
@@ -2117,7 +2155,7 @@ class LLMEngine:
                 written += (view.k_scales, view.v_scales)
             return (nxt, logp, alt_ids, alt_lps) + written
 
-        donate = (11, 12, 13, 14) if self._kv_quantized else (11, 12)
+        donate = (13, 14, 15, 16) if self._kv_quantized else (13, 14)
         return jax.jit(decode, donate_argnums=self._donate(donate))
 
     def _build_prefill(self, bucket):

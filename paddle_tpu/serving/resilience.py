@@ -97,10 +97,12 @@ _COARSE_SLEEP_S = 0.001
 class MonitoredWait:
     """Bounded wait on a step's result arrays.
 
-    `wait(arrays, phase, attempt)` returns normally once the arrays are
-    ready (or immediately when the watchdog is disarmed — the caller
-    then blocks on the host transfer exactly as before PR 7); raises
-    `StepHang` when the budget elapses first. An armed chaos "hang"
+    `wait(arrays, phase, attempt, programs)` returns normally once the
+    arrays are ready (or immediately when the watchdog is disarmed — the
+    caller then blocks on the host transfer exactly as before PR 7); raises
+    `StepHang` when the budget elapses first (`programs` budgets where
+    the wait covers that many programs on the device's queue: a prefill
+    dispatched behind a pipelined decode launch). An armed chaos "hang"
     injector for `op=f"serve.{phase}"` trips the hang path
     deterministically without consuming the budget in real time — each
     ladder rung re-polls, so `times=N` hangs exactly N attempts. A
@@ -117,10 +119,14 @@ class MonitoredWait:
         return (self._budget_s if self._budget_s is not None
                 else watchdog_budget_s()) is not None
 
-    def wait(self, arrays, phase, attempt=1):
+    def wait(self, arrays, phase, attempt=1, programs=1):
         from ..ops import guardian
         budget = (self._budget_s if self._budget_s is not None
                   else watchdog_budget_s())
+        if budget is not None:
+            # the budget is one step's: a wait that also covers programs
+            # queued ahead of its own is allowed one budget for each
+            budget *= programs
         if guardian.faults_armed():
             kind = guardian.poll_fault(f"serve.{phase}",
                                        ("hang", "stall"))

@@ -349,14 +349,14 @@ class TestInt8KV:
             bound = sc[:, None] / QMAX * (0.5 * bs)
             assert (np.abs(deq[i] - vec) <= bound + 1e-6).all(), i
 
-    def test_int8_greedy_decode_token_identical_to_fp32(self, model):
+    def test_int8_greedy_decode_token_identical_to_fp32(self, model, loop):
         """End-to-end: the int8-KV engine reproduces the fp32 reference
         stream token for token on the tiny-GPT fixture — including under
         preemption churn (evict -> requeue -> re-prefill requantizes)."""
         prompts = [_prompt(n, seed=21) for n in (11, 5, 17, 3)]
         refs = [_ref(model, p, 10) for p in prompts]
         engine = LLMEngine(model, max_batch_size=4, block_size=4,
-                           kv_dtype="int8")
+                           kv_dtype="int8", pipeline_decode=loop)
         outs = engine.generate(prompts, max_new_tokens=10)
         assert outs == refs
         st = engine.stats()
@@ -367,7 +367,7 @@ class TestInt8KV:
         refs2 = [_ref(model, p, 10) for p in prompts2]
         churn = LLMEngine(model, max_batch_size=3, block_size=4,
                           num_blocks=10, watermark_blocks=1,
-                          kv_dtype="int8")
+                          kv_dtype="int8", pipeline_decode=loop)
         outs2 = churn.generate(prompts2, max_new_tokens=10)
         st2 = churn.stats()
         assert st2["evictions"] >= 1
@@ -676,11 +676,13 @@ class TestLengthBoundedLoop:
         assert st["attn_streamed_share"] == 1.0
         assert st["attn_held_share"] == pytest.approx(shares[1024][1])
 
-    def test_engine_at_two_widths_serves_generates_tokens(self, model):
+    def test_engine_at_two_widths_serves_generates_tokens(self, model,
+                                                          loop):
         """128 slots run at two widths: the ordered decode step serves
         exactly `model.generate`'s tokens, compiled once."""
         prompts = [_prompt(3 + (i * 5) % 23, seed=29) for i in range(140)]
-        engine = LLMEngine(model, max_batch_size=128, block_size=4)
+        engine = LLMEngine(model, max_batch_size=128, block_size=4,
+                           pipeline_decode=loop)
         assert _blockwise_plan(128, engine.max_blocks_per_seq, 4, 4, 8)[0] \
             == (128, 64)
         outs = engine.generate(prompts, max_new_tokens=6)

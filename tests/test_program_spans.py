@@ -90,7 +90,8 @@ def traced(tmp_path_factory):
     (plain and pipelined), inside the caller's own annotation."""
     out = str(tmp_path_factory.mktemp("trace"))
     step, x, y = tiny_train_step(), *batch(16)
-    engine, piped = tiny_engine(), tiny_engine(pipeline_decode=True)
+    engine = tiny_engine(pipeline_decode=False)
+    piped = tiny_engine(pipeline_decode=True)
     jax.profiler.start_trace(out)
     try:
         with jax.profiler.TraceAnnotation("caller.window"):
@@ -346,9 +347,7 @@ def test_the_decode_attention_and_its_kv_write_are_scoped(variant):
 def test_sampling_scatter_head_and_loss_are_scoped():
     engine = tiny_engine()
     engine.generate(PROMPTS[:1], max_new_tokens=2)
-    decode = engine._decode_fn.lower(*engine._kv_args(
-        engine._tokens, engine._tables, engine._lens, engine._active,
-        *engine._sampler_args(), engine._k_pools, engine._v_pools))
+    decode = engine._decode_fn.lower(*engine._decode_args())
     text = decode.as_text(debug_info=True)
     for scope in ("paged_attention", "paged_kv_write", "sample_tokens",
                   "lm_head"):

@@ -42,6 +42,8 @@ from paddle_tpu.serving.sampling import (SAMPLER_VERSION, default_seed,
                                          apply_temperature, apply_top_k,
                                          apply_top_p, sample_tokens)
 
+import serving_backlog as backlog
+
 VOCAB = 128
 
 
@@ -339,17 +341,20 @@ class TestSampledDeterminism:
             assert list(g.generated) == list(r.generated)
 
     def test_rung2_rebuild_replays_sampled_streams(self, model):
-        """Two consecutive hangs climb to rung 2: the decode executable
-        is REBUILT mid-stream. The rebuilt program derives the same
-        fold_in(seed, position) keys, so every sampled stream continues
-        byte-identically (the retrace is honest: compiles goes to 2)."""
+        """Two consecutive hangs climb to rung 2 of the SERIAL loop's
+        ladder (the pipelined one has no rebuild rung: its second hang
+        fails the batch): the decode executable is REBUILT mid-stream.
+        The rebuilt program derives the same fold_in(seed, position)
+        keys, so every sampled stream continues byte-identically (the
+        retrace is honest: compiles goes to 2)."""
         prompts = [_prompt(n, seed=58) for n in (9, 6)]
         cfgs = [dict(temperature=0.8, top_k=20, seed=3001),
                 dict(temperature=1.0, top_p=0.9, seed=3002)]
         clean, _ = _run_streams(model, prompts, cfgs, n_new=8,
                                 max_queue_depth=None)
         set_flags({"FLAGS_serve_step_timeout_ms": 2000})
-        eng = LLMEngine(model, max_batch_size=4, block_size=4)
+        eng = LLMEngine(model, max_batch_size=4, block_size=4,
+                        pipeline_decode=False)
         reqs = [eng.add_request(p, max_new_tokens=8, **c)
                 for p, c in zip(prompts, cfgs)]
         for _ in range(3):
@@ -475,13 +480,41 @@ class TestPipelined:
         clean drain needs zero rollbacks."""
         prompts = [_prompt(n, seed=64) for n in (9, 6, 11, 7, 8)]
         cfgs = [dict(SAMPLERS[i % len(SAMPLERS)]) for i in range(5)]
-        plain, e1 = _run_streams(model, prompts, cfgs)
+        plain, e1 = _run_streams(model, prompts, cfgs,
+                                 pipeline_decode=False)
         piped, e2 = _run_streams(model, prompts, cfgs,
                                  pipeline_decode=True)
         assert piped == plain
         assert e1.stats()["decode_compiles"] == 1
         assert e2.stats()["decode_compiles"] == 1
         assert e2.stats()["commit_rollbacks"] == 0
+
+    @pytest.mark.parametrize("mix", ["greedy", "seeded", "mixed"])
+    def test_backlog_streams_identical_over_both_loops(self, model, mix):
+        """The benchmark's backlog cell in small: every slot full,
+        requests of mixed prompt buckets finishing AND joining at every
+        boundary, so every pipelined launch mixes device-fed slots with
+        host-authored ones. Greedy, seeded and both in one batch: the
+        pipelined loop serves the serial loop's streams token for token,
+        with no rollback and one decode program."""
+        samplers = {"greedy": (dict(),), "seeded": SAMPLERS[1:],
+                    "mixed": SAMPLERS}[mix]
+        streams = {}
+        for piped in (False, True):
+            requests, boundaries, eng = backlog.drive(
+                model, VOCAB, 48, samplers=samplers, pipeline_decode=piped)
+            backlog.assert_steady(boundaries)
+            st = eng.stats()
+            assert st["decode_compiles"] == 1
+            assert st["commit_rollbacks"] == 0
+            hot = sum(r.max_new_tokens for r in requests
+                      if r.temperature > 0)
+            assert st["sampled_tokens"] == hot
+            assert (hot > 0) == (mix != "greedy")
+            streams[piped] = [list(r.generated) for r in requests]
+        n = min(len(streams[False]), len(streams[True]))
+        assert n >= 100
+        assert streams[True][:n] == streams[False][:n]
 
     def test_commit_lag_cancel_rolls_back_not_leaks(self, model):
         """Cancel lands between launch N+1 and its commit: the launched
@@ -491,7 +524,8 @@ class TestPipelined:
         prompts = [_prompt(n, seed=65) for n in (10, 8, 9)]
         cfgs = [dict(temperature=0.9, top_k=20, seed=5000 + i)
                 for i in range(3)]
-        plain, _ = _run_streams(model, prompts, cfgs, n_new=10)
+        plain, _ = _run_streams(model, prompts, cfgs, n_new=10,
+                                pipeline_decode=False)
         eng = LLMEngine(model, max_batch_size=4, block_size=4,
                         pipeline_decode=True)
         reqs = [eng.add_request(p, max_new_tokens=10, **c)

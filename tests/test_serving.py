@@ -39,6 +39,8 @@ from paddle_tpu.serving import (BlockAllocator, LLMEngine, Request,
                                 QUEUED, RUNNING, FINISHED, FAILED,
                                 CANCELLED, EXPIRED)
 
+import serving_backlog as backlog
+
 VOCAB = 128
 
 
@@ -177,21 +179,23 @@ class TestSchedulerPolicy:
 # ---------------------------------------------------------------------------
 
 class TestDecodeParity:
-    def test_mixed_length_batch_matches_generate(self, model):
+    def test_mixed_length_batch_matches_generate(self, model, loop):
         prompts = [_prompt(n) for n in (11, 5, 17, 3)]
         refs = [_ref(model, p, 10) for p in prompts]
-        engine = LLMEngine(model, max_batch_size=4, block_size=4)
+        engine = LLMEngine(model, max_batch_size=4, block_size=4,
+                           pipeline_decode=loop)
         outs = engine.generate(prompts, max_new_tokens=10)
         assert outs == refs
         st = engine.stats()
         assert st["decode_compiles"] == 1
         assert st["completed"] == 4
 
-    def test_eos_stops_a_stream_early(self, model):
+    def test_eos_stops_a_stream_early(self, model, loop):
         p = _prompt(7)
         ref = _ref(model, p, 12)
         eos = ref[4]                       # force a stop mid-stream
-        engine = LLMEngine(model, max_batch_size=2, block_size=4)
+        engine = LLMEngine(model, max_batch_size=2, block_size=4,
+                           pipeline_decode=loop)
         req = engine.add_request(p, max_new_tokens=12, eos_token_id=eos)
         engine.run()
         assert req.state == FINISHED
@@ -200,11 +204,12 @@ class TestDecodeParity:
         assert req.generated == ref[:stop + 1]
         assert len(req.generated) < 12
 
-    def test_streaming_callbacks_fire_per_token(self, model):
+    def test_streaming_callbacks_fire_per_token(self, model, loop):
         p = _prompt(9)
         ref = _ref(model, p, 8)
         seen = []
-        engine = LLMEngine(model, max_batch_size=2, block_size=4)
+        engine = LLMEngine(model, max_batch_size=2, block_size=4,
+                           pipeline_decode=loop)
         engine.add_request(p, max_new_tokens=8,
                            on_token=lambda r, tok, text: seen.append(tok))
         engine.run()
@@ -212,13 +217,15 @@ class TestDecodeParity:
 
 
 class TestContinuousBatching:
-    def test_join_mid_flight_keeps_running_stream_bitwise(self, model):
+    def test_join_mid_flight_keeps_running_stream_bitwise(self, model,
+                                                          loop):
         """A request joining the batch must not perturb a stream that is
         already decoding: same tokens as a solo run, bit for bit."""
         pa, pb = _prompt(13, seed=1), _prompt(6, seed=2)
         ref_a = _ref(model, pa, 12)
         ref_b = _ref(model, pb, 8)
-        engine = LLMEngine(model, max_batch_size=2, block_size=4)
+        engine = LLMEngine(model, max_batch_size=2, block_size=4,
+                           pipeline_decode=loop)
         ra = engine.add_request(pa, max_new_tokens=12)
         for _ in range(5):                 # a is mid-flight...
             engine.step()
@@ -230,26 +237,29 @@ class TestContinuousBatching:
         assert rb.generated == ref_b
         assert engine.stats()["decode_compiles"] == 1
 
-    def test_departure_mid_flight_keeps_survivors_bitwise(self, model):
+    def test_departure_mid_flight_keeps_survivors_bitwise(self, model,
+                                                          loop):
         """Short streams finishing and leaving slots must not perturb the
         longer streams still running."""
         long_p, short_p = _prompt(10, seed=3), _prompt(4, seed=4)
         ref_long = _ref(model, long_p, 14)
-        engine = LLMEngine(model, max_batch_size=3, block_size=4)
+        engine = LLMEngine(model, max_batch_size=3, block_size=4,
+                           pipeline_decode=loop)
         rl = engine.add_request(long_p, max_new_tokens=14)
         rs = engine.add_request(short_p, max_new_tokens=2)
         engine.run()
         assert rs.state == FINISHED and len(rs.generated) == 2
         assert rl.generated == ref_long
 
-    def test_preempt_resume_token_equivalence(self, model):
+    def test_preempt_resume_token_equivalence(self, model, loop):
         """A deliberately tight pool forces eviction; the evicted stream
         re-prefills from its block-table-less state and must still match
         the never-preempted reference."""
         prompts = [_prompt(n, seed=5) for n in (11, 12, 10, 5)]
         refs = [_ref(model, p, 10) for p in prompts]
         engine = LLMEngine(model, max_batch_size=3, block_size=4,
-                           num_blocks=10, watermark_blocks=1)
+                           num_blocks=10, watermark_blocks=1,
+                           pipeline_decode=loop)
         outs = engine.generate(prompts, max_new_tokens=10)
         st = engine.stats()
         assert st["evictions"] >= 1        # the tight pool actually bit
@@ -292,7 +302,7 @@ class TestContinuousBatching:
 
 
 class TestZeroRetrace:
-    def test_64_mixed_streams_one_decode_compile(self, model):
+    def test_64_mixed_streams_one_decode_compile(self, model, loop):
         """The acceptance criterion: 64 concurrent mixed-length requests
         churning through 8 slots, ONE decode trace, every stream
         token-identical to generate()."""
@@ -300,7 +310,8 @@ class TestZeroRetrace:
         uniques = {n: _prompt(n, seed=7) for n in lengths}
         refs = {n: _ref(model, p, 6) for n, p in uniques.items()}
         prompts = [uniques[lengths[i % len(lengths)]] for i in range(64)]
-        engine = LLMEngine(model, max_batch_size=8, block_size=4)
+        engine = LLMEngine(model, max_batch_size=8, block_size=4,
+                           pipeline_decode=loop)
         outs = engine.generate(prompts, max_new_tokens=6)
         st = engine.stats()
         assert st["decode_compiles"] == 1
@@ -330,6 +341,92 @@ class TestZeroRetrace:
         st = engine.stats()
         assert st["decode_compiles"] == 0       # no retrace in the window
         assert st["completed"] == 1
+
+
+class TestBacklog:
+    """The benchmark's backlog cell in small (`serving_backlog.py`): every
+    slot full, requests of mixed prompt buckets finishing and joining at
+    every boundary."""
+
+    STEPS = 48
+
+    def test_every_stream_matches_generate_under_both_loops(self, model,
+                                                            loop):
+        requests, boundaries, engine = backlog.drive(
+            model, VOCAB, self.STEPS, pipeline_decode=loop)
+        backlog.assert_steady(boundaries)
+        for i, r in enumerate(requests):
+            assert r.generated == _ref(model, r.prompt, r.max_new_tokens), \
+                f"stream {i}"
+        st = engine.stats()
+        assert st["decode_compiles"] == 1
+        assert st["commit_rollbacks"] == 0
+        assert st["prefill_compiles"] == 3         # buckets 8, 16, 32
+
+    def test_a_joined_slot_decodes_from_its_prefill_token(self, model):
+        """The pipelined launch feeds a slot from the launch before it,
+        on the device, unless the host wrote the slot's token. A slot
+        that changed tenants holds the NEW tenant's prefill token, marked
+        as the host's: never what the launch before sampled there for the
+        tenant that left."""
+        tenants, joined, fed = {}, [], []
+
+        def on_launch(engine, args):
+            tokens, feedback, override = args[:3]
+            active = args[engine._ARG_ACTIVE]
+            for req in engine.scheduler.running:
+                slot = req.slot
+                if not active[slot]:
+                    continue
+                if tenants.get(slot) is req:
+                    # a stream in flight: its last token is on the device
+                    assert not override[slot]
+                    assert not isinstance(feedback, np.ndarray)
+                    fed.append(slot)
+                    continue
+                tenants[slot] = req
+                assert req.generated == [int(tokens[slot])]
+                assert override[slot]
+                joined.append(slot)
+
+        requests, _, engine = backlog.drive(
+            model, VOCAB, self.STEPS, on_launch=on_launch,
+            pipeline_decode=True)
+        assert len(joined) == len(requests) >= 80
+        assert set(joined) == set(range(backlog.SLOTS))
+        assert len(fed) >= len(joined)
+        for r in requests[:24]:
+            assert r.generated == _ref(model, r.prompt, r.max_new_tokens)
+
+    def test_launches_overlap_the_commit_before_them(self, model):
+        """`pipelined_launch_share`: launches issued while the launch
+        before them was uncommitted, over all launches of the window."""
+        _, _, serial = backlog.drive(model, VOCAB, 12,
+                                     pipeline_decode=False)
+        st = serial.stats()
+        assert st["pipelined_launch_share"] == 0.0
+        assert serial._stats.launches == st["steps"] > 0
+
+        engine = LLMEngine(model, max_batch_size=backlog.SLOTS,
+                           block_size=4)
+        requests = []
+
+        def steps(n):
+            for _ in range(n):
+                backlog.top_up(engine, requests, VOCAB)
+                engine.step()
+
+        steps(4)                           # the first launch has none before
+        assert engine.stats()["pipelined_launch_share"] == 0.75
+        engine.reset_stats()
+        assert engine.stats()["pipelined_launch_share"] == 0.0
+        assert engine._stats.launches == 0
+        steps(12)
+        st = engine.stats()
+        assert st["pipelined_launch_share"] == 1.0
+        assert engine._stats.launches_overlapped == st["steps"] == 12
+        engine.run()
+        assert engine.stats()["commit_rollbacks"] == 0
 
 
 # ---------------------------------------------------------------------------
@@ -697,25 +794,70 @@ class TestWatchdog:
         rep = explain(ev)
         assert rep["verdict"] == "serving_degraded"
 
-    def test_three_hangs_fail_active_without_wedging(self, model):
-        """Rung 3: a step that will not come back fails the ACTIVE
+    @pytest.mark.parametrize("pipelined, rungs", [(False, 3), (True, 2)],
+                             ids=["serial", "pipelined"])
+    def test_a_step_that_never_returns_fails_active_without_wedging(
+            self, model, pipelined, rungs):
+        """The last rung: a step that will not come back fails the ACTIVE
         requests with an attributed reason; queued and new requests are
-        then served normally — the process never wedges."""
+        then served normally — the process never wedges. The serial loop
+        retries, rebuilds, then gives up (3 hangs); the pipelined loop
+        cannot replay a launch whose successor already consumed its
+        pools, so it retries the wait once and gives up (2)."""
         set_flags({"FLAGS_serve_step_timeout_ms": 2000})
-        engine = LLMEngine(model, max_batch_size=2, block_size=4)
+        engine = LLMEngine(model, max_batch_size=2, block_size=4,
+                           pipeline_decode=pipelined)
         doomed = engine.add_request(_prompt(6, seed=32), max_new_tokens=8)
         engine.step()
-        guardian.inject_fault("hang", op="serve.decode", times=3)
+        guardian.inject_fault("hang", op="serve.decode", times=rungs)
         try:
             engine.run()
         finally:
             guardian.clear_faults()
         assert doomed.state == FAILED
         assert doomed.error == "step_hang"
-        assert engine.stats()["hangs"] == 3
+        assert engine.stats()["hangs"] == rungs
         fresh = engine.add_request(_prompt(5, seed=33), max_new_tokens=4)
         engine.run()
         assert fresh.state == FINISHED
+
+
+    def test_a_prefill_behind_a_launch_is_given_both_budgets(self, model):
+        """The pipelined loop dispatches a boundary's prefills behind
+        the uncommitted decode launch, so the armed watchdog's wait for a
+        prefill covers two programs: it is given two budgets, and a
+        prefill with nothing ahead of it one."""
+        set_flags({"FLAGS_serve_step_timeout_ms": 2000})
+        engine = LLMEngine(model, max_batch_size=4, block_size=4)
+        waits, wait = [], engine._monitor.wait
+
+        def spy(arrays, phase, attempt=1, programs=1):
+            waits.append((phase, programs, engine._inflight is not None))
+            return wait(arrays, phase, attempt, programs)
+
+        engine._monitor.wait = spy
+        engine.add_request(_prompt(6, seed=71), max_new_tokens=6)
+        engine.step()
+        engine.add_request(_prompt(9, seed=72), max_new_tokens=4)
+        engine.run()
+        assert engine.stats()["hangs"] == 0
+        prefills = [w for w in waits if w[0] == "prefill"]
+        assert prefills == [("prefill", 1, False), ("prefill", 2, True)]
+
+    def test_a_wait_over_two_programs_burns_two_budgets(self):
+        from paddle_tpu.serving.resilience import MonitoredWait, StepHang
+
+        class Never:
+            def is_ready(self):
+                return False
+
+        with pytest.raises(StepHang) as hang:
+            MonitoredWait(budget_s=0.01).wait([Never()], "prefill",
+                                              programs=2)
+        assert hang.value.budget_ms == pytest.approx(20.0)
+        with pytest.raises(StepHang) as hang:
+            MonitoredWait(budget_s=0.01).wait([Never()], "prefill")
+        assert hang.value.budget_ms == pytest.approx(10.0)
 
 
 class TestDegradedFallback:

@@ -373,13 +373,14 @@ class TestAliasedAdmission:
 # ---------------------------------------------------------------------------
 
 class TestPrefixServing:
-    def test_shared_prefix_one_prefill_token_identical(self, model):
+    def test_shared_prefix_one_prefill_token_identical(self, model, loop):
         """Four streams share a 12-token prefix: ONE prefill total, and
         every stream's greedy output matches per-stream generate —
         including through the copy-on-write divergence."""
         prompts = _shared_prompts(4, prefix_len=12, suffix_len=3)
         engine = LLMEngine(model, max_batch_size=4, block_size=4,
-                           num_blocks=64, enable_prefix_cache=True)
+                           num_blocks=64, enable_prefix_cache=True,
+                           pipeline_decode=loop)
         clear_fusion_events()
         set_flags({"FLAGS_profiler_events": True})
         try:
@@ -401,13 +402,14 @@ class TestPrefixServing:
         assert len(hits) == 3
         assert all(e["reason"] == "prefix_hit" for e in hits)
 
-    def test_identical_prompts_full_alias_and_cow(self, model):
+    def test_identical_prompts_full_alias_and_cow(self, model, loop):
         """Bit-identical prompts alias every block (hit = len-1); the
         divergence then happens inside a SHARED block, so parity proves
         copy-on-write actually copies."""
         p = _prompt(12, seed=11)
         engine = LLMEngine(model, max_batch_size=2, block_size=4,
-                           num_blocks=64, enable_prefix_cache=True)
+                           num_blocks=64, enable_prefix_cache=True,
+                           pipeline_decode=loop)
         outs = engine.generate([p, list(p)], max_new_tokens=8)
         ref = _ref(model, p, 8)
         assert outs[0] == ref and outs[1] == ref
@@ -464,12 +466,13 @@ class TestPrefixServing:
 # ---------------------------------------------------------------------------
 
 class TestAdapters:
-    def test_base_tenant_bit_identical_to_adapter_free(self, model):
+    def test_base_tenant_bit_identical_to_adapter_free(self, model, loop):
         """Slot 0's delta is an exact 0.0 — base tenants on an
         adapter-enabled engine match per-stream generate exactly."""
         prompts = [_prompt(9, seed=31), _prompt(7, seed=32)]
         engine = LLMEngine(model, max_batch_size=2, block_size=4,
-                           num_blocks=64, max_adapters=2, adapter_rank=2)
+                           num_blocks=64, max_adapters=2, adapter_rank=2,
+                           pipeline_decode=loop)
         outs = engine.generate(prompts, max_new_tokens=8)
         for p, o in zip(prompts, outs):
             assert o == _ref(model, p, 8)
@@ -489,12 +492,13 @@ class TestAdapters:
         assert runs[0] != _ref(model, p, 6)       # the delta bites
         assert runs[0] == runs[1]                 # and is deterministic
 
-    def test_tenant_churn_zero_recompiles(self, model):
+    def test_tenant_churn_zero_recompiles(self, model, loop):
         """Tenants joining/leaving only edit stack VALUES and slot
         indices: the decode executable compiles exactly once."""
         prompts = _shared_prompts(6, prefix_len=8, suffix_len=2, seed=40)
         engine = LLMEngine(model, max_batch_size=3, block_size=4,
-                           num_blocks=64, max_adapters=3, adapter_rank=2)
+                           num_blocks=64, max_adapters=3, adapter_rank=2,
+                           pipeline_decode=loop)
         engine.register_adapter("t1", seed=1, scale=25.0)
         engine.register_adapter("t2", seed=2, scale=25.0)
         plan = ["t1", None, "t2", "t1", "t2", None]
@@ -586,14 +590,15 @@ class TestAdapters:
 # ---------------------------------------------------------------------------
 
 class TestHotSwap:
-    def test_swap_between_steps_byte_exact_zero_recompiles(self):
+    def test_swap_between_steps_byte_exact_zero_recompiles(self, loop):
         m1 = _make_model(seed=0)
         m2 = _make_model(seed=1)
         w2 = [np.asarray(p._value) for p in m2.parameters()]
         p = _prompt(9, seed=51)
         ref1 = _gen(m1, p, 6)
         engine = LLMEngine(m1, max_batch_size=2, block_size=4,
-                           num_blocks=64, hot_swap=True)
+                           num_blocks=64, hot_swap=True,
+                           pipeline_decode=loop)
         assert engine.generate([p], max_new_tokens=6)[0] == ref1
         assert engine.weight_epoch == 0
         epoch = engine.swap_weights(w2)
@@ -605,7 +610,7 @@ class TestHotSwap:
         assert st["weight_swaps"] == 1
         assert st["weight_epoch"] == 1
 
-    def test_mid_run_swap_cutover_boundary_is_exact(self):
+    def test_mid_run_swap_cutover_boundary_is_exact(self, loop):
         """Streams in flight at the cutover finish as: every token
         emitted before the swap is exactly the OLD weights' token,
         every token after is exactly the NEW weights' continuation of
@@ -616,7 +621,8 @@ class TestHotSwap:
         prompts = [_prompt(8, seed=52), _prompt(10, seed=53)]
         refs1 = [_gen(m1, p, 10) for p in prompts]
         engine = LLMEngine(m1, max_batch_size=2, block_size=4,
-                           num_blocks=64, hot_swap=True)
+                           num_blocks=64, hot_swap=True,
+                           pipeline_decode=loop)
         reqs = [engine.add_request(p, max_new_tokens=10,
                                    request_id=f"w{i}")
                 for i, p in enumerate(prompts)]
@@ -769,7 +775,7 @@ class TestTenantCrashResume:
 # ---------------------------------------------------------------------------
 
 class TestCombined:
-    def test_prefix_adapters_swap_one_executable(self):
+    def test_prefix_adapters_swap_one_executable(self, loop):
         """Scaled-down ISSUE 17 acceptance: streams over mixed tenants
         with a shared prefix, a mid-run weight swap — ONE decode
         compile through all of it."""
@@ -778,7 +784,8 @@ class TestCombined:
         w2 = [np.asarray(p._value) for p in m2.parameters()]
         engine = LLMEngine(m1, max_batch_size=4, block_size=4,
                            num_blocks=96, enable_prefix_cache=True,
-                           max_adapters=3, adapter_rank=2, hot_swap=True)
+                           max_adapters=3, adapter_rank=2, hot_swap=True,
+                           pipeline_decode=loop)
         engine.register_adapter("a1", seed=1, scale=25.0)
         engine.register_adapter("a2", seed=2, scale=25.0)
         prompts = _shared_prompts(8, prefix_len=12, suffix_len=2,

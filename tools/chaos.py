@@ -375,7 +375,10 @@ def scenario_serve_hang():
                 "serving_degraded")
 
         # -- rung 2: two consecutive hangs -> rebuild, still finishes -------
-        engine2 = LLMEngine(model, max_batch_size=2, block_size=4)
+        # (the serial loop's ladder, by name: the pipelined loop has no
+        # rebuild rung, its second hang fails the batch)
+        engine2 = LLMEngine(model, max_batch_size=2, block_size=4,
+                            pipeline_decode=False)
         reqs2 = [engine2.add_request(p, max_new_tokens=8) for p in prompts]
         for _ in range(3):
             engine2.step()
@@ -493,7 +496,11 @@ def scenario_telemetry():
             time.sleep(0.01)        # ~100 Hz across both endpoints
 
     try:
-        engine = LLMEngine(model, max_batch_size=2, block_size=4)
+        # two wedged steps in a row, then recovery: the SERIAL loop's
+        # ladder (retry, rebuild), by name; the pipelined loop's second
+        # hang fails the batch
+        engine = LLMEngine(model, max_batch_size=2, block_size=4,
+                           pipeline_decode=False)
         reqs = [engine.add_request(p, max_new_tokens=8) for p in prompts]
         for _ in range(3):
             engine.step()           # warm + heartbeat established
@@ -605,7 +612,10 @@ def scenario_sentinel():
         engine.run()
 
     try:
-        engine = LLMEngine(model, max_batch_size=4, block_size=4)
+        # the storm below wedges two steps in a row and the streams
+        # must survive it: the serial loop's ladder, by name
+        engine = LLMEngine(model, max_batch_size=4, block_size=4,
+                           pipeline_decode=False)
         filler(engine)              # decode compiled pre-calibration
         snt.arm(window_s=window_s)
         deadline = time.perf_counter() + 60
