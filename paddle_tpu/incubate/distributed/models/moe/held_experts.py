@@ -1,0 +1,111 @@
+"""The expert block of a SERVING program that holds a share of the experts.
+
+`MoELayer` (moe_layer.py) trains: a capacity per expert, tokens past it
+dropped, an auxiliary loss. Serving a model cut to one chip's share of an
+expert-parallel deployment asks something else of the block:
+
+  * it routes over ALL the experts the router was published with (real
+    ones and zero-computation "identity" ones) and is TOLD which real
+    experts it holds: it computes those, adds nothing for a chosen real
+    expert held elsewhere, and computes the identity experts where the
+    token lives (a scale of the token, no matrix product, no exchange);
+  * no token is dropped at any load, inside one compiled program of static
+    shapes, and an expert no token chose is not read: each held expert
+    runs under a `lax.cond` on its own load, over every token of the call
+    masked by the choice (a decode launch's products are bound by the
+    expert's bytes, not by its rows), so `computed == held` always (the
+    counters prove it) and a launch's time follows its routing;
+  * the router works in float32, products at "highest": top-k of its
+    scores is a discrete choice and the reference's router is float32 too.
+
+Pure `jax.numpy` over arrays: the model that owns the weights calls it
+from inside the engine's programs. On one chip it runs without the
+exchange that expert parallelism adds, and nothing here stands in for the
+absent chips.
+"""
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+__all__ = ["route", "held_expert_block", "COUNTERS"]
+
+# what `held_expert_block` counts of a call's choices, in this order
+COUNTERS = ("routed_held", "routed_identity", "routed_elsewhere",
+            "load_max", "experts_idle", "routed_computed")
+
+
+def route(u, router_w, bias, topk, scaling):
+    """The router: scores ``softmax(u W_r)`` over every expert in float32,
+    the `topk` of ``scores + bias`` chosen (the bias moves the choice and
+    never the weight), each weighed ``scaling * score``, not renormalised.
+    Returns ``(chosen ids [T, topk], weights [T, topk] float32)``."""
+    logits = jnp.matmul(u.astype(jnp.float32), router_w.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.softmax(logits, axis=-1)
+    ranked = scores if bias is None else scores + bias.astype(jnp.float32)
+    _, chosen = jax.lax.top_k(ranked, topk)
+    weights = jnp.take_along_axis(scores, chosen, axis=-1)
+    return chosen.astype(jnp.int32), jnp.float32(scaling) * weights
+
+
+def _swiglu(x, gate_w, up_w, down_w):
+    """One expert over rows x ``[C, d]`` -> ``[C, d]`` float32."""
+    g = jnp.matmul(x, gate_w, preferred_element_type=jnp.float32)
+    up = jnp.matmul(x, up_w, preferred_element_type=jnp.float32)
+    h = (jax.nn.silu(g) * up).astype(x.dtype)
+    return jnp.matmul(h, down_w, preferred_element_type=jnp.float32)
+
+
+def held_expert_block(u, router_w, bias, gate_w, up_w, down_w, *, topk,
+                      real_experts, scaling, first_held=0, valid=None):
+    """``sum over chosen held e of w_e E_e(u) + sum over chosen identity e
+    of w_e u`` for tokens u ``[T, d]``, and the call's counters.
+
+    router_w ``[d, real_experts + identity experts]``; the stacked
+    gate/up/down weights hold experts ``first_held .. first_held + E - 1``
+    of the `real_experts`; ids from `real_experts` up are identity
+    experts. `valid` ``[T]`` bool masks padding (a prompt's bucket, an
+    empty slot) out of the counts and of the products. Returns ``(m [T, d]
+    float32, counters int32 [len(COUNTERS)])``."""
+    t, d = u.shape
+    held = gate_w.shape[0]
+    if valid is None:
+        valid = jnp.ones((t,), bool)
+    chosen, weights = route(u, router_w, bias, topk, scaling)
+    is_identity = chosen >= real_experts
+    local = chosen - first_held
+    is_held = (local >= 0) & (local < held) & ~is_identity
+    # the identity experts: a scale of the token
+    scale = jnp.sum(jnp.where(is_identity, weights, 0.0), axis=-1)
+    out = scale[:, None] * u.astype(jnp.float32)
+    # [T, E]: the weight a token gives each held expert (0: not chosen);
+    # a token chooses an expert at most once
+    hit = (local[:, :, None] == jnp.arange(held)[None, None, :]) \
+        & (is_held & valid[:, None])[:, :, None]
+    w_te = jnp.sum(jnp.where(hit, weights[:, :, None], 0.0), axis=1)
+    took = jnp.any(hit, axis=1)                          # [T, E]
+    load = jnp.sum(took, axis=0).astype(jnp.int32)       # [E]
+
+    def loaded(e, acc):
+        """Expert e over every token, weighed by the choice (0 for a
+        token that did not choose it)."""
+        y = _swiglu(u, gate_w[e], up_w[e], down_w[e])
+        return acc + w_te[:, e:e + 1] * y, load[e]
+
+    computed = jnp.int32(0)
+    for e in range(held):
+        out, n = jax.lax.cond(load[e] > 0, functools.partial(loaded, e),
+                              lambda acc: (acc, jnp.int32(0)), out)
+        computed = computed + n
+    counted = valid[:, None]
+    n_held = jnp.sum(is_held & counted)
+    counters = jnp.stack([
+        n_held, jnp.sum(is_identity & counted),
+        jnp.sum(~is_held & ~is_identity & counted),
+        jnp.max(load), jnp.sum(load == 0),
+        # what the experts that ran computed: the tokens that chose them
+        computed]).astype(jnp.int32)
+    return out, counters

@@ -4,3 +4,7 @@ from .gpt import (  # noqa: F401
     gpt2_124m, gpt2_355m, gpt3_1p3b, gpt3_6p7b, shard_gpt,
     GPTEmbeddingPipe, GPTHeadPipe, gpt_pipeline_layers, GPTDecodeStep,
 )
+from . import longcat_flash  # noqa: F401
+from .longcat_flash import (  # noqa: F401
+    LongCatFlashConfig, LongCatFlashForCausalLM,
+)

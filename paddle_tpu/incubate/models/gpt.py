@@ -354,6 +354,15 @@ class GPTForCausalLM(Layer):
                        dtype))
                 for _ in range(cfg.num_hidden_layers)]
 
+    def cache_spec(self):
+        """What a serving engine caches for a token of this model: a key
+        and a value for every head of every layer."""
+        from ...serving.cache import CacheSpec
+        cfg = self.config
+        return CacheSpec.per_head(
+            cfg.num_hidden_layers, cfg.num_attention_heads,
+            cfg.hidden_size // cfg.num_attention_heads)
+
     def forward(self, input_ids, position_ids=None, caches=None):
         if caches is None:
             hidden = self.gpt(input_ids, position_ids)

@@ -70,7 +70,8 @@ except Exception:  # pragma: no cover
 from .._common import ZERO as _ZERO, on_tpu as _on_tpu
 from ...quantization.kv_cache import QMAX as _QMAX, dequantize as _dequant
 
-__all__ = ["blockwise_paged_attention", "blockwise_streamed_entries",
+__all__ = ["blockwise_paged_attention", "blockwise_latent_attention",
+           "blockwise_streamed_entries",
            "pallas_paged_attention", "is_eligible"]
 
 _NEG_INF = -1e30
@@ -123,7 +124,7 @@ def is_eligible(num_heads, head_dim, block_size):
 # ---------------------------------------------------------------------------
 
 def _blockwise_plan(num_slots, table_entries, block_size, num_heads,
-                    head_dim, chunk_blocks=None):
+                    head_dim, chunk_blocks=None, min_width=None):
     """The static shape of the blockwise loop, from the call's shapes
     alone: ``(widths, chunk_blocks, n_chunks)``. `widths` are the slot
     counts a loop step can run at, widest first: all the slots, then
@@ -138,7 +139,9 @@ def _blockwise_plan(num_slots, table_entries, block_size, num_heads,
     chunk_blocks = min(int(chunk_blocks), table_entries)
     n_chunks = -(-table_entries // chunk_blocks)
     widths = [num_slots]
-    while -(-widths[-1] // 2) >= _MIN_WIDTH_SLOTS:
+    # a narrower last width is a caller's to ask for, with a chunk whose
+    # gather at that width is as large as one this chip has run
+    while -(-widths[-1] // 2) >= (min_width or _MIN_WIDTH_SLOTS):
         widths.append(-(-widths[-1] // 2))
     return tuple(widths), chunk_blocks, n_chunks
 
@@ -165,7 +168,8 @@ def _step_widths(lens, widths, chunk_tokens, n_chunks, xp):
 
 
 def blockwise_streamed_entries(lens, active, table_entries, block_size,
-                               num_heads, head_dim):
+                               num_heads, head_dim, chunk_blocks=None,
+                               min_width=None):
     """The host's count of one decode step's attention, in table entries
     summed over the slots: ``(streamed, held)``. `streamed` is what
     `blockwise_paged_attention`'s loop reads for these lengths (the same
@@ -176,7 +180,8 @@ def blockwise_streamed_entries(lens, active, table_entries, block_size,
     active = np.asarray(active, bool)
     eff = np.where(active, np.asarray(lens, np.int64), 0)
     widths, chunk_blocks, n_chunks = _blockwise_plan(
-        eff.shape[0], table_entries, block_size, num_heads, head_dim)
+        eff.shape[0], table_entries, block_size, num_heads, head_dim,
+        chunk_blocks, min_width)
     trips, which = _step_widths(-np.sort(-eff), widths,
                                 chunk_blocks * block_size, n_chunks, np)
     # the last chunk may reach past the table: those entries are fill
@@ -187,43 +192,24 @@ def blockwise_streamed_entries(lens, active, table_entries, block_size,
     return streamed, held
 
 
-def blockwise_paged_attention(q, k_pools, v_pools, layer, block_tables,
-                              lens, block_size, k_scales=None, v_scales=None,
-                              chunk_blocks=None):
-    """Online-softmax paged attention, one KV chunk at a time, over the
-    chunks and the slots that hold tokens.
+def _blockwise_loop(q32, block_tables, lens, plan, block_size, value_width,
+                    chunk):
+    """The loop both blockwise attentions share: online softmax over the
+    chunks that hold tokens, at the widths `_step_widths` picks.
 
-    q: ``[S, H, D]`` this step's queries; k_pools/v_pools:
-    ``[L, num_blocks, bs, H*D]`` (fp, or int8 with `k_scales`/`v_scales`
-    ``[L, num_blocks, H]``) and `layer` the one to read; block_tables:
-    ``[S, M]`` int32; lens: ``[S]`` int32 EFFECTIVE lengths (position p
-    attends iff p <= lens[s]; inactive slots pass 0). Returns
-    ``[S, H, D]`` in q's dtype. Each loop step gathers
-    ``pool[layer, block ids]`` and splits the GATHERED rows into heads:
-    neither a layer of the pool nor the pool in another shape is ever a
-    value, so the program reads a donated pool where it lies.
-
-    The loop stops after the chunk that holds the longest slot's newest
-    token. Where `_blockwise_plan` gives more than one width, the slots
-    are ordered longest first and a step reads its chunk for the first
-    W of them only, W the narrowest of those static widths that holds
-    every slot with a token in that chunk (`_step_widths`, read on the
-    device: the shapes, and so the compiled program, do not depend on
-    the lengths). A slot's chunk that is not read adds exactly nothing
-    to the recurrence, so every slot's output is what a loop over the
-    whole table gives. Inside a step, positions past a slot's own length
-    are still gathered and masked; an inactive slot reads the null block
-    through one chunk.
-    """
-    s, h, d = q.shape
+    q32: ``[S, H, .]`` float32 scaled queries; plan: `_blockwise_plan`'s;
+    ``chunk(w, bids, q)`` reads the chunk whose block ids are `bids`
+    ``[w, C]`` for the first `w` slots (a static count; `q` the queries
+    of ALL the slots in the loop's order, of which it takes ``q[:w]``)
+    and returns ``(scores [w, H, t], weigh)``, `weigh(p)` being the chunk's values
+    weighed by p ``[w, H, t]`` -> ``[w, H, value_width]``. Returns the
+    attention output ``[S, H, value_width]`` float32, in the callers'
+    slot order."""
+    s, h = q32.shape[:2]
     m = block_tables.shape[1]
-    bs = int(block_size)
-    quant = k_scales is not None
-    widths, chunk_blocks, n_chunks = _blockwise_plan(
-        s, m, bs, h, d, chunk_blocks)
-    t_chunk = chunk_blocks * bs
+    widths, chunk_blocks, n_chunks = plan
+    t_chunk = chunk_blocks * int(block_size)
     lens = lens.astype(jnp.int32)
-    q32 = q.astype(jnp.float32) * (1.0 / math.sqrt(d))
     tables = block_tables
     order = None
     if len(widths) > 1:
@@ -243,19 +229,7 @@ def blockwise_paged_attention(q, k_pools, v_pools, layer, block_tables,
         `w` slots (a static count)."""
         acc_all, mx_all, l_all = carry
         acc, mx, l = acc_all[:w], mx_all[:w], l_all[:w]
-        bids = bids[:w]                                 # [w, C]
-        kc = k_pools[layer, bids]                       # [w, C, bs, H*D]
-        vc = v_pools[layer, bids]
-        if quant:
-            split = (w, chunk_blocks, bs, h, d)
-            kc = _dequant(kc.reshape(split), k_scales[layer, bids])
-            vc = _dequant(vc.reshape(split), v_scales[layer, bids])
-        else:
-            kc = kc.astype(jnp.float32)
-            vc = vc.astype(jnp.float32)
-        kc = kc.reshape(w, t_chunk, h, d)
-        vc = vc.reshape(w, t_chunk, h, d)
-        scores = jnp.einsum("shd,sthd->sht", q32[:w], kc)
+        scores, weigh = chunk(w, bids[:w], q32)
         pos = ci * t_chunk + offs
         valid = pos[None, :] <= lens[:w, None]          # [w, t]
         scores = jnp.where(valid[:, None, :], scores,
@@ -267,8 +241,7 @@ def blockwise_paged_attention(q, k_pools, v_pools, layer, block_tables,
                       jnp.exp(scores - m_new[..., None]), 0.0)
         alpha = jnp.exp(mx - m_new)
         l = alpha * l + jnp.sum(p, axis=-1)
-        acc = acc * alpha[..., None] \
-            + jnp.einsum("sht,sthd->shd", p, vc)
+        acc = acc * alpha[..., None] + weigh(p)
         return (acc_all.at[:w].set(acc), mx_all.at[:w].set(m_new),
                 l_all.at[:w].set(l))
 
@@ -279,7 +252,7 @@ def blockwise_paged_attention(q, k_pools, v_pools, layer, block_tables,
             return branches[0](ci, tabs[ci], carry)
         return jax.lax.switch(which[ci], branches, ci, tabs[ci], carry)
 
-    acc0 = jnp.zeros((s, h, d), jnp.float32)
+    acc0 = jnp.zeros((s, h, value_width), jnp.float32)
     m0 = jnp.full((s, h), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((s, h), jnp.float32)
     # a traced bound: a while loop whose trip count the device reads
@@ -290,7 +263,102 @@ def blockwise_paged_attention(q, k_pools, v_pools, layer, block_tables,
         back = jnp.zeros_like(order).at[order].set(
             jnp.arange(s, dtype=jnp.int32))
         out = out[back]
-    return out.astype(q.dtype)
+    return out
+
+
+def blockwise_paged_attention(q, k_pools, v_pools, layer, block_tables,
+                              lens, block_size, k_scales=None, v_scales=None,
+                              chunk_blocks=None):
+    """Online-softmax paged attention, one KV chunk at a time, over the
+    chunks and the slots that hold tokens.
+
+    q: ``[S, H, D]`` this step's queries; k_pools/v_pools:
+    ``[L, num_blocks, bs, H*D]`` (fp, or int8 with `k_scales`/`v_scales`
+    ``[L, num_blocks, H]``) and `layer` the one to read; block_tables:
+    ``[S, M]`` int32; lens: ``[S]`` int32 EFFECTIVE lengths (position p
+    attends iff p <= lens[s]; inactive slots pass 0). Returns
+    ``[S, H, D]`` in q's dtype. Each loop step gathers
+    ``pool[layer, block ids]`` and splits the GATHERED rows into heads:
+    neither a layer of the pool nor the pool in another shape is ever a
+    value, so the program reads a donated pool where it lies.
+
+    The loop (`_blockwise_loop`) stops after the chunk that holds the
+    longest slot's newest token. Where `_blockwise_plan` gives more than
+    one width, the slots are ordered longest first and a step reads its
+    chunk for the first W of them only, W the narrowest of those static
+    widths that holds every slot with a token in that chunk
+    (`_step_widths`, read on the device: the shapes, and so the compiled
+    program, do not depend on the lengths). A slot's chunk that is not
+    read adds exactly nothing to the recurrence, so every slot's output
+    is what a loop over the whole table gives. Inside a step, positions
+    past a slot's own length are still gathered and masked; an inactive
+    slot reads the null block through one chunk.
+    """
+    s, h, d = q.shape
+    bs = int(block_size)
+    quant = k_scales is not None
+    plan = _blockwise_plan(s, block_tables.shape[1], bs, h, d, chunk_blocks)
+    chunk_blocks = plan[1]
+    t_chunk = chunk_blocks * bs
+    q32 = q.astype(jnp.float32) * (1.0 / math.sqrt(d))
+
+    def chunk(w, bids, q):
+        kc = k_pools[layer, bids]                       # [w, C, bs, H*D]
+        vc = v_pools[layer, bids]
+        if quant:
+            split = (w, chunk_blocks, bs, h, d)
+            kc = _dequant(kc.reshape(split), k_scales[layer, bids])
+            vc = _dequant(vc.reshape(split), v_scales[layer, bids])
+        else:
+            kc = kc.astype(jnp.float32)
+            vc = vc.astype(jnp.float32)
+        kc = kc.reshape(w, t_chunk, h, d)
+        vc = vc.reshape(w, t_chunk, h, d)
+        return (jnp.einsum("shd,sthd->sht", q[:w], kc),
+                lambda p: jnp.einsum("sht,sthd->shd", p, vc))
+
+    return _blockwise_loop(q32, block_tables, lens, plan, bs, d,
+                           chunk).astype(q.dtype)
+
+
+def blockwise_latent_attention(q, pool, layer, block_tables, lens,
+                               block_size, value_width, scale,
+                               chunk_blocks=None, min_width=None):
+    """The blockwise loop over a LATENT pool: every head attends over the
+    one row a token holds, absorbed (multi-head latent attention at
+    decode: no key or value of any head is ever made for a cached token).
+
+    q: ``[S, H, W]`` the queries already carried into the row's space (a
+    head's compressed-key product beside its rotary part); pool:
+    ``[L, num_blocks, bs, >= W]`` (rows zero past W); the value of a
+    token is the first `value_width` values of its row. Scores are
+    ``scale * q . row``; `lens` as in `blockwise_paged_attention`.
+    Returns ``[S, H, value_width]`` float32. The plan, the widths and
+    the trip count are that loop's own (`_blockwise_plan` with the row as
+    ONE head), so `blockwise_streamed_entries` counts this loop too."""
+    s, _, w_q = q.shape
+    bs = int(block_size)
+    row = pool.shape[-1]
+    plan = _blockwise_plan(s, block_tables.shape[1], bs, 1, row,
+                           chunk_blocks, min_width)
+    q32 = q.astype(jnp.float32) * jnp.float32(scale)
+    if row > w_q:                                       # a padded row
+        q32 = jnp.pad(q32, ((0, 0), (0, 0), (0, row - w_q)))
+
+    def chunk(w, bids, q):
+        # the rows stay in the pool's type and both products round
+        # their other operand to it, accumulating in float32: what the
+        # chip's matrix unit does with float32 operands anyway, without
+        # ever writing the chunk widened (PERF.md section 5)
+        rows = pool[layer, bids].reshape(w, plan[1] * bs, row)
+        return (jnp.einsum("shd,std->sht", q[:w].astype(rows.dtype), rows,
+                           preferred_element_type=jnp.float32),
+                lambda p: jnp.einsum("sht,std->shd", p.astype(rows.dtype),
+                                     rows[..., :value_width],
+                                     preferred_element_type=jnp.float32))
+
+    return _blockwise_loop(q32, block_tables, lens, plan, bs, value_width,
+                           chunk)
 
 
 # ---------------------------------------------------------------------------

@@ -356,8 +356,61 @@ def paged_decode_attention(q, k_new, v_new, k_pools, v_pools, layer,
     return out[:, None], k_pools, v_pools
 
 
-__all__ += ["paged_decode_attention", "resolve_paged_kernel",
-            "PAGED_KERNELS"]
+def paged_latent_decode_attention(q, parts_new, pool, layer, block_tables,
+                                  seq_lens, active, block_size, value_width,
+                                  scale, kernel=None, chunk_blocks=None,
+                                  min_width=None):
+    """`paged_decode_attention` for a LATENT cache (serving/cache.py
+    `CacheSpec`, kind ``"latent"``): a token holds ONE row that all heads
+    share, and attention reads it absorbed.
+
+    q: ``[S, H, W]`` this step's queries in the row's space; parts_new:
+    the token's row as its parts ``([S, w0], [S, w1])``, written side by
+    side (zeros up to the pool's width) into `pool`
+    ``[L, num_blocks, block_size, >= W]``. A token's value is the first
+    `value_width` values of its row; scores are ``scale * q . row``.
+    `kernel`: ``"blockwise"`` (the loop over the chunks that hold tokens)
+    or ``"reference"`` (a dense gather of the whole table). Returns
+    ``(out [S, H, value_width] float32, the written pool)``."""
+    s = q.shape[0]
+    lens = jnp.where(active, seq_lens, 0).astype(jnp.int32)
+    rows = jnp.arange(s, dtype=jnp.int32)
+    write_block = jnp.where(
+        active, block_tables[rows, lens // block_size], 0).astype(jnp.int32)
+    write_off = lens % block_size
+    with jax.named_scope("paged_kv_write"):
+        new = jnp.concatenate(parts_new, axis=-1)
+        new = jnp.pad(new, ((0, 0), (0, pool.shape[-1] - new.shape[-1])))
+        pool = pool.at[layer, write_block, write_off].set(
+            new.astype(pool.dtype))
+    with jax.named_scope("paged_attention"):
+        if kernel == "reference":
+            m = block_tables.shape[1]
+            ctx = pool[layer, block_tables].astype(jnp.float32).reshape(
+                s, m * block_size, -1)[..., :q.shape[-1]]
+            scores = jnp.einsum("shd,std->sht", q.astype(jnp.float32),
+                                ctx) * jnp.float32(scale)
+            valid = jnp.arange(m * block_size,
+                               dtype=jnp.int32)[None, :] <= lens[:, None]
+            scores = jnp.where(valid[:, None, :], scores,
+                               jnp.asarray(-1e30, jnp.float32))
+            out = jnp.einsum("sht,std->shd", jax.nn.softmax(scores, -1),
+                             ctx[..., :value_width])
+        elif kernel == "blockwise":
+            from ...kernels.pallas.paged_attention import (
+                blockwise_latent_attention)
+            out = blockwise_latent_attention(
+                q, pool, layer, block_tables, lens, block_size,
+                value_width, scale, chunk_blocks, min_width)
+        else:
+            raise ValueError(
+                f"no {kernel!r} attention over a latent cache: "
+                "'blockwise' or 'reference'")
+    return out, pool
+
+
+__all__ += ["paged_decode_attention", "paged_latent_decode_attention",
+            "resolve_paged_kernel", "PAGED_KERNELS"]
 
 
 @register_op("sparse_attention", "attention",

@@ -50,7 +50,7 @@ from collections import deque
 import jax
 import jax.numpy as jnp
 
-__all__ = ["BlockAllocator", "PagedKVCache", "PagedCacheView",
+__all__ = ["BlockAllocator", "CacheSpec", "PagedKVCache", "PagedCacheView",
            "scatter_prefill", "NULL_BLOCK", "pool_bytes_per_block",
            "num_blocks_for_bytes"]
 
@@ -145,6 +145,80 @@ class BlockAllocator:
                 self._refs[b] = rc - 1
 
 
+class CacheSpec:
+    """What a model caches for a token, as the model itself describes it
+    (`model.cache_spec()`); the manager builds the pools from it and the
+    engine asks the model nothing else about its attention.
+
+    kind: ``"kv"``, a key and a value for each of `num_heads` heads (a K
+    pool and a V pool of ``num_heads * head_dim`` values a row), or
+    ``"latent"``, ONE row that every head shares (a compressed key/value
+    and the rotated shared key; attention reads it absorbed).
+    num_layers: the cached attention sublayers (a layer may hold several).
+    parts: the two tensors a sublayer hands back for a prefilled token,
+    as trailing shapes: ``((H, D), (H, D))``, or ``((latent,), (rope,))``.
+    widths: a row's width in each pool: two under ``"kv"``; ONE under
+    ``"latent"``, a pool whose rows hold both parts side by side, padded
+    to that width (whole 128-lane tiles; a pool for each part read 1.8 x
+    slower on the chip, PERF.md section 4). The programs' second pool is
+    then empty.
+    num_heads / head_dim: what the blockwise loop plans its chunks from
+    (`_blockwise_plan`): a latent row is one shared head.
+    chunk_tokens / min_width_slots: the loop's chunk and its narrowest
+    width, where the plan's own are not wanted (`loop_plan(block_size)`
+    is what the loop and its counter are both given).
+    """
+
+    __slots__ = ("kind", "num_layers", "parts", "widths", "num_heads",
+                 "head_dim", "chunk_tokens", "min_width_slots")
+
+    def __init__(self, kind, num_layers, parts, widths, num_heads,
+                 head_dim, chunk_tokens=None, min_width_slots=None):
+        if kind not in ("kv", "latent"):
+            raise ValueError(f"unknown cache kind {kind!r}")
+        if len(widths) != (2 if kind == "kv" else 1):
+            raise ValueError(f"a {kind!r} cache of {len(widths)} pools")
+        self.kind = kind
+        self.num_layers = int(num_layers)
+        self.parts = tuple(tuple(int(n) for n in p) for p in parts)
+        self.widths = tuple(int(w) for w in widths)
+        self.num_heads = int(num_heads)
+        self.head_dim = int(head_dim)
+        self.chunk_tokens = chunk_tokens
+        self.min_width_slots = min_width_slots
+
+    def loop_plan(self, block_size):
+        """Keyword arguments of the blockwise loop's plan ({} for the
+        plan's own)."""
+        plan = {}
+        if self.chunk_tokens:
+            plan["chunk_blocks"] = max(
+                1, int(self.chunk_tokens) // int(block_size))
+        if self.min_width_slots:
+            plan["min_width"] = int(self.min_width_slots)
+        return plan
+
+    @classmethod
+    def per_head(cls, num_layers, num_heads, head_dim):
+        """A K and a V pool of all the heads' values side by side."""
+        part = (num_heads, head_dim)
+        return cls("kv", num_layers, (part, part),
+                   (num_heads * head_dim,) * 2, num_heads, head_dim)
+
+    def empty_prefill(self, dtype):
+        """The caches a prefill hands the model: for each cached sublayer
+        the two parts with no token in them yet."""
+        from ..framework.core import Tensor
+        caches = []
+        for _ in range(self.num_layers):
+            first = Tensor(jnp.zeros((1, 0) + self.parts[0], dtype))
+            # parts of one shape share the one empty tensor
+            caches.append((first, first if self.parts[1] == self.parts[0]
+                           else Tensor(jnp.zeros((1, 0) + self.parts[1],
+                                                 dtype))))
+        return caches
+
+
 class PagedCacheView:
     """The paged cache as one layer sees it from INSIDE the compiled
     decode step: the STACKED pools ``[L, num_blocks, block_size, H*D]``,
@@ -195,7 +269,9 @@ def _is_int8(dtype):
 class PagedKVCache:
     """The device pools + the allocator, sized once at engine start.
 
-    Pools are stacked over layers — ``[L, num_blocks, block_size, H*D]``
+    Built from the model's own description (`CacheSpec`): per-head K and
+    V pools, or a latent pool of one shared row a token. Pools are
+    stacked over layers — ``[L, num_blocks, block_size, H*D]``
     — so the compiled decode/prefill programs donate exactly two buffers
     regardless of depth, and every program updates them in place: the two
     minor dimensions are whole tiles (16 bf16 sublanes, H*D lanes), so
@@ -210,19 +286,24 @@ class PagedKVCache:
     HBM watermark admits ~2x the streams before `kv_exhausted`.
     """
 
-    def __init__(self, num_layers, num_heads, head_dim, num_blocks,
-                 block_size, dtype=jnp.float32):
-        self.num_layers = int(num_layers)
-        self.num_heads = int(num_heads)
-        self.head_dim = int(head_dim)
+    def __init__(self, spec, num_blocks, block_size, dtype=jnp.float32):
+        self.spec = spec
+        self.num_layers = spec.num_layers
+        self.num_heads = spec.num_heads
+        self.head_dim = spec.head_dim
         self.num_blocks = int(num_blocks)
         self.block_size = int(block_size)
         self.quantized = _is_int8(dtype)
         self.dtype = jnp.int8 if self.quantized else dtype
-        shape = (self.num_layers, self.num_blocks, self.block_size,
-                 self.num_heads * self.head_dim)
-        self.k_pools = jnp.zeros(shape, self.dtype)
-        self.v_pools = jnp.zeros(shape, self.dtype)
+        pools = [jnp.zeros((self.num_layers, self.num_blocks,
+                            self.block_size, width), self.dtype)
+                 for width in spec.widths]
+        # the programs thread two donated pools: a description with one
+        # width (a latent row, both parts side by side) leaves the second
+        # empty, and no program reads or writes it
+        self.k_pools = pools[0]
+        self.v_pools = pools[1] if len(pools) > 1 \
+            else jnp.zeros((0,), self.dtype)
         if self.quantized:
             sshape = (self.num_layers, self.num_blocks, self.num_heads)
             self.k_scales = jnp.zeros(sshape, jnp.float32)
@@ -298,7 +379,15 @@ def scatter_prefill(k_pools, v_pools, k_layers, v_layers, block_row,
         return k_pools, v_pools, k_scales, v_scales
     k_rows = k_layers.reshape(num_layers, t_bucket, -1).astype(k_pools.dtype)
     v_rows = v_layers.reshape(num_layers, t_bucket, -1).astype(v_pools.dtype)
+    if not v_pools.size:
+        # one pool (a latent row): both parts side by side in its rows,
+        # zeros up to the pool's width
+        fill = k_pools.shape[-1] - k_rows.shape[-1] - v_rows.shape[-1]
+        k_rows = jnp.concatenate(
+            [k_rows, v_rows, jnp.zeros(k_rows.shape[:2] + (fill,),
+                                       k_rows.dtype)], axis=-1)
     for layer in range(num_layers):
         k_pools = k_pools.at[layer, blocks, offs].set(k_rows[layer])
-        v_pools = v_pools.at[layer, blocks, offs].set(v_rows[layer])
+        if v_pools.size:
+            v_pools = v_pools.at[layer, blocks, offs].set(v_rows[layer])
     return k_pools, v_pools

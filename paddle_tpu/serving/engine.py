@@ -216,6 +216,14 @@ class ServeStats:
         self.attn_entries_streamed = 0
         self.attn_entries_held = 0
         self.attn_entries_total = 0
+        # tokens the programs were given: a prefill's context, a decode
+        # launch's active slots
+        self.prefill_tokens = 0
+        self.decode_tokens = 0
+        # what the model's own forward counted (an expert block's
+        # routing), summed over the calls of a phase: "<phase>_<name>",
+        # and "<phase>_counted" calls; read beside a launch's tokens
+        self.model_counts = {}
         # recent raw samples only (the admission wait estimate averages
         # the tail); percentiles live in the windowed histograms below
         self.step_times_s = []
@@ -225,6 +233,13 @@ class ServeStats:
         self.queue_wait_hist = LogHistogram()
         self.wall_t0 = None
         self.wall_t1 = None
+
+    def count_model(self, phase, names, values):
+        counts = self.model_counts
+        counts[phase + "_counted"] = counts.get(phase + "_counted", 0) + 1
+        for name, value in zip(names, values.tolist()):
+            key = f"{phase}_{name}"
+            counts[key] = counts.get(key, 0) + value
 
     def observe_step(self, active, num_slots, demand, dt_s):
         self.steps += 1
@@ -292,6 +307,10 @@ class ServeStats:
             "attn_held_share": (
                 self.attn_entries_held / self.attn_entries_total
                 if self.attn_entries_total else 0.0),
+            "prefill_tokens": self.prefill_tokens,
+            "decode_tokens": self.decode_tokens,
+            "decode_launches": self.launches,
+            **self.model_counts,
             "occupancy_mean": (self.occupancy_sum / self.steps
                                if self.steps else 0.0),
             "occupancy_saturated": (
@@ -331,7 +350,7 @@ class ServeStats:
 
 
 class LLMEngine:
-    """Multi-tenant autoregressive serving over a GPT-family model.
+    """Multi-tenant autoregressive serving over a decoder-only model.
 
     Usage::
 
@@ -343,8 +362,16 @@ class LLMEngine:
 
     Decoding is greedy (matches ``model.generate(do_sample=False)``
     token-for-token — the parity contract tests/test_serving.py pins).
-    The model is put in eval mode; by default its parameters are BAKED
-    into the compiled programs as constants. `hot_swap=True` and/or
+    The model is put in eval mode and asked what it caches for a token
+    (`model.cache_spec()`, serving/cache.py `CacheSpec`: per-head keys
+    and values, or one latent row): the pools, the prefill's empty
+    caches and the attention plan come from that description, and the
+    engine holds no head size of its own. By default the parameters are
+    BAKED into the compiled programs as constants; a model whose class
+    sets `serve_weights_as_arguments` (one too large for that) has them
+    passed as arguments instead, and one whose class names
+    `serve_counter_names` has its forward's counters (an expert block's
+    routing) summed into `stats()`. `hot_swap=True` and/or
     `max_adapters>0` switch the programs to the multi-tenant signature
     (serving/tenancy.py): the weights / adapter stacks become VALUE
     inputs, so `swap_weights()` refreshes the base checkpoint mid-traffic
@@ -395,7 +422,10 @@ class LLMEngine:
             params = model.parameters()
             dtype = params[0]._value.dtype if params else jnp.float32
         self._dtype = dtype
-        head_dim = cfg.hidden_size // cfg.num_attention_heads
+        # the model describes what it caches for a token (per-head keys
+        # and values, or one latent row); the pools, the attention plan
+        # and the prefill's empty caches all come from that description
+        spec = model.cache_spec()
         # kv_dtype="int8" stores the pool quantized (per-block-per-head
         # scales, quantization/kv_cache.py) — half the bytes per cached
         # token, so the same pool admits ~2x the streams
@@ -406,9 +436,23 @@ class LLMEngine:
         # bakes it in (zero retraces under churn); a flag flip only
         # affects engines built after it
         from ..nn.functional.attention import resolve_paged_kernel
+        if spec.kind == "latent":
+            # what a latent pool does not do yet is refused by name, here:
+            # none of it may run silently wrong
+            asked = attention_kernel or str(
+                _FLAGS.get("FLAGS_serve_attention_kernel") or "blockwise")
+            for option, on in (
+                    ("kv_dtype='int8'", self._kv_quantized),
+                    ("attention_kernel='pallas'", asked == "pallas"),
+                    ("enable_prefix_cache", enable_prefix_cache),
+                    ("max_adapters", max_adapters > 0)):
+                if on:
+                    raise ValueError(
+                        f"{option} is not supported over a latent cache "
+                        f"({type(model).__name__}.cache_spec())")
         self._attn_kernel = resolve_paged_kernel(
-            attention_kernel, num_heads=cfg.num_attention_heads,
-            head_dim=head_dim, block_size=self.block_size)
+            attention_kernel, num_heads=spec.num_heads,
+            head_dim=spec.head_dim, block_size=self.block_size)
         if self._kv_quantized:
             _EVENTS.emit("kernel.quantized", "serve.decode",
                          reason="kv_quantized",
@@ -416,9 +460,7 @@ class LLMEngine:
                                  "kernel": self._attn_kernel,
                                  "num_blocks": int(num_blocks),
                                  "block_size": self.block_size})
-        self.cache = PagedKVCache(cfg.num_hidden_layers,
-                                  cfg.num_attention_heads, head_dim,
-                                  num_blocks, self.block_size,
+        self.cache = PagedKVCache(spec, num_blocks, self.block_size,
                                   self._kv_dtype)
         self.scheduler = Scheduler(self.max_batch_size,
                                    self.cache.allocator, self.block_size,
@@ -435,11 +477,20 @@ class LLMEngine:
                                      dtype=self._dtype)
                           if max_adapters > 0 else None)
         self._hot_swap = bool(hot_swap)
+        # weights as program ARGUMENTS: for a hot-swapping engine, and for
+        # a model whose class says so (one too large to compile into the
+        # programs as constants)
+        self._weights_as_args = self._hot_swap or bool(
+            getattr(type(model), "serve_weights_as_arguments", False))
+        # what the model's forward counts for the engine's stats (an
+        # expert block's routing), fetched beside a launch's tokens
+        self._counter_names = tuple(
+            getattr(type(model), "serve_counter_names", ()))
         # aux-input mode: the decode/prefill signatures gain an `aux`
         # pytree (weights as values / adapter stacks + slot indices);
         # with both features off the signatures stay byte-identical to
         # the single-tenant engine
-        self._tenant = self._hot_swap or self._adapters is not None
+        self._tenant = self._weights_as_args or self._adapters is not None
         self._holder = None
         if self._adapters is not None:
             holder = getattr(model, "_tenancy_holder", None)
@@ -1167,6 +1218,7 @@ class LLMEngine:
             self._recover_with_fallback(rebuild=False)
             return None
         with self._span("engine.decode.fetch"):
+            self._count_model("decode", res)
             return (np.asarray(res[0]), np.asarray(res[1]),
                     np.asarray(res[2]), np.asarray(res[3]))
 
@@ -1323,6 +1375,8 @@ class LLMEngine:
                 self._prefix.publish(ctx, req.blocks,
                                      include_tail=not req.generated)
             tok = int(np.asarray(nxt))
+            self._stats.prefill_tokens += len(ctx)
+            self._count_model("prefill", res)
             # the prefill's sampled token is the next decode step's input
             self._tokens[req.slot] = tok
             if req.cached_len < self.max_context:
@@ -1640,6 +1694,7 @@ class LLMEngine:
                 self._k_scales, self._v_scales = res[6], res[7]
             self._maybe_store_decode()
             with self._span("engine.decode.fetch"):
+                self._count_model("decode", res)
                 return (np.asarray(nxt), np.asarray(res[1]),
                         np.asarray(res[2]), np.asarray(res[3]))
 
@@ -1670,6 +1725,7 @@ class LLMEngine:
         stats = self._stats
         stats.launches += 1
         stats.launches_overlapped += self._inflight is not None
+        stats.decode_tokens += int(np.count_nonzero(args[self._ARG_ACTIVE]))
         res = self._call_program("engine.decode.dispatch", fn, args,
                                  fn is not self._decode_called)
         self._decode_called = fn
@@ -1678,13 +1734,21 @@ class LLMEngine:
                               args[self._ARG_ACTIVE])
         return res
 
+    def _count_model(self, phase, res):
+        """A program's last result, where the model counts: its counters
+        for the stats, read in the same turn as the program's tokens."""
+        if self._counter_names:
+            self._stats.count_model(phase, self._counter_names,
+                                    np.asarray(res[-1]))
+
     def _count_attention(self, lens, active):
         """One decode launch's attention in block-table entries, from
         the lengths and the mask the launch is given. Only the blockwise
         loop follows the lengths; the other variants read every entry."""
         streamed, held = blockwise_streamed_entries(
             lens, active, self.max_blocks_per_seq, self.block_size,
-            self.cache.num_heads, self.cache.head_dim)
+            self.cache.num_heads, self.cache.head_dim,
+            **self.cache.spec.loop_plan(self.block_size))
         total = self.max_batch_size * self.max_blocks_per_seq
         stats = self._stats
         stats.attn_entries_streamed += (
@@ -1807,12 +1871,8 @@ class LLMEngine:
         re-prefill on admission."""
         assert not self.scheduler.running, \
             "KV reset with live streams would corrupt them"
-        cfg = self._model.config
-        head_dim = cfg.hidden_size // cfg.num_attention_heads
-        self.cache = PagedKVCache(cfg.num_hidden_layers,
-                                  cfg.num_attention_heads, head_dim,
-                                  self._num_blocks, self.block_size,
-                                  self._kv_dtype)
+        self.cache = PagedKVCache(self.cache.spec, self._num_blocks,
+                                  self.block_size, self._kv_dtype)
         self.scheduler.allocator = self.cache.allocator
         s, m = self.max_batch_size, self.max_blocks_per_seq
         self._tables = np.zeros((s, m), np.int32)
@@ -2027,13 +2087,28 @@ class LLMEngine:
                             meta={"max_batch_size": self.max_batch_size,
                                   "block_size": self.block_size})
 
+    def _forward(self, ids, caches, length=None):
+        """The model over `ids` through `caches`, inside a program's
+        trace: ``(logits, caches, extra outputs)``, the extra being the
+        model's own counters where its class names some (a prefill's
+        `length` keeps its bucket's padding out of them)."""
+        kwargs = {}
+        if self._counter_names and length is not None:
+            kwargs["valid"] = (jnp.arange(ids.shape[1], dtype=jnp.int32)
+                               < length)[None, :]
+        with set_grad_enabled(False):
+            logits, caches = self._model(
+                Tensor(ids, stop_gradient=True), caches=caches, **kwargs)
+        extra = ((self._model.pop_serve_counters(),)
+                 if self._counter_names else ())
+        return logits, caches, extra
+
     def _build_decode(self, use_aot=True):
         if self._tenant:
             # the aux-input program: weights/adapters as values. AOT
             # export of a pytree-carrying signature is not supported —
             # tenant replicas always trace once at start
             return self._build_decode_tenant()
-        model = self._model
         block_size = self.block_size
         stats = self._stats
         variant = self._attn_kernel
@@ -2052,10 +2127,7 @@ class LLMEngine:
             view = PagedCacheView(
                 k_pools, v_pools, 0, tables, lens, active, block_size,
                 k_scales=k_scales, v_scales=v_scales, kernel=variant)
-            with set_grad_enabled(False):
-                logits, (view,) = model(
-                    Tensor(tokens[:, None], stop_gradient=True),
-                    caches=[view])
+            logits, (view,), extra = self._forward(tokens[:, None], [view])
             # the in-graph history scatter: the input token enters the
             # context at index `lens` — under pipelined decode it may
             # exist ONLY on-device (feedback), so the host mirror cannot
@@ -2074,7 +2146,7 @@ class LLMEngine:
             written = (view.k_pools, view.v_pools)
             if k_scales is not None:
                 written += (view.k_scales, view.v_scales)
-            return (nxt, logp, alt_ids, alt_lps) + written
+            return (nxt, logp, alt_ids, alt_lps) + written + extra
 
         donate = (12, 13, 14, 15) if self._kv_quantized else (12, 13)
         jitted = jax.jit(decode, donate_argnums=self._donate(donate))
@@ -2132,10 +2204,8 @@ class LLMEngine:
                 view = PagedCacheView(
                     k_pools, v_pools, 0, tables, lens, active, block_size,
                     k_scales=k_scales, v_scales=v_scales, kernel=variant)
-                with set_grad_enabled(False):
-                    logits, (view,) = model(
-                        Tensor(tokens[:, None], stop_gradient=True),
-                        caches=[view])
+                logits, (view,), extra = self._forward(tokens[:, None],
+                                                       [view])
             finally:
                 if saved is not None:
                     for pp, vv in zip(params, saved):
@@ -2153,7 +2223,7 @@ class LLMEngine:
             written = (view.k_pools, view.v_pools)
             if k_scales is not None:
                 written += (view.k_scales, view.v_scales)
-            return (nxt, logp, alt_ids, alt_lps) + written
+            return (nxt, logp, alt_ids, alt_lps) + written + extra
 
         donate = (13, 14, 15, 16) if self._kv_quantized else (13, 14)
         return jax.jit(decode, donate_argnums=self._donate(donate))
@@ -2162,10 +2232,7 @@ class LLMEngine:
         if self._tenant:
             return self._build_prefill_tenant(bucket)
         model = self._model
-        cfg = model.config
-        num_layers = cfg.num_hidden_layers
-        heads = cfg.num_attention_heads
-        head_dim = cfg.hidden_size // heads
+        spec = self.cache.spec
         block_size = self.block_size
         params = model.parameters()
         dt = params[0]._value.dtype if params else jnp.float32
@@ -2176,11 +2243,8 @@ class LLMEngine:
                     seedv, k_pools, v_pools,
                     k_scales=None, v_scales=None):
             stats.prefill_compiles += 1   # runs only while tracing
-            empty = [(Tensor(jnp.zeros((1, 0, heads, head_dim), dt)),) * 2
-                     for _ in range(num_layers)]
-            with set_grad_enabled(False):
-                logits, caches = model(Tensor(ids, stop_gradient=True),
-                                       caches=[tuple(c) for c in empty])
+            logits, caches, extra = self._forward(
+                ids, spec.empty_prefill(dt), length)
             k_layers = jnp.stack([c[0]._value[0] for c in caches])
             v_layers = jnp.stack([c[1]._value[0] for c in caches])
             written = scatter_prefill(
@@ -2200,7 +2264,7 @@ class LLMEngine:
                 jnp.reshape(length, (1,)), ids.astype(jnp.int32), valid,
                 logprobs_topk=lp_topk)
             return (nxt[0], logp[0], alt_ids[0], alt_lps[0]) \
-                + tuple(written)
+                + tuple(written) + extra
 
         donate = (8, 9, 10, 11) if self._kv_quantized else (8, 9)
         return jax.jit(prefill, donate_argnums=self._donate(donate))
@@ -2210,10 +2274,7 @@ class LLMEngine:
         program with the aux pytree (weights as values in hot-swap mode;
         the one admitted request's scalar adapter slot)."""
         model = self._model
-        cfg = model.config
-        num_layers = cfg.num_hidden_layers
-        heads = cfg.num_attention_heads
-        head_dim = cfg.hidden_size // heads
+        spec = self.cache.spec
         block_size = self.block_size
         params = model.parameters()
         dt = params[0]._value.dtype if params else jnp.float32
@@ -2235,13 +2296,8 @@ class LLMEngine:
                 holder["active"] = AdapterSet.trace_ctx(
                     aux["adapters"], slot=aux["slot"])
             try:
-                empty = [(Tensor(jnp.zeros((1, 0, heads, head_dim),
-                                           dt)),) * 2
-                         for _ in range(num_layers)]
-                with set_grad_enabled(False):
-                    logits, caches = model(
-                        Tensor(ids, stop_gradient=True),
-                        caches=[tuple(c) for c in empty])
+                logits, caches, extra = self._forward(
+                    ids, spec.empty_prefill(dt), length)
             finally:
                 if saved is not None:
                     for pp, vv in zip(params, saved):
@@ -2264,7 +2320,7 @@ class LLMEngine:
                 jnp.reshape(length, (1,)), ids.astype(jnp.int32), valid,
                 logprobs_topk=lp_topk)
             return (nxt[0], logp[0], alt_ids[0], alt_lps[0]) \
-                + tuple(written)
+                + tuple(written) + extra
 
         donate = (9, 10, 11, 12) if self._kv_quantized else (9, 10)
         return jax.jit(prefill, donate_argnums=self._donate(donate))
@@ -2278,7 +2334,7 @@ class LLMEngine:
         between calls), so churning its values never re-keys the
         program."""
         aux = {}
-        if self._hot_swap:
+        if self._weights_as_args:
             aux["params"] = [p._value
                              for p in self._model.parameters()]
         if self._adapters is not None:
@@ -2288,7 +2344,7 @@ class LLMEngine:
 
     def _prefill_aux(self, req):
         aux = {}
-        if self._hot_swap:
+        if self._weights_as_args:
             aux["params"] = [p._value
                              for p in self._model.parameters()]
         if self._adapters is not None:

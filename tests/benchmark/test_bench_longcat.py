@@ -1,0 +1,323 @@
+"""The LongCat-Flash family through the benchmark, on the CPU at a tiny size
+(`longcat_model/tiny_longcat.py`): its cell runs on the `serve_backlog`
+loop with the REAL program and reference modules and is correct; served in
+the fp8 control it is not, by the mean gap; the reference reads the
+router's published width and the held count from the file; the counts and
+readers of its per-layer metrics; the seed check that drops the engine
+before the reference's weights are made."""
+import json
+import os
+import sys
+
+import numpy as np
+import pytest
+
+HERE = os.path.dirname(os.path.abspath(__file__))
+sys.path[:0] = [HERE, os.path.join(HERE, "longcat_model")]
+import config_rules  # noqa: E402
+import tiny_root  # noqa: E402
+from tiny_longcat import TINY_LONGCAT  # noqa: E402
+
+from benchmark import correct, harness, longcat_counts, \
+    run as bench_run, seeded, seedcheck_released  # noqa: E402
+from benchmark.loops import serving  # noqa: E402
+from benchmark.readers import engine_stat_ratio, \
+    longcat_decode_hbm_roofline, longcat_serve_mfu  # noqa: E402
+from benchmark.reference import longcat_flash as ref  # noqa: E402
+
+REPO = tiny_root.REPO
+# from readings on the CPU over seeds 3, 5, 2**31 + 7 and 3000028201: the
+# program through the engine and a bfloat16 stand-in read a widest gap
+# <= 0.0022 and a mean <= 0.00001; the fp8 control's widest 0.41-1.38 and
+# mean 0.0200-0.0257. By PERF.md section 4's rule for the mean (at least
+# twice the sound largest, at most half the control's smallest); the widest
+# guards against gross faults only and the control passes it
+LIMITS = {"logit_gap": 3.0, "logit_gap_mean": 0.008}
+STREAMS, PROMPT, SERVED, PAD = 8, 8, 24, 32
+with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+    SPEC = json.load(f)
+LONGCAT_METRICS = [m["name"] for m in SPEC["per_layer"]
+                   if m["name"].startswith("longcat.")]
+
+
+@pytest.fixture
+def longcat_root(root):
+    tiny_root.add_cell(
+        root, "longcat_cell", ("tiny_longcat", TINY_LONGCAT),
+        # 16 requests a sample: some 100 served tokens, so that a mean
+        # gap is a mean (three requests' 20 tokens swing by the sample)
+        ("longcat_mix", dict(tiny_root.TRAFFIC["tiny_backlog"],
+                             reference_pad_to=PAD, checked_requests=16)),
+        LIMITS, ("serve_tokens_per_s", *LONGCAT_METRICS))
+    return root
+
+
+@pytest.mark.parametrize("seed", [3, 2 ** 31 + 7])
+def test_the_tiny_cell_runs_on_the_backlog_loop_and_is_correct(
+        longcat_root, seed):
+    line = bench_run.run_cell(longcat_root, "longcat_cell", seed, 1.0, False,
+                              require_chip=False)
+    assert line["correct"] and line["failed"] == 0 and line["attempted"] > 0
+    assert set(line["metrics"]) == {"serve_tokens_per_s", "setup_s"}
+    assert set(line["checks"]) == set(serving.COMPARED)
+
+
+def test_a_traced_run_reports_every_metric_of_the_cell(longcat_root,
+                                                       monkeypatch):
+    """Every `longcat.*` metric appears, finite, with a canned device
+    trace (the CPU gives the profiler no device plane) and canned peaks;
+    a share of a peak stays inside 0..100."""
+    import contextlib
+
+    @contextlib.contextmanager
+    def traced_slice(self):
+        yield
+        self.evidence["trace"] = {
+            "window_s": 1.0, "devices": 1, "busy_s": 0.9,
+            "collective_s": 0.0, "collective_exposed_s": 0.0,
+            "op_seconds": {}, "op_counts": {}, "gaps": [], "spans": []}
+        self.evidence["peaks"] = {"bf16_flops_per_s": 197e12,
+                                  "hbm_bytes_per_s": 819e9}
+    monkeypatch.setattr(harness.Run, "traced_slice", traced_slice)
+    line = bench_run.run_cell(longcat_root, "longcat_cell", 5, 1.0, True,
+                              require_chip=False)
+    # the CPU's backend reports no memory peak: that one reader finds
+    # nothing to read and its metric is left out, not raised
+    assert set(line["metrics"]) == set(LONGCAT_METRICS) \
+        - {"longcat.hbm_peak_gb"}
+    for name, m in line["metrics"].items():
+        assert np.isfinite(m["value"]), name
+    for name in ("longcat.serve_mfu", "longcat.decode_hbm_roofline",
+                 "longcat.held_choice_share",
+                 "longcat.identity_choice_share",
+                 "longcat.pipelined_launch_share"):
+        assert 0 <= line["metrics"][name]["value"] <= 100, name
+    # at the tiny ratios: 4 of 24 ranked held, 8 identity
+    assert line["metrics"]["longcat.expert_load_max_over_mean"]["value"] >= 1
+
+
+def served_in(precision, seed):
+    """Requests decoded greedily by the reference computed in
+    `precision`: [(prompt ids, served ids)]."""
+    import jax
+    import jax.numpy as jnp
+    weights = correct.weight_maker(TINY_LONGCAT, seed)()
+    rng = seeded.host_rng(seed, 9)
+    ids = np.zeros((STREAMS, PAD), np.int32)
+    ids[:, :PROMPT] = rng.integers(0, TINY_LONGCAT["vocab_size"],
+                                   (STREAMS, PROMPT))
+
+    @jax.jit
+    def first(w, ids, at):
+        return jnp.argmax(ref.forward(w, ids, TINY_LONGCAT,
+                                      precision)[:, at], -1)
+
+    for at in range(PROMPT - 1, PROMPT + SERVED - 1):
+        ids[:, at + 1] = np.asarray(first(weights, jnp.asarray(ids), at))
+    return [(row[:PROMPT].tolist(), row[PROMPT:PROMPT + SERVED].tolist())
+            for row in ids]
+
+
+@pytest.mark.parametrize("seed", [3, 3000028201])
+@pytest.mark.parametrize("precision,correct_", [("bfloat16", True),
+                                                ("fp8", False)])
+def test_served_in_the_fp8_control_it_fails_by_the_mean_gap(
+        longcat_root, seed, precision, correct_):
+    run = harness.Run(longcat_root, "longcat_cell", seed, 1.0, False,
+                      require_chip=False)
+    serving.check_served(run, served_in(precision, seed))
+    checks = {name: ok for name, _, _, ok in run.checks}
+    assert checks["logit_gap"]              # the widest passes either way
+    assert checks["logit_gap_mean"] == correct_
+    assert (bool(run.checks) and all(c[3] for c in run.checks)) == correct_
+
+
+def test_the_reference_reads_the_routers_width_and_the_held_count():
+    shapes = ref.param_shapes(TINY_LONGCAT)
+    p = "model.layers.0.mlp."
+    assert shapes[p + "router.classifier.weight"] == (64, 16 + 8)
+    assert shapes[p + "experts.gate_proj.weight"] == (4, 64, 32)
+    whole = {k: v for k, v in TINY_LONGCAT.items() if k != "published"}
+    assert ref.param_shapes(whole)[p + "router.classifier.weight"] == \
+        (64, 4 + 8)
+    real = json.load(open(os.path.join(
+        REPO, "benchmark", "configs", "longcat_flash_chat_ep32.json")))
+    shapes = ref.param_shapes(real)
+    assert shapes[p + "router.classifier.weight"] == (6144, 512 + 256)
+    assert shapes[p + "experts.down_proj.weight"] == (16, 2048, 6144)
+    assert "model.layers.0.mlp.router.e_score_correction_bias" not in shapes
+    # 2 bytes a parameter: 10.35 GB of the chip's 15.75
+    assert 5.17e9 < ref.num_params(real) < 5.18e9
+    assert not any("paddle" in line for line in open(ref.__file__)
+                   if line.startswith(("import", "from")))
+
+
+def _entry():
+    return next(c for c in SPEC["configs"]
+                if c["name"] == "longcat_flash_chat_ep32")
+
+
+def test_the_configuration_keeps_every_published_number():
+    entry = _entry()
+    cfg = json.load(open(os.path.join(REPO, entry["file"])))
+    assert config_rules.problems(entry, cfg) == []
+    catalog = {
+        "attention_bias": False, "vocab_size": 131072, "hidden_size": 6144,
+        "ffn_hidden_size": 12288, "expert_ffn_hidden_size": 2048,
+        "num_layers": 28, "num_attention_heads": 64, "kv_lora_rank": 512,
+        "q_lora_rank": 1536, "qk_rope_head_dim": 64, "v_head_dim": 128,
+        "qk_nope_head_dim": 128, "mla_scale_q_lora": True,
+        "mla_scale_kv_lora": True, "routed_scaling_factor": 6,
+        "n_routed_experts": 512, "max_position_embeddings": 131072,
+        "rms_norm_eps": 1e-05, "rope_theta": 10000000,
+        "attention_method": "MLA", "zero_expert_num": 256,
+        "zero_expert_type": "identity", "moe_topk": 12}
+    for key, value in catalog.items():
+        if key in entry["reduced"]:
+            assert cfg["published"][key] == value and cfg[key] < value
+        else:
+            assert cfg[key] == value, key
+    assert entry["reduced"] == cfg["reduced"] == [
+        "num_layers", "n_routed_experts", "vocab_size"]
+    assert (cfg["num_layers"], cfg["n_routed_experts"],
+            cfg["vocab_size"]) == (4, 16, 16384)
+    assert "optimizer" not in cfg and cfg["precision"]["control"] == "fp8"
+
+
+@pytest.mark.parametrize("key", ["moe_topk", "zero_expert_num",
+                                 "expert_ffn_hidden_size", "kv_lora_rank"])
+def test_the_rules_refuse_a_cut_of_what_is_no_count_held(key):
+    entry = _entry()
+    cfg = json.load(open(os.path.join(REPO, entry["file"])))
+    cut = dict(entry, reduced=entry["reduced"] + [key])
+    found = config_rules.problems(cut, dict(cfg, changed=dict(
+        cfg["changed"], **{key: "cut"})))
+    assert any(f"names {key}: a width, or no kind" in f for f in found)
+
+
+# -- the counts and the readers -----------------------------------------------
+
+def test_the_counts_are_the_issues_arithmetic():
+    cfg = json.load(open(os.path.join(REPO, _entry()["file"])))
+    assert longcat_counts.attention_params(cfg) == 90570752
+    assert longcat_counts.dense_ffn_params(cfg) == 226492416
+    assert longcat_counts.expert_params(cfg) == 37748736
+    assert longcat_counts.router_params(cfg) == 6144 * 768
+    layer = 2 * 90570752 + 2 * 226492416 + 6144 * 768
+    assert longcat_counts.token_params(cfg) == 4 * layer + 6144 * 16384
+    assert longcat_counts.serve_flops(cfg, 10, 3) == 2 * (
+        10 * longcat_counts.token_params(cfg) + 3 * 37748736)
+    # a decode launch that reads every held expert and 128 x 500 rows a
+    # sublayer: 10.2 GB of weights and 0.59 GB of rows
+    moved = longcat_counts.decode_bytes(cfg, 1, 4 * 16, 128 * 500 * 8)
+    assert 10.7e9 < moved < 10.9e9
+
+
+def _evidence(stats):
+    cfg = json.load(open(os.path.join(REPO, _entry()["file"])))
+    return {"config": cfg, "engine_stats": stats, "window": (10.0, 50.0),
+            "peaks": {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9},
+            "engine_facts": {"slots": 128, "table_entries": 128,
+                             "block_size": 16, "cached_sublayers": 8}}
+
+
+def test_the_shares_of_the_peaks_follow_the_windows_counters():
+    stats = {"prefill_tokens": 100000, "decode_tokens": 250000,
+             "decode_launches": 2000, "decode_counted": 2000,
+             "decode_routed_computed": 62000,
+             "prefill_routed_computed": 25000,
+             "decode_experts_idle": 16000, "attn_held_share": 0.25}
+    cfg = _evidence(stats)["config"]
+    mfu = longcat_serve_mfu.read(_evidence(stats))
+    want = 100 * 2 * (350000 * longcat_counts.token_params(cfg)
+                      + 87000 * 37748736) / 40 / 197e12
+    assert mfu == pytest.approx(want) and 0 < mfu < 100
+    share = longcat_decode_hbm_roofline.read(_evidence(stats))
+    rows = 0.25 * 2000 * 128 * 128 * 16 * 8
+    want = 100 * longcat_counts.decode_bytes(
+        cfg, 2000, 2000 * 64 - 16000, rows) / 819e9 / 40
+    assert share == pytest.approx(want) and 0 < share < 100
+    # fewer idle experts are more bytes a launch
+    busier = dict(stats, decode_experts_idle=0)
+    assert longcat_decode_hbm_roofline.read(_evidence(busier)) > share
+
+
+def test_a_program_without_the_counters_leaves_the_metrics_out():
+    """What the parent commit's engine reports: no counter of the model's,
+    no `decode_launches`: each reader returns nothing and does not raise."""
+    bare = {"steps": 5, "attn_held_share": 0.2, "prefill_share": 0.1}
+    assert longcat_serve_mfu.read(_evidence(bare)) is None
+    assert longcat_decode_hbm_roofline.read(_evidence(bare)) is None
+    assert engine_stat_ratio.read(
+        _evidence(bare), over=["decode_load_max"],
+        under=["decode_routed_held"], scale_by="n_routed_experts") is None
+    assert longcat_serve_mfu.read({"config": {}}) is None
+
+
+def test_a_ratio_of_counters_scales_by_the_files_count():
+    stats = {"decode_load_max": 30, "decode_routed_held": 160,
+             "prefill_routed_held": 40}
+    ev = _evidence(stats)
+    assert engine_stat_ratio.read(
+        ev, over=["decode_load_max"], under=["decode_routed_held"],
+        scale_by="n_routed_experts") == pytest.approx(30 * 16 / 160)
+    assert engine_stat_ratio.read(
+        ev, over=["prefill_routed_held"],
+        under=["decode_routed_held", "prefill_routed_held"],
+        scale=100.0) == pytest.approx(20.0)
+    assert engine_stat_ratio.read(
+        _evidence(dict(stats, decode_routed_held=0)),
+        over=["decode_load_max"], under=["decode_routed_held"]) is None
+
+
+def test_the_released_seed_check_holds_the_control_to_the_limits(
+        longcat_root, monkeypatch, capsys):
+    """Every seed's traffic is served before the engine is dropped; then
+    the sound program passes and the control fails through `Run.check`."""
+    import gc
+    import jax
+    monkeypatch.setattr(seedcheck_released, "ROOT", longcat_root)
+    real = harness.Run.__init__
+    monkeypatch.setattr(
+        harness.Run, "__init__",
+        lambda self, *a, **kw: real(self, *a, **dict(kw,
+                                                     require_chip=False)))
+    alive = []
+    released = serving.released
+
+    def watch(run):
+        released(run)
+        gc.collect()
+        alive.append(sum(a.nbytes for a in jax.live_arrays()))
+    monkeypatch.setattr(serving, "released", watch)
+    out = os.path.join(longcat_root, "rows.jsonl")
+    seedcheck_released.main(["--workload", "longcat_cell", "--seeds", "3,5",
+                             "--control-seeds", "5", "--seconds", "1.0",
+                             "--out", out])
+    rows = [json.loads(line) for line in open(out)]
+    assert [r["seed"] for r in rows] == [3, 5]
+    assert all(r["correct"] and r["finished"] > 0 for r in rows)
+    assert rows[1]["control_correct"] is False
+    assert rows[1]["checks"]["control_logit_gap"]["ok"]
+    assert not rows[1]["checks"]["control_logit_gap_mean"]["ok"]
+    assert "control_correct" not in rows[0]
+    # when the first reference weights were made, nothing of the engine
+    # (weights, pools: 0.3 MB even at this size) was alive on the device
+    assert alive and alive[0] < 64 * 1024
+
+
+def test_the_long_decode_mix_stays_inside_its_buckets_and_its_context():
+    """Every prompt falls into a bucket the set-up compiled, and prompt +
+    output fits the engine's context and the reference's padded row."""
+    from benchmark import traffic
+    mix = json.load(open(os.path.join(
+        REPO, "benchmark", "traffic", "backlog_long_decode.json")))
+    prompts = traffic.stratified_lengths(mix["prompt_tokens"], mix["block"])
+    outputs = traffic.stratified_lengths(mix["output_tokens"], mix["block"])
+    buckets = {max(8, 1 << (n - 1).bit_length()) for n in prompts}
+    assert buckets <= set(mix["prefill_buckets"])
+    assert max(prompts) + max(outputs) <= mix["reference_pad_to"] \
+        <= mix["engine"]["max_context"]
+    assert mix["loop"] == "serve_backlog" and mix["queue_depth"] == 32
+    # a standing queue of long decodes: hundreds of output tokens each
+    assert min(outputs) >= 128 and sum(outputs) > 1.9 * sum(prompts)

@@ -234,3 +234,59 @@ def test_the_donated_kv_pool_is_updated_where_it_lies(v5e, monkeypatch,
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= 2 * CELL_LAYERS * one_layer
     assert memory.temp_size_in_bytes < one_layer, memory.temp_size_in_bytes
+
+
+# The long-decode cell's latent geometry (benchmark/traffic/
+# backlog_long_decode.json: 128 slots, block 16, 128 table entries; a row
+# of 512 + 64 values padded to 640, 64 heads attending absorbed); two
+# sublayers of 2,049 blocks stand for the cell's 8 of 16,385.
+LATENT_TABLE, LATENT_HEADS, LATENT_ROW, LATENT_VALUE = 128, 64, 640, 512
+LATENT_POOL = (CELL_LAYERS, CELL_BLOCKS, BLOCK, LATENT_ROW)
+
+
+@pytest.mark.parametrize("program", ["decode_blockwise", "prefill_512"])
+def test_the_donated_latent_pool_is_updated_where_it_lies(v5e, program):
+    """The ONE latent pool comes in row-major and goes out in the buffer it
+    came in; no instruction of the pool's shape is a `copy`, a
+    `concatenate` or a `pad`; the second (empty) pool costs nothing."""
+    from paddle_tpu.nn.functional.attention import \
+        paged_latent_decode_attention
+    if program == "prefill_512":
+        def fn(rows, keys, block_row, length, k_pools, v_pools):
+            return scatter_prefill(k_pools, v_pools, rows, keys, block_row,
+                                   length, BLOCK)
+        shapes = [((CELL_LAYERS, 512, LATENT_VALUE), jnp.bfloat16),
+                  ((CELL_LAYERS, 512, 64), jnp.bfloat16),
+                  ((LATENT_TABLE,), jnp.int32), ((), jnp.int32)]
+    else:
+        def fn(q, row, key, tables, lens, active, k_pools, v_pools):
+            for layer in range(CELL_LAYERS):
+                out, k_pools = paged_latent_decode_attention(
+                    q, (row, key), k_pools, layer, tables, lens, active,
+                    BLOCK, value_width=LATENT_VALUE, scale=0.07,
+                    kernel="blockwise", chunk_blocks=16)
+                q = q + jnp.pad(out, ((0, 0), (0, 0), (0, 64)))
+            return q, k_pools, v_pools
+        shapes = [((CELL_SLOTS, LATENT_HEADS, 576), jnp.float32),
+                  ((CELL_SLOTS, LATENT_VALUE), jnp.bfloat16),
+                  ((CELL_SLOTS, 64), jnp.bfloat16),
+                  ((CELL_SLOTS, LATENT_TABLE), jnp.int32),
+                  ((CELL_SLOTS,), jnp.int32), ((CELL_SLOTS,), jnp.bool_)]
+    shapes += [(LATENT_POOL, jnp.bfloat16), ((0,), jnp.bfloat16)]
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+            for shape, dtype in shapes]
+    compiled = jax.jit(fn, donate_argnums=(len(args) - 2,
+                                           len(args) - 1)).lower(
+        *args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    pool = "bf16[" + ",".join(map(str, LATENT_POOL)) + "]"
+    made = re.findall(r"= " + re.escape(pool) + r"\{([\d,]*)\S* ([\w-]+)\(",
+                      text)
+    assert {layout for layout, opcode in made
+            if opcode == "parameter"} == {"3,2,1,0"}
+    opcodes = {opcode for _, opcode in made}
+    assert "scatter" in opcodes
+    assert not opcodes & {"copy", "concatenate", "pad"}, opcodes
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 2 * math.prod(LATENT_POOL)
