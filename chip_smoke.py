@@ -290,9 +290,9 @@ def serve_requests(model, prompts, max_new_tokens, attention_kernel):
                                 "serve.hang")
                   for e in fusion_events(cat)]
 
-    want = attention_kernel or "blockwise"
-    check(stats["attention_kernel"] == want,
-          f"asked for {want}, engine runs {stats['attention_kernel']}")
+    check(stats["attention_kernel"] == attention_kernel,
+          f"asked for {attention_kernel}, "
+          f"engine runs {stats['attention_kernel']}")
     check(not hidden, f"the engine degraded or fell back: {hidden[:3]}")
     check(all(r.state == FINISHED and len(r.generated) == max_new_tokens
               for r in reqs),
@@ -343,15 +343,17 @@ def greedy_gaps(model, prompts, streams, pad_to):
 
 
 def serve_phase(cfg, prompt_lens, max_new_tokens, seed):
-    """Serve the same requests with the default attention variant and with
-    the Pallas kernel, and hold every stream to the model itself."""
+    """Serve the same requests with the blockwise loop and with the Pallas
+    kernel, each asked for by name (unasked, the engine chooses between
+    them from the platform and the pool), and hold every stream to the
+    model itself."""
     import jax.numpy as jnp
     model = make_model(cfg, seed)
     rng = np.random.default_rng(seed)
     prompts = [rng.integers(0, cfg.vocab_size, n).tolist()
                for n in prompt_lens]
     records, streams = [], {}
-    for kernel in (None, "pallas"):
+    for kernel in ("blockwise", "pallas"):
         streams[kernel], rec = serve_requests(model, prompts,
                                               max_new_tokens, kernel)
         records.append({"phase": "serve", **rec})
@@ -371,19 +373,20 @@ def serve_phase(cfg, prompt_lens, max_new_tokens, seed):
     pad_to = -(-(max(prompt_lens) + max_new_tokens) // 128) * 128
     gaps, scale = greedy_gaps(
         model, prompts + prompts + prompts[:1],
-        streams[None] + streams["pallas"] + [reference], pad_to)
+        streams["blockwise"] + streams["pallas"] + [reference], pad_to)
     slack = 4 * BF16_EPS * scale
     check(max(gaps) <= slack,
           f"a served token is not the reference's choice: worst logit gaps "
           f"{[round(g, 4) for g in gaps]} (bf16 slack {slack:.4f})")
-    check(streams[None][0] == reference,
+    check(streams["blockwise"][0] == reference,
           f"the engine's first stream is not model.generate's: "
-          f"{streams[None][0]} vs {reference}")
+          f"{streams['blockwise'][0]} vs {reference}")
     records[-1].update({
         "reference_generate_s": round(ref_s, 2),
         "generate_token_identical": True,
         "pallas_streams_identical": sum(
-            a == b for a, b in zip(streams[None], streams["pallas"])),
+            a == b for a, b in zip(streams["blockwise"],
+                                   streams["pallas"])),
         "worst_logit_gap": round(max(gaps), 5),
         "logit_gap_slack": round(slack, 5)})
     return records

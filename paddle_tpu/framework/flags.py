@@ -90,18 +90,23 @@ define_flag("FLAGS_serve_step_timeout_ms", 0,
             "GC pause or host hiccup never trips it")
 define_flag("FLAGS_use_flash_attention", True,
             "route eligible attention through the Pallas flash kernel")
-define_flag("FLAGS_serve_attention_kernel", "blockwise",
-            "paged decode attention variant for the serving engine: "
-            "'pallas' (TPU Pallas kernel, one KV block in VMEM at a time, "
-            "dequant fused into the block loads; falls back to blockwise "
-            "off-TPU / on ineligible shapes with an attributed "
-            "kernel.fallback event), 'blockwise' (pure-JAX lax.scan over "
-            "blocks with online softmax — the CPU/parity fallback, still "
-            "never materializes the [S, T, H, D] context), or 'reference' "
-            "(the dense gather-by-block-table oracle). The value is keyed "
-            "into the per-op dispatch cache key (the op fn closes over the "
-            "resolved variant) and the AOT store's environment fingerprint, "
-            "so flips re-key cleanly instead of replaying stale programs")
+define_flag("FLAGS_serve_attention_kernel", "",
+            "an explicit paged decode attention variant for the serving "
+            "engine. Unset (the default) the engine chooses from what it "
+            "observes (nn/functional/attention.py resolve_paged_kernel): "
+            "'pallas' on a TPU over a per-head fp pool whose row and block "
+            "are whole tiles, 'blockwise' for everything else. Set, it "
+            "names one: 'pallas' (the TPU kernel that copies only the "
+            "pages that hold tokens; falls back to blockwise off-TPU, on "
+            "ineligible shapes and over an int8 pool with an attributed "
+            "kernel.fallback event), 'blockwise' (the pure-JAX "
+            "length-bounded loop with online softmax, the CPU/parity path, "
+            "which never materializes the [S, T, H, D] context), or "
+            "'reference' (the dense gather-by-block-table oracle). The "
+            "value is keyed into the per-op dispatch cache key (the op fn "
+            "closes over the resolved variant) and the AOT store's "
+            "environment fingerprint, so flips re-key cleanly instead of "
+            "replaying stale programs")
 define_flag("FLAGS_use_fused_cross_entropy", False,
             "route large-vocab CE through the vocab-blocked Pallas kernel. "
             "Off by default: measured on v5e GPT-2 (V=50304), XLA's CE fused "

@@ -196,9 +196,10 @@ class GPTAttention(Layer):
         # pins the variant it resolved at construction.
         variant = cache.kernel
         if variant is None:
-            variant = resolve_paged_kernel(num_heads=self.num_heads,
-                                           head_dim=self.head_dim,
-                                           block_size=block_size)
+            variant = resolve_paged_kernel(
+                num_heads=self.num_heads, head_dim=self.head_dim,
+                block_size=block_size,
+                kv_dtype=ensure_tensor(cache.k_pools)._value.dtype)
 
         quantized = cache.k_scales is not None
         # the view holds the STACKED pools and this layer's index: the op

@@ -2,9 +2,10 @@
 
 Reference analog: the PHI fused-kernel layer (fluid/operators/fused/) —
 here the fusions target the continuous-batching decode step instead of
-training graphs: blockwise paged decode attention that consumes the
-block-pool KV cache (serving/cache.py) directly, with int8 dequant fused
-into the block loads (quantization/kv_cache.py).
+training graphs: paged decode attention that consumes the block-pool KV
+cache (serving/cache.py) where it lies, a Pallas kernel that copies only
+the pages that hold tokens and a length-bounded pure-JAX loop with int8
+dequant fused into its gathers (quantization/kv_cache.py).
 
 Modules import lazily from the routing layer
 (nn/functional/attention.py) so a CPU-only process never pays the Pallas

@@ -1,32 +1,35 @@
-"""Blockwise paged decode attention over the block-pool KV cache.
+"""Paged decode attention over the block-pool KV cache: streaming softmax
+over the pages that hold tokens, the dense ``[S, T, H, D]`` context of a
+gather by block table (nn/functional/attention.py, the oracle) never made.
 
-The serving decode step used to gather every slot's paged KV history into
-a dense ``[S, T, H, D]`` context per layer (nn/functional/attention.py)
-— the main obstacle between the 0.178 ms/step CPU proxy and the 0.08 ms
-TPU target. This module is the FlashAttention-style fix specialized to
-PagedAttention's memory model: stream the pool's KV blocks through the
-block table with ONLINE (streaming) softmax, fp32 accumulators, one block
-resident at a time — the dense context never exists.
+What the file holds: a Pallas TPU kernel and a pure-JAX loop over ONE pool
+layout, ``[L, num_blocks, bs, H*D]`` (serving/cache.py), read at a layer
+index where it lies (the pool is never reshaped or sliced per layer, so a
+program that donates it updates and reads it in place), each with the
+numpy count of what it reads that the engine keeps, and the same loop over
+a latent pool. `resolve_paged_kernel` (nn/functional/attention.py) chooses
+between the two from platform, cache kind, pool dtype and shape;
+`PERF.md` sections 5 and 6 have their chip readings.
 
-Both read ONE pool layout, ``[L, num_blocks, bs, H*D]`` (serving/cache.py),
-at a layer index, and only ever split GATHERED rows into heads: the pool
-is never reshaped or sliced per layer, so a program that donates it
-updates and reads it where it lies. Two implementations with identical
-semantics:
-
-  * `pallas_paged_attention` — the TPU kernel. Grid ``(S, M)``; the
-    block table and (effective) lengths ride as scalar-prefetch
-    arguments, so each grid cell's BlockSpec index map picks its pool
-    block ``(layer, tables[s, j])`` of the stacked pool directly — the
-    DMA engine walks the page table, the kernel body only ever sees one
-    ``[bs, H*D]`` block in VMEM (a token a sublane, its heads side by
-    side on the lanes) and reduces per head inside it.
-    int8 pools dequantize inside the load (`q * scale / 127`), so the
-    fp values exist only in VMEM. Length masking keeps the null-block
-    branch-free contract: padded/inactive table entries read block 0 and
-    their scores are masked, never branched on: this kernel visits all
-    ``S x M`` entries whatever the lengths. Runs under
-    ``interpret=True`` on CPU for the fused-vs-reference parity tests.
+  * `pallas_paged_attention` — the TPU kernel over a per-head fp pool.
+    A grid step a slot; the pools stay in HBM, block tables, lengths and
+    the layer index ride as scalar prefetch. For a slot it copies ONLY
+    the pages up to the one that holds its newest token (`_slot_pages`;
+    an inactive slot reads one page), `_group_pages` pages a step, K and
+    V each by `make_async_copy` into one of two VMEM buffers, and the
+    copies do not drain at a slot's end: the next slot's first group is
+    in flight while this one's last is multiplied. The rows go to the
+    matrix unit as they lie (``[tokens, H*D]``, heads side by side on
+    the lanes): scores ``[H, tokens]`` are a block-diagonal query
+    ``[H, H*D]`` (head h's query in its own D lanes) contracted with K
+    over the lanes, the output ``[H, H*D]`` is p x V, of which head h
+    keeps its own D lanes: H times the useful multiply-adds, and still
+    bound by its copies. The running max, sum and accumulator of a slot
+    stay on the chip from its first group to its last; only ``[S, H*D]``
+    is written. Positions past a slot's length in its last page, and
+    rows of a buffer no copy reached, are masked. `pallas_copied_pages`
+    is the host's count of the pages it copies, from `_slot_pages` too.
+    Runs under ``interpret=True`` on the CPU for the parity tests.
   * `blockwise_paged_attention` — a pure-JAX loop over block chunks with
     the same online-softmax recurrence, BOUNDED BY THE LENGTHS: the
     loop stops after the chunk that holds the longest slot's newest
@@ -37,19 +40,22 @@ semantics:
     entries a step leaves out are not read at all; inside a step,
     positions past a slot's own length and the entries of an inactive
     slot's one chunk still read what the table names (the null block,
-    once cleared) and are masked. This is the
-    default variant on every platform, the CPU/parity fallback AND a
-    standalone win: it replaces the dense gather's ``[S, T, H, D]``
-    materialization with cache-resident chunks
-    (tests/test_kernel_tier.py reads the traced program for it; its
-    speed is the `serve_124m_backlog` cell's).
+    once cleared) and are masked. What a latent pool, an int8 pool
+    (dequantized inside the chunk gather: `q * scale / 127`), the CPU
+    and a shape off the TPU's tiles run.
     `blockwise_streamed_entries` is the host's count
     of what that loop reads, from the same plan and step widths.
+  * `blockwise_latent_attention` — that loop over a pool whose token is
+    one row every head shares (multi-head latent attention, absorbed).
 
 Numerics: scores, the softmax recurrence, and the output accumulator are
 fp32 regardless of the query/pool dtype; only the final output casts back
-to the query dtype. Masked positions contribute exactly zero probability
-(explicit `where`, not just a large negative score).
+to the query dtype. In the kernel K and V enter the matrix unit in the
+pool's dtype: two bf16 operands are one exact pass (products exact in
+fp32), p goes into the second product as its three bf16 parts (all 24
+bits), and any fp32 operand runs at `Precision.HIGHEST`. Masked positions
+contribute exactly zero probability (explicit `where`, not just a large
+negative score).
 """
 from __future__ import annotations
 
@@ -71,8 +77,8 @@ from .._common import ZERO as _ZERO, on_tpu as _on_tpu
 from ...quantization.kv_cache import QMAX as _QMAX, dequantize as _dequant
 
 __all__ = ["blockwise_paged_attention", "blockwise_latent_attention",
-           "blockwise_streamed_entries",
-           "pallas_paged_attention", "is_eligible"]
+           "blockwise_streamed_entries", "pallas_paged_attention",
+           "pallas_copied_pages", "is_eligible"]
 
 _NEG_INF = -1e30
 
@@ -95,26 +101,30 @@ _CHUNK_TOKENS_MAX = 512
 _MIN_WIDTH_SLOTS = 64
 
 
-# Largest [block_size, H*D] pool block (in elements, the row padded to whole
-# 128-lane tiles) the v5e compiler accepted for every pool dtype: the kernel
-# keeps K and V double-buffered plus their fp32 copies and products in
-# VMEM, and a 2x larger int8 block ran out of it.
-# tests/test_tpu_compile.py compiles both sides of this bound.
-_MAX_BLOCK_ELEMS = 256 * 1024
-
-
-def is_eligible(num_heads, head_dim, block_size):
-    """Can the Pallas kernel run compiled (non-interpret) here?
-    Returns (ok, why) — `why` is the attribution detail for the
-    `kernel.fallback` flight-recorder event when not."""
+def is_eligible(num_heads, head_dim, block_size, kv_dtype=jnp.bfloat16):
+    """Can the Pallas kernel run compiled (non-interpret) here, over a
+    per-head pool of `kv_dtype`? Returns (ok, why) — `why` is the
+    attribution detail for the `kernel.fallback` flight-recorder event
+    when not. The shape's part is what the v5e compiler accepts
+    (tests/test_tpu_compile.py): the kernel multiplies the rows of a page
+    as they lie, so a row is whole 128-lane tiles and a page whole
+    sublane tiles of the pool's dtype, and two groups a side live in VMEM
+    (`_group_pages`)."""
     if not _HAS_PALLAS:
         return False, "no_pallas"
     if not _on_tpu():
         return False, "not_on_tpu"
     if None in (num_heads, head_dim, block_size):
         return False, "shape_unknown"
-    padded = block_size * -(-num_heads * head_dim // 128) * 128
-    if padded > _MAX_BLOCK_ELEMS:
+    kv_dtype = jnp.dtype(kv_dtype)
+    if not jnp.issubdtype(kv_dtype, jnp.floating):
+        return False, "quantized_pool"
+    row = num_heads * head_dim
+    if row % 128:
+        return False, "row_not_whole_lane_tiles"
+    if block_size % _SUBLANE_TILE:
+        return False, "block_not_whole_sublane_tiles"
+    if not _group_pages(1, block_size, row, kv_dtype):
         return False, "block_exceeds_vmem"
     return True, None
 
@@ -362,120 +372,254 @@ def blockwise_latent_attention(q, pool, layer, block_tables, lens,
 
 
 # ---------------------------------------------------------------------------
-# Pallas TPU kernel: one grid cell per (slot, table entry), all heads at once
+# Pallas TPU kernel: a slot a grid step, only its held pages copied
 # ---------------------------------------------------------------------------
 
-def _decode_kernel(tab_ref, lens_ref, q_ref, seg_ref, k_ref, v_ref, *rest,
-                   block_size, quantized):
-    if quantized:
-        ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
-    else:
-        o_ref, acc_ref, m_ref, l_ref = rest
+# The kernel's plan. A group is the pages one step copies and multiplies:
+# this many tokens (16 pages of 16; PERF.md section 6, PR 33, has the chip
+# readings of 4, 8, 12, 16 and 32), fewer where a group of one pool would pass
+# `_GROUP_BYTES_MAX`: two groups a side live in VMEM beside the float32
+# scores and products, and the v5e compiler took a group of 2 MB for
+# every fp dtype and refused one of 4 (tests/test_tpu_compile.py compiles
+# both sides of the line). A page is `_SUBLANE_TILE` rows or a multiple:
+# the copies land on whole tiles of the buffers.
+_GROUP_TOKENS = 256
+_GROUP_BYTES_MAX = 2 * 1024 * 1024
+_SUBLANE_TILE = 8
+
+
+def _group_pages(table_entries, block_size, row, dtype):
+    """Pages a group: `_GROUP_TOKENS` of them, within `_GROUP_BYTES_MAX`
+    a pool and the table; 0 where not even one page fits."""
+    page = int(block_size) * int(row) * jnp.dtype(dtype).itemsize
+    return min(max(1, _GROUP_TOKENS // int(block_size)),
+               _GROUP_BYTES_MAX // page, int(table_entries))
+
+
+def _slot_pages(lens, block_size, table_entries, xp):
+    """The pages the kernel copies for a slot of effective length `lens`:
+    up to the one that holds its newest token (an inactive slot, length
+    0, reads one), never past the table. In `xp`: jax.numpy on the
+    scalars the kernel reads its trip counts from, numpy for the host's
+    count, so the two cannot drift."""
+    return xp.minimum(lens // block_size + 1, table_entries)
+
+
+def pallas_copied_pages(lens, active, table_entries, block_size):
+    """The host's count of one decode step's attention under the Pallas
+    kernel, in table entries summed over the slots: ``(copied, held)``.
+    `copied` is the pages the kernel's copies move for these lengths
+    (`_slot_pages`, the kernel's own rule), `held` the entries that hold
+    a token some slot attends to, as `blockwise_streamed_entries` counts
+    them: the two differ by the one page an inactive slot reads.
+    lens/active: numpy ``[S]``, as the engine keeps them."""
+    active = np.asarray(active, bool)
+    eff = np.where(active, np.asarray(lens, np.int64), 0)
+    pages = _slot_pages(eff, int(block_size), int(table_entries), np)
+    return int(pages.sum()), int(pages[active].sum())
+
+
+def _exact_dot(a, b, contract):
+    """``a . b`` over `contract` with float32 accumulation and nothing of
+    either operand's value lost: two bf16 operands are one pass of the
+    matrix unit (their products are exact in float32), anything else runs
+    as float32 at `Precision.HIGHEST`."""
+    dims = (contract, ((), ()))
+    if a.dtype == b.dtype == jnp.bfloat16:
+        return jax.lax.dot_general(a, b, dims,
+                                   preferred_element_type=jnp.float32)
+    return jax.lax.dot_general(
+        a.astype(jnp.float32), b.astype(jnp.float32), dims,
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
+
+
+def _weigh(p, v):
+    """``p [Hp, T] float32 x v [T, H*D]`` with p's every bit kept. Over
+    bf16 values p goes in as its three bf16 parts (high, middle, low: 24
+    bits of significand, p exactly) stacked on the rows of ONE product,
+    so the values are loaded into the matrix unit once and not once a
+    pass of a float32 product; their sum is what `Precision.HIGHEST`
+    gives."""
+    if v.dtype != jnp.bfloat16:
+        return _exact_dot(p, v, ((1,), (0,)))
+    parts, rest = [], p
+    for _ in range(3):
+        part = rest.astype(jnp.bfloat16)
+        parts.append(part)
+        rest = rest - part.astype(jnp.float32)
+    out = _exact_dot(jnp.concatenate(parts, axis=0), v, ((1,), (0,)))
+    hp = p.shape[0]
+    return out[:hp] + out[hp:2 * hp] + out[2 * hp:]
+
+
+def _ragged_decode_kernel(layer_ref, tab_ref, lens_ref, q_ref, k_hbm, v_hbm,
+                          o_ref, k_buf, v_buf, sems, first_ref, *,
+                          block_size, pages, heads_padded, head_dim, scale):
+    """One slot a grid step, the steps in order. The slot's pages come out
+    of the pools in HBM a group of `pages` at a time, K and V each into
+    one of two VMEM buffers ``[2, pages * bs, H*D]``; while a group is
+    multiplied the next one's copies are in flight, and the next of a
+    slot's LAST group is the first group of the slot after it, so the
+    copies do not drain where a slot ends (`first_ref` carries which
+    buffer that group went to across the grid steps)."""
     s = pl.program_id(0)
-    j = pl.program_id(1)
+    n_slots = pl.num_programs(0)
+    # every constant a 32-bit one: under the framework's x64 mode a Python
+    # number traces as 64 bits, which Mosaic cannot legalize
+    zero, one = np.int32(0), np.int32(1)
+    bs, n_pages = np.int32(block_size), np.int32(pages)
+    m = np.int32(tab_ref.shape[1])
+    hp, hd = heads_padded, k_buf.shape[-1]
+    t_group = n_pages * bs
+    nothing = np.float32(0.0)
+    layer = layer_ref[0]
 
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def held(slot):
+        return _slot_pages(lens_ref[slot], bs, m, jnp)
 
-    # A pool block is [bs, H*D]: a token a sublane, its heads side by side
-    # on the lanes. `seg` [H*D, H] is 1 where a lane belongs to a head, so
-    # two small matrix products (exact: float32 contraction, and a lane
-    # belongs to one head) take a row to per-head values and back; the
-    # softmax state lives on the lanes, every head's value repeated over
-    # its D lanes, so nothing below reshapes or relayouts.
-    seg = seg_ref[...]
-    exact = dict(precision=jax.lax.Precision.HIGHEST,
-                 preferred_element_type=jnp.float32)
+    def each_copy(slot, group, buf, act):
+        """`act` on the copy of every page of the slot's `group` that
+        holds a token: never one past the slot's length."""
+        first = group * n_pages
 
-    def per_head(x):                                   # [bs, H*D] -> [bs, H]
-        return jnp.dot(x, seg, **exact)
+        def page(i, carry=None):
+            if isinstance(i, int):                      # a static page
+                block = tab_ref[slot, first + np.int32(i)]
+                rows = pl.ds(i * block_size, block_size)
+            else:
+                block = tab_ref[slot, first + i]
+                rows = pl.ds(pl.multiple_of(i * bs, block_size), block_size)
+            for side, pool, vmem in ((zero, k_hbm, k_buf),
+                                     (one, v_hbm, v_buf)):
+                act(pltpu.make_async_copy(pool.at[layer, block],
+                                          vmem.at[buf, rows],
+                                          sems.at[side, buf]))
+            return carry
 
-    def per_lane(x):                                   # [bs, H] -> [bs, H*D]
-        return jax.lax.dot_general(x, seg, (((1,), (1,)), ((), ())), **exact)
+        count = jnp.minimum(held(slot) - first, n_pages)
 
-    k = k_ref[...].astype(jnp.float32)                 # [bs, H*D]
-    v = v_ref[...].astype(jnp.float32)
-    if quantized:
-        # dequant fused into the block load: fp K/V exist only in VMEM
-        heads = (k.shape[0], seg.shape[1])
-        k = k * per_lane(jnp.broadcast_to(ks_ref[...] * (1.0 / _QMAX), heads))
-        v = v * per_lane(jnp.broadcast_to(vs_ref[...] * (1.0 / _QMAX), heads))
-    scores = per_lane(per_head(k * q_ref[...]))        # q is pre-scaled
-    pos = j * jnp.int32(block_size) + jax.lax.broadcasted_iota(
-        jnp.int32, scores.shape, 0)
-    valid = pos <= lens_ref[s]
-    scores = jnp.where(valid, scores, jnp.float32(_NEG_INF))
-    m_prev = m_ref[...]                                # [1, H*D]
-    m_new = jnp.maximum(m_prev, jnp.max(scores, axis=0, keepdims=True))
-    p = jnp.where(valid, jnp.exp(scores - m_new), 0.0)
-    alpha = jnp.exp(m_prev - m_new)
-    l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=0, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha \
-        + jnp.sum(p * v, axis=0, keepdims=True)
-    m_ref[...] = m_new
+        # a whole group needs no trip count and every offset is a
+        # constant (PERF.md section 6, PR 33: 6-24% of a call's time)
+        @pl.when(count == n_pages)
+        def _whole():
+            for i in range(pages):
+                page(i)
 
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _flush():
-        o_ref[...] = (acc_ref[...]
-                      / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+        @pl.when(count < n_pages)
+        def _part():
+            jax.lax.fori_loop(zero, count, page, zero)
+
+    def start(slot, group, buf):
+        each_copy(slot, group, buf, lambda copy: copy.start())
+
+    def wait(slot, group, buf):
+        each_copy(slot, group, buf, lambda copy: copy.wait())
+
+    @pl.when(s == 0)
+    def _first_slot():
+        # rows a copy never reaches are multiplied by p == 0: they must
+        # be numbers, which fresh VMEM need not hold
+        k_buf[...] = jnp.zeros_like(k_buf)
+        v_buf[...] = jnp.zeros_like(v_buf)
+        first_ref[0] = zero
+        start(zero, zero, zero)
+
+    base = first_ref[0]
+    length = lens_ref[s]
+    groups = (held(s) + (n_pages - one)) // n_pages
+
+    # head h's query in its own D lanes of row h, zeros elsewhere: the
+    # rows of a page go to the matrix unit as they lie, all heads at once
+    row = jax.lax.broadcasted_iota(jnp.int32, (hp, hd), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (hp, hd), 1)
+    own = ((lane >= row * np.int32(head_dim))
+           & (lane < (row + one) * np.int32(head_dim)))
+    # (selected as float32: the mask's layout is a 32-bit one)
+    q = jnp.where(own, q_ref[...].astype(jnp.float32),
+                  nothing).astype(q_ref.dtype)
+    offs = jax.lax.broadcasted_iota(jnp.int32, (hp, t_group), 1)
+
+    def group(g, carry):
+        mx, l, acc = carry
+        cur = (base + g) & one
+        more = g + one < groups
+        nxt_slot = jnp.where(more, s, s + one)
+
+        @pl.when(nxt_slot < n_slots)
+        def _prefetch():
+            start(nxt_slot, jnp.where(more, g + one, zero), one - cur)
+
+        wait(s, g, cur)
+        k = k_buf[cur]                                  # [T, H*D]
+        v = v_buf[cur]
+        scores = _exact_dot(q, k, ((1,), (1,))) * np.float32(scale)
+        valid = g * t_group + offs <= length            # [Hp, T]
+        scores = jnp.where(valid, scores, np.float32(_NEG_INF))
+        m_new = jnp.maximum(mx, jnp.max(scores, axis=1, keepdims=True))
+        p = jnp.where(valid, jnp.exp(scores - m_new), nothing)
+        alpha = jnp.exp(mx - m_new)
+        l = alpha * l + jnp.sum(p, axis=1, keepdims=True)
+        acc = acc * alpha + _weigh(p, v)                # [Hp, H*D]
+        return m_new, l, acc
+
+    _, l, acc = jax.lax.fori_loop(
+        zero, groups, group,
+        (jnp.full((hp, 1), np.float32(_NEG_INF), jnp.float32),
+         jnp.zeros((hp, 1), jnp.float32),
+         jnp.zeros((hp, hd), jnp.float32)))
+    first_ref[0] = (base + groups) & one
+    # head h keeps its own D lanes of row h
+    out = jnp.where(own, acc / jnp.maximum(l, np.float32(1e-30)), nothing)
+    o_ref[...] = jnp.sum(out, axis=0, keepdims=True).astype(o_ref.dtype)
 
 
+@functools.partial(jax.jit, static_argnames=("block_size", "interpret",
+                                             "group_pages"))
 def pallas_paged_attention(q, k_pools, v_pools, layer, block_tables, lens,
-                           block_size, k_scales=None, v_scales=None,
-                           interpret=False):
-    """The Pallas kernel: same contract as `blockwise_paged_attention`.
-    `interpret=True` runs the kernel through the Pallas interpreter on
-    any backend (the CPU parity path)."""
+                           block_size, interpret=False, group_pages=None):
+    """The Pallas kernel: `blockwise_paged_attention`'s contract over fp
+    pools (int8 pools resolve to that loop: `resolve_paged_kernel`).
+    The pools stay in HBM where they lie; block tables, lengths and the
+    layer index ride as scalar prefetch, and the kernel copies only
+    `_slot_pages` pages of a slot, `group_pages` a step (default: the
+    plan's, `_group_pages`). `interpret=True` runs it through the Pallas
+    interpreter on any backend (the CPU parity path).
+
+    The layer is an OPERAND and the function is jitted, so a program
+    traces and lowers ONE kernel for all its layers' calls: traced anew
+    for each of twelve layers the body cost the backlog cell 21 s of
+    set-up (PERF.md section 6, PR 33)."""
     s, h, d = q.shape
     hd = h * d
     bs = int(block_size)
     m = block_tables.shape[1]
-    quant = k_scales is not None
+    pages = int(group_pages or _group_pages(m, bs, hd, k_pools.dtype))
+    hp = -(-h // 16) * 16                   # whole tiles of rows, bf16's
     zero = _ZERO
-    layer = np.int32(layer)      # index maps emit i32 (kernels/_common.py)
-    qf = (q.astype(jnp.float32) * (1.0 / math.sqrt(d))).reshape(s, 1, hd)
-    seg = (jnp.arange(hd, dtype=jnp.int32)[:, None] // d
-           == jnp.arange(h, dtype=jnp.int32)[None, :]).astype(jnp.float32)
-    tables = block_tables.astype(jnp.int32)
-    lens32 = lens.astype(jnp.int32)
 
-    # The pool is read where it lies: a block is one [bs, H*D] row group
-    # of the STACKED pool, picked by (layer, table entry) in the index map
-    # — the block table IS the page table the DMA walks. Index maps
-    # receive (grid ids..., scalar-prefetch refs). Every block's last two
-    # dimensions equal its array's, the shape the TPU lowering accepts
-    # whatever H and D are.
-    pool_spec = pl.BlockSpec(
-        (None, None, bs, hd),
-        lambda si, j, t, l: (layer, t[si, j], zero, zero))
     slot_spec = pl.BlockSpec((None, 1, hd),
-                             lambda si, j, t, l: (si, zero, zero))
-    seg_spec = pl.BlockSpec((hd, h), lambda si, j, t, l: (zero, zero))
-    in_specs = [slot_spec, seg_spec, pool_spec, pool_spec]
-    args = [tables, lens32, qf, seg, k_pools, v_pools]
-    if quant:
-        spec = pl.BlockSpec(
-            (None, None, 1, h),
-            lambda si, j, t, l: (layer, t[si, j], zero, zero))
-        in_specs += [spec, spec]
-        args += [k_scales[:, :, None], v_scales[:, :, None]]
-
+                             lambda si, *_: (si, zero, zero))
+    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
+    buf = pltpu.VMEM((2, pages * bs, hd), k_pools.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(s, m),
-        in_specs=in_specs,
+        num_scalar_prefetch=3,
+        grid=(s,),
+        in_specs=[slot_spec, pool_spec, pool_spec],
         out_specs=slot_spec,
-        scratch_shapes=[pltpu.VMEM((1, hd), jnp.float32)] * 3)
-    kernel = functools.partial(_decode_kernel, block_size=bs,
-                               quantized=quant)
+        scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2, 2)),
+                        pltpu.SMEM((1,), jnp.int32)])
+    kernel = functools.partial(
+        _ragged_decode_kernel, block_size=bs, pages=pages, heads_padded=hp,
+        head_dim=d, scale=1.0 / math.sqrt(d))
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((s, 1, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-        name="paged_decode_attention")(*args)
+        name="paged_decode_attention")(
+            jnp.asarray(layer, jnp.int32).reshape(1),
+            block_tables.astype(jnp.int32), lens.astype(jnp.int32),
+            q.reshape(s, 1, hd), k_pools, v_pools)
     return out.reshape(s, h, d)

@@ -203,28 +203,44 @@ PAGED_KERNELS = ("pallas", "blockwise", "reference")
 
 
 def resolve_paged_kernel(kernel=None, num_heads=None, head_dim=None,
-                         block_size=None, interpret=False):
-    """Resolve the serving attention variant: the request (explicit
-    `kernel` or FLAGS_serve_attention_kernel) -> the variant that will
-    actually run. An ineligible request falls back to `blockwise` (same
-    math, no Mosaic constraints) and is VISIBLE: a `kernel.fallback`
-    flight-recorder event attributes the demotion, never silent."""
+                         block_size=None, interpret=False, kv_dtype=None,
+                         cache_kind="kv"):
+    """Resolve the serving attention variant -> the one that will run.
+
+    With NO request (no `kernel`, FLAGS_serve_attention_kernel unset) the
+    choice follows what can be observed here: `pallas` on a TPU over a
+    per-head (`cache_kind` ``"kv"``) fp pool of `kv_dtype` whose shape the
+    kernel takes (`paged_attention.is_eligible`), `blockwise` for anything
+    else: a latent pool, an int8 pool, another platform, a row or a block
+    off the tiles. An explicit request that cannot run falls back to
+    `blockwise` (same math, no Mosaic constraints) and is VISIBLE: a
+    `kernel.fallback` flight-recorder event attributes the demotion,
+    never silent. `interpret` (the CPU parity path) lifts the platform's
+    and the shape's conditions from an explicit `pallas`, not the pool's:
+    the kernel reads fp rows."""
     from ...framework.flags import _FLAGS
     from ...profiler.events import EVENTS as _EVENTS
-    req = kernel or str(_FLAGS.get("FLAGS_serve_attention_kernel")
-                        or "blockwise")
+    from ...kernels.pallas import paged_attention as _pk
+    kv_dtype = jnp.dtype(jnp.bfloat16 if kv_dtype is None else kv_dtype)
+    req = kernel or str(_FLAGS.get("FLAGS_serve_attention_kernel") or "")
+    if not req:
+        ok = cache_kind == "kv" and _pk.is_eligible(
+            num_heads, head_dim, block_size, kv_dtype)[0]
+        return "pallas" if ok else "blockwise"
     if req not in PAGED_KERNELS:
         raise ValueError(
             f"unknown paged attention kernel {req!r}; expected one of "
             f"{PAGED_KERNELS}")
     actual, why = req, None
     if req == "pallas":
-        from ...kernels.pallas import paged_attention as _pk
         if not _pk._HAS_PALLAS:
             # interpret mode still needs the pallas import itself
             actual, why = "blockwise", "no_pallas"
+        elif not jnp.issubdtype(kv_dtype, jnp.floating):
+            actual, why = "blockwise", "quantized_pool"
         elif not interpret:
-            ok, why = _pk.is_eligible(num_heads, head_dim, block_size)
+            ok, why = _pk.is_eligible(num_heads, head_dim, block_size,
+                                      kv_dtype)
             if not ok:
                 actual = "blockwise"
     if actual != req:
@@ -233,7 +249,8 @@ def resolve_paged_kernel(kernel=None, num_heads=None, head_dim=None,
                      detail={"requested": req, "actual": actual,
                              "why": why, "num_heads": num_heads,
                              "head_dim": head_dim,
-                             "block_size": block_size})
+                             "block_size": block_size,
+                             "kv_dtype": kv_dtype.name})
     return actual
 
 
@@ -294,9 +311,10 @@ def paged_decode_attention(q, k_new, v_new, k_pools, v_pools, layer,
     them).
 
     `kernel` selects the attention implementation (`pallas` |
-    `blockwise` | `reference`, default FLAGS_serve_attention_kernel);
-    every variant shares the SAME write path, masking, and fp32 softmax
-    numerics — only the schedule differs. Pure jnp and shape-static: ONE
+    `blockwise` | `reference`; default FLAGS_serve_attention_kernel,
+    else what `resolve_paged_kernel` chooses from platform, pool and
+    shape); every variant shares the SAME write path, masking, and fp32
+    softmax numerics — only the schedule differs. Shape-static: ONE
     compiled program serves every token of every tenant mix —
     join/leave/evict is a table edit, never a retrace.
 
@@ -332,7 +350,8 @@ def paged_decode_attention(q, k_new, v_new, k_pools, v_pools, layer,
                 v_new[:, 0].reshape(s, -1).astype(v_pools.dtype))
 
     variant = resolve_paged_kernel(kernel, num_heads, head_dim, block_size,
-                                   interpret=interpret)
+                                   interpret=interpret,
+                                   kv_dtype=k_pools.dtype)
     qh = q[:, 0]                                       # [S, H, D]
     with jax.named_scope("paged_attention"):
         if variant == "reference":
@@ -350,7 +369,7 @@ def paged_decode_attention(q, k_new, v_new, k_pools, v_pools, layer,
                 pallas_paged_attention)
             out = pallas_paged_attention(
                 qh, k_pools, v_pools, layer, block_tables, lens, block_size,
-                k_scales, v_scales, interpret=interpret)
+                interpret=interpret)
     if quantized:
         return out[:, None], k_pools, v_pools, k_scales, v_scales
     return out[:, None], k_pools, v_pools

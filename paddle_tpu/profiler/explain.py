@@ -265,10 +265,12 @@ REASON_HINTS = {
         "the requested paged-attention kernel variant "
         "(FLAGS_serve_attention_kernel) was ineligible here and the call "
         "fell back to the blockwise path — see the event's `why` detail "
-        "(no_pallas / not_on_tpu / shape_unknown / "
+        "(no_pallas / not_on_tpu / shape_unknown / quantized_pool / "
+        "row_not_whole_lane_tiles / block_not_whole_sublane_tiles / "
         "block_exceeds_vmem). Same math, no silent wrong-kernel "
-        "serving; shrink block_size or request 'blockwise' "
-        "explicitly to quiet the event."),
+        "serving; leave the variant unset (the engine then chooses "
+        "what can run) or request 'blockwise' explicitly to quiet "
+        "the event."),
     "kv_quantized": (
         "the serving engine's KV cache pool runs int8 with "
         "per-block-per-head scales (quantization/kv_cache.py): half the "

@@ -33,10 +33,11 @@ Write paths:
     exact (the stored int8 levels re-quantize to themselves), so error
     only accrues on the rare amax-raising writes.
 
-Dequantization (`value = int8 * scale / 127`) is fused into the attention
-kernels' block loads (kernels/pallas/paged_attention.py) — the fp values
-exist only inside the kernel's VMEM tile (or the scan body's chunk), never
-as a materialized pool.
+Dequantization (`value = int8 * scale / 127`) is fused into the blockwise
+attention loop's chunk gathers (kernels/pallas/paged_attention.py): the
+fp values exist only inside the loop body's chunk, never as a
+materialized pool. (The Pallas kernel reads fp pools; an int8 pool
+resolves to the loop.)
 
 Everything here is shape-static pure jnp: the compiled decode/prefill
 programs stay ONE executable per engine, int8 or not.

@@ -34,8 +34,9 @@ and, with enough slots, leaves the shorter half of them out of the
 chunks only longer ones reach, so those entries are never gathered;
 entries it does read that lie past a slot's own length, and the one
 chunk an inactive slot is read through, still come from the table (the
-null block for padding) and are masked. The Pallas kernel and the dense
-reference read every entry and mask.
+null block for padding) and are masked. The Pallas kernel copies only
+the pages up to each slot's newest token (one page for an inactive
+slot); the dense reference reads every entry and masks.
 
 The device side of the design lives in
 `nn/functional/attention.py::paged_decode_attention` (gather-by-block-table

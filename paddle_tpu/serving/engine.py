@@ -34,9 +34,11 @@ millions of users"), combining:
     token's decode launch, the loop being pipelined at lag 1 (see
     `LLMEngine`);
   * a **kernel tier** (PR 11): the decode step's paged attention runs
-    blockwise streaming softmax over the block table
-    (kernels/pallas/paged_attention.py — Pallas on TPU, a `lax.scan`
-    twin elsewhere; `attention_kernel=` / FLAGS_serve_attention_kernel)
+    streaming softmax over the pages that hold tokens
+    (kernels/pallas/paged_attention.py: a Pallas kernel on a TPU over a
+    per-head fp pool, a length-bounded pure-JAX loop elsewhere, chosen by
+    `resolve_paged_kernel`; `attention_kernel=` /
+    FLAGS_serve_attention_kernel name one explicitly)
     instead of gathering a dense `[S, T, H, D]` context, and
     `kv_dtype="int8"` halves KV bytes per token via per-block-per-head
     scales (quantization/kv_cache.py) so the same pool admits ~2x the
@@ -117,7 +119,8 @@ from ..profiler.metrics import LogHistogram, SERVE as _M, \
 from ..profiler import goodput as _goodput
 from ..profiler import telemetry_server as _telemetry
 from ..profiler import sentinel as _sentinel
-from ..kernels.pallas.paged_attention import blockwise_streamed_entries
+from ..kernels.pallas.paged_attention import (blockwise_streamed_entries,
+                                               pallas_copied_pages)
 from .cache import PagedKVCache, PagedCacheView, scatter_prefill, _is_int8
 from .scheduler import (Request, Scheduler, QUEUED, RUNNING, FINISHED,
                         FAILED, CANCELLED, EXPIRED)
@@ -210,9 +213,10 @@ class ServeStats:
         self.launches = 0
         self.launches_overlapped = 0
         # decode attention, in block-table entries summed over the decode
-        # launches: what the attention's loops read, what held a token,
-        # and slots x entries (kernels/pallas/paged_attention.py
-        # blockwise_streamed_entries, counted on the host every launch)
+        # launches: what the attention's loop reads or its kernel copies,
+        # what held a token, and slots x entries (kernels/pallas/
+        # paged_attention.py blockwise_streamed_entries /
+        # pallas_copied_pages, counted on the host every launch)
         self.attn_entries_streamed = 0
         self.attn_entries_held = 0
         self.attn_entries_total = 0
@@ -440,7 +444,7 @@ class LLMEngine:
             # what a latent pool does not do yet is refused by name, here:
             # none of it may run silently wrong
             asked = attention_kernel or str(
-                _FLAGS.get("FLAGS_serve_attention_kernel") or "blockwise")
+                _FLAGS.get("FLAGS_serve_attention_kernel") or "")
             for option, on in (
                     ("kv_dtype='int8'", self._kv_quantized),
                     ("attention_kernel='pallas'", asked == "pallas"),
@@ -452,7 +456,8 @@ class LLMEngine:
                         f"({type(model).__name__}.cache_spec())")
         self._attn_kernel = resolve_paged_kernel(
             attention_kernel, num_heads=spec.num_heads,
-            head_dim=spec.head_dim, block_size=self.block_size)
+            head_dim=spec.head_dim, block_size=self.block_size,
+            kv_dtype=self._kv_dtype, cache_kind=spec.kind)
         if self._kv_quantized:
             _EVENTS.emit("kernel.quantized", "serve.decode",
                          reason="kv_quantized",
@@ -1743,16 +1748,22 @@ class LLMEngine:
 
     def _count_attention(self, lens, active):
         """One decode launch's attention in block-table entries, from
-        the lengths and the mask the launch is given. Only the blockwise
-        loop follows the lengths; the other variants read every entry."""
-        streamed, held = blockwise_streamed_entries(
-            lens, active, self.max_blocks_per_seq, self.block_size,
-            self.cache.num_heads, self.cache.head_dim,
-            **self.cache.spec.loop_plan(self.block_size))
+        the lengths and the mask the launch is given: what the blockwise
+        loop streams or the Pallas kernel copies, each counted by the
+        plan its program traces; the dense oracle reads every entry."""
         total = self.max_batch_size * self.max_blocks_per_seq
+        if self._attn_kernel == "pallas":
+            streamed, held = pallas_copied_pages(
+                lens, active, self.max_blocks_per_seq, self.block_size)
+        else:
+            streamed, held = blockwise_streamed_entries(
+                lens, active, self.max_blocks_per_seq, self.block_size,
+                self.cache.num_heads, self.cache.head_dim,
+                **self.cache.spec.loop_plan(self.block_size))
+            if self._attn_kernel != "blockwise":
+                streamed = total
         stats = self._stats
-        stats.attn_entries_streamed += (
-            streamed if self._attn_kernel == "blockwise" else total)
+        stats.attn_entries_streamed += streamed
         stats.attn_entries_held += held
         stats.attn_entries_total += total
 

@@ -24,10 +24,10 @@ from paddle_tpu.framework.flags import get_flags, set_flags  # noqa: E402
 from paddle_tpu.incubate.models import GPTConfig  # noqa: E402
 
 SEQ = 128
-# one 64-wide head per layer keeps head_dim at 64, which both the flash
-# kernel and the paged kernel take
-TINY = GPTConfig(vocab_size=512, hidden_size=64, num_hidden_layers=2,
-                 num_attention_heads=1, intermediate_size=128,
+# two 64-wide heads per layer: head_dim 64, which the flash kernel takes,
+# and a row of 128, one whole lane tile, which the paged kernel takes
+TINY = GPTConfig(vocab_size=512, hidden_size=128, num_hidden_layers=2,
+                 num_attention_heads=2, intermediate_size=128,
                  max_position_embeddings=SEQ, hidden_dropout_prob=0.0,
                  attention_probs_dropout_prob=0.0)
 

@@ -693,6 +693,170 @@ class TestLengthBoundedLoop:
 
 
 # ---------------------------------------------------------------------------
+# the Pallas kernel (ISSUE 33): only the pages that hold tokens are copied
+# ---------------------------------------------------------------------------
+
+RAGGED_BS, RAGGED_M = 16, 20        # the plan's groups of 16 pages: two a slot
+
+
+def _ragged_case(heads, head_dim, dtype, seed=0):
+    """One batch of ragged lengths at a real block size: an inactive slot
+    (effective length 0, its table cleared to the null block), the last
+    position of a page and of a group, the first of the next, one slot at
+    `max_context`; every table padded with the null block past the pages
+    its slot holds."""
+    lens = np.asarray([0, RAGGED_BS - 1, RAGGED_BS, 8 * RAGGED_BS - 1,
+                       8 * RAGGED_BS, 201, 16 * RAGGED_BS - 1,
+                       16 * RAGGED_BS, RAGGED_M * RAGGED_BS - 1], np.int32)
+    S = len(lens)
+    rng = np.random.default_rng(seed)
+    nb = 1 + S * RAGGED_M
+    mk = lambda sh: jnp.asarray(
+        rng.standard_normal(sh).astype(np.float32)).astype(dtype)
+    q = mk((S, heads, head_dim))
+    kp = mk((LAYERS, nb, RAGGED_BS, heads * head_dim))
+    vp = mk((LAYERS, nb, RAGGED_BS, heads * head_dim))
+    pages = lens // RAGGED_BS + 1
+    tables = np.zeros((S, RAGGED_M), np.int32)
+    ids = rng.permutation(np.arange(1, nb))     # a churned allocator's
+    for s in range(1, S):
+        tables[s, :pages[s]] = ids[s * RAGGED_M:s * RAGGED_M + pages[s]]
+    return q, kp, vp, jnp.asarray(tables), jnp.asarray(lens)
+
+
+class TestRaggedPallasKernel:
+    @pytest.mark.parametrize("dtype", ("float32", "bfloat16"))
+    @pytest.mark.parametrize("heads,head_dim", ((12, 64), (16, 128)),
+                             ids=("12x64", "16x128"))
+    def test_ragged_lengths_match_the_dense_oracle(self, heads, head_dim,
+                                                   dtype):
+        """Every slot of a ragged batch, the inactive one included, reads
+        what the dense gather reads, in float32 and bf16 pools, at both
+        models' head shapes and at every number of pages a group."""
+        dtype = jnp.dtype(dtype)
+        q, kp, vp, tables, lens = _ragged_case(heads, head_dim, dtype)
+        oracle = np.asarray(_dense_gather_attention(
+            q, kp, vp, LAYER, tables, lens, RAGGED_BS), np.float32)
+        tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+        for pages in (None, 8, 3, 1):
+            got = paged_attention.pallas_paged_attention(
+                q, kp, vp, LAYER, tables, lens, RAGGED_BS, interpret=True,
+                group_pages=pages)
+            assert got.dtype == q.dtype and got.shape == q.shape
+            np.testing.assert_allclose(np.asarray(got, np.float32), oracle,
+                                       rtol=0.0, atol=tol,
+                                       err_msg=f"group_pages={pages}")
+
+    @pytest.mark.parametrize("dtype", ("float32", "bfloat16"))
+    def test_pages_that_hold_no_attended_token_are_never_copied(self,
+                                                                dtype):
+        """NaN in every page that holds no token some slot attends to:
+        the pages a table names past its slot's length, every block no
+        table names, and all of the other layers. The kernel is bounded
+        by the lengths and not only masked (a copied NaN would reach the
+        output through a zero probability), so its output is bitwise the
+        clean pools'."""
+        dtype = jnp.dtype(dtype)
+        q, kp, vp, tables, lens = _ragged_case(12, 64, dtype, seed=3)
+        # the tables name blocks past their slots' lengths too
+        full = np.asarray(tables).copy()
+        spare = iter(np.setdiff1d(np.arange(1, kp.shape[1]), full))
+        pages = np.asarray(lens) // RAGGED_BS + 1
+        for s in range(1, full.shape[0]):
+            for j in range(pages[s], RAGGED_M):
+                full[s, j] = next(spare)
+        attended = np.zeros(kp.shape[1], bool)
+        for s in range(full.shape[0]):
+            attended[full[s, :pages[s]]] = True     # slot 0: the null block
+        poison = np.ones(kp.shape[:2], bool)
+        poison[LAYER] = ~attended
+        assert poison[LAYER].sum() > kp.shape[1] // 2
+        bad = lambda pool: jnp.where(jnp.asarray(poison)[:, :, None, None],
+                                     jnp.nan, pool).astype(pool.dtype)
+        run = lambda k, v: np.asarray(paged_attention.pallas_paged_attention(
+            q, k, v, LAYER, jnp.asarray(full), lens, RAGGED_BS,
+            interpret=True), np.float32)
+        got = run(bad(kp), bad(vp))
+        assert np.isfinite(got).all()
+        assert np.array_equal(got, run(kp, vp))
+        oracle = _dense_gather_attention(q, kp, vp, LAYER, tables, lens,
+                                         RAGGED_BS)
+        np.testing.assert_allclose(
+            got, np.asarray(oracle, np.float32), rtol=0.0,
+            atol=2e-2 if dtype == jnp.bfloat16 else 2e-5)
+
+    @pytest.mark.parametrize("slots", (1, 8, 128))
+    def test_host_count_is_the_pages_the_plan_copies(self, slots):
+        """`pallas_copied_pages` (numpy, the engine's counter) and the
+        kernel (jax.numpy on the scalars it prefetches) share
+        `_slot_pages`: for the same lengths the host counts the pages the
+        kernel's trip counts add up to: the held pages, and one for each
+        inactive slot; a full batch copies exactly what it holds."""
+        M, bs = 64, 16
+        on_device = jax.jit(lambda lens: paged_attention._slot_pages(
+            lens, np.int32(bs), np.int32(M), jnp))
+        rng = np.random.default_rng(slots)
+        for draw in range(5):
+            lens = rng.integers(0, M * bs, slots).astype(np.int32)
+            lens //= rng.integers(1, 6, slots).astype(np.int32)
+            active = rng.random(slots) < (0.0, 0.5, 0.9, 1, 1)[draw]
+            if draw == 4:
+                lens[:] = M * bs - 1
+            eff = np.where(active, lens, 0).astype(np.int32)
+            copied, held = paged_attention.pallas_copied_pages(
+                lens, active, M, bs)
+            assert copied == int(np.asarray(on_device(jnp.asarray(eff))).sum())
+            assert held == blockwise_streamed_entries(
+                lens, active, M, bs, 12, 64)[1]
+            assert copied == held + int((~active).sum())
+            if active.all():
+                assert copied == held <= slots * M
+            if draw == 4:
+                assert copied == slots * M
+
+    def test_engine_serves_through_the_kernel_it_chooses(self, monkeypatch):
+        """On a TPU (here: told so, the kernel in the interpreter) an
+        engine that is asked for no variant chooses `pallas` for a
+        per-head fp pool of whole tiles, serves `model.generate`'s tokens
+        through it, and counts the pages it copies: the held share, plus
+        a page for every inactive slot of a launch."""
+        paddle.seed(0)
+        cfg = GPTConfig(vocab_size=VOCAB, hidden_size=128,
+                        num_hidden_layers=2, num_attention_heads=2,
+                        intermediate_size=128, max_position_embeddings=256,
+                        hidden_dropout_prob=0.0,
+                        attention_probs_dropout_prob=0.0,
+                        use_flash_attention=False)
+        wide = GPTForCausalLM(cfg)
+        wide.eval()
+        prompts = [_prompt(n, seed=33) for n in (5, 30, 17, 21)]
+        expect = LLMEngine(wide, max_batch_size=4, block_size=8,
+                           attention_kernel="reference").generate(
+                               prompts, max_new_tokens=5)
+        monkeypatch.setattr(paged_attention, "_on_tpu", lambda: True)
+        kernel = paged_attention.pallas_paged_attention
+        monkeypatch.setattr(
+            paged_attention, "pallas_paged_attention",
+            lambda *args, interpret=False, **kw: kernel(
+                *args, interpret=True, **kw))
+        engine = LLMEngine(wide, max_batch_size=4, block_size=8)
+        assert engine.stats()["attention_kernel"] == "pallas"
+        assert engine.generate(prompts, max_new_tokens=5) == expect
+        st, raw = engine.stats(), engine._stats
+        assert st["decode_compiles"] == 1
+        assert 0.0 < st["attn_held_share"] <= st["attn_streamed_share"] < 0.5
+        idle = raw.launches * 4 - raw.decode_tokens
+        assert raw.attn_entries_streamed == raw.attn_entries_held + idle
+        # a row off the lane tiles, an int8 pool: the loop, and no event
+        # (nothing was asked for)
+        clear_fusion_events()
+        assert LLMEngine(wide, max_batch_size=4, block_size=8,
+                         kv_dtype="int8").stats()["attention_kernel"] \
+            == "blockwise"
+        assert fusion_events("kernel.fallback") == []
+
+
+# ---------------------------------------------------------------------------
 # keying: dispatch cache, AOT fingerprint, fallback attribution
 # ---------------------------------------------------------------------------
 

@@ -104,50 +104,160 @@ def test_fused_cross_entropy_fwd_bwd(v5e):
 SLOTS, BLOCK, TABLE, POOL, LAYERS = 8, 16, 64, 513, 2
 
 
-def paged_shapes(heads, head_dim, block, pool_dtype):
-    pool = ((LAYERS, POOL, block, heads * head_dim), pool_dtype)
-    shapes = [((SLOTS, heads, head_dim), jnp.bfloat16), pool, pool,
-              ((SLOTS, TABLE), jnp.int32), ((SLOTS,), jnp.int32)]
+def paged_shapes(heads, head_dim, block, pool_dtype, slots=SLOTS,
+                 table=TABLE, pool=(LAYERS, POOL)):
+    pool = (pool + (block, heads * head_dim), pool_dtype)
+    shapes = [((slots, heads, head_dim), jnp.bfloat16), pool, pool,
+              ((slots, table), jnp.int32), ((slots,), jnp.int32)]
     if pool_dtype == jnp.int8:
         shapes += [((LAYERS, POOL, heads), jnp.float32)] * 2
     return shapes
 
 
+def paged_kernel(block, group_pages=None):
+    return lambda q, k, v, tables, lens: \
+        paged_attention.pallas_paged_attention(q, k, v, 1, tables, lens,
+                                               block,
+                                               group_pages=group_pages)
+
+
 @pytest.mark.parametrize("pool_dtype", [jnp.bfloat16, jnp.int8],
                          ids=["bf16", "int8"])
 @pytest.mark.parametrize("heads", [12, 16], ids=["124m", "355m"])
-def test_paged_decode_attention(v5e, heads, pool_dtype):
-    def decode(q, k, v, tables, lens, *scales):
-        return paged_attention.pallas_paged_attention(
-            q, k, v, 1, tables, lens, BLOCK, *scales)
+def test_paged_decode_attention(v5e, monkeypatch, heads, pool_dtype):
+    """The serve legs of `chip_smoke.py` ask for `pallas` by name: over
+    the bf16 pool the kernel compiles; over an int8 pool the request
+    falls back to the loop, attributed, and that compiles."""
+    if pool_dtype == jnp.bfloat16:
+        compile_for(v5e, paged_kernel(BLOCK),
+                    *paged_shapes(heads, 64, BLOCK, pool_dtype))
+        return
+    from paddle_tpu.framework.flags import get_flags, set_flags
+    from paddle_tpu.profiler.events import (clear_fusion_events,
+                                            fusion_events)
+    monkeypatch.setattr(paged_attention, "_on_tpu", lambda: True)
 
-    compile_for(v5e, decode, *paged_shapes(heads, 64, BLOCK, pool_dtype))
+    def decode(q, k, v, tables, lens, k_scales, v_scales):
+        token = q[:, None]
+        return paged_decode_attention(
+            token, token, token, k, v, 1, tables, lens, lens > 0, BLOCK,
+            k_scales=k_scales, v_scales=v_scales, kernel="pallas")
+
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=v5e) for shape,
+            dtype in paged_shapes(heads, 64, BLOCK, pool_dtype)]
+    prev = get_flags(["FLAGS_profiler_events"])
+    set_flags({"FLAGS_profiler_events": True})
+    clear_fusion_events()
+    try:
+        text = jax.jit(decode).lower(*args).compile().as_text()
+    finally:
+        set_flags(prev)
+    assert "tpu_custom_call" not in text
+    (event,) = fusion_events("kernel.fallback")
+    assert event["detail"]["why"] == "quantized_pool"
+    assert event["detail"]["actual"] == "blockwise"
+
+
+@pytest.mark.parametrize("config", ["gpt2_124m", "gpt3_1p3b"])
+def test_paged_kernel_at_the_cells_geometry(v5e, config):
+    """`serve_124m_backlog`'s decode attention as the engine traces it:
+    128 slots, 64 table entries, the cell's whole `bf16[12,8193,16,768]`
+    pools; and the same slots at `gpt3_1p3b`'s widths, 16 heads of 128
+    (two layers of 2,049 blocks stand for its 24)."""
+    heads, head_dim, pool = {"gpt2_124m": (12, 64, (12, 8193)),
+                             "gpt3_1p3b": (16, 128, (2, 2049))}[config]
+    text = compile_for(v5e, paged_kernel(BLOCK), *paged_shapes(
+        heads, head_dim, BLOCK, jnp.bfloat16, slots=128, pool=pool))
+    assert "while" not in text
 
 
 def test_paged_eligibility_is_what_the_compiler_accepts(v5e, monkeypatch):
-    """`is_eligible` draws its line at the largest pool block the compiler
-    takes for every pool dtype; an int8 block twice that size runs out of
-    VMEM."""
+    """`is_eligible` is true exactly where the kernel compiles: a row of
+    whole 128-lane tiles, a page of whole sublane tiles, and a page that
+    leaves VMEM room for two groups a side, whatever the fp dtype."""
     monkeypatch.setattr(paged_attention, "_on_tpu", lambda: True)
 
-    def decode(block):
-        return lambda q, k, v, tables, lens, *scales: \
-            paged_attention.pallas_paged_attention(q, k, v, 1, tables, lens,
-                                                   block, *scales)
+    def compiles(heads, head_dim, block, pool_dtype):
+        ok, why = paged_attention.is_eligible(heads, head_dim, block,
+                                              pool_dtype)
+        shapes = paged_shapes(heads, head_dim, block, pool_dtype, table=8,
+                              pool=(LAYERS, 65))
+        if ok:
+            assert why is None
+            compile_for(v5e, paged_kernel(block), *shapes)
+        else:
+            # (past the VMEM line the plan has no group: try a page)
+            pages = 1 if why == "block_exceeds_vmem" else 8
+            with pytest.raises(Exception, match="vmem|aligned to tiling"):
+                compile_for(v5e, paged_kernel(block, pages), *shapes)
+        return why
 
-    heads, head_dim = 32, 128
-    assert paged_attention.is_eligible(heads, head_dim, 64) == (True, None)
-    for pool_dtype in (jnp.float32, jnp.int8):
-        compile_for(v5e, decode(64),
-                    *paged_shapes(heads, head_dim, 64, pool_dtype))
-    assert paged_attention.is_eligible(heads, head_dim, 128) == (
-        False, "block_exceeds_vmem")
-    with pytest.raises(Exception, match="vmem"):
-        compile_for(v5e, decode(128),
-                    *paged_shapes(heads, head_dim, 128, jnp.int8))
-    # the shapes the engine serves today are far inside the line
-    assert paged_attention.is_eligible(12, 64, 16) == (True, None)
-    assert paged_attention.is_eligible(16, 64, 16) == (True, None)
+    # the shapes the engine serves today, and the smallest that fit
+    for heads, head_dim in ((12, 64), (16, 64), (16, 128), (2, 64)):
+        for pool_dtype in (jnp.bfloat16, jnp.float32):
+            assert compiles(heads, head_dim, BLOCK, pool_dtype) is None
+    assert compiles(12, 64, 8, jnp.bfloat16) is None
+    # a row or a page off the tiles
+    assert compiles(3, 16, BLOCK, jnp.bfloat16) == "row_not_whole_lane_tiles"
+    assert compiles(12, 64, 4, jnp.float32) == \
+        "block_not_whole_sublane_tiles"
+    # a page of 2 MB is a group of its own; one of 4 MB has no VMEM
+    assert compiles(32, 128, 128, jnp.float32) is None
+    assert compiles(32, 128, 256, jnp.bfloat16) is None
+    assert compiles(32, 128, 256, jnp.float32) == "block_exceeds_vmem"
+    assert compiles(32, 128, 512, jnp.bfloat16) == "block_exceeds_vmem"
+    # the plan: 256 tokens a group where they fit
+    assert paged_attention._group_pages(64, 16, 768, jnp.bfloat16) == 16
+    assert paged_attention._group_pages(64, 16, 2048, jnp.bfloat16) == 16
+    assert paged_attention._group_pages(4, 16, 768, jnp.bfloat16) == 4
+    assert paged_attention._group_pages(64, 16, 32 * 128, jnp.float32) == 8
+    assert paged_attention._group_pages(64, 64, 32 * 128, jnp.float32) == 2
+    # what is not a shape's to decide
+    assert paged_attention.is_eligible(12, 64, 16, jnp.int8) == (
+        False, "quantized_pool")
+    assert paged_attention.is_eligible(None, 64, 16) == (
+        False, "shape_unknown")
+
+
+def test_the_unrequested_variant_follows_what_is_observed(monkeypatch):
+    """No flag, no argument: `pallas` on a TPU over a per-head fp pool
+    whose shape the kernel takes; `blockwise` for a latent cache, an int8
+    pool, a row off the lane tiles, and off the TPU: with no event, for
+    nothing was asked for and nothing fell back."""
+    from paddle_tpu.framework.flags import get_flags, set_flags
+    from paddle_tpu.nn.functional.attention import resolve_paged_kernel
+    from paddle_tpu.profiler.events import (clear_fusion_events,
+                                            fusion_events)
+    assert get_flags(["FLAGS_serve_attention_kernel"]) == {
+        "FLAGS_serve_attention_kernel": ""}
+    cell = dict(num_heads=12, head_dim=64, block_size=16)
+    prev = get_flags(["FLAGS_profiler_events"])
+    set_flags({"FLAGS_profiler_events": True})
+    clear_fusion_events()
+    try:
+        assert resolve_paged_kernel(**cell) == "blockwise"      # a CPU
+        monkeypatch.setattr(paged_attention, "_on_tpu", lambda: True)
+        assert resolve_paged_kernel(**cell) == "pallas"
+        assert resolve_paged_kernel(**cell, kv_dtype=jnp.float32) == "pallas"
+        assert resolve_paged_kernel(num_heads=16, head_dim=128,
+                                    block_size=16) == "pallas"
+        assert resolve_paged_kernel(**cell, cache_kind="latent") \
+            == "blockwise"
+        assert resolve_paged_kernel(**cell, kv_dtype=jnp.int8) == "blockwise"
+        assert resolve_paged_kernel(num_heads=3, head_dim=16,
+                                    block_size=16) == "blockwise"
+        assert resolve_paged_kernel(num_heads=12, head_dim=64,
+                                    block_size=4) == "blockwise"
+        assert fusion_events("kernel.fallback") == []
+        # the explicit overrides stay what they were
+        assert resolve_paged_kernel("blockwise", **cell) == "blockwise"
+        assert resolve_paged_kernel("reference", **cell) == "reference"
+        assert resolve_paged_kernel("pallas", num_heads=3, head_dim=16,
+                                    block_size=16) == "blockwise"
+        (event,) = fusion_events("kernel.fallback")
+        assert event["detail"]["why"] == "row_not_whole_lane_tiles"
+    finally:
+        set_flags(prev)
 
 
 # The backlog cell's serving geometry (benchmark/traffic/backlog_mixed.json:
