@@ -37,17 +37,25 @@ COUNTERS = ("routed_held", "routed_identity", "routed_elsewhere",
             "load_max", "experts_idle", "routed_computed")
 
 
-def route(u, router_w, bias, topk, scaling):
-    """The router: scores ``softmax(u W_r)`` over every expert in float32,
-    the `topk` of ``scores + bias`` chosen (the bias moves the choice and
-    never the weight), each weighed ``scaling * score``, not renormalised.
+def route(u, router_w, bias, topk, scaling, scoring="softmax",
+          normalise=False):
+    """The router: scores over every expert in float32, ``softmax(u W_r)``
+    or, with `scoring` "sigmoid", ``sigmoid(u W_r)``; the `topk` of
+    ``scores + bias`` chosen (the bias moves the choice and never the
+    weight), each weighed ``scaling * score``, with `normalise` over the
+    sum of the chosen scores (all `topk`, wherever their experts live).
     Returns ``(chosen ids [T, topk], weights [T, topk] float32)``."""
     logits = jnp.matmul(u.astype(jnp.float32), router_w.astype(jnp.float32),
                         precision=jax.lax.Precision.HIGHEST)
-    scores = jax.nn.softmax(logits, axis=-1)
+    if scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)
     ranked = scores if bias is None else scores + bias.astype(jnp.float32)
     _, chosen = jax.lax.top_k(ranked, topk)
     weights = jnp.take_along_axis(scores, chosen, axis=-1)
+    if normalise:
+        weights = weights / (jnp.sum(weights, -1, keepdims=True) + 1e-20)
     return chosen.astype(jnp.int32), jnp.float32(scaling) * weights
 
 

@@ -8,3 +8,7 @@ from . import longcat_flash  # noqa: F401
 from .longcat_flash import (  # noqa: F401
     LongCatFlashConfig, LongCatFlashForCausalLM,
 )
+from . import joyai_llm_flash  # noqa: F401
+from .joyai_llm_flash import (  # noqa: F401
+    JoyAIFlashConfig, JoyAIFlashForCausalLM,
+)

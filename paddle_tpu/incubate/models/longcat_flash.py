@@ -12,8 +12,9 @@ technical report (arXiv:2509.01322). What differs from `gpt.py`:
   * attention is multi-head LATENT attention: queries through a low-rank
     bottleneck, keys and values expanded from one compressed row
     (`kv_lora_rank` values) a token, plus ONE rotary key of
-    `qk_rope_head_dim` values that all heads share. Only that row is
-    cached. Prefill expands it into per-head keys and values; decode
+    `qk_rope_head_dim` values that all heads share (`mla.py`, shared with
+    the models that train it). Only that row is cached. Prefill expands it
+    into per-head keys and values; decode
     against the paged cache runs ABSORBED: a head's query is carried into
     the row's space, attends over the rows, and its output is expanded by
     the value half of the expansion, so no key or value of any head is made
@@ -43,6 +44,8 @@ from ...nn.layer_base import Layer
 from ...framework.core import Tensor, Parameter
 from ...incubate.distributed.models.moe.held_experts import (
     held_expert_block, COUNTERS)
+from . import mla
+from .mla import rms as _rms
 
 __all__ = ["LongCatFlashConfig", "LongCatFlashForCausalLM"]
 
@@ -141,29 +144,6 @@ def router_bias_name(layer):
     return f"model.layers.{layer}.mlp.router.e_score_correction_bias"
 
 
-def _rms(x, g, eps):
-    x32 = x.astype(jnp.float32)
-    y = x32 * jax.lax.rsqrt(jnp.mean(jnp.square(x32), -1, keepdims=True)
-                            + eps)
-    return (y * g.astype(jnp.float32)).astype(x.dtype)
-
-
-def _rotate(x, pos, theta):
-    """Rotary positions over the last axis of x ``[..., T, (heads,) r]``,
-    pairs interleaved (2i, 2i+1); pos ``[..., T]`` int."""
-    r = x.shape[-1]
-    freq = jnp.float32(theta) ** (-jnp.arange(0, r, 2, dtype=jnp.float32)
-                                  / r)
-    ang = pos.astype(jnp.float32)[..., None] * freq          # [..., T, r/2]
-    if x.ndim == ang.ndim + 1:                               # a heads axis
-        ang = ang[..., None, :]
-    x32 = x.astype(jnp.float32)
-    even, odd = x32[..., 0::2], x32[..., 1::2]
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    out = jnp.stack([even * cos - odd * sin, even * sin + odd * cos], -1)
-    return out.reshape(x.shape).astype(x.dtype)
-
-
 def _swiglu(x, gate_w, up_w, down_w):
     return (jax.nn.silu(x @ gate_w) * (x @ up_w)) @ down_w
 
@@ -244,54 +224,9 @@ class LongCatFlashForCausalLM(Layer):
                 for _ in range(2 * cfg.num_layers)]
 
     # -- attention ----------------------------------------------------------
-    def _queries_and_row(self, x, pos, prefix):
-        """x ``[B, T, d]`` -> q_nope ``[B, T, H, nope]``, q_rope (rotated)
-        ``[B, T, H, rope]``, the row's parts c_kv ``[B, T, kv_lora]``
-        (normed and scaled) and k_rope ``[B, T, rope]`` (rotated)."""
-        cfg = self.config
-        h, nope, rope = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
-                         cfg.qk_rope_head_dim)
-        b, t, d = x.shape
-        w = lambda leaf: self._w(prefix + leaf)
-        c_q = _rms(x @ w("q_a_proj.weight"), w("q_a_layernorm.weight"),
-                   cfg.rms_norm_eps)
-        q = c_q @ w("q_b_proj.weight")
-        if cfg.mla_scale_q_lora:
-            q = q * jnp.asarray(math.sqrt(d / cfg.q_lora_rank), q.dtype)
-        q = q.reshape(b, t, h, nope + rope)
-        q_nope, q_rope = q[..., :nope], q[..., nope:]
-        ckr = x @ w("kv_a_proj_with_mqa.weight")
-        c_kv = _rms(ckr[..., :cfg.kv_lora_rank],
-                    w("kv_a_layernorm.weight"), cfg.rms_norm_eps)
-        if cfg.mla_scale_kv_lora:
-            c_kv = c_kv * jnp.asarray(math.sqrt(d / cfg.kv_lora_rank),
-                                      c_kv.dtype)
-        k_rope = _rotate(ckr[..., cfg.kv_lora_rank:], pos, cfg.rope_theta)
-        return q_nope, _rotate(q_rope, pos, cfg.rope_theta), c_kv, k_rope
-
-    def _expanded(self, q_nope, q_rope, c_kv, k_rope, prefix, past):
-        """Causal attention with every head's keys and values expanded
-        from the rows (prefill, the eager path). `past` rows precede the
-        call's own."""
-        cfg = self.config
-        h, nope, vd = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
-                       cfg.v_head_dim)
-        b, t = q_nope.shape[:2]
-        total = c_kv.shape[1]
-        kv = (c_kv @ self._w(prefix + "kv_b_proj.weight")).reshape(
-            b, total, h, nope + vd)
-        k_nope, v = kv[..., :nope], kv[..., nope:]
-        scale = 1.0 / math.sqrt(nope + cfg.qk_rope_head_dim)
-        s = (jnp.einsum("bqhd,bkhd->bhqk", q_nope, k_nope,
-                        preferred_element_type=jnp.float32)
-             + jnp.einsum("bqhd,bkd->bhqk", q_rope, k_rope,
-                          preferred_element_type=jnp.float32)) * scale
-        keep = (jnp.arange(total)[None, :]
-                <= past + jnp.arange(t)[:, None])
-        p = jax.nn.softmax(jnp.where(keep, s, -jnp.inf), axis=-1)
-        o = jnp.einsum("bhqk,bkhd->bqhd", p, v,
-                       preferred_element_type=jnp.float32).astype(v.dtype)
-        return o.reshape(b, t, h * vd) @ self._w(prefix + "o_proj.weight")
+    def _leaf(self, prefix):
+        """A sublayer's weights by the leaf's name, for `mla`."""
+        return lambda leaf: self._w(prefix + leaf)
 
     def _absorbed(self, q_nope, q_rope, c_kv, k_rope, prefix, view):
         """One token a slot against the paged latent pool: the query goes
@@ -321,7 +256,8 @@ class LongCatFlashForCausalLM(Layer):
         return o, view.updated(pool, view.v_pools)
 
     def _attention(self, x, pos, prefix, cache):
-        q_nope, q_rope, c_kv, k_rope = self._queries_and_row(x, pos, prefix)
+        q_nope, q_rope, c_kv, k_rope = mla.queries_and_row(
+            x, pos, self._leaf(prefix), self.config)
         if cache is not None and hasattr(cache, "block_tables"):
             return self._absorbed(q_nope, q_rope, c_kv, k_rope, prefix,
                                   cache)
@@ -333,8 +269,8 @@ class LongCatFlashForCausalLM(Layer):
             k_rope = jnp.concatenate([cache[1]._value.astype(k_rope.dtype),
                                       k_rope], axis=1)
             cache = (Tensor(c_kv), Tensor(k_rope))
-        return self._expanded(q_nope, q_rope, c_kv, k_rope, prefix,
-                              past), cache
+        return mla.expanded(q_nope, q_rope, c_kv, k_rope,
+                            self._leaf(prefix), self.config, past), cache
 
     # -- the model ------------------------------------------------------------
     def forward(self, input_ids, position_ids=None, caches=None,

@@ -247,20 +247,26 @@ class _PrefetchIterator:
     def __init__(self, produce_batches, prefetch=2):
         from ..core import BoundedQueue
         self._q = BoundedQueue(max(prefetch, 1))
-        self._exc = None
-        self._thread = threading.Thread(target=self._run,
-                                        args=(produce_batches,), daemon=True)
+        # the producer is handed the queue and this box, never the
+        # iterator: one dropped mid-epoch (`next(iter(loader))`) is then
+        # collected, `__del__` closes the queue, and the producer blocked
+        # on it lets go of its batches instead of holding them for good
+        self._failed = []
+        self._thread = threading.Thread(
+            target=self._run, args=(self._q, self._failed, produce_batches),
+            daemon=True)
         self._thread.start()
 
-    def _run(self, produce_batches):
+    @staticmethod
+    def _run(q, failed, produce_batches):
         try:
             for b in produce_batches():
-                if not self._q.push(b):
+                if not q.push(b):
                     return  # consumer closed the queue
         except BaseException as e:  # propagate to consumer
-            self._exc = e
+            failed.append(e)
         finally:
-            self._q.close()
+            q.close()
 
     def __iter__(self):
         return self
@@ -269,8 +275,8 @@ class _PrefetchIterator:
         try:
             return self._q.pop()
         except StopIteration:
-            if self._exc is not None:
-                raise self._exc from None
+            if self._failed:
+                raise self._failed[0] from None
             raise
 
     def close(self):

@@ -43,8 +43,13 @@ def train_step_stats():
 class TrainStepStats:
     """Host-side counters of one `TrainStep`: the seconds of each phase of
     `__call__` in a bounded histogram keyed by the phase's span, which
-    feeds it, and the programs the jitted step has traced (bumped INSIDE
-    the traced function, which runs only while tracing)."""
+    feeds it, the programs the jitted step has traced (bumped INSIDE
+    the traced function, which runs only while tracing), and, of a model
+    that counts (`train_counter_names` and a buffer `train_counters` its
+    forward fills: expert loads, loss terms), the newest step's counters
+    and, as `first_step_<name>`, the first step's: they leave the compiled
+    step with the other buffers, in the same turn as the loss, and are
+    fetched only when the stats are read."""
 
     # spans that own a histogram (`train_step.build` is for the trace alone)
     PHASES = ("train_step.call", "train_step.gather_state",
@@ -53,10 +58,26 @@ class TrainStepStats:
     def __init__(self):
         self.compiles = 0
         self.phase = {name: LogHistogram() for name in self.PHASES}
+        # what the model counted in its newest step: (names, the device
+        # array the step returned beside the loss), read on `snapshot()`;
+        # and in its FIRST step (a router that trains moves its loads)
+        self.model_counters = None
+        self.first_model_counters = None
 
     def snapshot(self):
+        from ..kernels import flash_attention
         out = {"steps": self.phase["train_step.call"].count,
-               "compiles": self.compiles}
+               "compiles": self.compiles,
+               # long causal attentions a TPU sent to XLA's N^2 path for
+               # their head widths alone (process-wide; 0, or a model has
+               # lost its kernel)
+               "flash_width_fallbacks": flash_attention.width_fallbacks()}
+        if self.model_counters is not None:
+            for prefix, (names, values) in (
+                    ("", self.model_counters),
+                    ("first_step_", self.first_model_counters)):
+                out.update(zip((prefix + n for n in names),
+                               jax.device_get(values).tolist()))
         for name, hist in self.phase.items():
             short = name.split(".", 1)[1]
             out[f"{short}_p50_ms"] = hist.percentile(50) * 1e3
@@ -100,8 +121,10 @@ class TrainStep:
         _LIVE[next(_SERIAL)] = self
 
     def stats(self):
-        """{"steps", "compiles", "<phase>_p50_ms", "<phase>_p99_ms"} for
-        the phases `call`, `gather_state`, `dispatch`, `write_back`."""
+        """{"steps", "compiles", "flash_width_fallbacks", "<phase>_p50_ms",
+        "<phase>_p99_ms"} for the phases `call`, `gather_state`,
+        `dispatch`, `write_back`, and the newest and the first step's
+        counters of a model that counts, by its `train_counter_names`."""
         return self._stats.snapshot()
 
     def _span(self, name):
@@ -117,6 +140,9 @@ class TrainStep:
         buffers = [b for _, b in model.named_buffers()]
         self._params = params
         self._buffers = buffers
+        names = [n for n, _ in model.named_buffers()]
+        self._counters_at = names.index("train_counters") \
+            if getattr(model, "train_counter_names", None) else None
         opt._create_accumulators(params)
         acc_names = sorted(opt._accumulators.keys())
         self._acc_names = acc_names
@@ -248,6 +274,14 @@ class TrainStep:
                         opt._accumulators[n][p.name] = v
             for b, v in zip(self._buffers, new_b):
                 b._value = v
+            if self._counters_at is not None:
+                counted = (self.model.train_counter_names,
+                           new_b[self._counters_at])
+                self._stats.model_counters = counted
+                if self._stats.first_model_counters is None:
+                    # a copy: the buffer itself is donated to the next step
+                    self._stats.first_model_counters = (
+                        counted[0], jnp.copy(counted[1]))
             # goodput accountant (profiler/goodput.py): the explicit fused
             # TrainStep never crosses Optimizer.step, so the boundary is
             # here

@@ -29,7 +29,7 @@ except Exception:  # pragma: no cover
 
 from ._common import ZERO as _SHARED_ZERO, on_tpu as _on_tpu
 
-__all__ = ["flash_attention_bnhd", "is_eligible"]
+__all__ = ["flash_attention_bnhd", "is_eligible", "width_fallbacks"]
 
 _NEG_INF = -1e30
 
@@ -70,7 +70,7 @@ def is_eligible(q, k, v, mask, dropout_p, is_causal=False):
         return False
     b, n, h, d = q.shape
     m = k.shape[1]
-    if d not in (64, 128, 256):
+    if not _width_ok(q, k, v, is_causal):
         return False
     if is_causal and n != m:
         # kv-cache decode/prefill shapes (m > n) use bottom-right causal
@@ -126,7 +126,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal, scale,
     else:
         upper = num_k_blocks
 
-    d = q.shape[-1]
+    d = v_ref.shape[-1]                       # the accumulator is v's width
     o0 = jnp.zeros((block_q, d), jnp.float32)
     m0 = jnp.full((block_q,), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((block_q,), jnp.float32)
@@ -143,17 +143,17 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal, scale,
 
 def _flash_fwd(q, k, v, causal, scale, block_q=None, block_k=None,
                interpret=False):
-    """q,k,v: [B, N, H, D] — runs the kernel per (b*h, q_block).
+    """q,k: [B, N, H, D], v: [B, N, H, Dv] — the kernel per (b*h, q_block).
 
-    Returns (out [B,N,H,D], lse [B*H, N] float32)."""
+    Returns (out [B,N,H,Dv], lse [B*H, N] float32)."""
     b, n, h, d = q.shape
-    m = k.shape[1]
+    m, dv = k.shape[1], v.shape[-1]
     if block_q is None or block_k is None:
         block_q, block_k = _auto_blocks(n, m)
     # fold batch & heads, move seq to the row dim: [B*H, N, D]
     qf = jnp.swapaxes(q, 1, 2).reshape(b * h, n, d)
     kf = jnp.swapaxes(k, 1, 2).reshape(b * h, m, d)
-    vf = jnp.swapaxes(v, 1, 2).reshape(b * h, m, d)
+    vf = jnp.swapaxes(v, 1, 2).reshape(b * h, m, dv)
 
     grid = (b * h, n // block_q)
     kernel = functools.partial(_fwd_kernel, causal=causal, scale=scale,
@@ -166,20 +166,20 @@ def _flash_fwd(q, k, v, causal, scale, block_q=None, block_k=None,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, zero)),
             pl.BlockSpec((1, m, d), lambda bh, qi: (bh, zero, zero)),
-            pl.BlockSpec((1, m, d), lambda bh, qi: (bh, zero, zero)),
+            pl.BlockSpec((1, m, dv), lambda bh, qi: (bh, zero, zero)),
         ],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, zero)),
+            pl.BlockSpec((1, block_q, dv), lambda bh, qi: (bh, qi, zero)),
             pl.BlockSpec((1, block_q, 1), lambda bh, qi: (bh, qi, zero)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((b * h, n, d), q.dtype),
+            jax.ShapeDtypeStruct((b * h, n, dv), q.dtype),
             jax.ShapeDtypeStruct((b * h, n, 1), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret, compiler_params=_vmem(m, d, dv),
         name="flash_attention_fwd",
     )(qf, kf, vf)
-    return out.reshape(b, h, n, d).swapaxes(1, 2), lse
+    return out.reshape(b, h, n, dv).swapaxes(1, 2), lse
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
@@ -265,9 +265,9 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             preferred_element_type=jnp.float32)        # ds^T @ q
         return dk_new, dv_new
 
-    d = k_blk.shape[-1]
+    d, dv = k_blk.shape[-1], v_blk.shape[-1]
     init = (jnp.zeros((block_k, d), jnp.float32),
-            jnp.zeros((block_k, d), jnp.float32))
+            jnp.zeros((block_k, dv), jnp.float32))
     count = jnp.asarray(num_q_blocks - first, jnp.int32)
     dk, dv = jax.lax.fori_loop(jnp.int32(0), count, body, init)
     dk_ref[0] = dk.astype(dk_ref.dtype)
@@ -280,14 +280,14 @@ def _flash_bwd(q, k, v, out, lse, g, causal, scale,
     kernel over k blocks — FlashAttention-2 decomposition, no atomics, no
     N x N materialization."""
     b, n, h, d = q.shape
-    m = k.shape[1]
+    m, dv = k.shape[1], v.shape[-1]
     if block_q is None or block_k is None:
         block_q, block_k = _auto_blocks(n, m)
     qf = jnp.swapaxes(q, 1, 2).reshape(b * h, n, d)
     kf = jnp.swapaxes(k, 1, 2).reshape(b * h, m, d)
-    vf = jnp.swapaxes(v, 1, 2).reshape(b * h, m, d)
-    of = jnp.swapaxes(out, 1, 2).reshape(b * h, n, d)
-    gf = jnp.swapaxes(g, 1, 2).reshape(b * h, n, d)
+    vf = jnp.swapaxes(v, 1, 2).reshape(b * h, m, dv)
+    of = jnp.swapaxes(out, 1, 2).reshape(b * h, n, dv)
+    gf = jnp.swapaxes(g, 1, 2).reshape(b * h, n, dv)
     # rescale q once here so fwd/bwd agree on s = (q*scale) @ k^T
     delta = jnp.sum(of.astype(jnp.float32) * gf.astype(jnp.float32),
                     axis=-1, keepdims=True)             # [bh, n, 1]
@@ -301,47 +301,47 @@ def _flash_bwd(q, k, v, out, lse, g, causal, scale,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, zero)),
             pl.BlockSpec((1, m, d), lambda bh, qi: (bh, zero, zero)),
-            pl.BlockSpec((1, m, d), lambda bh, qi: (bh, zero, zero)),
-            pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, zero)),
+            pl.BlockSpec((1, m, dv), lambda bh, qi: (bh, zero, zero)),
+            pl.BlockSpec((1, block_q, dv), lambda bh, qi: (bh, qi, zero)),
             pl.BlockSpec((1, block_q, 1), lambda bh, qi: (bh, qi, zero)),
             pl.BlockSpec((1, block_q, 1), lambda bh, qi: (bh, qi, zero)),
         ],
         out_specs=pl.BlockSpec((1, block_q, d),
                                lambda bh, qi: (bh, qi, zero)),
         out_shape=jax.ShapeDtypeStruct((b * h, n, d), q.dtype),
-        interpret=interpret,
+        interpret=interpret, compiler_params=_vmem(m, d, dv),
         name="flash_attention_dq",
     )(qf, kf, vf, gf, lse, delta)
 
     dkv_kernel = functools.partial(_dkv_kernel, causal=causal, scale=scale,
                                    block_q=block_q, block_k=block_k, seq_q=n)
-    dk, dv = pl.pallas_call(
+    dk, dw = pl.pallas_call(
         dkv_kernel,
         grid=(b * h, m // block_k),
         in_specs=[
             pl.BlockSpec((1, n, d), lambda bh, ki: (bh, zero, zero)),
             pl.BlockSpec((1, block_k, d), lambda bh, ki: (bh, ki, zero)),
-            pl.BlockSpec((1, block_k, d), lambda bh, ki: (bh, ki, zero)),
-            pl.BlockSpec((1, n, d), lambda bh, ki: (bh, zero, zero)),
+            pl.BlockSpec((1, block_k, dv), lambda bh, ki: (bh, ki, zero)),
+            pl.BlockSpec((1, n, dv), lambda bh, ki: (bh, zero, zero)),
             pl.BlockSpec((1, n, 1), lambda bh, ki: (bh, zero, zero)),
             pl.BlockSpec((1, n, 1), lambda bh, ki: (bh, zero, zero)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda bh, ki: (bh, ki, zero)),
-            pl.BlockSpec((1, block_k, d), lambda bh, ki: (bh, ki, zero)),
+            pl.BlockSpec((1, block_k, dv), lambda bh, ki: (bh, ki, zero)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b * h, m, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h, m, d), v.dtype),
+            jax.ShapeDtypeStruct((b * h, m, dv), v.dtype),
         ],
-        interpret=interpret,
+        interpret=interpret, compiler_params=_vmem(n, d, dv, True),
         name="flash_attention_dkv",
     )(qf, kf, vf, gf, lse, delta)
 
     def unfold(t, nn):
-        return t.reshape(b, h, nn, d).swapaxes(1, 2)
+        return t.reshape(b, h, nn, t.shape[-1]).swapaxes(1, 2)
 
-    return unfold(dq, n), unfold(dk, m), unfold(dv, m)
+    return unfold(dq, n), unfold(dk, m), unfold(dw, m)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -367,3 +367,56 @@ def _fa_bwd(causal, scale, res, g):
 
 
 flash_attention_bnhd.defvjp(_fa_fwd, _fa_bwd)
+
+
+# -- head widths ------------------------------------------------------------
+# Kept BELOW the kernels: a Mosaic kernel's lowering carries the line of
+# every operation of its body, so a line added above them changes the
+# compiled bytes of every model's step.
+
+# (q and k width, v and o width) the kernels take: one width for all four,
+# or latent attention's 192-wide q and k (128 + 64 rotary) over a 128-wide v
+_WIDTHS = frozenset({(64, 64), (128, 128), (256, 256), (192, 128)})
+
+# causal self-attentions of >= FLASH_MIN_SEQ tokens that a TPU sent to XLA's
+# N^2 attention for their head widths alone, counted where they are traced
+_width_fallbacks = [0]
+
+
+def width_fallbacks():
+    """How many attention calls this process has traced ON A TPU that met
+    every condition of the flash path but the head widths (`_WIDTHS`) and so
+    took XLA's N^2 attention. `TrainStepStats` reports it: 0, or a model
+    has lost its kernel unseen."""
+    return _width_fallbacks[0]
+
+
+def _width_ok(q, k, v, is_causal):
+    """Whether the kernels take these head widths; a refusal that sends a
+    long causal self-attention to the N^2 path is counted."""
+    n, d = q.shape[1], q.shape[-1]
+    if k.shape[-1] == d and (d, v.shape[-1]) in _WIDTHS:
+        return True
+    if is_causal and k.shape[1] == n and n % 128 == 0 \
+            and n >= FLASH_MIN_SEQ:
+        _width_fallbacks[0] += 1
+    return False
+
+
+# a call keeps one head's whole K and V (forward, dQ) or Q, dO and the two
+# row statistics (dK/dV) in VMEM, twice for the pipeline; a statistic's
+# [rows, 1] block is padded to 128 lanes. Past this many bytes the
+# compiler's default scoped limit (16 MB of the core's 128) is raised
+_VMEM_DEFAULT_FITS = 8 << 20
+
+
+def _vmem(rows, d, dv, with_stats=False):
+    """`compiler_params` of a call that keeps `rows` rows of a `d`-wide
+    and a `dv`-wide bf16 operand resident: None (the compiler's own limit,
+    and the lowering every model had before) while they fit it."""
+    lanes = lambda w: -(-w // 128) * 128
+    held = 2 * rows * (2 * lanes(d) + 2 * lanes(dv)
+                       + (2 * 128 * 4 if with_stats else 0))
+    if held <= _VMEM_DEFAULT_FITS:
+        return None
+    return pltpu.CompilerParams(vmem_limit_bytes=min(4 * held, 100 << 20))

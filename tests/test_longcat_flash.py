@@ -133,12 +133,12 @@ def test_a_fault_in_the_layers_mathematics_fails_the_comparison(
             return chosen, s * weight / jnp.sum(weight, -1, keepdims=True)
         monkeypatch.setattr(held_experts, "route", renormalised)
     elif fault == "rotary_key_scaled":
-        keep = model._queries_and_row
+        keep = lc.mla.queries_and_row
 
-        def scaled(x, pos, prefix):
-            qn, qr, c, kr = keep(x, pos, prefix)
+        def scaled(x, pos, w, cfg):
+            qn, qr, c, kr = keep(x, pos, w, cfg)
             return qn, qr, c, kr * 2.0
-        monkeypatch.setattr(model, "_queries_and_row", scaled)
+        monkeypatch.setattr(lc.mla, "queries_and_row", scaled)
     else:
         # the block's result joins right after it is computed
         def early(u, *a, **kw):

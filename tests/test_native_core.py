@@ -232,8 +232,19 @@ def test_dataloader_early_abandon_no_crash():
         def __getitem__(self, i):
             return np.zeros((64, 64), np.float32)
 
+    import threading
+    import weakref
+    before = threading.active_count()
     for _ in range(5):
         it = iter(DataLoader(Big(), batch_size=4))
         next(it)
+        gone, producer = weakref.ref(it), it._thread
         del it          # abandon with producer likely blocked on full queue
         gc.collect()
+        # the producer holds the queue, not the iterator: the iterator is
+        # collected, its queue closed, and the producer ends with its
+        # batches released (it used to block for good, holding them)
+        assert gone() is None
+        producer.join(timeout=5.0)
+        assert not producer.is_alive()
+    assert threading.active_count() <= before

@@ -155,8 +155,9 @@ def test_train_step_stats_count_every_call_and_only_the_contract_keys():
         step(x, y)
     stats = step.stats()
     phases = ("call", "gather_state", "dispatch", "write_back")
-    assert set(stats) == {"steps", "compiles"} | {
+    assert set(stats) == {"steps", "compiles", "flash_width_fallbacks"} | {
         f"{p}_{q}_ms" for p in phases for q in ("p50", "p99")}
+    assert stats["flash_width_fallbacks"] == 0
     assert stats["steps"] == 5
     hists = step._stats.phase
     for p in phases:

@@ -78,6 +78,44 @@ def test_flash_attention_fwd_bwd(v5e):
     assert text.count("tpu_custom_call") >= 3      # fwd, dq, dkv
 
 
+def test_flash_attention_at_unequal_head_widths(v5e):
+    """Latent attention's training shape in `train_joyai_mtp_4k`: batch 4,
+    seq 4096, 32 heads, q and k 192 wide (128 + 64 rotary), v 128. At 4096
+    rows the dK/dV kernel keeps Q, dO and two row statistics resident:
+    past the compiler's default scoped VMEM, which `_vmem` raises."""
+    qk, v = ((4, 4096, 32, 192), jnp.bfloat16), ((4, 4096, 32, 128),
+                                                 jnp.bfloat16)
+
+    def loss(q, k, v):
+        out = flash_attention.flash_attention_bnhd(q, k, v, True, 192 ** -.5)
+        assert out.shape == (4, 4096, 32, 128)
+        return out.astype(jnp.float32).sum()
+
+    text = compile_for(v5e, jax.grad(loss, argnums=(0, 1, 2)), qk, qk, v)
+    assert text.count("tpu_custom_call") >= 3      # fwd, dq, dkv
+    assert flash_attention._vmem(1024, 64, 64, True) is None   # GPT's calls
+    assert flash_attention._vmem(2048, 128, 128, True) is None
+    assert flash_attention._vmem(4096, 192, 128, True) is not None
+
+
+def test_the_grouped_expert_block_compiles_at_the_cells_size(v5e):
+    """16,384 tokens top 8 of 256 with 8 held: the buffer of 131,072 rows
+    that no routing overflows, grouped products, forward and backward."""
+    from paddle_tpu.incubate.distributed.models.moe import grouped_experts
+    bf16, t, d, f = jnp.bfloat16, 16384, 2048, 768
+
+    def loss(u, router, gate, up, down):
+        out, counters, load = grouped_experts.grouped_held_expert_block(
+            u, router, None, gate, up, down, topk=8, scaling=2.5)
+        return out.sum() + counters.sum() + load.sum()
+
+    args = [jax.ShapeDtypeStruct(shape, bf16, sharding=v5e) for shape in (
+        (t, d), (d, 256), (8, d, f), (8, d, f), (8, f, d))]
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        *args).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 6e9
+
+
 def test_fused_layer_norm(v5e):
     rows, d = 16 * 1024, 768
     assert fused_ln._pick_block_r(d) is not None
