@@ -15,8 +15,9 @@ millions of users"), combining:
     override [S], block_tables [S, M], seq_lens [S], active [S], k_pools,
     v_pools) -> (next_tokens, new_pools)`` with the pools donated (a
     slot's input is the host's token where `override` is set, else the
-    launch before's, still on the device). Requests joining or leaving the
-    batch only change the *values* of the integer inputs, never a shape:
+    launch before's or its own prefill's, still on the device). Requests
+    joining or leaving the batch only change the *values* of the integer
+    inputs, never a shape:
     the decode program compiles exactly once and then serves every token
     of every stream (`stats()["decode_compiles"]`, held at 1 by
     tests/test_serving.py);
@@ -24,15 +25,17 @@ millions of users"), combining:
     length buckets, so admitting a new request compiles at most
     ``log2(max_context)`` prefill programs ever — and never touches the
     decode executable (`bucket_retrace` in the flight recorder marks
-    each new bucket);
+    each new bucket). A prefill is launched and not awaited: it hands
+    its token to the next decode launch on the device, and a boundary's
+    first tokens reach the host in one fetch, a step later;
   * a **continuous-batching scheduler** (serving/scheduler.py): FCFS +
     free-block watermark admission, LIFO preempt-resume via block
     tables, join/leave at token boundaries;
   * **streaming detokenization**: per-request `on_token` callbacks fire
     when a token is committed (optionally through a tokenizer's
     `decode`), not when the request completes: one `step()` after the
-    token's decode launch, the loop being pipelined at lag 1 (see
-    `LLMEngine`);
+    token's launch (decode or prefill), the loop being pipelined at lag
+    1 (see `LLMEngine`);
   * a **kernel tier** (PR 11): the decode step's paged attention runs
     streaming softmax over the pages that hold tokens
     (kernels/pallas/paged_attention.py: a Pallas kernel on a TPU over a
@@ -60,10 +63,11 @@ Resilience (PR 7, serving/resilience.py) rides every one of those layers:
   * **hung-step watchdog** — decode/prefill fires resolve through a
     monitored completion bounded by `FLAGS_serve_step_timeout_ms`; a
     stuck step emits `serve.hang`, marks the engine degraded, and climbs
-    a recovery ladder instead of wedging: the pipelined loop, which
-    waits for a launch at its lag-1 commit, retries the wait and then
-    fails the active requests with attributed reasons; the serial loop
-    retries the step, rebuilds the decode executable, then fails them;
+    a recovery ladder instead of wedging: a wait at a commit (a
+    launch's in the pipelined loop, a boundary's prefills' in both) is
+    retried once and then fails the active requests with attributed
+    reasons; the serial loop's decode step is retried, its executable
+    rebuilt, then the active requests fail;
   * **degraded-mode fallback** — a faulting/poisoned compiled decode
     finishes its in-flight streams per-request through the eager
     `generate()` path, token-identically, then rebuilds;
@@ -212,6 +216,11 @@ class ServeStats:
         # beside the device (the serial tail never has one in flight)
         self.launches = 0
         self.launches_overlapped = 0
+        # prefills whose results the host had not touched when the decode
+        # launch that feeds on their tokens was dispatched (none in the
+        # serial loop, which commits a boundary's prefills before it
+        # launches; nor a bucket's first call)
+        self.prefills_unawaited = 0
         # decode attention, in block-table entries summed over the decode
         # launches: what the attention's loop reads or its kernel copies,
         # what held a token, and slots x entries (kernels/pallas/
@@ -302,6 +311,9 @@ class ServeStats:
             "pipelined_launch_share": (
                 self.launches_overlapped / self.launches
                 if self.launches else 0.0),
+            "prefill_unawaited_share": (
+                self.prefills_unawaited / self.prefills
+                if self.prefills else 0.0),
             # share of the block table the decode attention read / that
             # held tokens (streamed == held is the ideal, 1.0 a loop over
             # the whole table)
@@ -389,12 +401,13 @@ class LLMEngine:
     on the device, and only then commits launch N, so the host's turn
     (callbacks, retirement, the caller's own work between steps, the next
     admission) runs beside the decode program and not after it. For a
-    caller that means: a decoded token's `on_token` runs one `step()`
-    after the step that launched it (a request's first token, sampled by
-    its prefill, still arrives in the step that admits it); a stream that
-    is cancelled, expires or is preempted loses at most the one token it
-    had in flight (`stats()["commit_rollbacks"]`), and one that ends by
-    `max_new_tokens` loses none; a finished request's slot is refilled one
+    caller that means: a token's `on_token` runs one `step()` after the
+    step that launched it (a request's first token, sampled by its
+    prefill, too: the prefill is launched and not awaited, and the decode
+    launch of the admitting step takes the token from the device); a
+    stream that is cancelled, expires or is preempted loses at most the
+    tokens it had in flight (`stats()["commit_rollbacks"]`), and one that
+    ends by `max_new_tokens` loses none; a finished request's slot is refilled one
     boundary later; `step()` keeps returning True until the last launch
     is committed. WHICH tokens are served does not change: streams are
     token-identical to `pipeline_decode=False`, the serial loop (launch,
@@ -557,6 +570,17 @@ class LLMEngine:
         self._inflight = None
         self._feedback = None
         self._override = np.ones(s, bool)
+        # -- prefills launched, not awaited ------------------------------
+        # a prefill writes what it sampled into `_feedback` at its slot
+        # (the next decode launch's input, on the device) and the token's
+        # host-side half (id, logprob, panel, the model's counters) as
+        # one int32 row of `_firsts`, both threaded from program to
+        # program as the pools are. `_joined` holds, by slot, the
+        # request, position and admission of the prefills dispatched
+        # since the last launch; the launch takes them into its inflight
+        # record, and the step after commits them from ONE fetch
+        self._firsts = self._empty_firsts()
+        self._joined = {}
         self._k_pools = self.cache.k_pools
         self._v_pools = self.cache.v_pools
         self._k_scales = self.cache.k_scales       # None unless int8 KV
@@ -842,11 +866,14 @@ class LLMEngine:
 
     def step(self):
         """One engine iteration: expire/cancel at the boundary, admit
-        (one prefill a request), grow/evict for KV headroom, LAUNCH the
-        ONE compiled decode step for every running slot, then stream the
-        tokens of the launch BEFORE it and retire finished requests while
-        the new one runs (`pipeline_decode=False`: wait for this step's
-        own launch and stream that). Both waits are the watchdog's.
+        (one prefill a request, dispatched and not awaited), grow/evict
+        for KV headroom, stream the first tokens of the step before's
+        prefills, LAUNCH the ONE compiled decode step for every running
+        slot, then stream the tokens of the launch BEFORE it and retire
+        finished requests while the new one runs (`pipeline_decode=
+        False`: fetch this step's prefills' tokens once all are
+        dispatched, wait for its own launch and stream that). All waits
+        are the watchdog's.
         Returns True while any request is running or waiting, or a
         launch is uncommitted."""
         if self._stats.wall_t0 is None:
@@ -905,6 +932,11 @@ class LLMEngine:
                         continue
                     break
                 self._admit(req)
+        if not self._pipeline:
+            # the serial loop's first tokens arrive in the admitting step:
+            # the boundary's prefills, dispatched back to back, are
+            # committed here from one fetch
+            self._drain_joined()
         if not sched.running:
             if self._pipeline:
                 self._flush_inflight()
@@ -1019,20 +1051,31 @@ class LLMEngine:
     # software-pipelined decode (PR 18): launch N+1, commit N at lag 1
     # ------------------------------------------------------------------
     def _step_pipelined(self):
-        """Pipelined tail of one iteration: LAUNCH this step's decode
-        against device-fed tokens (the previous launch's sampled ids
-        feed back as a device array — no host round-trip), then COMMIT
-        the previous launch's host work (detokenize, callbacks,
-        retirement) while the device runs the new one. Steady-state step
+        """Pipelined tail of one iteration: commit the first tokens of
+        the prefills the previous launch was queued behind, LAUNCH this
+        step's decode against device-fed tokens (the previous launch's
+        sampled ids, and this boundary's prefills', feed back as a
+        device array — no host round-trip), then COMMIT the previous
+        launch's host work (detokenize, callbacks, retirement) while the
+        device runs the new one. Steady-state step
         time is max(device, host-commit) instead of their sum, and the
         watchdog's monitored wait only ever covers device time."""
         sched = self.scheduler
         demand = sched.demand
         n_active = len(sched.running)
         t0 = time.perf_counter()
-        with self._span("engine.decode"):
-            launched = self._launch_decode()
-        ok = self._commit_inflight()
+        # the first tokens of the prefills behind the launch before come
+        # to the host BEFORE this launch: it is the second to run over
+        # their slots and scatters only its own input into the history
+        ok = self._commit_joined(self._inflight)
+        launched = None
+        if ok:
+            with self._span("engine.decode"):
+                launched = self._launch_decode()
+            if launched is None:
+                # no slot takes another token: nothing to commit behind
+                ok = self._drain_joined()
+        ok = ok and self._commit_inflight()
         if not ok:
             # destructive recovery fired mid-window: the launch just
             # issued consumed suspect pool/token state — discard it too
@@ -1096,7 +1139,11 @@ class LLMEngine:
             if req.state != RUNNING or req.slot is None:
                 continue
             rec = pending.get(req.slot)
-            in_flight = rec is not None and rec[0] is req
+            # uncommitted tokens: the launch before's, and the one of a
+            # prefill dispatched at this boundary
+            first = self._joined.get(req.slot)
+            in_flight = (rec is not None and rec[0] is req) \
+                + (first is not None and first[0] is req)
             if (not req.chew
                     and len(req.generated) + in_flight
                     >= req.max_new_tokens):
@@ -1133,7 +1180,9 @@ class LLMEngine:
                 # this launch's result
                 records[slot] = (req, req.cached_len, req.admit_seq)
                 self._override[slot] = False
-        return {"res": res, "records": records}
+        joined, self._joined = self._joined, {}
+        return {"res": res, "records": records, "joined": joined,
+                "firsts": self._firsts}
 
     def _commit_inflight(self):
         """Commit the PREVIOUS launch: monitored wait, then stream its
@@ -1146,6 +1195,10 @@ class LLMEngine:
         inf, self._inflight = self._inflight, None
         if inf is None:
             return True
+        # at a drain point the prefills queued before the launch are
+        # still uncommitted: their tokens come first
+        if not self._commit_joined(inf):
+            return False
         with self._span("engine.decode"):
             out = self._await_launch(inf)
         if out is None:
@@ -1186,27 +1239,8 @@ class LLMEngine:
                                      "phase": "commit",
                                      "active": len(
                                          self.scheduler.running)})
-                consumed = self._pools_consumed()
-                if attempt >= 2 or consumed:
-                    # a wedged device holds BOTH outstanding launches —
-                    # rungs 1-2 of the serial ladder cannot replay a
-                    # window whose successor already consumed it, so the
-                    # pipelined ladder goes straight to fail-active
-                    self._degrade("step_hang",
-                                  {"rung": "fail_active",
-                                   "phase": "commit",
-                                   "pools_consumed": consumed})
-                    self._discard_records(inf)
-                    for req in list(self.scheduler.running):
-                        self._fail(req, "step_hang")
-                    self._reset_pipeline()
-                    if consumed:
-                        self._reset_kv_state()
-                    self._compile_grace_ns = time.perf_counter_ns()
-                    self._decode_fn = self._build_decode(use_aot=False)
+                if not self._retry_commit(inf, attempt, "commit"):
                     return None
-                self._degrade("step_hang", {"rung": "retry",
-                                            "phase": "commit"})
                 attempt += 1
             except jax.errors.JaxRuntimeError as e:
                 self._degrade("decode_fault",
@@ -1227,9 +1261,113 @@ class LLMEngine:
             return (np.asarray(res[0]), np.asarray(res[1]),
                     np.asarray(res[2]), np.asarray(res[3]))
 
+    def _retry_commit(self, inf, attempt, phase):
+        """A commit's wait hung. True: wait once more. False: the last
+        rung was taken. A wedged device holds every outstanding program,
+        and rungs 1-2 of the serial ladder cannot replay one whose
+        successor already consumed its pools, so the ladder of a commit
+        goes from one retry straight to fail-active."""
+        consumed = self._pools_consumed()
+        if attempt < 2 and not consumed:
+            self._degrade("step_hang", {"rung": "retry", "phase": phase})
+            return True
+        self._degrade("step_hang", {"rung": "fail_active", "phase": phase,
+                                    "pools_consumed": consumed})
+        self._discard_records(inf)
+        if self._inflight is not None and self._inflight is not inf:
+            self._discard_records(self._inflight)
+        for req in list(self.scheduler.running):
+            self._fail(req, "step_hang")
+        self._reset_pipeline()
+        if consumed:
+            self._reset_kv_state()
+        self._compile_grace_ns = time.perf_counter_ns()
+        self._decode_fn = self._build_decode(use_aot=False)
+        return False
+
+    def _commit_joined(self, inf, programs=None):
+        """Commit the first tokens of the prefills a launch record was
+        queued behind (`inf["joined"]`, emptied here): one monitored wait
+        and ONE fetch for all of them, then each through the normal
+        emission path, unless its request left the slot meanwhile
+        (`commit_lag_rollback`). `programs` is what the device's queue
+        holds up to the last of them. Returns False when the wait hung
+        and the ladder retired the batch."""
+        joined = inf and inf["joined"]
+        if not joined:
+            return True
+        attempt = 1
+        with self._span("engine.prefill.commit"):
+            while True:
+                try:
+                    with self._span("engine.prefill.wait"):
+                        self._monitor.wait(
+                            (inf["firsts"],), "prefill", attempt,
+                            programs=programs or len(joined))
+                        # with the watchdog disarmed THIS is where the
+                        # host waits for the programs
+                        firsts = np.asarray(inf["firsts"])
+                    break
+                except StepHang:
+                    self._stats.hangs += 1
+                    self._note_hang()
+                    if _metrics_on():
+                        # prefill time is not measured as a productive
+                        # step: no later interval to subtract from
+                        _goodput.ACCOUNTANT.drop_stall_carry()
+                    _EVENTS.emit("serve.hang", "engine",
+                                 reason="step_hang",
+                                 detail={"phase": "prefill",
+                                         "attempt": attempt,
+                                         "prefills": len(joined)})
+                    if not self._retry_commit(inf, attempt, "prefill"):
+                        return False
+                    attempt += 1
+            inf["joined"] = {}
+            topk = self._logprobs_topk
+            for slot, (req, pos, aseq) in joined.items():
+                ints = firsts[slot]
+                floats = ints.view(np.float32)
+                if self._counter_names:
+                    self._stats.count_model("prefill", self._counter_names,
+                                            ints[2 + 2 * topk:])
+                if (req.state != RUNNING or req.slot != slot
+                        or req.admit_seq != aseq):
+                    self._rollback(req, slot)
+                    continue
+                tok = int(ints[0])
+                self._tokens[slot] = tok
+                if pos < self.max_context:
+                    self._history[slot, pos] = tok
+                self._emit_token(
+                    req, tok, logp=float(floats[1]),
+                    alts=((ints[2:2 + topk], floats[2 + topk:2 + 2 * topk])
+                          if topk else None))
+        return True
+
+    def _drain_joined(self):
+        """Synchronously commit the prefills dispatched since the last
+        launch (a bucket's first call, the serial loop's boundary, a
+        boundary nothing launches behind, a drain point): no launch has
+        fed on their tokens, so the host's copy stands for the slot."""
+        joined, self._joined = self._joined, {}
+        if not joined:
+            return True
+        inf = self._inflight
+        ahead = 0 if inf is None else 1 + len(inf["joined"])
+        ok = self._commit_joined(
+            {"records": {}, "joined": joined, "firsts": self._firsts},
+            programs=len(joined) + ahead)
+        if ok:
+            for slot, (req, _pos, aseq) in joined.items():
+                if req.slot == slot and req.admit_seq == aseq:
+                    self._override[slot] = True
+        return ok
+
     def _discard_records(self, inf):
-        for slot, (req, _pos, _aseq) in inf["records"].items():
-            self._rollback(req, slot)
+        for records in (inf["joined"], inf["records"]):
+            for slot, (req, _pos, _aseq) in records.items():
+                self._rollback(req, slot)
 
     def _rollback(self, req, slot):
         """One speculative token discarded at the lag-1 boundary."""
@@ -1242,18 +1380,23 @@ class LLMEngine:
 
     def _flush_inflight(self):
         """Synchronously commit (or roll back) the pending pipelined
-        launch. Drain points — an idle boundary, the weight-swap
+        launch and every prefill whose token is uncommitted. Drain
+        points — an idle boundary, the weight-swap
         cutover, explicit drains — must not leave a speculative token in
         flight. After the flush the host token mirror is authoritative
         for every slot. No-op when nothing is pending (including the
         unpipelined engine)."""
         if self._inflight is not None:
             self._commit_inflight()
+        self._drain_joined()
         self._feedback = None
         self._override[:] = True
 
     def _reset_pipeline(self):
         self._inflight = None
+        for slot, (req, _pos, _aseq) in self._joined.items():
+            self._rollback(req, slot)
+        self._joined = {}
         self._feedback = None
         self._override[:] = True
 
@@ -1324,7 +1467,9 @@ class LLMEngine:
         case) into the request's freshly assigned blocks, then join the
         decode batch. Never touches the decode executable. A prefix-hit
         admission (try_admit aliased cached blocks) skips the prefill
-        entirely."""
+        entirely. The prefill is dispatched and NOT awaited: its token
+        feeds the next decode launch on the device and is committed from
+        the record in `_joined` (`_commit_joined`)."""
         ctx = req.prompt + req.generated
         if req.prefix_hit > 0:
             self._admit_prefix_hit(req, ctx)
@@ -1362,14 +1507,17 @@ class LLMEngine:
             padded[0, :len(ctx)] = ctx
             row = np.zeros(self.max_blocks_per_seq, np.int32)
             row[:len(req.blocks)] = req.blocks
-            res = self._prefill_step(fn, padded, np.int32(len(ctx)), row,
-                                     req, first=new_bucket)
-            if res is None:
-                return            # watchdog failed the request, slot is clear
-            nxt, logp, aids, alps = res[0], res[1], res[2], res[3]
-            self._k_pools, self._v_pools = res[4], res[5]
+            # launched, not awaited: what the prefill sampled stays on
+            # the device as the slot's next decode input, and nothing of
+            # its results is touched here
+            res = self._call_program(
+                "engine.prefill.dispatch", fn,
+                self._prefill_args(padded, np.int32(len(ctx)), row, req),
+                new_bucket)
+            self._feedback, self._firsts = res[0], res[1]
+            self._k_pools, self._v_pools = res[2], res[3]
             if self._kv_quantized:
-                self._k_scales, self._v_scales = res[6], res[7]
+                self._k_scales, self._v_scales = res[4], res[5]
             req.cached_len = len(ctx)
             self._sync_slot(req)
             self._set_adapter_slot(req)
@@ -1379,17 +1527,13 @@ class LLMEngine:
                 # must never be served as prompt KV
                 self._prefix.publish(ctx, req.blocks,
                                      include_tail=not req.generated)
-            tok = int(np.asarray(nxt))
             self._stats.prefill_tokens += len(ctx)
-            self._count_model("prefill", res)
-            # the prefill's sampled token is the next decode step's input
-            self._tokens[req.slot] = tok
-            if req.cached_len < self.max_context:
-                self._history[req.slot, req.cached_len] = tok
-            self._override[req.slot] = True
-            self._emit_token(req, tok, logp=float(np.asarray(logp)),
-                             alts=((aids, alps) if self._logprobs_topk
-                                   else None))
+            self._override[req.slot] = False
+            self._joined[req.slot] = (req, len(ctx), req.admit_seq)
+        if new_bucket:
+            # a bucket's first call traced and compiled: the host has
+            # waited for it already, so it is committed where it stands
+            self._drain_joined()
 
     def _admit_prefix_hit(self, req, ctx):
         """Prefix-hit admission: the aliased blocks already hold the
@@ -1449,68 +1593,28 @@ class LLMEngine:
                 _M.adapter_switches.inc()
         self._aslots[req.slot] = idx
 
-    def _prefill_step(self, fn, padded, length, row, req, first=False):
-        """One monitored prefill fire; `first` marks the program's first
-        call. The ladder is per-request (a hung prefill only has one
-        tenant): retry once, then fail the request with `step_hang` — the
-        decode batch never waits on it."""
-        attempt = 1
-        while True:
-            try:
-                base = (padded, length, row)
-                if self._tenant:
-                    base = base + (self._prefill_aux(req),)
-                # the admitted request's sampler config rides as scalar
-                # VALUES — a new config never re-keys the bucket program
-                base = base + (np.float32(req.temperature),
-                               np.int32(req.top_k),
-                               np.float32(req.top_p),
-                               np.float32(req.repetition_penalty),
-                               np.uint32(req.seed or 0))
-                res = self._call_program(
-                    "engine.prefill.dispatch", fn,
-                    self._kv_args(*(base + (self._k_pools,
-                                            self._v_pools))),
-                    first and attempt == 1)
-                with self._span("engine.prefill.wait"):
-                    # behind an uncommitted decode launch the prefill
-                    # waits for that program too: two steps' budget, or a
-                    # healthy prefill reads as hung
-                    self._monitor.wait(
-                        res, "prefill", attempt,
-                        programs=1 + (self._inflight is not None))
-                    # the sampled token on the host (kept on the array for
-                    # `_admit`): with the watchdog disarmed THIS is where
-                    # the host waits for the program
-                    np.asarray(res[0])
-                return res
-            except StepHang:
-                self._stats.hangs += 1
-                self._note_hang()
-                if _metrics_on():
-                    # prefill time is not measured as a productive step,
-                    # so there is no later interval to subtract from
-                    _goodput.ACCOUNTANT.drop_stall_carry()
-                _EVENTS.emit("serve.hang", req.rid, reason="step_hang",
-                             detail={"phase": "prefill",
-                                     "attempt": attempt})
-                consumed = self._pools_consumed()
-                if attempt >= 2 or consumed:
-                    self._degrade("step_hang",
-                                  {"rung": "fail_request",
-                                   "phase": "prefill", "rid": req.rid,
-                                   "pools_consumed": consumed})
-                    self._fail(req, "step_hang")
-                    if consumed:
-                        surviving = list(self.scheduler.running)
-                        for r in surviving:
-                            # their KV lived in the consumed pools
-                            self._evict(r)
-                        self._reset_kv_state()
-                    return None
-                self._degrade("step_hang", {"rung": "retry",
-                                            "phase": "prefill"})
-                attempt += 1
+    def _empty_firsts(self):
+        """A row a slot for what a prefill hands the host: token,
+        logprob, the panel's ids and logprobs, the model's counters."""
+        width = 2 + 2 * self._logprobs_topk + len(self._counter_names)
+        return jnp.zeros((self.max_batch_size, width), jnp.int32)
+
+    def _prefill_args(self, padded, length, row, req):
+        """A prefill program's positional arguments. The admitted
+        request's sampler config rides as scalar VALUES (a new config
+        never re-keys the bucket program); `feedback` and `firsts` are
+        threaded through the program as the pools are, and not donated:
+        the commit of the launch before still reads them."""
+        base = (padded, length, row)
+        if self._tenant:
+            base = base + (self._prefill_aux(req),)
+        feedback = self._tokens.copy() if self._feedback is None \
+            else self._feedback
+        return self._kv_args(*(base + (
+            np.float32(req.temperature), np.int32(req.top_k),
+            np.float32(req.top_p), np.float32(req.repetition_penalty),
+            np.uint32(req.seed or 0), np.int32(req.slot), feedback,
+            self._firsts, self._k_pools, self._v_pools)))
 
     def _kv_args(self, *base):
         """Positional args for the compiled decode/prefill programs:
@@ -1730,6 +1834,7 @@ class LLMEngine:
         stats = self._stats
         stats.launches += 1
         stats.launches_overlapped += self._inflight is not None
+        stats.prefills_unawaited += len(self._joined)
         stats.decode_tokens += int(np.count_nonzero(args[self._ARG_ACTIVE]))
         res = self._call_program("engine.decode.dispatch", fn, args,
                                  fn is not self._decode_called)
@@ -1900,6 +2005,8 @@ class LLMEngine:
         self._inflight = None
         self._feedback = None
         self._override = np.ones(s, bool)
+        self._firsts = self._empty_firsts()
+        self._joined = {}
         self._k_pools = self.cache.k_pools
         self._v_pools = self.cache.v_pools
         self._k_scales = self.cache.k_scales
@@ -2239,62 +2346,86 @@ class LLMEngine:
         donate = (13, 14, 15, 16) if self._kv_quantized else (13, 14)
         return jax.jit(decode, donate_argnums=self._donate(donate))
 
+    def _prefill_results(self, ids, length, block_row, sampler, slot,
+                         feedback, firsts, k_pools, v_pools, k_scales,
+                         v_scales, logits, caches, extra):
+        """What both prefill programs do behind the model's forward,
+        inside their trace: the prompt's KV into the pools, the first
+        token sampled, and the token handed on WHERE IT IS: into
+        `feedback` at the request's slot (the next decode launch's
+        input) and, with its logprob, its panel and the model's counters,
+        as one int32 row of `firsts` (floats by their bits), which the
+        host fetches once for all of a boundary's prefills. Returns
+        ``(feedback, firsts) + the written pools``."""
+        k_layers = jnp.stack([c[0]._value[0] for c in caches])
+        v_layers = jnp.stack([c[1]._value[0] for c in caches])
+        written = scatter_prefill(
+            k_pools, v_pools, k_layers, v_layers, block_row, length,
+            self.block_size, k_scales=k_scales, v_scales=v_scales)
+        last = jax.lax.dynamic_index_in_dim(
+            logits._value[0], length - 1, axis=0, keepdims=False)
+        # the prompt's first sampled token: position = prompt length
+        # (the count of known context tokens), same convention as the
+        # decode head — replays land on the same fold_in stream
+        valid = (jnp.arange(ids.shape[1], dtype=jnp.int32)
+                 < length)[None, :]
+        nxt, logp, alt_ids, alt_lps = sample_tokens(
+            last[None, :], *(jnp.reshape(v, (1,)) for v in sampler),
+            jnp.reshape(length, (1,)), ids.astype(jnp.int32), valid,
+            logprobs_topk=self._logprobs_topk)
+
+        return self._hand_on(feedback, firsts, slot, nxt, logp, alt_ids,
+                             alt_lps, extra) + tuple(written)
+
+    @staticmethod
+    def _hand_on(feedback, firsts, slot, nxt, logp, alt_ids, alt_lps,
+                 extra=()):
+        """`feedback` with the sampled token at `slot`, and `firsts`
+        with the slot's row: token, logprob, the panel's ids and
+        logprobs, the model's int32 counters (floats by their bits)."""
+        def bits(x):
+            return jax.lax.bitcast_convert_type(
+                x.astype(jnp.float32), jnp.int32)
+        row = jnp.concatenate(
+            [nxt, bits(logp), alt_ids[0], bits(alt_lps[0])]
+            + [jnp.reshape(c, (-1,)) for c in extra]).astype(jnp.int32)
+        return (feedback.at[slot].set(nxt[0].astype(feedback.dtype)),
+                firsts.at[slot].set(row))
+
     def _build_prefill(self, bucket):
         if self._tenant:
             return self._build_prefill_tenant(bucket)
-        model = self._model
         spec = self.cache.spec
-        block_size = self.block_size
-        params = model.parameters()
+        params = self._model.parameters()
         dt = params[0]._value.dtype if params else jnp.float32
         stats = self._stats
-        lp_topk = self._logprobs_topk
 
         def prefill(ids, length, block_row, temp, topk, topp, rpen,
-                    seedv, k_pools, v_pools,
+                    seedv, slot, feedback, firsts, k_pools, v_pools,
                     k_scales=None, v_scales=None):
             stats.prefill_compiles += 1   # runs only while tracing
             logits, caches, extra = self._forward(
                 ids, spec.empty_prefill(dt), length)
-            k_layers = jnp.stack([c[0]._value[0] for c in caches])
-            v_layers = jnp.stack([c[1]._value[0] for c in caches])
-            written = scatter_prefill(
-                k_pools, v_pools, k_layers, v_layers, block_row, length,
-                block_size, k_scales=k_scales, v_scales=v_scales)
-            last = jax.lax.dynamic_index_in_dim(
-                logits._value[0], length - 1, axis=0, keepdims=False)
-            # the prompt's first sampled token: position = prompt length
-            # (the count of known context tokens), same convention as the
-            # decode head — replays land on the same fold_in stream
-            valid = (jnp.arange(ids.shape[1], dtype=jnp.int32)
-                     < length)[None, :]
-            nxt, logp, alt_ids, alt_lps = sample_tokens(
-                last[None, :], jnp.reshape(temp, (1,)),
-                jnp.reshape(topk, (1,)), jnp.reshape(topp, (1,)),
-                jnp.reshape(rpen, (1,)), jnp.reshape(seedv, (1,)),
-                jnp.reshape(length, (1,)), ids.astype(jnp.int32), valid,
-                logprobs_topk=lp_topk)
-            return (nxt[0], logp[0], alt_ids[0], alt_lps[0]) \
-                + tuple(written) + extra
+            return self._prefill_results(
+                ids, length, block_row, (temp, topk, topp, rpen, seedv),
+                slot, feedback, firsts, k_pools, v_pools, k_scales,
+                v_scales, logits, caches, extra)
 
-        donate = (8, 9, 10, 11) if self._kv_quantized else (8, 9)
+        donate = (11, 12, 13, 14) if self._kv_quantized else (11, 12)
         return jax.jit(prefill, donate_argnums=self._donate(donate))
 
     def _build_prefill_tenant(self, bucket):
         """Tenant twin of `_build_prefill`: the same bucketed prompt
         program with the aux pytree (weights as values in hot-swap mode;
         the one admitted request's scalar adapter slot)."""
-        model = self._model
         spec = self.cache.spec
-        block_size = self.block_size
-        params = model.parameters()
+        params = self._model.parameters()
         dt = params[0]._value.dtype if params else jnp.float32
         stats = self._stats
         holder = self._holder
-        lp_topk = self._logprobs_topk
 
         def prefill(ids, length, block_row, aux, temp, topk, topp, rpen,
-                    seedv, k_pools, v_pools,
+                    seedv, slot, feedback, firsts, k_pools, v_pools,
                     k_scales=None, v_scales=None):
             stats.prefill_compiles += 1   # runs only while tracing
             pvals = aux.get("params")
@@ -2315,25 +2446,12 @@ class LLMEngine:
                         pp._value = vv
                 if holder is not None:
                     holder["active"] = None
-            k_layers = jnp.stack([c[0]._value[0] for c in caches])
-            v_layers = jnp.stack([c[1]._value[0] for c in caches])
-            written = scatter_prefill(
-                k_pools, v_pools, k_layers, v_layers, block_row, length,
-                block_size, k_scales=k_scales, v_scales=v_scales)
-            last = jax.lax.dynamic_index_in_dim(
-                logits._value[0], length - 1, axis=0, keepdims=False)
-            valid = (jnp.arange(ids.shape[1], dtype=jnp.int32)
-                     < length)[None, :]
-            nxt, logp, alt_ids, alt_lps = sample_tokens(
-                last[None, :], jnp.reshape(temp, (1,)),
-                jnp.reshape(topk, (1,)), jnp.reshape(topp, (1,)),
-                jnp.reshape(rpen, (1,)), jnp.reshape(seedv, (1,)),
-                jnp.reshape(length, (1,)), ids.astype(jnp.int32), valid,
-                logprobs_topk=lp_topk)
-            return (nxt[0], logp[0], alt_ids[0], alt_lps[0]) \
-                + tuple(written) + extra
+            return self._prefill_results(
+                ids, length, block_row, (temp, topk, topp, rpen, seedv),
+                slot, feedback, firsts, k_pools, v_pools, k_scales,
+                v_scales, logits, caches, extra)
 
-        donate = (9, 10, 11, 12) if self._kv_quantized else (9, 10)
+        donate = (12, 13, 14, 15) if self._kv_quantized else (12, 13)
         return jax.jit(prefill, donate_argnums=self._donate(donate))
 
     # ------------------------------------------------------------------

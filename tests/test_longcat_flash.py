@@ -410,6 +410,23 @@ def test_the_engines_stats_carry_the_counters_by_phase(model):
     assert "decode_counted" not in engine.stats()
 
 
+def test_a_launched_prefills_counters_come_with_its_first_token(model):
+    """The pipelined loop launches a prefill and reads its counters at
+    the commit, from the row that brings the first token: the window's
+    `prefill_*` counts are the serial loop's, and four of the six
+    prefills (two were their bucket's first call) were never awaited."""
+    piped, _, served = serve(model, True)
+    serial, _, same = serve(model, False)
+    assert served == same
+    a, b = piped.stats(), serial.stats()
+    keys = [k for k in b if k.startswith("prefill_")
+            and isinstance(b[k], int)]          # counts, not clock shares
+    assert "prefill_routed_held" in keys and "prefill_counted" in keys
+    assert {k: a[k] for k in keys} == {k: b[k] for k in keys}
+    assert a["prefill_unawaited_share"] == pytest.approx(4 / 6)
+    assert b["prefill_unawaited_share"] == 0.0
+
+
 # -- (e) the seams in the engine ----------------------------------------------
 
 @pytest.mark.parametrize("option,named", [

@@ -31,7 +31,8 @@ ENGINE_PARENTS = {
     "engine.admit": "engine.step",
     "engine.prefill": "engine.admit",
     "engine.prefill.dispatch": "engine.prefill",
-    "engine.prefill.wait": "engine.prefill",
+    "engine.prefill.commit": "engine.step",
+    "engine.prefill.wait": "engine.prefill.commit",
     "engine.compile": "engine.step",
     "engine.kv_grow": "engine.step",
     "engine.decode": "engine.step",
@@ -100,6 +101,8 @@ def traced(tmp_path_factory):
             engine.generate(PROMPTS, max_new_tokens=4)
         with jax.profiler.TraceAnnotation("caller.pipelined"):
             piped.generate(PROMPTS, max_new_tokens=4)
+        with jax.profiler.TraceAnnotation("caller.warm"):
+            piped.generate(PROMPTS, max_new_tokens=4)
     finally:
         jax.profiler.stop_trace()
     lines, files = host_spans(out)
@@ -147,6 +150,45 @@ def test_the_pipelined_tail_opens_the_same_spans(traced):
     names = {n for events in lines.values() for n, a, b in events
              if window[1] <= a and b <= window[2]}
     assert {n for n in ENGINE_PARENTS} <= names
+
+
+def _inside(lines, name, window):
+    return [f for f in _named(lines, name)
+            if f[0] == window[0] and window[1] <= f[1] and f[2] <= window[2]]
+
+
+def test_a_prefill_is_dispatched_and_its_wait_sits_under_the_commit(traced):
+    """A prefill's span holds its dispatch and no wait: the wait for a
+    boundary's prefills is the commit's. In the pipelined loop, the
+    buckets warm, that commit lies past the admission and the table
+    growth of the NEXT step, before that step's decode launch."""
+    lines = traced["lines"]
+    for outer in ("caller.window", "caller.pipelined", "caller.warm"):
+        window = _named(lines, outer)[0]
+        prefills = _inside(lines, "engine.prefill", window)
+        assert len(prefills) == 3
+        for prefill in prefills:
+            assert len(_inside(lines, "engine.prefill.dispatch",
+                               prefill)) == 1
+            assert not _inside(lines, "engine.prefill.wait", prefill)
+        waits = _inside(lines, "engine.prefill.wait", window)
+        assert waits and all(
+            any(c[1] <= w[1] and w[2] <= c[2]
+                for c in _inside(lines, "engine.prefill.commit", window))
+            for w in waits)
+    warm = _named(lines, "caller.warm")[0]
+    steps = sorted(_inside(lines, "engine.step", warm), key=lambda s: s[1])
+    [commit] = _inside(lines, "engine.prefill.commit", warm)
+    # three prefills behind one launch, one commit, a step later
+    assert _inside(lines, "engine.prefill", steps[0]) \
+        and not _inside(lines, "engine.prefill.commit", steps[0])
+    assert _inside(lines, "engine.prefill.commit", steps[1]) == [commit]
+    assert not _inside(lines, "engine.admit", commit) \
+        and not any(a[1] <= commit[1] and commit[2] <= a[2]
+                    for a in _inside(lines, "engine.admit", steps[1]))
+    [grow] = _inside(lines, "engine.kv_grow", steps[1])
+    [launch] = _inside(lines, "engine.decode.dispatch", steps[1])
+    assert grow[2] <= commit[1] and commit[2] <= launch[1]
 
 
 def test_train_step_stats_count_every_call_and_only_the_contract_keys():
@@ -357,6 +399,7 @@ def test_sampling_scatter_head_and_loss_are_scoped():
         np.zeros((1, 8), np.int32), np.int32(5),
         np.zeros(engine.max_blocks_per_seq, np.int32), np.float32(0),
         np.int32(0), np.float32(1), np.float32(1), np.uint32(0),
+        np.int32(0), engine._tokens, engine._firsts,
         engine._k_pools, engine._v_pools))
     assert "scatter_prefill" in prefill.as_text(debug_info=True)
     from paddle_tpu.incubate.models import GPTPretrainingCriterion

@@ -364,12 +364,13 @@ class TestBacklog:
         assert st["prefill_compiles"] == 3         # buckets 8, 16, 32
 
     def test_a_joined_slot_decodes_from_its_prefill_token(self, model):
-        """The pipelined launch feeds a slot from the launch before it,
-        on the device, unless the host wrote the slot's token. A slot
-        that changed tenants holds the NEW tenant's prefill token, marked
-        as the host's: never what the launch before sampled there for the
-        tenant that left."""
-        tenants, joined, fed = {}, [], []
+        """The pipelined launch feeds a slot from the device unless the
+        host wrote the slot's token. A slot that changed tenants is fed
+        the NEW tenant's prefill token, which the host has not seen when
+        the launch is dispatched (a bucket's first call excepted: that
+        one was committed where it stood, and is the host's): never what
+        the launch before sampled there for the tenant that left."""
+        tenants, joined, fed, awaited = {}, [], [], []
 
         def on_launch(engine, args):
             tokens, feedback, override = args[:3]
@@ -385,18 +386,27 @@ class TestBacklog:
                     fed.append(slot)
                     continue
                 tenants[slot] = req
-                assert req.generated == [int(tokens[slot])]
-                assert override[slot]
-                joined.append(slot)
+                if override[slot]:
+                    assert req.generated == [int(tokens[slot])]
+                    awaited.append(slot)
+                else:
+                    assert req.generated == []
+                    joined.append((req, slot,
+                                   int(np.asarray(feedback)[slot])))
 
         requests, _, engine = backlog.drive(
             model, VOCAB, self.STEPS, on_launch=on_launch,
             pipeline_decode=True)
-        assert len(joined) == len(requests) >= 80
-        assert set(joined) == set(range(backlog.SLOTS))
+        assert len(awaited) == 3                   # buckets 8, 16, 32
+        assert len(joined) + len(awaited) == len(requests) >= 80
+        assert {slot for _, slot, _ in joined} == set(range(backlog.SLOTS))
+        assert all(r.generated[0] == tok for r, _, tok in joined)
         assert len(fed) >= len(joined)
         for r in requests[:24]:
             assert r.generated == _ref(model, r.prompt, r.max_new_tokens)
+        st = engine.stats()
+        assert st["prefill_unawaited_share"] == len(joined) / len(requests)
+        assert st["commit_rollbacks"] == 0
 
     def test_launches_overlap_the_commit_before_them(self, model):
         """`pipelined_launch_share`: launches issued while the launch
@@ -427,6 +437,243 @@ class TestBacklog:
         assert engine._stats.launches_overlapped == st["steps"] == 12
         engine.run()
         assert engine.stats()["commit_rollbacks"] == 0
+
+
+class _NumpySpy:
+    """numpy, as the engine's module sees it, telling `on_fetch` of every
+    `asarray`: the one call by which the engine brings a device array to
+    the host."""
+
+    def __init__(self, on_fetch):
+        self._on_fetch = on_fetch
+
+    def __getattr__(self, name):
+        return getattr(np, name)
+
+    def asarray(self, a, *args, **kw):
+        self._on_fetch(a)
+        return np.asarray(a, *args, **kw)
+
+
+class TestPrefillLaunchedNotAwaited:
+    """A prefill is dispatched and not awaited: its token feeds the
+    decode launch of the admitting step on the device and is committed a
+    step later, from one fetch for all of a boundary's prefills."""
+
+    PENALISED = (dict(),
+                 dict(temperature=0.8, top_k=6, repetition_penalty=4.0,
+                      seed=900),
+                 dict(repetition_penalty=1.5),
+                 dict(temperature=1.1, top_p=0.9, repetition_penalty=2.5,
+                      seed=901))
+
+    @pytest.mark.parametrize("mix", ["greedy", "seeded"])
+    def test_staggered_admission_serves_the_same_streams(self, model, mix):
+        """Two to four prefills at every boundary: the pipelined loop,
+        the serial loop and (greedy) `model.generate` agree token for
+        token, under a repetition penalty too, whose history must hold
+        the first token by the second launch over the slot."""
+        samplers = (dict(),) if mix == "greedy" else self.PENALISED
+        streams = {}
+        for piped in (False, True):
+            requests, boundaries, eng = backlog.drive(
+                model, VOCAB, 40, samplers=samplers, pipeline_decode=piped)
+            backlog.assert_steady(boundaries, at_least=32)
+            assert all(2 <= joined <= 4 for joined, _, _ in boundaries[4:])
+            st = eng.stats()
+            assert st["commit_rollbacks"] == 0
+            assert st["decode_compiles"] == 1
+            assert st["prefill_compiles"] == 3
+            assert (st["prefill_unawaited_share"] > 0.9) == piped
+            streams[piped] = requests
+        n = min(len(streams[False]), len(streams[True]))
+        assert n >= 80
+        for a, b in zip(streams[False][:n], streams[True][:n]):
+            assert a.generated == b.generated
+            if a.temperature == 0:
+                assert b.generated == _ref(model, b.prompt,
+                                           b.max_new_tokens)
+        if mix == "seeded":
+            assert sum(r.temperature > 0 for r in streams[True]) >= 40
+
+    @staticmethod
+    def _warm(model, slots):
+        """An engine whose prefill buckets 8 and 16 and decode program
+        have run, and a neighbour stream that keeps its launches going."""
+        engine = LLMEngine(model, max_batch_size=slots, block_size=4)
+        engine.generate([_prompt(5, seed=80), _prompt(9, seed=80)],
+                        max_new_tokens=2)
+        engine.reset_stats()
+        return engine
+
+    @pytest.mark.parametrize("case", [
+        "first_token_eos", "max_new_tokens_1", "max_new_tokens_1_alone",
+        "cancel", "expiry", "preempt_resume", "refilled_slot"])
+    def test_a_stream_that_leaves_before_its_first_commit(self, model, case):
+        """Between a prefill's dispatch and the commit of its token lies
+        a decode launch that fed on it. Whatever takes the stream away in
+        between costs it that one speculative token (and, where the first
+        token was never delivered, that one too); nobody else's stream
+        moves."""
+        slots = 1 if case in ("refilled_slot",
+                              "max_new_tokens_1_alone") else 3
+        engine = self._warm(model, slots)
+        near = _prompt(10, seed=81)
+        neighbour = None
+        if slots > 1:
+            neighbour = engine.add_request(near, max_new_tokens=9)
+            engine.step()
+        prompt = _prompt(7, seed=82)
+        ref = _ref(model, prompt, 6)
+        kw = {"first_token_eos": dict(max_new_tokens=6,
+                                      eos_token_id=ref[0]),
+              "max_new_tokens_1": dict(max_new_tokens=1),
+              "max_new_tokens_1_alone": dict(max_new_tokens=1),
+              "expiry": dict(max_new_tokens=6, ttl_s=600.0)}.get(
+                  case, dict(max_new_tokens=6))
+        req = engine.add_request(prompt, **kw)
+        engine.step()                 # admitted, launched over, uncommitted
+        if case == "max_new_tokens_1_alone":
+            # nothing could be launched behind it: committed on the spot
+            assert req.state == FINISHED
+        else:
+            assert req.state == RUNNING and req.generated == []
+        want, rollbacks, state = [], 2, None
+        if case == "first_token_eos":
+            want, rollbacks, state = ref[:1], 1, FINISHED
+        elif case.startswith("max_new_tokens_1"):
+            # its slot was held and not launched: no token to lose
+            want, rollbacks, state = ref[:1], 0, FINISHED
+        elif case == "cancel":
+            assert engine.cancel(req.rid)
+            state = CANCELLED
+        elif case == "expiry":
+            req.deadline_ns = 0
+            state = EXPIRED
+        elif case == "preempt_resume":
+            engine._evict(req)        # what KV growth does to the newest
+            want, state = ref, FINISHED
+        elif case == "refilled_slot":
+            assert engine.cancel(req.rid)
+            other = _prompt(6, seed=83)
+            heir = engine.add_request(other, max_new_tokens=5)
+        engine.run()
+        st = engine.stats()
+        if case == "refilled_slot":
+            assert heir.generated == _ref(model, other, 5)
+            state = CANCELLED
+        assert req.state == state
+        assert req.generated == want
+        assert st["commit_rollbacks"] == rollbacks
+        assert st["decode_compiles"] == 0 and st["prefill_compiles"] == 0
+        if neighbour is not None:
+            assert neighbour.generated == _ref(model, near, 9)
+
+    def test_no_result_of_a_prefill_is_touched_before_the_launch(
+            self, model, loop, monkeypatch):
+        """Between a boundary's prefill dispatches and the decode launch
+        behind them the host neither waits for nor fetches anything those
+        prefills return (`prefill_unawaited_share` 1.0); the serial loop
+        fetches them once, after the last dispatch and before its launch
+        (0.0). The share is windowed by `reset_stats()`."""
+        import paddle_tpu.serving.engine as engine_mod
+        engine = LLMEngine(model, max_batch_size=4, block_size=4,
+                           pipeline_decode=loop)
+        engine.generate([_prompt(5, seed=84), _prompt(9, seed=84)],
+                        max_new_tokens=2)           # buckets 8, 16 + decode
+        assert engine.stats()["prefill_unawaited_share"] == 0.0
+        engine.reset_stats()
+        events, fresh = [], []
+        call, wait = engine._call_program, engine._monitor.wait
+
+        def spy_call(name, fn, args, first):
+            res = call(name, fn, args, first)
+            if name == "engine.prefill.dispatch":
+                fresh.extend(res)
+            events.append((name, None))
+            return res
+
+        def touched(kind, arrays):
+            events.append((kind, any(a is f for a in arrays
+                                     for f in fresh)))
+
+        def spy_wait(arrays, phase, attempt=1, programs=1):
+            touched("wait", arrays)
+            return wait(arrays, phase, attempt, programs)
+
+        engine._call_program = spy_call
+        engine._monitor.wait = spy_wait
+        monkeypatch.setattr(engine_mod, "np", _NumpySpy(
+            lambda a: touched("fetch", (a,))))
+        prompts = [_prompt(n, seed=85) for n in (6, 11, 7)]
+        reqs = [engine.add_request(p, max_new_tokens=5) for p in prompts]
+        del events[:]
+        engine.step()                   # three prefills, then the launch
+        names = [n for n, _ in events]
+        last = max(i for i, n in enumerate(names)
+                   if n == "engine.prefill.dispatch")
+        launch = names.index("engine.decode.dispatch")
+        assert names[:last + 1] == ["engine.prefill.dispatch"] * 3
+        between = [e for e in events[last + 1:launch] if e[1]]
+        if loop:
+            assert between == []
+            assert all(r.generated == [] for r in reqs)
+        else:
+            # one wait and one fetch, of the last prefill's one array
+            assert between == [("wait", True), ("fetch", True)]
+            # ... and its step commits its own launch as well
+            assert all(len(r.generated) == 2 for r in reqs)
+        engine.run()
+        monkeypatch.undo()
+        for r, p in zip(reqs, prompts):
+            assert r.generated == _ref(model, p, 5)
+        st = engine.stats()
+        assert st["prefills"] == 3
+        assert st["prefill_unawaited_share"] == (1.0 if loop else 0.0)
+        assert engine._stats.prefills_unawaited == (3 if loop else 0)
+        engine.reset_stats()
+        assert engine.stats()["prefill_unawaited_share"] == 0.0
+        assert engine._stats.prefills_unawaited == 0
+
+    def test_a_boundarys_first_tokens_come_in_one_fetch(self, model,
+                                                        monkeypatch):
+        """Three prefills at one boundary cost the host ONE fetch (token,
+        logprob, panel in one row a slot), a step later; their logprobs
+        and panels are the serial loop's."""
+        import paddle_tpu.serving.engine as engine_mod
+        prompts = [_prompt(n, seed=86) for n in (6, 11, 7)]
+        out = {}
+        for piped in (False, True):
+            engine = LLMEngine(model, max_batch_size=4, block_size=4,
+                               logprobs_topk=3, pipeline_decode=piped)
+            engine.generate([_prompt(5, seed=84), _prompt(9, seed=84)],
+                            max_new_tokens=2)
+            reqs = [engine.add_request(p, max_new_tokens=4,
+                                       temperature=0.7, seed=40 + i)
+                    for i, p in enumerate(prompts)]
+            engine.step()
+            if piped:
+                fetched, commit = [], engine._commit_joined
+
+                def counted(inf, programs=None):
+                    # what the commit of the first tokens brings to the
+                    # host: device arrays (rows of a fetched one are the
+                    # host's already)
+                    with monkeypatch.context() as patch:
+                        patch.setattr(engine_mod, "np", _NumpySpy(
+                            lambda a: isinstance(a, np.ndarray)
+                            or fetched.append(a)))
+                        return commit(inf, programs)
+
+                engine._commit_joined = counted
+                engine.step()
+                assert len(fetched) == 1
+                assert all(len(r.generated) == 2 for r in reqs)
+            engine.run()
+            out[piped] = [(r.generated, r.token_logprobs, r.alt_ids,
+                           r.alt_logprobs) for r in reqs]
+        assert out[True] == out[False]
+        assert all(len(ids[0]) == 3 for _, _, ids, _ in out[True])
 
 
 # ---------------------------------------------------------------------------
@@ -823,10 +1070,13 @@ class TestWatchdog:
 
 
     def test_a_prefill_behind_a_launch_is_given_both_budgets(self, model):
-        """The pipelined loop dispatches a boundary's prefills behind
-        the uncommitted decode launch, so the armed watchdog's wait for a
-        prefill covers two programs: it is given two budgets, and a
-        prefill with nothing ahead of it one."""
+        """The armed watchdog's wait for a prefill is the commit's, and
+        is given one budget for every program the device's queue holds
+        up to it. A bucket's first call is committed where it stands:
+        one budget with nothing ahead, two behind an uncommitted launch.
+        Warm, a boundary's prefills are awaited a step later, together:
+        as many budgets as there were prefills, and the launch they were
+        queued before is then given its own one."""
         set_flags({"FLAGS_serve_step_timeout_ms": 2000})
         engine = LLMEngine(model, max_batch_size=4, block_size=4)
         waits, wait = [], engine._monitor.wait
@@ -843,6 +1093,45 @@ class TestWatchdog:
         assert engine.stats()["hangs"] == 0
         prefills = [w for w in waits if w[0] == "prefill"]
         assert prefills == [("prefill", 1, False), ("prefill", 2, True)]
+        # both buckets warm: one request, then two at one boundary
+        del waits[:]
+        engine.add_request(_prompt(7, seed=73), max_new_tokens=6)
+        engine.step()
+        assert waits == []                  # dispatched, launched over
+        engine.add_request(_prompt(5, seed=74), max_new_tokens=3)
+        engine.add_request(_prompt(10, seed=75), max_new_tokens=3)
+        engine.step()
+        assert waits == [("prefill", 1, True), ("decode", 1, False)]
+        del waits[:]
+        engine.step()
+        assert waits == [("prefill", 2, True), ("decode", 1, False)]
+        engine.run()
+        assert engine.stats()["hangs"] == 0
+
+    def test_a_hung_prefill_found_at_the_commit_fails_the_active(self, model):
+        """A prefill that never comes back is found at its commit, a
+        step later, with later programs queued over its pools: the wait
+        is retried once, then the active requests fail with an
+        attributed reason and the engine serves new work."""
+        set_flags({"FLAGS_serve_step_timeout_ms": 2000})
+        engine = LLMEngine(model, max_batch_size=2, block_size=4)
+        engine.generate([_prompt(6, seed=76)], max_new_tokens=2)   # warm
+        doomed = engine.add_request(_prompt(7, seed=77), max_new_tokens=8)
+        engine.step()
+        assert doomed.generated == []
+        guardian.inject_fault("hang", op="serve.prefill", times=2)
+        try:
+            engine.run()
+        finally:
+            guardian.clear_faults()
+        assert doomed.state == FAILED and doomed.error == "step_hang"
+        st = engine.stats()
+        assert st["hangs"] == 2
+        assert st["commit_rollbacks"] == 2     # first token + decoded one
+        fresh = engine.add_request(_prompt(5, seed=78), max_new_tokens=4)
+        engine.run()
+        assert fresh.state == FINISHED
+        assert fresh.generated == _ref(model, _prompt(5, seed=78), 4)
 
     def test_a_wait_over_two_programs_burns_two_budgets(self):
         from paddle_tpu.serving.resilience import MonitoredWait, StepHang

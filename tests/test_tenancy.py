@@ -589,6 +589,44 @@ class TestAdapters:
 # live weight hot-swap (compiled path; fresh models — swap mutates them)
 # ---------------------------------------------------------------------------
 
+class TestTenantPrefillLaunchedNotAwaited:
+    def test_joined_tenants_first_tokens_ride_the_device(self, model):
+        """The tenant prefill program hands its token on as the plain one
+        does: with the buckets warm, tenants joining a running batch two
+        at a boundary are launched over unawaited, and serve what the
+        serial loop serves (a base tenant: what `generate` does)."""
+        prompts = [_prompt(n, seed=60 + n) for n in (6, 11, 7, 10, 5, 12)]
+        plan = [None, "t1", "t2", None, "t1", None]
+        streams = {}
+        for piped in (False, True):
+            engine = LLMEngine(model, max_batch_size=3, block_size=4,
+                               num_blocks=64, max_adapters=3,
+                               adapter_rank=2, hot_swap=True,
+                               pipeline_decode=piped)
+            engine.register_adapter("t1", seed=1, scale=25.0)
+            engine.register_adapter("t2", seed=2, scale=25.0)
+            engine.generate([_prompt(5, seed=59), _prompt(9, seed=59)],
+                            max_new_tokens=2)        # buckets 8, 16
+            engine.reset_stats()
+            reqs = []
+            for i in range(0, len(prompts), 2):
+                for p, ad in zip(prompts[i:i + 2], plan[i:i + 2]):
+                    reqs.append(engine.add_request(
+                        p, max_new_tokens=4 + i, adapter=ad))
+                engine.step()
+                engine.step()
+            engine.run()
+            st = engine.stats()
+            assert st["decode_compiles"] == 0 == st["prefill_compiles"]
+            assert st["commit_rollbacks"] == 0
+            assert st["prefill_unawaited_share"] == (1.0 if piped else 0.0)
+            streams[piped] = [r.generated for r in reqs]
+        assert streams[True] == streams[False]
+        for i in (0, 3, 5):
+            assert streams[True][i] == _ref(model, prompts[i],
+                                            4 + 2 * (i // 2))
+
+
 class TestHotSwap:
     def test_swap_between_steps_byte_exact_zero_recompiles(self, loop):
         m1 = _make_model(seed=0)

@@ -384,6 +384,40 @@ def test_the_donated_kv_pool_is_updated_where_it_lies(v5e, monkeypatch,
     assert memory.temp_size_in_bytes < one_layer, memory.temp_size_in_bytes
 
 
+@pytest.mark.parametrize("panel,counters", [(0, 0), (3, 6)])
+def test_a_prefill_hands_its_token_on_without_a_copy_of_a_pool(v5e, panel,
+                                                               counters):
+    """What PR 36 adds to a prefill program (`LLMEngine._hand_on`): the
+    token into the decode launch's input vector at the slot and a row of
+    int32 for the host, beside the donated pools' scatter: it compiles
+    for the chip, the pools still go out where they came in, and the two
+    small results are not aliased to their arguments (the commit of the
+    launch before still reads those)."""
+    from paddle_tpu.serving import LLMEngine
+    width = 2 + 2 * panel + counters
+    scatter, shapes = _prefill_scatter()
+
+    def fn(nxt, logp, alt_ids, alt_lps, extra, slot, feedback, firsts,
+           k_layers, v_layers, block_row, length, k_pools, v_pools):
+        return LLMEngine._hand_on(
+            feedback, firsts, slot, nxt, logp, alt_ids, alt_lps,
+            (extra,) if counters else ()) + tuple(scatter(
+                k_layers, v_layers, block_row, length, k_pools, v_pools))
+
+    shapes = [((1,), jnp.int32), ((1,), jnp.float32),
+              ((1, panel), jnp.int32), ((1, panel), jnp.float32),
+              ((counters,), jnp.int32), ((), jnp.int32),
+              ((CELL_SLOTS,), jnp.int32), ((CELL_SLOTS, width), jnp.int32)
+              ] + shapes + [(CELL_POOL, jnp.bfloat16)] * 2
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+            for shape, dtype in shapes]
+    compiled = jax.jit(fn, donate_argnums=(len(args) - 2, len(args) - 1)
+                       ).lower(*args).compile()
+    memory = compiled.memory_analysis()
+    pools = 2 * 2 * math.prod(CELL_POOL)
+    assert pools <= memory.alias_size_in_bytes < pools + 4 * CELL_SLOTS
+
+
 # The long-decode cell's latent geometry (benchmark/traffic/
 # backlog_long_decode.json: 128 slots, block 16, 128 table entries; a row
 # of 512 + 64 values padded to 640, 64 heads attending absorbed); two
