@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from ..framework.core import Tensor
 from ..framework import random as _random
 from ..framework.autograd import set_grad_enabled
-from ..profiler import RecordEvent
+from ..profiler import RecordEvent, watch_gc
 from ..profiler.metrics import LogHistogram
 
 __all__ = ["TrainStep", "TrainStepStats", "train_step_stats",
@@ -49,29 +49,78 @@ class TrainStepStats:
     forward fills: expert loads, loss terms), the newest step's counters
     and, as `first_step_<name>`, the first step's: they leave the compiled
     step with the other buffers, in the same turn as the loss, and are
-    fetched only when the stats are read."""
+    fetched only when the stats are read.
+
+    What `snapshot()` holds, `<phase>` being `call`, `gather_state`,
+    `dispatch`, `write_back` and `gc` (a collection of the Python heap
+    wherever it falls: `profiler.watch_gc`):
+
+    `steps`, `compiles`, `flash_width_fallbacks`
+        calls; programs traced; long attentions a TPU sent to XLA's N^2
+        path
+    `<phase>_p50_ms`, `<phase>_p99_ms`
+        mids of the histogram's log buckets
+    `<phase>_max_ms`
+        the longest single span since the step was built
+    `dispatches`
+        calls of the compiled step after the first, the calls that trace
+        left out
+    `dispatches_device_idle`, `starved_dispatch_share`
+        of those, the calls that found the loss of the step before READY
+        at entry (nothing was queued on the device), and their share
+    `dispatches_blocked`, `dispatch_blocked_share`
+        those that found it not ready at entry and ready at return (the
+        call outlasted the step before), and their share
+    a counting model's names, and `first_step_<name>`
+        as above
+
+    Nothing here is windowed (there is no reset): a caller's checked
+    steps, which wait for their results, count in `dispatches`."""
 
     # spans that own a histogram (`train_step.build` is for the trace alone)
     PHASES = ("train_step.call", "train_step.gather_state",
               "train_step.dispatch", "train_step.write_back")
+    GC_SPAN = "train_step.gc"
 
     def __init__(self):
         self.compiles = 0
-        self.phase = {name: LogHistogram() for name in self.PHASES}
+        self.phase = {name: LogHistogram()
+                      for name in self.PHASES + (self.GC_SPAN,)}
+        watch_gc(self.GC_SPAN, self.phase[self.GC_SPAN])
+        self.dispatches = 0
+        self.dispatches_device_idle = 0
+        self.dispatches_blocked = 0
         # what the model counted in its newest step: (names, the device
         # array the step returned beside the loss), read on `snapshot()`;
         # and in its FIRST step (a router that trains moves its loads)
         self.model_counters = None
         self.first_model_counters = None
 
+    def count_dispatch(self, ready_at_entry, ready_at_return):
+        """One call of the compiled step, by whether the loss of the step
+        before was ready when the call began and when it returned."""
+        self.dispatches += 1
+        if ready_at_entry:
+            self.dispatches_device_idle += 1
+        elif ready_at_return:
+            self.dispatches_blocked += 1
+
     def snapshot(self):
         from ..kernels import flash_attention
+        asked = self.dispatches
         out = {"steps": self.phase["train_step.call"].count,
                "compiles": self.compiles,
                # long causal attentions a TPU sent to XLA's N^2 path for
                # their head widths alone (process-wide; 0, or a model has
                # lost its kernel)
-               "flash_width_fallbacks": flash_attention.width_fallbacks()}
+               "flash_width_fallbacks": flash_attention.width_fallbacks(),
+               "dispatches": asked,
+               "dispatches_device_idle": self.dispatches_device_idle,
+               "dispatches_blocked": self.dispatches_blocked,
+               "starved_dispatch_share":
+                   self.dispatches_device_idle / asked if asked else 0.0,
+               "dispatch_blocked_share":
+                   self.dispatches_blocked / asked if asked else 0.0}
         if self.model_counters is not None:
             for prefix, (names, values) in (
                     ("", self.model_counters),
@@ -82,6 +131,7 @@ class TrainStepStats:
             short = name.split(".", 1)[1]
             out[f"{short}_p50_ms"] = hist.percentile(50) * 1e3
             out[f"{short}_p99_ms"] = hist.percentile(99) * 1e3
+            out[f"{short}_max_ms"] = (hist.max or 0.0) * 1e3
         return out
 
 
@@ -118,13 +168,12 @@ class TrainStep:
         self._acc_names = None
         self._donate = donate
         self._stats = TrainStepStats()
+        self._loss_before = None    # the newest call's loss, on the device
         _LIVE[next(_SERIAL)] = self
 
     def stats(self):
-        """{"steps", "compiles", "flash_width_fallbacks", "<phase>_p50_ms",
-        "<phase>_p99_ms"} for the phases `call`, `gather_state`,
-        `dispatch`, `write_back`, and the newest and the first step's
-        counters of a model that counts, by its `train_counter_names`."""
+        """The host's side of every call since the step was built: the
+        keys of the table in `TrainStepStats`' docstring."""
         return self._stats.snapshot()
 
     def _span(self, name):
@@ -247,9 +296,16 @@ class TrainStep:
         params = self._params
         acc_names = self._acc_names
 
+        # did this call find the device idle, and did it outlast the step
+        # before? Asked of that step's loss, without waiting
+        before, traced = self._loss_before, self._stats.compiles
+        ready_at_entry = before is not None and before.is_ready()
         with self._span("train_step.dispatch"):
             loss, new_p, new_accs, new_b = self._jitted(*state, step_count,
                                                         key)
+        if before is not None and self._stats.compiles == traced:
+            self._stats.count_dispatch(ready_at_entry, before.is_ready())
+        self._loss_before = loss
         from ..framework.flags import _FLAGS
         if _FLAGS.get("FLAGS_check_nan_inf") and \
                 not bool(jnp.isfinite(loss)):

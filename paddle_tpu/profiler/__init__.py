@@ -15,10 +15,12 @@ ips/tokens-per-second benchmark hooks (reference: profiler/timer.py).
 from __future__ import annotations
 
 import contextlib
+import gc
 import json
 import os
 import threading
 import time
+import weakref
 from enum import Enum
 
 import jax
@@ -41,6 +43,7 @@ from .goodput import (GoodputAccountant, ACCOUNTANT, goodput_snapshot,
                       estimate_cycle_flops, peak_flops_per_chip)
 
 __all__ = ["Profiler", "ProfilerState", "ProfilerTarget", "RecordEvent",
+           "watch_gc",
            "make_scheduler", "export_chrome_tracing", "export_protobuf",
            "load_profiler_result", "benchmark", "SortedKeys", "SummaryView",
            "DispatchStats", "dispatch_cache_stats",
@@ -198,6 +201,45 @@ class RecordEvent:
     def __exit__(self, *exc):
         self.end()
         return False
+
+
+# (span name, weak reference to the histogram) of every `watch_gc`, and
+# the spans of the collection that is running
+_gc_watchers = []
+_gc_open = []
+
+
+def watch_gc(span_name, hist):
+    """Every collection of the Python heap, from now on, is a span
+    `span_name` that feeds `hist`: a `RecordEvent` opened when the
+    collector starts and closed when it stops, so on the xplane's host
+    plane it lies INSIDE whatever span the collection interrupted, and a
+    reader that names a moment by its innermost span names the collection.
+    One `gc.callbacks` entry serves every watcher of the process, installed
+    by the first. `hist` is held weakly: the watch ends with the object
+    that owns the histogram."""
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
+    _gc_watchers.append((span_name, weakref.ref(hist)))
+
+
+def _on_gc(phase, info):
+    if phase == "start":
+        gone = False
+        for name, ref in _gc_watchers:
+            hist = ref()
+            if hist is None:
+                gone = True
+                continue
+            span = RecordEvent(name, hist=hist)
+            span.begin()
+            _gc_open.append(span)
+        if gone:
+            _gc_watchers[:] = [w for w in _gc_watchers
+                               if w[1]() is not None]
+    else:
+        while _gc_open:
+            _gc_open.pop().end()
 
 
 def make_scheduler(closed, ready, record, repeat=0, skip_first=0):

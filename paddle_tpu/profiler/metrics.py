@@ -92,7 +92,7 @@ class LogHistogram:
     """
 
     __slots__ = ("_cur", "_prev", "_life", "_window", "_cur_n", "_lock",
-                 "count", "sum", "min", "max")
+                 "count", "sum", "min", "max", "__weakref__")
 
     def __init__(self, window=None):
         if window is None:

@@ -106,6 +106,7 @@ aggregated by `profiler.explain` / `tools/fusion_doctor`.
 """
 from __future__ import annotations
 
+import logging
 import math
 import time
 
@@ -116,7 +117,7 @@ import jax.numpy as jnp
 from ..framework.core import Tensor
 from ..framework.autograd import set_grad_enabled
 from ..framework.flags import _FLAGS
-from ..profiler import RecordEvent
+from ..profiler import RecordEvent, watch_gc
 from ..profiler.events import EVENTS as _EVENTS
 from ..profiler.metrics import LogHistogram, SERVE as _M, \
     enabled as _metrics_on
@@ -141,6 +142,15 @@ _EST_WINDOW = 32
 
 _MIN_BUCKET = 8
 
+# the slow-step rule (`ServeStats`' docstring): a step is reported when it
+# is over this many times the window's mean step AND over this many
+# seconds, once the window holds this many steps, at most once a period
+_SLOW_STEP_FACTOR = 20.0
+_SLOW_STEP_MIN_S = 0.25
+_SLOW_STEP_MIN_STEPS = 100
+_SLOW_STEP_LOG_PERIOD_S = 1.0
+_LOG = logging.getLogger("paddle_tpu.serving")
+
 
 class ServeStats:
     """Engine counters + step-latency histograms. `decode_compiles` is
@@ -153,12 +163,66 @@ class ServeStats:
     long the engine runs, and FRESH — the old raw `step_times_s` list
     stopped appending at 100k samples, silently freezing p50/p99 for the
     rest of the process's life. `step_times_s` survives as a short
-    recent-sample list (the admission-time wait estimate reads it)."""
+    recent-sample list (the admission-time wait estimate reads it).
 
-    # spans of the engine that own a histogram, fed on exit (the others
-    # are for the trace alone); the shares of `snapshot()` come from sums
-    PHASES = ("engine.step", "engine.prefill", "engine.decode",
-              "engine.decode.dispatch", "engine.stream")
+    THE HOST'S TURN. Every span a `step()` opens (`PHASES`), and
+    `engine.gc`, a collection of the Python heap wherever it falls
+    (`profiler.watch_gc`), owns a histogram in `phase`, fed when the span
+    closes; `reset()` starts them anew, `engine.compile` excepted. What
+    `snapshot()` makes of them, `<short>` being a span's name without
+    `engine.` and with `_` for `.` (`prefill_dispatch`, `gc`, ...):
+
+    `<short>_ms_per_step`
+        1e3 x the histogram's exact sum / `steps` (no bucket)
+    `<short>_max_ms`
+        the longest single span of the window
+    `step_unattributed_ms_per_step`
+        `engine.step` less its direct children (`DIRECT`), never
+        negative: over a tenth of a step, a span is missing. `engine.gc`
+        lies inside other spans and is not taken off
+    `host_wait_ms_per_step`
+        `engine.decode.wait` + `engine.prefill.wait`: the host blocked
+        on the device
+    `gc_collections`
+        collections the window saw, of every generation
+    `dispatches`, `dispatches_device_idle`, `dispatches_ran_dry`;
+    each also `prefill_…`, `decode_…`
+        program calls after a program's first; those that found the
+        result of the program dispatched before them ready at ENTRY:
+        the device's queue was empty and stayed so until the call
+        landed; and those that found it ready only at RETURN: the
+        queue ran dry while the host was inside the call
+        (`LLMEngine._call_program` asks `is_ready()`, no wait)
+    `starved_dispatch_share`, `ran_dry_dispatch_share`; `prefill_…`,
+    `decode_…`
+        each over `dispatches`: how often the host kept the device
+        waiting, from before the call or during it, and for which
+        program
+    `slowest_step`
+        `{index, seconds, phases: {span: seconds in that step}, gc_s}`
+        of the window's longest `step()`, or None; one record, replaced
+
+    THE SLOW-STEP RULE. A `step()` that took BOTH over `_SLOW_STEP_FACTOR`
+    (20) x the mean of the window's steps before it and over
+    `_SLOW_STEP_MIN_S` (0.25 s) writes one WARNING to the logger
+    `paddle_tpu.serving` (the step's index, seconds, three largest phases
+    and GC seconds): never for a step that opened `engine.compile`, nor
+    before the window holds `_SLOW_STEP_MIN_STEPS` (100) steps, and at
+    most once in `_SLOW_STEP_LOG_PERIOD_S` (1 s). An ordinary step pays
+    one read of each histogram's sum and one comparison."""
+
+    # the spans a `step()` opens, each with a histogram fed on exit; the
+    # shares and per-step times of `snapshot()` come from their sums
+    PHASES = ("engine.step", "engine.admit", "engine.prefill",
+              "engine.prefill.dispatch", "engine.kv_grow",
+              "engine.prefill.commit", "engine.prefill.wait",
+              "engine.decode", "engine.decode.dispatch",
+              "engine.decode.wait", "engine.decode.fetch", "engine.stream")
+    # those that lie directly under `engine.step`
+    DIRECT = ("engine.admit", "engine.kv_grow", "engine.prefill.commit",
+              "engine.decode", "engine.stream")
+    GC_SPAN = "engine.gc"
+    KINDS = ("prefill", "decode")
 
     def __init__(self):
         # first calls of programs (`engine.compile`): what the process
@@ -171,8 +235,21 @@ class ServeStats:
         closures hold a reference to this object (that is how
         decode_compiles counts real traces), so a bench warmup resets the
         window without losing retrace visibility."""
-        self.phase = {name: LogHistogram() for name in self.PHASES}
+        self.phase = {name: LogHistogram()
+                      for name in self.PHASES + (self.GC_SPAN,)}
+        watch_gc(self.GC_SPAN, self.phase[self.GC_SPAN])
+        # in the order of `_at_entry`, which `step_begin` fills
+        self._timed = tuple(self.phase.items())
+        self._at_entry = None
         self.phase["engine.compile"] = self.compile_hist
+        self.slowest_step = None
+        self._warned_at = None
+        # program calls after a program's first, by kind; those that
+        # found the device's queue empty; those that saw it run dry
+        # (`count_dispatch`)
+        self.dispatches = dict.fromkeys(self.KINDS, 0)
+        self.dispatches_device_idle = dict.fromkeys(self.KINDS, 0)
+        self.dispatches_ran_dry = dict.fromkeys(self.KINDS, 0)
         self.steps = 0
         self.tokens_generated = 0
         self.prefills = 0
@@ -266,6 +343,59 @@ class ServeStats:
             del self.step_times_s[:-_EST_WINDOW]
         self.step_hist.observe(dt_s)
 
+    def count_dispatch(self, kind, ready_at_entry, ready_at_return):
+        """One call of a `kind` program, by whether the result of the
+        program dispatched before it was ready when the call began and
+        when it returned."""
+        self.dispatches[kind] += 1
+        if ready_at_entry:
+            self.dispatches_device_idle[kind] += 1
+        elif ready_at_return:
+            self.dispatches_ran_dry[kind] += 1
+
+    def step_begin(self):
+        """`step()` entry: where every phase's sum stands."""
+        self._at_entry = [hist.sum for _, hist in self._timed] \
+            + [self.compile_hist.count]
+
+    def step_end(self):
+        """`step()` exit, its span closed: keep the step's anatomy if it
+        is the window's longest, and report it under the slow-step rule."""
+        entry, self._at_entry = self._at_entry, None
+        if entry is None:       # the window was reset inside the step
+            return
+        whole = self.phase["engine.step"]
+        seconds = whole.sum - entry[0]
+        slowest = self.slowest_step
+        longest = slowest is None or seconds > slowest["seconds"]
+        slow = seconds > _SLOW_STEP_MIN_S
+        if not (longest or slow):
+            return
+        spent = {name: hist.sum - was
+                 for (name, hist), was in zip(self._timed, entry)}
+        gc_s = spent.pop(self.GC_SPAN)
+        before = whole.count - 1        # the window's steps before this
+        if longest:
+            self.slowest_step = {"index": before, "seconds": seconds,
+                                 "phases": spent, "gc_s": gc_s}
+        if not slow or before < _SLOW_STEP_MIN_STEPS \
+                or self.compile_hist.count != entry[-1]:
+            return
+        mean = entry[0] / before
+        if seconds <= _SLOW_STEP_FACTOR * mean:
+            return
+        now = time.monotonic()
+        if self._warned_at is not None \
+                and now - self._warned_at < _SLOW_STEP_LOG_PERIOD_S:
+            return
+        self._warned_at = now
+        largest = sorted((n for n in spent if n != "engine.step"),
+                         key=spent.get, reverse=True)[:3]
+        _LOG.warning(
+            "engine step %d took %.3f s where the window's mean is %.6f s: "
+            "%s; gc %.3f s", before, seconds, mean,
+            ", ".join(f"{n} {spent[n]:.3f} s" for n in largest), gc_s)
+
     def snapshot(self):
         def pct(p):
             return self.step_hist.percentile(p)
@@ -280,6 +410,27 @@ class ServeStats:
         phase = self.phase
         inside = sum(phase[n].sum for n in (
             "engine.prefill", "engine.decode", "engine.stream"))
+
+        def ms_per_step(seconds):
+            return 1e3 * seconds / self.steps if self.steps else 0.0
+
+        turn = {}
+        for name, hist in self._timed:
+            short = name.split(".", 1)[1].replace(".", "_")
+            turn[short + "_ms_per_step"] = ms_per_step(hist.sum)
+            turn[short + "_max_ms"] = 1e3 * (hist.max or 0.0)
+        for prefix, kinds in [("", self.KINDS)] \
+                + [(k + "_", (k,)) for k in self.KINDS]:
+            asked = sum(self.dispatches[k] for k in kinds)
+            idle = sum(self.dispatches_device_idle[k] for k in kinds)
+            dry = sum(self.dispatches_ran_dry[k] for k in kinds)
+            turn[prefix + "dispatches"] = asked
+            turn[prefix + "dispatches_device_idle"] = idle
+            turn[prefix + "dispatches_ran_dry"] = dry
+            turn[prefix + "starved_dispatch_share"] = \
+                idle / asked if asked else 0.0
+            turn[prefix + "ran_dry_dispatch_share"] = \
+                dry / asked if asked else 0.0
         return {
             "steps": self.steps,
             "tokens_generated": self.tokens_generated,
@@ -358,6 +509,16 @@ class ServeStats:
             # scheduler, KV growth, housekeeping
             "step_self_share": share(
                 max(0.0, phase["engine.step"].sum - inside)),
+            # the host's turn, phase by phase (the class docstring's table)
+            **turn,
+            "step_unattributed_ms_per_step": ms_per_step(max(
+                0.0, phase["engine.step"].sum
+                - sum(phase[n].sum for n in self.DIRECT))),
+            "host_wait_ms_per_step": ms_per_step(
+                phase["engine.decode.wait"].sum
+                + phase["engine.prefill.wait"].sum),
+            "gc_collections": phase[self.GC_SPAN].count,
+            "slowest_step": self.slowest_step,
             "compile_s": self.compile_hist.sum,
             "elapsed_s": elapsed,
             "tokens_per_sec": (self.tokens_generated / elapsed
@@ -615,6 +776,9 @@ class LLMEngine:
         # supervisor never kills a replica for legitimately compiling
         self._compile_grace_ns = None
         self._decode_called = None      # the decode program last called
+        # the first result of the program dispatched last: ready means the
+        # device has nothing queued (`_call_program`)
+        self._newest_result = None
         _telemetry.maybe_start_from_flags()
         _telemetry.register_engine(self)
         _sentinel.maybe_arm_from_flags()
@@ -880,11 +1044,13 @@ class LLMEngine:
             self._stats.wall_t0 = time.perf_counter()
         self._hb_ns = time.perf_counter_ns()
         self._stepping = True
+        self._stats.step_begin()
         try:
             with self._span("engine.step"):
                 return self._step_locked()
         finally:
             self._stepping = False
+            self._stats.step_end()
             self._stats.wall_t1 = time.perf_counter()
 
     def _span(self, name):
@@ -893,13 +1059,27 @@ class LLMEngine:
         return RecordEvent(name, hist=self._stats.phase.get(name))
 
     def _call_program(self, name, fn, args, first):
-        """`fn(*args)` under the dispatch span `name`; a program's first
-        call, which traces and compiles it, under `engine.compile` too."""
+        """`fn(*args)` under the dispatch span `engine.<kind>.dispatch`;
+        a program's first call, which traces and compiles it, under
+        `engine.compile` too. Every other call asks, without waiting,
+        whether the device has finished the program dispatched before
+        it: at entry (if so its queue is empty, and stays empty until
+        this call lands) and, if not, again at return (it ran dry while
+        the host was in here). What is asked is that program's first
+        result (the sampled tokens), which no later program donates."""
         if first:
             with self._span("engine.compile"), self._span(name):
-                return fn(*args)
-        with self._span(name):
-            return fn(*args)
+                res = fn(*args)
+        else:
+            newest = self._newest_result
+            idle = newest is not None and newest.is_ready()
+            with self._span(name):
+                res = fn(*args)
+            self._stats.count_dispatch(
+                name.split(".")[1], idle,
+                idle or (newest is not None and newest.is_ready()))
+        self._newest_result = res[0]
+        return res
 
     def _step_locked(self):
         sched = self.scheduler
@@ -1006,11 +1186,12 @@ class LLMEngine:
             # meaningful in a process that never crosses an optimizer
             # boundary (stall time lands via the watchdog's note_stall)
             _goodput.ACCOUNTANT.note_productive(dt)
-        _EVENTS.emit("serve.step", "engine",
-                     detail={"active": n_active,
-                             "occupancy": round(
-                                 n_active / self.max_batch_size, 4),
-                             "ms": round(dt * 1e3, 4)})
+        if _EVENTS.enabled:
+            _EVENTS.emit("serve.step", "engine",
+                         detail={"active": n_active,
+                                 "occupancy": round(
+                                     n_active / self.max_batch_size, 4),
+                                 "ms": round(dt * 1e3, 4)})
         if self.degraded:
             # first clean decode step after a hang/fault: recovered
             self.degraded = False
@@ -1099,12 +1280,13 @@ class LLMEngine:
             _M.step_s.observe(dt)
             _M.occupancy.set(n_active / self.max_batch_size)
             _goodput.ACCOUNTANT.note_productive(dt)
-        _EVENTS.emit("serve.step", "engine",
-                     detail={"active": n_active,
-                             "occupancy": round(
-                                 n_active / self.max_batch_size, 4),
-                             "ms": round(dt * 1e3, 4),
-                             "pipelined": True})
+        if _EVENTS.enabled:
+            _EVENTS.emit("serve.step", "engine",
+                         detail={"active": n_active,
+                                 "occupancy": round(
+                                     n_active / self.max_batch_size, 4),
+                                 "ms": round(dt * 1e3, 4),
+                                 "pipelined": True})
         if self.degraded:
             self.degraded = False
             _EVENTS.emit("serve.degrade", "engine",
@@ -1256,6 +1438,15 @@ class LLMEngine:
             self._reset_pipeline()
             self._recover_with_fallback(rebuild=False)
             return None
+        return self._fetch_launch(res)
+
+    def _fetch_launch(self, res):
+        """A launch's four results and the model's counters on the host.
+        With the watchdog disarmed `_monitor.wait` returns at once and
+        THIS is where the host waits for the program: under the wait's
+        span, so that the fetch's holds the copies alone."""
+        with self._span("engine.decode.wait"):
+            res[0].block_until_ready()
         with self._span("engine.decode.fetch"):
             self._count_model("decode", res)
             return (np.asarray(res[0]), np.asarray(res[1]),
@@ -1423,6 +1614,10 @@ class LLMEngine:
         return [list(r.generated) for r in reqs]
 
     def stats(self):
+        """`ServeStats.snapshot()` over the window since `reset_stats()`
+        (the keys of the host's turn, per phase and per dispatch: the one
+        table in `ServeStats`' docstring) and the engine's own facts:
+        scheduler, pool size, block size, attention kernel, KV dtype."""
         snap = self._stats.snapshot()
         snap["scheduler"] = self.scheduler.info()
         snap["kv_blocks"] = self.cache.num_blocks
@@ -1787,7 +1982,6 @@ class LLMEngine:
                               {"organic": True, "error": str(e)[:200]})
                 self._recover_with_fallback(rebuild=True)
                 return None
-            nxt = res[0]
             if guardian.poll_fault("serve.decode",
                                    ("nan_output", "raise")) is not None:
                 # chaos-poisoned fused decode output: commit NOTHING from
@@ -1802,10 +1996,7 @@ class LLMEngine:
             if self._kv_quantized:
                 self._k_scales, self._v_scales = res[6], res[7]
             self._maybe_store_decode()
-            with self._span("engine.decode.fetch"):
-                self._count_model("decode", res)
-                return (np.asarray(nxt), np.asarray(res[1]),
-                        np.asarray(res[2]), np.asarray(res[3]))
+            return self._fetch_launch(res)
 
     # where `_decode_args` puts the lengths and the mask of active slots
     _ARG_LENS, _ARG_ACTIVE = 4, 5

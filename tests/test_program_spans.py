@@ -3,7 +3,9 @@
 host plane of the one `.xplane.pb`), nested as the tables of ISSUE 24 say,
 each feeding the phase histogram that `stats()` reports; silent when no
 `Profiler` listens."""
+import gc
 import glob
+import logging
 import math
 import os
 
@@ -17,6 +19,7 @@ from paddle_tpu import profiler
 from paddle_tpu.incubate.models import GPTConfig, GPTForCausalLM
 from paddle_tpu.jit import TrainStep, train_step_stats
 from paddle_tpu.serving import LLMEngine
+from paddle_tpu.serving.engine import ServeStats
 
 # span -> the span it must lie inside, on the same thread
 TRAIN_PARENTS = {
@@ -40,6 +43,8 @@ ENGINE_PARENTS = {
     "engine.decode.wait": "engine.decode",
     "engine.decode.fetch": "engine.decode",
     "engine.stream": "engine.step",
+    # a collection forced from an `on_token` callback
+    "engine.gc": "engine.stream",
 }
 PROMPTS = [[1, 2, 3, 4, 5], [7, 8, 9], [3] * 12]
 
@@ -93,18 +98,31 @@ def traced(tmp_path_factory):
     step, x, y = tiny_train_step(), *batch(16)
     engine = tiny_engine(pipeline_decode=False)
     piped = tiny_engine(pipeline_decode=True)
+    forced = []
+
+    def collect_once(req, tok, text):
+        # a decoded token (the serial loop emits a first token under
+        # `engine.prefill.commit`, every later one under `engine.stream`)
+        if len(req.generated) == 2 and not forced:
+            forced.append(gc.collect())
+
+    gc.disable()        # the only collection of the session is the forced
     jax.profiler.start_trace(out)
     try:
         with jax.profiler.TraceAnnotation("caller.window"):
             for _ in range(3):
                 step(x, y)
-            engine.generate(PROMPTS, max_new_tokens=4)
+            for p in PROMPTS:
+                engine.add_request(p, max_new_tokens=4,
+                                   on_token=collect_once)
+            engine.run()
         with jax.profiler.TraceAnnotation("caller.pipelined"):
             piped.generate(PROMPTS, max_new_tokens=4)
         with jax.profiler.TraceAnnotation("caller.warm"):
             piped.generate(PROMPTS, max_new_tokens=4)
     finally:
         jax.profiler.stop_trace()
+        gc.enable()
     lines, files = host_spans(out)
     return {"lines": lines, "files": files}
 
@@ -149,7 +167,8 @@ def test_the_pipelined_tail_opens_the_same_spans(traced):
     window = _named(lines, "caller.pipelined")[0]
     names = {n for events in lines.values() for n, a, b in events
              if window[1] <= a and b <= window[2]}
-    assert {n for n in ENGINE_PARENTS} <= names
+    # (the session's one collection was forced in the serial window)
+    assert {n for n in ENGINE_PARENTS if n != "engine.gc"} <= names
 
 
 def _inside(lines, name, window):
@@ -197,14 +216,20 @@ def test_train_step_stats_count_every_call_and_only_the_contract_keys():
         step(x, y)
     stats = step.stats()
     phases = ("call", "gather_state", "dispatch", "write_back")
-    assert set(stats) == {"steps", "compiles", "flash_width_fallbacks"} | {
-        f"{p}_{q}_ms" for p in phases for q in ("p50", "p99")}
+    assert set(stats) == {
+        "steps", "compiles", "flash_width_fallbacks", "dispatches",
+        "dispatches_device_idle", "dispatches_blocked",
+        "starved_dispatch_share", "dispatch_blocked_share"} | {
+        f"{p}_{q}_ms" for p in phases + ("gc",)
+        for q in ("p50", "p99", "max")}
     assert stats["flash_width_fallbacks"] == 0
     assert stats["steps"] == 5
     hists = step._stats.phase
     for p in phases:
         assert hists[f"train_step.{p}"].count == 5
         assert 0 < stats[f"{p}_p50_ms"] <= stats[f"{p}_p99_ms"]
+        assert stats[f"{p}_max_ms"] == pytest.approx(
+            1e3 * hists[f"train_step.{p}"].max)
     # a parent's time covers its children's
     assert hists["train_step.call"].sum >= sum(
         hists[f"train_step.{p}"].sum for p in phases[1:])
@@ -275,10 +300,279 @@ def test_reset_stats_zeroes_the_phases_and_keeps_compile_seconds():
         assert after[key] == 0.0
     assert all(h.count == 0 for name, h in engine._stats.phase.items()
                if name != "engine.compile")
+    turn = [k for k in after if k.endswith(("_ms_per_step", "_max_ms"))
+            or "dispatch" in k]
+    assert len(turn) == 2 * 13 + 2 + 15 + 1     # 13 spans, 2 derived,
+    assert all(after[k] == 0 for k in turn)     # 15 counters, the old p50
+    assert before["dispatches"] > 0 and before["step_max_ms"] > 0
+    assert before["slowest_step"]["seconds"] > 0
+    assert after["slowest_step"] is None and after["gc_collections"] == 0
     # the next window compiles nothing, so the sum stands still
     engine.generate(PROMPTS, max_new_tokens=3)
     assert engine.stats()["compile_s"] == before["compile_s"]
     assert engine.stats()["prefill_share"] > 0
+
+
+def _short(span):
+    return span.split(".", 1)[1].replace(".", "_")
+
+
+def _count_spans(engine):
+    """Every span the engine opens from now on, counted by name."""
+    opened, span = {}, engine._span
+
+    def counting(name):
+        opened[name] = opened.get(name, 0) + 1
+        return span(name)
+    engine._span = counting
+    return opened
+
+
+@pytest.mark.parametrize("pipelined", [False, True])
+def test_every_span_of_a_step_feeds_a_histogram_of_its_own(pipelined):
+    """The known traffic, warm: each of the twelve phases has as many
+    observations as its span was opened, a step's direct children and
+    what is left add up to the step, and a maximum is no less than a
+    mean."""
+    engine = tiny_engine(pipeline_decode=pipelined)
+    engine.generate(PROMPTS, max_new_tokens=6)      # compiles
+    engine.reset_stats()
+    opened = _count_spans(engine)
+    engine.generate(PROMPTS, max_new_tokens=6)
+    stats, phase = engine.stats(), engine._stats.phase
+    assert len(ServeStats.PHASES) == 12
+    for name in ServeStats.PHASES:
+        assert phase[name].count == opened[name] > 0, name
+    assert "engine.compile" not in opened
+    assert opened["engine.prefill.dispatch"] == opened["engine.prefill"] == 3
+    assert opened["engine.decode.dispatch"] == stats["steps"]
+    assert opened["engine.prefill.commit"] == opened["engine.prefill.wait"] \
+        == 1                            # one fetch for the boundary's three
+    steps = stats["steps"]
+    for name in ServeStats.PHASES + ("engine.gc",):
+        hist, short = phase[name], _short(name)
+        assert stats[short + "_ms_per_step"] == pytest.approx(
+            1e3 * hist.sum / steps)
+        assert stats[short + "_max_ms"] == pytest.approx(
+            1e3 * (hist.max or 0.0))
+        if hist.count:
+            assert stats[short + "_max_ms"] >= \
+                stats[short + "_ms_per_step"] * steps / hist.count * (1 - 1e-9)
+    children = sum(stats[_short(n) + "_ms_per_step"]
+                   for n in ServeStats.DIRECT)
+    assert stats["step_unattributed_ms_per_step"] >= 0
+    assert children + stats["step_unattributed_ms_per_step"] == \
+        pytest.approx(stats["step_ms_per_step"])
+    assert children > 0.5 * stats["step_ms_per_step"]
+    assert stats["host_wait_ms_per_step"] == pytest.approx(
+        stats["decode_wait_ms_per_step"] + stats["prefill_wait_ms_per_step"])
+    # the longest step's own anatomy obeys the same identity
+    slowest = stats["slowest_step"]
+    spent = slowest["phases"]
+    assert set(spent) == set(ServeStats.PHASES)
+    assert spent["engine.step"] == slowest["seconds"] == pytest.approx(
+        1e-3 * stats["step_max_ms"])
+    assert 0 <= slowest["index"] < phase["engine.step"].count
+    assert sum(spent[n] for n in ServeStats.DIRECT) <= \
+        slowest["seconds"] * (1 + 1e-9)
+
+
+def test_the_serial_loop_finds_the_device_idle_at_every_dispatch():
+    """One request at a time through the serial loop: the host has
+    fetched every program's result before it dispatches the next."""
+    engine = tiny_engine(pipeline_decode=False)
+    for p in PROMPTS:
+        engine.generate([p], max_new_tokens=4)
+    stats = engine.stats()
+    # buckets 8 and 16 and the decode program: three first calls
+    assert stats["prefill_dispatches"] == stats["prefills"] - 2 == 1
+    assert stats["decode_dispatches"] == stats["decode_launches"] - 1 == 8
+    assert stats["dispatches"] == 9
+    for kind in ("", "prefill_", "decode_"):
+        assert stats[kind + "dispatches_device_idle"] == \
+            stats[kind + "dispatches"]
+        assert stats[kind + "starved_dispatch_share"] == 1.0
+        assert stats[kind + "dispatches_ran_dry"] == 0
+        assert stats[kind + "ran_dry_dispatch_share"] == 0.0
+
+
+def test_the_pipelined_loop_counts_every_call_but_a_programs_first():
+    engine = tiny_engine(pipeline_decode=True)
+    engine.generate(PROMPTS, max_new_tokens=6)
+    stats = engine.stats()
+    assert stats["dispatches"] == \
+        stats["prefills"] + stats["decode_launches"] - 3
+    assert stats["dispatches"] == \
+        stats["prefill_dispatches"] + stats["decode_dispatches"]
+    for kind in ("", "prefill_", "decode_"):
+        # idle before the call, or run dry during it, or neither
+        assert 0 <= stats[kind + "dispatches_device_idle"] \
+            + stats[kind + "dispatches_ran_dry"] <= stats[kind + "dispatches"]
+        assert 0.0 <= stats[kind + "starved_dispatch_share"] \
+            + stats[kind + "ran_dry_dispatch_share"] <= 1.0
+    engine.reset_stats()
+    assert engine.stats()["dispatches"] == 0
+    assert engine.stats()["starved_dispatch_share"] == 0.0
+
+
+def test_a_caller_that_waits_for_each_loss_starves_every_dispatch():
+    step, x, y = tiny_train_step(), *batch(16)
+    for _ in range(6):
+        float(step(x, y))
+    stats = step.stats()
+    assert stats["dispatches"] == 5     # the call that traces is left out
+    assert stats["dispatches_device_idle"] == 5
+    assert stats["dispatches_blocked"] == 0
+    assert stats["starved_dispatch_share"] == 1.0
+    assert stats["dispatch_blocked_share"] == 0.0
+    step(*batch(8))                     # a new shape traces: not counted
+    assert step.stats()["dispatches"] == 5
+
+
+def test_train_step_dispatches_are_sorted_by_the_loss_before():
+    stats = tiny_train_step()._stats
+    for at_entry, at_return in ((True, True), (False, True), (False, True),
+                                (False, False)):
+        stats.count_dispatch(at_entry, at_return)
+    snap = stats.snapshot()
+    assert (snap["dispatches"], snap["dispatches_device_idle"],
+            snap["dispatches_blocked"]) == (4, 1, 2)
+    assert snap["starved_dispatch_share"] == 0.25
+    assert snap["dispatch_blocked_share"] == 0.5
+
+
+def test_engine_dispatches_are_sorted_by_the_program_before():
+    stats = ServeStats()
+    for kind, at_entry, at_return in (
+            ("prefill", True, True), ("prefill", False, True),
+            ("decode", False, True), ("decode", False, False),
+            ("decode", False, False)):
+        stats.count_dispatch(kind, at_entry, at_return)
+    snap = stats.snapshot()
+    assert [snap[k] for k in ("dispatches", "dispatches_device_idle",
+                              "dispatches_ran_dry")] == [5, 1, 2]
+    assert [snap["prefill_" + k] for k in (
+        "dispatches", "dispatches_device_idle", "dispatches_ran_dry",
+        "starved_dispatch_share", "ran_dry_dispatch_share")] == \
+        [2, 1, 1, 0.5, 0.5]
+    assert snap["decode_ran_dry_dispatch_share"] == pytest.approx(1 / 3)
+    assert snap["decode_starved_dispatch_share"] == 0.0
+    assert snap["starved_dispatch_share"] == 0.2
+    assert snap["ran_dry_dispatch_share"] == 0.4
+
+
+def _hand_step(stats, seconds, compiles=False, **phases):
+    """One `step()` as `ServeStats` sees it, its spans' seconds set by
+    hand (no clock): `phases` by short name."""
+    stats.step_begin()
+    for short, spent in phases.items():
+        stats.phase["engine." + short.replace("_", ".")].observe(spent)
+    if compiles:
+        stats.compile_hist.observe(seconds)
+    stats.phase["engine.step"].observe(seconds)
+    stats.step_end()
+
+
+@pytest.fixture
+def serving_log(caplog):
+    caplog.set_level(logging.WARNING, logger="paddle_tpu.serving")
+    return caplog
+
+
+def test_the_slowest_step_keeps_its_anatomy(serving_log):
+    stats = ServeStats()
+    assert stats.snapshot()["slowest_step"] is None
+    _hand_step(stats, 0.010, admit=0.004, decode=0.003, stream=0.002)
+    _hand_step(stats, 0.030, admit=0.020, prefill=0.019, decode=0.004,
+               decode_wait=0.001, gc=0.015)
+    _hand_step(stats, 0.020, admit=0.019)
+    slowest = stats.snapshot()["slowest_step"]
+    assert slowest["index"] == 1 and slowest["seconds"] == \
+        pytest.approx(0.030)
+    assert slowest["gc_s"] == pytest.approx(0.015)
+    spent = slowest["phases"]
+    assert spent["engine.admit"] == pytest.approx(0.020)
+    assert spent["engine.prefill"] == pytest.approx(0.019)
+    assert spent["engine.stream"] == 0.0 and "engine.gc" not in spent
+    unattributed = spent["engine.step"] - sum(
+        spent[n] for n in ServeStats.DIRECT)
+    assert unattributed == pytest.approx(0.006)
+    assert not serving_log.records      # nothing near the rule
+    stats.reset()
+    assert stats.snapshot()["slowest_step"] is None
+
+
+def test_a_stalled_step_says_which_phase_held_it_once(serving_log):
+    stats = ServeStats()
+    for _ in range(99):
+        _hand_step(stats, 0.010, decode=0.004)
+    # the window holds 99 steps: too few to know its mean
+    _hand_step(stats, 0.9, decode=0.8)
+    assert not serving_log.records
+    _hand_step(stats, 0.010, decode=0.004)
+    # a step that compiled is slow by right
+    _hand_step(stats, 3.0, compiles=True, admit=2.9)
+    # 0.2 s is 20 x the mean and under a quarter second; 0.3 s is over a
+    # quarter second and under 20 x the mean by now (0.048 s)
+    _hand_step(stats, 0.2, decode=0.19)
+    _hand_step(stats, 0.3, decode=0.29)
+    assert not serving_log.records
+    _hand_step(stats, 2.5, admit=0.1, decode=2.3, decode_wait=2.2,
+               stream=0.05, gc=2.1)
+    # not the window's longest, over the rule all the same, and inside
+    # the second of the line before it
+    _hand_step(stats, 2.0, stream=1.9)
+    [record] = serving_log.records
+    assert record.levelno == logging.WARNING
+    line = record.getMessage()
+    assert "step 104 took 2.500 s" in line
+    assert "engine.decode 2.300 s, engine.decode.wait 2.200 s, " \
+        "engine.admit 0.100 s" in line
+    assert line.endswith("gc 2.100 s")
+    assert stats.snapshot()["slowest_step"]["index"] == 101     # compiled
+
+
+@pytest.fixture
+def only_forced_collections():
+    gc.collect()
+    gc.disable()
+    yield
+    gc.enable()
+
+
+def test_one_gc_callback_serves_every_watcher_and_forgets_the_dropped(
+        only_forced_collections):
+    import weakref
+
+    def ours():
+        return [cb for cb in gc.callbacks if cb is profiler._on_gc]
+    engines = [tiny_engine(), tiny_engine()]
+    step = tiny_train_step()
+    assert len(ours()) == 1
+    hists = [e._stats.phase["engine.gc"] for e in engines] \
+        + [step._stats.phase["train_step.gc"]]
+    gc.collect()
+    assert [h.count for h in hists] == [1, 1, 1]
+    assert engines[0].stats()["gc_collections"] == 1
+    gc.collect()
+    assert [h.count for h in hists] == [2, 2, 2]
+    assert engines[1].stats()["gc_collections"] == 2
+    assert engines[1].stats()["gc_max_ms"] > 0
+    assert step.stats()["gc_max_ms"] > 0
+    # a new window's histogram is watched in the old one's place
+    old = weakref.ref(hists.pop(0))
+    engines[0].reset_stats()
+    gc.collect()
+    assert engines[0].stats()["gc_collections"] == 1 and old() is None
+    # a dropped engine takes its watcher along
+    dropped = weakref.ref(hists.pop(0))
+    del engines[1]
+    gc.collect()
+    gc.collect()        # the start of a collection forgets the dead
+    assert dropped() is None
+    assert all(ref() is not None for _, ref in profiler._gc_watchers)
+    assert len(ours()) == 1 and not profiler._gc_open
+    assert hists[0].count == 5
 
 
 @pytest.fixture
