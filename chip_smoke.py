@@ -5,6 +5,8 @@
                                      training, the eager loop promoted to a
                                      whole-step executable, `LLMEngine`
                                      serving with both attention variants
+                                     over GPT's per-head pools and over a
+                                     latent pool (a small LongCat-Flash)
     python chip_smoke.py --chips 4   four chips: the hybrid-parallel mesh
                                      (dp=2 x mp=2) and its one-device
                                      control, and no other phase
@@ -117,6 +119,36 @@ def make_model(cfg, seed):
     model = GPTForCausalLM(cfg)
     model.bfloat16()
     return model
+
+
+# A LongCat-Flash small enough to compile in seconds, at the published
+# widths of its latent row (512 + 64 values, padded to 640 in the pool)
+# and heads (128 + 64 wide queries, values of 128). Every token chooses
+# EVERY expert, real and identity: with no discrete choice in it, two bf16
+# programs that round at different places part by a rounding and not by
+# an expert (PERF.md section 4, "a model that routes").
+LATENT_SMOKE = dict(
+    vocab_size=4096, hidden_size=512, ffn_hidden_size=1024,
+    expert_ffn_hidden_size=256, num_layers=2, num_attention_heads=16,
+    q_lora_rank=256, kv_lora_rank=512, qk_nope_head_dim=128,
+    qk_rope_head_dim=64, v_head_dim=128, n_routed_experts=4,
+    zero_expert_num=2, moe_topk=6, max_position_embeddings=1024)
+
+
+def make_latent_model(sizes, seed):
+    """`LongCatFlashForCausalLM` at `sizes` around bf16 weights from
+    `seed` (norm scales 1, the rest N(0, `initializer_range`))."""
+    import jax.numpy as jnp
+    from paddle_tpu.incubate.models.longcat_flash import (
+        LongCatFlashConfig, LongCatFlashForCausalLM, param_shapes)
+    cfg = LongCatFlashConfig(**sizes)
+    rng = np.random.default_rng(seed)
+    weights = {
+        name: jnp.ones(shape, jnp.bfloat16) if len(shape) == 1
+        else jnp.asarray(rng.normal(0.0, cfg.initializer_range, shape),
+                         jnp.bfloat16)
+        for name, shape in param_shapes(cfg).items()}
+    return LongCatFlashForCausalLM(cfg, weights=weights)
 
 
 def make_optimizer(model):
@@ -343,25 +375,48 @@ def greedy_gaps(model, prompts, streams, pad_to):
 
 
 def serve_phase(cfg, prompt_lens, max_new_tokens, seed):
+    """GPT's serve legs: per-head K and V pools."""
+    return serve_legs(make_model(cfg, seed), "serve", cfg.vocab_size,
+                      prompt_lens, max_new_tokens, seed)
+
+
+def latent_serve_phase(sizes, prompt_lens, max_new_tokens, seed):
+    """The same legs over a LATENT pool (`make_latent_model`): the
+    blockwise loop and the Pallas kernel over one row every head shares.
+    Its `generate` is not run: it attends expanded where the engine
+    attends absorbed (no token-exact twin), a token at a time with no
+    compiled loop (184 s for one stream on the chip, PR 38); every stream
+    is held to the reference's ranking."""
+    return serve_legs(make_latent_model(sizes, seed), "serve_latent",
+                      sizes["vocab_size"], prompt_lens, max_new_tokens,
+                      seed, with_generate=False)
+
+
+def serve_legs(model, phase, vocab_size, prompt_lens, max_new_tokens, seed,
+               with_generate=True):
     """Serve the same requests with the blockwise loop and with the Pallas
     kernel, each asked for by name (unasked, the engine chooses between
     them from the platform and the pool), and hold every stream to the
-    model itself."""
+    model itself: its full forward's ranking, and (`with_generate`) the
+    first stream to `model.generate`'s tokens."""
     import jax.numpy as jnp
-    model = make_model(cfg, seed)
     rng = np.random.default_rng(seed)
-    prompts = [rng.integers(0, cfg.vocab_size, n).tolist()
+    prompts = [rng.integers(0, vocab_size, n).tolist()
                for n in prompt_lens]
     records, streams = [], {}
     for kernel in ("blockwise", "pallas"):
         streams[kernel], rec = serve_requests(model, prompts,
                                               max_new_tokens, kernel)
-        records.append({"phase": "serve", **rec})
+        records.append({"phase": phase, **rec})
 
-    ref, ref_s = timed(lambda: model.generate(
-        jnp.asarray([prompts[0]], jnp.int32),
-        max_new_tokens=max_new_tokens, do_sample=False)._value)
-    reference = np.asarray(ref)[0].tolist()
+    served = streams["blockwise"] + streams["pallas"]
+    asked = prompts + prompts
+    if with_generate:
+        ref, ref_s = timed(lambda: model.generate(
+            jnp.asarray([prompts[0]], jnp.int32),
+            max_new_tokens=max_new_tokens, do_sample=False)._value)
+        reference = np.asarray(ref)[0].tolist()
+        served, asked = served + [reference], asked + prompts[:1]
 
     # Greedy streams of two bf16 programs that round at different places
     # may swap near-tied tokens, after which their contexts differ (on the
@@ -371,19 +426,18 @@ def serve_phase(cfg, prompt_lens, max_new_tokens, seed):
     # forward ranks it (near-)first; the first stream, whose tokens
     # `generate` is known to reproduce on the chip, must do so exactly.
     pad_to = -(-(max(prompt_lens) + max_new_tokens) // 128) * 128
-    gaps, scale = greedy_gaps(
-        model, prompts + prompts + prompts[:1],
-        streams["blockwise"] + streams["pallas"] + [reference], pad_to)
+    gaps, scale = greedy_gaps(model, asked, served, pad_to)
     slack = 4 * BF16_EPS * scale
     check(max(gaps) <= slack,
           f"a served token is not the reference's choice: worst logit gaps "
           f"{[round(g, 4) for g in gaps]} (bf16 slack {slack:.4f})")
-    check(streams["blockwise"][0] == reference,
-          f"the engine's first stream is not model.generate's: "
-          f"{streams['blockwise'][0]} vs {reference}")
+    if with_generate:
+        check(streams["blockwise"][0] == reference,
+              f"the engine's first stream is not model.generate's: "
+              f"{streams['blockwise'][0]} vs {reference}")
+        records[-1].update({"reference_generate_s": round(ref_s, 2),
+                            "generate_token_identical": True})
     records[-1].update({
-        "reference_generate_s": round(ref_s, 2),
-        "generate_token_identical": True,
         "pallas_streams_identical": sum(
             a == b for a, b in zip(streams["blockwise"],
                                    streams["pallas"])),
@@ -513,6 +567,13 @@ def run_one_chip(seed, cache_dir):
     # edges of them
     for rec in serve_phase(cfg, [32, 57, 64, 200, 230, 256, 400, 512],
                            max_new_tokens=64, seed=seed):
+        emit(rec)
+    gc.collect()
+    # the latent kernel's group is 512 tokens: contexts inside one group,
+    # across its boundary, and in a second
+    for rec in latent_serve_phase(LATENT_SMOKE,
+                                  [32, 57, 200, 256, 460, 500, 512, 600],
+                                  max_new_tokens=64, seed=seed):
         emit(rec)
 
 

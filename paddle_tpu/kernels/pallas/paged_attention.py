@@ -6,9 +6,9 @@ What the file holds: a Pallas TPU kernel and a pure-JAX loop over ONE pool
 layout, ``[L, num_blocks, bs, H*D]`` (serving/cache.py), read at a layer
 index where it lies (the pool is never reshaped or sliced per layer, so a
 program that donates it updates and reads it in place), each with the
-numpy count of what it reads that the engine keeps, and the same loop over
+numpy count of what it reads that the engine keeps, and both again over
 a latent pool. `resolve_paged_kernel` (nn/functional/attention.py) chooses
-between the two from platform, cache kind, pool dtype and shape;
+between kernel and loop from platform, pool dtype and the row's shape;
 `PERF.md` sections 5 and 6 have their chip readings.
 
   * `pallas_paged_attention` — the TPU kernel over a per-head fp pool.
@@ -45,8 +45,13 @@ between the two from platform, cache kind, pool dtype and shape;
     and a shape off the TPU's tiles run.
     `blockwise_streamed_entries` is the host's count
     of what that loop reads, from the same plan and step widths.
-  * `blockwise_latent_attention` — that loop over a pool whose token is
-    one row every head shares (multi-head latent attention, absorbed).
+  * `blockwise_latent_attention`, `pallas_latent_attention` — that loop
+    and that kernel over a pool whose token is ONE row every head shares
+    (multi-head latent attention, absorbed): the kernel's copy pipeline
+    is the per-head kernel's own (`_page_copies`), over one pool and not
+    two; its multiply step has no block-diagonal query: scores are one
+    product of all the heads' queries with the rows as they lie, the
+    value the rows' first lanes.
 
 Numerics: scores, the softmax recurrence, and the output accumulator are
 fp32 regardless of the query/pool dtype; only the final output casts back
@@ -78,7 +83,7 @@ from ...quantization.kv_cache import QMAX as _QMAX, dequantize as _dequant
 
 __all__ = ["blockwise_paged_attention", "blockwise_latent_attention",
            "blockwise_streamed_entries", "pallas_paged_attention",
-           "pallas_copied_pages", "is_eligible"]
+           "pallas_latent_attention", "pallas_copied_pages", "is_eligible"]
 
 _NEG_INF = -1e30
 
@@ -102,11 +107,12 @@ _MIN_WIDTH_SLOTS = 64
 
 
 def is_eligible(num_heads, head_dim, block_size, kv_dtype=jnp.bfloat16):
-    """Can the Pallas kernel run compiled (non-interpret) here, over a
-    per-head pool of `kv_dtype`? Returns (ok, why) — `why` is the
+    """Can the Pallas kernels run compiled (non-interpret) here, over a
+    pool of `kv_dtype` whose row is ``num_heads * head_dim`` values (a
+    latent row is ONE head)? Returns (ok, why) — `why` is the
     attribution detail for the `kernel.fallback` flight-recorder event
-    when not. The shape's part is what the v5e compiler accepts
-    (tests/test_tpu_compile.py): the kernel multiplies the rows of a page
+    when not. The shape's part is what the v5e compiler accepts of both
+    kernels (tests/test_tpu_compile.py): they multiply the rows of a page
     as they lie, so a row is whole 128-lane tiles and a page whole
     sublane tiles of the pool's dtype, and two groups a side live in VMEM
     (`_group_pages`)."""
@@ -388,11 +394,12 @@ _GROUP_BYTES_MAX = 2 * 1024 * 1024
 _SUBLANE_TILE = 8
 
 
-def _group_pages(table_entries, block_size, row, dtype):
-    """Pages a group: `_GROUP_TOKENS` of them, within `_GROUP_BYTES_MAX`
+def _group_pages(table_entries, block_size, row, dtype,
+                 tokens=_GROUP_TOKENS):
+    """Pages a group: `tokens` tokens of them, within `_GROUP_BYTES_MAX`
     a pool and the table; 0 where not even one page fits."""
     page = int(block_size) * int(row) * jnp.dtype(dtype).itemsize
-    return min(max(1, _GROUP_TOKENS // int(block_size)),
+    return min(max(1, int(tokens) // int(block_size)),
                _GROUP_BYTES_MAX // page, int(table_entries))
 
 
@@ -453,6 +460,60 @@ def _weigh(p, v):
     return out[:hp] + out[hp:2 * hp] + out[2 * hp:]
 
 
+def _page_copies(tab_ref, lens_ref, layer, sides, sems, block_size, pages):
+    """The copy pipeline both kernels share. `sides` are the pools to
+    read, each ``(pool in HBM, its VMEM buffers [2, pages * bs, row])``,
+    with a DMA semaphore a side and a buffer half (`sems` ``[sides, 2]``).
+    Returns ``(held, start, wait)``: `held(slot)` the pages the slot's
+    length says it holds (`_slot_pages`), and `start` / `wait`
+    ``(slot, group, half)``, which start, or wait for, the copy of every
+    page of the slot's `group` that holds a token (never one past the
+    slot's length) from ``pool[layer, block]`` into the buffers' `half`."""
+    zero = np.int32(0)
+    bs, n_pages = np.int32(block_size), np.int32(pages)
+    m = np.int32(tab_ref.shape[1])
+
+    def held(slot):
+        return _slot_pages(lens_ref[slot], bs, m, jnp)
+
+    def each_copy(slot, group, half, act):
+        first = group * n_pages
+
+        def page(i, carry=None):
+            if isinstance(i, int):                      # a static page
+                block = tab_ref[slot, first + np.int32(i)]
+                rows = pl.ds(i * block_size, block_size)
+            else:
+                block = tab_ref[slot, first + i]
+                rows = pl.ds(pl.multiple_of(i * bs, block_size), block_size)
+            for side, (pool, vmem) in enumerate(sides):
+                act(pltpu.make_async_copy(pool.at[layer, block],
+                                          vmem.at[half, rows],
+                                          sems.at[np.int32(side), half]))
+            return carry
+
+        count = jnp.minimum(held(slot) - first, n_pages)
+
+        # a whole group needs no trip count and every offset is a
+        # constant (PERF.md section 6, PR 33: 6-24% of a call's time)
+        @pl.when(count == n_pages)
+        def _whole():
+            for i in range(pages):
+                page(i)
+
+        @pl.when(count < n_pages)
+        def _part():
+            jax.lax.fori_loop(zero, count, page, zero)
+
+    def start(slot, group, half):
+        each_copy(slot, group, half, lambda copy: copy.start())
+
+    def wait(slot, group, half):
+        each_copy(slot, group, half, lambda copy: copy.wait())
+
+    return held, start, wait
+
+
 def _ragged_decode_kernel(layer_ref, tab_ref, lens_ref, q_ref, k_hbm, v_hbm,
                           o_ref, k_buf, v_buf, sems, first_ref, *,
                           block_size, pages, heads_padded, head_dim, scale):
@@ -469,52 +530,14 @@ def _ragged_decode_kernel(layer_ref, tab_ref, lens_ref, q_ref, k_hbm, v_hbm,
     # number traces as 64 bits, which Mosaic cannot legalize
     zero, one = np.int32(0), np.int32(1)
     bs, n_pages = np.int32(block_size), np.int32(pages)
-    m = np.int32(tab_ref.shape[1])
     hp, hd = heads_padded, k_buf.shape[-1]
     t_group = n_pages * bs
     nothing = np.float32(0.0)
     layer = layer_ref[0]
 
-    def held(slot):
-        return _slot_pages(lens_ref[slot], bs, m, jnp)
-
-    def each_copy(slot, group, buf, act):
-        """`act` on the copy of every page of the slot's `group` that
-        holds a token: never one past the slot's length."""
-        first = group * n_pages
-
-        def page(i, carry=None):
-            if isinstance(i, int):                      # a static page
-                block = tab_ref[slot, first + np.int32(i)]
-                rows = pl.ds(i * block_size, block_size)
-            else:
-                block = tab_ref[slot, first + i]
-                rows = pl.ds(pl.multiple_of(i * bs, block_size), block_size)
-            for side, pool, vmem in ((zero, k_hbm, k_buf),
-                                     (one, v_hbm, v_buf)):
-                act(pltpu.make_async_copy(pool.at[layer, block],
-                                          vmem.at[buf, rows],
-                                          sems.at[side, buf]))
-            return carry
-
-        count = jnp.minimum(held(slot) - first, n_pages)
-
-        # a whole group needs no trip count and every offset is a
-        # constant (PERF.md section 6, PR 33: 6-24% of a call's time)
-        @pl.when(count == n_pages)
-        def _whole():
-            for i in range(pages):
-                page(i)
-
-        @pl.when(count < n_pages)
-        def _part():
-            jax.lax.fori_loop(zero, count, page, zero)
-
-    def start(slot, group, buf):
-        each_copy(slot, group, buf, lambda copy: copy.start())
-
-    def wait(slot, group, buf):
-        each_copy(slot, group, buf, lambda copy: copy.wait())
+    held, start, wait = _page_copies(
+        tab_ref, lens_ref, layer, ((k_hbm, k_buf), (v_hbm, v_buf)), sems,
+        block_size, pages)
 
     @pl.when(s == 0)
     def _first_slot():
@@ -623,3 +646,149 @@ def pallas_paged_attention(q, k_pools, v_pools, layer, block_tables, lens,
             block_tables.astype(jnp.int32), lens.astype(jnp.int32),
             q.reshape(s, 1, hd), k_pools, v_pools)
     return out.reshape(s, h, d)
+
+
+# ---------------------------------------------------------------------------
+# Pallas TPU kernel over a LATENT pool: the same plan, one pool, a shared row
+# ---------------------------------------------------------------------------
+
+# A latent group: one pool's pages leave VMEM room for more tokens a step
+# than two pools' do (PERF.md section 5 has the chip readings of 8, 16, 32,
+# 48 and 64 pages of 16 tokens: 32 is the cell's contexts' best by 3% and a
+# full table's by 20%).
+_LATENT_GROUP_TOKENS = 512
+
+
+def _latent_decode_kernel(layer_ref, tab_ref, lens_ref, q_ref, pool_hbm,
+                          o_ref, buf, sems, first_ref, *, block_size, pages):
+    """`_ragged_decode_kernel`'s plan over ONE pool whose row every head
+    shares: a slot a grid step, its held pages copied a group of `pages`
+    at a time into one of two VMEM buffers ``[2, pages * bs, row]``, the
+    next group (or the next slot's first) in flight while this one is
+    multiplied. The multiply step is latent attention's: scores
+    ``[H, tokens]`` are ONE product of the queries ``[H, row]`` (already
+    scaled, in the row's space and the pool's dtype) with the rows as
+    they lie, and the output ``[H, value]`` is p x the first `value`
+    lanes of the same rows, `value` being the output block's width."""
+    s = pl.program_id(0)
+    n_slots = pl.num_programs(0)
+    # every constant a 32-bit one (Mosaic cannot legalize x64's)
+    zero, one = np.int32(0), np.int32(1)
+    bs, n_pages = np.int32(block_size), np.int32(pages)
+    h, value = o_ref.shape
+    t_group = n_pages * bs
+    nothing = np.float32(0.0)
+    layer = layer_ref[0]
+
+    held, start, wait = _page_copies(
+        tab_ref, lens_ref, layer, ((pool_hbm, buf),), sems, block_size,
+        pages)
+
+    @pl.when(s == 0)
+    def _first_slot():
+        # rows a copy never reaches are multiplied by p == 0: they must
+        # be numbers, which fresh VMEM need not hold
+        buf[...] = jnp.zeros_like(buf)
+        first_ref[0] = zero
+        start(zero, zero, zero)
+
+    base = first_ref[0]
+    length = lens_ref[s]
+    groups = (held(s) + (n_pages - one)) // n_pages
+    q = q_ref[...]                                      # [H, row]
+    offs = jax.lax.broadcasted_iota(jnp.int32, (h, t_group), 1)
+
+    def group(g, carry):
+        mx, l, acc = carry
+        cur = (base + g) & one
+        more = g + one < groups
+        nxt_slot = jnp.where(more, s, s + one)
+
+        @pl.when(nxt_slot < n_slots)
+        def _prefetch():
+            start(nxt_slot, jnp.where(more, g + one, zero), one - cur)
+
+        wait(s, g, cur)
+        rows = buf[cur]                                 # [T, row]
+        scores = _exact_dot(q, rows, ((1,), (1,)))      # [H, T]
+        valid = g * t_group + offs <= length
+        scores = jnp.where(valid, scores, np.float32(_NEG_INF))
+        m_new = jnp.maximum(mx, jnp.max(scores, axis=1, keepdims=True))
+        p = jnp.where(valid, jnp.exp(scores - m_new), nothing)
+        alpha = jnp.exp(mx - m_new)
+        l = alpha * l + jnp.sum(p, axis=1, keepdims=True)
+        # p in the rows' type, as the loop weighs it: its one caller
+        # rounds the output to that type at once, and p's other 16 bits
+        # cost a fifth of the call (PERF.md section 5)
+        acc = acc * alpha + _exact_dot(p.astype(rows.dtype),
+                                       rows[:, :value], ((1,), (0,)))
+        return m_new, l, acc
+
+    _, l, acc = jax.lax.fori_loop(
+        zero, groups, group,
+        (jnp.full((h, 1), np.float32(_NEG_INF), jnp.float32),
+         jnp.zeros((h, 1), jnp.float32),
+         jnp.zeros((h, value), jnp.float32)))
+    first_ref[0] = (base + groups) & one
+    o_ref[...] = (acc / jnp.maximum(l, np.float32(1e-30))).astype(
+        o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "block_size", "value_width", "scale", "interpret", "group_pages"))
+def pallas_latent_attention(q, pool, layer, block_tables, lens, block_size,
+                            value_width, scale, interpret=False,
+                            group_pages=None):
+    """The Pallas kernel over a latent pool: `blockwise_latent_attention`'s
+    contract (q ``[S, H, W]`` in the row's space, pool
+    ``[L, num_blocks, bs, >= W]`` fp, the value the first `value_width`
+    values of a row; returns ``[S, H, value_width]`` float32), by
+    `pallas_paged_attention`'s plan: the pool stays in HBM, the kernel
+    copies only `_slot_pages` pages of a slot, `group_pages` a step
+    (default: `_LATENT_GROUP_TOKENS` of them), and the layer is an
+    OPERAND of this jitted function, so a program lowers ONE kernel for
+    all its sublayers' calls.
+
+    Numerics, the loop's: the queries are scaled in float32 and rounded
+    ONCE to the pool's dtype, p is rounded to it for the second product,
+    both products accumulate in float32 (bf16 operands: one exact pass
+    each), and the scores, the recurrence, the accumulator and the
+    result are float32; masked positions weigh exactly zero."""
+    s, h, w_q = q.shape
+    bs = int(block_size)
+    m = block_tables.shape[1]
+    row = pool.shape[-1]
+    pages = int(group_pages or _group_pages(m, bs, row, pool.dtype,
+                                            _LATENT_GROUP_TOKENS))
+    # whole tiles: of rows for the queries (bf16's 16), of lanes for the
+    # value; zero queries and the lanes past the value are cut off below
+    hp = -(-h // 16) * 16
+    value = min(-(-int(value_width) // 128) * 128, row)
+    q = q.astype(jnp.float32) * np.float32(scale)
+    q = jnp.pad(q, ((0, 0), (0, hp - h), (0, row - w_q))).astype(pool.dtype)
+    zero = _ZERO
+
+    def slot_spec(width):
+        return pl.BlockSpec((None, hp, width),
+                            lambda si, *_: (si, zero, zero))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(s,),
+        in_specs=[slot_spec(row), pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=slot_spec(value),
+        scratch_shapes=[pltpu.VMEM((2, pages * bs, row), pool.dtype),
+                        pltpu.SemaphoreType.DMA((1, 2)),
+                        pltpu.SMEM((1,), jnp.int32)])
+    out = pl.pallas_call(
+        functools.partial(_latent_decode_kernel, block_size=bs, pages=pages),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((s, hp, value), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name="latent_decode_attention")(
+            jnp.asarray(layer, jnp.int32).reshape(1),
+            block_tables.astype(jnp.int32), lens.astype(jnp.int32),
+            q, pool)
+    return out[:, :h, :value_width]

@@ -203,16 +203,16 @@ PAGED_KERNELS = ("pallas", "blockwise", "reference")
 
 
 def resolve_paged_kernel(kernel=None, num_heads=None, head_dim=None,
-                         block_size=None, interpret=False, kv_dtype=None,
-                         cache_kind="kv"):
+                         block_size=None, interpret=False, kv_dtype=None):
     """Resolve the serving attention variant -> the one that will run.
 
     With NO request (no `kernel`, FLAGS_serve_attention_kernel unset) the
-    choice follows what can be observed here: `pallas` on a TPU over a
-    per-head (`cache_kind` ``"kv"``) fp pool of `kv_dtype` whose shape the
-    kernel takes (`paged_attention.is_eligible`), `blockwise` for anything
-    else: a latent pool, an int8 pool, another platform, a row or a block
-    off the tiles. An explicit request that cannot run falls back to
+    choice follows what can be observed here: `pallas` on a TPU over an fp
+    pool of `kv_dtype` whose shape the kernels take
+    (`paged_attention.is_eligible`; a latent pool is asked as the ONE head
+    its row is, `CacheSpec`'s `num_heads` 1), `blockwise` for anything
+    else: an int8 pool, another platform, a row or a block off the tiles.
+    An explicit request that cannot run falls back to
     `blockwise` (same math, no Mosaic constraints) and is VISIBLE: a
     `kernel.fallback` flight-recorder event attributes the demotion,
     never silent. `interpret` (the CPU parity path) lifts the platform's
@@ -224,8 +224,7 @@ def resolve_paged_kernel(kernel=None, num_heads=None, head_dim=None,
     kv_dtype = jnp.dtype(jnp.bfloat16 if kv_dtype is None else kv_dtype)
     req = kernel or str(_FLAGS.get("FLAGS_serve_attention_kernel") or "")
     if not req:
-        ok = cache_kind == "kv" and _pk.is_eligible(
-            num_heads, head_dim, block_size, kv_dtype)[0]
+        ok, _ = _pk.is_eligible(num_heads, head_dim, block_size, kv_dtype)
         return "pallas" if ok else "blockwise"
     if req not in PAGED_KERNELS:
         raise ValueError(
@@ -378,7 +377,7 @@ def paged_decode_attention(q, k_new, v_new, k_pools, v_pools, layer,
 def paged_latent_decode_attention(q, parts_new, pool, layer, block_tables,
                                   seq_lens, active, block_size, value_width,
                                   scale, kernel=None, chunk_blocks=None,
-                                  min_width=None):
+                                  min_width=None, interpret=False):
     """`paged_decode_attention` for a LATENT cache (serving/cache.py
     `CacheSpec`, kind ``"latent"``): a token holds ONE row that all heads
     share, and attention reads it absorbed.
@@ -388,9 +387,12 @@ def paged_latent_decode_attention(q, parts_new, pool, layer, block_tables,
     side (zeros up to the pool's width) into `pool`
     ``[L, num_blocks, block_size, >= W]``. A token's value is the first
     `value_width` values of its row; scores are ``scale * q . row``.
-    `kernel`: ``"blockwise"`` (the loop over the chunks that hold tokens)
-    or ``"reference"`` (a dense gather of the whole table). Returns
-    ``(out [S, H, value_width] float32, the written pool)``."""
+    `kernel`: ``"pallas"`` (the TPU kernel that copies only the pages
+    that hold tokens; `interpret` runs it on any backend), ``"blockwise"``
+    (the loop over the chunks that hold tokens, which alone reads
+    `chunk_blocks` and `min_width`) or ``"reference"`` (a dense gather of
+    the whole table). Returns ``(out [S, H, value_width] float32, the
+    written pool)``."""
     s = q.shape[0]
     lens = jnp.where(active, seq_lens, 0).astype(jnp.int32)
     rows = jnp.arange(s, dtype=jnp.int32)
@@ -421,10 +423,16 @@ def paged_latent_decode_attention(q, parts_new, pool, layer, block_tables,
             out = blockwise_latent_attention(
                 q, pool, layer, block_tables, lens, block_size,
                 value_width, scale, chunk_blocks, min_width)
+        elif kernel == "pallas":
+            from ...kernels.pallas.paged_attention import (
+                pallas_latent_attention)
+            out = pallas_latent_attention(
+                q, pool, layer, block_tables, lens, block_size,
+                value_width, scale, interpret=interpret)
         else:
             raise ValueError(
                 f"no {kernel!r} attention over a latent cache: "
-                "'blockwise' or 'reference'")
+                "'pallas', 'blockwise' or 'reference'")
     return out, pool
 
 

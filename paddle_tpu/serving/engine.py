@@ -38,8 +38,8 @@ millions of users"), combining:
     1 (see `LLMEngine`);
   * a **kernel tier** (PR 11): the decode step's paged attention runs
     streaming softmax over the pages that hold tokens
-    (kernels/pallas/paged_attention.py: a Pallas kernel on a TPU over a
-    per-head fp pool, a length-bounded pure-JAX loop elsewhere, chosen by
+    (kernels/pallas/paged_attention.py: a Pallas kernel on a TPU over an
+    fp pool on the tiles, a length-bounded pure-JAX loop elsewhere, chosen by
     `resolve_paged_kernel`; `attention_kernel=` /
     FLAGS_serve_attention_kernel name one explicitly)
     instead of gathering a dense `[S, T, H, D]` context, and
@@ -617,11 +617,8 @@ class LLMEngine:
         if spec.kind == "latent":
             # what a latent pool does not do yet is refused by name, here:
             # none of it may run silently wrong
-            asked = attention_kernel or str(
-                _FLAGS.get("FLAGS_serve_attention_kernel") or "")
             for option, on in (
                     ("kv_dtype='int8'", self._kv_quantized),
-                    ("attention_kernel='pallas'", asked == "pallas"),
                     ("enable_prefix_cache", enable_prefix_cache),
                     ("max_adapters", max_adapters > 0)):
                 if on:
@@ -631,7 +628,7 @@ class LLMEngine:
         self._attn_kernel = resolve_paged_kernel(
             attention_kernel, num_heads=spec.num_heads,
             head_dim=spec.head_dim, block_size=self.block_size,
-            kv_dtype=self._kv_dtype, cache_kind=spec.kind)
+            kv_dtype=self._kv_dtype)
         if self._kv_quantized:
             _EVENTS.emit("kernel.quantized", "serve.decode",
                          reason="kv_quantized",

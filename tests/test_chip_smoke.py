@@ -184,6 +184,24 @@ class TestOneChipRehearsal:
             assert r["decode_compiles"] == 1
         assert recs[-1]["worst_logit_gap"] <= recs[-1]["logit_gap_slack"]
 
+    def test_serve_latent_both_variants(self, kernels_interpreted):
+        """The latent legs at a tiny size: a row of 40 + 8 values padded
+        to one lane tile, pages of 16 tokens, every expert chosen."""
+        sizes = dict(chip_smoke.LATENT_SMOKE, vocab_size=128,
+                     hidden_size=64, ffn_hidden_size=64,
+                     expert_ffn_hidden_size=32, num_attention_heads=2,
+                     q_lora_rank=32, kv_lora_rank=40, qk_nope_head_dim=16,
+                     qk_rope_head_dim=8, v_head_dim=16,
+                     max_position_embeddings=64)
+        recs = chip_smoke.latent_serve_phase(sizes, [8, 13, 16, 41],
+                                             max_new_tokens=6, seed=0)
+        assert [(r["phase"], r["attention_kernel"]) for r in recs] == [
+            ("serve_latent", "blockwise"), ("serve_latent", "pallas")]
+        for r in recs:
+            assert r["requests"] == 4 and r["tokens"] == 24
+            assert r["decode_compiles"] == 1
+        assert recs[-1]["worst_logit_gap"] <= recs[-1]["logit_gap_slack"]
+
     def test_demoted_kernel_fails_the_serve_phase(self):
         """Off the chip (and not steered) `pallas` demotes to `blockwise`
         with a kernel.fallback event: legitimate in production, a failure

@@ -857,6 +857,162 @@ class TestRaggedPallasKernel:
 
 
 # ---------------------------------------------------------------------------
+# the Pallas kernel over a latent pool (ISSUE 38): the same plan, one pool
+# ---------------------------------------------------------------------------
+
+LATENT_BS, LATENT_M, LATENT_GROUP = 8, 12, 4    # three groups of four pages
+LATENT_W, LATENT_ROW, LATENT_VALUE, LATENT_HEADS = 24, 128, 16, 4
+LATENT_SCALE = 0.3
+
+# (length, active): an inactive slot whose length the mask must hide, an
+# empty one, the last position of a page and the first of the next, of a
+# group and of the next, one group + 1, a slot whose LAST group is partial
+# followed by a slot with one page (the buffers' parity crosses the grid
+# step there), the table's last entry, and a one-page slot at the end
+LATENT_SLOTS = ((37, False), (0, True), (LATENT_BS - 1, True),
+                (LATENT_BS, True), (LATENT_GROUP * LATENT_BS - 1, True),
+                (LATENT_GROUP * LATENT_BS, True),
+                (LATENT_GROUP * LATENT_BS + 1, True), (75, True), (3, True),
+                (LATENT_M * LATENT_BS - 1, True), (5, True))
+
+
+def _latent_case(dtype, seed=0):
+    """A latent pool with a row of `LATENT_W` values padded with zeros to
+    `LATENT_ROW`, a churned allocator's tables cleared to the null block
+    past the pages each slot holds (an inactive slot holds none), and the
+    new token's row in its two parts."""
+    lens = np.asarray([n for n, _ in LATENT_SLOTS], np.int32)
+    active = np.asarray([a for _, a in LATENT_SLOTS])
+    S = len(lens)
+    rng = np.random.default_rng(seed)
+    nb = 1 + S * LATENT_M
+    mk = lambda *sh: jnp.asarray(rng.standard_normal(sh), jnp.float32)
+    pool = mk(LAYERS, nb, LATENT_BS, LATENT_ROW)
+    pool = pool.at[..., LATENT_W:].set(0.0).astype(dtype)
+    pages = np.where(active, lens // LATENT_BS + 1, 0)
+    tables = np.zeros((S, LATENT_M), np.int32)
+    ids = rng.permutation(np.arange(1, nb))
+    for s in range(S):
+        tables[s, :pages[s]] = ids[s * LATENT_M:s * LATENT_M + pages[s]]
+    new = (mk(S, LATENT_VALUE).astype(dtype),
+           mk(S, LATENT_W - LATENT_VALUE).astype(dtype))
+    return (mk(S, LATENT_HEADS, LATENT_W), new, pool, jnp.asarray(tables),
+            jnp.asarray(lens), jnp.asarray(active))
+
+
+def _latent_attend(case, kernel, **kw):
+    from paddle_tpu.nn.functional.attention import \
+        paged_latent_decode_attention
+    q, new, pool, tables, lens, active = case
+    with jax.default_matmul_precision("highest"):
+        out, written = paged_latent_decode_attention(
+            q, new, pool, LAYER, tables, lens, active, LATENT_BS,
+            value_width=LATENT_VALUE, scale=LATENT_SCALE, kernel=kernel,
+            **kw)
+    return np.asarray(out), written
+
+
+class TestRaggedLatentKernel:
+    @pytest.mark.parametrize("dtype", ("float32", "bfloat16"))
+    @pytest.mark.parametrize("other", ("reference", "blockwise"))
+    def test_ragged_lengths_match_the_oracle_and_the_loop(self, other,
+                                                          dtype):
+        """Every active slot of a ragged batch reads what the dense gather
+        and what the blockwise loop read, over float32 and bf16 rows,
+        through the entry the model calls; the result is float32 and the
+        written pool is the other variant's."""
+        case = _latent_case(jnp.dtype(dtype))
+        got, written = _latent_attend(case, "pallas", interpret=True)
+        want, same = _latent_attend(case, other)
+        assert got.dtype == np.float32 and got.shape == want.shape
+        assert np.array_equal(np.asarray(written, np.float32),
+                              np.asarray(same, np.float32))
+        active = np.asarray(case[-1])
+        # (bf16: the kernel and the loop round the queries to the rows'
+        # type, the oracle does not)
+        np.testing.assert_allclose(
+            got[active], want[active], rtol=0.0,
+            atol=2e-2 if dtype == "bfloat16" else 2e-5)
+
+    @pytest.mark.parametrize("dtype", ("float32", "bfloat16"))
+    @pytest.mark.parametrize("pages", (LATENT_GROUP, 3, 1, LATENT_M))
+    def test_every_group_size_gives_the_same_attention(self, pages, dtype):
+        """Groups of four pages (the lengths' edges are this size's), of
+        three (no length is on its boundaries), of one page, and one
+        group for the whole table: the plan changes, the output does
+        not."""
+        q, new, pool, tables, lens, active = _latent_case(jnp.dtype(dtype),
+                                                          seed=1)
+        eff = jnp.where(active, lens, 0)
+        run = lambda pages: np.asarray(
+            paged_attention.pallas_latent_attention(
+                q, pool, LAYER, tables, eff, LATENT_BS, LATENT_VALUE,
+                LATENT_SCALE, interpret=True, group_pages=pages))
+        with jax.default_matmul_precision("highest"):
+            loop = np.asarray(paged_attention.blockwise_latent_attention(
+                q, pool, LAYER, tables, eff, LATENT_BS, LATENT_VALUE,
+                LATENT_SCALE))
+            got = run(pages)
+        assert got.shape == (len(LATENT_SLOTS), LATENT_HEADS, LATENT_VALUE)
+        # an inactive slot reads its one page, the null block: numbers
+        assert np.isfinite(got).all()
+        np.testing.assert_allclose(
+            got, loop, rtol=0.0, atol=5e-3 if dtype == "bfloat16" else 2e-5)
+
+    @pytest.mark.parametrize("dtype", ("float32", "bfloat16"))
+    def test_pages_that_hold_no_attended_token_are_never_copied(self,
+                                                                dtype):
+        """NaN in every page that holds no token some slot attends to (the
+        pages a table names past its slot's length, every block no table
+        names, all of the other sublayer): the kernel is bounded by the
+        lengths and not only masked, so its output is bitwise the clean
+        pool's."""
+        q, new, pool, tables, lens, active = _latent_case(jnp.dtype(dtype),
+                                                          seed=3)
+        eff = np.where(np.asarray(active), np.asarray(lens), 0)
+        full = np.asarray(tables).copy()
+        spare = iter(np.setdiff1d(np.arange(1, pool.shape[1]), full))
+        pages = eff // LATENT_BS + 1
+        attended = np.zeros(pool.shape[1], bool)
+        for s in range(full.shape[0]):
+            if active[s]:
+                for j in range(pages[s], LATENT_M):
+                    full[s, j] = next(spare)
+            attended[full[s, :pages[s]]] = True
+        poison = np.ones(pool.shape[:2], bool)
+        poison[LAYER] = ~attended
+        assert poison[LAYER].sum() > pool.shape[1] // 2
+        bad = jnp.where(jnp.asarray(poison)[:, :, None, None], jnp.nan,
+                        pool).astype(pool.dtype)
+        run = lambda pool: np.asarray(
+            paged_attention.pallas_latent_attention(
+                q, pool, LAYER, jnp.asarray(full), jnp.asarray(eff),
+                LATENT_BS, LATENT_VALUE, LATENT_SCALE, interpret=True,
+                group_pages=LATENT_GROUP))
+        got = run(bad)
+        assert np.isfinite(got).all()
+        assert np.array_equal(got, run(pool))
+
+    def test_the_plan_is_one_pools(self):
+        """A latent group is `_LATENT_GROUP_TOKENS` tokens of pages, within
+        the bytes a group of one pool may take and within the table; the
+        host's count of what the kernel copies is `pallas_copied_pages`,
+        whatever the pool holds."""
+        tokens = paged_attention._LATENT_GROUP_TOKENS
+        plan = lambda *a: paged_attention._group_pages(*a, tokens)
+        assert plan(128, 16, 640, jnp.bfloat16) == tokens // 16
+        assert plan(8, 16, 640, jnp.bfloat16) == 8
+        assert plan(128, 16, 640, jnp.float32) == min(
+            tokens // 16, paged_attention._GROUP_BYTES_MAX // (16 * 640 * 4))
+        lens = np.asarray([n for n, _ in LATENT_SLOTS])
+        active = np.asarray([a for _, a in LATENT_SLOTS])
+        copied, held = paged_attention.pallas_copied_pages(
+            lens, active, LATENT_M, LATENT_BS)
+        assert held == int((lens // LATENT_BS + 1)[active].sum())
+        assert copied == held + 1
+
+
+# ---------------------------------------------------------------------------
 # keying: dispatch cache, AOT fingerprint, fallback attribution
 # ---------------------------------------------------------------------------
 

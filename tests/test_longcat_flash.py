@@ -431,7 +431,6 @@ def test_a_launched_prefills_counters_come_with_its_first_token(model):
 
 @pytest.mark.parametrize("option,named", [
     ({"kv_dtype": "int8"}, "kv_dtype='int8'"),
-    ({"attention_kernel": "pallas"}, "attention_kernel='pallas'"),
     ({"enable_prefix_cache": True}, "enable_prefix_cache"),
     ({"max_adapters": 2}, "max_adapters")])
 def test_an_option_the_latent_cache_lacks_is_refused_by_name(model, option,
@@ -439,6 +438,53 @@ def test_an_option_the_latent_cache_lacks_is_refused_by_name(model, option,
     with pytest.raises(ValueError, match=named):
         LLMEngine(model, max_batch_size=2, block_size=4, max_context=32,
                   **option)
+
+
+def test_the_engine_serves_through_the_latent_kernel(model, weights,
+                                                     monkeypatch):
+    """On a TPU (here: told so, the kernel in the interpreter) an engine
+    over a latent cache takes `attention_kernel='pallas'`, and chooses
+    it unasked for a row and a page on the tiles: it serves the blockwise
+    engine's tokens, compiles one decode program, and counts the pages
+    the kernel copies: those that hold tokens, and one for every
+    inactive slot of a launch."""
+    from paddle_tpu.kernels.pallas import paged_attention as pa
+
+    def serve_through(kernel, block_size=8):
+        engine = LLMEngine(model, max_batch_size=4, block_size=block_size,
+                           max_context=48, attention_kernel=kernel)
+        rng = np.random.default_rng(1)
+        prompts = [rng.integers(0, FILE["vocab_size"], n).tolist()
+                   for n in (5, 9, 13, 7, 11, 6)]
+        reqs = [engine.add_request(p, max_new_tokens=10) for p in prompts]
+        highest(engine.run)
+        return engine, prompts, [list(r.generated) for r in reqs]
+
+    _, prompts, expect = serve_through("blockwise")
+    monkeypatch.setattr(pa, "_on_tpu", lambda: True)
+    kernel = pa.pallas_latent_attention
+    calls = []
+    monkeypatch.setattr(
+        pa, "pallas_latent_attention",
+        lambda *args, interpret=False, **kw: calls.append(1) or kernel(
+            *args, interpret=True, **kw))
+    for asked in ("pallas", None):
+        engine, _, served = serve_through(asked)
+        st, raw = engine.stats(), engine._stats
+        assert st["attention_kernel"] == "pallas"
+        assert served == expect
+        assert st["decode_compiles"] == 1
+        idle = raw.launches * 4 - raw.decode_tokens
+        assert idle > 0
+        assert raw.attn_entries_streamed == raw.attn_entries_held + idle
+        assert 0.0 < st["attn_held_share"] < st["attn_streamed_share"] < 1.0
+    # traced once a cached sublayer, in each engine's ONE decode program
+    assert len(calls) == 2 * model.cache_spec().num_layers
+    gaps, scale = gaps_of(weights, prompts, served)
+    assert gaps.max() <= TOL * scale
+    # a page off the sublane tiles: the loop, unasked and with no event
+    assert serve_through(None, block_size=4)[0].stats()[
+        "attention_kernel"] == "blockwise"
 
 
 def test_this_models_weights_are_program_arguments_and_never_read(model):

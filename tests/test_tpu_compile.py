@@ -258,10 +258,12 @@ def test_paged_eligibility_is_what_the_compiler_accepts(v5e, monkeypatch):
 
 
 def test_the_unrequested_variant_follows_what_is_observed(monkeypatch):
-    """No flag, no argument: `pallas` on a TPU over a per-head fp pool
-    whose shape the kernel takes; `blockwise` for a latent cache, an int8
-    pool, a row off the lane tiles, and off the TPU: with no event, for
-    nothing was asked for and nothing fell back."""
+    """No flag, no argument: `pallas` on a TPU over an fp pool whose shape
+    the kernels take, per-head or latent (a latent row is asked as the
+    one head `CacheSpec` describes); `blockwise` for an int8 pool, a row
+    off the lane tiles or a page off the sublane tiles of either kind,
+    and off the TPU: with no event, for nothing was asked for and nothing
+    fell back."""
     from paddle_tpu.framework.flags import get_flags, set_flags
     from paddle_tpu.nn.functional.attention import resolve_paged_kernel
     from paddle_tpu.profiler.events import (clear_fusion_events,
@@ -269,17 +271,27 @@ def test_the_unrequested_variant_follows_what_is_observed(monkeypatch):
     assert get_flags(["FLAGS_serve_attention_kernel"]) == {
         "FLAGS_serve_attention_kernel": ""}
     cell = dict(num_heads=12, head_dim=64, block_size=16)
+    # `serve_longcat_decode`'s row: 512 + 64 values padded to 640
+    latent = dict(num_heads=1, head_dim=LATENT_ROW, block_size=16)
     prev = get_flags(["FLAGS_profiler_events"])
     set_flags({"FLAGS_profiler_events": True})
     clear_fusion_events()
     try:
         assert resolve_paged_kernel(**cell) == "blockwise"      # a CPU
+        assert resolve_paged_kernel(**latent) == "blockwise"
         monkeypatch.setattr(paged_attention, "_on_tpu", lambda: True)
         assert resolve_paged_kernel(**cell) == "pallas"
+        assert resolve_paged_kernel(**latent) == "pallas"
+        assert resolve_paged_kernel(**latent, kv_dtype=jnp.float32) \
+            == "pallas"
         assert resolve_paged_kernel(**cell, kv_dtype=jnp.float32) == "pallas"
         assert resolve_paged_kernel(num_heads=16, head_dim=128,
                                     block_size=16) == "pallas"
-        assert resolve_paged_kernel(**cell, cache_kind="latent") \
+        assert resolve_paged_kernel(num_heads=1, head_dim=576,
+                                    block_size=16) == "blockwise"
+        assert resolve_paged_kernel(**dict(latent, block_size=4)) \
+            == "blockwise"
+        assert resolve_paged_kernel(**latent, kv_dtype=jnp.int8) \
             == "blockwise"
         assert resolve_paged_kernel(**cell, kv_dtype=jnp.int8) == "blockwise"
         assert resolve_paged_kernel(num_heads=3, head_dim=16,
@@ -426,11 +438,14 @@ LATENT_TABLE, LATENT_HEADS, LATENT_ROW, LATENT_VALUE = 128, 64, 640, 512
 LATENT_POOL = (CELL_LAYERS, CELL_BLOCKS, BLOCK, LATENT_ROW)
 
 
-@pytest.mark.parametrize("program", ["decode_blockwise", "prefill_512"])
+@pytest.mark.parametrize("program", ["decode_blockwise", "decode_pallas",
+                                     "prefill_512"])
 def test_the_donated_latent_pool_is_updated_where_it_lies(v5e, program):
     """The ONE latent pool comes in row-major and goes out in the buffer it
     came in; no instruction of the pool's shape is a `copy`, a
-    `concatenate` or a `pad`; the second (empty) pool costs nothing."""
+    `concatenate` or a `pad`; the second (empty) pool costs nothing. Under
+    `pallas` the kernel reads the written pool where it lies, and the
+    sublayers share ONE lowered kernel (the layer is its operand)."""
     from paddle_tpu.nn.functional.attention import \
         paged_latent_decode_attention
     if program == "prefill_512":
@@ -446,7 +461,7 @@ def test_the_donated_latent_pool_is_updated_where_it_lies(v5e, program):
                 out, k_pools = paged_latent_decode_attention(
                     q, (row, key), k_pools, layer, tables, lens, active,
                     BLOCK, value_width=LATENT_VALUE, scale=0.07,
-                    kernel="blockwise", chunk_blocks=16)
+                    kernel=program[len("decode_"):], chunk_blocks=16)
                 q = q + jnp.pad(out, ((0, 0), (0, 0), (0, 64)))
             return q, k_pools, v_pools
         shapes = [((CELL_SLOTS, LATENT_HEADS, 576), jnp.float32),
@@ -461,7 +476,11 @@ def test_the_donated_latent_pool_is_updated_where_it_lies(v5e, program):
                                            len(args) - 1)).lower(
         *args).compile()
     text = compiled.as_text()
-    assert "tpu_custom_call" not in text
+    assert ("tpu_custom_call" in text) == (program == "decode_pallas")
+    if program == "decode_pallas":
+        assert "while" not in text
+        lowered = jax.jit(fn).lower(*args).as_text()
+        assert lowered.count("tpu_custom_call") == 1 < CELL_LAYERS
     pool = "bf16[" + ",".join(map(str, LATENT_POOL)) + "]"
     made = re.findall(r"= " + re.escape(pool) + r"\{([\d,]*)\S* ([\w-]+)\(",
                       text)
@@ -472,3 +491,65 @@ def test_the_donated_latent_pool_is_updated_where_it_lies(v5e, program):
     assert not opcodes & {"copy", "concatenate", "pad"}, opcodes
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= 2 * math.prod(LATENT_POOL)
+
+
+def latent_kernel(block, value=LATENT_VALUE, group_pages=None):
+    return lambda q, pool, tables, lens: \
+        paged_attention.pallas_latent_attention(
+            q, pool, 1, tables, lens, block, value, 0.07,
+            group_pages=group_pages)
+
+
+def latent_shapes(heads, row, block, pool_dtype, slots=SLOTS, table=TABLE,
+                  pool=(LAYERS, POOL), width=None):
+    return [((slots, heads, width or row), jnp.float32),
+            (pool + (block, row), pool_dtype),
+            ((slots, table), jnp.int32), ((slots,), jnp.int32)]
+
+
+def test_the_latent_kernel_at_the_cells_geometry(v5e):
+    """`serve_longcat_decode`'s decode attention as the engine traces it:
+    128 slots, 128 table entries, 64 heads' queries of 576 values in
+    float32 over the cell's whole `bf16[8,16385,16,640]` pool, the value
+    a row's first 512: the blockwise loop is gone from the program."""
+    text = compile_for(v5e, latent_kernel(BLOCK), *latent_shapes(
+        LATENT_HEADS, LATENT_ROW, BLOCK, jnp.bfloat16, slots=CELL_SLOTS,
+        table=LATENT_TABLE, pool=(8, 16385), width=576))
+    assert "while" not in text
+
+
+def test_latent_eligibility_is_what_the_compiler_accepts(v5e, monkeypatch):
+    """`is_eligible`, asked of a latent row as the ONE head `CacheSpec`
+    describes, is true exactly where the latent kernel compiles: a row of
+    whole 128-lane tiles and a page of whole sublane tiles, whatever the
+    fp dtype, the number of heads and the value's width (both are padded
+    to whole tiles around the kernel)."""
+    monkeypatch.setattr(paged_attention, "_on_tpu", lambda: True)
+
+    def compiles(row, block, pool_dtype, heads=LATENT_HEADS, value=None):
+        ok, why = paged_attention.is_eligible(1, row, block, pool_dtype)
+        value = value or min(row, LATENT_VALUE)
+        shapes = latent_shapes(heads, row, block, pool_dtype, table=8,
+                               pool=(LAYERS, 65))
+        if ok:
+            assert why is None
+            compile_for(v5e, latent_kernel(block, value), *shapes)
+        else:
+            with pytest.raises(Exception, match="vmem|aligned to tiling"):
+                compile_for(v5e, latent_kernel(block, value, 8), *shapes)
+        return why
+
+    for pool_dtype in (jnp.bfloat16, jnp.float32):
+        assert compiles(LATENT_ROW, BLOCK, pool_dtype) is None
+        assert compiles(128, 8, pool_dtype, heads=4, value=16) is None
+    # DeepSeek-V3's 128 heads; a count of heads off the tiles
+    assert compiles(LATENT_ROW, BLOCK, jnp.bfloat16, heads=128) is None
+    assert compiles(LATENT_ROW, BLOCK, jnp.bfloat16, heads=12) is None
+    # the row as published (512 + 64), a page of four tokens
+    assert compiles(576, BLOCK, jnp.bfloat16) == "row_not_whole_lane_tiles"
+    assert compiles(LATENT_ROW, 4, jnp.float32) == \
+        "block_not_whole_sublane_tiles"
+    # the plan at the cell's geometry: 512 tokens a group
+    assert paged_attention._group_pages(
+        LATENT_TABLE, BLOCK, LATENT_ROW, jnp.bfloat16,
+        paged_attention._LATENT_GROUP_TOKENS) == 32
