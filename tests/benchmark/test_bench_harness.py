@@ -17,12 +17,17 @@ from benchmark import harness, run as bench_run  # noqa: E402
 from benchmark.loops import serving  # noqa: E402
 
 REPO = tiny_root.REPO
+KERNEL = '%k.1 = bf16[8] custom-call(), custom_call_target="tpu_custom_call"'
+POOL_COPY = "%copy.1 = bf16[2,65,4,64]{3,2,1,0} copy(%p)"
+# the decode kernel's own instruction, and one that only NAMES it
+ATTENTION = "%paged_decode_attention.3 = bf16[4,1,64]{2,1,0} custom-call(%q)"
+CONSUMER = "%fusion.7 = bf16[4,64]{1,0} fusion(%paged_decode_attention.3)"
 FAKE_TRACE = {"window_s": 1.0, "devices": 1, "busy_s": 0.9,
               "collective_s": 0.2, "collective_exposed_s": 0.1,
-              "op_seconds": {'%k.1 = bf16[8] custom-call(), custom_call_target="tpu_custom_call"': 0.3,
-                             "%copy.1 = bf16[2,65,4,64]{3,2,1,0} copy(%p)": 0.2},
-              "op_counts": {'%k.1 = bf16[8] custom-call(), custom_call_target="tpu_custom_call"': 6,
-                            "%copy.1 = bf16[2,65,4,64]{3,2,1,0} copy(%p)": 3},
+              "op_seconds": {KERNEL: 0.3, POOL_COPY: 0.2, ATTENTION: 0.09,
+                             CONSUMER: 0.05},
+              "op_counts": {KERNEL: 6, POOL_COPY: 3, ATTENTION: 3,
+                            CONSUMER: 3},
               "gaps": [("bench.step", 0.1)], "spans": []}
 
 
@@ -87,9 +92,11 @@ def test_a_traced_run_reports_the_per_layer_metrics_of_its_cell(
     # size, heads x head size): found by shape
     assert m["backlog.kv_copy_time_share"]["value"] == pytest.approx(
         100 * 0.2 / 0.9)
+    # the kernel's share by its instruction's own name, not its consumer's
+    assert m["backlog.decode_attn_time_share"]["value"] == pytest.approx(
+        100 * 0.09 / 0.9)
     assert m["backlog.batch_occupancy"]["value"] > 50
     assert 0 < m["backlog.kv_pool_filled_share"]["value"] <= 100
-    assert 0 <= m["backlog.prefill_wall_share"]["value"] <= 100
     assert not any(k.startswith(("mesh.", "train.")) for k in m)
 
 
@@ -344,16 +351,13 @@ UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 
 
-@pytest.fixture(scope="module")
-def spec():
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        return json.load(f)
+# `spec` is the file as it stands and again with an arrival appended, and
+# `spec_root` the root whose data files lie beside it (`conftest.py`)
 
-
-def test_benchmark_json_has_exactly_the_contracts_keys(spec):
+def test_benchmark_json_has_exactly_the_contracts_keys(spec, spec_root):
     assert set(spec) == {"command", "paths", "run_seconds", "configs",
                          "workloads", "end_to_end", "per_layer"}
-    assert os.path.getsize(os.path.join(REPO, "BENCHMARK.json")) < 65536
+    assert os.path.getsize(os.path.join(spec_root, "BENCHMARK.json")) < 65536
     assert 1 <= spec["run_seconds"] <= 51
     cells = len(spec["workloads"])
     assert (2 + 14 * 24) * (spec["run_seconds"] + 60) + 24 * 180 + 1200 \
@@ -384,13 +388,13 @@ def test_names_units_and_bounds_are_inside_the_limits(spec):
             assert m["unit"] == "%"
 
 
-def test_a_train_mix_names_the_rate_its_cell_reports(spec):
+def test_a_train_mix_names_the_rate_its_cell_reports(spec, spec_root):
     """The train loop reports its rate under the traffic file's
     `rate_metric`: that has to be an end-to-end metric of the cell. Any
     number of cells may report one rate."""
     seen = set()
     for w in spec["workloads"]:
-        mix = json.load(open(os.path.join(REPO, "benchmark", "traffic",
+        mix = json.load(open(os.path.join(spec_root, "benchmark", "traffic",
                                           w["traffic"] + ".json")))
         if mix["loop"] != "train":
             continue
@@ -402,7 +406,8 @@ def test_a_train_mix_names_the_rate_its_cell_reports(spec):
     assert {"train_124m_step", "train_1p3b_mesh4"} <= seen
 
 
-def test_every_cell_finds_its_files_and_reports_what_it_must(spec):
+def test_every_cell_finds_its_files_and_reports_what_it_must(spec,
+                                                             spec_root):
     configs = {c["name"]: c for c in spec["configs"]}
     cells = {w["name"] for w in spec["workloads"]}
     used = set()
@@ -411,13 +416,14 @@ def test_every_cell_finds_its_files_and_reports_what_it_must(spec):
         assert w["chips"] in (1, 4)
         used.add(w["config"])
         cfg = configs[w["config"]]
-        assert os.path.exists(os.path.join(REPO, cfg["file"]))
-        mix = os.path.join(REPO, "benchmark", "traffic",
+        assert os.path.exists(os.path.join(spec_root, cfg["file"]))
+        mix = os.path.join(spec_root, "benchmark", "traffic",
                            w["traffic"] + ".json")
         loop = json.load(open(mix))["loop"]
         assert os.path.exists(os.path.join(REPO, "benchmark", "loops",
                                            loop + ".py"))
-        limits = json.load(open(os.path.join(REPO, "benchmark", "limits",
+        limits = json.load(open(os.path.join(spec_root, "benchmark",
+                                             "limits",
                                              w["name"] + ".json")))
         if loop.startswith("serve"):
             # a serve cell compares both numbers, each against its own
@@ -439,10 +445,11 @@ def test_every_cell_finds_its_files_and_reports_what_it_must(spec):
         assert set(m.get("workloads", [])) <= cells
 
 
-def test_metric_files_and_per_layer_metrics_are_the_same_set(spec):
+def test_metric_files_and_per_layer_metrics_are_the_same_set(spec,
+                                                             spec_root):
     """Each per-layer metric has its file, each file its metric, and
     every file names a reader kept under `benchmark/readers/`."""
-    top = os.path.join(REPO, "benchmark", "metrics")
+    top = os.path.join(spec_root, "benchmark", "metrics")
     assert sorted(n[:-len(".json")] for n in os.listdir(top)) == sorted(
         m["name"] for m in spec["per_layer"])
     for m in spec["per_layer"]:
@@ -454,18 +461,69 @@ def test_metric_files_and_per_layer_metrics_are_the_same_set(spec):
                                            module + ".py")), reader
 
 
-def _configs():
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        return json.load(f)["configs"]
+@pytest.mark.parametrize("name,cell,kernel", [
+    ("longcat.decode_attn_time_share", "serve_longcat_decode",
+     "latent_decode_attention"),
+    ("backlog.decode_attn_time_share", "serve_124m_backlog",
+     "paged_decode_attention"),
+])
+def test_a_decode_kernels_share_of_device_time_is_a_data_file(
+        spec, spec_root, name, cell, kernel):
+    """ISSUE 39's two: entries and data files over the reader that was
+    there, matching the kernel's own instruction (the `pallas_call`'s
+    name) and no instruction that takes its result."""
+    [entry] = [m for m in spec["per_layer"] if m["name"] == name]
+    [twin] = [m for m in spec["per_layer"]
+              if m["name"] == "backlog.kv_copy_time_share"]
+    assert entry == dict(twin, name=name, workloads=[cell])
+    metric = json.load(open(os.path.join(spec_root, "benchmark", "metrics",
+                                         name + ".json")))
+    assert metric["reader"] == "benchmark.readers.trace_op_share"
+    pattern = re.compile(metric["args"]["pattern"])
+    assert pattern.search(f"%{kernel}.9 = f32[128,64,512]{{2,1,0}} "
+                          "custom-call(%q, %pool)")
+    assert not pattern.search(f"%fusion.3 = f32[8]{{0}} fusion(%{kernel}.9)")
 
 
-@pytest.mark.parametrize("entry", _configs(), ids=lambda c: c["name"])
-def test_configurations_keep_the_published_widths(entry):
+@pytest.mark.parametrize("name", ["backlog.prefill_wall_share",
+                                  "backlog.decode_step_p50_ms"])
+def test_a_retired_metric_is_gone_with_its_file(spec, spec_root, name):
+    """Read nothing since PR 31 (`PERF.md` section 6, PR 39)."""
+    assert name not in {m["name"] for m in spec["per_layer"]}
+    assert not os.path.exists(os.path.join(spec_root, "benchmark",
+                                           "metrics", name + ".json"))
+
+
+def test_an_arrival_appends_and_edits_nothing_that_was_there():
+    """What the second case of every `spec` test stands on: the arrival
+    is entries at the END of each list, one cell more in one end-to-end
+    metric's `workloads`, and nothing else."""
+    before = tiny_root.spec_of("as_it_stands")
+    after = tiny_root.spec_of("with_an_arrival")
+    cell = tiny_root.ARRIVAL["cell"]["name"]
+    for key, more in (("configs", 1), ("workloads", 1), ("per_layer", 3)):
+        assert after[key][:len(before[key])] == before[key]
+        assert len(after[key]) == len(before[key]) + more
+    assert [m["name"] for m in after["per_layer"][-3:]] == list(
+        tiny_root.ARRIVAL["per_layer"])
+    for was, now in zip(before["end_to_end"], after["end_to_end"]):
+        if now["name"] == tiny_root.ARRIVAL["end_to_end"]:
+            assert now == dict(was, workloads=was["workloads"] + [cell])
+        else:
+            assert now == was
+    for key in ("command", "paths", "run_seconds"):
+        assert after[key] == before[key]
+
+
+@pytest.mark.parametrize("standing,name", tiny_root.config_cases(),
+                         indirect=["standing"])
+def test_configurations_keep_the_published_widths(spec, spec_root, name):
     """Each configuration is held to its own source: what it cuts is of a
     kind a cell may cut and never a width, and the file says where it
     comes from and where it departs (`config_rules`). A configuration of
     the GPT reference is also held to GPT's own shape."""
-    cfg = json.load(open(os.path.join(REPO, entry["file"])))
+    [entry] = [c for c in spec["configs"] if c["name"] == name]
+    cfg = json.load(open(os.path.join(spec_root, entry["file"])))
     assert config_rules.problems(entry, cfg) == []
     if cfg["reference"] == "benchmark.reference.gpt":
         assert cfg["n_embd"] % cfg["n_head"] == 0
@@ -529,3 +587,69 @@ def test_a_layer_pattern_keeps_a_whole_period():
     cfg["layer_types"] = pattern[:3]
     assert any("whole period" in f
                for f in config_rules.problems(entry, cfg))
+
+
+# layer patterns as their publishers print them, a letter a layer, after
+# the leading dense layers: a tail that departs from the period (a last
+# attention layer one layer early) leaves the period what it is
+HYBRID = list("ccAcccAcccAcccAcccAccAcc")     # 24 layers, the first 2 dense
+
+
+@pytest.mark.parametrize("pattern,p", [
+    ("lllf" * 8, 4),
+    ("".join(HYBRID[2:]), 4),                 # Acccx4, Acc, Acc: not 19
+    ("AcccAcccAcccAcccAcccAcccAcccAcccAcccAc", 4),
+    ("mmmmmammmmmmmmmammmmmmmmmammmmmmmmmammmm", 10),
+    ("AccAccAccAcAc", 3),                     # `tiny_root.ARRIVAL`'s
+    ("c" * 15 + "A" + "c" * 15 + "A" + "c" * 15 + "A", 16),
+    ("f" * 48, 1),
+    ("abcdefg", 7),                           # no repetition: its length
+])
+def test_the_period_is_the_one_the_publisher_counts(pattern, p):
+    assert config_rules.period(list(pattern)) == p
+
+
+def _hybrid(kept_after_dense):
+    """The 24-layer pattern above cut in depth, `num_dense_layers` 2."""
+    kept = HYBRID[:2] + list(kept_after_dense)
+    entry = {"reduced": ["num_hidden_layers", "layer_types"]}
+    cfg = dict({k: v for k, v in CUT.items()
+                if k != "first_k_dense_replace"},
+               num_dense_layers=2, num_hidden_layers=len(kept),
+               layer_types=kept, n_routed_experts=64, vocab_size=154880,
+               published={"num_hidden_layers": 24, "layer_types": HYBRID},
+               changed={"num_hidden_layers": "24 -> 2 dense + the rest",
+                        "layer_types": "its beginning"})
+    return entry, cfg
+
+
+@pytest.mark.parametrize("case,says", [
+    (_hybrid("AcccAcccAcccAc"), None),        # 16 of 24: 3.5 periods
+    (_hybrid("Accc"), None),                  # one whole period
+    (_hybrid("Acc"), "under four layers"),
+    (_hybrid("Acc"), "whole period"),
+    (_hybrid("cccc"), "keeps no A layer"),
+    (_hybrid("cAcccAccc"), "not the beginning"),
+    (_hybrid("AAcccAcccAcccA"), "not the beginning"),
+    (_hybrid("AcccAAAA"), "from the published share"),
+], ids=lambda x: x if isinstance(x, str) else None)
+def test_a_cut_pattern_is_the_published_ones_beginning_in_its_shares(
+        case, says):
+    found = config_rules.problems(*case)
+    if says is None:
+        assert found == []
+    else:
+        assert any(says in f for f in found), found
+
+
+def test_leading_dense_layers_count_under_either_key():
+    entry, cfg = _hybrid("Acc")
+    assert config_rules.leading_dense(cfg) == 2
+    assert config_rules.leading_dense(dict(CUT)) == 1
+    assert config_rules.leading_dense({"n_layer": 12}) == 0
+    assert any("under four layers after the 2 leading dense" in f
+               for f in config_rules.problems(entry, cfg))
+    # counted as 0, the two dense layers would pass for two of the four
+    del cfg["num_dense_layers"]
+    assert not any("under four layers" in f
+                   for f in config_rules.problems(entry, cfg))

@@ -38,8 +38,7 @@ REPO = tiny_root.REPO
 # 0.082); the other two guard against gross faults
 LIMITS = {"loss_gap": 0.005, "grad_norm_gap": 0.06, "delta_norm_gap": 0.05}
 MIX = dict(tiny_root.TRAFFIC["tiny_train"])
-with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-    SPEC = json.load(f)
+SPEC = tiny_root.spec_of("as_it_stands")
 JOYAI_METRICS = [m["name"] for m in SPEC["per_layer"]
                  if m["name"].startswith("joyai.")]
 PEAKS = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}

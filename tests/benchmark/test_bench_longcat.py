@@ -34,8 +34,7 @@ REPO = tiny_root.REPO
 # guards against gross faults only and the control passes it
 LIMITS = {"logit_gap": 3.0, "logit_gap_mean": 0.008}
 STREAMS, PROMPT, SERVED, PAD = 8, 8, 24, 32
-with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-    SPEC = json.load(f)
+SPEC = tiny_root.spec_of("as_it_stands")
 LONGCAT_METRICS = [m["name"] for m in SPEC["per_layer"]
                    if m["name"].startswith("longcat.")]
 

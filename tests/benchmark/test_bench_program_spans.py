@@ -37,12 +37,6 @@ CELL_OF = {"backlog": ("serve_124m_backlog", "tiny_backlog_cell"),
 
 
 @pytest.fixture(scope="module")
-def spec():
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        return json.load(f)
-
-
-@pytest.fixture(scope="module")
 def traced_lines(tmp_path_factory):
     """One traced run of each tiny cell, the device trace canned (the CPU
     gives the profiler no device plane)."""
@@ -69,13 +63,14 @@ def traced_lines(tmp_path_factory):
 
 
 @pytest.mark.parametrize("name", sorted(NEW))
-def test_a_new_metric_is_a_file_over_a_reader_that_exists(spec, name):
+def test_a_new_metric_is_a_file_over_a_reader_that_exists(spec, spec_root,
+                                                          name):
     entry = [m for m in spec["per_layer"] if m["name"] == name]
     assert len(entry) == 1
     real_cell, _ = CELL_OF[name.split(".")[0]]
     assert entry[0]["workloads"] == [real_cell]
     assert entry[0]["source"] in ("program_span", "program_counter")
-    with open(os.path.join(REPO, "benchmark", "metrics",
+    with open(os.path.join(spec_root, "benchmark", "metrics",
                            name + ".json")) as f:
         metric = json.load(f)
     package, _, module = metric["reader"].rpartition(".")

@@ -1,8 +1,13 @@
 """A benchmark root of tiny cells in a temporary directory, for driving the
 harness on the CPU: new configuration, traffic and limits files beside
-copies of the real metric files; no file of the benchmark is edited."""
+copies of the real metric files; no file of the benchmark is edited. And
+the ONE reader of the real `BENCHMARK.json` for the tests (`spec_of`,
+`arrival_root`): the file as it stands, and the file after an arrival, so that
+a test which pins a tail, a length or a set of names fails in the PR that
+writes it and not in the PR that brings the next configuration."""
 from __future__ import annotations
 
+import copy
 import json
 import os
 import shutil
@@ -69,10 +74,109 @@ def _dump(path, obj):
         json.dump(obj, f)
 
 
+# -- the real file, as it stands and after an arrival ------------------------
+# What a `model_config` PR brings, as files and entries: one configuration
+# (a layer pattern of the irregular kind: one leading dense layer under
+# `num_dense_layers`, period 3, a tail that departs from it), one cell
+# appended to an end-to-end metric's `workloads`, three per-layer entries at
+# the END of the list, each a data file over a reader that exists.
+STANDINGS = ("as_it_stands", "with_an_arrival")
+_PUBLISHED_TYPES = ["conv"] + ["full_attention", "conv", "conv"] * 3 \
+    + ["full_attention", "conv"] * 2
+ARRIVAL_CONFIG = {
+    "hidden_size": 64, "intermediate_size": 256, "moe_intermediate_size": 32,
+    "num_attention_heads": 4, "num_key_value_heads": 2, "conv_L_cache": 3,
+    "num_experts": 8, "num_experts_per_tok": 2, "vocab_size": 256,
+    "num_dense_layers": 1, "num_hidden_layers": 6,
+    "layer_types": _PUBLISHED_TYPES[:6],
+    "published": {"num_hidden_layers": len(_PUBLISHED_TYPES),
+                  "layer_types": _PUBLISHED_TYPES},
+    "changed": {"num_hidden_layers": "14 -> the dense layer + 5",
+                "layer_types": "the first 6 of 14"},
+    "source": "test", "deployment": "one chip holds the first six layers",
+    "program": "arrival_program", "reference": "arrival_reference",
+}
+ARRIVAL = {
+    "config": {"name": "arrival_hybrid", "source": "test",
+               "file": "benchmark/configs/arrival_hybrid.json",
+               "reduced": ["num_hidden_layers", "layer_types"],
+               "why": "test"},
+    "cell": {"name": "serve_arrival_decode", "config": "arrival_hybrid",
+             "traffic": "arrival_backlog", "chips": 1, "why": "test"},
+    "end_to_end": "serve_tokens_per_s",
+    "per_layer": {
+        "arrival.conv_time_share": {
+            "unit": "%", "source": "device_trace",
+            "file": {"reader": "benchmark.readers.trace_op_share",
+                     "args": {"pattern": "short_conv"}}},
+        "arrival.decode_attn_roofline": {
+            "unit": "%", "source": "device_trace", "better": "higher",
+            "file": {"reader": "benchmark.readers.trace_kernel_roofline",
+                     "args": {"pattern": "grouped_decode_attention"}}},
+        "arrival.host_step_ms": {
+            "unit": "ms", "source": "program_span",
+            "file": {"reader": "benchmark.readers.engine_stat",
+                     "args": {"key": "step_ms_per_step"}}}},
+}
+
+
+def spec_of(standing):
+    """The real `BENCHMARK.json` as a dict: as it stands, or with the
+    arrival's entries appended (in memory: nothing is written)."""
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        spec = json.load(f)
+    if standing == "as_it_stands":
+        return spec
+    assert standing == "with_an_arrival", standing
+    cell = ARRIVAL["cell"]["name"]
+    spec["configs"].append(copy.deepcopy(ARRIVAL["config"]))
+    spec["workloads"].append(dict(ARRIVAL["cell"]))
+    for m in spec["end_to_end"]:
+        if m["name"] == ARRIVAL["end_to_end"]:
+            m["workloads"].append(cell)
+    for name, m in ARRIVAL["per_layer"].items():
+        spec["per_layer"].append({
+            "name": name, "unit": m["unit"],
+            "better": m.get("better", "lower"), "source": m["source"],
+            "layer": "test", "moves": ARRIVAL["end_to_end"],
+            "workloads": [cell]})
+    return spec
+
+
+def arrival_root(scratch):
+    """Where the data files beside `spec_of("with_an_arrival")` lie: a root
+    at `scratch` that holds copies of the benchmark's data files, the
+    arrival's own beside them, and the extended `BENCHMARK.json`, laid out
+    as the repository is (the file as it stands has the repository itself).
+    The code (`loops/`, `readers/`, `programs/`) is the repository's."""
+    root = str(scratch)
+    data = os.path.join(root, "benchmark")
+    for kind in ("configs", "traffic", "limits", "metrics"):
+        shutil.copytree(os.path.join(REPO, "benchmark", kind),
+                        os.path.join(data, kind))
+    _dump(os.path.join(root, ARRIVAL["config"]["file"]), ARRIVAL_CONFIG)
+    _dump(os.path.join(data, "traffic",
+                       ARRIVAL["cell"]["traffic"] + ".json"),
+          TRAFFIC["tiny_backlog"])
+    _dump(os.path.join(data, "limits", ARRIVAL["cell"]["name"] + ".json"),
+          SERVE_LIMITS)
+    for name, m in ARRIVAL["per_layer"].items():
+        _dump(os.path.join(data, "metrics", name + ".json"), m["file"])
+    with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
+        json.dump(spec_of("with_an_arrival"), f, indent=1)
+    return root
+
+
+def config_cases():
+    """(standing, configuration's name) for every configuration of every
+    standing: what a test of one configuration is parametrised over."""
+    return [(standing, c["name"]) for standing in STANDINGS
+            for c in spec_of(standing)["configs"]]
+
+
 def make(root):
     """Write the tiny root under `root`; returns its BENCHMARK.json dict."""
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        real = json.load(f)
+    real = spec_of("as_it_stands")
     data = os.path.join(root, "benchmark")
     shutil.copytree(os.path.join(REPO, "benchmark", "metrics"),
                     os.path.join(data, "metrics"))
