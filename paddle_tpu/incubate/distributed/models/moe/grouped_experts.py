@@ -37,20 +37,9 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .held_experts import route, COUNTERS
+from .held_experts import route, _sorted_assignments, COUNTERS
 
 __all__ = ["grouped_held_expert_block", "COUNTERS"]
-
-
-def _sorted_assignments(chosen, first_held, held, rows):
-    """flat ``[rows]`` int32: the positions, in the flattened ``[T * k]``
-    assignments of chosen ``[T, k]``, of the first `rows` rows of the order
-    sorted by expert: the held ones first, grouped by expert, in token
-    order inside a group."""
-    local = chosen.reshape(-1) - first_held
-    key = jnp.where((local >= 0) & (local < held), local, held)
-    # stable: inside a group the rows stay in token order
-    return jnp.argsort(key, stable=True)[:rows].astype(jnp.int32)
 
 
 def grouped_held_expert_block(u, router_w, bias, gate_w, up_w, down_w, *,

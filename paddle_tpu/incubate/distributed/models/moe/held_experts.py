@@ -10,11 +10,18 @@ expert-parallel deployment asks something else of the block:
     expert held elsewhere, and computes the identity experts where the
     token lives (a scale of the token, no matrix product, no exchange);
   * no token is dropped at any load, inside one compiled program of static
-    shapes, and an expert no token chose is not read: each held expert
-    runs under a `lax.cond` on its own load, over every token of the call
-    masked by the choice (a decode launch's products are bound by the
-    expert's bytes, not by its rows), so `computed == held` always (the
-    counters prove it) and a launch's time follows its routing;
+    shapes, and an expert no token chose is not read. The products come
+    in one of two forms, chosen by a fact of the call's static shape
+    (`products_form`; `PERF.md` section 4 has the chip's readings):
+    MASKED, each held expert under a `lax.cond` on its own load, over
+    every token of the call masked by the choice (a decode launch's
+    products are bound by the expert's bytes, not by its rows; but the
+    operations are ``held / topk`` x the needed ones once every expert
+    is held), or GROUPED, the assignments sorted by expert into a buffer
+    no routing can overflow and multiplied by `jax.lax.ragged_dot`, each
+    group against its own expert (`grouped_experts.py`'s products,
+    forward only). Either way `computed == held` always (the counters
+    prove it) and a launch's time follows its routing;
   * the router works in float32, products at "highest": top-k of its
     scores is a discrete choice and the reference's router is float32 too.
 
@@ -30,7 +37,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
-__all__ = ["route", "held_expert_block", "COUNTERS"]
+__all__ = ["route", "held_expert_block", "masked_products",
+           "grouped_products", "products_form", "COUNTERS"]
 
 # what `held_expert_block` counts of a call's choices, in this order
 COUNTERS = ("routed_held", "routed_identity", "routed_elsewhere",
@@ -38,12 +46,13 @@ COUNTERS = ("routed_held", "routed_identity", "routed_elsewhere",
 
 
 def route(u, router_w, bias, topk, scaling, scoring="softmax",
-          normalise=False):
+          normalise=False, epsilon=1e-20):
     """The router: scores over every expert in float32, ``softmax(u W_r)``
     or, with `scoring` "sigmoid", ``sigmoid(u W_r)``; the `topk` of
     ``scores + bias`` chosen (the bias moves the choice and never the
     weight), each weighed ``scaling * score``, with `normalise` over the
-    sum of the chosen scores (all `topk`, wherever their experts live).
+    sum of the chosen scores (all `topk`, wherever their experts live)
+    plus `epsilon`.
     Returns ``(chosen ids [T, topk], weights [T, topk] float32)``."""
     logits = jnp.matmul(u.astype(jnp.float32), router_w.astype(jnp.float32),
                         precision=jax.lax.Precision.HIGHEST)
@@ -55,7 +64,8 @@ def route(u, router_w, bias, topk, scaling, scoring="softmax",
     _, chosen = jax.lax.top_k(ranked, topk)
     weights = jnp.take_along_axis(scores, chosen, axis=-1)
     if normalise:
-        weights = weights / (jnp.sum(weights, -1, keepdims=True) + 1e-20)
+        weights = weights / (jnp.sum(weights, -1, keepdims=True)
+                             + epsilon)
     return chosen.astype(jnp.int32), jnp.float32(scaling) * weights
 
 
@@ -67,35 +77,42 @@ def _swiglu(x, gate_w, up_w, down_w):
     return jnp.matmul(h, down_w, preferred_element_type=jnp.float32)
 
 
-def held_expert_block(u, router_w, bias, gate_w, up_w, down_w, *, topk,
-                      real_experts, scaling, first_held=0, valid=None):
-    """``sum over chosen held e of w_e E_e(u) + sum over chosen identity e
-    of w_e u`` for tokens u ``[T, d]``, and the call's counters.
+def _sorted_assignments(chosen, first_held, held, rows):
+    """flat ``[rows]`` int32: the positions, in the flattened ``[T * k]``
+    assignments of chosen ``[T, k]``, of the first `rows` rows of the order
+    sorted by expert: the held ones first, grouped by expert, in token
+    order inside a group."""
+    local = chosen.reshape(-1) - first_held
+    key = jnp.where((local >= 0) & (local < held), local, held)
+    # stable: inside a group the rows stay in token order
+    return jnp.argsort(key, stable=True)[:rows].astype(jnp.int32)
 
-    router_w ``[d, real_experts + identity experts]``; the stacked
-    gate/up/down weights hold experts ``first_held .. first_held + E - 1``
-    of the `real_experts`; ids from `real_experts` up are identity
-    experts. `valid` ``[T]`` bool masks padding (a prompt's bucket, an
-    empty slot) out of the counts and of the products. Returns ``(m [T, d]
-    float32, counters int32 [len(COUNTERS)])``."""
-    t, d = u.shape
+
+def products_form(tokens, topk, held):
+    """Which form a served call's products take, from its static shape:
+    GROUPED where the block holds at least twice the experts a token
+    chooses (the masked form then multiplies ``held / topk`` >= 2 times
+    the rows any routing holds, and unrolls `held` conditionals a layer
+    into every program), else MASKED. The chip's readings behind the
+    rule, by `tokens` too: `PERF.md` section 4."""
+    return "grouped" if held >= 2 * topk else "masked"
+
+
+def masked_products(u, local, is_held, valid, weights, gate_w, up_w, down_w,
+                    acc):
+    """`acc` + every held expert over EVERY token, weighed by the choice
+    (0 for a token that did not choose it), an expert no token chose not
+    read. local ``[T, k]``: the chosen ids from the first held one;
+    is_held ``[T, k]`` bool: the assignments held here; valid ``[T]``
+    bool: the tokens that count. Returns ``(acc, load int32 [E],
+    assignments computed)``."""
     held = gate_w.shape[0]
-    if valid is None:
-        valid = jnp.ones((t,), bool)
-    chosen, weights = route(u, router_w, bias, topk, scaling)
-    is_identity = chosen >= real_experts
-    local = chosen - first_held
-    is_held = (local >= 0) & (local < held) & ~is_identity
-    # the identity experts: a scale of the token
-    scale = jnp.sum(jnp.where(is_identity, weights, 0.0), axis=-1)
-    out = scale[:, None] * u.astype(jnp.float32)
     # [T, E]: the weight a token gives each held expert (0: not chosen);
     # a token chooses an expert at most once
     hit = (local[:, :, None] == jnp.arange(held)[None, None, :]) \
         & (is_held & valid[:, None])[:, :, None]
     w_te = jnp.sum(jnp.where(hit, weights[:, :, None], 0.0), axis=1)
-    took = jnp.any(hit, axis=1)                          # [T, E]
-    load = jnp.sum(took, axis=0).astype(jnp.int32)       # [E]
+    load = jnp.sum(jnp.any(hit, axis=1), axis=0).astype(jnp.int32)   # [E]
 
     def loaded(e, acc):
         """Expert e over every token, weighed by the choice (0 for a
@@ -105,9 +122,77 @@ def held_expert_block(u, router_w, bias, gate_w, up_w, down_w, *, topk,
 
     computed = jnp.int32(0)
     for e in range(held):
-        out, n = jax.lax.cond(load[e] > 0, functools.partial(loaded, e),
-                              lambda acc: (acc, jnp.int32(0)), out)
+        acc, n = jax.lax.cond(load[e] > 0, functools.partial(loaded, e),
+                              lambda acc: (acc, jnp.int32(0)), acc)
         computed = computed + n
+    return acc, load, computed
+
+
+def grouped_products(u, local, is_held, valid, weights, gate_w, up_w, down_w,
+                     acc):
+    """`masked_products`' contract by sorted assignments: the held, valid
+    assignments gathered in expert order into a buffer of
+    ``T * min(k, E)`` rows, which no routing overflows (a token chooses
+    an expert at most once), each group multiplied by its own expert
+    (`jax.lax.ragged_dot`), weighed and added back to its token. The
+    rows past the last group cost their bytes, not their products, and
+    whatever the product leaves there is cut off behind it. Forward
+    only."""
+    t, _ = u.shape
+    held, topk = gate_w.shape[0], local.shape[1]
+    rows = t * min(topk, held)
+    # an assignment not taken lies outside every group
+    mine_id = jnp.where(is_held & valid[:, None], local, -1)
+    flat = _sorted_assignments(mine_id, 0, held, rows)
+    load = jnp.sum(mine_id.reshape(-1)[:, None] == jnp.arange(
+        held, dtype=mine_id.dtype)[None], axis=0, dtype=jnp.int32)
+    total = jnp.sum(load)
+    mine = (jnp.arange(rows, dtype=jnp.int32) < total)[:, None]
+    token = flat // topk
+
+    def product(a, w):
+        return jnp.where(mine, jax.lax.ragged_dot(
+            a, w, load, preferred_element_type=jnp.float32), 0.0)
+
+    with jax.named_scope("grouped_experts"):
+        x = jnp.take(u, token, axis=0)                       # [rows, d]
+        hidden = (jax.nn.silu(product(x, gate_w))
+                  * product(x, up_w)).astype(u.dtype)
+        y = product(hidden, down_w)                          # [rows, d] f32
+    w_row = jnp.where(mine[:, 0], jnp.take(weights.reshape(-1), flat), 0.0)
+    return (acc.at[token].add(y * w_row[:, None]), load,
+            jnp.sum(mine).astype(jnp.int32))
+
+
+def held_expert_block(u, router_w, bias, gate_w, up_w, down_w, *, topk,
+                      real_experts, scaling, first_held=0, valid=None,
+                      scoring="softmax", normalise=False, epsilon=1e-20):
+    """``sum over chosen held e of w_e E_e(u) + sum over chosen identity e
+    of w_e u`` for tokens u ``[T, d]``, and the call's counters.
+
+    router_w ``[d, real_experts + identity experts]``; the stacked
+    gate/up/down weights hold experts ``first_held .. first_held + E - 1``
+    of the `real_experts`; ids from `real_experts` up are identity
+    experts. `scoring`, `normalise` and `epsilon` are the router's form
+    (`route`). `valid` ``[T]`` bool masks padding (a prompt's bucket, an
+    empty slot) out of the counts and of the products. Returns ``(m [T, d]
+    float32, counters int32 [len(COUNTERS)])``."""
+    t, d = u.shape
+    held = gate_w.shape[0]
+    if valid is None:
+        valid = jnp.ones((t,), bool)
+    chosen, weights = route(u, router_w, bias, topk, scaling, scoring,
+                            normalise, epsilon)
+    is_identity = chosen >= real_experts
+    local = chosen - first_held
+    is_held = (local >= 0) & (local < held) & ~is_identity
+    # the identity experts: a scale of the token
+    scale = jnp.sum(jnp.where(is_identity, weights, 0.0), axis=-1)
+    out = scale[:, None] * u.astype(jnp.float32)
+    products = grouped_products \
+        if products_form(t, topk, held) == "grouped" else masked_products
+    out, load, computed = products(u, local, is_held, valid, weights,
+                                   gate_w, up_w, down_w, out)
     counted = valid[:, None]
     n_held = jnp.sum(is_held & counted)
     counters = jnp.stack([

@@ -12,3 +12,7 @@ from . import joyai_llm_flash  # noqa: F401
 from .joyai_llm_flash import (  # noqa: F401
     JoyAIFlashConfig, JoyAIFlashForCausalLM,
 )
+from . import lfm2_moe  # noqa: F401
+from .lfm2_moe import (  # noqa: F401
+    Lfm2MoeConfig, Lfm2MoeForCausalLM,
+)

@@ -288,7 +288,9 @@ def blockwise_paged_attention(q, k_pools, v_pools, layer, block_tables,
     """Online-softmax paged attention, one KV chunk at a time, over the
     chunks and the slots that hold tokens.
 
-    q: ``[S, H, D]`` this step's queries; k_pools/v_pools:
+    q: ``[S, Hq, D]`` this step's queries, Hq a multiple of the H heads
+    a row holds (query head i reads key/value head ``i // (Hq // H)``);
+    k_pools/v_pools:
     ``[L, num_blocks, bs, H*D]`` (fp, or int8 with `k_scales`/`v_scales`
     ``[L, num_blocks, H]``) and `layer` the one to read; block_tables:
     ``[S, M]`` int32; lens: ``[S]`` int32 EFFECTIVE lengths (position p
@@ -310,9 +312,13 @@ def blockwise_paged_attention(q, k_pools, v_pools, layer, block_tables,
     past a slot's own length are still gathered and masked; an inactive
     slot reads the null block through one chunk.
     """
-    s, h, d = q.shape
+    s, hq, d = q.shape
     bs = int(block_size)
     quant = k_scales is not None
+    # the heads a row holds; `hq // h` queries read each (grouped queries:
+    # query head i reads key/value head i // group)
+    h = k_pools.shape[-1] // d
+    group = hq // h
     plan = _blockwise_plan(s, block_tables.shape[1], bs, h, d, chunk_blocks)
     chunk_blocks = plan[1]
     t_chunk = chunk_blocks * bs
@@ -330,8 +336,15 @@ def blockwise_paged_attention(q, k_pools, v_pools, layer, block_tables,
             vc = vc.astype(jnp.float32)
         kc = kc.reshape(w, t_chunk, h, d)
         vc = vc.reshape(w, t_chunk, h, d)
-        return (jnp.einsum("shd,sthd->sht", q[:w], kc),
-                lambda p: jnp.einsum("sht,sthd->shd", p, vc))
+        if group == 1:
+            return (jnp.einsum("shd,sthd->sht", q[:w], kc),
+                    lambda p: jnp.einsum("sht,sthd->shd", p, vc))
+        qg = q[:w].reshape(w, h, group, d)
+        return (jnp.einsum("shgd,sthd->shgt", qg, kc).reshape(
+                    w, hq, t_chunk),
+                lambda p: jnp.einsum(
+                    "shgt,sthd->shgd", p.reshape(w, h, group, t_chunk),
+                    vc).reshape(w, hq, d))
 
     return _blockwise_loop(q32, block_tables, lens, plan, bs, d,
                            chunk).astype(q.dtype)
@@ -514,16 +527,22 @@ def _page_copies(tab_ref, lens_ref, layer, sides, sems, block_size, pages):
     return held, start, wait
 
 
-def _ragged_decode_kernel(layer_ref, tab_ref, lens_ref, q_ref, k_hbm, v_hbm,
-                          o_ref, k_buf, v_buf, sems, first_ref, *,
-                          block_size, pages, heads_padded, head_dim, scale):
-    """One slot a grid step, the steps in order. The slot's pages come out
+def _ragged_decode_kernel(layer_ref, tab_ref, lens_ref, q_ref, *rest,
+                          block_size, pages, heads_padded, head_dim, scale,
+                          per_head=1):
+    """One slot a grid step, the steps in order. With `per_head` queries a
+    key/value head (grouped queries; `_grouped_constants`) three constant
+    operands precede the pools: query head i lies in the lanes of
+    key/value head ``i // per_head`` and the output is assembled a query
+    head. The slot's pages come out
     of the pools in HBM a group of `pages` at a time, K and V each into
     one of two VMEM buffers ``[2, pages * bs, H*D]``; while a group is
     multiplied the next one's copies are in flight, and the next of a
     slot's LAST group is the first group of the slot after it, so the
     copies do not drain where a slot ends (`first_ref` carries which
     buffer that group went to across the grid steps)."""
+    grouped, rest = rest[:len(rest) - 7], rest[len(rest) - 7:]
+    k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, first_ref = rest
     s = pl.program_id(0)
     n_slots = pl.num_programs(0)
     # every constant a 32-bit one: under the framework's x64 mode a Python
@@ -552,15 +571,25 @@ def _ragged_decode_kernel(layer_ref, tab_ref, lens_ref, q_ref, k_hbm, v_hbm,
     length = lens_ref[s]
     groups = (held(s) + (n_pages - one)) // n_pages
 
-    # head h's query in its own D lanes of row h, zeros elsewhere: the
-    # rows of a page go to the matrix unit as they lie, all heads at once
-    row = jax.lax.broadcasted_iota(jnp.int32, (hp, hd), 0)
-    lane = jax.lax.broadcasted_iota(jnp.int32, (hp, hd), 1)
-    own = ((lane >= row * np.int32(head_dim))
-           & (lane < (row + one) * np.int32(head_dim)))
-    # (selected as float32: the mask's layout is a 32-bit one)
-    q = jnp.where(own, q_ref[...].astype(jnp.float32),
-                  nothing).astype(q_ref.dtype)
+    if per_head == 1:
+        # head h's query in its own D lanes of row h, zeros elsewhere: the
+        # rows of a page go to the matrix unit as they lie, all heads at
+        # once
+        row = jax.lax.broadcasted_iota(jnp.int32, (hp, hd), 0)
+        lane = jax.lax.broadcasted_iota(jnp.int32, (hp, hd), 1)
+        own = ((lane >= row * np.int32(head_dim))
+               & (lane < (row + one) * np.int32(head_dim)))
+        # (selected as float32: the mask's layout is a 32-bit one)
+        q = jnp.where(own, q_ref[...].astype(jnp.float32),
+                      nothing).astype(q_ref.dtype)
+    else:
+        # query head i ([Hp, D] here) repeated into every head's lanes by
+        # a 0/1 product, of which it keeps the lanes of key/value head
+        # i // per_head: `per_head` rows a head's lanes
+        tile_ref, own_ref, pick_ref = grouped
+        own = own_ref[...]                              # [Hp, H*D] 0/1
+        q = (_exact_dot(q_ref[...], tile_ref[...], ((1,), (0,)))
+             * own).astype(q_ref.dtype)
     offs = jax.lax.broadcasted_iota(jnp.int32, (hp, t_group), 1)
 
     def group(g, carry):
@@ -592,9 +621,35 @@ def _ragged_decode_kernel(layer_ref, tab_ref, lens_ref, q_ref, k_hbm, v_hbm,
          jnp.zeros((hp, 1), jnp.float32),
          jnp.zeros((hp, hd), jnp.float32)))
     first_ref[0] = (base + groups) & one
-    # head h keeps its own D lanes of row h
-    out = jnp.where(own, acc / jnp.maximum(l, np.float32(1e-30)), nothing)
-    o_ref[...] = jnp.sum(out, axis=0, keepdims=True).astype(o_ref.dtype)
+    if per_head == 1:
+        # head h keeps its own D lanes of row h
+        out = jnp.where(own, acc / jnp.maximum(l, np.float32(1e-30)),
+                        nothing)
+        o_ref[...] = jnp.sum(out, axis=0, keepdims=True).astype(o_ref.dtype)
+    else:
+        # a query head's output is the D lanes of its key/value head in
+        # its own row: picked out by a 0/1 product (every bit kept), a
+        # row a query head
+        out = acc / jnp.maximum(l, np.float32(1e-30)) * own
+        o_ref[...] = _weigh(out, pick_ref[...]).astype(o_ref.dtype)
+
+
+def _grouped_constants(heads_padded, kv_heads, head_dim, group, dtype):
+    """The three 0/1 operands of the kernel with `group` queries a
+    key/value head: `tile` ``[D, H*D]`` repeats a query's D values into
+    every head's lanes, `own` ``[Hp, H*D]`` float32 keeps for query row i
+    the lanes of head ``i // group`` (rows past the query heads keep
+    none), `pick` ``[H*D, D]`` bfloat16 folds a row's kept lanes back to
+    D. Made on the host: the kernel has no integer division to make them
+    with."""
+    hd = kv_heads * head_dim
+    lane = np.arange(hd)
+    tile = (lane[None, :] % head_dim == np.arange(head_dim)[:, None])
+    rows = np.arange(heads_padded)
+    own = (lane[None, :] // head_dim == rows[:, None] // group) \
+        & (rows[:, None] < kv_heads * group)
+    return (jnp.asarray(tile, dtype), jnp.asarray(own, jnp.float32),
+            jnp.asarray(tile.T, jnp.bfloat16))
 
 
 @functools.partial(jax.jit, static_argnames=("block_size", "interpret",
@@ -612,9 +667,16 @@ def pallas_paged_attention(q, k_pools, v_pools, layer, block_tables, lens,
     The layer is an OPERAND and the function is jitted, so a program
     traces and lowers ONE kernel for all its layers' calls: traced anew
     for each of twelve layers the body cost the backlog cell 21 s of
-    set-up (PERF.md section 6, PR 33)."""
+    set-up (PERF.md section 6, PR 33).
+
+    q may hold a multiple of the heads a pool's row holds (grouped
+    queries: ``[S, Hq, D]`` over rows of ``H*D``, query head i reading
+    key/value head ``i // (Hq // H)``): the row, the pages and the copies
+    are the pool's, only the query's placement in the lanes and the
+    output's assembly differ (`_grouped_constants`)."""
     s, h, d = q.shape
-    hd = h * d
+    hd = k_pools.shape[-1]
+    group = h * d // hd
     bs = int(block_size)
     m = block_tables.shape[1]
     pages = int(group_pages or _group_pages(m, bs, hd, k_pools.dtype))
@@ -625,27 +687,49 @@ def pallas_paged_attention(q, k_pools, v_pools, layer, block_tables, lens,
                              lambda si, *_: (si, zero, zero))
     pool_spec = pl.BlockSpec(memory_space=pl.ANY)
     buf = pltpu.VMEM((2, pages * bs, hd), k_pools.dtype)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(s,),
-        in_specs=[slot_spec, pool_spec, pool_spec],
-        out_specs=slot_spec,
-        scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2, 2)),
-                        pltpu.SMEM((1,), jnp.int32)])
+    scratch = [buf, buf, pltpu.SemaphoreType.DMA((2, 2)),
+               pltpu.SMEM((1,), jnp.int32)]
     kernel = functools.partial(
         _ragged_decode_kernel, block_size=bs, pages=pages, heads_padded=hp,
         head_dim=d, scale=1.0 / math.sqrt(d))
+    scalars = (jnp.asarray(layer, jnp.int32).reshape(1),
+               block_tables.astype(jnp.int32), lens.astype(jnp.int32))
+    params = pltpu.CompilerParams(dimension_semantics=("arbitrary",))
+    if group == 1:
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(s,),
+            in_specs=[slot_spec, pool_spec, pool_spec],
+            out_specs=slot_spec,
+            scratch_shapes=scratch)
+        out = pl.pallas_call(
+            kernel, grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((s, 1, hd), q.dtype),
+            compiler_params=params,
+            interpret=interpret,
+            name="paged_decode_attention")(
+                *scalars, q.reshape(s, 1, hd), k_pools, v_pools)
+        return out.reshape(s, h, d)
+    # a row a query head, `group` of them over each head's lanes
+    heads_spec = pl.BlockSpec((None, hp, d), lambda si, *_: (si, zero, zero))
+    consts = _grouped_constants(hp, hd // d, d, group, q.dtype)
+    whole = [pl.BlockSpec(c.shape, lambda si, *_: (zero, zero))
+             for c in consts]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(s,),
+        in_specs=[heads_spec] + whole + [pool_spec, pool_spec],
+        out_specs=heads_spec,
+        scratch_shapes=scratch)
     out = pl.pallas_call(
-        kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((s, 1, hd), q.dtype),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+        functools.partial(kernel, per_head=group), grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((s, hp, d), q.dtype),
+        compiler_params=params,
         interpret=interpret,
         name="paged_decode_attention")(
-            jnp.asarray(layer, jnp.int32).reshape(1),
-            block_tables.astype(jnp.int32), lens.astype(jnp.int32),
-            q.reshape(s, 1, hd), k_pools, v_pools)
-    return out.reshape(s, h, d)
+            *scalars, jnp.pad(q, ((0, 0), (0, hp - h), (0, 0))), *consts,
+            k_pools, v_pools)
+    return out[:, :h]
 
 
 # ---------------------------------------------------------------------------

@@ -258,8 +258,12 @@ def _dense_gather_attention(qh, k_pools, v_pools, layer, block_tables, lens,
     """The reference oracle: gather `layer`'s blocks by block table into a
     dense ``[S, T, H, D]`` context, full softmax. Scores and the
     softmax/PV accumulation run in fp32 (matching `_plain_attention`) so
-    bf16 serving keeps its tail tokens; only the output casts back."""
-    s, h, d = qh.shape
+    bf16 serving keeps its tail tokens; only the output casts back. With
+    more query heads than a row holds, query head i reads key/value head
+    ``i // group``."""
+    s, hq, d = qh.shape
+    h = k_pools.shape[-1] // d
+    group = hq // h
     m = block_tables.shape[1]
     t_max = m * block_size
     # the GATHERED rows are split into heads, never the pool
@@ -274,6 +278,9 @@ def _dense_gather_attention(qh, k_pools, v_pools, layer, block_tables, lens,
         vg = vg.astype(jnp.float32)
     keys = kg.reshape(s, t_max, h, d)
     vals = vg.reshape(s, t_max, h, d)
+    if group > 1:
+        keys = jnp.repeat(keys, group, axis=2)
+        vals = jnp.repeat(vals, group, axis=2)
     scores = jnp.einsum("shd,sthd->sht", qh.astype(jnp.float32), keys) \
         / jnp.sqrt(jnp.asarray(d, jnp.float32))
     valid = jnp.arange(t_max, dtype=jnp.int32)[None, :] <= lens[:, None]
@@ -291,7 +298,9 @@ def paged_decode_attention(q, k_new, v_new, k_pools, v_pools, layer,
     cache (the PagedAttention memory model; serving/cache.py).
 
     q/k_new/v_new: ``[S, 1, H, D]`` — this step's projections for every
-    batch slot (S is the engine's fixed max-batch slot count).
+    batch slot (S is the engine's fixed max-batch slot count). q may hold
+    a multiple of k_new's heads (grouped queries: query head i reads
+    key/value head ``i // group``); the pools' row is k_new's.
     k_pools/v_pools: ``[L, num_blocks, block_size, H*D]`` — the pools of
     ALL layers (fp, or int8 with per-block-per-head `k_scales`/`v_scales`
     ``[L, num_blocks, H]``; quantization/kv_cache.py); `layer` (a Python
@@ -321,7 +330,8 @@ def paged_decode_attention(q, k_new, v_new, k_pools, v_pools, layer,
     ``(new_k_scales, new_v_scales)`` in int8 mode. Every other layer of
     the returned pools is what came in.
     """
-    s, _, num_heads, head_dim = q.shape
+    s, _, _, head_dim = q.shape
+    num_heads = k_new.shape[2]              # the heads a pool's row holds
     quantized = k_scales is not None
     lens = jnp.where(active, seq_lens, 0).astype(jnp.int32)
     rows = jnp.arange(s, dtype=jnp.int32)

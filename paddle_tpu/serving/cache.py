@@ -168,17 +168,46 @@ class CacheSpec:
     chunk_tokens / min_width_slots: the loop's chunk and its narrowest
     width, where the plan's own are not wanted (`loop_plan(block_size)`
     is what the loop and its counter are both given).
+    query_heads: the heads that ASK, where they are more than the
+    `num_heads` a ``"kv"`` row holds (grouped queries: query head i reads
+    key/value head ``i // (query_heads // num_heads)``); `num_heads`
+    where not given.
+    state_layers / state_shape: a SECOND kind of state beside the paged
+    pool, for layers that are not attention and still keep something of
+    the past (a short convolution's last inputs): `state_layers` layers
+    each keep ONE fixed block of trailing shape `state_shape` a slot,
+    not paged: ``[state_layers, slots] + state_shape`` in the model's
+    dtype (`PagedKVCache.slot_state`), threaded through the layers by
+    `PagedCacheView` as the pools are. 0 layers: the model keeps none,
+    no buffer exists and no program has an argument for it.
+
+    THE RULE a slot's state is kept by (tests/test_lfm2_moe.py holds it):
+    a PREFILL WRITES its slot's state WHOLE, as it stands after the
+    prompt's true `length` (not its bucket's end; what lies before the
+    sequence is zeros, so prompts shorter than the state need no special
+    case), so a reused slot needs no clearing and an evicted request's
+    resume, which is a re-prefill of prompt + generated tokens, restores
+    the state by computing it; a DECODE launch shifts and writes the
+    state of ACTIVE slots only, where it lies. What a state cannot do
+    yet the engine refuses by name at construction: reuse of a prefix
+    (no state exists at a prefix's boundary), adapters, an int8 pool.
     """
 
     __slots__ = ("kind", "num_layers", "parts", "widths", "num_heads",
-                 "head_dim", "chunk_tokens", "min_width_slots")
+                 "head_dim", "chunk_tokens", "min_width_slots",
+                 "query_heads", "state_layers", "state_shape")
 
     def __init__(self, kind, num_layers, parts, widths, num_heads,
-                 head_dim, chunk_tokens=None, min_width_slots=None):
+                 head_dim, chunk_tokens=None, min_width_slots=None,
+                 query_heads=None, state_layers=0, state_shape=()):
         if kind not in ("kv", "latent"):
             raise ValueError(f"unknown cache kind {kind!r}")
         if len(widths) != (2 if kind == "kv" else 1):
             raise ValueError(f"a {kind!r} cache of {len(widths)} pools")
+        query_heads = int(query_heads or num_heads)
+        if query_heads % int(num_heads):
+            raise ValueError(f"{query_heads} query heads over {num_heads} "
+                             "key/value heads: not whole groups")
         self.kind = kind
         self.num_layers = int(num_layers)
         self.parts = tuple(tuple(int(n) for n in p) for p in parts)
@@ -187,6 +216,9 @@ class CacheSpec:
         self.head_dim = int(head_dim)
         self.chunk_tokens = chunk_tokens
         self.min_width_slots = min_width_slots
+        self.query_heads = query_heads
+        self.state_layers = int(state_layers)
+        self.state_shape = tuple(int(n) for n in state_shape)
 
     def loop_plan(self, block_size):
         """Keyword arguments of the blockwise loop's plan ({} for the
@@ -200,15 +232,17 @@ class CacheSpec:
         return plan
 
     @classmethod
-    def per_head(cls, num_layers, num_heads, head_dim):
+    def per_head(cls, num_layers, num_heads, head_dim, **more):
         """A K and a V pool of all the heads' values side by side."""
         part = (num_heads, head_dim)
         return cls("kv", num_layers, (part, part),
-                   (num_heads * head_dim,) * 2, num_heads, head_dim)
+                   (num_heads * head_dim,) * 2, num_heads, head_dim, **more)
 
     def empty_prefill(self, dtype):
         """The caches a prefill hands the model: for each cached sublayer
-        the two parts with no token in them yet."""
+        the two parts with no token in them yet, then, for each layer that
+        keeps a per-slot state, that state as it is before a sequence:
+        zeros. The model hands back the same list after the prompt."""
         from ..framework.core import Tensor
         caches = []
         for _ in range(self.num_layers):
@@ -217,6 +251,9 @@ class CacheSpec:
             caches.append((first, first if self.parts[1] == self.parts[0]
                            else Tensor(jnp.zeros((1, 0) + self.parts[1],
                                                  dtype))))
+        if self.state_layers:
+            caches += [Tensor(jnp.zeros((1,) + self.state_shape, dtype))] \
+                * self.state_layers
         return caches
 
 
@@ -236,14 +273,22 @@ class PagedCacheView:
     (`k_scales`/`v_scales`, quantization/kv_cache.py); `kernel` pins the
     attention variant the owning engine resolved at construction
     (nn/functional/attention.resolve_paged_kernel), so a mid-run flag
-    flip never re-keys a live engine's compiled decode step."""
+    flip never re-keys a live engine's compiled decode step.
+
+    `slot_state` (``[state_layers, slots, ...]``, or None where the model
+    keeps none) is the slots' state that is not paged, with
+    `state_layer`, the index of the next layer that owns one: such a
+    layer reads and writes its own index and hands the view on through
+    `updated(slot_state=...)`, as an attention layer hands on the
+    pools."""
 
     __slots__ = ("k_pools", "v_pools", "layer", "block_tables", "seq_lens",
-                 "active", "block_size", "k_scales", "v_scales", "kernel")
+                 "active", "block_size", "k_scales", "v_scales", "kernel",
+                 "slot_state", "state_layer")
 
     def __init__(self, k_pools, v_pools, layer, block_tables, seq_lens,
                  active, block_size, k_scales=None, v_scales=None,
-                 kernel=None):
+                 kernel=None, slot_state=None, state_layer=0):
         self.k_pools = k_pools
         self.v_pools = v_pools
         self.layer = int(layer)
@@ -254,13 +299,27 @@ class PagedCacheView:
         self.k_scales = k_scales
         self.v_scales = v_scales
         self.kernel = kernel
+        self.slot_state = slot_state
+        self.state_layer = int(state_layer)
 
-    def updated(self, k_pools, v_pools, k_scales=None, v_scales=None):
-        """The view for the NEXT layer, over the pools this one wrote."""
+    def updated(self, k_pools=None, v_pools=None, k_scales=None,
+                v_scales=None, slot_state=None):
+        """The view for the NEXT layer: over the pools an attention layer
+        wrote, or, given `slot_state`, over the state a layer that keeps
+        one wrote (the pools and their layer index as they were)."""
+        if slot_state is not None:
+            return PagedCacheView(
+                self.k_pools, self.v_pools, self.layer, self.block_tables,
+                self.seq_lens, self.active, self.block_size,
+                k_scales=self.k_scales, v_scales=self.v_scales,
+                kernel=self.kernel, slot_state=slot_state,
+                state_layer=self.state_layer + 1)
         return PagedCacheView(k_pools, v_pools, self.layer + 1,
                               self.block_tables, self.seq_lens, self.active,
                               self.block_size, k_scales=k_scales,
-                              v_scales=v_scales, kernel=self.kernel)
+                              v_scales=v_scales, kernel=self.kernel,
+                              slot_state=self.slot_state,
+                              state_layer=self.state_layer)
 
 
 def _is_int8(dtype):
@@ -285,9 +344,17 @@ class PagedKVCache:
     scale side-tables ``[L, num_blocks, H]`` (`k_scales`/`v_scales`) —
     each cached token costs 1 byte per element instead of 4, so the same
     HBM watermark admits ~2x the streams before `kv_exhausted`.
+
+    A model whose `CacheSpec` describes a per-slot state gets it beside
+    the pools: `slot_state` ``[state_layers, num_slots] + state_shape``
+    in `state_dtype` (the model's; the pool's where not given), zeros,
+    not paged and never allocated from. `buffers()` is every device
+    buffer of the cache in the order the engine's programs take, donate
+    and hand them back.
     """
 
-    def __init__(self, spec, num_blocks, block_size, dtype=jnp.float32):
+    def __init__(self, spec, num_blocks, block_size, dtype=jnp.float32,
+                 num_slots=0, state_dtype=None):
         self.spec = spec
         self.num_layers = spec.num_layers
         self.num_heads = spec.num_heads
@@ -312,7 +379,23 @@ class PagedKVCache:
         else:
             self.k_scales = None
             self.v_scales = None
+        self.slot_state = None
+        if spec.state_layers:
+            self.slot_state = jnp.zeros(
+                (spec.state_layers, int(num_slots)) + spec.state_shape,
+                self.dtype if state_dtype is None else state_dtype)
         self.allocator = BlockAllocator(self.num_blocks)
+
+    def buffers(self):
+        """The cache's device buffers: the two pools, the int8 scale
+        side-tables where the pool is quantized, the slots' state where
+        the model keeps one."""
+        out = (self.k_pools, self.v_pools)
+        if self.quantized:
+            out += (self.k_scales, self.v_scales)
+        if self.slot_state is not None:
+            out += (self.slot_state,)
+        return out
 
 
 def pool_bytes_per_block(num_layers, num_heads, head_dim, block_size,

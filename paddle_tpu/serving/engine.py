@@ -13,7 +13,10 @@ millions of users"), combining:
   * a **compiled decode step**: a single `jax.jit` executable over a
     fixed max-batch slot layout — ``(tokens [S], feedback [S],
     override [S], block_tables [S, M], seq_lens [S], active [S], k_pools,
-    v_pools) -> (next_tokens, new_pools)`` with the pools donated (a
+    v_pools, ...) -> (next_tokens, new_pools, ...)`` with the cache's
+    buffers donated, however many it has (`PagedKVCache.buffers()`: the
+    two pools, an int8 pool's scales, a per-slot state of layers that
+    are not attention) (a
     slot's input is the host's token where `override` is set, else the
     launch before's or its own prefill's, still on the device). Requests
     joining or leaving the batch only change the *values* of the integer
@@ -310,6 +313,9 @@ class ServeStats:
         # launch's active slots
         self.prefill_tokens = 0
         self.decode_tokens = 0
+        # the width of every prefill's bucket, beside `prefill_tokens`:
+        # what the programs multiplied, padding included
+        self.prefill_bucket_tokens = 0
         # what the model's own forward counted (an expert block's
         # routing), summed over the calls of a phase: "<phase>_<name>",
         # and "<phase>_counted" calls; read beside a launch's tokens
@@ -475,6 +481,9 @@ class ServeStats:
                 self.attn_entries_held / self.attn_entries_total
                 if self.attn_entries_total else 0.0),
             "prefill_tokens": self.prefill_tokens,
+            "prefill_bucket_tokens": self.prefill_bucket_tokens,
+            "prefill_padding_tokens": (self.prefill_bucket_tokens
+                                       - self.prefill_tokens),
             "decode_tokens": self.decode_tokens,
             "decode_launches": self.launches,
             **self.model_counts,
@@ -541,9 +550,16 @@ class LLMEngine:
     token-for-token — the parity contract tests/test_serving.py pins).
     The model is put in eval mode and asked what it caches for a token
     (`model.cache_spec()`, serving/cache.py `CacheSpec`: per-head keys
-    and values, or one latent row): the pools, the prefill's empty
+    and values, or one latent row; and, for layers that are not
+    attention and still keep something of the past, a fixed per-slot
+    state beside the pools): the pools, the state, the prefill's empty
     caches and the attention plan come from that description, and the
-    engine holds no head size of its own. By default the parameters are
+    engine holds no head size of its own. A per-slot state follows
+    `CacheSpec`'s rule in every loop: a prefill writes its slot's state
+    whole, as it stands at the prompt's true length, a decode launch
+    shifts the active slots' where it lies, so slot reuse, eviction,
+    resume and `restore_state()` need nothing of their own (each
+    re-prefills). By default the parameters are
     BAKED into the compiled programs as constants; a model whose class
     sets `serve_weights_as_arguments` (one too large for that) has them
     passed as arguments instead, and one whose class names
@@ -625,6 +641,18 @@ class LLMEngine:
                     raise ValueError(
                         f"{option} is not supported over a latent cache "
                         f"({type(model).__name__}.cache_spec())")
+        if spec.state_layers:
+            # nor what a per-slot state cannot do yet (`CacheSpec`): a
+            # prefix hit skips the prefill that writes the state, and no
+            # state exists at a prefix's boundary
+            for option, on in (
+                    ("enable_prefix_cache", enable_prefix_cache),
+                    ("max_adapters", max_adapters > 0),
+                    ("kv_dtype='int8'", self._kv_quantized)):
+                if on:
+                    raise ValueError(
+                        f"{option} is not supported beside a per-slot "
+                        f"state ({type(model).__name__}.cache_spec())")
         self._attn_kernel = resolve_paged_kernel(
             attention_kernel, num_heads=spec.num_heads,
             head_dim=spec.head_dim, block_size=self.block_size,
@@ -637,7 +665,9 @@ class LLMEngine:
                                  "num_blocks": int(num_blocks),
                                  "block_size": self.block_size})
         self.cache = PagedKVCache(spec, num_blocks, self.block_size,
-                                  self._kv_dtype)
+                                  self._kv_dtype,
+                                  num_slots=self.max_batch_size,
+                                  state_dtype=self._dtype)
         self.scheduler = Scheduler(self.max_batch_size,
                                    self.cache.allocator, self.block_size,
                                    watermark_blocks,
@@ -739,10 +769,10 @@ class LLMEngine:
         # record, and the step after commits them from ONE fetch
         self._firsts = self._empty_firsts()
         self._joined = {}
-        self._k_pools = self.cache.k_pools
-        self._v_pools = self.cache.v_pools
-        self._k_scales = self.cache.k_scales       # None unless int8 KV
-        self._v_scales = self.cache.v_scales
+        # the cache's device buffers (`PagedKVCache.buffers()`: the two
+        # pools, the int8 scales, a per-slot state, whichever it has):
+        # every program takes them last, donates them and hands them back
+        self._bufs = self.cache.buffers()
         self._decode_fn = None
         self._prefill_fns = {}
         # AOT warm start (ops/aot_cache.py): the decode digest is computed
@@ -1337,9 +1367,7 @@ class LLMEngine:
         # adopt the launch's pool lineage NOW: any prefill issued before
         # the commit must consume THESE outputs, so XLA's dataflow
         # orders the speculative KV write before the reuse
-        self._k_pools, self._v_pools = res[4], res[5]
-        if self._kv_quantized:
-            self._k_scales, self._v_scales = res[6], res[7]
+        self._bufs = res[4:4 + len(self._bufs)]
         self._feedback = res[0]
         # by slot, what the commit needs to know the token is still
         # wanted: the request, its position and its admission
@@ -1621,6 +1649,8 @@ class LLMEngine:
         snap["block_size"] = self.block_size
         snap["attention_kernel"] = self._attn_kernel
         snap["kv_dtype"] = str(jnp.dtype(self._kv_dtype))
+        state = self.cache.slot_state
+        snap["slot_state_bytes"] = 0 if state is None else int(state.nbytes)
         if self._prefix is not None:
             snap["prefix_entries"] = self._prefix.entries
         if self._tenant:
@@ -1707,9 +1737,7 @@ class LLMEngine:
                 self._prefill_args(padded, np.int32(len(ctx)), row, req),
                 new_bucket)
             self._feedback, self._firsts = res[0], res[1]
-            self._k_pools, self._v_pools = res[2], res[3]
-            if self._kv_quantized:
-                self._k_scales, self._v_scales = res[4], res[5]
+            self._bufs = res[2:2 + len(self._bufs)]
             req.cached_len = len(ctx)
             self._sync_slot(req)
             self._set_adapter_slot(req)
@@ -1720,6 +1748,7 @@ class LLMEngine:
                 self._prefix.publish(ctx, req.blocks,
                                      include_tail=not req.generated)
             self._stats.prefill_tokens += len(ctx)
+            self._stats.prefill_bucket_tokens += bucket
             self._override[req.slot] = False
             self._joined[req.slot] = (req, len(ctx), req.admit_seq)
         if new_bucket:
@@ -1802,20 +1831,46 @@ class LLMEngine:
             base = base + (self._prefill_aux(req),)
         feedback = self._tokens.copy() if self._feedback is None \
             else self._feedback
-        return self._kv_args(*(base + (
+        return base + (
             np.float32(req.temperature), np.int32(req.top_k),
             np.float32(req.top_p), np.float32(req.repetition_penalty),
             np.uint32(req.seed or 0), np.int32(req.slot), feedback,
-            self._firsts, self._k_pools, self._v_pools)))
+            self._firsts) + self._bufs
 
     def _kv_args(self, *base):
         """Positional args for the compiled decode/prefill programs:
-        `base` plus the int8 scale side-tables when the pool is
-        quantized — the single source of truth for the signatures'
-        optional trailing pair."""
-        if self._kv_quantized:
-            return base + (self._k_scales, self._v_scales)
-        return base
+        `base`, which ends with the two pools, plus whatever else the
+        cache holds (the int8 scale side-tables, a per-slot state) — the
+        single source of truth for the signatures' optional tail."""
+        return base + self._bufs[2:]
+
+    # the cache's buffers by name, for what reads one of them
+    _k_pools = property(lambda self: self._bufs[0])
+    _v_pools = property(lambda self: self._bufs[1])
+
+    def _donated(self, first):
+        """The argument numbers of the cache's buffers in a program whose
+        signature has them from `first` on."""
+        return self._donate(tuple(range(first, first + len(self._bufs))))
+
+    def _split_more(self, more):
+        """What a program's signature holds behind the two pools:
+        ``(k_scales, v_scales, slot_state)``, None where the cache has
+        none."""
+        scales = tuple(more[:2]) if self._kv_quantized else (None, None)
+        state = more[-1] if self.cache.slot_state is not None else None
+        return scales + (state,)
+
+    @staticmethod
+    def _written(view):
+        """The buffers a program hands back, in `buffers()`' order, from
+        the view its last layer returned."""
+        out = (view.k_pools, view.v_pools)
+        if view.k_scales is not None:
+            out += (view.k_scales, view.v_scales)
+        if view.slot_state is not None:
+            out += (view.slot_state,)
+        return out
 
     def _sync_slot(self, req):
         slot = req.slot
@@ -1989,9 +2044,7 @@ class LLMEngine:
                 self._degrade("decode_fault", {"injected": True})
                 self._recover_with_fallback(rebuild=False)
                 return None
-            self._k_pools, self._v_pools = res[4], res[5]
-            if self._kv_quantized:
-                self._k_scales, self._v_scales = res[6], res[7]
+            self._bufs = res[4:4 + len(self._bufs)]
             self._maybe_store_decode()
             return self._fetch_launch(res)
 
@@ -2013,9 +2066,9 @@ class LLMEngine:
                 self._lens, self._active)
         if self._tenant:
             base = base + (self._decode_aux(),)
-        return self._kv_args(*(base + (
+        return base + (
             self._temps, self._topks, self._topps, self._rpens,
-            self._seeds, self._history, self._k_pools, self._v_pools)))
+            self._seeds, self._history) + self._bufs
 
     def _call_decode(self, args):
         fn = self._decode_fn
@@ -2061,11 +2114,8 @@ class LLMEngine:
         stats.attn_entries_total += total
 
     def _pools_consumed(self):
-        deleted = getattr(self._k_pools, "is_deleted", None)
-        if deleted is not None and deleted():
-            return True
-        deleted = getattr(self._v_pools, "is_deleted", None)
-        return deleted is not None and deleted()
+        return any(b.is_deleted() for b in self._bufs
+                   if hasattr(b, "is_deleted"))
 
     def _note_hang(self):
         """Metrics-side view of one watchdog firing: the wedged wall
@@ -2176,7 +2226,9 @@ class LLMEngine:
         assert not self.scheduler.running, \
             "KV reset with live streams would corrupt them"
         self.cache = PagedKVCache(self.cache.spec, self._num_blocks,
-                                  self.block_size, self._kv_dtype)
+                                  self.block_size, self._kv_dtype,
+                                  num_slots=self.max_batch_size,
+                                  state_dtype=self._dtype)
         self.scheduler.allocator = self.cache.allocator
         s, m = self.max_batch_size, self.max_blocks_per_seq
         self._tables = np.zeros((s, m), np.int32)
@@ -2195,10 +2247,7 @@ class LLMEngine:
         self._override = np.ones(s, bool)
         self._firsts = self._empty_firsts()
         self._joined = {}
-        self._k_pools = self.cache.k_pools
-        self._v_pools = self.cache.v_pools
-        self._k_scales = self.cache.k_scales
-        self._v_scales = self.cache.v_scales
+        self._bufs = self.cache.buffers()
         if self._prefix is not None:
             # the old pool died with its allocator — the index's
             # references are meaningless now: forget, do not free
@@ -2209,8 +2258,9 @@ class LLMEngine:
     # ------------------------------------------------------------------
     def state_payload(self):
         """JSON-able snapshot of every in-flight request (prompt, emitted
-        tokens, arrival order, remaining TTL) — NOT the KV pool, which
-        re-prefills token-identically on resume. Saved each boundary by
+        tokens, arrival order, remaining TTL) — NOT the KV pool nor a
+        per-slot state, which the re-prefill on resume computes again,
+        token-identically (`CacheSpec`'s rule). Saved each boundary by
         `incubate.checkpoint.ServeCheckpointer`; feed the loaded payload
         to `restore_state()` in the restarted process."""
         now = time.perf_counter_ns()
@@ -2349,7 +2399,8 @@ class LLMEngine:
                  self.block_size, self._num_blocks,
                  # the pool's shape is the donated arguments' signature:
                  # an artifact traced for another layout never replays
-                 tuple(self.cache.k_pools.shape),
+                 tuple(self.cache.k_pools.shape)
+                 + tuple(getattr(self.cache.slot_state, "shape", ())),
                  self.max_blocks_per_seq, str(self._dtype),
                  # the kernel tier re-keys the artifact: a blockwise
                  # executable must never replay as the pallas one, and an
@@ -2397,9 +2448,11 @@ class LLMEngine:
         """The model over `ids` through `caches`, inside a program's
         trace: ``(logits, caches, extra outputs)``, the extra being the
         model's own counters where its class names some (a prefill's
-        `length` keeps its bucket's padding out of them)."""
+        `length` keeps its bucket's padding out of them, and tells a
+        model that keeps a per-slot state where the prompt ends)."""
         kwargs = {}
-        if self._counter_names and length is not None:
+        if (self._counter_names or self.cache.spec.state_layers) \
+                and length is not None:
             kwargs["valid"] = (jnp.arange(ids.shape[1], dtype=jnp.int32)
                                < length)[None, :]
         with set_grad_enabled(False):
@@ -2422,8 +2475,9 @@ class LLMEngine:
 
         def decode(tokens, feedback, override, tables, lens, active,
                    temps, topks, topps, rpens, seeds, history, k_pools,
-                   v_pools, k_scales=None, v_scales=None):
+                   v_pools, *more):
             stats.decode_compiles += 1   # runs only while tracing
+            k_scales, v_scales, slot_state = self._split_more(more)
             # a slot's input is the token the launch before sampled for
             # it, still on the device, unless the host wrote one
             tokens = jnp.where(override, tokens, feedback)
@@ -2432,7 +2486,8 @@ class LLMEngine:
             # the last layer returns are the step's, updated in place
             view = PagedCacheView(
                 k_pools, v_pools, 0, tables, lens, active, block_size,
-                k_scales=k_scales, v_scales=v_scales, kernel=variant)
+                k_scales=k_scales, v_scales=v_scales, kernel=variant,
+                slot_state=slot_state)
             logits, (view,), extra = self._forward(tokens[:, None], [view])
             # the in-graph history scatter: the input token enters the
             # context at index `lens` — under pipelined decode it may
@@ -2449,13 +2504,11 @@ class LLMEngine:
             nxt, logp, alt_ids, alt_lps = sample_tokens(
                 logits._value[:, -1, :], temps, topks, topps, rpens,
                 seeds, lens + 1, hist, valid, logprobs_topk=lp_topk)
-            written = (view.k_pools, view.v_pools)
-            if k_scales is not None:
-                written += (view.k_scales, view.v_scales)
-            return (nxt, logp, alt_ids, alt_lps) + written + extra
+            return (nxt, logp, alt_ids, alt_lps) + self._written(view) \
+                + extra
 
-        donate = (12, 13, 14, 15) if self._kv_quantized else (12, 13)
-        jitted = jax.jit(decode, donate_argnums=self._donate(donate))
+        donate = self._donated(12)
+        jitted = jax.jit(decode, donate_argnums=donate)
         from ..ops import aot_cache as _aot
         if use_aot and _aot.enabled():
             # warm start: a restarted replica deserializes yesterday's
@@ -2468,7 +2521,7 @@ class LLMEngine:
                 exe = _aot.load_callable(
                     "decode", digest, "serve.decode",
                     fallback=lambda: jitted,
-                    donate_argnums=self._donate(donate))
+                    donate_argnums=donate)
                 if exe is not None:
                     return exe
                 self._aot_pending_store = (digest, jitted)
@@ -2494,8 +2547,9 @@ class LLMEngine:
 
         def decode(tokens, feedback, override, tables, lens, active, aux,
                    temps, topks, topps, rpens, seeds, history, k_pools,
-                   v_pools, k_scales=None, v_scales=None):
+                   v_pools, *more):
             stats.decode_compiles += 1   # runs only while tracing
+            k_scales, v_scales, slot_state = self._split_more(more)
             tokens = jnp.where(override, tokens, feedback)
             pvals = aux.get("params")
             saved = None
@@ -2509,7 +2563,8 @@ class LLMEngine:
             try:
                 view = PagedCacheView(
                     k_pools, v_pools, 0, tables, lens, active, block_size,
-                    k_scales=k_scales, v_scales=v_scales, kernel=variant)
+                    k_scales=k_scales, v_scales=v_scales, kernel=variant,
+                    slot_state=slot_state)
                 logits, (view,), extra = self._forward(tokens[:, None],
                                                        [view])
             finally:
@@ -2526,30 +2581,37 @@ class LLMEngine:
             nxt, logp, alt_ids, alt_lps = sample_tokens(
                 logits._value[:, -1, :], temps, topks, topps, rpens,
                 seeds, lens + 1, hist, valid, logprobs_topk=lp_topk)
-            written = (view.k_pools, view.v_pools)
-            if k_scales is not None:
-                written += (view.k_scales, view.v_scales)
-            return (nxt, logp, alt_ids, alt_lps) + written + extra
+            return (nxt, logp, alt_ids, alt_lps) + self._written(view) \
+                + extra
 
-        donate = (13, 14, 15, 16) if self._kv_quantized else (13, 14)
-        return jax.jit(decode, donate_argnums=self._donate(donate))
+        return jax.jit(decode, donate_argnums=self._donated(13))
 
     def _prefill_results(self, ids, length, block_row, sampler, slot,
-                         feedback, firsts, k_pools, v_pools, k_scales,
-                         v_scales, logits, caches, extra):
+                         feedback, firsts, k_pools, v_pools, more, logits,
+                         caches, extra):
         """What both prefill programs do behind the model's forward,
         inside their trace: the prompt's KV into the pools, the first
         token sampled, and the token handed on WHERE IT IS: into
         `feedback` at the request's slot (the next decode launch's
         input) and, with its logprob, its panel and the model's counters,
         as one int32 row of `firsts` (floats by their bits), which the
-        host fetches once for all of a boundary's prefills. Returns
-        ``(feedback, firsts) + the written pools``."""
-        k_layers = jnp.stack([c[0]._value[0] for c in caches])
-        v_layers = jnp.stack([c[1]._value[0] for c in caches])
-        written = scatter_prefill(
+        host fetches once for all of a boundary's prefills. Where the
+        model keeps a per-slot state, the forward's caches end with one
+        state a layer that keeps one, as it stands after the prompt's
+        `length`: written WHOLE at the request's slot (`CacheSpec`'s
+        rule: a reused slot needs no clearing). Returns ``(feedback,
+        firsts) + the written buffers``."""
+        k_scales, v_scales, slot_state = self._split_more(more)
+        paged = caches[:self.cache.spec.num_layers]
+        k_layers = jnp.stack([c[0]._value[0] for c in paged])
+        v_layers = jnp.stack([c[1]._value[0] for c in paged])
+        written = tuple(scatter_prefill(
             k_pools, v_pools, k_layers, v_layers, block_row, length,
-            self.block_size, k_scales=k_scales, v_scales=v_scales)
+            self.block_size, k_scales=k_scales, v_scales=v_scales))
+        if slot_state is not None:
+            states = jnp.stack([c._value[0] for c in caches[len(paged):]])
+            written += (slot_state.at[:, slot].set(
+                states.astype(slot_state.dtype)),)
         last = jax.lax.dynamic_index_in_dim(
             logits._value[0], length - 1, axis=0, keepdims=False)
         # the prompt's first sampled token: position = prompt length
@@ -2563,7 +2625,7 @@ class LLMEngine:
             logprobs_topk=self._logprobs_topk)
 
         return self._hand_on(feedback, firsts, slot, nxt, logp, alt_ids,
-                             alt_lps, extra) + tuple(written)
+                             alt_lps, extra) + written
 
     @staticmethod
     def _hand_on(feedback, firsts, slot, nxt, logp, alt_ids, alt_lps,
@@ -2590,17 +2652,16 @@ class LLMEngine:
 
         def prefill(ids, length, block_row, temp, topk, topp, rpen,
                     seedv, slot, feedback, firsts, k_pools, v_pools,
-                    k_scales=None, v_scales=None):
+                    *more):
             stats.prefill_compiles += 1   # runs only while tracing
             logits, caches, extra = self._forward(
                 ids, spec.empty_prefill(dt), length)
             return self._prefill_results(
                 ids, length, block_row, (temp, topk, topp, rpen, seedv),
-                slot, feedback, firsts, k_pools, v_pools, k_scales,
-                v_scales, logits, caches, extra)
+                slot, feedback, firsts, k_pools, v_pools, more, logits,
+                caches, extra)
 
-        donate = (11, 12, 13, 14) if self._kv_quantized else (11, 12)
-        return jax.jit(prefill, donate_argnums=self._donate(donate))
+        return jax.jit(prefill, donate_argnums=self._donated(11))
 
     def _build_prefill_tenant(self, bucket):
         """Tenant twin of `_build_prefill`: the same bucketed prompt
@@ -2614,7 +2675,7 @@ class LLMEngine:
 
         def prefill(ids, length, block_row, aux, temp, topk, topp, rpen,
                     seedv, slot, feedback, firsts, k_pools, v_pools,
-                    k_scales=None, v_scales=None):
+                    *more):
             stats.prefill_compiles += 1   # runs only while tracing
             pvals = aux.get("params")
             saved = None
@@ -2636,11 +2697,10 @@ class LLMEngine:
                     holder["active"] = None
             return self._prefill_results(
                 ids, length, block_row, (temp, topk, topp, rpen, seedv),
-                slot, feedback, firsts, k_pools, v_pools, k_scales,
-                v_scales, logits, caches, extra)
+                slot, feedback, firsts, k_pools, v_pools, more, logits,
+                caches, extra)
 
-        donate = (12, 13, 14, 15) if self._kv_quantized else (12, 13)
-        return jax.jit(prefill, donate_argnums=self._donate(donate))
+        return jax.jit(prefill, donate_argnums=self._donated(12))
 
     # ------------------------------------------------------------------
     # multi-tenant serving (PR 17, serving/tenancy.py)
@@ -2758,9 +2818,7 @@ class LLMEngine:
         res = self._cow_fn(*self._kv_args(
             self._k_pools, self._v_pools,
             jnp.asarray(src, jnp.int32), jnp.asarray(dst, jnp.int32)))
-        self._k_pools, self._v_pools = res[0], res[1]
-        if self._kv_quantized:
-            self._k_scales, self._v_scales = res[2], res[3]
+        self._bufs = tuple(res)
 
     def _params_crc(self):
         """CRC over every parameter's bytes — the weight-set identity

@@ -126,10 +126,11 @@ def test_a_fault_in_the_layers_mathematics_fails_the_comparison(
         monkeypatch.setattr(lc, "held_expert_block", faulty)
     elif fault == "bias_skipped":
         monkeypatch.setattr(held_experts, "route",
-                            lambda u, w, b, k, s: real(u, w, None, k, s))
+                            lambda u, w, b, k, s, *form:
+                            real(u, w, None, k, s, *form))
     elif fault == "weights_renormalised":
-        def renormalised(u, w, b, k, s):
-            chosen, weight = real(u, w, b, k, s)
+        def renormalised(u, w, b, k, s, *form):
+            chosen, weight = real(u, w, b, k, s, *form)
             return chosen, s * weight / jnp.sum(weight, -1, keepdims=True)
         monkeypatch.setattr(held_experts, "route", renormalised)
     elif fault == "rotary_key_scaled":
@@ -419,8 +420,12 @@ def test_a_launched_prefills_counters_come_with_its_first_token(model):
     serial, _, same = serve(model, False)
     assert served == same
     a, b = piped.stats(), serial.stats()
+    # counts, not clock shares; nor what a dispatch FOUND the device
+    # doing (`_device_idle`, `_ran_dry`): that is the clock's observation,
+    # and differs between two engines on a loaded machine
     keys = [k for k in b if k.startswith("prefill_")
-            and isinstance(b[k], int)]          # counts, not clock shares
+            and isinstance(b[k], int)
+            and not k.endswith(("_device_idle", "_ran_dry"))]
     assert "prefill_routed_held" in keys and "prefill_counted" in keys
     assert {k: a[k] for k in keys} == {k: b[k] for k in keys}
     assert a["prefill_unawaited_share"] == pytest.approx(4 / 6)

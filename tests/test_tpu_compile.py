@@ -553,3 +553,121 @@ def test_latent_eligibility_is_what_the_compiler_accepts(v5e, monkeypatch):
     assert paged_attention._group_pages(
         LATENT_TABLE, BLOCK, LATENT_ROW, jnp.bfloat16,
         paged_attention._LATENT_GROUP_TOKENS) == 32
+
+
+# The retrieval cell's geometry (benchmark/traffic/backlog_rag_2k.json,
+# benchmark/configs/lfm2_8b_a1b_l16.json: 128 slots, block 16, 128 table
+# entries; 32 query heads over 8 key/value heads of 64, so a pool's row is
+# 512; 12 convolution layers that keep a row of 2 x 2048 a slot).
+GQ_HEADS, GQ_KV_HEADS, GQ_HEAD_DIM, GQ_TABLE = 32, 8, 64, 128
+
+
+def test_the_grouped_query_kernel_at_the_cells_geometry(v5e):
+    """`serve_lfm2_rag_backlog`'s decode attention as the engine traces
+    it: four query heads a key/value head over the cell's whole
+    `bf16[4,16385,16,512]` pools, the query's placement in the lanes and
+    the output's assembly by the kernel's 0/1 products: Mosaic takes it,
+    and the program holds no loop."""
+    pool = ((4, 16385, BLOCK, GQ_KV_HEADS * GQ_HEAD_DIM), jnp.bfloat16)
+    text = compile_for(
+        v5e, paged_kernel(BLOCK),
+        ((CELL_SLOTS, GQ_HEADS, GQ_HEAD_DIM), jnp.bfloat16), pool, pool,
+        ((CELL_SLOTS, GQ_TABLE), jnp.int32), ((CELL_SLOTS,), jnp.int32))
+    assert "while" not in text
+
+
+def test_a_decode_step_updates_the_pool_and_the_slots_state_where_they_lie(
+        v5e, monkeypatch):
+    """A model with two kinds of layer through ONE `PagedCacheView`, as
+    the engine's decode program threads it (weights as arguments): the
+    donated pools AND the donated per-slot state go out in the buffers
+    they came in; no instruction of either's shape is a `copy`, a
+    `concatenate` or a `pad`."""
+    import paddle_tpu as paddle
+    from paddle_tpu.incubate.models.lfm2_moe import (Lfm2MoeConfig,
+                                                     Lfm2MoeForCausalLM)
+    from paddle_tpu.serving.cache import PagedCacheView
+    monkeypatch.setattr(paged_attention, "_on_tpu", lambda: True)
+    kinds = ("conv", "full_attention", "conv", "full_attention")
+    model = Lfm2MoeForCausalLM(Lfm2MoeConfig(
+        vocab_size=512, hidden_size=2048, intermediate_size=256,
+        moe_intermediate_size=128, num_hidden_layers=4, layer_types=kinds,
+        num_dense_layers=4, num_attention_heads=GQ_HEADS,
+        num_key_value_heads=GQ_KV_HEADS, num_experts=4,
+        num_experts_per_tok=2))
+    params = model.parameters()
+    pool = (2, CELL_BLOCKS, BLOCK, GQ_KV_HEADS * GQ_HEAD_DIM)
+    state = (2, CELL_SLOTS, 2 * 2048)
+
+    def decode(values, tokens, tables, lens, active, k_pools, v_pools,
+               slot_state):
+        saved = [p._value for p in params]
+        for p, v in zip(params, values):
+            p._value = v
+        try:
+            view = PagedCacheView(k_pools, v_pools, 0, tables, lens, active,
+                                  BLOCK, kernel="pallas",
+                                  slot_state=slot_state)
+            logits, (view,) = model(paddle.Tensor(tokens[:, None]),
+                                    caches=[view])
+        finally:
+            for p, v in zip(params, saved):
+                p._value = v
+        assert (view.layer, view.state_layer) == (2, 2)
+        return logits._value, view.k_pools, view.v_pools, view.slot_state
+
+    sd = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                   sharding=v5e)
+    args = [[sd(p._value.shape, jnp.bfloat16) for p in params],
+            sd((CELL_SLOTS,), jnp.int32),
+            sd((CELL_SLOTS, GQ_TABLE), jnp.int32),
+            sd((CELL_SLOTS,), jnp.int32), sd((CELL_SLOTS,), jnp.bool_),
+            sd(pool, jnp.bfloat16), sd(pool, jnp.bfloat16),
+            sd(state, jnp.bfloat16)]
+    compiled = jax.jit(decode, donate_argnums=(5, 6, 7)).lower(
+        *args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    for shape in (pool, state):
+        name = "bf16[" + ",".join(map(str, shape)) + "]"
+        made = re.findall(
+            r"= " + re.escape(name) + r"\{([\d,]*)\S* ([\w-]+)\(", text)
+        assert {layout for layout, opcode in made
+                if opcode == "parameter"} == {
+                    ",".join(map(str, reversed(range(len(shape)))))}, name
+        opcodes = {opcode for _, opcode in made}
+        assert not opcodes & {"copy", "concatenate", "pad"}, (name, opcodes)
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 2 * (2 * math.prod(pool)
+                                              + math.prod(state))
+
+
+@pytest.mark.parametrize("tokens", [128, 2048],
+                         ids=["decode_launch", "prefill_2048"])
+def test_the_served_expert_block_with_every_expert_held(v5e, tokens):
+    """All 32 experts of 1,792 held, top 4, sigmoid scores normalised over
+    the chosen: a decode launch of 128 tokens and a 2,048-token prefill
+    through the form the call's shape picks (the grouped products, forward
+    only), compiled for the chip; what it keeps besides its operands is a
+    few buffers of `tokens * 4` rows, not `tokens * 32`."""
+    from paddle_tpu.incubate.distributed.models.moe import held_experts
+    bf16, d, f, experts = jnp.bfloat16, 2048, 1792, 32
+    assert held_experts.products_form(tokens, 4, experts) == "grouped"
+
+    def block(u, router, bias, w1, w3, w2, valid):
+        return held_experts.held_expert_block(
+            u, router, bias, w1, w3, w2, topk=4, real_experts=experts,
+            scaling=1.0, valid=valid, scoring="sigmoid", normalise=True,
+            epsilon=1e-6)
+
+    shapes = [((tokens, d), bf16), ((d, experts), bf16),
+              ((experts,), jnp.float32), ((experts, d, f), bf16),
+              ((experts, d, f), bf16), ((experts, f, d), bf16),
+              ((tokens,), jnp.bool_)]
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=v5e)
+            for shape, dtype in shapes]
+    compiled = jax.jit(block).lower(*args).compile()
+    assert "ragged" in compiled.as_text()
+    rows = tokens * 4
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 16 * rows * d * 4 + (64 << 20)
