@@ -6,7 +6,8 @@
                                      whole-step executable, `LLMEngine`
                                      serving with both attention variants
                                      over GPT's per-head pools and over a
-                                     latent pool (a small LongCat-Flash)
+                                     latent pool (a small LongCat-Flash),
+                                     the grouped expert matmul kernel
     python chip_smoke.py --chips 4   four chips: the hybrid-parallel mesh
                                      (dp=2 x mp=2) and its one-device
                                      control, and no other phase
@@ -29,6 +30,7 @@ import argparse
 import contextlib
 import gc
 import json
+import math
 import re
 import sys
 import time
@@ -392,6 +394,44 @@ def latent_serve_phase(sizes, prompt_lens, max_new_tokens, seed):
                       seed, with_generate=False)
 
 
+def grouped_matmul_phase(rows, k, n, experts, seed, interpret=False):
+    """The tiled grouped matmul kernel against `jax.lax.ragged_dot` over
+    the same sorted rows: `rows` bf16 rows in `experts` uneven groups (one
+    empty, the buffer's last eighth past every group and POISONED), each
+    against its own ``[k, n]`` matrix. Both accumulate in float32 from the
+    same bf16 operands, so they agree to the order of a product's sums;
+    nothing of the poisoned rows may reach a live one."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.kernels.pallas import grouped_matmul as gm
+    rng = np.random.default_rng(seed)
+    live = rows - rows // 8
+    share = rng.dirichlet(np.ones(experts - 1))
+    load = np.floor(share * live).astype(np.int32)
+    load[0] += live - load.sum()
+    load = np.insert(load, experts // 2, 0)             # an idle expert
+    a = rng.normal(0, 1, (rows, k)).astype(np.float32)
+    a[live:] = np.nan
+    a = jnp.asarray(a, jnp.bfloat16)
+    w = jnp.asarray(rng.normal(0, 0.02, (experts, k, n)), jnp.bfloat16)
+    sizes = jnp.asarray(load, jnp.int32)
+    got = np.asarray(gm.grouped_matmul(a, w, sizes, interpret=interpret))
+    want = np.asarray(jax.lax.ragged_dot(
+        a, w, sizes, preferred_element_type=jnp.float32))
+    check(got.dtype == np.float32 and got.shape == (rows, n),
+          f"the kernel's result is {got.dtype}{got.shape}")
+    check(np.isfinite(got[:live]).all(),
+          "a poisoned row past the groups reached a live row")
+    gap = float(np.abs(got[:live] - want[:live]).max())
+    scale = float(np.abs(want[:live]).max())
+    # float32 sums of k exact bf16 products in another order
+    check(gap <= 1e-5 * math.sqrt(k) * scale,
+          f"kernel and ragged_dot differ by {gap} of {scale}")
+    return {"phase": "grouped_matmul", "rows": rows, "live_rows": live,
+            "groups": experts, "k": k, "n": n, "tiles": gm.tiles(rows, k, n),
+            "max_abs_gap": gap, "max_abs": scale}
+
+
 def serve_legs(model, phase, vocab_size, prompt_lens, max_new_tokens, seed,
                with_generate=True):
     """Serve the same requests with the blockwise loop and with the Pallas
@@ -575,6 +615,10 @@ def run_one_chip(seed, cache_dir):
                                   [32, 57, 200, 256, 460, 500, 512, 600],
                                   max_new_tokens=64, seed=seed):
         emit(rec)
+    gc.collect()
+    # a decode launch of `serve_lfm2_rag_backlog`: 128 tokens' top 4 of 32
+    # experts, a gate product
+    emit(grouped_matmul_phase(512, 2048, 1792, 32, seed))
 
 
 def run_four_chips(seed, cache_dir):

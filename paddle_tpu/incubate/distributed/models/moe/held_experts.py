@@ -18,10 +18,13 @@ expert-parallel deployment asks something else of the block:
     products are bound by the expert's bytes, not by its rows; but the
     operations are ``held / topk`` x the needed ones once every expert
     is held), or GROUPED, the assignments sorted by expert into a buffer
-    no routing can overflow and multiplied by `jax.lax.ragged_dot`, each
-    group against its own expert (`grouped_experts.py`'s products,
-    forward only). Either way `computed == held` always (the counters
-    prove it) and a launch's time follows its routing;
+    no routing can overflow and multiplied each group against its own
+    expert: by the tiled Pallas kernel
+    (`kernels/pallas/grouped_matmul.py`) where `product_kernel` finds a
+    TPU and a shape on its tiles, else by `jax.lax.ragged_dot`
+    (`grouped_experts.py`'s products, forward only). Either way
+    `computed == held` always (the counters prove it) and a launch's
+    time follows its routing;
   * the router works in float32, products at "highest": top-k of its
     scores is a discrete choice and the reference's router is float32 too.
 
@@ -37,8 +40,12 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from .....kernels.pallas import grouped_matmul as _gm
+from .....profiler.events import EVENTS as _EVENTS
+
 __all__ = ["route", "held_expert_block", "masked_products",
-           "grouped_products", "products_form", "COUNTERS"]
+           "grouped_products", "products_form", "product_kernel",
+           "products_run", "COUNTERS"]
 
 # what `held_expert_block` counts of a call's choices, in this order
 COUNTERS = ("routed_held", "routed_identity", "routed_elsewhere",
@@ -98,6 +105,37 @@ def products_form(tokens, topk, held):
     return "grouped" if held >= 2 * topk else "masked"
 
 
+def product_kernel(rows, k, n, dtype):
+    """Which product multiplies `rows` sorted rows of `dtype` by their
+    groups' ``[k, n]`` matrices, from the platform and the call's static
+    shape: ``"pallas"``, the tiled kernel, on a TPU over a shape on its
+    tiles (`grouped_matmul.is_eligible`), else ``"ragged_dot"``, the
+    library's. A TPU's call sent back for its SHAPE is visible: one
+    `kernel.fallback` flight-recorder event says why."""
+    ok, why = _gm.is_eligible(rows, k, n, dtype)
+    if not ok and why not in ("no_pallas", "not_on_tpu"):
+        _EVENTS.emit("kernel.fallback", "ragged_expert_matmul",
+                     reason="kernel_fallback",
+                     detail={"requested": "pallas", "actual": "ragged_dot",
+                             "why": why, "rows": rows, "k": k, "n": n,
+                             "dtype": jnp.dtype(dtype).name})
+    return "pallas" if ok else "ragged_dot"
+
+
+def products_run(tokens, topk, held, hidden, width, dtype):
+    """``(grouped products, those of them the kernel runs)`` of ONE block
+    call over `tokens` tokens of `hidden` values with experts `width`
+    wide, from the rules the block itself follows (`products_form`,
+    `grouped_matmul.is_eligible`): constants of the traced program, for
+    the model that counts them."""
+    if products_form(tokens, topk, held) != "grouped":
+        return 0, 0
+    rows = tokens * min(topk, held)
+    shapes = ((hidden, width), (hidden, width), (width, hidden))
+    return len(shapes), sum(_gm.is_eligible(rows, k, n, dtype)[0]
+                            for k, n in shapes)
+
+
 def masked_products(u, local, is_held, valid, weights, gate_w, up_w, down_w,
                     acc):
     """`acc` + every held expert over EVERY token, weighed by the choice
@@ -134,10 +172,10 @@ def grouped_products(u, local, is_held, valid, weights, gate_w, up_w, down_w,
     assignments gathered in expert order into a buffer of
     ``T * min(k, E)`` rows, which no routing overflows (a token chooses
     an expert at most once), each group multiplied by its own expert
-    (`jax.lax.ragged_dot`), weighed and added back to its token. The
-    rows past the last group cost their bytes, not their products, and
-    whatever the product leaves there is cut off behind it. Forward
-    only."""
+    (`product_kernel`: the tiled kernel or `jax.lax.ragged_dot`),
+    weighed and added back to its token. The rows past the last group
+    cost their bytes, not their products, and whatever the product
+    leaves there is cut off behind it. Forward only."""
     t, _ = u.shape
     held, topk = gate_w.shape[0], local.shape[1]
     rows = t * min(topk, held)
@@ -151,8 +189,12 @@ def grouped_products(u, local, is_held, valid, weights, gate_w, up_w, down_w,
     token = flat // topk
 
     def product(a, w):
-        return jnp.where(mine, jax.lax.ragged_dot(
-            a, w, load, preferred_element_type=jnp.float32), 0.0)
+        if product_kernel(rows, *w.shape[1:], a.dtype) == "pallas":
+            y = _gm.grouped_matmul(a, w, load)
+        else:
+            y = jax.lax.ragged_dot(a, w, load,
+                                   preferred_element_type=jnp.float32)
+        return jnp.where(mine, y, 0.0)
 
     with jax.named_scope("grouped_experts"):
         x = jnp.take(u, token, axis=0)                       # [rows, d]

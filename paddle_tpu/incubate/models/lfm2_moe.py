@@ -45,7 +45,7 @@ import jax.numpy as jnp
 from ...nn.layer_base import Layer
 from ...framework.core import Tensor, Parameter
 from ...incubate.distributed.models.moe.held_experts import (
-    held_expert_block, COUNTERS)
+    held_expert_block, products_run, COUNTERS)
 from .mla import rms as _rms
 
 __all__ = ["Lfm2MoeConfig", "Lfm2MoeForCausalLM"]
@@ -187,8 +187,11 @@ class Lfm2MoeForCausalLM(Layer):
 
     # `LLMEngine` passes this model's weights to its programs as arguments
     serve_weights_as_arguments = True
-    # what a forward through a cache leaves in `pop_serve_counters()`
-    serve_counter_names = COUNTERS
+    # what a forward through a cache leaves in `pop_serve_counters()`: the
+    # expert blocks' counters, then the grouped products the forward ran
+    # and those of them the tiled kernel ran (constants of the program:
+    # `held_experts.products_run`)
+    serve_counter_names = COUNTERS + ("products", "kernel_products")
 
     def __init__(self, config: Lfm2MoeConfig, weights=None):
         super().__init__()
@@ -234,8 +237,8 @@ class Lfm2MoeForCausalLM(Layer):
             state_layers=kinds.count(CONV), state_shape=cfg.conv_state)
 
     def pop_serve_counters(self):
-        """The expert blocks' counters of the forward just traced, summed
-        over the layers (int32 [len(serve_counter_names)])."""
+        """The counters of the forward just traced, summed over the
+        layers (int32 [len(serve_counter_names)])."""
         counters, self._counters = self._counters, None
         return counters
 
@@ -393,7 +396,8 @@ class Lfm2MoeForCausalLM(Layer):
         view = caches[0] if paged else None
         n_attn = cfg.layer_types.count(ATTENTION)
         pairs, states = [], []
-        counters = 0
+        counters = jnp.zeros(len(COUNTERS), jnp.int32)
+        products = np.zeros(2, np.int32)
         first, held = cfg.held
         for i, kind in enumerate(cfg.layer_types):
             p = f"model.layers.{i}."
@@ -439,13 +443,16 @@ class Lfm2MoeForCausalLM(Layer):
                     normalise=cfg.norm_topk_prob,
                     epsilon=cfg.router_epsilon)
             counters = counters + counted
+            products += products_run(
+                b * t, cfg.num_experts_per_tok, held, cfg.hidden_size,
+                cfg.moe_intermediate_size, u.dtype)
             x = x + m.reshape(b, t, -1).astype(x.dtype)
         x = _rms(x, self._w("model.embedding_norm.weight"), cfg.norm_eps)
         with jax.named_scope("lm_head"):
             # the head is the embedding's own matrix
             logits = Tensor(jnp.einsum(
                 "btd,vd->btv", x, self._w("model.embed_tokens.weight")))
-        self._counters = counters
+        self._counters = jnp.concatenate([counters, jnp.asarray(products)])
         if caches is None:
             return logits
         return logits, ([view] if paged else pairs + states)

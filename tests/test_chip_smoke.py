@@ -202,6 +202,27 @@ class TestOneChipRehearsal:
             assert r["decode_compiles"] == 1
         assert recs[-1]["worst_logit_gap"] <= recs[-1]["logit_gap_slack"]
 
+    def test_grouped_matmul_leg(self, monkeypatch):
+        """The leg at a tiny size, the kernel in the interpreter: uneven
+        groups, an idle expert, a poisoned tail. A kernel that lets the
+        tail into a live row fails it."""
+        from paddle_tpu.kernels.pallas import grouped_matmul as gm
+        rec = chip_smoke.grouped_matmul_phase(128, 128, 256, 8, seed=0,
+                                              interpret=True)
+        assert rec["phase"] == "grouped_matmul"
+        assert rec["live_rows"] == 112 and rec["groups"] == 8
+        assert 0.0 <= rec["max_abs_gap"] <= 1e-4 * rec["max_abs"]
+        assert not any("ms" in key or "seconds" in key for key in rec)
+        kernel = gm.grouped_matmul
+        # a kernel whose rows are not kept apart: the last row in every one
+        monkeypatch.setattr(
+            gm, "grouped_matmul", lambda a, w, load, interpret=False:
+            kernel(a, w, load, interpret=interpret)
+            + a[-1:, :1].astype("float32"))
+        with pytest.raises(chip_smoke.SmokeFailure, match="poisoned"):
+            chip_smoke.grouped_matmul_phase(128, 128, 256, 8, seed=0,
+                                            interpret=True)
+
     def test_demoted_kernel_fails_the_serve_phase(self):
         """Off the chip (and not steered) `pallas` demotes to `blockwise`
         with a kernel.fallback event: legitimate in production, a failure

@@ -32,6 +32,7 @@ from benchmark.reference import lfm2_moe as ref  # noqa: E402
 from paddle_tpu.incubate.distributed.models.moe import (  # noqa: E402
     held_experts)
 from paddle_tpu.incubate.models import lfm2_moe as lfm  # noqa: E402
+from paddle_tpu.kernels.pallas import grouped_matmul as gm  # noqa: E402
 from paddle_tpu.kernels.pallas import paged_attention as pa  # noqa: E402
 from paddle_tpu.nn.functional import attention as fattn  # noqa: E402
 from paddle_tpu.serving import LLMEngine  # noqa: E402
@@ -315,7 +316,27 @@ def block_inputs(weights, n=24, seed=11):
         weights[p + f"experts.{leaf}.weight"] for leaf in ("w1", "w3", "w2")]
 
 
+KERNEL = gm.grouped_matmul
+
+
+def through_the_kernel(monkeypatch):
+    """Every grouped product of whatever shape through the tiled kernel,
+    in the interpreter: `grouped_matmul.is_eligible` is what the block's
+    choice AND the model's count both ask."""
+    calls = []
+    monkeypatch.setattr(gm, "is_eligible", lambda *shape: (True, None))
+    monkeypatch.setattr(
+        gm, "grouped_matmul", lambda a, w, load: calls.append(a.shape)
+        or KERNEL(a, w, load, interpret=True))
+    return calls
+
+
 def block(u, router, bias, experts, form, first=0, valid=None, monkeypatch=None):
+    """`form`: "masked", "grouped" (the library's product, as the CPU
+    resolves) or "kernel" (grouped, by the tiled kernel interpreted)."""
+    if form == "kernel":
+        form = "grouped"
+        through_the_kernel(monkeypatch)
     monkeypatch.setattr(held_experts, "products_form",
                         lambda tokens, topk, held: form)
     return highest(
@@ -325,12 +346,15 @@ def block(u, router, bias, experts, form, first=0, valid=None, monkeypatch=None)
         valid=valid, scoring="sigmoid", normalise=True, epsilon=1e-6)
 
 
-def test_the_grouped_and_the_masked_products_agree(weights, monkeypatch):
+@pytest.mark.parametrize("product", ["grouped", "kernel"],
+                         ids=["ragged_dot", "tiled_kernel"])
+def test_the_grouped_and_the_masked_products_agree(weights, monkeypatch,
+                                                   product):
     u, router, bias, experts = block_inputs(weights)
     valid = jnp.arange(24) < 19             # a bucket's padding
     masked, c_m = block(u, router, bias, experts, "masked", valid=valid,
                         monkeypatch=monkeypatch)
-    grouped, c_g = block(u, router, bias, experts, "grouped", valid=valid,
+    grouped, c_g = block(u, router, bias, experts, product, valid=valid,
                          monkeypatch=monkeypatch)
     close(grouped[:19], masked[:19])
     assert float(jnp.max(jnp.abs(grouped[19:]))) == 0.0
@@ -357,7 +381,7 @@ def test_the_form_follows_the_calls_shape():
             for t in (128, 256, 512)} == {"masked"}
 
 
-@pytest.mark.parametrize("form", ["grouped", "masked"])
+@pytest.mark.parametrize("form", ["grouped", "masked", "kernel"])
 def test_four_shares_of_the_experts_add_up_to_the_block_that_holds_all(
         weights, form, monkeypatch):
     """The test that ties a share to the model: shares of 4 experts
@@ -377,6 +401,66 @@ def test_four_shares_of_the_experts_add_up_to_the_block_that_holds_all(
         assert int(c[0]) == int(c[5]) and int(c[0]) + int(c[2]) == 24 * 4
     close(total, whole)
     assert held == int(counters[0]) == 24 * 4
+
+
+def test_a_kernel_product_skips_what_the_padding_leaves_past_the_groups(
+        weights, monkeypatch):
+    """The kernel leaves in the rows past the last group what the memory
+    held; the block cuts them off behind every product: a POISONED
+    padding token (its rows sort past the groups) changes no valid
+    token's result, and its own is zero."""
+    u, router, bias, experts = block_inputs(weights)
+    valid = jnp.arange(24) < 7
+    clean, _ = block(u, router, bias, experts, "kernel", valid=valid,
+                     monkeypatch=monkeypatch)
+    dirty, _ = block(u.at[7:].set(jnp.nan), router, bias, experts, "kernel",
+                     valid=valid, monkeypatch=monkeypatch)
+    assert np.array_equal(np.asarray(clean[:7]), np.asarray(dirty[:7]))
+    assert float(jnp.max(jnp.abs(clean[7:]))) == 0.0
+
+
+def test_the_products_and_the_kernels_are_counted_over_layers_and_calls(
+        model, monkeypatch):
+    """`products`: three a block call, summed over the expert layers and,
+    in `stats()`, over the calls; `kernel_products`: those of them the
+    tiled kernel ran: none on the CPU, all of them where the kernel takes
+    the shapes (here: told so, the kernel interpreted). The count and the
+    block's choice ask the same function."""
+    names = lfm.Lfm2MoeForCausalLM.serve_counter_names
+    assert names[:len(held_experts.COUNTERS)] == held_experts.COUNTERS
+    assert names[-2:] == ("products", "kernel_products")
+    layers = FILE["num_hidden_layers"] - FILE["num_dense_layers"]
+    ids = jnp.asarray([prompt_of(9)], jnp.int32)
+
+    def counted():
+        highest(model, ids)
+        return dict(zip(names, np.asarray(model.pop_serve_counters())))
+    got = counted()
+    assert (got["products"], got["kernel_products"]) == (3 * layers, 0)
+    assert got["routed_held"] == 9 * FILE["num_experts_per_tok"] * layers
+
+    def served(engine):
+        reqs = [engine.add_request(prompt_of(n), max_new_tokens=4)
+                for n in (13, 5)]
+        highest(engine.run)
+        st = engine.stats()
+        assert all(len(r.generated) == 4 for r in reqs)
+        for phase in ("decode", "prefill"):
+            assert st[phase + "_products"] \
+                == 3 * layers * st[phase + "_counted"] > 0
+        return st, [list(r.generated) for r in reqs]
+    st, tokens = served(LLMEngine(model, max_batch_size=2, block_size=4,
+                                  max_context=48))
+    assert st["decode_kernel_products"] == st["prefill_kernel_products"] == 0
+    calls = through_the_kernel(monkeypatch)
+    got = counted()
+    assert got["kernel_products"] == got["products"] == 3 * layers
+    assert len(calls) == 3 * layers
+    st, through = served(LLMEngine(model, max_batch_size=2, block_size=4,
+                                   max_context=48))
+    assert st["decode_kernel_products"] == st["decode_products"]
+    assert st["prefill_kernel_products"] == st["prefill_products"]
+    assert through == tokens
 
 
 # -- (e) what the engine refuses ----------------------------------------------

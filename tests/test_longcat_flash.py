@@ -594,3 +594,46 @@ def test_three_widths_of_the_latent_loop_give_what_the_whole_table_gives():
         by_hand += min(w for w in widths if w >= need) * chunk
     assert streamed == by_hand < slots * table
     assert held == int((lens // bs + 1).sum())
+
+
+def test_the_programs_lower_the_same_with_the_grouped_kernel_unimportable(
+        model, monkeypatch):
+    """16 held of top 12 resolve to the masked products, so nothing of the
+    grouped form, its tiled kernel (`kernels/pallas/grouped_matmul.py`)
+    or the kernel's eligibility reaches this model's programs: every
+    prefill and decode program lowers to the same StableHLO with all three
+    out of reach (any use raises). Against the parent's bytes:
+    `benchmark/proof/pr41_stablehlo.txt`."""
+
+    def lowered():
+        engine = LLMEngine(model, max_batch_size=4, block_size=4,
+                           max_context=48)
+        seen, real = [], engine._call_program
+
+        def spy(name, fn, args, first):
+            if first:
+                seen.append((name, fn.lower(*args).as_text()))
+            return real(name, fn, args, first)
+        monkeypatch.setattr(engine, "_call_program", spy)
+        rng = np.random.default_rng(0)
+        engine.generate([rng.integers(0, FILE["vocab_size"], n).tolist()
+                         for n in (5, 9, 13)], max_new_tokens=4)
+        return seen
+
+    class Unimportable:
+        def __getattr__(self, name):
+            raise ImportError(f"grouped_matmul.{name} reached")
+
+    def unreachable(*args, **kw):
+        raise AssertionError("the grouped products reached")
+
+    with_kernel = lowered()
+    monkeypatch.setattr(held_experts, "_gm", Unimportable())
+    monkeypatch.setattr(held_experts, "grouped_products", unreachable)
+    monkeypatch.setattr(held_experts, "product_kernel", unreachable)
+    without = lowered()
+    names = [name for name, _ in with_kernel]
+    assert names.count("engine.decode.dispatch") == 1
+    assert names.count("engine.prefill.dispatch") >= 2   # two buckets
+    assert with_kernel == without
+    assert not any("ragged" in text for _, text in with_kernel)

@@ -671,3 +671,66 @@ def test_the_served_expert_block_with_every_expert_held(v5e, tokens):
     rows = tokens * 4
     assert compiled.memory_analysis().temp_size_in_bytes \
         < 16 * rows * d * 4 + (64 << 20)
+
+
+@pytest.mark.parametrize("rows,k,n", [(512, 2048, 1792), (8192, 1792, 2048)],
+                         ids=["a_launchs_gate", "a_2048_buckets_down"])
+def test_the_grouped_matmul_kernel_at_the_cells_extremes(v5e, rows, k, n):
+    """The tiled grouped matmul at the two ends of what
+    `serve_lfm2_rag_backlog` hands it, by the tiles its shape rule picks,
+    under the framework's x64 mode (every scalar of the kernel 32-bit):
+    the instruction carries the name the benchmark's readers match."""
+    from paddle_tpu.kernels.pallas import grouped_matmul as gm
+    text = compile_for(v5e, gm.grouped_matmul, ((rows, k), jnp.bfloat16),
+                       ((32, k, n), jnp.bfloat16), ((32,), jnp.int32))
+    assert len(re.findall(r"^\s*(?:ROOT )?%\S*ragged\S* = ", text,
+                          re.M)) == 1
+    assert "%ragged_expert_matmul" in text
+
+
+def test_grouped_matmul_eligibility_is_what_the_compiler_accepts(
+        v5e, monkeypatch):
+    """What `is_eligible` lets through on a TPU compiles, at the row
+    counts of every program of the cell and at a tile's least; what it
+    refuses for its shape is refused by the compiler or never asked."""
+    from paddle_tpu.kernels.pallas import grouped_matmul as gm
+    monkeypatch.setattr(gm, "_on_tpu", lambda: True)
+    for rows, k, n in [(16, 128, 128), (1024, 2048, 1792),
+                       (2048, 1792, 2048), (4096, 2048, 1792),
+                       (1536, 6144, 2048)]:
+        assert gm.is_eligible(rows, k, n) == (True, None)
+        compile_for(v5e, gm.grouped_matmul, ((rows, k), jnp.bfloat16),
+                    ((16, k, n), jnp.bfloat16), ((16,), jnp.int32))
+    assert gm.is_eligible(512, 2048, 1800)[0] is False
+    assert gm.is_eligible(520, 2048, 1792)[0] is False
+
+
+@pytest.mark.parametrize("tokens", [128, 2048],
+                         ids=["decode_launch", "prefill_2048"])
+def test_the_served_expert_block_through_the_kernel(v5e, monkeypatch,
+                                                    tokens):
+    """On a TPU (here: told so) the block's three grouped products are
+    three calls of the kernel and nothing of the library's product is
+    left: exactly three instructions carry `ragged` in their names, which
+    is what `lfm2.expert_products_roofline` counts a block call by."""
+    from paddle_tpu.incubate.distributed.models.moe import held_experts
+    from paddle_tpu.kernels.pallas import grouped_matmul as gm
+    monkeypatch.setattr(gm, "_on_tpu", lambda: True)
+    bf16, d, f, experts = jnp.bfloat16, 2048, 1792, 32
+    assert held_experts.products_run(tokens, 4, experts, d, f, bf16) == (3, 3)
+
+    def block(u, router, bias, w1, w3, w2, valid):
+        return held_experts.held_expert_block(
+            u, router, bias, w1, w3, w2, topk=4, real_experts=experts,
+            scaling=1.0, valid=valid, scoring="sigmoid", normalise=True,
+            epsilon=1e-6)
+
+    text = compile_for(
+        v5e, block, ((tokens, d), bf16), ((d, experts), bf16),
+        ((experts,), jnp.float32), ((experts, d, f), bf16),
+        ((experts, d, f), bf16), ((experts, f, d), bf16),
+        ((tokens,), jnp.bool_))
+    named = re.findall(r"^\s*(?:ROOT )?%(\S*ragged\S*) = ", text, re.M)
+    assert len(named) == 3
+    assert all(name.startswith("ragged_expert_matmul") for name in named)
+    assert "ragged-dot" not in text
