@@ -44,8 +44,8 @@ from .....kernels.pallas import grouped_matmul as _gm
 from .....profiler.events import EVENTS as _EVENTS
 
 __all__ = ["route", "held_expert_block", "masked_products",
-           "grouped_products", "products_form", "product_kernel",
-           "products_run", "COUNTERS"]
+           "grouped_products", "products_form", "buffer_rows",
+           "product_kernel", "products_run", "COUNTERS"]
 
 # what `held_expert_block` counts of a call's choices, in this order
 COUNTERS = ("routed_held", "routed_identity", "routed_elsewhere",
@@ -95,14 +95,39 @@ def _sorted_assignments(chosen, first_held, held, rows):
     return jnp.argsort(key, stable=True)[:rows].astype(jnp.int32)
 
 
+# a block that holds FEWER than twice the experts a token chooses takes the
+# grouped form from this many tokens a call (the chip's micro-calls at 8
+# held of top 8, 4096 -> 2048: masked 0.85 / 2.68 / 5.19 / 19.6 ms at 128 /
+# 1,024 / 2,048 / 8,192 tokens against grouped 0.93 / 2.94 / 3.39 / 5.55;
+# `PERF.md` section 4)
+_GROUPED_FROM_TOKENS = 2048
+# the grouped form's buffer holds at most this many sorted rows (what the
+# largest accepted call, a 2,048-token bucket at top 4, already holds): a
+# call whose routing could fill more runs the sorted order a buffer at a time
+_ROWS_MAX = 8192
+
+
 def products_form(tokens, topk, held):
     """Which form a served call's products take, from its static shape:
     GROUPED where the block holds at least twice the experts a token
     chooses (the masked form then multiplies ``held / topk`` >= 2 times
     the rows any routing holds, and unrolls `held` conditionals a layer
-    into every program), else MASKED. The chip's readings behind the
-    rule, by `tokens` too: `PERF.md` section 4."""
-    return "grouped" if held >= 2 * topk else "masked"
+    into every program); where it holds fewer (a share of many experts:
+    8 held of top 8 of 256, 16 held of top 12 of 768) MASKED for a call
+    of under `_GROUPED_FROM_TOKENS` tokens, which is bound by the experts'
+    bytes in either form, and GROUPED from there on, where the masked
+    form multiplies every held expert by every token and the routing
+    sends a held expert a few of a hundred. The chip's readings behind
+    the rule, by `tokens`: `PERF.md` section 4."""
+    if held >= 2 * topk or tokens >= _GROUPED_FROM_TOKENS:
+        return "grouped"
+    return "masked"
+
+
+def buffer_rows(tokens, topk, held):
+    """The grouped form's buffer, in sorted rows: what any routing can
+    hold (a token chooses an expert at most once), at most `_ROWS_MAX`."""
+    return min(tokens * min(topk, held), _ROWS_MAX)
 
 
 def product_kernel(rows, k, n, dtype):
@@ -130,7 +155,7 @@ def products_run(tokens, topk, held, hidden, width, dtype):
     the model that counts them."""
     if products_form(tokens, topk, held) != "grouped":
         return 0, 0
-    rows = tokens * min(topk, held)
+    rows = buffer_rows(tokens, topk, held)
     shapes = ((hidden, width), (hidden, width), (width, hidden))
     return len(shapes), sum(_gm.is_eligible(rows, k, n, dtype)[0]
                             for k, n in shapes)
@@ -175,35 +200,69 @@ def grouped_products(u, local, is_held, valid, weights, gate_w, up_w, down_w,
     (`product_kernel`: the tiled kernel or `jax.lax.ragged_dot`),
     weighed and added back to its token. The rows past the last group
     cost their bytes, not their products, and whatever the product
-    leaves there is cut off behind it. Forward only."""
+    leaves there is cut off behind it. Forward only.
+
+    A call whose routing could hold more than `_ROWS_MAX` rows (8 held of
+    top 8 over 8,192 tokens: 65,536, 2.7 GB of float32 temporaries) keeps
+    the buffer at `_ROWS_MAX` and runs the SORTED ORDER a buffer at a
+    time, under a loop whose trip count is the routing's
+    (``ceil(held assignments / _ROWS_MAX)``, one where a held expert sees
+    its share of the tokens): no token is dropped at any load and the
+    temporaries are bounded by the buffer, not by ``T * k``."""
     t, _ = u.shape
     held, topk = gate_w.shape[0], local.shape[1]
-    rows = t * min(topk, held)
+    most = t * min(topk, held)
+    rows = buffer_rows(t, topk, held)
     # an assignment not taken lies outside every group
     mine_id = jnp.where(is_held & valid[:, None], local, -1)
-    flat = _sorted_assignments(mine_id, 0, held, rows)
+    flat = _sorted_assignments(mine_id, 0, held, most)
     load = jnp.sum(mine_id.reshape(-1)[:, None] == jnp.arange(
         held, dtype=mine_id.dtype)[None], axis=0, dtype=jnp.int32)
     total = jnp.sum(load)
-    mine = (jnp.arange(rows, dtype=jnp.int32) < total)[:, None]
-    token = flat // topk
 
-    def product(a, w):
-        if product_kernel(rows, *w.shape[1:], a.dtype) == "pallas":
-            y = _gm.grouped_matmul(a, w, load)
-        else:
-            y = jax.lax.ragged_dot(a, w, load,
-                                   preferred_element_type=jnp.float32)
-        return jnp.where(mine, y, 0.0)
+    def buffer(acc, flat, load, mine):
+        """`acc` + one buffer of sorted rows `flat` in groups of `load`,
+        of which `mine` ``[rows, 1]`` are some token's."""
+        token = flat // topk
 
-    with jax.named_scope("grouped_experts"):
-        x = jnp.take(u, token, axis=0)                       # [rows, d]
-        hidden = (jax.nn.silu(product(x, gate_w))
-                  * product(x, up_w)).astype(u.dtype)
-        y = product(hidden, down_w)                          # [rows, d] f32
-    w_row = jnp.where(mine[:, 0], jnp.take(weights.reshape(-1), flat), 0.0)
-    return (acc.at[token].add(y * w_row[:, None]), load,
-            jnp.sum(mine).astype(jnp.int32))
+        def product(a, w):
+            if product_kernel(rows, *w.shape[1:], a.dtype) == "pallas":
+                y = _gm.grouped_matmul(a, w, load)
+            else:
+                y = jax.lax.ragged_dot(a, w, load,
+                                       preferred_element_type=jnp.float32)
+            return jnp.where(mine, y, 0.0)
+
+        with jax.named_scope("grouped_experts"):
+            x = jnp.take(u, token, axis=0)                   # [rows, d]
+            hidden = (jax.nn.silu(product(x, gate_w))
+                      * product(x, up_w)).astype(u.dtype)
+            y = product(hidden, down_w)                      # [rows, d] f32
+        w_row = jnp.where(mine[:, 0], jnp.take(weights.reshape(-1), flat),
+                          0.0)
+        return acc.at[token].add(y * w_row[:, None])
+
+    if rows == most:
+        mine = (jnp.arange(rows, dtype=jnp.int32) < total)[:, None]
+        return (buffer(acc, flat, load, mine), load,
+                jnp.sum(mine).astype(jnp.int32))
+    # buffer i holds the sorted rows i * rows .. (i + 1) * rows - 1: of each
+    # group the part that lies there
+    flat = jnp.pad(flat, (0, -most % rows))
+    ends = jnp.cumsum(load, dtype=jnp.int32)
+    rows32 = jnp.int32(rows)
+
+    def one(i, acc):
+        lo = i * rows32
+        here = jnp.clip(ends - lo, 0, rows32) \
+            - jnp.clip(ends - load - lo, 0, rows32)
+        mine = (lo + jnp.arange(rows, dtype=jnp.int32) < total)[:, None]
+        return buffer(acc, jax.lax.dynamic_slice_in_dim(flat, lo, rows),
+                      here.astype(jnp.int32), mine)
+
+    trips = (total + (rows32 - 1)) // rows32
+    return (jax.lax.fori_loop(jnp.int32(0), trips.astype(jnp.int32), one,
+                              acc), load, total.astype(jnp.int32))
 
 
 def held_expert_block(u, router_w, bias, gate_w, up_w, down_w, *, topk,
@@ -223,8 +282,9 @@ def held_expert_block(u, router_w, bias, gate_w, up_w, down_w, *, topk,
     held = gate_w.shape[0]
     if valid is None:
         valid = jnp.ones((t,), bool)
-    chosen, weights = route(u, router_w, bias, topk, scaling, scoring,
-                            normalise, epsilon)
+    with jax.named_scope("router"):
+        chosen, weights = route(u, router_w, bias, topk, scaling, scoring,
+                                normalise, epsilon)
     is_identity = chosen >= real_experts
     local = chosen - first_held
     is_held = (local >= 0) & (local < held) & ~is_identity

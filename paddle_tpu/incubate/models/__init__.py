@@ -16,3 +16,7 @@ from . import lfm2_moe  # noqa: F401
 from .lfm2_moe import (  # noqa: F401
     Lfm2MoeConfig, Lfm2MoeForCausalLM,
 )
+from . import mimo_v2_flash  # noqa: F401
+from .mimo_v2_flash import (  # noqa: F401
+    MiMoV2FlashConfig, MiMoV2FlashForCausalLM,
+)

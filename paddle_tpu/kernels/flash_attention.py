@@ -420,3 +420,142 @@ def _vmem(rows, d, dv, with_stats=False):
     if held <= _VMEM_DEFAULT_FITS:
         return None
     return pltpu.CompilerParams(vmem_limit_bytes=min(4 * held, 100 << 20))
+
+
+# -- a causal BAND (a window layer's prefill), forward only ------------------
+# Below the kernels above, as the head widths are: their lowered bytes carry
+# their lines' numbers (this section's import too).
+
+import numpy as np  # noqa: E402
+
+__all__ += ["flash_band_attention_bnhd", "is_band_eligible"]
+
+
+def _band_block(window):
+    """The block both the queries and the keys go by: whole 128-row tiles
+    that hold ``window - 1`` positions, so that a block of queries sees
+    its own block of keys and the one before it and no other."""
+    return max(128, -(-(int(window) - 1) // 128) * 128)
+
+
+def is_band_eligible(q, k, v, window):
+    """Can `flash_band_attention_bnhd` run compiled here: a TPU, the flag,
+    self-attention of whole blocks (`_band_block`) and at least
+    `FLASH_MIN_SEQ` positions, head widths the kernels take, whole groups
+    of query heads a key/value head."""
+    if not _HAS_PALLAS or not _on_tpu() or q.ndim != 4:
+        return False
+    n, h, d = q.shape[1:]
+    if k.shape[1] != n or n % _band_block(window) or n < FLASH_MIN_SEQ:
+        return False
+    if k.shape[-1] != d or (d, v.shape[-1]) not in _WIDTHS \
+            or h % k.shape[2]:
+        return False
+    from ..framework.flags import FLAGS
+    return bool(FLAGS.use_flash_attention)
+
+
+def _band_kernel(q_ref, kp_ref, kc_ref, vp_ref, vc_ref, *rest, scale, block,
+                 window):
+    """One block of queries of one head against its own block of keys
+    (`kc`, `vc`) and the block before it (`kp`, `vp`): every key a query
+    of a band of `window` sees lies in those two, and no other block is
+    copied or multiplied. Scores are exact products of the operands as
+    they are (bf16: one pass) accumulated in float32 and scaled after;
+    the softmax and p . v are float32. With a sink (`rest` then starts
+    with its [1, 1] block) a head's learned score joins the denominator
+    and adds no value."""
+    sink_ref = rest[0] if len(rest) == 2 else None
+    o_ref = rest[-1]
+    qi = pl.program_id(1)
+    q = q_ref[0]
+    dims = (((1,), (1,)), ((), ()))
+    row = jax.lax.broadcasted_iota(jnp.int32, (block, block), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (block, block), 1)
+    neg = jnp.float32(_NEG_INF)
+
+    def scores(k_ref, keep):
+        s = jax.lax.dot_general(q, k_ref[0], dims,
+                                preferred_element_type=jnp.float32)
+        return jnp.where(keep, s * jnp.float32(scale), neg)
+
+    # the block before: key c lies block - c + r positions behind query r;
+    # the first block of queries has none before it (its index map reads
+    # block 0 again)
+    keep_p = (row - col + jnp.int32(block) < jnp.int32(window)) \
+        & (qi > jnp.int32(0))
+    keep_c = (col <= row) & (row - col < jnp.int32(window))
+    s_p, s_c = scores(kp_ref, keep_p), scores(kc_ref, keep_c)
+    m = jnp.maximum(jnp.max(s_p, axis=1, keepdims=True),
+                    jnp.max(s_c, axis=1, keepdims=True))
+    if sink_ref is not None:
+        m = jnp.maximum(m, sink_ref[0])
+    p_p = jnp.where(keep_p, jnp.exp(s_p - m), jnp.float32(0.0))
+    p_c = jnp.where(keep_c, jnp.exp(s_c - m), jnp.float32(0.0))
+    l = jnp.sum(p_p, axis=1, keepdims=True) \
+        + jnp.sum(p_c, axis=1, keepdims=True)
+    if sink_ref is not None:
+        l = l + jnp.exp(sink_ref[0] - m)
+    pv = (((1,), (0,)), ((), ()))
+    o = jax.lax.dot_general(p_p, vp_ref[0].astype(jnp.float32), pv,
+                            preferred_element_type=jnp.float32) \
+        + jax.lax.dot_general(p_c, vc_ref[0].astype(jnp.float32), pv,
+                              preferred_element_type=jnp.float32)
+    o_ref[0] = (o / jnp.maximum(l, jnp.float32(1e-30))).astype(o_ref.dtype)
+
+
+def flash_band_attention_bnhd(q, k, v, window, sink=None, scale=None,
+                              interpret=False):
+    """Causal attention inside a band: query i attends key j with
+    ``i - window < j <= i``. q ``[B, N, H, D]``; k ``[B, N, KH, D]`` and
+    v ``[B, N, KH, Dv]`` with KH dividing H (query head i reads key/value
+    head ``i // (H // KH)`` through the index map: no head is repeated in
+    memory); `sink` ``[H]`` float32 or None. Forward only (serving). A
+    grid cell per (batch * head, block of queries) copies TWO blocks of
+    keys and values, whatever N: the blocks outside the band are skipped,
+    not masked. Returns ``[B, N, H, Dv]``."""
+    b, n, h, d = q.shape
+    kh, dv = k.shape[2], v.shape[-1]
+    group = h // kh
+    block = _band_block(window)
+    if scale is None:
+        scale = 1.0 / math.sqrt(d)
+    qf = jnp.swapaxes(q, 1, 2).reshape(b * h, n, d)
+    kf = jnp.swapaxes(k, 1, 2).reshape(b * kh, n, d)
+    vf = jnp.swapaxes(v, 1, 2).reshape(b * kh, n, dv)
+    zero = _SHARED_ZERO
+    # (numpy scalars: an index map may capture no array)
+    one, grp = np.int32(1), np.int32(group)
+
+    def own(bh, qi):
+        return (bh, qi, zero)
+
+    # the key/value head of query head bh: batch-major on both sides, so
+    # bh // group is (batch, head // group) folded
+    def before(bh, qi):
+        return (jax.lax.div(bh, grp), jnp.maximum(qi - one, zero), zero)
+
+    def current(bh, qi):
+        return (jax.lax.div(bh, grp), qi, zero)
+
+    in_specs = [pl.BlockSpec((1, block, d), own),
+                pl.BlockSpec((1, block, d), before),
+                pl.BlockSpec((1, block, d), current),
+                pl.BlockSpec((1, block, dv), before),
+                pl.BlockSpec((1, block, dv), current)]
+    operands = [qf, kf, kf, vf, vf]
+    if sink is not None:
+        in_specs.append(pl.BlockSpec((1, 1, 1),
+                                     lambda bh, qi: (bh, zero, zero)))
+        operands.append(jnp.tile(sink.astype(jnp.float32), b)[:, None, None])
+    out = pl.pallas_call(
+        functools.partial(_band_kernel, scale=scale, block=block,
+                          window=int(window)),
+        grid=(b * h, n // block),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((1, block, dv), own),
+        out_shape=jax.ShapeDtypeStruct((b * h, n, dv), q.dtype),
+        interpret=interpret,
+        name="flash_band_attention",
+    )(*operands)
+    return out.reshape(b, h, n, dv).swapaxes(1, 2)

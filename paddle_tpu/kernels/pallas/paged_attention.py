@@ -83,7 +83,8 @@ from ...quantization.kv_cache import QMAX as _QMAX, dequantize as _dequant
 
 __all__ = ["blockwise_paged_attention", "blockwise_latent_attention",
            "blockwise_streamed_entries", "pallas_paged_attention",
-           "pallas_latent_attention", "pallas_copied_pages", "is_eligible"]
+           "pallas_latent_attention", "pallas_banded_attention",
+           "pallas_copied_pages", "is_eligible"]
 
 _NEG_INF = -1e30
 
@@ -209,9 +210,13 @@ def blockwise_streamed_entries(lens, active, table_entries, block_size,
 
 
 def _blockwise_loop(q32, block_tables, lens, plan, block_size, value_width,
-                    chunk):
+                    chunk, starts=None, sink=None):
     """The loop both blockwise attentions share: online softmax over the
     chunks that hold tokens, at the widths `_step_widths` picks.
+    `starts` ``[S]`` int32 (a window's oldest position: positions before
+    it are masked) and `sink` ``[H]`` float32 (a learned score a head
+    that joins the softmax's denominator and adds no value: the
+    recurrence starts from max = sink, sum = 1) where the caller has them.
 
     q32: ``[S, H, .]`` float32 scaled queries; plan: `_blockwise_plan`'s;
     ``chunk(w, bids, q)`` reads the chunk whose block ids are `bids`
@@ -231,6 +236,8 @@ def _blockwise_loop(q32, block_tables, lens, plan, block_size, value_width,
     if len(widths) > 1:
         order = jnp.argsort(-lens, stable=True).astype(jnp.int32)
         q32, lens, tables = q32[order], lens[order], tables[order]
+        if starts is not None:
+            starts = starts[order]
     trips, which = _step_widths(lens, widths, t_chunk, n_chunks, jnp)
     # table entries past M (the last chunk's fill) read the null block;
     # their positions exceed every length, so the mask kills them.
@@ -248,6 +255,8 @@ def _blockwise_loop(q32, block_tables, lens, plan, block_size, value_width,
         scores, weigh = chunk(w, bids[:w], q32)
         pos = ci * t_chunk + offs
         valid = pos[None, :] <= lens[:w, None]          # [w, t]
+        if starts is not None:
+            valid = valid & (pos[None, :] >= starts[:w, None])
         scores = jnp.where(valid[:, None, :], scores,
                            jnp.float32(_NEG_INF))
         m_new = jnp.maximum(mx, jnp.max(scores, axis=-1))
@@ -271,6 +280,9 @@ def _blockwise_loop(q32, block_tables, lens, plan, block_size, value_width,
     acc0 = jnp.zeros((s, h, value_width), jnp.float32)
     m0 = jnp.full((s, h), _NEG_INF, jnp.float32)
     l0 = jnp.zeros((s, h), jnp.float32)
+    if sink is not None:
+        m0 = jnp.broadcast_to(sink.astype(jnp.float32)[None, :], (s, h))
+        l0 = jnp.ones((s, h), jnp.float32)
     # a traced bound: a while loop whose trip count the device reads
     acc, _, l = jax.lax.fori_loop(0, trips, step, (acc0, m0, l0))
     out = acc / jnp.maximum(l, 1e-30)[..., None]
@@ -284,7 +296,7 @@ def _blockwise_loop(q32, block_tables, lens, plan, block_size, value_width,
 
 def blockwise_paged_attention(q, k_pools, v_pools, layer, block_tables,
                               lens, block_size, k_scales=None, v_scales=None,
-                              chunk_blocks=None):
+                              chunk_blocks=None, starts=None, sink=None):
     """Online-softmax paged attention, one KV chunk at a time, over the
     chunks and the slots that hold tokens.
 
@@ -311,6 +323,11 @@ def blockwise_paged_attention(q, k_pools, v_pools, layer, block_tables,
     is what a loop over the whole table gives. Inside a step, positions
     past a slot's own length are still gathered and masked; an inactive
     slot reads the null block through one chunk.
+
+    A value may be narrower than its key (``v_pools`` rows of ``H*Dv``:
+    the result is ``[S, Hq, Dv]``); `starts` ``[S]`` and `sink` ``[Hq]``
+    are `_blockwise_loop`'s (a window's oldest position, a head's learned
+    sink).
     """
     s, hq, d = q.shape
     bs = int(block_size)
@@ -318,6 +335,7 @@ def blockwise_paged_attention(q, k_pools, v_pools, layer, block_tables,
     # the heads a row holds; `hq // h` queries read each (grouped queries:
     # query head i reads key/value head i // group)
     h = k_pools.shape[-1] // d
+    dv = v_pools.shape[-1] // h
     group = hq // h
     plan = _blockwise_plan(s, block_tables.shape[1], bs, h, d, chunk_blocks)
     chunk_blocks = plan[1]
@@ -335,7 +353,7 @@ def blockwise_paged_attention(q, k_pools, v_pools, layer, block_tables,
             kc = kc.astype(jnp.float32)
             vc = vc.astype(jnp.float32)
         kc = kc.reshape(w, t_chunk, h, d)
-        vc = vc.reshape(w, t_chunk, h, d)
+        vc = vc.reshape(w, t_chunk, h, dv)
         if group == 1:
             return (jnp.einsum("shd,sthd->sht", q[:w], kc),
                     lambda p: jnp.einsum("sht,sthd->shd", p, vc))
@@ -344,10 +362,10 @@ def blockwise_paged_attention(q, k_pools, v_pools, layer, block_tables,
                     w, hq, t_chunk),
                 lambda p: jnp.einsum(
                     "shgt,sthd->shgd", p.reshape(w, h, group, t_chunk),
-                    vc).reshape(w, hq, d))
+                    vc).reshape(w, hq, dv))
 
-    return _blockwise_loop(q32, block_tables, lens, plan, bs, d,
-                           chunk).astype(q.dtype)
+    return _blockwise_loop(q32, block_tables, lens, plan, bs, dv,
+                           chunk, starts, sink).astype(q.dtype)
 
 
 def blockwise_latent_attention(q, pool, layer, block_tables, lens,
@@ -876,3 +894,166 @@ def pallas_latent_attention(q, pool, layer, block_tables, lens, block_size,
             block_tables.astype(jnp.int32), lens.astype(jnp.int32),
             q, pool)
     return out[:, :h, :value_width]
+
+
+# ---------------------------------------------------------------------------
+# Pallas TPU kernel over rows whose key is wider than their value, with a
+# window's oldest position a slot and a learned sink a head
+# ---------------------------------------------------------------------------
+
+def _banded_decode_kernel(layer_ref, tab_ref, lens_ref, starts_ref, q_ref,
+                          *rest, block_size, pages, scale, has_sink):
+    """`_ragged_decode_kernel`'s plan (a slot a grid step, its held pages
+    copied a group at a time into one of two VMEM buffers a pool, the next
+    group in flight while this one is multiplied) with `per_head` queries a
+    key/value head, over K rows ``H*Dk`` and V rows ``H*Dv`` of their own
+    widths (two sets of the 0/1 constants, `_grouped_constants` at each
+    width). A position attends iff ``starts[slot] <= position <=
+    lens[slot]``; with `has_sink` a head's learned score joins the
+    denominator and adds no value: the recurrence starts from max = sink,
+    sum = 1."""
+    if has_sink:
+        sink_ref, rest = rest[0], rest[1:]
+    (tile_ref, own_k_ref, own_v_ref, pick_ref, k_hbm, v_hbm, o_ref, k_buf,
+     v_buf, sems, first_ref) = rest
+    s = pl.program_id(0)
+    n_slots = pl.num_programs(0)
+    # every constant a 32-bit one (Mosaic cannot legalize x64's)
+    zero, one = np.int32(0), np.int32(1)
+    bs, n_pages = np.int32(block_size), np.int32(pages)
+    hp = q_ref.shape[0]
+    t_group = n_pages * bs
+    nothing = np.float32(0.0)
+    layer = layer_ref[0]
+
+    held, start, wait = _page_copies(
+        tab_ref, lens_ref, layer, ((k_hbm, k_buf), (v_hbm, v_buf)), sems,
+        block_size, pages)
+
+    @pl.when(s == 0)
+    def _first_slot():
+        # rows a copy never reaches are multiplied by p == 0: they must
+        # be numbers, which fresh VMEM need not hold
+        k_buf[...] = jnp.zeros_like(k_buf)
+        v_buf[...] = jnp.zeros_like(v_buf)
+        first_ref[0] = zero
+        start(zero, zero, zero)
+
+    base = first_ref[0]
+    length, oldest = lens_ref[s], starts_ref[s]
+    groups = (held(s) + (n_pages - one)) // n_pages
+    # query head i repeated into every head's key lanes by a 0/1 product,
+    # of which it keeps the lanes of key/value head i // per_head
+    q = (_exact_dot(q_ref[...], tile_ref[...], ((1,), (0,)))
+         * own_k_ref[...]).astype(q_ref.dtype)
+    offs = jax.lax.broadcasted_iota(jnp.int32, (hp, t_group), 1)
+
+    def group(g, carry):
+        mx, l, acc = carry
+        cur = (base + g) & one
+        more = g + one < groups
+        nxt_slot = jnp.where(more, s, s + one)
+
+        @pl.when(nxt_slot < n_slots)
+        def _prefetch():
+            start(nxt_slot, jnp.where(more, g + one, zero), one - cur)
+
+        wait(s, g, cur)
+        k = k_buf[cur]                                  # [T, H*Dk]
+        v = v_buf[cur]                                  # [T, H*Dv]
+        scores = _exact_dot(q, k, ((1,), (1,))) * np.float32(scale)
+        pos = g * t_group + offs
+        valid = (pos <= length) & (pos >= oldest)       # [Hp, T]
+        scores = jnp.where(valid, scores, np.float32(_NEG_INF))
+        m_new = jnp.maximum(mx, jnp.max(scores, axis=1, keepdims=True))
+        p = jnp.where(valid, jnp.exp(scores - m_new), nothing)
+        alpha = jnp.exp(mx - m_new)
+        l = alpha * l + jnp.sum(p, axis=1, keepdims=True)
+        acc = acc * alpha + _weigh(p, v)                # [Hp, H*Dv]
+        return m_new, l, acc
+
+    if has_sink:
+        first = (sink_ref[...], jnp.ones((hp, 1), jnp.float32))
+    else:
+        first = (jnp.full((hp, 1), np.float32(_NEG_INF), jnp.float32),
+                 jnp.zeros((hp, 1), jnp.float32))
+    _, l, acc = jax.lax.fori_loop(
+        zero, groups, group,
+        first + (jnp.zeros((hp, v_buf.shape[-1]), jnp.float32),))
+    first_ref[0] = (base + groups) & one
+    # a query head's output is the Dv lanes of its key/value head in its
+    # own row: picked out by a 0/1 product (every bit kept)
+    out = acc / jnp.maximum(l, np.float32(1e-30)) * own_v_ref[...]
+    o_ref[...] = _weigh(out, pick_ref[...]).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("block_size", "interpret",
+                                             "group_pages", "name"))
+def pallas_banded_attention(q, k_pools, v_pools, layer, block_tables, lens,
+                            block_size, starts=None, sink=None,
+                            interpret=False, group_pages=None,
+                            name="banded_decode_attention"):
+    """`blockwise_paged_attention`'s contract with `starts` and `sink`, by
+    `pallas_paged_attention`'s plan, over fp pools whose K rows
+    (``H*Dk``) and V rows (``H*Dv``) have their own widths: q
+    ``[S, Hq, Dk]``, Hq a multiple of H; a position attends iff
+    ``starts[s] <= position <= lens[s]`` (`starts` None: from 0); `sink`
+    ``[Hq]`` (or None) joins each head's denominator. Returns
+    ``[S, Hq, Dv]`` in q's dtype. The kernel copies only `_slot_pages`
+    pages of a slot: over a window layer's ring table (nn/functional/
+    attention.py `paged_window_decode_attention`), whose first entry is
+    the block of the window's oldest position, that is the window's pages
+    and no more. The layer is an OPERAND of this jitted function: a
+    program lowers one kernel for all its layers of a kind, under `name`
+    (the device trace tells a model's kinds of layer apart by it)."""
+    s, h, d = q.shape
+    hd_k, hd_v = k_pools.shape[-1], v_pools.shape[-1]
+    kv_heads = hd_k // d
+    dv = hd_v // kv_heads
+    group = h // kv_heads
+    bs = int(block_size)
+    m = block_tables.shape[1]
+    pages = int(group_pages or _group_pages(m, bs, hd_k, k_pools.dtype))
+    hp = -(-h // 16) * 16                   # whole tiles of rows, bf16's
+    zero = _ZERO
+    if starts is None:
+        starts = jnp.zeros((s,), jnp.int32)
+
+    def heads_spec(width):
+        return pl.BlockSpec((None, hp, width),
+                            lambda si, *_: (si, zero, zero))
+
+    tile, own_k, _ = _grouped_constants(hp, kv_heads, d, group, q.dtype)
+    _, own_v, pick = _grouped_constants(hp, kv_heads, dv, group, q.dtype)
+    consts = (tile, own_k, own_v, pick)
+    if sink is not None:
+        consts = (jnp.pad(sink.astype(jnp.float32), (0, hp - h),
+                          constant_values=_NEG_INF)[:, None],) + consts
+    whole = [pl.BlockSpec(c.shape, lambda si, *_: (zero, zero))
+             for c in consts]
+    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(s,),
+        in_specs=[heads_spec(d)] + whole + [pool_spec, pool_spec],
+        out_specs=heads_spec(dv),
+        scratch_shapes=[pltpu.VMEM((2, pages * bs, hd_k), k_pools.dtype),
+                        pltpu.VMEM((2, pages * bs, hd_v), v_pools.dtype),
+                        pltpu.SemaphoreType.DMA((2, 2)),
+                        pltpu.SMEM((1,), jnp.int32)])
+    out = pl.pallas_call(
+        functools.partial(_banded_decode_kernel, block_size=bs, pages=pages,
+                          scale=1.0 / math.sqrt(d),
+                          has_sink=sink is not None),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((s, hp, dv), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+        name=name)(
+            jnp.asarray(layer, jnp.int32).reshape(1),
+            block_tables.astype(jnp.int32), lens.astype(jnp.int32),
+            starts.astype(jnp.int32),
+            jnp.pad(q, ((0, 0), (0, hp - h), (0, 0))), *consts,
+            k_pools, v_pools)
+    return out[:, :h]

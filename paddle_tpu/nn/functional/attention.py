@@ -446,8 +446,115 @@ def paged_latent_decode_attention(q, parts_new, pool, layer, block_tables,
     return out, pool
 
 
+def paged_banded_decode_attention(q, k_new, v_new, k_pools, v_pools, layer,
+                                  block_tables, seq_lens, active, block_size,
+                                  window=None, sink=None, kernel=None,
+                                  interpret=False,
+                                  name="banded_decode_attention"):
+    """`paged_decode_attention` for layers it does not take: a key wider
+    than its value (K rows ``H*Dk``, V rows ``H*Dv``), a learned `sink`
+    ``[Hq]`` in the softmax's denominator, and, given `window`, a WINDOW
+    layer over its ring pools (serving/cache.py `CacheSpec`'s rule).
+
+    q ``[S, 1, Hq, Dk]``, k_new ``[S, 1, H, Dk]``, v_new ``[S, 1, H, Dv]``,
+    Hq a multiple of H. `window` None: the pools are the paged pools and
+    `block_tables` the slots' tables, as there. `window` W: the pools are
+    the ring pools ``[L, 1 + S * ring, bs, .]`` and `block_tables` is not
+    read: the new token of an active slot is written at its position's
+    ring block (an inactive slot's to the null block), and the slot reads
+    a table of its OWN ring's blocks from the one that holds the window's
+    oldest position ``max(0, p - W + 1)`` to the newest's, positions
+    before the oldest masked: at most ``ring`` entries, and under
+    ``"pallas"`` exactly the pages the window lies in are copied.
+    `kernel`: ``"pallas"`` (`pallas_banded_attention`, under `name` in the
+    device trace), ``"blockwise"`` (the loop) or ``"reference"`` (a dense
+    gather of the whole table). Returns ``(out [S, 1, Hq, Dv], the two
+    written pools)``."""
+    s, _, hq, dk = q.shape
+    heads = k_new.shape[2]
+    lens = jnp.where(active, seq_lens, 0).astype(jnp.int32)
+    rows = jnp.arange(s, dtype=jnp.int32)
+    starts = None
+    if window is None:
+        tables, eff = block_tables, lens
+        write_block = jnp.where(active,
+                                block_tables[rows, lens // block_size], 0)
+    else:
+        from ...serving.cache import ring_block
+        ring = (k_pools.shape[1] - 1) // s
+        write_block = jnp.where(
+            active, ring_block(rows, lens, block_size, ring), 0)
+        oldest = jnp.maximum(lens - (int(window) - 1), 0)
+        first = oldest // block_size                   # its block, absolute
+        entry = (first[:, None] + jnp.arange(ring, dtype=jnp.int32)[None]) \
+            * block_size
+        tables = jnp.where(active[:, None], ring_block(
+            rows[:, None], entry, block_size, ring), 0).astype(jnp.int32)
+        eff, starts = lens - first * block_size, oldest - first * block_size
+    write_block = write_block.astype(jnp.int32)
+    write_off = lens % block_size
+    with jax.named_scope("paged_kv_write"):
+        k_pools = k_pools.at[layer, write_block, write_off].set(
+            k_new[:, 0].reshape(s, -1).astype(k_pools.dtype))
+        v_pools = v_pools.at[layer, write_block, write_off].set(
+            v_new[:, 0].reshape(s, -1).astype(v_pools.dtype))
+    variant = resolve_paged_kernel(kernel, heads, dk, block_size,
+                                   interpret=interpret,
+                                   kv_dtype=k_pools.dtype)
+    qh = q[:, 0]
+    with jax.named_scope("paged_attention"):
+        if variant == "reference":
+            out = _dense_banded_attention(qh, k_pools, v_pools, layer,
+                                          tables, eff, block_size, starts,
+                                          sink)
+        elif variant == "blockwise":
+            from ...kernels.pallas.paged_attention import (
+                blockwise_paged_attention)
+            out = blockwise_paged_attention(
+                qh, k_pools, v_pools, layer, tables, eff, block_size,
+                starts=starts, sink=sink)
+        else:
+            from ...kernels.pallas.paged_attention import (
+                pallas_banded_attention)
+            out = pallas_banded_attention(
+                qh, k_pools, v_pools, layer, tables, eff, block_size,
+                starts=starts, sink=sink, interpret=interpret, name=name)
+    return out[:, None], k_pools, v_pools
+
+
+def _dense_banded_attention(qh, k_pools, v_pools, layer, tables, lens,
+                            block_size, starts=None, sink=None):
+    """`_dense_gather_attention` with a value narrower than its key, a
+    window's oldest position a slot and a sink a head: the oracle of
+    `paged_banded_decode_attention`'s other two variants."""
+    s, hq, dk = qh.shape
+    h = k_pools.shape[-1] // dk
+    t_max = tables.shape[1] * block_size
+    keys = k_pools[layer, tables].astype(jnp.float32).reshape(
+        s, t_max, h, dk)
+    vals = v_pools[layer, tables].astype(jnp.float32).reshape(
+        s, t_max, h, -1)
+    qg = qh.astype(jnp.float32).reshape(s, h, hq // h, dk)
+    scores = jnp.einsum("shgd,sthd->shgt", qg, keys).reshape(s, hq, t_max) \
+        / jnp.sqrt(jnp.asarray(dk, jnp.float32))
+    pos = jnp.arange(t_max, dtype=jnp.int32)[None, :]
+    valid = pos <= lens[:, None]
+    if starts is not None:
+        valid = valid & (pos >= starts[:, None])
+    scores = jnp.where(valid[:, None, :], scores,
+                       jnp.asarray(-1e30, jnp.float32))
+    if sink is not None:
+        scores = jnp.concatenate([scores, jnp.broadcast_to(
+            sink.astype(jnp.float32)[None, :, None], (s, hq, 1))], -1)
+    probs = jax.nn.softmax(scores, axis=-1)[..., :t_max]
+    out = jnp.einsum("shgt,sthd->shgd",
+                     probs.reshape(s, h, hq // h, t_max), vals)
+    return out.reshape(s, hq, -1).astype(qh.dtype)
+
+
 __all__ += ["paged_decode_attention", "paged_latent_decode_attention",
-            "resolve_paged_kernel", "PAGED_KERNELS"]
+            "paged_banded_decode_attention", "resolve_paged_kernel",
+            "PAGED_KERNELS"]
 
 
 @register_op("sparse_attention", "attention",

@@ -52,8 +52,9 @@ import jax
 import jax.numpy as jnp
 
 __all__ = ["BlockAllocator", "CacheSpec", "PagedKVCache", "PagedCacheView",
-           "scatter_prefill", "NULL_BLOCK", "pool_bytes_per_block",
-           "num_blocks_for_bytes"]
+           "scatter_prefill", "scatter_window_prefill", "ring_block",
+           "ring_blocks",
+           "NULL_BLOCK", "pool_bytes_per_block", "num_blocks_for_bytes"]
 
 # block id 0 is never allocated: it is the write/read target for inactive
 # slots and out-of-range table entries (see module docstring)
@@ -191,15 +192,49 @@ class CacheSpec:
     state of ACTIVE slots only, where it lies. What a state cannot do
     yet the engine refuses by name at construction: reuse of a prefix
     (no state exists at a prefix's boundary), adapters, an int8 pool.
+
+    window_layers / window / window_parts: a THIRD kind, for attention
+    layers that see only the last `window` tokens (position i attends j
+    with ``i - window < j <= i``). Held in the paged pool such a layer
+    would keep every token of a sequence for nothing, so its keys and
+    values live in an allocation of their own: a RING OF BLOCKS A SLOT,
+    ``ring_blocks(block_size) = ceil(window / block_size) + 1`` blocks
+    (the window and the block the newest token is filling), two pools
+    ``[window_layers, 1 + slots * ring, block_size, H * D]`` at the
+    rows of `window_parts` (``((H, Dk), (H, Dv))``: their own head count
+    and widths), block 0 the null block. Position p of slot s lies in
+    block ``1 + s * ring + (p // block_size) % ring`` at row
+    ``p % block_size``: no table, no allocator, nothing the scheduler
+    counts; what it costs is ``slots * ring * block_size`` rows a layer
+    whatever the contexts. Under ``"kv"`` the two `parts` may differ in
+    width too (a key wider than its value).
+
+    THE RULE the rings are kept by (tests/test_mimo_v2_flash.py holds
+    it): a PREFILL WRITES the last `window` tokens before the prompt's
+    true `length` (not its bucket's end; all of a shorter prompt) into
+    its slot's ring, which is every position the slot's first decode
+    launch may read, so a reused slot needs no clearing; a DECODE launch
+    writes the new token of ACTIVE slots only (an inactive slot's goes
+    to the null block) over the block that left the window longest ago,
+    and reads the blocks from the window's oldest position to the
+    newest, masked to the window; EVICTION frees nothing here (the ring
+    is the slot's, not the request's) and a RESUME, which is a
+    re-prefill of prompt + generated tokens, writes the ring anew. What
+    a ring cannot do yet the engine refuses by name at construction:
+    reuse of a prefix (a prefix hit skips the prefill that writes the
+    ring, and no ring exists at a prefix's boundary), adapters, an int8
+    pool.
     """
 
     __slots__ = ("kind", "num_layers", "parts", "widths", "num_heads",
                  "head_dim", "chunk_tokens", "min_width_slots",
-                 "query_heads", "state_layers", "state_shape")
+                 "query_heads", "state_layers", "state_shape",
+                 "window_layers", "window", "window_parts")
 
     def __init__(self, kind, num_layers, parts, widths, num_heads,
                  head_dim, chunk_tokens=None, min_width_slots=None,
-                 query_heads=None, state_layers=0, state_shape=()):
+                 query_heads=None, state_layers=0, state_shape=(),
+                 window_layers=0, window=0, window_parts=()):
         if kind not in ("kv", "latent"):
             raise ValueError(f"unknown cache kind {kind!r}")
         if len(widths) != (2 if kind == "kv" else 1):
@@ -219,6 +254,20 @@ class CacheSpec:
         self.query_heads = query_heads
         self.state_layers = int(state_layers)
         self.state_shape = tuple(int(n) for n in state_shape)
+        self.window_layers = int(window_layers)
+        self.window = int(window)
+        self.window_parts = tuple(tuple(int(n) for n in p)
+                                  for p in window_parts)
+        if self.window_layers and (self.window < 1
+                                   or len(self.window_parts) != 2):
+            raise ValueError(
+                f"{self.window_layers} window layers need a window and the "
+                f"two parts of a token, got window {window!r} and parts "
+                f"{window_parts!r}")
+
+    def ring_blocks(self, block_size):
+        """Blocks a slot's ring holds (`ring_blocks`)."""
+        return ring_blocks(self.window, block_size)
 
     def loop_plan(self, block_size):
         """Keyword arguments of the blockwise loop's plan ({} for the
@@ -232,25 +281,32 @@ class CacheSpec:
         return plan
 
     @classmethod
-    def per_head(cls, num_layers, num_heads, head_dim, **more):
-        """A K and a V pool of all the heads' values side by side."""
-        part = (num_heads, head_dim)
-        return cls("kv", num_layers, (part, part),
-                   (num_heads * head_dim,) * 2, num_heads, head_dim, **more)
+    def per_head(cls, num_layers, num_heads, head_dim, value_dim=None,
+                 **more):
+        """A K and a V pool of all the heads' values side by side
+        (`value_dim`: a value's width where it is not the key's)."""
+        value_dim = head_dim if value_dim is None else value_dim
+        return cls("kv", num_layers,
+                   ((num_heads, head_dim), (num_heads, value_dim)),
+                   (num_heads * head_dim, num_heads * value_dim), num_heads,
+                   head_dim, **more)
 
     def empty_prefill(self, dtype):
         """The caches a prefill hands the model: for each cached sublayer
-        the two parts with no token in them yet, then, for each layer that
-        keeps a per-slot state, that state as it is before a sequence:
-        zeros. The model hands back the same list after the prompt."""
+        the two parts with no token in them yet, then the same for each
+        window layer, then, for each layer that keeps a per-slot state,
+        that state as it is before a sequence: zeros. The model hands
+        back the same list after the prompt."""
         from ..framework.core import Tensor
         caches = []
-        for _ in range(self.num_layers):
-            first = Tensor(jnp.zeros((1, 0) + self.parts[0], dtype))
-            # parts of one shape share the one empty tensor
-            caches.append((first, first if self.parts[1] == self.parts[0]
-                           else Tensor(jnp.zeros((1, 0) + self.parts[1],
-                                                 dtype))))
+        for parts, layers in ((self.parts, self.num_layers),
+                              (self.window_parts, self.window_layers)):
+            for _ in range(layers):
+                first = Tensor(jnp.zeros((1, 0) + parts[0], dtype))
+                # parts of one shape share the one empty tensor
+                caches.append((first, first if parts[1] == parts[0]
+                               else Tensor(jnp.zeros((1, 0) + parts[1],
+                                                     dtype))))
         if self.state_layers:
             caches += [Tensor(jnp.zeros((1,) + self.state_shape, dtype))] \
                 * self.state_layers
@@ -280,15 +336,23 @@ class PagedCacheView:
     `state_layer`, the index of the next layer that owns one: such a
     layer reads and writes its own index and hands the view on through
     `updated(slot_state=...)`, as an attention layer hands on the
-    pools."""
+    pools.
+
+    `window_pools` (the two ring pools of `CacheSpec`'s window layers,
+    or None where the model has none) with `window_layer` and `window`
+    (tokens) go the same way: a window layer reads and writes its own
+    index of them and hands the view on through
+    `updated(window_pools=...)`."""
 
     __slots__ = ("k_pools", "v_pools", "layer", "block_tables", "seq_lens",
                  "active", "block_size", "k_scales", "v_scales", "kernel",
-                 "slot_state", "state_layer")
+                 "slot_state", "state_layer", "window_pools", "window_layer",
+                 "window")
 
     def __init__(self, k_pools, v_pools, layer, block_tables, seq_lens,
                  active, block_size, k_scales=None, v_scales=None,
-                 kernel=None, slot_state=None, state_layer=0):
+                 kernel=None, slot_state=None, state_layer=0,
+                 window_pools=None, window_layer=0, window=0):
         self.k_pools = k_pools
         self.v_pools = v_pools
         self.layer = int(layer)
@@ -301,25 +365,37 @@ class PagedCacheView:
         self.kernel = kernel
         self.slot_state = slot_state
         self.state_layer = int(state_layer)
+        self.window_pools = window_pools
+        self.window_layer = int(window_layer)
+        self.window = int(window)
 
     def updated(self, k_pools=None, v_pools=None, k_scales=None,
-                v_scales=None, slot_state=None):
+                v_scales=None, slot_state=None, window_pools=None):
         """The view for the NEXT layer: over the pools an attention layer
-        wrote, or, given `slot_state`, over the state a layer that keeps
-        one wrote (the pools and their layer index as they were)."""
-        if slot_state is not None:
+        wrote, or, given `slot_state` or `window_pools`, over what a layer
+        that keeps a state, or a window layer, wrote (the pools and their
+        layer index as they were)."""
+        if slot_state is not None or window_pools is not None:
+            stated = slot_state is not None
             return PagedCacheView(
                 self.k_pools, self.v_pools, self.layer, self.block_tables,
                 self.seq_lens, self.active, self.block_size,
                 k_scales=self.k_scales, v_scales=self.v_scales,
-                kernel=self.kernel, slot_state=slot_state,
-                state_layer=self.state_layer + 1)
+                kernel=self.kernel,
+                slot_state=slot_state if stated else self.slot_state,
+                state_layer=self.state_layer + stated,
+                window_pools=self.window_pools if stated else window_pools,
+                window_layer=self.window_layer + (not stated),
+                window=self.window)
         return PagedCacheView(k_pools, v_pools, self.layer + 1,
                               self.block_tables, self.seq_lens, self.active,
                               self.block_size, k_scales=k_scales,
                               v_scales=v_scales, kernel=self.kernel,
                               slot_state=self.slot_state,
-                              state_layer=self.state_layer)
+                              state_layer=self.state_layer,
+                              window_pools=self.window_pools,
+                              window_layer=self.window_layer,
+                              window=self.window)
 
 
 def _is_int8(dtype):
@@ -348,9 +424,12 @@ class PagedKVCache:
     A model whose `CacheSpec` describes a per-slot state gets it beside
     the pools: `slot_state` ``[state_layers, num_slots] + state_shape``
     in `state_dtype` (the model's; the pool's where not given), zeros,
-    not paged and never allocated from. `buffers()` is every device
-    buffer of the cache in the order the engine's programs take, donate
-    and hand them back.
+    not paged and never allocated from. One whose `CacheSpec` describes
+    window layers gets their two ring pools (`window_pools`
+    ``[window_layers, 1 + num_slots * ring, block_size, H * D]``, zeros,
+    in the pool's dtype; never allocated from either: a slot's ring is
+    its own). `buffers()` is every device buffer of the cache in the
+    order the engine's programs take, donate and hand them back.
     """
 
     def __init__(self, spec, num_blocks, block_size, dtype=jnp.float32,
@@ -384,17 +463,27 @@ class PagedKVCache:
             self.slot_state = jnp.zeros(
                 (spec.state_layers, int(num_slots)) + spec.state_shape,
                 self.dtype if state_dtype is None else state_dtype)
+        self.window_pools = None
+        if spec.window_layers:
+            blocks = 1 + int(num_slots) * spec.ring_blocks(self.block_size)
+            self.window_pools = tuple(
+                jnp.zeros((spec.window_layers, blocks, self.block_size,
+                           heads * width), self.dtype)
+                for heads, width in spec.window_parts)
         self.allocator = BlockAllocator(self.num_blocks)
 
     def buffers(self):
         """The cache's device buffers: the two pools, the int8 scale
         side-tables where the pool is quantized, the slots' state where
-        the model keeps one."""
+        the model keeps one, the window layers' two ring pools where it
+        has such layers."""
         out = (self.k_pools, self.v_pools)
         if self.quantized:
             out += (self.k_scales, self.v_scales)
         if self.slot_state is not None:
             out += (self.slot_state,)
+        if self.window_pools is not None:
+            out += self.window_pools
         return out
 
 
@@ -474,4 +563,52 @@ def scatter_prefill(k_pools, v_pools, k_layers, v_layers, block_row,
         k_pools = k_pools.at[layer, blocks, offs].set(k_rows[layer])
         if v_pools.size:
             v_pools = v_pools.at[layer, blocks, offs].set(v_rows[layer])
+    return k_pools, v_pools
+
+
+def ring_blocks(window, block_size):
+    """Blocks a slot's ring holds for a window of `window` tokens: the
+    window and the block the newest token is filling."""
+    return -(-int(window) // int(block_size)) + 1
+
+
+def ring_block(slot, position, block_size, ring):
+    """The block of a window layer's ring pool that holds `position` of
+    `slot` (`CacheSpec`: block 0 is the null block, a slot's `ring`
+    blocks follow each other, a position's block turns with
+    ``position // block_size``). Arrays or tracers, int32."""
+    return 1 + slot * ring + (position // block_size) % ring
+
+
+@jax.named_scope("scatter_window_prefill")
+def scatter_window_prefill(k_pools, v_pools, k_layers, v_layers, slot,
+                           length, block_size, window):
+    """A prefilled prompt's last `window` tokens into `slot`'s ring
+    (`CacheSpec`'s rule: the tokens before the prompt's TRUE `length`,
+    every position the slot's first decode launch may read).
+
+    k_layers/v_layers: ``[L, T_bucket, H, D]`` of the window layers, as
+    the bucketed prefill computed them; k_pools/v_pools: the ring pools
+    ``[L, 1 + slots * ring, block_size, H*D]``. Only `window` rows a
+    layer are moved (a slice that ends at `length`, not the bucket); rows
+    of it that lie before the sequence or past its length go to the null
+    block. Traceable; returns the written pools."""
+    num_layers, t_bucket = k_layers.shape[:2]
+    ring = ring_blocks(window, block_size)
+    rows = min(int(window), t_bucket)
+    first = jnp.clip(length - rows, 0, t_bucket - rows).astype(jnp.int32)
+    pos = first + jnp.arange(rows, dtype=jnp.int32)
+    blocks = jnp.where(pos < length,
+                       ring_block(slot, pos, block_size, ring),
+                       jnp.asarray(NULL_BLOCK, jnp.int32)).astype(jnp.int32)
+    offs = pos % block_size
+
+    def last(layers, pool):
+        cut = jax.lax.dynamic_slice_in_dim(layers, first, rows, axis=1)
+        return cut.reshape(num_layers, rows, -1).astype(pool.dtype)
+
+    k_rows, v_rows = last(k_layers, k_pools), last(v_layers, v_pools)
+    for layer in range(num_layers):
+        k_pools = k_pools.at[layer, blocks, offs].set(k_rows[layer])
+        v_pools = v_pools.at[layer, blocks, offs].set(v_rows[layer])
     return k_pools, v_pools

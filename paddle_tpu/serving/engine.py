@@ -129,7 +129,8 @@ from ..profiler import telemetry_server as _telemetry
 from ..profiler import sentinel as _sentinel
 from ..kernels.pallas.paged_attention import (blockwise_streamed_entries,
                                                pallas_copied_pages)
-from .cache import PagedKVCache, PagedCacheView, scatter_prefill, _is_int8
+from .cache import (PagedKVCache, PagedCacheView, scatter_prefill,
+                    scatter_window_prefill, _is_int8)
 from .scheduler import (Request, Scheduler, QUEUED, RUNNING, FINISHED,
                         FAILED, CANCELLED, EXPIRED)
 from .resilience import (ServeRefusal, MonitoredWait, StepHang,
@@ -309,6 +310,16 @@ class ServeStats:
         self.attn_entries_streamed = 0
         self.attn_entries_held = 0
         self.attn_entries_total = 0
+        # the tokens the active slots attended to, summed over the
+        # launches (each slot's context and its new token): exact, where
+        # the entries above count whole pages
+        self.attn_tokens_held = 0
+        # the same of ONE window layer's ring (serving/cache.py
+        # `CacheSpec`), in pages summed over the launches: what the
+        # attention read, and the pages the slots' windows lie in
+        self.window_pages_streamed = 0
+        self.window_pages_held = 0
+        self.window_tokens_held = 0
         # tokens the programs were given: a prefill's context, a decode
         # launch's active slots
         self.prefill_tokens = 0
@@ -480,6 +491,16 @@ class ServeStats:
             "attn_held_share": (
                 self.attn_entries_held / self.attn_entries_total
                 if self.attn_entries_total else 0.0),
+            "attn_tokens_held": self.attn_tokens_held,
+            # a window layer's ring: pages read over the pages the
+            # windows lie in (1.0: the window and no more), and the
+            # tokens inside the windows
+            "window_pages_streamed": self.window_pages_streamed,
+            "window_pages_held": self.window_pages_held,
+            "window_tokens_held": self.window_tokens_held,
+            "window_streamed_share": (
+                self.window_pages_streamed / self.window_pages_held
+                if self.window_pages_held else 0.0),
             "prefill_tokens": self.prefill_tokens,
             "prefill_bucket_tokens": self.prefill_bucket_tokens,
             "prefill_padding_tokens": (self.prefill_bucket_tokens
@@ -630,33 +651,37 @@ class LLMEngine:
         # bakes it in (zero retraces under churn); a flag flip only
         # affects engines built after it
         from ..nn.functional.attention import resolve_paged_kernel
-        if spec.kind == "latent":
-            # what a latent pool does not do yet is refused by name, here:
-            # none of it may run silently wrong
+        # what a kind of cache does not do yet is refused by name, here:
+        # none of it may run silently wrong. (`CacheSpec` says why of a
+        # state and of a ring: a prefix hit skips the prefill that writes
+        # them, and neither exists at a prefix's boundary)
+        unequal = spec.kind == "kv" and spec.parts[0] != spec.parts[1]
+        for cannot, where in (
+                (spec.kind == "latent", "over a latent cache"),
+                (spec.state_layers, "beside a per-slot state"),
+                (spec.window_layers or unequal, "over window layers' rings "
+                 "or keys wider than their values")):
             for option, on in (
                     ("kv_dtype='int8'", self._kv_quantized),
                     ("enable_prefix_cache", enable_prefix_cache),
                     ("max_adapters", max_adapters > 0)):
-                if on:
+                if cannot and on:
                     raise ValueError(
-                        f"{option} is not supported over a latent cache "
+                        f"{option} is not supported {where} "
                         f"({type(model).__name__}.cache_spec())")
-        if spec.state_layers:
-            # nor what a per-slot state cannot do yet (`CacheSpec`): a
-            # prefix hit skips the prefill that writes the state, and no
-            # state exists at a prefix's boundary
-            for option, on in (
-                    ("enable_prefix_cache", enable_prefix_cache),
-                    ("max_adapters", max_adapters > 0),
-                    ("kv_dtype='int8'", self._kv_quantized)):
-                if on:
-                    raise ValueError(
-                        f"{option} is not supported beside a per-slot "
-                        f"state ({type(model).__name__}.cache_spec())")
         self._attn_kernel = resolve_paged_kernel(
             attention_kernel, num_heads=spec.num_heads,
             head_dim=spec.head_dim, block_size=self.block_size,
             kv_dtype=self._kv_dtype)
+        # every other row the cache holds (a value narrower than its key,
+        # a window layer's) has to be on the kernel's tiles too
+        for heads, width in (spec.parts[1:] if spec.kind == "kv" else ()) \
+                + spec.window_parts:
+            if self._attn_kernel == "pallas" \
+                    and (heads, width) != (spec.num_heads, spec.head_dim):
+                self._attn_kernel = resolve_paged_kernel(
+                    attention_kernel, num_heads=heads, head_dim=width,
+                    block_size=self.block_size, kv_dtype=self._kv_dtype)
         if self._kv_quantized:
             _EVENTS.emit("kernel.quantized", "serve.decode",
                          reason="kv_quantized",
@@ -1651,6 +1676,9 @@ class LLMEngine:
         snap["kv_dtype"] = str(jnp.dtype(self._kv_dtype))
         state = self.cache.slot_state
         snap["slot_state_bytes"] = 0 if state is None else int(state.nbytes)
+        rings = self.cache.window_pools
+        snap["window_ring_bytes"] = 0 if rings is None \
+            else int(sum(pool.nbytes for pool in rings))
         if self._prefix is not None:
             snap["prefix_entries"] = self._prefix.entries
         if self._tenant:
@@ -1854,12 +1882,25 @@ class LLMEngine:
         return self._donate(tuple(range(first, first + len(self._bufs))))
 
     def _split_more(self, more):
-        """What a program's signature holds behind the two pools:
-        ``(k_scales, v_scales, slot_state)``, None where the cache has
-        none."""
-        scales = tuple(more[:2]) if self._kv_quantized else (None, None)
-        state = more[-1] if self.cache.slot_state is not None else None
-        return scales + (state,)
+        """What a program's signature holds behind the two pools, in
+        `buffers()`' order: ``(k_scales, v_scales, slot_state,
+        window_pools)``, None where the cache has none."""
+        more = list(more)
+        scales = (more.pop(0), more.pop(0)) if self._kv_quantized \
+            else (None, None)
+        state = more.pop(0) if self.cache.slot_state is not None else None
+        rings = tuple(more) if self.cache.window_pools is not None else None
+        return scales + (state, rings)
+
+    def _view(self, k_pools, v_pools, tables, lens, active, more):
+        """The ONE view a decode program threads through the model's
+        layers, over the program's own (donated) buffers."""
+        k_scales, v_scales, slot_state, rings = self._split_more(more)
+        return PagedCacheView(
+            k_pools, v_pools, 0, tables, lens, active, self.block_size,
+            k_scales=k_scales, v_scales=v_scales, kernel=self._attn_kernel,
+            slot_state=slot_state, window_pools=rings,
+            window=self.cache.spec.window)
 
     @staticmethod
     def _written(view):
@@ -1870,6 +1911,8 @@ class LLMEngine:
             out += (view.k_scales, view.v_scales)
         if view.slot_state is not None:
             out += (view.slot_state,)
+        if view.window_pools is not None:
+            out += tuple(view.window_pools)
         return out
 
     def _sync_slot(self, req):
@@ -2112,6 +2155,31 @@ class LLMEngine:
         stats.attn_entries_streamed += streamed
         stats.attn_entries_held += held
         stats.attn_entries_total += total
+        context = np.where(active, lens, 0).astype(np.int64) + active
+        stats.attn_tokens_held += int(context.sum())
+        spec = self.cache.spec
+        if spec.window_layers:
+            # a window layer reads its slots' rings through a table that
+            # starts at the block of the window's oldest position
+            # (nn/functional/attention.py paged_banded_decode_attention)
+            ring = spec.ring_blocks(self.block_size)
+            pos = np.where(active, lens, 0).astype(np.int64)
+            first = np.maximum(pos - (spec.window - 1), 0) \
+                // self.block_size
+            eff = pos - first * self.block_size
+            if self._attn_kernel == "pallas":
+                streamed, held = pallas_copied_pages(
+                    eff, active, ring, self.block_size)
+            else:
+                streamed, held = blockwise_streamed_entries(
+                    eff, active, ring, self.block_size,
+                    *spec.window_parts[0])
+                if self._attn_kernel != "blockwise":
+                    streamed = self.max_batch_size * ring
+            stats.window_pages_streamed += streamed
+            stats.window_pages_held += held
+            stats.window_tokens_held += int(
+                np.minimum(context, spec.window).sum())
 
     def _pools_consumed(self):
         return any(b.is_deleted() for b in self._bufs
@@ -2468,26 +2536,20 @@ class LLMEngine:
             # export of a pytree-carrying signature is not supported —
             # tenant replicas always trace once at start
             return self._build_decode_tenant()
-        block_size = self.block_size
         stats = self._stats
-        variant = self._attn_kernel
         lp_topk = self._logprobs_topk
 
         def decode(tokens, feedback, override, tables, lens, active,
                    temps, topks, topps, rpens, seeds, history, k_pools,
                    v_pools, *more):
             stats.decode_compiles += 1   # runs only while tracing
-            k_scales, v_scales, slot_state = self._split_more(more)
             # a slot's input is the token the launch before sampled for
             # it, still on the device, unless the host wrote one
             tokens = jnp.where(override, tokens, feedback)
             # ONE view over the stacked (donated) pools: every layer
             # writes at its own index and hands them on, so the pools
             # the last layer returns are the step's, updated in place
-            view = PagedCacheView(
-                k_pools, v_pools, 0, tables, lens, active, block_size,
-                k_scales=k_scales, v_scales=v_scales, kernel=variant,
-                slot_state=slot_state)
+            view = self._view(k_pools, v_pools, tables, lens, active, more)
             logits, (view,), extra = self._forward(tokens[:, None], [view])
             # the in-graph history scatter: the input token enters the
             # context at index `lens` — under pipelined decode it may
@@ -2538,9 +2600,7 @@ class LLMEngine:
         inputs. Compiles exactly once per engine, like the base
         program."""
         model = self._model
-        block_size = self.block_size
         stats = self._stats
-        variant = self._attn_kernel
         params = model.parameters()
         holder = self._holder
         lp_topk = self._logprobs_topk
@@ -2549,7 +2609,6 @@ class LLMEngine:
                    temps, topks, topps, rpens, seeds, history, k_pools,
                    v_pools, *more):
             stats.decode_compiles += 1   # runs only while tracing
-            k_scales, v_scales, slot_state = self._split_more(more)
             tokens = jnp.where(override, tokens, feedback)
             pvals = aux.get("params")
             saved = None
@@ -2561,10 +2620,8 @@ class LLMEngine:
                 holder["active"] = AdapterSet.trace_ctx(
                     aux["adapters"], slots=aux["aslots"])
             try:
-                view = PagedCacheView(
-                    k_pools, v_pools, 0, tables, lens, active, block_size,
-                    k_scales=k_scales, v_scales=v_scales, kernel=variant,
-                    slot_state=slot_state)
+                view = self._view(k_pools, v_pools, tables, lens, active,
+                                  more)
                 logits, (view,), extra = self._forward(tokens[:, None],
                                                        [view])
             finally:
@@ -2601,17 +2658,29 @@ class LLMEngine:
         `length`: written WHOLE at the request's slot (`CacheSpec`'s
         rule: a reused slot needs no clearing). Returns ``(feedback,
         firsts) + the written buffers``."""
-        k_scales, v_scales, slot_state = self._split_more(more)
-        paged = caches[:self.cache.spec.num_layers]
-        k_layers = jnp.stack([c[0]._value[0] for c in paged])
-        v_layers = jnp.stack([c[1]._value[0] for c in paged])
+        k_scales, v_scales, slot_state, rings = self._split_more(more)
+        spec = self.cache.spec
+        paged = caches[:spec.num_layers]
+        windowed = caches[len(paged):len(paged) + spec.window_layers]
+
+        def stacked(pairs, part):
+            return jnp.stack([c[part]._value[0] for c in pairs])
+
         written = tuple(scatter_prefill(
-            k_pools, v_pools, k_layers, v_layers, block_row, length,
-            self.block_size, k_scales=k_scales, v_scales=v_scales))
+            k_pools, v_pools, stacked(paged, 0), stacked(paged, 1),
+            block_row, length, self.block_size, k_scales=k_scales,
+            v_scales=v_scales))
         if slot_state is not None:
-            states = jnp.stack([c._value[0] for c in caches[len(paged):]])
+            states = jnp.stack([c._value[0] for c in
+                                caches[len(paged) + len(windowed):]])
             written += (slot_state.at[:, slot].set(
                 states.astype(slot_state.dtype)),)
+        if rings is not None:
+            # `CacheSpec`'s rule: the last `window` tokens before the
+            # prompt's true length, into the slot's own ring
+            written += tuple(scatter_window_prefill(
+                *rings, stacked(windowed, 0), stacked(windowed, 1), slot,
+                length, self.block_size, spec.window))
         last = jax.lax.dynamic_index_in_dim(
             logits._value[0], length - 1, axis=0, keepdims=False)
         # the prompt's first sampled token: position = prompt length

@@ -734,3 +734,121 @@ def test_the_served_expert_block_through_the_kernel(v5e, monkeypatch,
     assert len(named) == 3
     assert all(name.startswith("ragged_expert_matmul") for name in named)
     assert "ragged-dot" not in text
+
+
+# -- MiMo-V2-Flash: keys wider than values, window layers' rings --------------
+
+MIMO_HEADS, MIMO_DK, MIMO_DV, MIMO_WINDOW = 64, 192, 128, 128
+MIMO_TABLE = 8192 // BLOCK                         # a context of 8,192
+MIMO_RING = MIMO_WINDOW // BLOCK + 1
+
+
+@pytest.mark.parametrize("kind", ["full", "window"])
+def test_the_banded_decode_kernel_at_the_cells_geometry(v5e, kind):
+    """`serve_mimo_reasoning_8k`'s decode attention as the engine traces
+    it: 64 query heads over 4 key/value heads (full layers: the cell's
+    whole pools, K rows of 768 and V rows of 512) or over 8 (window
+    layers: the rings, rows of 1,536 and 1,024, a table of 9 entries, the
+    window's oldest position and the sink): Mosaic takes both, and the
+    program holds no loop."""
+    kv, layers, blocks, table = {
+        "full": (4, 2, 1 + CELL_SLOTS * MIMO_TABLE, MIMO_TABLE),
+        "window": (8, 5, 1 + CELL_SLOTS * MIMO_RING, MIMO_RING)}[kind]
+    windowed = kind == "window"
+
+    def call(q, k_pools, v_pools, tables, lens, starts, sink):
+        return paged_attention.pallas_banded_attention(
+            q, k_pools, v_pools, 1, tables, lens, BLOCK,
+            starts=starts if windowed else None,
+            sink=sink if windowed else None,
+            name=kind + "_decode_attention")
+
+    text = compile_for(
+        v5e, call, ((CELL_SLOTS, MIMO_HEADS, MIMO_DK), jnp.bfloat16),
+        ((layers, blocks, BLOCK, kv * MIMO_DK), jnp.bfloat16),
+        ((layers, blocks, BLOCK, kv * MIMO_DV), jnp.bfloat16),
+        ((CELL_SLOTS, table), jnp.int32), ((CELL_SLOTS,), jnp.int32),
+        ((CELL_SLOTS,), jnp.int32), ((MIMO_HEADS,), jnp.float32))
+    assert "while" not in text
+    assert kind + "_decode_attention" in text
+
+
+@pytest.mark.parametrize("bucket", [2048, 8192])
+def test_the_band_prefill_kernel_at_the_cells_buckets(v5e, bucket):
+    """A window layer's prefill: 64 query heads of 192 over 8 key/value
+    heads (no head repeated: the index map divides), values of 128, a
+    band of 128 with the sink."""
+    text = compile_for(
+        v5e, lambda q, k, v, sink: flash_attention.flash_band_attention_bnhd(
+            q, k, v, MIMO_WINDOW, sink),
+        ((1, bucket, MIMO_HEADS, MIMO_DK), jnp.bfloat16),
+        ((1, bucket, 8, MIMO_DK), jnp.bfloat16),
+        ((1, bucket, 8, MIMO_DV), jnp.bfloat16),
+        ((MIMO_HEADS,), jnp.float32))
+    assert "flash_band_attention" in text
+
+
+def test_a_decode_step_updates_both_caches_where_they_lie(v5e, monkeypatch):
+    """A model with window and full layers through ONE `PagedCacheView`,
+    as the engine's decode program threads it (weights as arguments): the
+    donated paged pools AND the donated ring pools go out in the buffers
+    they came in; no instruction of any of their shapes is a `copy`, a
+    `concatenate` or a `pad`."""
+    import paddle_tpu as paddle
+    from paddle_tpu.incubate.models.mimo_v2_flash import (
+        FULL, WINDOW, MiMoV2FlashConfig, MiMoV2FlashForCausalLM)
+    from paddle_tpu.serving.cache import PagedCacheView
+    monkeypatch.setattr(paged_attention, "_on_tpu", lambda: True)
+    model = MiMoV2FlashForCausalLM(MiMoV2FlashConfig(
+        vocab_size=512, hidden_size=512, intermediate_size=256,
+        moe_intermediate_size=128, num_hidden_layers=4,
+        layer_types=(FULL, WINDOW, WINDOW, FULL), first_k_dense_replace=4,
+        n_routed_experts=8, num_experts_per_tok=2))
+    params = model.parameters()
+    blocks = 1 + CELL_SLOTS * 128                    # contexts of 2,048
+    pools = [(2, blocks, BLOCK, 4 * MIMO_DK), (2, blocks, BLOCK, 4 * MIMO_DV)]
+    rings = [(2, 1 + CELL_SLOTS * MIMO_RING, BLOCK, 8 * MIMO_DK),
+             (2, 1 + CELL_SLOTS * MIMO_RING, BLOCK, 8 * MIMO_DV)]
+
+    def decode(values, tokens, tables, lens, active, k_pools, v_pools,
+               ring_k, ring_v):
+        saved = [p._value for p in params]
+        for p, v in zip(params, values):
+            p._value = v
+        try:
+            view = PagedCacheView(k_pools, v_pools, 0, tables, lens, active,
+                                  BLOCK, kernel="pallas",
+                                  window_pools=(ring_k, ring_v),
+                                  window=MIMO_WINDOW)
+            logits, (view,) = model(paddle.Tensor(tokens[:, None]),
+                                    caches=[view])
+        finally:
+            for p, v in zip(params, saved):
+                p._value = v
+        assert (view.layer, view.window_layer) == (2, 2)
+        return (logits._value, view.k_pools, view.v_pools) \
+            + tuple(view.window_pools)
+
+    sd = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                   sharding=v5e)
+    args = [[sd(p._value.shape, jnp.bfloat16) for p in params],
+            sd((CELL_SLOTS,), jnp.int32), sd((CELL_SLOTS, 128), jnp.int32),
+            sd((CELL_SLOTS,), jnp.int32), sd((CELL_SLOTS,), jnp.bool_)] \
+        + [sd(shape, jnp.bfloat16) for shape in pools + rings]
+    compiled = jax.jit(decode, donate_argnums=(5, 6, 7, 8)).lower(
+        *args).compile()
+    text = compiled.as_text()
+    assert text.count("full_decode_attention") \
+        and text.count("window_decode_attention")
+    for shape in pools + rings:
+        name = "bf16[" + ",".join(map(str, shape)) + "]"
+        made = re.findall(
+            r"= " + re.escape(name) + r"\{([\d,]*)\S* ([\w-]+)\(", text)
+        assert {layout for layout, opcode in made
+                if opcode == "parameter"} == {
+                    ",".join(map(str, reversed(range(len(shape)))))}, name
+        opcodes = {opcode for _, opcode in made}
+        assert not opcodes & {"copy", "concatenate", "pad"}, (name, opcodes)
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 2 * sum(
+        math.prod(shape) for shape in pools + rings)
