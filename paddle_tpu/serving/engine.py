@@ -11,12 +11,17 @@ millions of users"), combining:
     shared by every sequence, per-sequence block tables, admission /
     eviction / preemption as integer-table edits;
   * a **compiled decode step**: a single `jax.jit` executable over a
-    fixed max-batch slot layout — ``(tokens [S], feedback [S],
-    override [S], block_tables [S, M], seq_lens [S], active [S], k_pools,
-    v_pools, ...) -> (next_tokens, new_pools, ...)`` with the cache's
-    buffers donated, however many it has (`PagedKVCache.buffers()`: the
-    two pools, an int8 pool's scales, a per-slot state of layers that
-    are not attention) (a
+    fixed max-batch slot layout — ``(slots [S, M + 4], feedback [S],
+    sampler [S, 5], history [S, C], k_pools, v_pools, ...) ->
+    (next_tokens, rows, sampler, history, new_pools, ...)`` with the
+    cache's buffers donated, however many it has
+    (`PagedKVCache.buffers()`: the two pools, an int8 pool's scales, a
+    per-slot state of layers that are not attention). `slots` is the ONE
+    host array of a launch (block tables and the columns tokens,
+    override, seq_lens, active), `rows` the ONE array the host fetches
+    (token, logprob, panel, the model's counters); the sampler's table
+    and history live on the device and are threaded from program to
+    program (a
     slot's input is the host's token where `override` is set, else the
     launch before's or its own prefill's, still on the device). Requests
     joining or leaving the batch only change the *values* of the integer
@@ -146,6 +151,22 @@ _EST_WINDOW = 32
 
 _MIN_BUCKET = 8
 
+# WHAT CROSSES BETWEEN HOST AND DEVICE IN ONE PROGRAM CALL: one int32
+# array in, one int32 array out (floats by their bits); everything else a
+# program reads is on the device already. A decode launch takes `[S,
+# max_blocks_per_seq + 4]`: the block tables, then these columns. A
+# prefill takes `[bucket + max_blocks_per_seq + 8]`: the padded ids, the
+# block row, then length, slot and the request's row of the sampler's
+# table (`_SAMPLER_NOOP`'s columns), one value spare
+_COL_TOKENS, _COL_OVERRIDE, _COL_LENS, _COL_ACTIVE = range(4)
+_PREFILL_TAIL = 8
+# the sampler's table, a row a slot: temperature, top k, top p, repetition
+# penalty, seed. This row (0, 0, 1, 1, 0) samples nothing: a program reads
+# it for every slot that is not active, which keeps a batch of greedy
+# streams on the cheap branch of `sample_tokens` whatever a departed
+# request left in the table
+_SAMPLER_NOOP = np.array([0.0, 0, 1.0, 1.0, 0], np.float32).view(np.int32)
+
 # the slow-step rule (`ServeStats`' docstring): a step is reported when it
 # is over this many times the window's mean step AND over this many
 # seconds, once the window holds this many steps, at most once a period
@@ -205,6 +226,15 @@ class ServeStats:
     `slowest_step`
         `{index, seconds, phases: {span: seconds in that step}, gc_s}`
         of the window's longest `step()`, or None; one record, replaced
+    `decode_host_arrays`, `prefill_host_arrays`
+        host values (numpy arrays and scalars) among the arguments of
+        the programs called, summed over the calls: over `decode_launches`
+        and `prefills`, 1.0 each (the one packed array) in a window in
+        which no call uploaded the sampler's record (`state_uploads`: the
+        calls that did, two host arrays more each)
+    `decode_fetched_arrays`
+        arrays brought to the host for the launches' results: one a
+        launch
 
     THE SLOW-STEP RULE. A `step()` that took BOTH over `_SLOW_STEP_FACTOR`
     (20) x the mean of the window's steps before it and over
@@ -254,6 +284,12 @@ class ServeStats:
         self.dispatches = dict.fromkeys(self.KINDS, 0)
         self.dispatches_device_idle = dict.fromkeys(self.KINDS, 0)
         self.dispatches_ran_dry = dict.fromkeys(self.KINDS, 0)
+        # host values among the arguments of the programs called, by kind;
+        # the calls that uploaded the host's record of the sampler's
+        # table and history; arrays fetched for the launches' results
+        self.host_arrays = dict.fromkeys(self.KINDS, 0)
+        self.state_uploads = 0
+        self.decode_fetched_arrays = 0
         self.steps = 0
         self.tokens_generated = 0
         self.prefills = 0
@@ -507,6 +543,10 @@ class ServeStats:
                                        - self.prefill_tokens),
             "decode_tokens": self.decode_tokens,
             "decode_launches": self.launches,
+            "decode_host_arrays": self.host_arrays["decode"],
+            "prefill_host_arrays": self.host_arrays["prefill"],
+            "state_uploads": self.state_uploads,
+            "decode_fetched_arrays": self.decode_fetched_arrays,
             **self.model_counts,
             "occupancy_mean": (self.occupancy_sum / self.steps
                                if self.steps else 0.0),
@@ -769,6 +809,21 @@ class LLMEngine:
         # its own input token at index `lens` in-graph, so the one token
         # the host has not committed yet (pipelined mode) is still seen
         self._history = np.zeros((s, self.max_context), np.int32)
+        # those six are the host's RECORD (`_sync_slot` on a resume,
+        # `state_payload`); what the programs read is on the device: a
+        # `[S, 5]` int32 table of the five (floats by their bits) and the
+        # history, threaded from program to program as the pools are. A
+        # prefill writes its slot's row of both from the request's values
+        # and ids it is given anyway, a launch scatters its input tokens
+        # and masks the table by `active` (a cleared slot uploads
+        # nothing). None: stale. An edit of the record with no prefill
+        # behind it (a prefix-hit admission, `restore_state`, a KV reset,
+        # a rebuilt decode program) marks them so, and the next program
+        # call is handed the record itself, once
+        self._sampler_dev = None        # (table, history) on the device
+        # how many of a kind of program's arguments are host values, by
+        # whether the call uploads the record (`_call_program`)
+        self._host_arity = {}
         # -- software-pipelined decode (PR 18; the default loop) --------
         # launch step N+1 against device-fed tokens while step N's host
         # commit overlaps: `_inflight` holds the un-committed launch
@@ -782,6 +837,9 @@ class LLMEngine:
         self._pipeline = bool(pipeline_decode)
         self._inflight = None
         self._feedback = None
+        # what stands in for `_feedback` while there is none (every slot
+        # is overridden then): a device array, so that no call uploads one
+        self._no_feedback = jnp.zeros(s, jnp.int32)
         self._override = np.ones(s, bool)
         # -- prefills launched, not awaited ------------------------------
         # a prefill writes what it sampled into `_feedback` at its slot
@@ -1118,7 +1176,19 @@ class LLMEngine:
         it: at entry (if so its queue is empty, and stays empty until
         this call lands) and, if not, again at return (it ran dry while
         the host was in here). What is asked is that program's first
-        result (the sampled tokens), which no later program donates."""
+        result (the sampled tokens), which no later program donates.
+        Counted too: the host values among the arguments (one, the
+        packed array; three in a call that uploads the sampler's record,
+        which stands where the device's copy of the history would),
+        from the arguments' types, once a signature."""
+        kind = name.split(".")[1]
+        uploads = isinstance(args[-len(self._bufs) - 1], np.ndarray)
+        handed = self._host_arity.get((kind, uploads))
+        if handed is None:
+            handed = self._host_arity[kind, uploads] = sum(
+                isinstance(a, (np.ndarray, np.generic)) for a in args)
+        self._stats.host_arrays[kind] += handed
+        self._stats.state_uploads += uploads
         if first:
             with self._span("engine.compile"), self._span(name):
                 res = fn(*args)
@@ -1128,7 +1198,7 @@ class LLMEngine:
             with self._span(name):
                 res = fn(*args)
             self._stats.count_dispatch(
-                name.split(".")[1], idle,
+                kind, idle,
                 idle or (newest is not None and newest.is_ready()))
         self._newest_result = res[0]
         return res
@@ -1361,12 +1431,11 @@ class LLMEngine:
             self._decode_fn = self._build_decode()
         # the launch is asynchronous and reads its host arguments when it
         # runs, while this method goes on to edit `_lens`, `_tokens` and
-        # `_override` (and the next admission `_tables`, the sampler
-        # buffers) in place: hand it copies, or a slow dispatch reads the
-        # NEXT step's values
-        args = tuple(a.copy() if isinstance(a, np.ndarray) else a
-                     for a in self._decode_args())
-        launch_active = args[self._ARG_ACTIVE]
+        # `_override` (and the next admission `_tables`) in place: the
+        # packed array is made anew for every launch, the ONE copy, or a
+        # slow dispatch would read the NEXT step's values
+        args = self._decode_args()
+        launch_active = args[0][:, self.max_blocks_per_seq + _COL_ACTIVE]
         pending = self._inflight["records"] if self._inflight else {}
         plan = []
         for req in list(sched.running):
@@ -1392,7 +1461,7 @@ class LLMEngine:
         # adopt the launch's pool lineage NOW: any prefill issued before
         # the commit must consume THESE outputs, so XLA's dataflow
         # orders the speculative KV write before the reuse
-        self._bufs = res[4:4 + len(self._bufs)]
+        self._adopt(res)
         self._feedback = res[0]
         # by slot, what the commit needs to know the token is still
         # wanted: the request, its position and its admission
@@ -1453,8 +1522,8 @@ class LLMEngine:
         return True
 
     def _await_launch(self, inf):
-        """The monitored wait for a launch and its four results on the
-        host, or None when destructive recovery retired the batch."""
+        """The monitored wait for a launch and its results on the host,
+        or None when destructive recovery retired the batch."""
         from ..ops import guardian
         res = inf["res"]
         attempt = 1
@@ -1491,16 +1560,32 @@ class LLMEngine:
         return self._fetch_launch(res)
 
     def _fetch_launch(self, res):
-        """A launch's four results and the model's counters on the host.
-        With the watchdog disarmed `_monitor.wait` returns at once and
-        THIS is where the host waits for the program: under the wait's
-        span, so that the fetch's holds the copies alone."""
+        """A launch's results on the host, from ONE fetch of its rows
+        (`_rows`: a row a slot of token, logprob, panel, the model's
+        counters): ``(tokens, logprobs, panel ids, panel logprobs)``, a
+        view each. With the watchdog disarmed `_monitor.wait` returns at
+        once and THIS is where the host waits for the program: under the
+        wait's span, so that the fetch's holds the copy alone."""
         with self._span("engine.decode.wait"):
             res[0].block_until_ready()
         with self._span("engine.decode.fetch"):
-            self._count_model("decode", res)
-            return (np.asarray(res[0]), np.asarray(res[1]),
-                    np.asarray(res[2]), np.asarray(res[3]))
+            ints = np.asarray(res[1])
+            self._stats.decode_fetched_arrays += 1
+            # every row carries the launch's counters
+            return self._read_rows("decode", ints, ints[0])
+
+    def _read_rows(self, phase, ints, counted):
+        """The reader of `_rows`, for one row or a launch's `[S, width]`:
+        ``(tokens, logprobs, panel ids, panel logprobs)``, a view each;
+        the model's counters, in the tail of the row `counted`, go to the
+        stats."""
+        topk = self._logprobs_topk
+        floats = ints.view(np.float32)
+        if self._counter_names:
+            self._stats.count_model(phase, self._counter_names,
+                                    counted[2 + 2 * topk:])
+        return (ints[..., 0], floats[..., 1], ints[..., 2:2 + topk],
+                floats[..., 2 + topk:2 + 2 * topk])
 
     def _retry_commit(self, inf, attempt, phase):
         """A commit's wait hung. True: wait once more. False: the last
@@ -1522,8 +1607,7 @@ class LLMEngine:
         self._reset_pipeline()
         if consumed:
             self._reset_kv_state()
-        self._compile_grace_ns = time.perf_counter_ns()
-        self._decode_fn = self._build_decode(use_aot=False)
+        self._rebuild_decode()
         return False
 
     def _commit_joined(self, inf, programs=None):
@@ -1565,25 +1649,20 @@ class LLMEngine:
                         return False
                     attempt += 1
             inf["joined"] = {}
-            topk = self._logprobs_topk
             for slot, (req, pos, aseq) in joined.items():
-                ints = firsts[slot]
-                floats = ints.view(np.float32)
-                if self._counter_names:
-                    self._stats.count_model("prefill", self._counter_names,
-                                            ints[2 + 2 * topk:])
+                tok, logp, aids, alps = self._read_rows(
+                    "prefill", firsts[slot], firsts[slot])
                 if (req.state != RUNNING or req.slot != slot
                         or req.admit_seq != aseq):
                     self._rollback(req, slot)
                     continue
-                tok = int(ints[0])
+                tok = int(tok)
                 self._tokens[slot] = tok
                 if pos < self.max_context:
                     self._history[slot, pos] = tok
                 self._emit_token(
-                    req, tok, logp=float(floats[1]),
-                    alts=((ints[2:2 + topk], floats[2 + topk:2 + 2 * topk])
-                          if topk else None))
+                    req, tok, logp=float(logp),
+                    alts=(aids, alps) if self._logprobs_topk else None)
         return True
 
     def _drain_joined(self):
@@ -1634,6 +1713,9 @@ class LLMEngine:
         self._override[:] = True
 
     def _reset_pipeline(self):
+        # destructive recovery: what the suspect programs handed on of
+        # the sampler's table and history is not trusted either
+        self._sampler_dev = None
         self._inflight = None
         for slot, (req, _pos, _aseq) in self._joined.items():
             self._rollback(req, slot)
@@ -1753,19 +1835,14 @@ class LLMEngine:
                 self._stats.queue_wait_hist.observe(wait_s)
                 if _metrics_on():
                     _M.queue_wait_s.observe(wait_s)
-            padded = np.zeros((1, bucket), np.int32)
-            padded[0, :len(ctx)] = ctx
-            row = np.zeros(self.max_blocks_per_seq, np.int32)
-            row[:len(req.blocks)] = req.blocks
             # launched, not awaited: what the prefill sampled stays on
             # the device as the slot's next decode input, and nothing of
             # its results is touched here
             res = self._call_program(
                 "engine.prefill.dispatch", fn,
-                self._prefill_args(padded, np.int32(len(ctx)), row, req),
-                new_bucket)
+                self._prefill_args(ctx, bucket, req), new_bucket)
             self._feedback, self._firsts = res[0], res[1]
-            self._bufs = res[2:2 + len(self._bufs)]
+            self._adopt(res)
             req.cached_len = len(ctx)
             self._sync_slot(req)
             self._set_adapter_slot(req)
@@ -1817,6 +1894,8 @@ class LLMEngine:
         self._note_prefix_rate()
         req.cached_len = hit
         self._sync_slot(req)
+        # no prefill writes this slot's rows on the device
+        self._sampler_dev = None
         self._set_adapter_slot(req)
         # decode input: the first token WITHOUT cached KV; the known
         # tokens after it queue as chew (fed, never emitted)
@@ -1848,22 +1927,66 @@ class LLMEngine:
         width = 2 + 2 * self._logprobs_topk + len(self._counter_names)
         return jnp.zeros((self.max_batch_size, width), jnp.int32)
 
-    def _prefill_args(self, padded, length, row, req):
-        """A prefill program's positional arguments. The admitted
-        request's sampler config rides as scalar VALUES (a new config
-        never re-keys the bucket program); `feedback` and `firsts` are
+    def _prefill_args(self, ctx, bucket, req):
+        """A prefill program's positional arguments: ONE host array (the
+        padded ids, the request's block row, then length, slot and the
+        request's sampler values as the table's row, floats by their
+        bits: VALUES, so a new config never re-keys the bucket program;
+        `_unpack_prefill` is its reader) and what is on the device.
+        `feedback`, `firsts`, the sampler's table and the history are
         threaded through the program as the pools are, and not donated:
-        the commit of the launch before still reads them."""
-        base = (padded, length, row)
+        the commit of the launch before still reads the first two."""
+        m = self.max_blocks_per_seq
+        packed = np.zeros(bucket + m + _PREFILL_TAIL, np.int32)
+        packed[:len(ctx)] = ctx
+        packed[bucket:bucket + len(req.blocks)] = req.blocks
+        tail = packed[bucket + m:]
+        tail[0], tail[1] = len(ctx), req.slot
+        tail[2:7] = self._sampler_row(
+            req.temperature, req.top_k, req.top_p, req.repetition_penalty,
+            req.seed or 0)
+        base = (packed,)
         if self._tenant:
             base = base + (self._prefill_aux(req),)
-        feedback = self._tokens.copy() if self._feedback is None \
+        return base + (self._fed(), self._firsts) + self._sampler_state() \
+            + self._bufs
+
+    def _fed(self):
+        """The sampled tokens as the device holds them, for the next
+        program's `feedback`."""
+        return self._no_feedback if self._feedback is None \
             else self._feedback
-        return base + (
-            np.float32(req.temperature), np.int32(req.top_k),
-            np.float32(req.top_p), np.float32(req.repetition_penalty),
-            np.uint32(req.seed or 0), np.int32(req.slot), feedback,
-            self._firsts) + self._bufs
+
+    @staticmethod
+    def _sampler_row(temperature, top_k, top_p, repetition_penalty, seed):
+        """A request's five sampler values as the table holds them:
+        int32, the floats (float32) by their bits, the seed's 32 bits."""
+        row = np.empty(5, np.int32)
+        row.view(np.float32)[[0, 2, 3]] = (temperature, top_p,
+                                           repetition_penalty)
+        row[1] = top_k
+        row.view(np.uint32)[4] = seed
+        return row
+
+    def _sampler_state(self):
+        """The sampler's table and the history for a program's
+        arguments: the device's, or, when they are stale, the host's
+        record itself (copies: the program reads them when it runs),
+        which the program hands back as the device's."""
+        if self._sampler_dev is not None:
+            return self._sampler_dev
+        table = np.empty((self.max_batch_size, 5), np.int32)
+        for col, record in enumerate((self._temps, self._topks, self._topps,
+                                      self._rpens, self._seeds)):
+            table[:, col] = record.view(np.int32)
+        return table, self._history.copy()
+
+    def _adopt(self, res):
+        """Take a program's threaded results for the next call: the
+        sampler's table, the history and the cache's buffers (behind the
+        two results that are the program's own)."""
+        self._sampler_dev = res[2:4]
+        self._bufs = res[4:4 + len(self._bufs)]
 
     def _kv_args(self, *base):
         """Positional args for the compiled decode/prefill programs:
@@ -1916,6 +2039,9 @@ class LLMEngine:
         return out
 
     def _sync_slot(self, req):
+        """An admitted request into its slot of the host's arrays: what a
+        launch is handed (table, length, active) and the RECORD of what
+        the device holds of it (sampler values, history)."""
         slot = req.slot
         row = np.zeros(self.max_blocks_per_seq, np.int32)
         row[:len(req.blocks)] = req.blocks
@@ -2087,31 +2213,34 @@ class LLMEngine:
                 self._degrade("decode_fault", {"injected": True})
                 self._recover_with_fallback(rebuild=False)
                 return None
-            self._bufs = res[4:4 + len(self._bufs)]
+            self._adopt(res)
             self._maybe_store_decode()
             return self._fetch_launch(res)
-
-    # where `_decode_args` puts the lengths and the mask of active slots
-    _ARG_LENS, _ARG_ACTIVE = 4, 5
 
     def _decode_args(self):
         """The decode program's positional arguments, from the engine's
         own buffers — the single source of truth shared by both tails,
-        the AOT spec builder and the tests that lower the program.
-        `feedback` is the previous launch's sampled tokens where they are,
-        on the device; the program takes a slot's input from it unless
-        `override` says the host wrote the slot's token (admission, chew,
-        restore). With no launch to feed from every slot is overridden
-        and the host's tokens stand in for it."""
-        feedback = self._tokens if self._feedback is None \
-            else self._feedback
-        base = (self._tokens, feedback, self._override, self._tables,
-                self._lens, self._active)
+        the AOT spec builder and the tests that lower the program. ONE
+        host array, made anew every call: `[S, max_blocks_per_seq + 4]`,
+        the block tables and the columns `_COL_TOKENS`, `_COL_OVERRIDE`,
+        `_COL_LENS`, `_COL_ACTIVE` behind them (`_unpack_slots` is its
+        reader). `feedback` is the previous launch's sampled tokens where
+        they are, on the device; the program takes a slot's input from it
+        unless `override` says the host wrote the slot's token
+        (admission, chew, restore). With no launch to feed from every
+        slot is overridden. The sampler's table and the history are the
+        device's (`_sampler_state`)."""
+        m = self.max_blocks_per_seq
+        slots = np.empty((self.max_batch_size, m + 4), np.int32)
+        slots[:, :m] = self._tables
+        slots[:, m + _COL_TOKENS] = self._tokens
+        slots[:, m + _COL_OVERRIDE] = self._override
+        slots[:, m + _COL_LENS] = self._lens
+        slots[:, m + _COL_ACTIVE] = self._active
+        base = (slots, self._fed())
         if self._tenant:
             base = base + (self._decode_aux(),)
-        return base + (
-            self._temps, self._topks, self._topps, self._rpens,
-            self._seeds, self._history) + self._bufs
+        return base + self._sampler_state() + self._bufs
 
     def _call_decode(self, args):
         fn = self._decode_fn
@@ -2119,21 +2248,16 @@ class LLMEngine:
         stats.launches += 1
         stats.launches_overlapped += self._inflight is not None
         stats.prefills_unawaited += len(self._joined)
-        stats.decode_tokens += int(np.count_nonzero(args[self._ARG_ACTIVE]))
+        m = self.max_blocks_per_seq
+        lens = args[0][:, m + _COL_LENS]
+        active = args[0][:, m + _COL_ACTIVE] != 0
+        stats.decode_tokens += int(np.count_nonzero(active))
         res = self._call_program("engine.decode.dispatch", fn, args,
                                  fn is not self._decode_called)
         self._decode_called = fn
         # counted behind the dispatch, beside the device
-        self._count_attention(args[self._ARG_LENS],
-                              args[self._ARG_ACTIVE])
+        self._count_attention(lens, active)
         return res
-
-    def _count_model(self, phase, res):
-        """A program's last result, where the model counts: its counters
-        for the stats, read in the same turn as the program's tokens."""
-        if self._counter_names:
-            self._stats.count_model(phase, self._counter_names,
-                                    np.asarray(res[-1]))
 
     def _count_attention(self, lens, active):
         """One decode launch's attention in block-table entries, from
@@ -2228,8 +2352,7 @@ class LLMEngine:
                 self._fail(req, "step_hang")
             if consumed:
                 self._reset_kv_state()
-            self._compile_grace_ns = time.perf_counter_ns()
-            self._decode_fn = self._build_decode(use_aot=False)
+            self._rebuild_decode()
             return False
         if attempt == 1:
             # rung 1: transient host/device hiccup — retry the same
@@ -2240,8 +2363,7 @@ class LLMEngine:
             # (the retrace is honest: decode_compiles counts it, the
             # degrade event explains it)
             self._degrade("step_hang", {"rung": "rebuild"})
-            self._compile_grace_ns = time.perf_counter_ns()
-            self._decode_fn = self._build_decode(use_aot=False)
+            self._rebuild_decode()
         return True
 
     def _recover_with_fallback(self, rebuild):
@@ -2254,8 +2376,7 @@ class LLMEngine:
         if self._pools_consumed():
             self._reset_kv_state()
         if rebuild:
-            self._compile_grace_ns = time.perf_counter_ns()
-            self._decode_fn = self._build_decode(use_aot=False)
+            self._rebuild_decode()
 
     def _fallback_eager(self, req):
         """Finish one request via model.generate() from its prompt +
@@ -2310,6 +2431,7 @@ class LLMEngine:
         self._rpens = np.ones(s, np.float32)
         self._seeds = np.zeros(s, np.uint32)
         self._history = np.zeros((s, self.max_context), np.int32)
+        self._sampler_dev = None
         self._inflight = None
         self._feedback = None
         self._override = np.ones(s, bool)
@@ -2418,6 +2540,9 @@ class LLMEngine:
                          detail={"generated": len(req.generated),
                                  "remaining": req.remaining_tokens})
             restored.append(req)
+        # a process restored is one whose device state nobody vouches
+        # for: the next program call is handed the host's record
+        self._sampler_dev = None
         self._next_rid = max(self._next_rid,
                              int(payload.get("next_rid") or 0))
         self._weight_epoch = max(self._weight_epoch,
@@ -2480,8 +2605,9 @@ class LLMEngine:
                  # history buffer width all change the executable
                  ("sampler", SAMPLER_VERSION, self._logprobs_topk,
                   self.max_context),
-                 # the input select (tokens, feedback, override)
-                 "feedback"))
+                 # the protocol of a call: one packed array each way, the
+                 # sampler's table and history on the device
+                 "packed"))
         except Exception:
             dg = None
         self._aot_digest_cache = dg or ""
@@ -2530,6 +2656,70 @@ class LLMEngine:
                  if self._counter_names else ())
         return logits, caches, extra
 
+    def _unpack_slots(self, slots, feedback):
+        """The reader of a launch's packed array (`_decode_args`), inside
+        a decode program's trace: ``(tokens, tables, lens, active)``. A
+        slot's input is the token the launch before sampled for it,
+        still on the device, unless the host wrote one."""
+        m = self.max_blocks_per_seq
+        tokens = jnp.where(slots[:, m + _COL_OVERRIDE] != 0,
+                           slots[:, m + _COL_TOKENS], feedback)
+        return (tokens, slots[:, :m], slots[:, m + _COL_LENS],
+                slots[:, m + _COL_ACTIVE] != 0)
+
+    @staticmethod
+    def _sampler_columns(table):
+        """The sampler's table (rows of `_sampler_row`) as
+        `sample_tokens`' five arguments, each value the bits it was
+        stored with."""
+        def real(col):
+            return jax.lax.bitcast_convert_type(table[:, col], jnp.float32)
+        return (real(0), table[:, 1], real(2), real(3),
+                jax.lax.bitcast_convert_type(table[:, 4], jnp.uint32))
+
+    def _decode_results(self, logits, tokens, lens, active, sampler,
+                        history, view, extra):
+        """What both decode programs do behind the model's forward,
+        inside their trace: the launch's tokens sampled and handed on
+        where they are. Returns ``(tokens, rows, sampler, history) + the
+        written buffers``: the sampled tokens stay a result of their own
+        on the device (the next launch's `feedback`), `rows` is the ONE
+        array the host fetches (`_rows`), the table and the history are
+        threaded on."""
+        # the in-graph history scatter: the input token enters the
+        # context at index `lens` — under pipelined decode it may exist
+        # ONLY on-device (feedback). The history lives on the device, so
+        # the scatter stands: an active slot's row holds its context up
+        # to `lens` whatever the host has committed
+        rows = jnp.arange(tokens.shape[0], dtype=jnp.int32)
+        idx = jnp.clip(lens, 0, history.shape[1] - 1)
+        hist = history.at[rows, idx].set(
+            jnp.where(active, tokens, history[rows, idx]))
+        valid = (jnp.arange(history.shape[1], dtype=jnp.int32)[None, :]
+                 <= lens[:, None])
+        # a slot that is not active reads the row that samples nothing,
+        # whatever its last request left in the table: a cleared slot
+        # uploads nothing and still keeps the batch on the greedy branch
+        table = jnp.where(active[:, None], sampler,
+                          jnp.asarray(_SAMPLER_NOOP)[None, :])
+        # sampling position = known context tokens = lens + 1; every
+        # replay (preempt re-prefill, rebuild, kill-9 resume)
+        # restores the same positions -> byte-identical streams
+        nxt, logp, alt_ids, alt_lps = sample_tokens(
+            logits._value[:, -1, :], *self._sampler_columns(table),
+            lens + 1, hist, valid, logprobs_topk=self._logprobs_topk)
+        return (nxt, self._rows(nxt, logp, alt_ids, alt_lps, extra),
+                sampler, hist) + self._written(view)
+
+    def _rebuild_decode(self):
+        """The watchdog's rebuild: a FRESH trace in the suspect program's
+        place (never the stored bytes, which may embody the fault), fed
+        from the host's record: what the old one handed on of the
+        sampler's table and the history is suspect with it."""
+        self._compile_grace_ns = time.perf_counter_ns()
+        self._sampler_dev = None
+        self._decode_fn = self._build_decode(use_aot=False)
+
     def _build_decode(self, use_aot=True):
         if self._tenant:
             # the aux-input program: weights/adapters as values. AOT
@@ -2537,39 +2727,21 @@ class LLMEngine:
             # tenant replicas always trace once at start
             return self._build_decode_tenant()
         stats = self._stats
-        lp_topk = self._logprobs_topk
 
-        def decode(tokens, feedback, override, tables, lens, active,
-                   temps, topks, topps, rpens, seeds, history, k_pools,
-                   v_pools, *more):
+        def decode(slots, feedback, sampler, history, k_pools, v_pools,
+                   *more):
             stats.decode_compiles += 1   # runs only while tracing
-            # a slot's input is the token the launch before sampled for
-            # it, still on the device, unless the host wrote one
-            tokens = jnp.where(override, tokens, feedback)
+            tokens, tables, lens, active = self._unpack_slots(slots,
+                                                               feedback)
             # ONE view over the stacked (donated) pools: every layer
             # writes at its own index and hands them on, so the pools
             # the last layer returns are the step's, updated in place
             view = self._view(k_pools, v_pools, tables, lens, active, more)
             logits, (view,), extra = self._forward(tokens[:, None], [view])
-            # the in-graph history scatter: the input token enters the
-            # context at index `lens` — under pipelined decode it may
-            # exist ONLY on-device (feedback), so the host mirror cannot
-            # be trusted to contain it
-            rows = jnp.arange(tokens.shape[0], dtype=jnp.int32)
-            idx = jnp.clip(lens, 0, history.shape[1] - 1)
-            hist = history.at[rows, idx].set(tokens)
-            valid = (jnp.arange(history.shape[1], dtype=jnp.int32)[None, :]
-                     <= lens[:, None])
-            # sampling position = known context tokens = lens + 1; every
-            # replay (preempt re-prefill, rebuild, kill-9 resume)
-            # restores the same positions -> byte-identical streams
-            nxt, logp, alt_ids, alt_lps = sample_tokens(
-                logits._value[:, -1, :], temps, topks, topps, rpens,
-                seeds, lens + 1, hist, valid, logprobs_topk=lp_topk)
-            return (nxt, logp, alt_ids, alt_lps) + self._written(view) \
-                + extra
+            return self._decode_results(logits, tokens, lens, active,
+                                        sampler, history, view, extra)
 
-        donate = self._donated(12)
+        donate = self._donated(4)
         jitted = jax.jit(decode, donate_argnums=donate)
         from ..ops import aot_cache as _aot
         if use_aot and _aot.enabled():
@@ -2603,13 +2775,12 @@ class LLMEngine:
         stats = self._stats
         params = model.parameters()
         holder = self._holder
-        lp_topk = self._logprobs_topk
 
-        def decode(tokens, feedback, override, tables, lens, active, aux,
-                   temps, topks, topps, rpens, seeds, history, k_pools,
+        def decode(slots, feedback, aux, sampler, history, k_pools,
                    v_pools, *more):
             stats.decode_compiles += 1   # runs only while tracing
-            tokens = jnp.where(override, tokens, feedback)
+            tokens, tables, lens, active = self._unpack_slots(slots,
+                                                               feedback)
             pvals = aux.get("params")
             saved = None
             if pvals is not None:
@@ -2630,34 +2801,40 @@ class LLMEngine:
                         pp._value = vv
                 if holder is not None:
                     holder["active"] = None
-            rows = jnp.arange(tokens.shape[0], dtype=jnp.int32)
-            idx = jnp.clip(lens, 0, history.shape[1] - 1)
-            hist = history.at[rows, idx].set(tokens)
-            valid = (jnp.arange(history.shape[1], dtype=jnp.int32)[None, :]
-                     <= lens[:, None])
-            nxt, logp, alt_ids, alt_lps = sample_tokens(
-                logits._value[:, -1, :], temps, topks, topps, rpens,
-                seeds, lens + 1, hist, valid, logprobs_topk=lp_topk)
-            return (nxt, logp, alt_ids, alt_lps) + self._written(view) \
-                + extra
+            return self._decode_results(logits, tokens, lens, active,
+                                        sampler, history, view, extra)
 
-        return jax.jit(decode, donate_argnums=self._donated(13))
+        return jax.jit(decode, donate_argnums=self._donated(5))
 
-    def _prefill_results(self, ids, length, block_row, sampler, slot,
-                         feedback, firsts, k_pools, v_pools, more, logits,
-                         caches, extra):
+    def _unpack_prefill(self, packed):
+        """The reader of a prefill's packed array (`_prefill_args`),
+        inside a prefill program's trace: ``(ids [1, bucket], length,
+        block row, slot, the request's row of the sampler's table)``."""
+        m = self.max_blocks_per_seq
+        bucket = packed.shape[0] - m - _PREFILL_TAIL
+        tail = packed[bucket + m:]
+        return (packed[None, :bucket], tail[0], packed[bucket:bucket + m],
+                tail[1], tail[2:7])
+
+    def _prefill_results(self, ids, length, block_row, row, slot,
+                         feedback, firsts, sampler, history, k_pools,
+                         v_pools, more, logits, caches, extra):
         """What both prefill programs do behind the model's forward,
         inside their trace: the prompt's KV into the pools, the first
         token sampled, and the token handed on WHERE IT IS: into
         `feedback` at the request's slot (the next decode launch's
         input) and, with its logprob, its panel and the model's counters,
         as one int32 row of `firsts` (floats by their bits), which the
-        host fetches once for all of a boundary's prefills. Where the
+        host fetches once for all of a boundary's prefills. The request's
+        `row` of sampler values goes into the `sampler` table at its
+        slot, and its ids into the slot's row of `history` (ids below
+        `length`, zeros above: what `_sync_slot` records on the host), so
+        that the launches find both on the device. Where the
         model keeps a per-slot state, the forward's caches end with one
         state a layer that keeps one, as it stands after the prompt's
         `length`: written WHOLE at the request's slot (`CacheSpec`'s
         rule: a reused slot needs no clearing). Returns ``(feedback,
-        firsts) + the written buffers``."""
+        firsts, sampler, history) + the written buffers``."""
         k_scales, v_scales, slot_state, rings = self._split_more(more)
         spec = self.cache.spec
         paged = caches[:spec.num_layers]
@@ -2689,27 +2866,43 @@ class LLMEngine:
         valid = (jnp.arange(ids.shape[1], dtype=jnp.int32)
                  < length)[None, :]
         nxt, logp, alt_ids, alt_lps = sample_tokens(
-            last[None, :], *(jnp.reshape(v, (1,)) for v in sampler),
+            last[None, :], *self._sampler_columns(row[None, :]),
             jnp.reshape(length, (1,)), ids.astype(jnp.int32), valid,
             logprobs_topk=self._logprobs_topk)
-
+        # the slot's history as a launch reads it: the context's ids,
+        # zeros behind them
+        width = min(ids.shape[1], history.shape[1])
+        known = jnp.zeros(history.shape[1], jnp.int32).at[:width].set(
+            jnp.where(valid[0, :width], ids[0, :width], 0))
         return self._hand_on(feedback, firsts, slot, nxt, logp, alt_ids,
-                             alt_lps, extra) + written
+                             alt_lps, extra) \
+            + (sampler.at[slot].set(row), history.at[slot].set(known)) \
+            + written
 
     @staticmethod
     def _hand_on(feedback, firsts, slot, nxt, logp, alt_ids, alt_lps,
                  extra=()):
         """`feedback` with the sampled token at `slot`, and `firsts`
-        with the slot's row: token, logprob, the panel's ids and
-        logprobs, the model's int32 counters (floats by their bits)."""
+        with the slot's row (`_rows`)."""
+        return (feedback.at[slot].set(nxt[0].astype(feedback.dtype)),
+                firsts.at[slot].set(LLMEngine._rows(
+                    nxt, logp, alt_ids, alt_lps, extra)[0]))
+
+    @staticmethod
+    def _rows(nxt, logp, alt_ids, alt_lps, extra=()):
+        """What the programs hand the host of the tokens they sampled,
+        one int32 row a token: token, logprob, the panel's ids and
+        logprobs, the model's int32 counters of the call in every row
+        (floats by their bits). A prefill's one row goes into `firsts`
+        at its slot; a launch's `[S, width]` is its second result."""
         def bits(x):
             return jax.lax.bitcast_convert_type(
                 x.astype(jnp.float32), jnp.int32)
-        row = jnp.concatenate(
-            [nxt, bits(logp), alt_ids[0], bits(alt_lps[0])]
-            + [jnp.reshape(c, (-1,)) for c in extra]).astype(jnp.int32)
-        return (feedback.at[slot].set(nxt[0].astype(feedback.dtype)),
-                firsts.at[slot].set(row))
+        n = nxt.shape[0]
+        return jnp.concatenate(
+            [nxt[:, None], bits(logp)[:, None], alt_ids, bits(alt_lps)]
+            + [jnp.broadcast_to(jnp.reshape(c, (1, -1)), (n, c.size))
+               for c in extra], axis=1).astype(jnp.int32)
 
     def _build_prefill(self, bucket):
         if self._tenant:
@@ -2719,18 +2912,18 @@ class LLMEngine:
         dt = params[0]._value.dtype if params else jnp.float32
         stats = self._stats
 
-        def prefill(ids, length, block_row, temp, topk, topp, rpen,
-                    seedv, slot, feedback, firsts, k_pools, v_pools,
-                    *more):
+        def prefill(packed, feedback, firsts, sampler, history, k_pools,
+                    v_pools, *more):
             stats.prefill_compiles += 1   # runs only while tracing
+            ids, length, block_row, slot, row = self._unpack_prefill(packed)
             logits, caches, extra = self._forward(
                 ids, spec.empty_prefill(dt), length)
             return self._prefill_results(
-                ids, length, block_row, (temp, topk, topp, rpen, seedv),
-                slot, feedback, firsts, k_pools, v_pools, more, logits,
-                caches, extra)
+                ids, length, block_row, row, slot, feedback, firsts,
+                sampler, history, k_pools, v_pools, more, logits, caches,
+                extra)
 
-        return jax.jit(prefill, donate_argnums=self._donated(11))
+        return jax.jit(prefill, donate_argnums=self._donated(5))
 
     def _build_prefill_tenant(self, bucket):
         """Tenant twin of `_build_prefill`: the same bucketed prompt
@@ -2742,10 +2935,10 @@ class LLMEngine:
         stats = self._stats
         holder = self._holder
 
-        def prefill(ids, length, block_row, aux, temp, topk, topp, rpen,
-                    seedv, slot, feedback, firsts, k_pools, v_pools,
-                    *more):
+        def prefill(packed, aux, feedback, firsts, sampler, history,
+                    k_pools, v_pools, *more):
             stats.prefill_compiles += 1   # runs only while tracing
+            ids, length, block_row, slot, row = self._unpack_prefill(packed)
             pvals = aux.get("params")
             saved = None
             if pvals is not None:
@@ -2765,11 +2958,11 @@ class LLMEngine:
                 if holder is not None:
                     holder["active"] = None
             return self._prefill_results(
-                ids, length, block_row, (temp, topk, topp, rpen, seedv),
-                slot, feedback, firsts, k_pools, v_pools, more, logits,
-                caches, extra)
+                ids, length, block_row, row, slot, feedback, firsts,
+                sampler, history, k_pools, v_pools, more, logits, caches,
+                extra)
 
-        return jax.jit(prefill, donate_argnums=self._donated(12))
+        return jax.jit(prefill, donate_argnums=self._donated(6))
 
     # ------------------------------------------------------------------
     # multi-tenant serving (PR 17, serving/tenancy.py)

@@ -689,12 +689,10 @@ def test_sampling_scatter_head_and_loss_are_scoped():
     for scope in ("paged_attention", "paged_kv_write", "sample_tokens",
                   "lm_head"):
         assert scope in text, scope
-    prefill = engine._prefill_fns[8].lower(*engine._kv_args(
-        np.zeros((1, 8), np.int32), np.int32(5),
-        np.zeros(engine.max_blocks_per_seq, np.int32), np.float32(0),
-        np.int32(0), np.float32(1), np.float32(1), np.uint32(0),
-        np.int32(0), engine._tokens, engine._firsts,
-        engine._k_pools, engine._v_pools))
+    req = engine.add_request(PROMPTS[0], max_new_tokens=2)
+    req.slot, req.blocks = 0, []
+    prefill = engine._prefill_fns[8].lower(
+        *engine._prefill_args(req.prompt, 8, req))
     assert "scatter_prefill" in prefill.as_text(debug_info=True)
     from paddle_tpu.incubate.models import GPTPretrainingCriterion
     crit = GPTPretrainingCriterion()

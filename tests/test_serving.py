@@ -373,8 +373,9 @@ class TestBacklog:
         tenants, joined, fed, awaited = {}, [], [], []
 
         def on_launch(engine, args):
-            tokens, feedback, override = args[:3]
-            active = args[engine._ARG_ACTIVE]
+            slots, feedback = args[:2]
+            tokens, override, _, active = slots[
+                :, engine.max_blocks_per_seq:].T
             for req in engine.scheduler.running:
                 slot = req.slot
                 if not active[slot]:
