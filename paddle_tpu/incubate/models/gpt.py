@@ -11,7 +11,10 @@ TPU-first design:
   - hybrid parallelism is expressed as NamedShardings over the global mesh
     (`shard_gpt`): embedding/vocab and qkv/ffn columns on the "model" axis,
     activations on "data" (+ sequence on "sep" when present) — XLA inserts the
-    Megatron collectives;
+    Megatron collectives. The fused qkv columns read [3, H, Dh], so their
+    contiguous column shards are NOT heads: the training path moves the
+    weight to a split by heads in front of the product (`_qkv_by_heads`),
+    so that no activation crosses the "model" axis for it;
   - everything trains through one jitted step (paddle_tpu.jit.TrainStep or
     the sharded variant in __graft_entry__).
 """
@@ -82,6 +85,99 @@ def gpt3_6p7b(**overrides):
                         **overrides})
 
 
+def _head_split_mesh(weight, heads):
+    """The key of the global mesh when the fused qkv product has to be taken
+    against the weight moved to heads (`_qkv_by_heads`), else None: the
+    weight is one `shard_gpt` split over a "model" axis wider than one
+    (`is_distributed`, the mark the reference's mp layers leave on such a
+    weight, which a trace keeps where it hides the value's sharding), the
+    heads divide by that axis, and the call is not inside a manual region
+    (there a value is one shard already, and nothing is constrained)."""
+    from ...distributed.fleet.meta_parallel.mp_ops import in_spmd_axis
+    from ...distributed.mesh import current_mesh, mesh_key
+    mesh = current_mesh()
+    if mesh is None or not getattr(weight, "is_distributed", False):
+        return None
+    width = mesh.shape.get("model", 1)
+    if width <= 1 or heads % width or \
+            any(in_spmd_axis(a) for a in mesh.axis_names):
+        return None
+    return mesh_key(mesh)
+
+
+def _qkv_by_heads(x, proj, heads, mkey):
+    """q, k and v of `proj(x)`, each [B, N, H, Dh] and split by HEADS over
+    "model".
+
+    `shard_gpt` stores the fused weight [D, 3D] in contiguous column shards
+    of the "model" axis. The columns read [3, H, Dh], so with two shards
+    the first holds all of q and half of k's heads and the second the rest
+    of k and all of v: a split of the columns, not of the heads, and the
+    partitioner undid it after the product by gathering the whole [B, N,
+    3D] activation (and its gradient in the backward) over the axis. Here
+    the WEIGHT is moved instead, in front of the products: each of its
+    three [D, D] column blocks (q, k, v; columns [H, Dh]) is constrained to
+    a column split of its own, which IS a split by heads (the bias the
+    same), and each is multiplied on its own, so q, k and v leave as the
+    attention's `shard_map` (`_FLASH_SPEC`) wants them and what crosses the
+    axis a layer is weight-sized: a B-th of the activation's rows. The
+    stored parameter keeps its name, shape, column order and sharding; the
+    products and their float32 sums are `F.linear`'s over the same `d`, so
+    is the op's name (AMP lists, FLOP accounting).
+
+    The backward is written out (`custom_vjp`) for one reason: it moves the
+    weight AGAIN from the stored parameter, behind a barrier that ties the
+    move to the incoming gradient. Left to autodiff the moved blocks are
+    residuals, alive from every layer's forward to its backward: a step's
+    peak higher by the whole model's qkv weights. The weight's gradient
+    goes back to the stored split the way the weight came (the move's own
+    transpose).
+
+    The mesh's key, not the mesh, rides in the closure (the op stays
+    keyable); the mesh is read back at trace time."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from ...distributed.mesh import current_mesh, mesh_key
+    from ...ops._helpers import call_op_multi
+
+    def by_heads(w, bias):
+        mesh = current_mesh()
+        if mesh_key(mesh) != mkey:
+            raise RuntimeError("the global mesh changed between the dispatch "
+                               "of the qkv projection and its trace")
+        d = w.shape[0]
+        columns, entries = (NamedSharding(mesh, P(None, "model")),
+                            NamedSharding(mesh, P("model")))
+        blocks = [slice(t * d, (t + 1) * d) for t in range(3)]
+        return ([jax.lax.with_sharding_constraint(w[:, at], columns)
+                 for at in blocks],
+                [jax.lax.with_sharding_constraint(bias[at], entries)
+                 for at in blocks])
+
+    @jax.custom_vjp
+    def fn(v, w, bias):
+        return tuple((jnp.matmul(v, wt) + bt).reshape(*v.shape[:2], heads, -1)
+                     for wt, bt in zip(*by_heads(w, bias)))
+
+    def fwd(v, w, bias):
+        return fn(v, w, bias), (v, w, bias)
+
+    def bwd(kept, grads):
+        v, w, bias = kept
+        w, first = jax.lax.optimization_barrier((w, grads[0]))
+        grads = (first,) + tuple(grads[1:])
+        (moved, _), to_stored = jax.vjp(by_heads, w, bias)
+        grads = [g.reshape(*g.shape[:2], -1) for g in grads]
+        dw, dbias = to_stored((
+            [jnp.einsum("bnd,bnc->dc", v, g) for g in grads],
+            [g.sum((0, 1), dtype=jnp.float32).astype(bias.dtype)
+             for g in grads]))
+        dv = sum(jnp.einsum("bnc,dc->bnd", g, wt)
+                 for g, wt in zip(grads, moved))
+        return dv, dw, dbias
+    fn.defvjp(fwd, bwd)
+    return call_op_multi("linear", fn, (x, proj.weight, proj.bias), 3)
+
+
 class GPTAttention(Layer):
     def __init__(self, config: GPTConfig):
         super().__init__()
@@ -99,11 +195,16 @@ class GPTAttention(Layer):
 
     def forward(self, x, cache=None):
         b, n = x.shape[0], x.shape[1]
-        qkv = self.qkv_proj(x)
-        qkv = manip.reshape(qkv, [b, n, 3, self.num_heads, self.head_dim])
-        q = manip.squeeze(manip.slice(qkv, [2], [0], [1]), 2)
-        k = manip.squeeze(manip.slice(qkv, [2], [1], [2]), 2)
-        v = manip.squeeze(manip.slice(qkv, [2], [2], [3]), 2)
+        mkey = _head_split_mesh(self.qkv_proj.weight, self.num_heads) \
+            if cache is None else None
+        if mkey is None:
+            qkv = self.qkv_proj(x)
+            qkv = manip.reshape(qkv, [b, n, 3, self.num_heads, self.head_dim])
+            q = manip.squeeze(manip.slice(qkv, [2], [0], [1]), 2)
+            k = manip.squeeze(manip.slice(qkv, [2], [1], [2]), 2)
+            v = manip.squeeze(manip.slice(qkv, [2], [2], [3]), 2)
+        else:
+            q, k, v = _qkv_by_heads(x, self.qkv_proj, self.num_heads, mkey)
         if cache is not None and hasattr(cache, "block_tables"):
             # paged serving cache (serving/cache.py PagedCacheView): the
             # continuous-batching engine's block-pool memory — sequences
@@ -581,6 +682,19 @@ def shard_gpt(model: GPTForCausalLM, mesh, dtype=None):
     (in-dim on "model"), embeddings vocab-parallel. Remaining axes are left to
     the partitioner; optimizer state inherits shardings from params and is
     further sharded over "sharding" by the sharded optimizer.
+
+    The fused qkv weight is STORED [D, 3D] in contiguous column shards, and
+    its columns read [3, H, Dh]: over two shards the first holds q and half
+    of k's heads, the second the rest of k and v. Those are not heads. The
+    split by heads that attention needs is made where the weight is USED:
+    `GPTAttention.forward` (no serving cache) multiplies by each of the
+    three [D, D] blocks constrained to a column split of its own
+    (`_qkv_by_heads`), which moves weight-sized tensors a layer each way
+    and no activation. The stored name, shape, column order and sharding
+    are a checkpoint's and the optimizer's, and stay. Every parameter split
+    over a "model" axis wider than one is marked `is_distributed` (as the
+    reference's mp layers mark theirs): a trace hides a value's sharding,
+    the mark is what the model reads there.
     """
     import jax
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -614,6 +728,8 @@ def shard_gpt(model: GPTForCausalLM, mesh, dtype=None):
                 spec = s
                 break
         put(p, spec if spec is not None else P())
+        p.is_distributed = spec is not None and "model" in spec \
+            and mesh.shape.get("model", 1) > 1
     return model
 
 

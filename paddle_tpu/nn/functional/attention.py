@@ -38,8 +38,8 @@ def _flash_partition(batch, heads):
     """How a [B, N, H, D] Mosaic kernel has to be called here. The TPU
     lowering refuses to partition a Mosaic kernel automatically, so under
     a global multi-device mesh the kernel runs per shard through
-    `shard_map`: batch over the data axes, heads over "model" (the
-    Megatron split `shard_gpt` gives qkv). Returns None to call it
+    `shard_map`: batch over the data axes, heads over "model" (where
+    `gpt._qkv_by_heads` leaves q, k and v). Returns None to call it
     directly (no mesh, or already inside a manual region that binds every
     sharded axis), the mesh's key to wrap it, or False when it cannot run
     (shapes that do not divide, or a partly manual region)."""
