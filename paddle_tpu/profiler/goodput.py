@@ -385,8 +385,8 @@ class GoodputAccountant:
 
     def drop_stall_carry(self):
         """Forget the pending stall subtraction: the measurement the
-        stall was inside never completed (watchdog rung 3 / eager
-        fallback retired the step), so the NEXT productive interval —
+        stall was inside never completed (the watchdog's fail-active /
+        eager fallback retired the step), so the NEXT productive interval —
         which does not contain the stall — must be booked whole."""
         self._stalled_extra = 0.0
 

@@ -72,10 +72,8 @@ Resilience (PR 7, serving/resilience.py) rides every one of those layers:
     monitored completion bounded by `FLAGS_serve_step_timeout_ms`; a
     stuck step emits `serve.hang`, marks the engine degraded, and climbs
     a recovery ladder instead of wedging: a wait at a commit (a
-    launch's in the pipelined loop, a boundary's prefills' in both) is
-    retried once and then fails the active requests with attributed
-    reasons; the serial loop's decode step is retried, its executable
-    rebuilt, then the active requests fail;
+    launch's, or a boundary's prefills') is retried once and then fails
+    the active requests with attributed reasons;
   * **degraded-mode fallback** — a faulting/poisoned compiled decode
     finishes its in-flight streams per-request through the eager
     `generate()` path, token-identically, then rebuilds;
@@ -136,7 +134,7 @@ from ..kernels.pallas.paged_attention import (blockwise_streamed_entries,
                                                pallas_copied_pages)
 from .cache import (PagedKVCache, PagedCacheView, scatter_prefill,
                     scatter_window_prefill, _is_int8)
-from .scheduler import (Request, Scheduler, QUEUED, RUNNING, FINISHED,
+from .scheduler import (Request, Scheduler, RUNNING, FINISHED,
                         FAILED, CANCELLED, EXPIRED)
 from .resilience import (ServeRefusal, MonitoredWait, StepHang,
                          request_payload, payload_request)
@@ -330,13 +328,12 @@ class ServeStats:
         self.commit_rollbacks = 0
         # decode launches, and those issued while the launch before them
         # was still uncommitted: the host's turn after such a launch ran
-        # beside the device (the serial tail never has one in flight)
+        # beside the device
         self.launches = 0
         self.launches_overlapped = 0
         # prefills whose results the host had not touched when the decode
-        # launch that feeds on their tokens was dispatched (none in the
-        # serial loop, which commits a boundary's prefills before it
-        # launches; nor a bucket's first call)
+        # launch that feeds on their tokens was dispatched (not a bucket's
+        # first call, which is committed where it stands)
         self.prefills_unawaited = 0
         # decode attention, in block-table entries summed over the decode
         # launches: what the attention's loop reads or its kernel copies,
@@ -634,9 +631,9 @@ class LLMEngine:
     copy-on-write — N streams sharing a system prompt pay its prefill
     and its KV bytes once.
 
-    The loop is pipelined at lag 1 (`pipeline_decode=True`, the default):
-    a `step()` launches decode N+1 from launch N's tokens where they are,
-    on the device, and only then commits launch N, so the host's turn
+    The loop is pipelined at lag 1: a `step()` launches decode N+1 from
+    launch N's tokens where they are, on the device, and only then
+    commits launch N, so the host's turn
     (callbacks, retirement, the caller's own work between steps, the next
     admission) runs beside the decode program and not after it. For a
     caller that means: a token's `on_token` runs one `step()` after the
@@ -647,9 +644,9 @@ class LLMEngine:
     tokens it had in flight (`stats()["commit_rollbacks"]`), and one that
     ends by `max_new_tokens` loses none; a finished request's slot is refilled one
     boundary later; `step()` keeps returning True until the last launch
-    is committed. WHICH tokens are served does not change: streams are
-    token-identical to `pipeline_decode=False`, the serial loop (launch,
-    wait, commit inside one step), which the tests compare them with.
+    is committed. WHICH tokens are served is what one request at a time
+    through the model's own dense forward is served
+    (tests/serving_reference.py).
     """
 
     def __init__(self, model, max_batch_size=8, block_size=16,
@@ -658,7 +655,7 @@ class LLMEngine:
                  aging_max_preemptions=3, kv_dtype=None,
                  attention_kernel=None, enable_prefix_cache=False,
                  max_adapters=0, adapter_rank=4, hot_swap=False,
-                 logprobs_topk=0, pipeline_decode=True):
+                 logprobs_topk=0):
         cfg = model.config
         model.eval()
         self._model = model
@@ -781,81 +778,14 @@ class LLMEngine:
         # transitions emit serve.degrade so the flight recorder shows the
         # full degraded window)
         self.degraded = False
-        # fixed slot-layout state the compiled decode step consumes
-        s, m = self.max_batch_size, self.max_blocks_per_seq
-        self._tables = np.zeros((s, m), np.int32)
-        self._lens = np.zeros(s, np.int32)
-        self._active = np.zeros(s, bool)
-        self._tokens = np.zeros(s, np.int32)
-        # per-slot adapter index into the padded stacks (0 = base);
-        # deliberately NOT reset by _clear_slot — a stale index on an
-        # inactive slot is masked out, and clearing it would count a
-        # spurious adapter switch on the next same-tenant admission
-        self._aslots = np.zeros(s, np.int32)
-        # -- compiled stochastic sampling (PR 18, serving/sampling.py) --
-        # per-slot sampler config as fixed [S] VALUE buffers — edited
-        # like tokens/lens on join/leave, never reshaping, so arbitrary
-        # per-slot sampler churn keeps decode_compiles == 1. Greedy is
-        # temperature=0 under the same program; the no-op values below
-        # keep a cleared slot on the cheap all-greedy cond branch
         self._logprobs_topk = int(logprobs_topk)
-        self._temps = np.zeros(s, np.float32)
-        self._topks = np.zeros(s, np.int32)
-        self._topps = np.ones(s, np.float32)
-        self._rpens = np.ones(s, np.float32)
-        self._seeds = np.zeros(s, np.uint32)
-        # per-slot context-token history for the in-graph repetition
-        # penalty; positions <= lens are valid. The decode step scatters
-        # its own input token at index `lens` in-graph, so the one token
-        # the host has not committed yet (pipelined mode) is still seen
-        self._history = np.zeros((s, self.max_context), np.int32)
-        # those six are the host's RECORD (`_sync_slot` on a resume,
-        # `state_payload`); what the programs read is on the device: a
-        # `[S, 5]` int32 table of the five (floats by their bits) and the
-        # history, threaded from program to program as the pools are. A
-        # prefill writes its slot's row of both from the request's values
-        # and ids it is given anyway, a launch scatters its input tokens
-        # and masks the table by `active` (a cleared slot uploads
-        # nothing). None: stale. An edit of the record with no prefill
-        # behind it (a prefix-hit admission, `restore_state`, a KV reset,
-        # a rebuilt decode program) marks them so, and the next program
-        # call is handed the record itself, once
-        self._sampler_dev = None        # (table, history) on the device
         # how many of a kind of program's arguments are host values, by
         # whether the call uploads the record (`_call_program`)
         self._host_arity = {}
-        # -- software-pipelined decode (PR 18; the default loop) --------
-        # launch step N+1 against device-fed tokens while step N's host
-        # commit overlaps: `_inflight` holds the un-committed launch
-        # (its result arrays, and by slot the request, position and
-        # admission it decoded for), `_feedback` the device next-token
-        # array the next launch will consume, and `_override[slot]` marks
-        # slots whose HOST token (admission / chew / restore) must win
-        # over the device feedback: the decode program selects. The
-        # serial tail never clears a mark, so it feeds every slot from
-        # the host
-        self._pipeline = bool(pipeline_decode)
-        self._inflight = None
-        self._feedback = None
         # what stands in for `_feedback` while there is none (every slot
         # is overridden then): a device array, so that no call uploads one
-        self._no_feedback = jnp.zeros(s, jnp.int32)
-        self._override = np.ones(s, bool)
-        # -- prefills launched, not awaited ------------------------------
-        # a prefill writes what it sampled into `_feedback` at its slot
-        # (the next decode launch's input, on the device) and the token's
-        # host-side half (id, logprob, panel, the model's counters) as
-        # one int32 row of `_firsts`, both threaded from program to
-        # program as the pools are. `_joined` holds, by slot, the
-        # request, position and admission of the prefills dispatched
-        # since the last launch; the launch takes them into its inflight
-        # record, and the step after commits them from ONE fetch
-        self._firsts = self._empty_firsts()
-        self._joined = {}
-        # the cache's device buffers (`PagedKVCache.buffers()`: the two
-        # pools, the int8 scales, a per-slot state, whichever it has):
-        # every program takes them last, donates them and hands them back
-        self._bufs = self.cache.buffers()
+        self._no_feedback = jnp.zeros(self.max_batch_size, jnp.int32)
+        self._reset_slots()
         self._decode_fn = None
         self._prefill_fns = {}
         # AOT warm start (ops/aot_cache.py): the decode digest is computed
@@ -1144,10 +1074,8 @@ class LLMEngine:
         for KV headroom, stream the first tokens of the step before's
         prefills, LAUNCH the ONE compiled decode step for every running
         slot, then stream the tokens of the launch BEFORE it and retire
-        finished requests while the new one runs (`pipeline_decode=
-        False`: fetch this step's prefills' tokens once all are
-        dispatched, wait for its own launch and stream that). All waits
-        are the watchdog's.
+        finished requests while the new one runs. All waits are the
+        watchdog's.
         Returns True while any request is running or waiting, or a
         launch is uncommitted."""
         if self._stats.wall_t0 is None:
@@ -1234,14 +1162,8 @@ class LLMEngine:
                         continue
                     break
                 self._admit(req)
-        if not self._pipeline:
-            # the serial loop's first tokens arrive in the admitting step:
-            # the boundary's prefills, dispatched back to back, are
-            # committed here from one fetch
-            self._drain_joined()
         if not sched.running:
-            if self._pipeline:
-                self._flush_inflight()
+            self._flush_inflight()
             return bool(sched.waiting)
         with self._span("engine.kv_grow"):
             # -- KV growth, preempting (newest first) on a dry pool ----
@@ -1272,98 +1194,18 @@ class LLMEngine:
             if sched.running and self._prefix is not None:
                 self._cow_sweep()
         if not sched.running:
-            if self._pipeline:
-                self._flush_inflight()
+            self._flush_inflight()
             return bool(sched.waiting)
-        # -- software-pipelined tail: launch N+1, commit N (lag 1) -----
-        if self._pipeline:
-            return self._step_pipelined()
-        # -- the ONE compiled decode step (watchdog-monitored) ---------
-        demand = sched.demand
-        n_active = len(sched.running)
-        t0 = time.perf_counter()
-        with self._span("engine.decode"):
-            out = self._decode_step()
-        if out is None:
-            # ladder rung 3 / eager fallback retired the batch; the
-            # engine stays serviceable for queued + new work. Any stall
-            # booked inside the abandoned step must not be subtracted
-            # from the NEXT (unrelated) productive step's time
-            if _metrics_on():
-                _goodput.ACCOUNTANT.drop_stall_carry()
-            return bool(sched.running or sched.waiting)
-        dt = time.perf_counter() - t0
-        self._stats.observe_step(n_active, self.max_batch_size, demand, dt)
-        self._hb_ns = time.perf_counter_ns()
-        # a completed step means any pending compile finished: the
-        # /healthz grace window closes and staleness reverts to the
-        # watchdog budget
-        self._compile_grace_ns = None
-        _telemetry.beat("decode", step=self._stats.steps)
-        _sentinel.tick()
-        if _metrics_on():
-            _M.step_s.observe(dt)
-            _M.occupancy.set(n_active / self.max_batch_size)
-            # productive serving time: the goodput fraction stays
-            # meaningful in a process that never crosses an optimizer
-            # boundary (stall time lands via the watchdog's note_stall)
-            _goodput.ACCOUNTANT.note_productive(dt)
-        if _EVENTS.enabled:
-            _EVENTS.emit("serve.step", "engine",
-                         detail={"active": n_active,
-                                 "occupancy": round(
-                                     n_active / self.max_batch_size, 4),
-                                 "ms": round(dt * 1e3, 4)})
-        if self.degraded:
-            # first clean decode step after a hang/fault: recovered
-            self.degraded = False
-            _EVENTS.emit("serve.degrade", "engine",
-                         detail={"recovered": True})
-        # -- stream + retire -------------------------------------------
-        toks, logps, aids, alps = out
-        with self._span("engine.stream"):
-            for req in list(sched.running):
-                if req.finished or req.slot is None:
-                    # retired mid-loop (a streaming callback cancelled
-                    # it); its token from this launch is dropped on the
-                    # floor
-                    continue
-                slot = req.slot
-                req.cached_len += 1
-                self._lens[slot] = req.cached_len
-                if req.chew:
-                    # prefix-hit warm-up: the next context token is
-                    # already KNOWN — feed it as the next decode input and
-                    # drop the prediction (made from a mid-context
-                    # position, it is not this stream's next output token)
-                    t = req.chew.pop(0)
-                    self._tokens[slot] = t
-                    if req.cached_len < self.max_context:
-                        self._history[slot, req.cached_len] = t
-                    continue
-                tok = int(toks[slot])
-                self._tokens[slot] = tok
-                if req.cached_len < self.max_context:
-                    self._history[slot, req.cached_len] = tok
-                self._emit_token(req, tok, logp=float(logps[slot]),
-                                 alts=((aids[slot], alps[slot])
-                                       if self._logprobs_topk else None))
-        return bool(sched.running or sched.waiting)
-
-    # ------------------------------------------------------------------
-    # software-pipelined decode (PR 18): launch N+1, commit N at lag 1
-    # ------------------------------------------------------------------
-    def _step_pipelined(self):
-        """Pipelined tail of one iteration: commit the first tokens of
-        the prefills the previous launch was queued behind, LAUNCH this
-        step's decode against device-fed tokens (the previous launch's
-        sampled ids, and this boundary's prefills', feed back as a
-        device array — no host round-trip), then COMMIT the previous
-        launch's host work (detokenize, callbacks, retirement) while the
-        device runs the new one. Steady-state step
-        time is max(device, host-commit) instead of their sum, and the
-        watchdog's monitored wait only ever covers device time."""
-        sched = self.scheduler
+        # -- the tail: launch N+1, commit N (lag 1) --------------------
+        # commit the first tokens of the prefills the launch before was
+        # queued behind, LAUNCH this step's decode against device-fed
+        # tokens (the launch before's sampled ids, and this boundary's
+        # prefills', feed back as a device array: no host round-trip),
+        # then COMMIT the launch before's host work (detokenize,
+        # callbacks, retirement) while the device runs the new one. A
+        # steady-state step takes max(device, host commit) and not their
+        # sum, and the watchdog's monitored wait only ever covers device
+        # time
         demand = sched.demand
         n_active = len(sched.running)
         t0 = time.perf_counter()
@@ -1395,21 +1237,27 @@ class LLMEngine:
         self._stats.observe_step(n_active, self.max_batch_size, demand,
                                  dt)
         self._hb_ns = time.perf_counter_ns()
+        # a completed step means any pending compile finished: the
+        # /healthz grace window closes and staleness reverts to the
+        # watchdog budget
         self._compile_grace_ns = None
         _telemetry.beat("decode", step=self._stats.steps)
         _sentinel.tick()
         if _metrics_on():
             _M.step_s.observe(dt)
             _M.occupancy.set(n_active / self.max_batch_size)
+            # productive serving time: the goodput fraction stays
+            # meaningful in a process that never crosses an optimizer
+            # boundary (stall time lands via the watchdog's note_stall)
             _goodput.ACCOUNTANT.note_productive(dt)
         if _EVENTS.enabled:
             _EVENTS.emit("serve.step", "engine",
                          detail={"active": n_active,
                                  "occupancy": round(
                                      n_active / self.max_batch_size, 4),
-                                 "ms": round(dt * 1e3, 4),
-                                 "pipelined": True})
+                                 "ms": round(dt * 1e3, 4)})
         if self.degraded:
+            # first clean decode step after a hang/fault: recovered
             self.degraded = False
             _EVENTS.emit("serve.degrade", "engine",
                          detail={"recovered": True})
@@ -1492,7 +1340,7 @@ class LLMEngine:
         discarded as `commit_lag_rollback` — boundary decisions land
         deterministically at lag 1, costing each departed stream exactly
         its one speculative token. Returns False when destructive
-        recovery (hang rung 3 / decode fault) retired the batch."""
+        recovery (fail-active / a decode fault) retired the batch."""
         inf, self._inflight = self._inflight, None
         if inf is None:
             return True
@@ -1533,31 +1381,43 @@ class LLMEngine:
                     self._monitor.wait(res, "decode", attempt)
                 break
             except StepHang:
-                self._stats.hangs += 1
-                self._note_hang()
-                _EVENTS.emit("serve.hang", "engine", reason="step_hang",
-                             detail={"attempt": attempt,
-                                     "phase": "commit",
-                                     "active": len(
-                                         self.scheduler.running)})
-                if not self._retry_commit(inf, attempt, "commit"):
+                if not self._retry_commit(
+                        inf, attempt, "commit",
+                        active=len(self.scheduler.running)):
                     return None
                 attempt += 1
             except jax.errors.JaxRuntimeError as e:
-                self._degrade("decode_fault",
-                              {"organic": True, "error": str(e)[:200]})
-                self._discard_records(inf)
-                self._reset_pipeline()
-                self._recover_with_fallback(rebuild=True)
+                # organic execution fault: the program/device state is
+                # suspect: eager-finish the batch, rebuild the program
+                self._decode_fault(
+                    inf, {"organic": True, "error": str(e)[:200]},
+                    rebuild=True)
                 return None
         if guardian.poll_fault("serve.decode",
                                ("nan_output", "raise")) is not None:
-            self._degrade("decode_fault", {"injected": True})
-            self._discard_records(inf)
-            self._reset_pipeline()
-            self._recover_with_fallback(rebuild=False)
+            # chaos-poisoned decode output: commit NOTHING from this
+            # launch. The executable itself is healthy (the poison models
+            # a transient device fault), so no rebuild: decode still
+            # compiles exactly once
+            self._decode_fault(inf, {"injected": True}, rebuild=False)
             return None
         return self._fetch_launch(res)
+
+    def _decode_fault(self, inf, detail, rebuild):
+        """A launch that faulted: nothing of it is committed, every
+        running stream finishes through the model's own eager
+        `generate()` (token-identical to the compiled decode per the PR 6
+        parity contract), and the compiled path is restored for queued
+        and new requests."""
+        self._degrade("decode_fault", detail)
+        self._discard_records(inf)
+        self._reset_pipeline()
+        for req in list(self.scheduler.running):
+            self._fallback_eager(req)
+        if self._pools_consumed():
+            self._reset_kv_state()
+        if rebuild:
+            self._rebuild_decode()
 
     def _fetch_launch(self, res):
         """A launch's results on the host, from ONE fetch of its rows
@@ -1587,12 +1447,31 @@ class LLMEngine:
         return (ints[..., 0], floats[..., 1], ints[..., 2:2 + topk],
                 floats[..., 2 + topk:2 + 2 * topk])
 
-    def _retry_commit(self, inf, attempt, phase):
-        """A commit's wait hung. True: wait once more. False: the last
-        rung was taken. A wedged device holds every outstanding program,
-        and rungs 1-2 of the serial ladder cannot replay one whose
-        successor already consumed its pools, so the ladder of a commit
-        goes from one retry straight to fail-active."""
+    def _retry_commit(self, inf, attempt, phase, **counted):
+        """A commit's wait hung: one watchdog firing, attributed, and
+        the recovery ladder climbed. True: wait once more. False: the
+        last rung was taken. A wedged device holds every outstanding
+        program, and a launch whose successor already consumed its pools
+        cannot be replayed, so the ladder goes from one retry of the
+        WAIT straight to fail-active."""
+        self._stats.hangs += 1
+        if _metrics_on():
+            _M.hangs.inc()
+            budget_s = float(_FLAGS.get("FLAGS_serve_step_timeout_ms")
+                             or 0) / 1e3
+            if budget_s > 0:
+                # the wedged wall time (the armed budget the monitor just
+                # burned) lands in the goodput `stalled` bucket, with the
+                # index of the step ABOUT to commit, so /goodput and the
+                # doctor can say WHICH steps stalled
+                _goodput.ACCOUNTANT.note_stall(
+                    budget_s, kind="step_hang", step=self._stats.steps + 1)
+            if phase == "prefill":
+                # prefill time is not measured as a productive step: no
+                # later interval to subtract the stall from
+                _goodput.ACCOUNTANT.drop_stall_carry()
+        _EVENTS.emit("serve.hang", "engine", reason="step_hang",
+                     detail={"attempt": attempt, "phase": phase, **counted})
         consumed = self._pools_consumed()
         if attempt < 2 and not consumed:
             self._degrade("step_hang", {"rung": "retry", "phase": phase})
@@ -1634,18 +1513,8 @@ class LLMEngine:
                         firsts = np.asarray(inf["firsts"])
                     break
                 except StepHang:
-                    self._stats.hangs += 1
-                    self._note_hang()
-                    if _metrics_on():
-                        # prefill time is not measured as a productive
-                        # step: no later interval to subtract from
-                        _goodput.ACCOUNTANT.drop_stall_carry()
-                    _EVENTS.emit("serve.hang", "engine",
-                                 reason="step_hang",
-                                 detail={"phase": "prefill",
-                                         "attempt": attempt,
-                                         "prefills": len(joined)})
-                    if not self._retry_commit(inf, attempt, "prefill"):
+                    if not self._retry_commit(inf, attempt, "prefill",
+                                              prefills=len(joined)):
                         return False
                     attempt += 1
             inf["joined"] = {}
@@ -1667,9 +1536,9 @@ class LLMEngine:
 
     def _drain_joined(self):
         """Synchronously commit the prefills dispatched since the last
-        launch (a bucket's first call, the serial loop's boundary, a
-        boundary nothing launches behind, a drain point): no launch has
-        fed on their tokens, so the host's copy stands for the slot."""
+        launch (a bucket's first call, a boundary nothing launches
+        behind, a drain point): no launch has fed on their tokens, so
+        the host's copy stands for the slot."""
         joined, self._joined = self._joined, {}
         if not joined:
             return True
@@ -1701,11 +1570,10 @@ class LLMEngine:
     def _flush_inflight(self):
         """Synchronously commit (or roll back) the pending pipelined
         launch and every prefill whose token is uncommitted. Drain
-        points — an idle boundary, the weight-swap
-        cutover, explicit drains — must not leave a speculative token in
-        flight. After the flush the host token mirror is authoritative
-        for every slot. No-op when nothing is pending (including the
-        unpipelined engine)."""
+        points — an idle boundary, the weight-swap cutover, explicit
+        drains — must not leave a speculative token in flight. After the
+        flush the host token mirror is authoritative for every slot.
+        No-op when nothing is pending."""
         if self._inflight is not None:
             self._commit_inflight()
         self._drain_joined()
@@ -1988,17 +1856,6 @@ class LLMEngine:
         self._sampler_dev = res[2:4]
         self._bufs = res[4:4 + len(self._bufs)]
 
-    def _kv_args(self, *base):
-        """Positional args for the compiled decode/prefill programs:
-        `base`, which ends with the two pools, plus whatever else the
-        cache holds (the int8 scale side-tables, a per-slot state) — the
-        single source of truth for the signatures' optional tail."""
-        return base + self._bufs[2:]
-
-    # the cache's buffers by name, for what reads one of them
-    _k_pools = property(lambda self: self._bufs[0])
-    _v_pools = property(lambda self: self._bufs[1])
-
     def _donated(self, first):
         """The argument numbers of the cache's buffers in a program whose
         signature has them from `first` on."""
@@ -2175,51 +2032,9 @@ class LLMEngine:
     # ------------------------------------------------------------------
     # watchdog + degraded-mode recovery (serving/resilience.py)
     # ------------------------------------------------------------------
-    def _decode_step(self):
-        """Run the compiled decode step through the monitored completion.
-        Returns the next-token array, or None when the recovery ladder
-        retired the running batch (hang rung 3 / decode-fault eager
-        fallback) — the engine keeps serving queued and new requests
-        either way."""
-        from ..ops import guardian
-        if self._decode_fn is None:
-            self._compile_grace_ns = time.perf_counter_ns()
-            self._decode_fn = self._build_decode()
-        attempt = 1
-        while True:
-            try:
-                res = self._call_decode(self._decode_args())
-                with self._span("engine.decode.wait"):
-                    self._monitor.wait(res, "decode", attempt)
-            except StepHang:
-                if not self._on_hang(attempt):
-                    return None
-                attempt += 1
-                continue
-            except jax.errors.JaxRuntimeError as e:
-                # organic execution fault: the program/device state is
-                # suspect — eager-finish the batch, rebuild the program
-                self._degrade("decode_fault",
-                              {"organic": True, "error": str(e)[:200]})
-                self._recover_with_fallback(rebuild=True)
-                return None
-            if guardian.poll_fault("serve.decode",
-                                   ("nan_output", "raise")) is not None:
-                # chaos-poisoned fused decode output: commit NOTHING from
-                # this launch; the in-flight streams finish through the
-                # eager path token-identically. The executable itself is
-                # healthy (the poison models a transient device fault),
-                # so no rebuild — decode still compiles exactly once.
-                self._degrade("decode_fault", {"injected": True})
-                self._recover_with_fallback(rebuild=False)
-                return None
-            self._adopt(res)
-            self._maybe_store_decode()
-            return self._fetch_launch(res)
-
     def _decode_args(self):
         """The decode program's positional arguments, from the engine's
-        own buffers — the single source of truth shared by both tails,
+        own buffers — the single source of truth shared by the launch,
         the AOT spec builder and the tests that lower the program. ONE
         host array, made anew every call: `[S, max_blocks_per_seq + 4]`,
         the block tables and the columns `_COL_TOKENS`, `_COL_OVERRIDE`,
@@ -2309,74 +2124,12 @@ class LLMEngine:
         return any(b.is_deleted() for b in self._bufs
                    if hasattr(b, "is_deleted"))
 
-    def _note_hang(self):
-        """Metrics-side view of one watchdog firing: the wedged wall
-        time (the armed budget the monitor just burned) lands in the
-        goodput `stalled` bucket and the hang counter."""
-        if not _metrics_on():
-            return
-        _M.hangs.inc()
-        budget_s = float(_FLAGS.get("FLAGS_serve_step_timeout_ms")
-                         or 0) / 1e3
-        if budget_s > 0:
-            # the stalled decode step is the one ABOUT to commit — its
-            # index lands in the goodput attribution ring so /goodput
-            # and the doctor can say WHICH steps stalled
-            _goodput.ACCOUNTANT.note_stall(budget_s, kind="step_hang",
-                                           step=self._stats.steps + 1)
-
     def _degrade(self, reason, detail):
         """Enter (or deepen) degraded mode with an attributed
         transition."""
         self.degraded = True
         _EVENTS.emit("serve.degrade", "engine", reason=reason,
                      detail=detail)
-
-    def _on_hang(self, attempt):
-        """One watchdog firing: attribute it, climb the recovery ladder.
-        Returns True to retry the step (rungs 1-2), False after rung 3
-        (active requests failed, engine reset for new work)."""
-        self._stats.hangs += 1
-        self._note_hang()
-        _EVENTS.emit("serve.hang", "engine", reason="step_hang",
-                     detail={"attempt": attempt,
-                             "active": len(self.scheduler.running)})
-        consumed = self._pools_consumed()
-        if consumed or attempt >= 3:
-            # rung 3: the step would not come back (or its donated
-            # buffers are gone) — fail the batch with an attributed
-            # reason instead of wedging, and restore serviceability
-            self._degrade("step_hang", {"rung": "fail_active",
-                                        "pools_consumed": consumed})
-            for req in list(self.scheduler.running):
-                self._fail(req, "step_hang")
-            if consumed:
-                self._reset_kv_state()
-            self._rebuild_decode()
-            return False
-        if attempt == 1:
-            # rung 1: transient host/device hiccup — retry the same
-            # executable with the same inputs
-            self._degrade("step_hang", {"rung": "retry"})
-        else:
-            # rung 2: the executable itself is suspect — rebuild it
-            # (the retrace is honest: decode_compiles counts it, the
-            # degrade event explains it)
-            self._degrade("step_hang", {"rung": "rebuild"})
-            self._rebuild_decode()
-        return True
-
-    def _recover_with_fallback(self, rebuild):
-        """Degraded-mode fallback: finish every running stream through
-        the model's own eager `generate()` (token-identical to the
-        compiled decode per the PR 6 parity contract), then restore the
-        compiled path for queued/new requests."""
-        for req in list(self.scheduler.running):
-            self._fallback_eager(req)
-        if self._pools_consumed():
-            self._reset_kv_state()
-        if rebuild:
-            self._rebuild_decode()
 
     def _fallback_eager(self, req):
         """Finish one request via model.generate() from its prompt +
@@ -2407,6 +2160,75 @@ class LLMEngine:
         if not req.finished:
             self._finish(req)
 
+    def _reset_slots(self):
+        """The fixed slot layout the compiled programs consume, every
+        slot empty, over the cache as it stands: at construction, and
+        after a launch consumed or poisoned the KV buffers."""
+        s, m = self.max_batch_size, self.max_blocks_per_seq
+        self._tables = np.zeros((s, m), np.int32)
+        self._lens = np.zeros(s, np.int32)
+        self._active = np.zeros(s, bool)
+        self._tokens = np.zeros(s, np.int32)
+        # per-slot adapter index into the padded stacks (0 = base);
+        # deliberately NOT reset by _clear_slot — a stale index on an
+        # inactive slot is masked out, and clearing it would count a
+        # spurious adapter switch on the next same-tenant admission
+        self._aslots = np.zeros(s, np.int32)
+        # -- compiled stochastic sampling (PR 18, serving/sampling.py) --
+        # per-slot sampler config as fixed [S] VALUE buffers — edited
+        # like tokens/lens on join/leave, never reshaping, so arbitrary
+        # per-slot sampler churn keeps decode_compiles == 1. Greedy is
+        # temperature=0 under the same program; the no-op values below
+        # keep a cleared slot on the cheap all-greedy cond branch
+        self._temps = np.zeros(s, np.float32)
+        self._topks = np.zeros(s, np.int32)
+        self._topps = np.ones(s, np.float32)
+        self._rpens = np.ones(s, np.float32)
+        self._seeds = np.zeros(s, np.uint32)
+        # per-slot context-token history for the in-graph repetition
+        # penalty; positions <= lens are valid. The decode step scatters
+        # its own input token at index `lens` in-graph, so the one token
+        # the host has not committed yet is still seen
+        self._history = np.zeros((s, self.max_context), np.int32)
+        # those six are the host's RECORD (`_sync_slot` on a resume,
+        # `state_payload`); what the programs read is on the device: a
+        # `[S, 5]` int32 table of the five (floats by their bits) and the
+        # history, threaded from program to program as the pools are. A
+        # prefill writes its slot's row of both from the request's values
+        # and ids it is given anyway, a launch scatters its input tokens
+        # and masks the table by `active` (a cleared slot uploads
+        # nothing). None: stale. An edit of the record with no prefill
+        # behind it (a prefix-hit admission, `restore_state`, a KV reset,
+        # a rebuilt decode program) marks them so, and the next program
+        # call is handed the record itself, once
+        self._sampler_dev = None        # (table, history) on the device
+        # -- software-pipelined decode (PR 18) ---------------------------
+        # launch step N+1 against device-fed tokens while step N's host
+        # commit overlaps: `_inflight` holds the un-committed launch
+        # (its result arrays, and by slot the request, position and
+        # admission it decoded for), `_feedback` the device next-token
+        # array the next launch will consume, and `_override[slot]` marks
+        # slots whose HOST token (admission / chew / restore) must win
+        # over the device feedback: the decode program selects
+        self._inflight = None
+        self._feedback = None
+        self._override = np.ones(s, bool)
+        # -- prefills launched, not awaited ------------------------------
+        # a prefill writes what it sampled into `_feedback` at its slot
+        # (the next decode launch's input, on the device) and the token's
+        # host-side half (id, logprob, panel, the model's counters) as
+        # one int32 row of `_firsts`, both threaded from program to
+        # program as the pools are. `_joined` holds, by slot, the
+        # request, position and admission of the prefills dispatched
+        # since the last launch; the launch takes them into its inflight
+        # record, and the step after commits them from ONE fetch
+        self._firsts = self._empty_firsts()
+        self._joined = {}
+        # the cache's device buffers (`PagedKVCache.buffers()`: the two
+        # pools, the int8 scales, a per-slot state, whichever it has):
+        # every program takes them last, donates them and hands them back
+        self._bufs = self.cache.buffers()
+
     def _reset_kv_state(self):
         """Fresh block pool + slot arrays after a launch consumed or
         poisoned the KV buffers. Only legal with an empty running batch
@@ -2419,25 +2241,7 @@ class LLMEngine:
                                   num_slots=self.max_batch_size,
                                   state_dtype=self._dtype)
         self.scheduler.allocator = self.cache.allocator
-        s, m = self.max_batch_size, self.max_blocks_per_seq
-        self._tables = np.zeros((s, m), np.int32)
-        self._lens = np.zeros(s, np.int32)
-        self._active = np.zeros(s, bool)
-        self._tokens = np.zeros(s, np.int32)
-        self._aslots = np.zeros(s, np.int32)
-        self._temps = np.zeros(s, np.float32)
-        self._topks = np.zeros(s, np.int32)
-        self._topps = np.ones(s, np.float32)
-        self._rpens = np.ones(s, np.float32)
-        self._seeds = np.zeros(s, np.uint32)
-        self._history = np.zeros((s, self.max_context), np.int32)
-        self._sampler_dev = None
-        self._inflight = None
-        self._feedback = None
-        self._override = np.ones(s, bool)
-        self._firsts = self._empty_firsts()
-        self._joined = {}
-        self._bufs = self.cache.buffers()
+        self._reset_slots()
         if self._prefix is not None:
             # the old pool died with its allocator — the index's
             # references are meaningless now: forget, do not free
@@ -2687,8 +2491,8 @@ class LLMEngine:
         array the host fetches (`_rows`), the table and the history are
         threaded on."""
         # the in-graph history scatter: the input token enters the
-        # context at index `lens` — under pipelined decode it may exist
-        # ONLY on-device (feedback). The history lives on the device, so
+        # context at index `lens` — it may exist ONLY on-device
+        # (feedback). The history lives on the device, so
         # the scatter stands: an active slot's row holds its context up
         # to `lens` whatever the host has committed
         rows = jnp.arange(tokens.shape[0], dtype=jnp.int32)
@@ -2747,7 +2551,7 @@ class LLMEngine:
         if use_aot and _aot.enabled():
             # warm start: a restarted replica deserializes yesterday's
             # decode program and serves its first token without a trace.
-            # The watchdog's rebuild rungs pass use_aot=False — a suspect
+            # The watchdog's rebuild passes use_aot=False — a suspect
             # program must be replaced by a FRESH trace, not by the very
             # bytes that may embody the fault
             digest = self._aot_decode_digest()
@@ -3060,27 +2864,17 @@ class LLMEngine:
             self._stats.cow_copies += 1
 
     def _copy_block(self, src, dst):
-        """One jitted pool-to-pool block copy (all layers, K+V, and the
-        int8 scale rows). src/dst are traced int32 scalars, so the copy
-        program compiles once and serves every COW."""
+        """One jitted block copy over every buffer of the cache (all
+        layers, K+V, and the int8 scale rows: each is `[layer, block,
+        ...]`). src/dst are traced int32 scalars, so the copy program
+        compiles once and serves every COW."""
         if self._cow_fn is None:
-            def cow(k_pools, v_pools, src, dst,
-                    k_scales=None, v_scales=None):
-                k_pools = k_pools.at[:, dst].set(k_pools[:, src])
-                v_pools = v_pools.at[:, dst].set(v_pools[:, src])
-                if k_scales is not None:
-                    k_scales = k_scales.at[:, dst].set(k_scales[:, src])
-                    v_scales = v_scales.at[:, dst].set(v_scales[:, src])
-                    return k_pools, v_pools, k_scales, v_scales
-                return k_pools, v_pools
+            def cow(src, dst, *bufs):
+                return tuple(b.at[:, dst].set(b[:, src]) for b in bufs)
 
-            donate = (0, 1, 4, 5) if self._kv_quantized else (0, 1)
-            self._cow_fn = jax.jit(cow,
-                                   donate_argnums=self._donate(donate))
-        res = self._cow_fn(*self._kv_args(
-            self._k_pools, self._v_pools,
-            jnp.asarray(src, jnp.int32), jnp.asarray(dst, jnp.int32)))
-        self._bufs = tuple(res)
+            self._cow_fn = jax.jit(cow, donate_argnums=self._donated(2))
+        self._bufs = self._cow_fn(jnp.asarray(src, jnp.int32),
+                                  jnp.asarray(dst, jnp.int32), *self._bufs)
 
     def _params_crc(self):
         """CRC over every parameter's bytes — the weight-set identity

@@ -15,9 +15,9 @@ program, not a *structure* program:
   * per-slot keys are ``fold_in(PRNGKey(seed), position)`` stream positions
     derived in-graph (framework/random.py::slot_sample_keys), where
     ``position`` is the count of known context tokens at sampling time.
-    Replays — preemption re-prefill, watchdog rung-2 rebuild, kill-9
-    resume — restore the same positions, so a given (seed, prompt, sampler
-    config) reproduces its token stream byte-identically;
+    Replays — preemption re-prefill, kill-9 resume — restore the same
+    positions, so a given (seed, prompt, sampler config) reproduces its
+    token stream byte-identically;
   * the whole stochastic path sits under one ``lax.cond`` on
     ``any(temperature > 0)``: an all-greedy batch never executes a sort.
 

@@ -46,13 +46,6 @@ def _no_mesh_outlives_its_module():
     set_global_mesh(None)
 
 
-@pytest.fixture(params=[False, True], ids=["serial", "pipelined"])
-def loop(request):
-    """Both decode loops of `LLMEngine`, as its `pipeline_decode`: WHAT
-    is served is the same whichever runs."""
-    return request.param
-
-
 @pytest.fixture(autouse=True)
 def _seed_everything():
     np.random.seed(0)

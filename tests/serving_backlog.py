@@ -1,13 +1,14 @@
 """The benchmark's backlog cell in small, for the serving tests: every
 slot of one engine kept full by a queue that is topped up before every
 `step()`, with requests finishing and joining at every boundary. Request
-i is the same prompt, length and sampler whichever loop serves it, so the
-streams of the serial and the pipelined loop compare index by index."""
+i is the same prompt, length and sampler in every run."""
 from __future__ import annotations
 
 import numpy as np
 
 from paddle_tpu.serving import FINISHED, LLMEngine
+
+from serving_reference import stream_of
 
 SLOTS = 8
 QUEUE_DEPTH = 4
@@ -25,10 +26,7 @@ def request_of(i, vocab, samplers):
     prompt = rng.integers(
         0, vocab, PROMPT_LENGTHS[i % len(PROMPT_LENGTHS)]).tolist()
     want = FIRST_OUTPUTS[i % len(FIRST_OUTPUTS)] if i < SLOTS else OUTPUTS
-    sampler = dict(samplers[i % len(samplers)])
-    if "seed" in sampler:
-        sampler["seed"] += i          # every stream its own
-    return prompt, want, sampler
+    return prompt, want, stream_of(samplers[i % len(samplers)], i)
 
 
 def top_up(engine, requests, vocab, samplers=({},)):

@@ -352,7 +352,7 @@ class TestWindowStitching:
     longer than the 8-op rolling window converge to a single launch."""
 
     @staticmethod
-    def _pipeline(x, depth=8):
+    def _op_chain(x, depth=8):
         h = x
         for _ in range(depth):
             h = paddle.tanh(h)
@@ -367,7 +367,7 @@ class TestWindowStitching:
         x = _t(np.linspace(-1.0, 1.0, 32, dtype=np.float32).reshape(4, 8))
         outs = []
         for _ in range(40):
-            outs.append(self._pipeline(x).numpy().copy())
+            outs.append(self._op_chain(x).numpy().copy())
         s = chain_fusion_stats()
         assert s["chains_stitched"] >= 1, s
         info = chain_cache_info()
@@ -377,7 +377,7 @@ class TestWindowStitching:
             f"no ≥16-op chain replayed: {[(c['ops'], c['replays']) for c in info['chains']]}"
         set_flags({"FLAGS_eager_chain_fusion": False})
         clear_dispatch_cache()
-        ref = self._pipeline(x).numpy()
+        ref = self._op_chain(x).numpy()
         np.testing.assert_array_equal(outs[-1], ref)
 
     def test_stitched_replay_counts_launches_saved_once(self):
@@ -386,13 +386,13 @@ class TestWindowStitching:
         constituent chains stop replaying entirely."""
         x = _t(np.linspace(-1.0, 1.0, 32, dtype=np.float32).reshape(4, 8))
         for _ in range(40):            # converge to the stitched chain
-            self._pipeline(x)
+            self._op_chain(x)
         info = chain_cache_info()
         top = max((c for c in info["chains"] if c["replays"] > 0),
                   key=lambda c: c["ops"])
         s0 = chain_fusion_stats()
         for _ in range(5):
-            self._pipeline(x)
+            self._op_chain(x)
         s1 = chain_fusion_stats()
         replays = s1["fused_replays"] - s0["fused_replays"]
         saved = s1["launches_saved"] - s0["launches_saved"]
@@ -408,7 +408,7 @@ class TestWindowStitching:
         set_flags({"FLAGS_eager_chain_stitching": False})
         x = _t(np.linspace(-1.0, 1.0, 32, dtype=np.float32).reshape(4, 8))
         for _ in range(40):
-            self._pipeline(x)
+            self._op_chain(x)
         s = chain_fusion_stats()
         assert s["chains_stitched"] == 0
         info = chain_cache_info()
@@ -431,7 +431,7 @@ class TestWindowStitching:
                    stop_gradient=False)
             out = []
             for _ in range(30):
-                y = self._pipeline(x, depth=6)     # 18 ops
+                y = self._op_chain(x, depth=6)     # 18 ops
                 loss = y.sum()
                 loss.backward()
                 out.append((loss.numpy().copy(), x.grad.numpy().copy()))

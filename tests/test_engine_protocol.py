@@ -6,12 +6,15 @@ history live on the device, the host's arrays are the record.
     `prefill_host_arrays`, `decode_fetched_arrays`, `state_uploads`);
   * the streams are the ones the engine served BEFORE the protocol
     changed, token for token and logprob bit for bit: a batch in which
-    every slot has sampler settings of its own, with a logprob panel, on
-    both tails, under a pool so tight that requests are evicted and
-    resumed, and across `state_payload()` -> `restore_state()` into a new
-    engine. `fixtures/serving/pr43_parent_streams.json` holds what commit
-    877c7c8 served (`python tests/test_engine_protocol.py --record` with
-    that commit's `paddle_tpu` on the path wrote it);
+    every slot has sampler settings of its own, with a logprob panel,
+    under a pool so tight that requests are evicted and resumed, and
+    across `state_payload()` -> `restore_state()` into a new engine.
+    `fixtures/serving/pr43_parent_streams.json` holds what commit 877c7c8
+    served from both loops it had (`serial-*`: launch, wait, commit in
+    one step; that loop went in PR 46, and the one loop is held to its
+    record too: the same tokens, logprobs to the last bits). The streams
+    are also the ones `serving_reference.Reference` works out with no
+    engine at all;
   * a slot cleared after a sampled request reads the row that samples
     nothing, though the device's table still holds the request's.
 """
@@ -19,7 +22,6 @@ from __future__ import annotations
 
 import json
 import os
-import sys
 
 import numpy as np
 import pytest
@@ -29,6 +31,8 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import paddle_tpu as paddle
 from paddle_tpu.incubate.models import GPTConfig, GPTForCausalLM
 from paddle_tpu.serving import LLMEngine, FINISHED
+
+from serving_reference import Reference
 
 VOCAB = 128
 RECORDED = os.path.join(os.path.dirname(os.path.abspath(__file__)),
@@ -101,20 +105,19 @@ def canary(model):
         .reshape(-1).tolist()
 
 
-def tight_engine(model, pipelined):
+def tight_engine(model):
     """Three slots over a pool that cannot hold three grown contexts:
     requests are evicted and resume by a second prefill."""
     return LLMEngine(model, max_batch_size=3, block_size=4, num_blocks=12,
-                     watermark_blocks=1, logprobs_topk=PANEL,
-                     pipeline_decode=pipelined)
+                     watermark_blocks=1, logprobs_topk=PANEL)
 
 
-def serve(model, pipelined, crash_after=None):
+def serve(model, crash_after=None):
     """The scenario: seven requests, each with settings of its own, through
     a tight pool; with `crash_after`, that many steps, then the snapshot
     into a NEW engine that finishes them. Returns ({rid: stream}, stats of
-    the engine that finished)."""
-    engine = tight_engine(model, pipelined)
+    the engine that finished, {rid: request})."""
+    engine = tight_engine(model)
     for i, (n, sampler) in enumerate(zip(LENGTHS, SAMPLERS)):
         engine.add_request(prompt(n), max_new_tokens=NEW_TOKENS,
                            request_id=f"q{i}", **sampler)
@@ -125,7 +128,7 @@ def serve(model, pipelined, crash_after=None):
         payload = json.loads(json.dumps(engine.state_payload()))
         done = {rid: r for rid, r in engine.requests.items() if r.finished}
         evictions = engine.stats()["evictions"]
-        engine = tight_engine(model, pipelined)
+        engine = tight_engine(model)
         engine.restore_state(payload)
     else:
         evictions = 0
@@ -134,25 +137,11 @@ def serve(model, pipelined, crash_after=None):
     assert all(r.state == FINISHED for r in done.values())
     stats = engine.stats()
     stats["evictions"] += evictions
-    return {rid: stream_of(r) for rid, r in sorted(done.items())}, stats
+    return ({rid: stream_of(r) for rid, r in sorted(done.items())}, stats,
+            done)
 
 
 SCENARIOS = {"evicted": None, "restored": 6}
-
-
-def record(path):
-    model = new_model()
-    out = {"canary": canary(model), "streams": {}}
-    for name, crash_after in SCENARIOS.items():
-        for pipelined in (False, True):
-            streams, stats = serve(model, pipelined, crash_after)
-            assert stats["evictions"] >= 1, (name, pipelined)
-            out["streams"][f"{name}-{'pipelined' if pipelined else 'serial'}"] \
-                = streams
-    os.makedirs(os.path.dirname(path), exist_ok=True)
-    with open(path, "w") as f:
-        json.dump(out, f, separators=(",", ":"))
-        f.write("\n")
 
 
 @pytest.fixture(scope="module")
@@ -165,16 +154,51 @@ def recorded(model):
     return kept["streams"]
 
 
+def ulps_apart(got, want):
+    """The largest distance, in float32 steps, between two streams'
+    logprobs (and panels) where both hold one."""
+    far = 0
+    for key in ("logprobs", "alt_logprobs"):
+        for a, b in zip(got[key], want[key]):
+            if a is not None and b is not None:
+                far = max(far, int(np.abs(np.asarray(a, np.int64)
+                                          - np.asarray(b, np.int64)).max()))
+    return far
+
+
 @pytest.mark.parametrize("scenario", sorted(SCENARIOS))
+@pytest.mark.parametrize("recorded_by", ["serial", "pipelined"])
 def test_streams_are_the_parents_token_for_token_and_bit_for_bit(
-        model, recorded, loop, scenario):
-    streams, stats = serve(model, loop, SCENARIOS[scenario])
+        model, recorded, recorded_by, scenario):
+    """The parent's pipelined loop is this engine's: its streams bit
+    for bit. The parent's serial loop committed a token a step earlier
+    (so a snapshot after six steps holds one logprob more a stream) and
+    batched other slots together: its tokens and panels' ids exactly,
+    its logprobs to the last bits."""
+    streams, stats, _ = serve(model, SCENARIOS[scenario])
     assert stats["evictions"] >= 1              # the pool actually bit
     assert stats["decode_compiles"] == 1
-    want = recorded[f"{scenario}-{'pipelined' if loop else 'serial'}"]
+    want = recorded[f"{scenario}-{recorded_by}"]
     assert sorted(streams) == sorted(want)
     for rid in want:
-        assert streams[rid] == want[rid], rid
+        if recorded_by == "pipelined":
+            assert streams[rid] == want[rid], rid
+            continue
+        assert streams[rid]["generated"] == want[rid]["generated"], rid
+        for got, kept in zip(streams[rid]["alt_ids"],
+                             want[rid]["alt_ids"]):
+            assert got is None or kept is None or got == kept, rid
+        assert ulps_apart(streams[rid], want[rid]) <= 2, rid
+
+
+@pytest.mark.parametrize("scenario", sorted(SCENARIOS))
+def test_the_scenarios_streams_are_the_references(model, scenario):
+    """The same seven streams against no engine at all: every sampler
+    of the scenario (a seed over 2^31, a penalty under 1, greedy with
+    every other knob set), through evictions and a restore."""
+    _, stats, done = serve(model, SCENARIOS[scenario])
+    assert stats["evictions"] >= 1
+    Reference(model).assert_served(done.values())
 
 
 def test_both_tails_and_both_scenarios_recorded_the_same_tokens(recorded):
@@ -188,10 +212,10 @@ def test_both_tails_and_both_scenarios_recorded_the_same_tokens(recorded):
     assert len({tuple(s) for s in first.values()}) == len(first)
 
 
-def test_greedy_slots_of_the_mixed_batch_match_generate(model, loop):
+def test_greedy_slots_of_the_mixed_batch_match_generate(model):
     """The two temperature-0 requests of the scenario, one with every
     other knob set: `model.generate`'s tokens, through evictions."""
-    streams, _ = serve(model, loop)
+    streams, _, _ = serve(model)
     for i in (0, 5):
         ids = np.asarray([prompt(LENGTHS[i])], np.int64)
         out = model.generate(paddle.Tensor(ids), max_new_tokens=NEW_TOKENS,
@@ -217,9 +241,9 @@ def crossing(engine):
         "prefill_host_arrays", "decode_fetched_arrays", "state_uploads")}
 
 
-def test_a_call_hands_over_one_host_array_and_fetches_one(model, loop):
+def test_a_call_hands_over_one_host_array_and_fetches_one(model):
     engine = LLMEngine(model, max_batch_size=4, block_size=4,
-                       logprobs_topk=PANEL, pipeline_decode=loop)
+                       logprobs_topk=PANEL)
     warm(engine)
     reqs = [engine.add_request(prompt(n), max_new_tokens=6, **sampler)
             for n, sampler in zip((6, 11, 7, 5, 9), SAMPLERS)]
@@ -245,9 +269,8 @@ def test_a_call_hands_over_one_host_array_and_fetches_one(model, loop):
     assert all(host == ["numpy"] for _, host in seen), seen
 
 
-def test_a_restore_uploads_the_record_once(model, loop):
-    engine = LLMEngine(model, max_batch_size=4, block_size=4,
-                       pipeline_decode=loop)
+def test_a_restore_uploads_the_record_once(model):
+    engine = LLMEngine(model, max_batch_size=4, block_size=4)
     warm(engine)
     engine.add_request(prompt(6), max_new_tokens=8, temperature=0.8, seed=5)
     for _ in range(3):
@@ -270,19 +293,15 @@ def test_a_restore_uploads_the_record_once(model, loop):
     assert after["decode_fetched_arrays"] == after["decode_launches"]
 
 
-def test_a_prefix_hit_uploads_the_record_once(model, loop):
+def test_a_prefix_hit_uploads_the_record_once(model):
     """A prefix-hit admission edits the record with no prefill behind it:
-    the next call is handed the record, and the stream is the one a cold
-    engine serves."""
+    the next call is handed the record, and the stream is the one the
+    reference works out with no cache at all."""
     shared = prompt(12, seed=9)
     sampler = dict(temperature=0.9, top_k=20, repetition_penalty=1.4,
                    seed=77)
-    cold = LLMEngine(model, max_batch_size=4, block_size=4,
-                     pipeline_decode=loop)
-    ref = cold.add_request(shared + [3, 4], max_new_tokens=6, **sampler)
-    cold.run()
     engine = LLMEngine(model, max_batch_size=4, block_size=4,
-                       enable_prefix_cache=True, pipeline_decode=loop)
+                       enable_prefix_cache=True)
     engine.generate([shared + [1, 2]], max_new_tokens=3)
     engine.generate([prompt(5)], max_new_tokens=2)
     engine.reset_stats()
@@ -292,14 +311,13 @@ def test_a_prefix_hit_uploads_the_record_once(model, loop):
     assert st["prefix_hit_tokens"] > 0 and st["prefills"] == 0
     assert st["state_uploads"] == 1
     assert st["decode_host_arrays"] == st["decode_launches"] + 2
-    assert hit.generated == ref.generated
+    Reference(model).assert_served([hit])
 
 
 def test_the_device_holds_what_the_record_holds(model):
     """At rest, the device's table and history agree with the host's
     record in every active slot's row up to its length."""
-    engine = LLMEngine(model, max_batch_size=4, block_size=4,
-                       pipeline_decode=True)
+    engine = LLMEngine(model, max_batch_size=4, block_size=4)
     reqs = [engine.add_request(prompt(n), max_new_tokens=12, **sampler)
             for n, sampler in zip((6, 11, 7), SAMPLERS[2:5])]
     for _ in range(5):
@@ -358,7 +376,7 @@ def test_the_programs_donate_the_caches_buffers_and_nothing_else(model,
 # a cleared slot
 # ---------------------------------------------------------------------------
 
-def test_a_cleared_slot_reads_the_row_that_samples_nothing(model, loop,
+def test_a_cleared_slot_reads_the_row_that_samples_nothing(model,
                                                            monkeypatch):
     """A sampled request leaves; a greedy one goes on. Nothing is uploaded
     for the clear, the device's table still holds the departed request's
@@ -376,8 +394,7 @@ def test_a_cleared_slot_reads_the_row_that_samples_nothing(model, loop,
                 ordered=True)
         return real(logits, temperature, *rest, **kw)
     monkeypatch.setattr(engine_mod, "sample_tokens", watched)
-    engine = LLMEngine(model, max_batch_size=4, block_size=4,
-                       pipeline_decode=loop)
+    engine = LLMEngine(model, max_batch_size=4, block_size=4)
     sampled = engine.add_request(prompt(6), max_new_tokens=3,
                                  temperature=0.9, top_k=7, seed=21)
     greedy = engine.add_request(prompt(9), max_new_tokens=12)
@@ -397,10 +414,3 @@ def test_a_cleared_slot_reads_the_row_that_samples_nothing(model, loop,
                          max_new_tokens=12, do_sample=False)
     ref = np.asarray(out._value if hasattr(out, "_value") else out)[0]
     assert greedy.generated == ref.tolist()
-
-
-if __name__ == "__main__":
-    if sys.argv[1:2] != ["--record"]:
-        raise SystemExit("usage: test_engine_protocol.py --record")
-    record(RECORDED)
-    print("recorded", RECORDED, "from", paddle.__file__)

@@ -62,6 +62,8 @@ from paddle_tpu.serving import LLMEngine, num_blocks_for_bytes
 from paddle_tpu.profiler.events import (clear_fusion_events, fusion_events,
                                         EVENTS)
 
+from serving_reference import SAMPLERS, Reference, stream_of
+
 VOCAB = 128
 
 VARIANTS = ("reference", "blockwise", "pallas")
@@ -349,30 +351,27 @@ class TestInt8KV:
             bound = sc[:, None] / QMAX * (0.5 * bs)
             assert (np.abs(deq[i] - vec) <= bound + 1e-6).all(), i
 
-    def test_int8_greedy_decode_token_identical_to_fp32(self, model, loop):
+    @pytest.mark.parametrize("pool", ["roomy", "tight"])
+    def test_int8_greedy_decode_token_identical_to_fp32(self, model, pool):
         """End-to-end: the int8-KV engine reproduces the fp32 reference
         stream token for token on the tiny-GPT fixture — including under
         preemption churn (evict -> requeue -> re-prefill requantizes)."""
-        prompts = [_prompt(n, seed=21) for n in (11, 5, 17, 3)]
-        refs = [_ref(model, p, 10) for p in prompts]
-        engine = LLMEngine(model, max_batch_size=4, block_size=4,
-                           kv_dtype="int8", pipeline_decode=loop)
+        if pool == "roomy":
+            prompts = [_prompt(n, seed=21) for n in (11, 5, 17, 3)]
+            engine = LLMEngine(model, max_batch_size=4, block_size=4,
+                               kv_dtype="int8")
+        else:
+            # tight pool: eviction + resume stays token-identical on int8
+            prompts = [_prompt(n, seed=22) for n in (11, 12, 10, 5)]
+            engine = LLMEngine(model, max_batch_size=3, block_size=4,
+                               num_blocks=10, watermark_blocks=1,
+                               kv_dtype="int8")
         outs = engine.generate(prompts, max_new_tokens=10)
-        assert outs == refs
+        assert outs == [_ref(model, p, 10) for p in prompts]
         st = engine.stats()
         assert st["kv_dtype"] == "int8"
+        assert (st["evictions"] >= 1) == (pool == "tight")
         assert st["decode_compiles"] == 1
-        # tight pool: eviction + resume stays token-identical on int8
-        prompts2 = [_prompt(n, seed=22) for n in (11, 12, 10, 5)]
-        refs2 = [_ref(model, p, 10) for p in prompts2]
-        churn = LLMEngine(model, max_batch_size=3, block_size=4,
-                          num_blocks=10, watermark_blocks=1,
-                          kv_dtype="int8", pipeline_decode=loop)
-        outs2 = churn.generate(prompts2, max_new_tokens=10)
-        st2 = churn.stats()
-        assert st2["evictions"] >= 1
-        assert outs2 == refs2
-        assert st2["decode_compiles"] == 1
 
     def test_int8_admits_1p8x_streams_at_same_pool_bytes(self, model):
         """The capacity win: with the SAME byte budget, the int8 pool
@@ -676,18 +675,25 @@ class TestLengthBoundedLoop:
         assert st["attn_streamed_share"] == 1.0
         assert st["attn_held_share"] == pytest.approx(shares[1024][1])
 
+    @pytest.mark.parametrize("sampler", [SAMPLERS[0], SAMPLERS[4]],
+                             ids=["greedy", "penalty"])
     def test_engine_at_two_widths_serves_generates_tokens(self, model,
-                                                          loop):
+                                                          sampler):
         """128 slots run at two widths: the ordered decode step serves
-        exactly `model.generate`'s tokens, compiled once."""
+        exactly `model.generate`'s tokens, compiled once; seeded, the
+        streams of one request at a time through the dense forward."""
         prompts = [_prompt(3 + (i * 5) % 23, seed=29) for i in range(140)]
-        engine = LLMEngine(model, max_batch_size=128, block_size=4,
-                           pipeline_decode=loop)
+        engine = LLMEngine(model, max_batch_size=128, block_size=4)
         assert _blockwise_plan(128, engine.max_blocks_per_seq, 4, 4, 8)[0] \
             == (128, 64)
-        outs = engine.generate(prompts, max_new_tokens=6)
-        for p, out in zip(prompts, outs):
-            assert out == _ref(model, p, 6)
+        reqs = [engine.add_request(p, max_new_tokens=6,
+                                   **stream_of(sampler, i))
+                for i, p in enumerate(prompts)]
+        engine.run()
+        Reference(model).assert_served(reqs)
+        if not sampler:
+            for p, r in zip(prompts, reqs):
+                assert r.generated == _ref(model, p, 6)
         st = engine.stats()
         assert st["decode_compiles"] == 1 and st["completed"] == 140
 

@@ -36,6 +36,7 @@ from paddle_tpu.kernels.pallas import grouped_matmul as gm  # noqa: E402
 from paddle_tpu.kernels.pallas import paged_attention as pa  # noqa: E402
 from paddle_tpu.nn.functional import attention as fattn  # noqa: E402
 from paddle_tpu.serving import LLMEngine  # noqa: E402
+from serving_reference import SAMPLERS, Reference, stream_of  # noqa: E402
 from tiny_lfm2 import TINY_LFM2 as FILE  # noqa: E402
 
 from benchmark.programs import paddle_lfm2  # noqa: E402
@@ -171,18 +172,14 @@ def hold_to_the_reference(weights, prompts, served, tol=TOL):
     return worst
 
 
-@pytest.mark.parametrize("pipeline", [True, False],
-                         ids=["pipelined", "serial"])
-def test_prefill_then_decode_gives_the_references_logits(model, weights,
-                                                         pipeline):
+def test_prefill_then_decode_gives_the_references_logits(model, weights):
     """Prompts of 1, 2 and 3 tokens (shorter than the state reaches back:
     zeros lie before the sequence), every prompt shorter than its bucket
     (8, 16: the state is taken at `length`), and ONE slot, so that every
     request after the first reuses it, the longest first: a shorter
     request's prefill overwrites the longer one's state whole."""
     prompts = [prompt_of(n) for n in (13, 1, 2, 3, 9, 5)]
-    engine, served = served_logprobs(model, prompts, max_batch_size=1,
-                                     pipeline_decode=pipeline)
+    engine, served = served_logprobs(model, prompts, max_batch_size=1)
     hold_to_the_reference(weights, prompts, served)
     s = engine.stats()
     assert s["decode_compiles"] == 1 and s["prefill_compiles"] == 2
@@ -194,29 +191,64 @@ def test_prefill_then_decode_gives_the_references_logits(model, weights,
     assert s["decode_routed_elsewhere"] == s["prefill_routed_elsewhere"] == 0
 
 
+# temperature, top-k, top-p and a repetition penalty at once
+SEEDED = SAMPLERS[4]
+ONE_SLOT_LENGTHS = (13, 1, 2, 3, 9, 5)
+
+
+@pytest.fixture(scope="module")
+def one_slot_seeded(model):
+    """The schedule above (ONE slot, the longest first, prompts shorter
+    than the state reaches back) with every stream SEEDED: by its length,
+    the request the slot served, and the reference over the model's own
+    dense forward, which carries no state from token to token."""
+    engine = LLMEngine(model, max_batch_size=1, block_size=4, max_context=48)
+    reqs = {n: engine.add_request(prompt_of(n), max_new_tokens=6,
+                                  **stream_of(SEEDED, n))
+            for n in ONE_SLOT_LENGTHS}
+    highest(engine.run)
+    assert engine.stats()["sampled_tokens"] == 6 * len(reqs)
+    return reqs, Reference(model, width=48)
+
+
+@pytest.mark.parametrize("length", ONE_SLOT_LENGTHS)
+def test_a_seeded_stream_is_the_dense_forwards(one_slot_seeded, length):
+    """What is DRAWN, and not only the logits drawn from: the key at
+    `fold_in(seed, position)`, the penalty's history in a reused slot,
+    the packed call's sampler row."""
+    reqs, reference = one_slot_seeded
+    highest(reference.assert_served, [reqs[length]])
+
+
 def test_a_full_batch_of_slots_gives_the_references_logits(model, weights):
     prompts = [prompt_of(n, 1) for n in (5, 9, 13, 7, 11, 6, 2)]
     _, served = served_logprobs(model, prompts, max_batch_size=3)
     hold_to_the_reference(weights, prompts, served)
 
 
-@pytest.mark.parametrize("pipeline", [True, False],
-                         ids=["pipelined", "serial"])
-def test_streams_are_generates_under_an_eviction_schedule(model, pipeline):
+@pytest.mark.parametrize("sampler", [SAMPLERS[0], SAMPLERS[4]],
+                         ids=["greedy", "penalty"])
+def test_streams_are_generates_under_an_eviction_schedule(model, sampler):
     """A pool too tight for its batch evicts; the evicted request's resume
     is a re-prefill of prompt + generated tokens, which restores the
     convolutions' state by computing it: every stream is token-identical
-    to `generate`, which never preempts, in both loops."""
+    to `generate`, which never preempts; a seeded stream (temperature,
+    top-k, top-p and a repetition penalty) to one request at a time
+    through the model's dense forward, which keeps no state at all."""
     prompts = [prompt_of(n, 5) for n in (11, 12, 10, 5)]
-    want = [np.asarray(highest(model.generate, np.asarray([p]),
-                               max_new_tokens=10)._value)[0].tolist()
-            for p in prompts]
     engine = LLMEngine(model, max_batch_size=3, block_size=4, num_blocks=10,
-                       watermark_blocks=1, pipeline_decode=pipeline)
-    got = highest(engine.generate, prompts, max_new_tokens=10)
+                       watermark_blocks=1)
+    reqs = [engine.add_request(p, max_new_tokens=10, **stream_of(sampler, i))
+            for i, p in enumerate(prompts)]
+    highest(engine.run)
     s = engine.stats()
     assert s["evictions"] >= 1 and s["decode_compiles"] == 1
-    assert got == want
+    highest(Reference(model).assert_served, reqs)
+    if not sampler:
+        assert [r.generated for r in reqs] == [
+            np.asarray(highest(model.generate, np.asarray([p]),
+                               max_new_tokens=10)._value)[0].tolist()
+            for p in prompts]
 
 
 # -- (c) decode attention with four queries a key/value head ------------------

@@ -35,6 +35,7 @@ from paddle_tpu.incubate.models import GPTConfig, GPTForCausalLM  # noqa: E402
 from paddle_tpu.serving import LLMEngine  # noqa: E402
 from paddle_tpu.serving.cache import (PagedCacheView,  # noqa: E402
                                       PagedKVCache, scatter_prefill)
+from serving_reference import SAMPLERS, Reference, stream_of  # noqa: E402
 from tiny_longcat import TINY_LONGCAT  # noqa: E402
 
 from benchmark.programs import paddle_longcat  # noqa: E402
@@ -162,10 +163,9 @@ def test_a_fault_in_the_layers_mathematics_fails_the_comparison(
 
 # -- (b) the engine through the latent paged cache ----------------------------
 
-def serve(model, pipeline, kernel=None, new_tokens=10):
+def serve(model, kernel=None, new_tokens=10):
     engine = LLMEngine(model, max_batch_size=4, block_size=4,
-                       max_context=48, pipeline_decode=pipeline,
-                       attention_kernel=kernel)
+                       max_context=48, attention_kernel=kernel)
     rng = np.random.default_rng(1)
     prompts = [rng.integers(0, FILE["vocab_size"], n).tolist()
                for n in (5, 9, 13, 7, 11, 6)]
@@ -191,11 +191,9 @@ def gaps_of(weights, prompts, served):
 @pytest.mark.parametrize("kernel", ["blockwise", "reference"])
 def test_prefill_then_decode_serves_the_references_tokens(model, weights,
                                                           kernel):
-    engine, prompts, pipelined = serve(model, True, kernel)
-    _, _, serial = serve(model, False, kernel)
-    assert pipelined == serial
-    assert all(len(s) == 10 for s in serial)
-    gaps, scale = gaps_of(weights, prompts, pipelined)
+    engine, prompts, served = serve(model, kernel)
+    assert all(len(s) == 10 for s in served)
+    gaps, scale = gaps_of(weights, prompts, served)
     assert gaps.max() <= TOL * scale
     s = engine.stats()
     assert s["decode_compiles"] == 1 and s["kv_dtype"] == "float32"
@@ -203,8 +201,39 @@ def test_prefill_then_decode_serves_the_references_tokens(model, weights,
     assert engine.cache.v_pools.size == 0          # ONE pool, one row a token
 
 
+# temperature, top-k, top-p and a repetition penalty at once
+SEEDED = SAMPLERS[4]
+
+
+@pytest.mark.parametrize("schedule", ["batch", "one_slot", "eviction"])
+def test_a_seeded_stream_is_the_dense_forwards(model, schedule):
+    """What is DRAWN through the latent pool, and not only the logits
+    drawn from: every stream is the one a request at a time through the
+    model's own dense forward is owed, in a full batch, through ONE slot
+    that every request after the first reuses (the longest first), and
+    under a pool so tight that requests are evicted and resume by a
+    second prefill."""
+    lengths = {"batch": (5, 9, 13, 7, 11, 6), "one_slot": (29, 1, 2, 13, 4),
+               "eviction": (11, 12, 10, 5)}[schedule]
+    engine = LLMEngine(
+        model, block_size=4, max_context=48,
+        **{"batch": dict(max_batch_size=4), "one_slot": dict(max_batch_size=1),
+           "eviction": dict(max_batch_size=3, num_blocks=10,
+                            watermark_blocks=1)}[schedule])
+    rng = np.random.default_rng(2)
+    reqs = [engine.add_request(rng.integers(0, FILE["vocab_size"], n).tolist(),
+                               max_new_tokens=10, **stream_of(SEEDED, i))
+            for i, n in enumerate(lengths)]
+    highest(engine.run)
+    s = engine.stats()
+    assert s["decode_compiles"] == 1
+    assert s["sampled_tokens"] == 10 * len(lengths)
+    assert (s["evictions"] >= 1) == (schedule == "eviction")
+    highest(Reference(model, width=48).assert_served, reqs)
+
+
 def test_an_altered_served_token_is_seen_in_the_gap(model, weights):
-    _, prompts, served = serve(model, True)
+    _, prompts, served = serve(model)
     served[2][4] = (served[2][4] + 1) % FILE["vocab_size"]
     gaps, scale = gaps_of(weights, prompts, served)
     assert gaps.max() > 100 * TOL * scale
@@ -395,7 +424,7 @@ def test_the_models_counters_sum_over_its_layers(model):
 
 
 def test_the_engines_stats_carry_the_counters_by_phase(model):
-    engine, prompts, served = serve(model, True)
+    engine, prompts, served = serve(model)
     s = engine.stats()
     kinds = ("routed_held", "routed_identity", "routed_elsewhere")
     assert s["prefill_counted"] == len(prompts)
@@ -412,24 +441,22 @@ def test_the_engines_stats_carry_the_counters_by_phase(model):
 
 
 def test_a_launched_prefills_counters_come_with_its_first_token(model):
-    """The pipelined loop launches a prefill and reads its counters at
-    the commit, from the row that brings the first token: the window's
-    `prefill_*` counts are the serial loop's, and four of the six
-    prefills (two were their bucket's first call) were never awaited."""
-    piped, _, served = serve(model, True)
-    serial, _, same = serve(model, False)
-    assert served == same
-    a, b = piped.stats(), serial.stats()
-    # counts, not clock shares; nor what a dispatch FOUND the device
-    # doing (`_device_idle`, `_ran_dry`): that is the clock's observation,
-    # and differs between two engines on a loaded machine
-    keys = [k for k in b if k.startswith("prefill_")
-            and isinstance(b[k], int)
-            and not k.endswith(("_device_idle", "_ran_dry"))]
-    assert "prefill_routed_held" in keys and "prefill_counted" in keys
-    assert {k: a[k] for k in keys} == {k: b[k] for k in keys}
-    assert a["prefill_unawaited_share"] == pytest.approx(4 / 6)
-    assert b["prefill_unawaited_share"] == 0.0
+    """The engine launches a prefill and reads its counters at the
+    commit, from the row that brings the first token: the window's
+    `prefill_*` counts are what the model's own dense forward counts
+    over each prompt, though four of the six prefills (two were their
+    bucket's first call) were never awaited."""
+    engine, prompts, _ = serve(model)
+    names = type(model).serve_counter_names
+    want = np.zeros(len(names), np.int64)
+    for prompt in prompts:
+        highest(model, paddle.Tensor(jnp.asarray([prompt], jnp.int32)))
+        want += np.asarray(model.pop_serve_counters())
+    s = engine.stats()
+    assert "routed_held" in names and want.sum() > 0
+    assert [s[f"prefill_{name}"] for name in names] == want.tolist()
+    assert s["prefill_counted"] == len(prompts)
+    assert s["prefill_unawaited_share"] == pytest.approx(4 / 6)
 
 
 # -- (e) the seams in the engine ----------------------------------------------

@@ -38,6 +38,7 @@ from paddle_tpu.nn.functional import attention as fattn  # noqa: E402
 from paddle_tpu.serving import LLMEngine  # noqa: E402
 from paddle_tpu.serving.cache import (  # noqa: E402
     CacheSpec, PagedKVCache, ring_block, scatter_window_prefill)
+from serving_reference import SAMPLERS, Reference, stream_of  # noqa: E402
 from tiny_mimo import TINY_MIMO as FILE  # noqa: E402
 
 from benchmark.programs import paddle_mimo  # noqa: E402
@@ -204,16 +205,33 @@ def gap_to_the_reference(weights, prompt, out, rows, file=FILE):
 LENGTHS = (1, 3, 4, 5, 6, 7, 8, 12, 13, 17, 29, 40)
 
 
-@pytest.fixture(scope="module", params=[True, False],
-                ids=["pipelined", "serial"])
-def one_slot(request, model):
+@pytest.fixture(scope="module")
+def one_slot(model):
     """ONE slot, so that every request after the first reuses it, the
     longest first: a shorter request's prefill must leave nothing of the
     longer one's ring that its decode can read."""
     prompts = [prompt_of(n) for n in reversed(LENGTHS)]
-    engine, served = served_logprobs(model, prompts, max_batch_size=1,
-                                     pipeline_decode=request.param)
+    engine, served = served_logprobs(model, prompts, max_batch_size=1)
     return engine, dict(zip(reversed(LENGTHS), zip(prompts, served)))
+
+
+# temperature, top-k, top-p and a repetition penalty at once
+SEEDED = SAMPLERS[4]
+
+
+@pytest.fixture(scope="module")
+def one_slot_seeded(model):
+    """The same schedule with every stream SEEDED: by its length, the
+    request the one slot served and the reference over the model's own
+    dense forward, which keeps no cache of either kind."""
+    engine = LLMEngine(model, max_batch_size=1, block_size=BLOCK,
+                       max_context=64)
+    reqs = {n: engine.add_request(prompt_of(n), max_new_tokens=14,
+                                  **stream_of(SEEDED, n))
+            for n in reversed(LENGTHS)}
+    highest(engine.run)
+    assert engine.stats()["sampled_tokens"] == 14 * len(LENGTHS)
+    return reqs, Reference(model)
 
 
 @pytest.mark.parametrize("length", LENGTHS)
@@ -222,6 +240,15 @@ def test_prefill_then_decode_gives_the_references_logits(one_slot, weights,
     prompt, (out, rows) = one_slot[1][length]
     assert len(out) == 14
     assert gap_to_the_reference(weights, prompt, out, rows) <= TOL
+
+
+@pytest.mark.parametrize("length", LENGTHS)
+def test_a_seeded_stream_is_the_dense_forwards(one_slot_seeded, length):
+    """What is DRAWN, and not only the logits drawn from: the key at
+    `fold_in(seed, position)`, the penalty's history through the ring's
+    wrap and a reused slot, the packed call's sampler row."""
+    reqs, reference = one_slot_seeded
+    highest(reference.assert_served, [reqs[length]])
 
 
 def test_the_engine_counts_what_the_two_caches_hold(one_slot):
@@ -251,24 +278,28 @@ def test_a_full_batch_of_slots_gives_the_references_logits(model, weights):
         assert gap_to_the_reference(weights, prompt, out, rows) <= TOL
 
 
-@pytest.mark.parametrize("pipeline", [True, False],
-                         ids=["pipelined", "serial"])
-def test_streams_are_generates_under_an_eviction_schedule(model, pipeline):
+@pytest.mark.parametrize("sampler", [SAMPLERS[0], SEEDED],
+                         ids=["greedy", "penalty"])
+def test_streams_are_generates_under_an_eviction_schedule(model, sampler):
     """A pool too tight for its batch evicts; the evicted request's resume
     is a re-prefill of prompt + generated tokens, which writes its slot's
     ring anew (`CacheSpec`'s rule): every stream is token-identical to
-    `generate`, which never preempts, in both loops."""
+    `generate`, which never preempts; a seeded stream to one request at
+    a time through the model's dense forward."""
     prompts = [prompt_of(n, 5) for n in (11, 12, 10, 5)]
-    want = [np.asarray(highest(model.generate, np.asarray([p]),
-                               max_new_tokens=10)._value)[0].tolist()
-            for p in prompts]
     engine = LLMEngine(model, max_batch_size=3, block_size=BLOCK,
-                       num_blocks=10, watermark_blocks=1,
-                       pipeline_decode=pipeline)
-    got = highest(engine.generate, prompts, max_new_tokens=10)
+                       num_blocks=10, watermark_blocks=1)
+    reqs = [engine.add_request(p, max_new_tokens=10, **stream_of(sampler, i))
+            for i, p in enumerate(prompts)]
+    highest(engine.run)
     s = engine.stats()
     assert s["evictions"] >= 1 and s["decode_compiles"] == 1
-    assert got == want
+    highest(Reference(model).assert_served, reqs)
+    if not sampler:
+        assert [r.generated for r in reqs] == [
+            np.asarray(highest(model.generate, np.asarray([p]),
+                               max_new_tokens=10)._value)[0].tolist()
+            for p in prompts]
 
 
 @pytest.mark.parametrize("option,kwargs", [

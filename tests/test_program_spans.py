@@ -92,16 +92,15 @@ def host_spans(trace_dir):
 
 @pytest.fixture(scope="module")
 def traced(tmp_path_factory):
-    """One `jax.profiler` session over a tiny TrainStep and tiny engines
-    (plain and pipelined), inside the caller's own annotation."""
+    """One `jax.profiler` session over a tiny TrainStep and a tiny
+    engine (cold, then warm), inside the caller's own annotation."""
     out = str(tmp_path_factory.mktemp("trace"))
     step, x, y = tiny_train_step(), *batch(16)
-    engine = tiny_engine(pipeline_decode=False)
-    piped = tiny_engine(pipeline_decode=True)
+    engine = tiny_engine()
     forced = []
 
     def collect_once(req, tok, text):
-        # a decoded token (the serial loop emits a first token under
+        # a decoded token (a first token is emitted under
         # `engine.prefill.commit`, every later one under `engine.stream`)
         if len(req.generated) == 2 and not forced:
             forced.append(gc.collect())
@@ -116,10 +115,8 @@ def traced(tmp_path_factory):
                 engine.add_request(p, max_new_tokens=4,
                                    on_token=collect_once)
             engine.run()
-        with jax.profiler.TraceAnnotation("caller.pipelined"):
-            piped.generate(PROMPTS, max_new_tokens=4)
         with jax.profiler.TraceAnnotation("caller.warm"):
-            piped.generate(PROMPTS, max_new_tokens=4)
+            engine.generate(PROMPTS, max_new_tokens=4)
     finally:
         jax.profiler.stop_trace()
         gc.enable()
@@ -162,15 +159,6 @@ def test_the_first_call_of_each_program_is_under_engine_compile(traced):
         assert len(inside) == 1
 
 
-def test_the_pipelined_tail_opens_the_same_spans(traced):
-    lines = traced["lines"]
-    window = _named(lines, "caller.pipelined")[0]
-    names = {n for events in lines.values() for n, a, b in events
-             if window[1] <= a and b <= window[2]}
-    # (the session's one collection was forced in the serial window)
-    assert {n for n in ENGINE_PARENTS if n != "engine.gc"} <= names
-
-
 def _inside(lines, name, window):
     return [f for f in _named(lines, name)
             if f[0] == window[0] and window[1] <= f[1] and f[2] <= window[2]]
@@ -178,11 +166,11 @@ def _inside(lines, name, window):
 
 def test_a_prefill_is_dispatched_and_its_wait_sits_under_the_commit(traced):
     """A prefill's span holds its dispatch and no wait: the wait for a
-    boundary's prefills is the commit's. In the pipelined loop, the
-    buckets warm, that commit lies past the admission and the table
-    growth of the NEXT step, before that step's decode launch."""
+    boundary's prefills is the commit's. The buckets warm, that commit
+    lies past the admission and the table growth of the NEXT step,
+    before that step's decode launch."""
     lines = traced["lines"]
-    for outer in ("caller.window", "caller.pipelined", "caller.warm"):
+    for outer in ("caller.window", "caller.warm"):
         window = _named(lines, outer)[0]
         prefills = _inside(lines, "engine.prefill", window)
         assert len(prefills) == 3
@@ -259,9 +247,8 @@ def test_the_accessor_returns_live_train_steps_oldest_first():
     assert first.stats()["steps"] == 0
 
 
-@pytest.mark.parametrize("pipelined", [False, True])
-def test_engine_phase_counts_and_shares(pipelined):
-    engine = tiny_engine(pipeline_decode=pipelined)
+def test_engine_phase_counts_and_shares():
+    engine = tiny_engine()
     for p in PROMPTS:
         engine.add_request(p, max_new_tokens=6)
     calls = 0
@@ -273,8 +260,6 @@ def test_engine_phase_counts_and_shares(pipelined):
     assert phase["engine.prefill"].count == stats["prefills"] == 3
     assert phase["engine.decode.dispatch"].count == stats["steps"]
     assert phase["engine.stream"].count == stats["steps"]
-    if not pipelined:
-        assert phase["engine.decode"].count == stats["steps"]
     assert phase["engine.compile"].count == 3
     shares = [stats[k] for k in ("prefill_share", "decode_share",
                                  "stream_share", "step_self_share")]
@@ -328,13 +313,12 @@ def _count_spans(engine):
     return opened
 
 
-@pytest.mark.parametrize("pipelined", [False, True])
-def test_every_span_of_a_step_feeds_a_histogram_of_its_own(pipelined):
+def test_every_span_of_a_step_feeds_a_histogram_of_its_own():
     """The known traffic, warm: each of the twelve phases has as many
     observations as its span was opened, a step's direct children and
     what is left add up to the step, and a maximum is no less than a
     mean."""
-    engine = tiny_engine(pipeline_decode=pipelined)
+    engine = tiny_engine()
     engine.generate(PROMPTS, max_new_tokens=6)      # compiles
     engine.reset_stats()
     opened = _count_spans(engine)
@@ -377,10 +361,19 @@ def test_every_span_of_a_step_feeds_a_histogram_of_its_own(pipelined):
         slowest["seconds"] * (1 + 1e-9)
 
 
-def test_the_serial_loop_finds_the_device_idle_at_every_dispatch():
-    """One request at a time through the serial loop: the host has
-    fetched every program's result before it dispatches the next."""
-    engine = tiny_engine(pipeline_decode=False)
+def test_a_host_that_comes_late_finds_the_device_idle_at_every_dispatch():
+    """One request at a time, and a host so slow that every program has
+    finished before the next is dispatched (here: it waits for the newest
+    result first): the device's queue is empty at every call."""
+    engine = tiny_engine()
+    call = engine._call_program
+
+    def late(name, fn, args, first):
+        if engine._newest_result is not None:
+            engine._newest_result.block_until_ready()
+        return call(name, fn, args, first)
+
+    engine._call_program = late
     for p in PROMPTS:
         engine.generate([p], max_new_tokens=4)
     stats = engine.stats()
@@ -396,8 +389,8 @@ def test_the_serial_loop_finds_the_device_idle_at_every_dispatch():
         assert stats[kind + "ran_dry_dispatch_share"] == 0.0
 
 
-def test_the_pipelined_loop_counts_every_call_but_a_programs_first():
-    engine = tiny_engine(pipeline_decode=True)
+def test_the_loop_counts_every_call_but_a_programs_first():
+    engine = tiny_engine()
     engine.generate(PROMPTS, max_new_tokens=6)
     stats = engine.stats()
     assert stats["dispatches"] == \

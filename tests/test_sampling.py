@@ -9,16 +9,17 @@ The contracts pinned here are the PR 18 acceptance criteria:
   * ``temperature=0`` is greedy under the SAME program — token-identical
     to ``model.generate(do_sample=False)`` whatever the other knobs say;
   * a given (seed, prompt, sampler config) reproduces its token stream
-    byte-identically across join-order permutations, preemption,
-    watchdog rung-2 rebuild, and crash-checkpoint resume (the per-slot
-    keys are ``fold_in(PRNGKey(seed), position)``, so a replay is a
-    replay, not a re-roll);
+    byte-identically across join-order permutations, preemption and
+    crash-checkpoint resume (the per-slot keys are
+    ``fold_in(PRNGKey(seed), position)``, so a replay is a replay, not a
+    re-roll): the stream one request at a time through the dense forward
+    is owed (tests/serving_reference.py);
   * per-token logprobs and static-K alternative panels ride the same
     executable with zero extra compiles;
-  * software-pipelined decode (launch N+1 before committing N) is
-    token-identical to the unpipelined engine, and the commit-lag-1
-    transaction rolls a launched-but-uncommitted token back instead of
-    leaking it into a cancelled stream.
+  * software-pipelined decode (launch N+1 before committing N) serves
+    that reference's streams, and the commit-lag-1 transaction rolls a
+    launched-but-uncommitted token back instead of leaking it into a
+    cancelled stream.
 """
 from __future__ import annotations
 
@@ -32,9 +33,7 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import jax.numpy as jnp
 
 import paddle_tpu as paddle
-from paddle_tpu.framework.flags import set_flags
 from paddle_tpu.incubate.models import GPTConfig, GPTForCausalLM
-from paddle_tpu.ops import guardian
 from paddle_tpu.serving import LLMEngine, FINISHED, CANCELLED
 from paddle_tpu.serving.sampling import (SAMPLER_VERSION, default_seed,
                                          validate_sampler,
@@ -43,6 +42,8 @@ from paddle_tpu.serving.sampling import (SAMPLER_VERSION, default_seed,
                                          apply_top_p, sample_tokens)
 
 import serving_backlog as backlog
+from serving_reference import (SAMPLERS, Reference, each_sampler,
+                               stream_of)
 
 VOCAB = 128
 
@@ -58,6 +59,11 @@ def model():
     m = GPTForCausalLM(cfg)
     m.eval()
     return m
+
+
+@pytest.fixture(scope="module")
+def reference(model):
+    return Reference(model)
 
 
 def _prompt(length, seed=0):
@@ -77,18 +83,6 @@ def _ref(model, prompt, n):
         arr = out._value if hasattr(out, "_value") else out
         _REF_CACHE[key] = np.asarray(arr)[0].tolist()
     return _REF_CACHE[key]
-
-
-# A spread of sampler configs used by the determinism tests: greedy,
-# temperature-only, top-k, top-p, and the full stack.
-SAMPLERS = (
-    dict(),
-    dict(temperature=0.7, seed=11),
-    dict(temperature=1.0, top_k=12, seed=12),
-    dict(temperature=0.9, top_p=0.85, seed=13),
-    dict(temperature=1.1, top_k=24, top_p=0.9, repetition_penalty=1.3,
-         seed=14),
-)
 
 
 def _run_streams(model, prompts, cfgs, n_new=8, **eng_kw):
@@ -305,30 +299,29 @@ class TestZeroRetraceSampling:
 # ---------------------------------------------------------------------------
 
 class TestSampledDeterminism:
-    def test_streams_invariant_under_join_order(self, model):
+    def test_streams_invariant_under_join_order(self, model, reference):
         """Each stream's tokens depend only on ITS (seed, prompt,
         sampler) — not on which neighbors shared the batch or the
-        admission order."""
+        admission order: both orders serve the reference's streams."""
         prompts = [_prompt(n, seed=56) for n in (8, 11, 6, 9, 7)]
         cfgs = [dict(SAMPLERS[i % len(SAMPLERS)]) for i in range(5)]
+        want = [reference.serve(p, 8, **c)[0]
+                for p, c in zip(prompts, cfgs)]
         fwd, e1 = _run_streams(model, prompts, cfgs)
         rev, e2 = _run_streams(model, list(reversed(prompts)),
                                list(reversed(cfgs)))
-        assert fwd == list(reversed(rev))
+        assert fwd == want == list(reversed(rev))
         assert e1.stats()["decode_compiles"] == 1
         assert e2.stats()["decode_compiles"] == 1
 
-    def test_preempt_resume_replays_not_rerolls(self, model):
+    def test_preempt_resume_replays_not_rerolls(self, model, reference):
         """A deliberately tight pool forces eviction of sampled streams;
         the re-prefilled stream continues from restored positions, so the
-        draws replay byte-identically vs a roomy never-preempted run."""
+        draws replay byte-identically: the streams of requests that were
+        never preempted (the reference's)."""
         prompts = [_prompt(n, seed=57) for n in (11, 12, 10, 5)]
         cfgs = [dict(temperature=0.9, top_k=16, top_p=0.9,
                      seed=2000 + i) for i in range(4)]
-        roomy = LLMEngine(model, max_batch_size=3, block_size=4)
-        refs = [roomy.add_request(p, max_new_tokens=10, **c)
-                for p, c in zip(prompts, cfgs)]
-        roomy.run()
         tight = LLMEngine(model, max_batch_size=3, block_size=4,
                           num_blocks=10, watermark_blocks=1)
         got = [tight.add_request(p, max_new_tokens=10, **c)
@@ -337,49 +330,18 @@ class TestSampledDeterminism:
         st = tight.stats()
         assert st["evictions"] >= 1                  # the pool actually bit
         assert st["decode_compiles"] == 1
-        for r, g in zip(refs, got):
-            assert list(g.generated) == list(r.generated)
+        reference.assert_served(got)
 
-    def test_rung2_rebuild_replays_sampled_streams(self, model):
-        """Two consecutive hangs climb to rung 2 of the SERIAL loop's
-        ladder (the pipelined one has no rebuild rung: its second hang
-        fails the batch): the decode executable is REBUILT mid-stream.
-        The rebuilt program derives the same fold_in(seed, position)
-        keys, so every sampled stream continues byte-identically (the
-        retrace is honest: compiles goes to 2)."""
-        prompts = [_prompt(n, seed=58) for n in (9, 6)]
-        cfgs = [dict(temperature=0.8, top_k=20, seed=3001),
-                dict(temperature=1.0, top_p=0.9, seed=3002)]
-        clean, _ = _run_streams(model, prompts, cfgs, n_new=8,
-                                max_queue_depth=None)
-        set_flags({"FLAGS_serve_step_timeout_ms": 2000})
-        eng = LLMEngine(model, max_batch_size=4, block_size=4,
-                        pipeline_decode=False)
-        reqs = [eng.add_request(p, max_new_tokens=8, **c)
-                for p, c in zip(prompts, cfgs)]
-        for _ in range(3):
-            eng.step()
-        guardian.inject_fault("hang", op="serve.decode", times=2)
-        try:
-            eng.run()
-        finally:
-            guardian.clear_faults()
-        st = eng.stats()
-        assert st["hangs"] == 2
-        assert st["decode_compiles"] == 2            # the rung-2 rebuild
-        assert not eng.degraded
-        for r, ref in zip(reqs, clean):
-            assert r.state == FINISHED and list(r.generated) == ref
-
-    def test_crash_resume_replays_sampled_streams(self, model):
+    @each_sampler
+    def test_crash_resume_replays_sampled_streams(self, model, reference,
+                                                  sampler):
         """state_payload() serializes the sampler identity; a FRESH
         engine restoring mid-flight sampled streams finishes them with
-        the same final tokens as the uninterrupted run."""
+        the final tokens of an uninterrupted run (the reference's)."""
         prompts = [_prompt(n, seed=59) for n in (11, 6, 9)]
-        cfgs = [dict(temperature=0.9, top_k=24, top_p=0.95,
-                     repetition_penalty=1.1, seed=4000 + i)
-                for i in range(3)]
-        clean, _ = _run_streams(model, prompts, cfgs, n_new=10)
+        cfgs = [stream_of(sampler, i) for i in range(3)]
+        clean = [reference.serve(p, 10, **c)[0]
+                 for p, c in zip(prompts, cfgs)]
         eng = LLMEngine(model, max_batch_size=2, block_size=4)
         for i, (p, c) in enumerate(zip(prompts, cfgs)):
             eng.add_request(p, max_new_tokens=10, request_id=f"s{i}", **c)
@@ -389,6 +351,8 @@ class TestSampledDeterminism:
         assert payload["requests"]
         eng2 = LLMEngine(model, max_batch_size=2, block_size=4)
         restored = eng2.restore_state(payload)
+        assert len(restored) >= 2
+        assert any(r.generated for r in restored)    # resumed mid-stream
         eng2.run()
         by_rid = {r.rid: r for r in restored}
         for i, ref in enumerate(clean):
@@ -450,14 +414,13 @@ class TestLogprobs:
 class TestPipelined:
     def test_launch_hands_the_program_copies_of_its_host_buffers(
             self, model):
-        """A pipelined launch is asynchronous and the engine edits
-        `_lens`, `_tables` and the sampler buffers in place right after
-        it: an argument that shares their memory lets a slow dispatch
-        read the NEXT step's values (under load the sampled streams then
-        differ from run to run — what failed the parity test below on a
+        """A launch is asynchronous and the engine edits `_lens`,
+        `_tables` and the sampler buffers in place right after it: an
+        argument that shares their memory lets a slow dispatch read the
+        NEXT step's values (under load the sampled streams then differ
+        from run to run — what failed the mixed-batch test below on a
         busy box). Every host array the program is given is a copy."""
-        eng = LLMEngine(model, max_batch_size=4, block_size=4,
-                        pipeline_decode=True)
+        eng = LLMEngine(model, max_batch_size=4, block_size=4)
         eng.add_request(_prompt(9, seed=64), max_new_tokens=8,
                         temperature=0.7, seed=11)
         mine = [a for a in vars(eng).values() if isinstance(a, np.ndarray)]
@@ -473,73 +436,66 @@ class TestPipelined:
         for a in seen:
             assert not any(np.shares_memory(a, m) for m in mine)
 
-    def test_pipelined_parity_with_unpipelined(self, model):
-        """pipeline_decode=True must change WHEN tokens are committed,
-        never WHICH tokens: mixed greedy+sampled streams are bitwise
-        identical to the unpipelined engine, one compile each, and the
-        clean drain needs zero rollbacks."""
+    def test_a_mixed_batch_serves_the_reference(self, model, reference):
+        """Launching N+1 before N is committed changes WHEN tokens are
+        committed, never WHICH tokens: mixed greedy+sampled streams are
+        the reference's, with one compile, and the clean drain needs
+        zero rollbacks."""
         prompts = [_prompt(n, seed=64) for n in (9, 6, 11, 7, 8)]
         cfgs = [dict(SAMPLERS[i % len(SAMPLERS)]) for i in range(5)]
-        plain, e1 = _run_streams(model, prompts, cfgs,
-                                 pipeline_decode=False)
-        piped, e2 = _run_streams(model, prompts, cfgs,
-                                 pipeline_decode=True)
-        assert piped == plain
-        assert e1.stats()["decode_compiles"] == 1
-        assert e2.stats()["decode_compiles"] == 1
-        assert e2.stats()["commit_rollbacks"] == 0
+        piped, eng = _run_streams(model, prompts, cfgs)
+        assert piped == [reference.serve(p, 8, **c)[0]
+                         for p, c in zip(prompts, cfgs)]
+        assert eng.stats()["decode_compiles"] == 1
+        assert eng.stats()["commit_rollbacks"] == 0
 
     @pytest.mark.parametrize("mix", ["greedy", "seeded", "mixed"])
-    def test_backlog_streams_identical_over_both_loops(self, model, mix):
+    def test_backlog_streams_are_the_references(self, model, reference,
+                                                mix):
         """The benchmark's backlog cell in small: every slot full,
         requests of mixed prompt buckets finishing AND joining at every
-        boundary, so every pipelined launch mixes device-fed slots with
+        boundary, so every launch mixes device-fed slots with
         host-authored ones. Greedy, seeded and both in one batch: the
-        pipelined loop serves the serial loop's streams token for token,
-        with no rollback and one decode program."""
+        engine serves the reference's streams token for token, with no
+        rollback and one decode program."""
         samplers = {"greedy": (dict(),), "seeded": SAMPLERS[1:],
                     "mixed": SAMPLERS}[mix]
-        streams = {}
-        for piped in (False, True):
-            requests, boundaries, eng = backlog.drive(
-                model, VOCAB, 48, samplers=samplers, pipeline_decode=piped)
-            backlog.assert_steady(boundaries)
-            st = eng.stats()
-            assert st["decode_compiles"] == 1
-            assert st["commit_rollbacks"] == 0
-            hot = sum(r.max_new_tokens for r in requests
-                      if r.temperature > 0)
-            assert st["sampled_tokens"] == hot
-            assert (hot > 0) == (mix != "greedy")
-            streams[piped] = [list(r.generated) for r in requests]
-        n = min(len(streams[False]), len(streams[True]))
-        assert n >= 100
-        assert streams[True][:n] == streams[False][:n]
+        requests, boundaries, eng = backlog.drive(
+            model, VOCAB, 48, samplers=samplers)
+        backlog.assert_steady(boundaries)
+        st = eng.stats()
+        assert st["decode_compiles"] == 1
+        assert st["commit_rollbacks"] == 0
+        hot = sum(r.max_new_tokens for r in requests if r.temperature > 0)
+        assert st["sampled_tokens"] == hot
+        assert (hot > 0) == (mix != "greedy")
+        assert len(requests) >= 100
+        reference.assert_served(requests)
 
-    def test_commit_lag_cancel_rolls_back_not_leaks(self, model):
+    @each_sampler
+    def test_commit_lag_cancel_rolls_back_not_leaks(self, model, reference,
+                                                    sampler):
         """Cancel lands between launch N+1 and its commit: the launched
         token for the cancelled slot is rolled back (never appended),
-        the rollback is attributed, and the surviving streams finish
-        bitwise-identically to the unpipelined run."""
+        the rollback is attributed, and the surviving streams finish as
+        the reference's; what the cancelled stream was served before is
+        the head of its own."""
         prompts = [_prompt(n, seed=65) for n in (10, 8, 9)]
-        cfgs = [dict(temperature=0.9, top_k=20, seed=5000 + i)
-                for i in range(3)]
-        plain, _ = _run_streams(model, prompts, cfgs, n_new=10,
-                                pipeline_decode=False)
-        eng = LLMEngine(model, max_batch_size=4, block_size=4,
-                        pipeline_decode=True)
-        reqs = [eng.add_request(p, max_new_tokens=10, **c)
-                for p, c in zip(prompts, cfgs)]
+        eng = LLMEngine(model, max_batch_size=4, block_size=4)
+        reqs = [eng.add_request(p, max_new_tokens=10,
+                                **stream_of(sampler, i))
+                for i, p in enumerate(prompts)]
         for _ in range(4):
             eng.step()                   # an uncommitted launch in flight
         victim = reqs[1]
         n_before = len(victim.generated)
+        assert 0 < n_before < 10
         eng.cancel(victim.rid)
         eng.run()
         st = eng.stats()
         assert victim.state == CANCELLED
         assert len(victim.generated) == n_before     # nothing leaked
+        assert victim.generated == reference.owed(victim)[0][:n_before]
         assert st["commit_rollbacks"] >= 1
         assert st["decode_compiles"] == 1
-        assert list(reqs[0].generated) == plain[0]
-        assert list(reqs[2].generated) == plain[2]
+        reference.assert_served([reqs[0], reqs[2]])

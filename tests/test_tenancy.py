@@ -37,6 +37,9 @@ from paddle_tpu.serving import (BlockAllocator, LLMEngine, Request,
                                 Scheduler, ServeRefusal, NULL_BLOCK,
                                 PrefixCache, AdapterSet, FINISHED)
 
+from serving_reference import (SAMPLERS, Reference, each_sampler,
+                               stream_of)
+
 VOCAB = 128
 
 
@@ -55,6 +58,25 @@ def _make_model(seed=0):
 @pytest.fixture(scope="module")
 def model():
     return _make_model(seed=0)
+
+
+@pytest.fixture(scope="module")
+def reference(model):
+    """What the engine owes a request, worked out without an engine."""
+    return Reference(model)
+
+
+def _assert_tenants_served(reference, engine, requests):
+    """Every request holds the reference's stream: a base tenant the
+    model's own, an adapter's tenant the model's with the adapter folded
+    into the weights (`AdapterSet.merged`: W + A @ B x scale, where the
+    programs add the low-rank product to the activations)."""
+    for req in requests:
+        if req.adapter is None:
+            reference.assert_served([req])
+        else:
+            with engine._adapters.merged(req.adapter):
+                reference.assert_served([req])
 
 
 def _prompt(length, seed=0):
@@ -373,23 +395,30 @@ class TestAliasedAdmission:
 # ---------------------------------------------------------------------------
 
 class TestPrefixServing:
-    def test_shared_prefix_one_prefill_token_identical(self, model, loop):
+    @each_sampler
+    def test_shared_prefix_one_prefill_token_identical(self, model,
+                                                       reference, sampler):
         """Four streams share a 12-token prefix: ONE prefill total, and
-        every stream's greedy output matches per-stream generate —
-        including through the copy-on-write divergence."""
+        every stream's output is the reference's (greedy: per-stream
+        generate's) — including through the copy-on-write divergence,
+        and a seeded stream's draws at the positions it chewed up to."""
         prompts = _shared_prompts(4, prefix_len=12, suffix_len=3)
         engine = LLMEngine(model, max_batch_size=4, block_size=4,
-                           num_blocks=64, enable_prefix_cache=True,
-                           pipeline_decode=loop)
+                           num_blocks=64, enable_prefix_cache=True)
         clear_fusion_events()
         set_flags({"FLAGS_profiler_events": True})
         try:
-            outs = engine.generate(prompts, max_new_tokens=8)
+            reqs = [engine.add_request(p, max_new_tokens=8,
+                                       **stream_of(sampler, i))
+                    for i, p in enumerate(prompts)]
+            engine.run()
             ev = fusion_events()
         finally:
             set_flags({"FLAGS_profiler_events": False})
-        for p, o in zip(prompts, outs):
-            assert o == _ref(model, p, 8)
+        reference.assert_served(reqs)
+        if not sampler:
+            for p, r in zip(prompts, reqs):
+                assert r.generated == _ref(model, p, 8)
         st = engine.stats()
         assert st["prefills"] == 1                # N sharers, one prefill
         assert st["decode_compiles"] == 1
@@ -402,17 +431,24 @@ class TestPrefixServing:
         assert len(hits) == 3
         assert all(e["reason"] == "prefix_hit" for e in hits)
 
-    def test_identical_prompts_full_alias_and_cow(self, model, loop):
+    @each_sampler
+    def test_identical_prompts_full_alias_and_cow(self, model, reference,
+                                                  sampler):
         """Bit-identical prompts alias every block (hit = len-1); the
         divergence then happens inside a SHARED block, so parity proves
-        copy-on-write actually copies."""
+        copy-on-write actually copies (seeded, the two streams draw
+        under seeds of their own and part at once)."""
         p = _prompt(12, seed=11)
         engine = LLMEngine(model, max_batch_size=2, block_size=4,
-                           num_blocks=64, enable_prefix_cache=True,
-                           pipeline_decode=loop)
-        outs = engine.generate([p, list(p)], max_new_tokens=8)
-        ref = _ref(model, p, 8)
-        assert outs[0] == ref and outs[1] == ref
+                           num_blocks=64, enable_prefix_cache=True)
+        reqs = [engine.add_request(list(p), max_new_tokens=8,
+                                   **stream_of(sampler, i))
+                for i in range(2)]
+        engine.run()
+        reference.assert_served(reqs)
+        if not sampler:
+            ref = _ref(model, p, 8)
+            assert reqs[0].generated == ref and reqs[1].generated == ref
         st = engine.stats()
         assert st["prefills"] == 1
         assert st["prefix_hit_tokens"] == len(p) - 1
@@ -466,16 +502,31 @@ class TestPrefixServing:
 # ---------------------------------------------------------------------------
 
 class TestAdapters:
-    def test_base_tenant_bit_identical_to_adapter_free(self, model, loop):
+    @each_sampler
+    def test_base_tenant_bit_identical_to_adapter_free(self, model,
+                                                       reference, sampler):
         """Slot 0's delta is an exact 0.0 — base tenants on an
-        adapter-enabled engine match per-stream generate exactly."""
-        prompts = [_prompt(9, seed=31), _prompt(7, seed=32)]
-        engine = LLMEngine(model, max_batch_size=2, block_size=4,
-                           num_blocks=64, max_adapters=2, adapter_rank=2,
-                           pipeline_decode=loop)
-        outs = engine.generate(prompts, max_new_tokens=8)
-        for p, o in zip(prompts, outs):
-            assert o == _ref(model, p, 8)
+        adapter-enabled engine, beside an adapter's tenant, are served
+        the adapter-free reference's streams (greedy: generate's), and
+        the adapter's tenant the stream of the model with the adapter
+        folded in."""
+        prompts = [_prompt(9, seed=31), _prompt(7, seed=32),
+                   _prompt(8, seed=33)]
+        engine = LLMEngine(model, max_batch_size=3, block_size=4,
+                           num_blocks=64, max_adapters=2, adapter_rank=2)
+        engine.register_adapter("tenant-a", seed=3, scale=25.0)
+        reqs = [engine.add_request(p, max_new_tokens=8, adapter=ad,
+                                   **stream_of(sampler, i))
+                for i, (p, ad) in enumerate(zip(
+                    prompts, (None, None, "tenant-a")))]
+        engine.run()
+        _assert_tenants_served(reference, engine, reqs)
+        if not sampler:
+            for p, r in zip(prompts[:2], reqs):
+                assert r.generated == _ref(model, p, 8)
+        base = reference.logits(prompts[2])
+        with engine._adapters.merged("tenant-a"):     # the delta bites
+            assert np.abs(reference.logits(prompts[2]) - base).max() > 1e-2
         assert engine.stats()["decode_compiles"] == 1
 
     def test_adapter_changes_output_deterministically(self, model):
@@ -492,13 +543,12 @@ class TestAdapters:
         assert runs[0] != _ref(model, p, 6)       # the delta bites
         assert runs[0] == runs[1]                 # and is deterministic
 
-    def test_tenant_churn_zero_recompiles(self, model, loop):
+    def test_tenant_churn_zero_recompiles(self, model, reference):
         """Tenants joining/leaving only edit stack VALUES and slot
         indices: the decode executable compiles exactly once."""
         prompts = _shared_prompts(6, prefix_len=8, suffix_len=2, seed=40)
         engine = LLMEngine(model, max_batch_size=3, block_size=4,
-                           num_blocks=64, max_adapters=3, adapter_rank=2,
-                           pipeline_decode=loop)
+                           num_blocks=64, max_adapters=3, adapter_rank=2)
         engine.register_adapter("t1", seed=1, scale=25.0)
         engine.register_adapter("t2", seed=2, scale=25.0)
         plan = ["t1", None, "t2", "t1", "t2", None]
@@ -511,6 +561,7 @@ class TestAdapters:
         base5 = _ref(model, prompts[5], 5)
         assert done["c1"].generated == base2
         assert done["c5"].generated == base5
+        _assert_tenants_served(reference, engine, done.values())
         st = engine.stats()
         assert st["decode_compiles"] == 1
         assert st["adapter_switches"] >= 2
@@ -590,53 +641,55 @@ class TestAdapters:
 # ---------------------------------------------------------------------------
 
 class TestTenantPrefillLaunchedNotAwaited:
-    def test_joined_tenants_first_tokens_ride_the_device(self, model):
+    @pytest.mark.parametrize("sampler", [SAMPLERS[0], SAMPLERS[4]],
+                             ids=["greedy", "penalty"])
+    def test_joined_tenants_first_tokens_ride_the_device(self, model,
+                                                         reference,
+                                                         sampler):
         """The tenant prefill program hands its token on as the plain one
         does: with the buckets warm, tenants joining a running batch two
-        at a boundary are launched over unawaited, and serve what the
-        serial loop serves (a base tenant: what `generate` does)."""
+        at a boundary are launched over unawaited, and are served the
+        reference's streams (a base tenant greedy: what `generate`
+        does)."""
         prompts = [_prompt(n, seed=60 + n) for n in (6, 11, 7, 10, 5, 12)]
         plan = [None, "t1", "t2", None, "t1", None]
-        streams = {}
-        for piped in (False, True):
-            engine = LLMEngine(model, max_batch_size=3, block_size=4,
-                               num_blocks=64, max_adapters=3,
-                               adapter_rank=2, hot_swap=True,
-                               pipeline_decode=piped)
-            engine.register_adapter("t1", seed=1, scale=25.0)
-            engine.register_adapter("t2", seed=2, scale=25.0)
-            engine.generate([_prompt(5, seed=59), _prompt(9, seed=59)],
-                            max_new_tokens=2)        # buckets 8, 16
-            engine.reset_stats()
-            reqs = []
-            for i in range(0, len(prompts), 2):
-                for p, ad in zip(prompts[i:i + 2], plan[i:i + 2]):
-                    reqs.append(engine.add_request(
-                        p, max_new_tokens=4 + i, adapter=ad))
-                engine.step()
-                engine.step()
-            engine.run()
-            st = engine.stats()
-            assert st["decode_compiles"] == 0 == st["prefill_compiles"]
-            assert st["commit_rollbacks"] == 0
-            assert st["prefill_unawaited_share"] == (1.0 if piped else 0.0)
-            streams[piped] = [r.generated for r in reqs]
-        assert streams[True] == streams[False]
-        for i in (0, 3, 5):
-            assert streams[True][i] == _ref(model, prompts[i],
-                                            4 + 2 * (i // 2))
+        engine = LLMEngine(model, max_batch_size=3, block_size=4,
+                           num_blocks=64, max_adapters=3,
+                           adapter_rank=2, hot_swap=True)
+        engine.register_adapter("t1", seed=1, scale=25.0)
+        engine.register_adapter("t2", seed=2, scale=25.0)
+        engine.generate([_prompt(5, seed=59), _prompt(9, seed=59)],
+                        max_new_tokens=2)        # buckets 8, 16
+        engine.reset_stats()
+        reqs = []
+        for i in range(0, len(prompts), 2):
+            for p, ad in zip(prompts[i:i + 2], plan[i:i + 2]):
+                reqs.append(engine.add_request(
+                    p, max_new_tokens=4 + i, adapter=ad,
+                    **stream_of(sampler, len(reqs))))
+            engine.step()
+            engine.step()
+        engine.run()
+        st = engine.stats()
+        assert st["decode_compiles"] == 0 == st["prefill_compiles"]
+        assert st["commit_rollbacks"] == 0
+        assert st["prefill_unawaited_share"] == 1.0
+        _assert_tenants_served(reference, engine, reqs)
+        if not sampler:
+            for i in (0, 3, 5):
+                assert reqs[i].generated == _ref(model, prompts[i],
+                                                 4 + 2 * (i // 2))
 
 
 class TestHotSwap:
-    def test_swap_between_steps_byte_exact_zero_recompiles(self, loop):
+    def test_swap_between_steps_byte_exact_zero_recompiles(self):
         m1 = _make_model(seed=0)
         m2 = _make_model(seed=1)
         w2 = [np.asarray(p._value) for p in m2.parameters()]
         p = _prompt(9, seed=51)
         ref1 = _gen(m1, p, 6)
         engine = LLMEngine(m1, max_batch_size=2, block_size=4,
-                           num_blocks=64, hot_swap=True,
-                           pipeline_decode=loop)
+                           num_blocks=64, hot_swap=True)
         assert engine.generate([p], max_new_tokens=6)[0] == ref1
         assert engine.weight_epoch == 0
         epoch = engine.swap_weights(w2)
@@ -648,32 +701,40 @@ class TestHotSwap:
         assert st["weight_swaps"] == 1
         assert st["weight_epoch"] == 1
 
-    def test_mid_run_swap_cutover_boundary_is_exact(self, loop):
+    @each_sampler
+    def test_mid_run_swap_cutover_boundary_is_exact(self, sampler):
         """Streams in flight at the cutover finish as: every token
         emitted before the swap is exactly the OLD weights' token,
         every token after is exactly the NEW weights' continuation of
-        (prompt + old tokens) — never a half-epoch token."""
+        (prompt + old tokens) — never a half-epoch token. A seeded
+        stream draws on at the positions it had reached."""
         m1 = _make_model(seed=0)
         m2 = _make_model(seed=1)
         w2 = [np.asarray(p._value) for p in m2.parameters()]
         prompts = [_prompt(8, seed=52), _prompt(10, seed=53)]
-        refs1 = [_gen(m1, p, 10) for p in prompts]
+        cfgs = [stream_of(sampler, i) for i in range(2)]
+        refs1 = [Reference(m1).serve(p, 10, **c)[0]
+                 for p, c in zip(prompts, cfgs)]
+        if not sampler:
+            assert refs1 == [_gen(m1, p, 10) for p in prompts]
         engine = LLMEngine(m1, max_batch_size=2, block_size=4,
-                           num_blocks=64, hot_swap=True,
-                           pipeline_decode=loop)
+                           num_blocks=64, hot_swap=True)
         reqs = [engine.add_request(p, max_new_tokens=10,
-                                   request_id=f"w{i}")
-                for i, p in enumerate(prompts)]
+                                   request_id=f"w{i}", **c)
+                for i, (p, c) in enumerate(zip(prompts, cfgs))]
         for _ in range(4):
             engine.step()
-        marks = [len(r.generated) for r in reqs]
-        assert any(k > 0 for k in marks)          # genuinely mid-flight
         engine.swap_weights(w2)                   # boundary: commits now
+        marks = [len(r.generated) for r in reqs]
+        assert any(0 < k < 10 for k in marks)     # genuinely mid-flight
         engine.run()
-        for r, p, ref1, k in zip(reqs, prompts, refs1, marks):
+        new = Reference(m2)
+        for r, p, c, ref1, k in zip(reqs, prompts, cfgs, refs1, marks):
             assert r.generated[:k] == ref1[:k]
-            cont = _gen(m2, p + ref1[:k], 10 - k)
+            cont, _ = new.serve(p + ref1[:k], 10 - k, **c)
             assert r.generated[k:] == cont
+            if not sampler:
+                assert cont == _gen(m2, p + ref1[:k], 10 - k)
         st = engine.stats()
         assert st["decode_compiles"] == 1
         assert st["weight_swaps"] == 1
@@ -730,10 +791,12 @@ class TestHotSwap:
 # ---------------------------------------------------------------------------
 
 class TestTenantCrashResume:
-    def test_snapshot_roundtrips_adapter_assignment(self, model):
+    def test_snapshot_roundtrips_adapter_assignment(self, model,
+                                                    reference):
         """A mid-flight snapshot carries each stream's adapter; the
         restored engine finishes them under the SAME adapter,
-        token-identically to the uninterrupted run."""
+        token-identically to an uninterrupted run (the reference's,
+        with the adapter folded into the weights)."""
         p1, p2 = _prompt(8, seed=61), _prompt(7, seed=62)
 
         def build():
@@ -741,15 +804,6 @@ class TestTenantCrashResume:
                           num_blocks=64, max_adapters=2, adapter_rank=2)
             e.register_adapter("tt", seed=8, scale=25.0)
             return e
-
-        full = build()
-        full.add_request(p1, max_new_tokens=8, request_id="u1",
-                         adapter="tt")
-        full.add_request(p2, max_new_tokens=8, request_id="u2")
-        full.run()
-        want = {rid: r.generated
-                for rid, r in full.pop_finished().items()}
-        assert want["u1"] != _ref(model, p1, 8)   # adapter is live
 
         half = build()
         half.add_request(p1, max_new_tokens=8, request_id="u1",
@@ -764,9 +818,11 @@ class TestTenantCrashResume:
         restored = fresh.restore_state(payload)
         fresh.run()
         by_rid = {r.rid: r for r in restored}
-        for rid, toks in want.items():
-            assert by_rid[rid].generated == toks
-            assert by_rid[rid].state == FINISHED
+        assert sorted(by_rid) == ["u1", "u2"]
+        assert all(r.state == FINISHED for r in restored)
+        _assert_tenants_served(reference, fresh, restored)
+        assert by_rid["u1"].generated != _ref(model, p1, 8)  # adapter live
+        assert by_rid["u2"].generated == _ref(model, p2, 8)
 
     def test_restore_refuses_unregistered_adapter(self, model):
         engine = LLMEngine(model, max_batch_size=2, block_size=4,
@@ -813,7 +869,7 @@ class TestTenantCrashResume:
 # ---------------------------------------------------------------------------
 
 class TestCombined:
-    def test_prefix_adapters_swap_one_executable(self, loop):
+    def test_prefix_adapters_swap_one_executable(self):
         """Scaled-down ISSUE 17 acceptance: streams over mixed tenants
         with a shared prefix, a mid-run weight swap — ONE decode
         compile through all of it."""
@@ -822,8 +878,7 @@ class TestCombined:
         w2 = [np.asarray(p._value) for p in m2.parameters()]
         engine = LLMEngine(m1, max_batch_size=4, block_size=4,
                            num_blocks=96, enable_prefix_cache=True,
-                           max_adapters=3, adapter_rank=2, hot_swap=True,
-                           pipeline_decode=loop)
+                           max_adapters=3, adapter_rank=2, hot_swap=True)
         engine.register_adapter("a1", seed=1, scale=25.0)
         engine.register_adapter("a2", seed=2, scale=25.0)
         prompts = _shared_prompts(8, prefix_len=12, suffix_len=2,

@@ -45,11 +45,12 @@ Serving scenarios (PR 7), the same methodology against LLMEngine:
 
   serve_hang        an injected decode hang (guardian.inject_fault
                     "hang") trips the FLAGS_serve_step_timeout_ms
-                    watchdog. Must hold: rung 1 (retry) recovers with the
-                    decode program still compiled exactly once, rung 2
-                    (two consecutive hangs) rebuilds and still finishes,
-                    every stream stays token-identical to generate(), and
-                    the doctor attributes `step_hang`.
+                    watchdog. Must hold: one hang (retry) recovers with
+                    the decode program still compiled exactly once and
+                    every stream token-identical to generate(), two in a
+                    row fail the active requests as `step_hang` and the
+                    queued one is then served token-identically, and the
+                    doctor attributes `step_hang`.
 
   serve_fused_fault a poisoned fused decode output (`nan_output` on
                     "serve.decode") discards the launch and finishes the
@@ -336,7 +337,7 @@ def scenario_serve_hang():
     from paddle_tpu.ops import guardian
     from paddle_tpu.profiler.events import clear_fusion_events
     from paddle_tpu.profiler.explain import explain
-    from paddle_tpu.serving import LLMEngine, FINISHED
+    from paddle_tpu.serving import LLMEngine, FAILED, FINISHED
 
     _arm_serve()
     set_flags({"FLAGS_serve_step_timeout_ms": 2000})
@@ -344,7 +345,7 @@ def scenario_serve_hang():
     refs = _serve_refs(model, prompts, 8)
     failures = []
     try:
-        # -- rung 1: one hang -> retry, same executable ---------------------
+        # -- the first rung: one hang -> retry, same executable -------------
         clear_fusion_events()
         engine = LLMEngine(model, max_batch_size=2, block_size=4)
         reqs = [engine.add_request(p, max_new_tokens=8) for p in prompts]
@@ -358,7 +359,7 @@ def scenario_serve_hang():
             failures.append("watchdog never fired on the injected hang")
         if st["decode_compiles"] != 1:
             failures.append(
-                f"rung 1 (retry) recompiled decode "
+                f"the retry recompiled decode "
                 f"{st['decode_compiles']}x, expected exactly 1")
         for r, ref in zip(reqs, refs):
             if r.state != FINISHED or r.generated != ref:
@@ -374,11 +375,9 @@ def scenario_serve_hang():
                 f"doctor verdict {rep['verdict']!r}, expected "
                 "serving_degraded")
 
-        # -- rung 2: two consecutive hangs -> rebuild, still finishes -------
-        # (the serial loop's ladder, by name: the pipelined loop has no
-        # rebuild rung, its second hang fails the batch)
-        engine2 = LLMEngine(model, max_batch_size=2, block_size=4,
-                            pipeline_decode=False)
+        # -- the last rung: two hangs in a row -> the active requests fail,
+        # the request that waited for a slot is served ---------------------
+        engine2 = LLMEngine(model, max_batch_size=2, block_size=4)
         reqs2 = [engine2.add_request(p, max_new_tokens=8) for p in prompts]
         for _ in range(3):
             engine2.step()
@@ -387,16 +386,23 @@ def scenario_serve_hang():
         guardian.clear_faults()
         st2 = engine2.stats()
         if st2["hangs"] != 2:
-            failures.append(f"expected 2 hangs at rung 2, saw "
+            failures.append(f"expected 2 hangs at the last rung, saw "
                             f"{st2['hangs']}")
         if st2["decode_compiles"] != 2:
             failures.append(
-                f"rung 2 (rebuild) should trace exactly once more "
+                f"fail-active should trace decode exactly once more "
                 f"(saw {st2['decode_compiles']} compiles)")
-        for r, ref in zip(reqs2, refs):
-            if r.state != FINISHED or r.generated != ref:
+        for r in reqs2[:2]:
+            if r.state != FAILED or r.error != "step_hang":
                 failures.append(
-                    f"stream {r.rid} not token-identical after rebuild")
+                    f"active stream {r.rid} not failed as step_hang "
+                    f"(state {r.state}, error {r.error})")
+        if reqs2[2].state != FINISHED or reqs2[2].generated != refs[2]:
+            failures.append(
+                f"queued stream {reqs2[2].rid} not served "
+                f"token-identically after fail-active")
+        if engine2.degraded:
+            failures.append("engine still degraded after serving again")
         return {"ok": not failures, "failures": failures,
                 "hangs": [st["hangs"], st2["hangs"]],
                 "doctor": rep["headline"]}
@@ -496,23 +502,22 @@ def scenario_telemetry():
             time.sleep(0.01)        # ~100 Hz across both endpoints
 
     try:
-        # two wedged steps in a row, then recovery: the SERIAL loop's
-        # ladder (retry, rebuild), by name; the pipelined loop's second
-        # hang fails the batch
-        engine = LLMEngine(model, max_batch_size=2, block_size=4,
-                           pipeline_decode=False)
-        reqs = [engine.add_request(p, max_new_tokens=8) for p in prompts]
-        for _ in range(3):
-            engine.step()           # warm + heartbeat established
+        # one step wedged at both of its commits, then recovery: the
+        # wait for the boundary's two prefills (two budgets, where
+        # liveness allows one) and the wait for the launch behind them
+        # each hang ONCE and are retried: what the ladder survives
+        engine = LLMEngine(model, max_batch_size=2, block_size=4)
+        engine.generate(prompts, max_new_tokens=2)  # warm + heartbeat
         st0, _ = probe("healthz")
         if st0 != 200:
-            failures.append("healthz not 200 on a healthy stepping "
-                            "engine")
+            failures.append("healthz not 200 on a healthy engine")
         thr = threading.Thread(target=scraper, daemon=True)
         thr.start()
         t_hang = time.perf_counter()
-        guardian.inject_fault("stall", op="serve.decode", times=2)
-        engine.run()                # wedges ~2x budget, then recovers
+        guardian.inject_fault("stall", op="serve.prefill", times=1)
+        guardian.inject_fault("stall", op="serve.decode", times=1)
+        reqs = [engine.add_request(p, max_new_tokens=8) for p in prompts]
+        engine.run()                # wedges ~3x budget, then recovers
         guardian.clear_faults()
         stop.set()
         thr.join(timeout=10)
@@ -612,10 +617,9 @@ def scenario_sentinel():
         engine.run()
 
     try:
-        # the storm below wedges two steps in a row and the streams
-        # must survive it: the serial loop's ladder, by name
-        engine = LLMEngine(model, max_batch_size=4, block_size=4,
-                           pipeline_decode=False)
+        # the storm below wedges two steps, one after the other, and the
+        # streams must survive it: each hang is its wait's first, retried
+        engine = LLMEngine(model, max_batch_size=4, block_size=4)
         filler(engine)              # decode compiled pre-calibration
         snt.arm(window_s=window_s)
         deadline = time.perf_counter() + 60
@@ -633,7 +637,9 @@ def scenario_sentinel():
 
         # -- the storm: two wedged decode steps mid-stream --------------
         t_inject = time.perf_counter()
-        guardian.inject_fault("stall", op="serve.decode", times=2)
+        guardian.inject_fault("stall", op="serve.decode", times=1)
+        # the retried wait passes this one by; the next step's trips it
+        guardian.inject_fault("stall", op="serve.decode", after=1, times=1)
         reqs = [engine.add_request(p, max_new_tokens=8) for p in prompts]
         engine.run()                # wedges ~2x budget, then recovers
         guardian.clear_faults()

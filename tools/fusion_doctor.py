@@ -234,7 +234,7 @@ def _demo_sample(steps):
     model = GPTForCausalLM(cfg)
     model.eval()
     engine = LLMEngine(model, max_batch_size=3, block_size=4,
-                       pipeline_decode=True, logprobs_topk=2)
+                       logprobs_topk=2)
     rng = np.random.default_rng(0)
     prompts = [rng.integers(0, 128, int(n)).tolist()
                for n in rng.integers(4, 12, max(6, steps))]
