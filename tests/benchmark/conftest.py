@@ -23,7 +23,8 @@ def root(tmp_path):
 def standing(request):
     """Every test of the real `BENCHMARK.json` runs twice: over the file
     as it stands, and over the file after the next PR's arrival (a
-    configuration, a cell, three per-layer entries at the END:
+    configuration, a cell that joins a shared per-layer entry's
+    `workloads`, two per-layer entries of its own at the END:
     `tiny_root.ARRIVAL`). A test that pins the list's tail, its length or
     its set of names fails here, in the PR that writes it."""
     return request.param

@@ -11,6 +11,7 @@ import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 import config_rules  # noqa: E402
+import metric_rules  # noqa: E402
 import tiny_root  # noqa: E402
 
 from benchmark import harness, run as bench_run  # noqa: E402
@@ -461,21 +462,99 @@ def test_metric_files_and_per_layer_metrics_are_the_same_set(spec,
                                            module + ".py")), reader
 
 
-@pytest.mark.parametrize("name,cell,kernel", [
-    ("longcat.decode_attn_time_share", "serve_longcat_decode",
+def _file_of(spec_root):
+    return lambda m: metric_rules.metric_file(spec_root, m["name"])
+
+
+def test_no_two_per_layer_entries_are_twins(spec, spec_root):
+    """One reader over one key, moving one end-to-end metric in one unit,
+    is ONE entry whose `workloads` lists its cells (`metric_rules`): the
+    test that would have kept the list from filling. A cell that arrives
+    JOINS such an entry (`tiny_root.ARRIVAL["joins"]`)."""
+    assert metric_rules.twins(spec["per_layer"], _file_of(spec_root)) == []
+
+
+def test_the_twin_rule_sees_a_twin_written_out_again(spec, spec_root):
+    """What PRs 32-42 did once a cell: the same file under another name,
+    its summed lists in another order."""
+    [shared] = [m for m in spec["per_layer"]
+                if m["name"] == "longcat.held_choice_share"]
+    file_of = _file_of(spec_root)
+    again = dict(shared, name="next.held_choice_share",
+                 workloads=["next_cell"])
+    turned = file_of(shared)
+    turned["args"]["under"].reverse()
+
+    def with_next(m):
+        return turned if m["name"] == again["name"] else file_of(m)
+    assert metric_rules.twins(spec["per_layer"] + [again], with_next) == [
+        ["longcat.held_choice_share", "next.held_choice_share"]]
+    # another end-to-end metric moved: the four-chip cell's own entry
+    assert metric_rules.twins(
+        spec["per_layer"] + [dict(again, moves="mesh_train_tokens_per_s")],
+        with_next) == []
+
+
+def test_per_layer_is_inside_its_limit_with_room_to_spare(spec):
+    n = len(spec["per_layer"])
+    free = metric_rules.PER_LAYER_MAX - n
+    assert n <= metric_rules.PER_LAYER_MAX, (
+        f"`per_layer` holds {n} entries, {-free} over the "
+        f"{metric_rules.PER_LAYER_MAX} it may hold: merge twins "
+        "(`metric_rules.twins`) or retire what tells nothing")
+    # ISSUE 47 left at most 100: a PR that brings the list back over 120
+    # says so here, with the room that is left, before the list is full
+    assert n <= 120, f"{free} entries free of {metric_rules.PER_LAYER_MAX}"
+
+
+def test_a_shared_entry_is_read_for_each_of_its_cells_and_no_third(root):
+    """One entry whose `workloads` names two cells is read for each of
+    them, through the one file, and for no cell it does not name."""
+    mix = ("second_mix", tiny_root.TRAFFIC["tiny_backlog"])
+    shared = "backlog.host_step_ms"
+    tiny_root.add_cell(root, "second_cell", ("second_gpt",
+                                             tiny_root.TINY_MODEL), mix,
+                       tiny_root.SERVE_LIMITS,
+                       ("serve_tokens_per_s", shared))
+    tiny_root.add_cell(root, "third_cell", ("third_gpt",
+                                            tiny_root.TINY_MODEL), mix,
+                       tiny_root.SERVE_LIMITS, ("serve_tokens_per_s",))
+    spec = json.load(open(os.path.join(root, "BENCHMARK.json")))
+    [entry] = [m for m in spec["per_layer"] if m["name"] == shared]
+    assert entry["workloads"] == ["tiny_backlog_cell", "second_cell"]
+    read = {}
+    for cell in ("tiny_backlog_cell", "second_cell", "third_cell"):
+        run = harness.Run(root, cell, 1, 0.5, True, require_chip=False)
+        run.evidence["engine_stats"] = {"step_ms_per_step": 2.5,
+                                        "occupancy_mean": 0.5}
+        read[cell] = run.per_layer_metrics()
+    for cell in entry["workloads"]:
+        assert read[cell][shared] == {"value": 2.5, "unit": "ms"}
+    assert shared not in read["third_cell"]
+    # an entry that names one cell is that cell's alone
+    assert read["tiny_backlog_cell"]["backlog.batch_occupancy"][
+        "value"] == 50.0
+    assert "backlog.batch_occupancy" not in read["second_cell"]
+    assert read["third_cell"] == {}
+
+
+@pytest.mark.parametrize("name,cells,kernel", [
+    ("longcat.decode_attn_time_share", ["serve_longcat_decode"],
      "latent_decode_attention"),
-    ("backlog.decode_attn_time_share", "serve_124m_backlog",
+    # LFM2's decode kernel is GPT's: one pattern, so one entry (ISSUE 47)
+    ("backlog.decode_attn_time_share",
+     ["serve_124m_backlog", "serve_lfm2_rag_backlog"],
      "paged_decode_attention"),
 ])
 def test_a_decode_kernels_share_of_device_time_is_a_data_file(
-        spec, spec_root, name, cell, kernel):
+        spec, spec_root, name, cells, kernel):
     """ISSUE 39's two: entries and data files over the reader that was
     there, matching the kernel's own instruction (the `pallas_call`'s
     name) and no instruction that takes its result."""
     [entry] = [m for m in spec["per_layer"] if m["name"] == name]
     [twin] = [m for m in spec["per_layer"]
               if m["name"] == "backlog.kv_copy_time_share"]
-    assert entry == dict(twin, name=name, workloads=[cell])
+    assert entry == dict(twin, name=name, workloads=cells)
     metric = json.load(open(os.path.join(spec_root, "benchmark", "metrics",
                                          name + ".json")))
     assert metric["reader"] == "benchmark.readers.trace_op_share"
@@ -485,10 +564,13 @@ def test_a_decode_kernels_share_of_device_time_is_a_data_file(
     assert not pattern.search(f"%fusion.3 = f32[8]{{0}} fusion(%{kernel}.9)")
 
 
-@pytest.mark.parametrize("name", ["backlog.prefill_wall_share",
-                                  "backlog.decode_step_p50_ms"])
+@pytest.mark.parametrize("name", [
+    "backlog.prefill_wall_share", "backlog.decode_step_p50_ms",
+    *(old for old, new in metric_rules.RENAMED.items()
+      if new == metric_rules.RETIRED)])
 def test_a_retired_metric_is_gone_with_its_file(spec, spec_root, name):
-    """Read nothing since PR 31 (`PERF.md` section 6, PR 39)."""
+    """Read nothing since PR 31 (`PERF.md` section 6, PR 39); the mid of a
+    log bucket beside its exact `*_ms_per_step` twin (PR 47)."""
     assert name not in {m["name"] for m in spec["per_layer"]}
     assert not os.path.exists(os.path.join(spec_root, "benchmark",
                                            "metrics", name + ".json"))
@@ -496,21 +578,29 @@ def test_a_retired_metric_is_gone_with_its_file(spec, spec_root, name):
 
 def test_an_arrival_appends_and_edits_nothing_that_was_there():
     """What the second case of every `spec` test stands on: the arrival
-    is entries at the END of each list, one cell more in one end-to-end
-    metric's `workloads`, and nothing else."""
+    is entries at the END of each list, one cell more in the `workloads`
+    of one end-to-end metric and of the shared per-layer entries it joins,
+    and nothing else."""
     before = tiny_root.spec_of("as_it_stands")
     after = tiny_root.spec_of("with_an_arrival")
     cell = tiny_root.ARRIVAL["cell"]["name"]
-    for key, more in (("configs", 1), ("workloads", 1), ("per_layer", 3)):
-        assert after[key][:len(before[key])] == before[key]
+    own = list(tiny_root.ARRIVAL["per_layer"])
+    joined = (tiny_root.ARRIVAL["end_to_end"], *tiny_root.ARRIVAL["joins"])
+    for key, more in (("configs", 1), ("workloads", 1),
+                      ("per_layer", len(own))):
         assert len(after[key]) == len(before[key]) + more
-    assert [m["name"] for m in after["per_layer"][-3:]] == list(
-        tiny_root.ARRIVAL["per_layer"])
-    for was, now in zip(before["end_to_end"], after["end_to_end"]):
-        if now["name"] == tiny_root.ARRIVAL["end_to_end"]:
+    for key in ("configs", "workloads"):
+        assert after[key][:len(before[key])] == before[key]
+    assert [m["name"] for m in after["per_layer"][-len(own):]] == own
+    seen = set()
+    for was, now in zip(before["end_to_end"] + before["per_layer"],
+                        after["end_to_end"] + after["per_layer"]):
+        if now["name"] in joined:
             assert now == dict(was, workloads=was["workloads"] + [cell])
+            seen.add(now["name"])
         else:
             assert now == was
+    assert seen == set(joined)
     for key in ("command", "paths", "run_seconds"):
         assert after[key] == before[key]
 

@@ -10,12 +10,15 @@ import sys
 import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+import metric_rules  # noqa: E402
 import tiny_root  # noqa: E402
 
 from benchmark import harness, run as bench_run  # noqa: E402
 from benchmark.readers import engine_stat, train_step_stat  # noqa: E402
 
 REPO = tiny_root.REPO
+# the names ISSUE 37 gave; since ISSUE 47 the `longcat.*` five are their
+# `backlog.*` twins' entries, found through the table (`metric_rules.today`)
 # metric -> (reader, key of `stats()`, scale)
 PER_STEP = ("prefill_dispatch", "decode_dispatch", "decode_fetch", "stream",
             "kv_grow", "prefill_commit", "host_wait", "step_unattributed",
@@ -100,15 +103,17 @@ def test_the_table_of_the_issue_is_all_there(spec):
     assert len(NEW) == 21     # ISSUE 37's nineteen and `ran_dry_…` twice
     # membership, not position: later PRs append after them (`spec` is
     # the file as it stands and again with an arrival: `conftest.py`)
-    assert set(NEW) <= {m["name"] for m in spec["per_layer"]}
+    assert {metric_rules.today(n) for n in NEW} <= {
+        m["name"] for m in spec["per_layer"]}
 
 
 @pytest.mark.parametrize("name", sorted(NEW))
 def test_a_metric_is_a_file_over_a_reader_and_a_key_that_exist(
         spec, spec_root, program_stats, name):
-    [entry] = [m for m in spec["per_layer"] if m["name"] == name]
+    today = metric_rules.today(name)
+    [entry] = [m for m in spec["per_layer"] if m["name"] == today]
     real_cell, _ = CELL_OF[name.split(".")[0]]
-    assert entry["workloads"] == [real_cell]
+    assert real_cell in entry["workloads"]
     assert entry["better"] == "lower"
     counter = name.endswith("_share")
     assert entry["source"] == ("program_counter" if counter
@@ -120,7 +125,7 @@ def test_a_metric_is_a_file_over_a_reader_and_a_key_that_exist(
             "train_1p3b_mesh4": "mesh_train_tokens_per_s"}[real_cell]
     assert entry["moves"] == rate
     with open(os.path.join(spec_root, "benchmark", "metrics",
-                           name + ".json")) as f:
+                           today + ".json")) as f:
         metric = json.load(f)
     reader, key = NEW[name]
     assert metric["reader"] == "benchmark.readers." + reader

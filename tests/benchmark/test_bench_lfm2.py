@@ -16,6 +16,7 @@ import pytest
 HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path[:0] = [HERE, os.path.join(HERE, "lfm2_model")]
 import config_rules  # noqa: E402
+import metric_rules  # noqa: E402
 import tiny_root  # noqa: E402
 from tiny_lfm2 import TINY_LFM2  # noqa: E402
 
@@ -40,8 +41,11 @@ CELL, CONFIG = "serve_lfm2_rag_backlog", "lfm2_8b_a1b_l16"
 LIMITS = {"logit_gap": 0.9, "logit_gap_mean": 0.01}
 STREAMS, PROMPT, SERVED, PAD = 8, 8, 24, 32
 SPEC = tiny_root.spec_of("as_it_stands")
+# the cell's metrics are every entry whose `workloads` names it: its own
+# (`lfm2.*`) and the shared ones of its loop and its expert block, which
+# it joined (ISSUE 47; a prefix names who brought an entry, not a cell)
 LFM2_METRICS = [m["name"] for m in SPEC["per_layer"]
-                if m["name"].startswith("lfm2.")]
+                if CELL in m["workloads"]]
 PEAKS = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
 
 
@@ -74,7 +78,7 @@ def test_the_tiny_cell_runs_on_the_backlog_loop_and_is_correct(lfm2_root,
 
 def test_a_traced_run_reports_every_metric_of_the_cell(lfm2_root,
                                                        monkeypatch):
-    """Every `lfm2.*` metric appears, finite, with a canned device trace
+    """Every metric of the cell appears, finite, with a canned device trace
     (the CPU gives the profiler no device plane) and canned peaks; a
     share of a peak stays inside 0..100."""
     import contextlib
@@ -100,23 +104,29 @@ def test_a_traced_run_reports_every_metric_of_the_cell(lfm2_root,
                               require_chip=False)
     # the CPU's backend reports no memory peak: that one reader finds
     # nothing to read and its metric is left out, not raised
-    assert set(line["metrics"]) == set(LFM2_METRICS) - {"lfm2.hbm_peak_gb"}
+    assert set(line["metrics"]) == set(LFM2_METRICS) \
+        - {"backlog.hbm_peak_gb"}
     value = {k: m["value"] for k, m in line["metrics"].items()}
     assert all(np.isfinite(v) for v in value.values()), value
     for name in ("lfm2.serve_mfu", "lfm2.decode_hbm_roofline",
                  "lfm2.expert_products_roofline",
-                 "lfm2.decode_attn_roofline", "lfm2.kv_pool_filled_share",
+                 "lfm2.decode_attn_roofline",
+                 "backlog.kv_pool_filled_share",
                  "lfm2.prefill_padding_share",
-                 "lfm2.pipelined_launch_share"):
+                 "backlog.pipelined_launch_share"):
         assert 0 < value[name] <= 100, name
     # every expert is held: nothing is routed elsewhere, at any size
-    assert value["lfm2.held_choice_share"] == 100.0
+    assert value["longcat.held_choice_share"] == 100.0
     assert value["lfm2.expert_load_max_over_mean"] >= 1
+    # the CPU's grouped products are `ragged_dot`'s, none the tiled
+    # kernel's; a call's programs take ONE host array each (PR 43)
+    assert value["lfm2.expert_kernel_product_share"] == 0.0
+    assert value["backlog.host_arrays_per_dispatch"] == 1.0
     # the anchored patterns count the kernels, not a fusion that takes
     # their result
     assert value["lfm2.expert_products_time_share"] == \
         pytest.approx(100 * 0.3 / 0.9)
-    assert value["lfm2.decode_attn_time_share"] == \
+    assert value["backlog.decode_attn_time_share"] == \
         pytest.approx(100 * 0.1 / 0.9)
     assert value["lfm2.prefill_attn_time_share"] == \
         pytest.approx(100 * 0.05 / 0.9)
@@ -303,18 +313,25 @@ def test_the_cell_and_its_traffic_are_the_issues():
     assert set(limits) == set(serving.COMPARED)
 
 
-def test_every_metric_of_the_cell_names_it_alone_and_has_its_file():
-    assert len(LFM2_METRICS) == 22
-    for m in SPEC["per_layer"]:
-        if m["name"].startswith("lfm2."):
-            assert m["workloads"] == [CELL]
-            assert m["moves"] == ("setup_s" if m["name"].endswith(
-                "programs_compile_s") else "serve_tokens_per_s")
-            spec = json.load(open(os.path.join(
-                REPO, "benchmark", "metrics", m["name"] + ".json")))
-            assert spec["reader"].startswith("benchmark.readers.")
-    shares = [m for m in SPEC["per_layer"] if m["name"].startswith("lfm2.")
-              and ("roofline" in m["name"] or "mfu" in m["name"])]
+def test_every_metric_of_the_cell_names_it_and_has_its_file():
+    """Membership, not a count: what ISSUE 40 brought (the parent's
+    entries that named this cell, under today's names) is all among the
+    entries that name the cell, and later PRs append or join beside it."""
+    brought = {metric_rules.today(m["name"])
+               for m in metric_rules.PARENT_PER_LAYER
+               if m["workloads"] == [CELL]}
+    assert len(brought) == 22 and brought <= set(LFM2_METRICS)
+    assert "lfm2.expert_kernel_product_share" in LFM2_METRICS   # ISSUE 41's
+    mine = [m for m in SPEC["per_layer"] if CELL in m["workloads"]]
+    for m in mine:
+        assert m["moves"] == ("setup_s" if m["name"].endswith(
+            "programs_compile_s") else "serve_tokens_per_s")
+        spec = json.load(open(os.path.join(
+            REPO, "benchmark", "metrics", m["name"] + ".json")))
+        assert spec["reader"].startswith("benchmark.readers.")
+    shares = [m for m in mine
+              if "roofline" in m["name"] or "mfu" in m["name"]]
+    assert len(shares) >= 4
     assert all(m["unit"] == "%" and m["better"] == "higher" for m in shares)
 
 

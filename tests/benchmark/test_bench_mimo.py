@@ -17,6 +17,7 @@ import pytest
 HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path[:0] = [HERE, os.path.join(HERE, "mimo_model")]
 import config_rules  # noqa: E402
+import metric_rules  # noqa: E402
 import tiny_root  # noqa: E402
 from tiny_mimo import TINY_MIMO  # noqa: E402
 
@@ -40,8 +41,11 @@ CELL, CONFIG = "serve_mimo_reasoning_8k", "mimo_v2_flash_ep32"
 LIMITS = {"logit_gap": 0.6, "logit_gap_mean": 0.003}
 STREAMS, PROMPT, SERVED, PAD = 8, 8, 24, 32
 SPEC = tiny_root.spec_of("as_it_stands")
+# the cell's metrics are every entry whose `workloads` names it: its own
+# (`mimo.*`) and the shared ones of its loop and its expert block, which
+# it joined (ISSUE 47; a prefix names who brought an entry, not a cell)
 MIMO_METRICS = [m["name"] for m in SPEC["per_layer"]
-                if m["name"].startswith("mimo.")]
+                if CELL in m["workloads"]]
 PEAKS = {"bf16_flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
 
 
@@ -74,11 +78,12 @@ def test_the_tiny_cell_runs_on_the_backlog_loop_and_is_correct(mimo_root,
 
 def test_a_traced_run_reports_every_metric_of_the_cell(mimo_root,
                                                        monkeypatch):
-    """Every `mimo.*` metric appears, finite, with a canned device trace
+    """Every metric of the cell appears, finite, with a canned device trace
     (the CPU gives the profiler no device plane) and canned peaks; a
     share of a peak stays inside 0..100. The tiny calls are under the
-    tokens from which the products are grouped, so the one metric that
-    reads the grouped products finds nothing and is left out."""
+    tokens from which the products are grouped, so the three metrics that
+    read the grouped products find nothing (a roofline, a share of no
+    products) or no instruction (a share of device time: 0.0)."""
     import contextlib
 
     @contextlib.contextmanager
@@ -102,8 +107,11 @@ def test_a_traced_run_reports_every_metric_of_the_cell(mimo_root,
     monkeypatch.setattr(harness.Run, "traced_slice", traced_slice)
     line = bench_run.run_cell(mimo_root, "mimo_cell", 5, 1.0, True,
                               require_chip=False)
+    # the CPU's backend reports no memory peak: that reader finds
+    # nothing to read either
     assert set(line["metrics"]) == set(MIMO_METRICS) \
-        - {"mimo.expert_products_roofline"}
+        - {"mimo.expert_products_roofline",
+           "lfm2.expert_kernel_product_share", "backlog.hbm_peak_gb"}
     value = {k: m["value"] for k, m in line["metrics"].items()}
     assert all(np.isfinite(v) for v in value.values()), value
     for name in ("mimo.serve_mfu", "mimo.decode_hbm_roofline",
@@ -118,6 +126,24 @@ def test_a_traced_run_reports_every_metric_of_the_cell(mimo_root,
         pytest.approx(100 * 0.1 / 0.9)
     assert value["mimo.window_attn_time_share"] == \
         pytest.approx(100 * 0.06 / 0.9)
+    # both prefill kernels' instructions (the canned trace holds the
+    # band's alone), and what the cell joined of its loop's and its
+    # expert block's shared entries (ISSUE 47)
+    assert value["mimo.prefill_attn_time_share"] == \
+        pytest.approx(100 * 0.05 / 0.9)
+    assert value["lfm2.expert_products_time_share"] == 0.0
+    assert value["backlog.device_idle_share"] == pytest.approx(10.0)
+    for name in ("backlog.kv_pool_filled_share",
+                 "backlog.pipelined_launch_share",
+                 "backlog.prefill_unawaited_share",
+                 "longcat.held_choice_share"):
+        assert 0 < value[name] <= 100, name
+    assert value["backlog.attn_streamed_share"] > 0
+    assert value["longcat.expert_load_max_over_mean"] >= 1
+    assert value["backlog.host_arrays_per_dispatch"] == 1.0
+    assert value["backlog.host_step_ms"] \
+        >= value["backlog.host_wait_ms_per_step"] >= 0
+    assert value["backlog.programs_compile_s"] > 0
 
 
 def served_in(precision, seed):
@@ -365,19 +391,38 @@ def test_the_cell_and_its_traffic_are_the_issues():
     assert set(limits) == set(serving.COMPARED)
 
 
-def test_every_metric_of_the_cell_names_it_alone_and_has_its_file():
-    """Nine: `per_layer` may hold 128 entries and held 119."""
-    assert len(MIMO_METRICS) == 9
-    for m in SPEC["per_layer"]:
-        if m["name"].startswith("mimo."):
-            assert m["workloads"] == [CELL]
-            assert m["moves"] == "serve_tokens_per_s"
-            spec = json.load(open(os.path.join(
-                REPO, "benchmark", "metrics", m["name"] + ".json")))
-            assert spec["reader"].startswith("benchmark.readers.")
-    shares = [m for m in SPEC["per_layer"] if m["name"].startswith("mimo.")
-              and ("roofline" in m["name"] or "mfu" in m["name"])]
-    assert len(shares) == 6
+def test_every_metric_of_the_cell_names_it_and_has_its_file():
+    """Membership, not a count: PR 42's nine (`per_layer` held 119 of 128)
+    are among the entries that name the cell, beside what it joined when
+    ISSUE 47 made room (what every cell of its loop reports, by its name
+    in their `workloads`: no entry, no file) and what later PRs add."""
+    brought = {metric_rules.today(m["name"])
+               for m in metric_rules.PARENT_PER_LAYER
+               if m["workloads"] == [CELL]}
+    assert len(brought) == 9 and brought <= set(MIMO_METRICS)
+    joined = {"backlog.kv_pool_filled_share", "backlog.attn_streamed_share",
+              "backlog.prefill_span_share", "backlog.decode_span_share",
+              "backlog.host_step_ms", "backlog.host_wait_ms_per_step",
+              "backlog.ran_dry_dispatch_share", "backlog.programs_compile_s",
+              "backlog.device_idle_share", "backlog.hbm_peak_gb",
+              "backlog.prefill_unawaited_share",
+              "backlog.pipelined_launch_share", "longcat.held_choice_share",
+              "longcat.expert_load_max_over_mean",
+              "lfm2.expert_products_time_share",
+              "lfm2.expert_kernel_product_share",
+              "backlog.host_arrays_per_dispatch"}
+    assert joined <= set(MIMO_METRICS) and not joined & brought
+    assert "mimo.prefill_attn_time_share" in MIMO_METRICS
+    mine = [m for m in SPEC["per_layer"] if CELL in m["workloads"]]
+    for m in mine:
+        assert m["moves"] == ("setup_s" if m["name"].endswith(
+            "programs_compile_s") else "serve_tokens_per_s")
+        spec = json.load(open(os.path.join(
+            REPO, "benchmark", "metrics", m["name"] + ".json")))
+        assert spec["reader"].startswith("benchmark.readers.")
+    shares = [m for m in mine
+              if "roofline" in m["name"] or "mfu" in m["name"]]
+    assert len(shares) >= 6
     assert all(m["unit"] == "%" and m["better"] == "higher" for m in shares)
 
 

@@ -78,8 +78,10 @@ def _dump(path, obj):
 # What a `model_config` PR brings, as files and entries: one configuration
 # (a layer pattern of the irregular kind: one leading dense layer under
 # `num_dense_layers`, period 3, a tail that departs from it), one cell
-# appended to an end-to-end metric's `workloads`, three per-layer entries at
-# the END of the list, each a data file over a reader that exists.
+# appended to an end-to-end metric's `workloads` AND to the `workloads` of the
+# shared per-layer entries that every cell of its loop reports (`joins`: no
+# entry, no file), two per-layer entries of its own at the END of the list,
+# each a data file over a reader that exists.
 STANDINGS = ("as_it_stands", "with_an_arrival")
 _PUBLISHED_TYPES = ["conv"] + ["full_attention", "conv", "conv"] * 3 \
     + ["full_attention", "conv"] * 2
@@ -104,6 +106,10 @@ ARRIVAL = {
     "cell": {"name": "serve_arrival_decode", "config": "arrival_hybrid",
              "traffic": "arrival_backlog", "chips": 1, "why": "test"},
     "end_to_end": "serve_tokens_per_s",
+    # what every cell of the `serve_backlog` loop reports is JOINED: an
+    # entry of its own over `engine_stat`'s `step_ms_per_step` would be a
+    # twin of this one (`test_no_two_per_layer_entries_are_twins`)
+    "joins": ["backlog.host_step_ms"],
     "per_layer": {
         "arrival.conv_time_share": {
             "unit": "%", "source": "device_trace",
@@ -112,11 +118,7 @@ ARRIVAL = {
         "arrival.decode_attn_roofline": {
             "unit": "%", "source": "device_trace", "better": "higher",
             "file": {"reader": "benchmark.readers.trace_kernel_roofline",
-                     "args": {"pattern": "grouped_decode_attention"}}},
-        "arrival.host_step_ms": {
-            "unit": "ms", "source": "program_span",
-            "file": {"reader": "benchmark.readers.engine_stat",
-                     "args": {"key": "step_ms_per_step"}}}},
+                     "args": {"pattern": "grouped_decode_attention"}}}},
 }
 
 
@@ -131,8 +133,8 @@ def spec_of(standing):
     cell = ARRIVAL["cell"]["name"]
     spec["configs"].append(copy.deepcopy(ARRIVAL["config"]))
     spec["workloads"].append(dict(ARRIVAL["cell"]))
-    for m in spec["end_to_end"]:
-        if m["name"] == ARRIVAL["end_to_end"]:
+    for m in spec["end_to_end"] + spec["per_layer"]:
+        if m["name"] in (ARRIVAL["end_to_end"], *ARRIVAL["joins"]):
             m["workloads"].append(cell)
     for name, m in ARRIVAL["per_layer"].items():
         spec["per_layer"].append({
