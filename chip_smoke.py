@@ -153,6 +153,33 @@ def make_latent_model(sizes, seed):
     return LongCatFlashForCausalLM(cfg, weights=weights)
 
 
+# A Solar-Open2 small enough to compile in seconds whose KDA layers keep
+# the cell's two state parts at their published shapes a head (a matrix of
+# 128 x 128 float32, convolutions of 4 taps over heads of 128): one gated
+# softmax layer and three delta-rule layers, every expert chosen (no
+# discrete choice, as above).
+STATE_SMOKE = dict(
+    vocab_size=4096, hidden_size=512, moe_intermediate_size=256,
+    num_hidden_layers=4,
+    layer_types=("full_attention",) + ("linear_attention",) * 3,
+    num_attention_heads=8, num_key_value_heads=2, head_dim=128,
+    linear_num_heads=8, linear_head_dim=128, n_routed_experts=4,
+    num_experts_per_tok=4, max_position_embeddings=1024)
+
+
+def make_state_model(sizes, seed):
+    """`SolarOpen2ForCausalLM` at `sizes` around bf16 weights from `seed`:
+    the model's own draws (norm scales 1, decays as a trained model's)."""
+    import jax.numpy as jnp
+    from paddle_tpu.incubate.models.solar_open2 import (
+        SolarOpen2Config, SolarOpen2ForCausalLM, _initial, param_shapes)
+    cfg = SolarOpen2Config(**sizes)
+    rng = np.random.default_rng(seed)
+    return SolarOpen2ForCausalLM(cfg, weights={
+        name: jnp.asarray(_initial(name, shape, cfg, rng), jnp.bfloat16)
+        for name, shape in param_shapes(cfg).items()})
+
+
 def make_optimizer(model):
     import paddle_tpu as paddle
     return paddle.optimizer.AdamW(learning_rate=1e-4, weight_decay=0.01,
@@ -394,6 +421,32 @@ def latent_serve_phase(sizes, prompt_lens, max_new_tokens, seed):
                       seed, with_generate=False)
 
 
+def state_serve_phase(sizes, prompt_lens, max_new_tokens, seed):
+    """The same legs beside a PER-SLOT STATE OF TWO PARTS
+    (`make_state_model`): every prefill writes the convolutions' inputs
+    (bf16) and the delta rule's matrices (float32) whole, every launch
+    moves the active slots' one token on where they lie (on a TPU through
+    the update's Pallas kernel). Its `generate` is not run (a token at a
+    time through the chunked scan, no compiled loop); every stream is
+    held to the model's own full forward, where the rule is one scan
+    over the whole sequence."""
+    model = make_state_model(sizes, seed)
+    parts = model.cache_spec().state_parts
+    check([str(np.dtype(dtype or "bfloat16")) for _, _, dtype in parts]
+          == ["bfloat16", "float32"] and len(parts[1][1]) == 3,
+          f"the state's parts are {parts}")
+    # twice the attention legs' slack: a served token's logit passes, a
+    # KDA layer, through the bf16 roundings of q and k behind their norms
+    # and of the gated output besides an attention layer's one, and the
+    # launch's recurrence and the forward's chunked scan sum in another
+    # order. On the v5e (PR 48) three of 16 streams read 0.0391 where the
+    # attention legs' 4 roundings allow 0.0349; a state lost or stale reads
+    # the logits' own spread, tens of times that.
+    return serve_legs(model, "serve_state", sizes["vocab_size"],
+                      prompt_lens, max_new_tokens, seed,
+                      with_generate=False, roundings=8)
+
+
 def grouped_matmul_phase(rows, k, n, experts, seed, interpret=False):
     """The tiled grouped matmul kernel against `jax.lax.ragged_dot` over
     the same sorted rows: `rows` bf16 rows in `experts` uneven groups (one
@@ -433,7 +486,7 @@ def grouped_matmul_phase(rows, k, n, experts, seed, interpret=False):
 
 
 def serve_legs(model, phase, vocab_size, prompt_lens, max_new_tokens, seed,
-               with_generate=True):
+               with_generate=True, roundings=4):
     """Serve the same requests with the blockwise loop and with the Pallas
     kernel, each asked for by name (unasked, the engine chooses between
     them from the platform and the pool), and hold every stream to the
@@ -467,7 +520,7 @@ def serve_legs(model, phase, vocab_size, prompt_lens, max_new_tokens, seed,
     # `generate` is known to reproduce on the chip, must do so exactly.
     pad_to = -(-(max(prompt_lens) + max_new_tokens) // 128) * 128
     gaps, scale = greedy_gaps(model, asked, served, pad_to)
-    slack = 4 * BF16_EPS * scale
+    slack = roundings * BF16_EPS * scale
     check(max(gaps) <= slack,
           f"a served token is not the reference's choice: worst logit gaps "
           f"{[round(g, 4) for g in gaps]} (bf16 slack {slack:.4f})")
@@ -614,6 +667,13 @@ def run_one_chip(seed, cache_dir):
     for rec in latent_serve_phase(LATENT_SMOKE,
                                   [32, 57, 200, 256, 460, 500, 512, 600],
                                   max_new_tokens=64, seed=seed):
+        emit(rec)
+    gc.collect()
+    # a state of two parts beside the pool: prompts inside one chunk of
+    # the scan (64), across chunks and across a span (512)
+    for rec in state_serve_phase(STATE_SMOKE,
+                                 [32, 57, 64, 130, 200, 256, 460, 600],
+                                 max_new_tokens=64, seed=seed):
         emit(rec)
     gc.collect()
     # a decode launch of `serve_lfm2_rag_backlog`: 128 tokens' top 4 of 32

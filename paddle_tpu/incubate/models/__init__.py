@@ -20,3 +20,7 @@ from . import mimo_v2_flash  # noqa: F401
 from .mimo_v2_flash import (  # noqa: F401
     MiMoV2FlashConfig, MiMoV2FlashForCausalLM,
 )
+from . import solar_open2  # noqa: F401
+from .solar_open2 import (  # noqa: F401
+    SolarOpen2Config, SolarOpen2ForCausalLM,
+)

@@ -176,6 +176,34 @@ def _rotate_half(x, pos, theta):
                            -1).astype(x.dtype)
 
 
+def grouped_causal_attention(q, k, v, past):
+    """Causal attention of q ``[B, T, H, D]`` over k, v ``[B, total,
+    KH, D]``, `past` rows of which precede q's own: a prompt of whole
+    tiles on a TPU through the flash kernel, the key/value heads
+    repeated in front of it (8 MB a layer at 2,048 tokens; the kernel
+    file is not touched, so no other model's step lowers anew), else
+    one ``[B, H, T, total]`` array of scores a group of queries."""
+    from ...kernels import flash_attention as fa
+    b, t, h, hd = q.shape
+    kh = k.shape[2]
+    group = h // kh
+    if past == 0 and fa.is_eligible(q, q, q, None, 0.0, is_causal=True):
+        with jax.named_scope("prefill_flash_attention"):
+            return fa.flash_attention_bnhd(
+                q, jnp.repeat(k, group, axis=2),
+                jnp.repeat(v, group, axis=2), True,
+                1.0 / math.sqrt(hd))
+    total = k.shape[1]
+    s = jnp.einsum("bqkgd,btkd->bkgqt", q.reshape(b, t, kh, group, hd),
+                   k, preferred_element_type=jnp.float32) \
+        / math.sqrt(hd)
+    keep = jnp.arange(total)[None, :] <= past + jnp.arange(t)[:, None]
+    prob = jax.nn.softmax(jnp.where(keep, s, -jnp.inf), axis=-1)
+    o = jnp.einsum("bkgqt,btkd->bqkgd", prob, v,
+                   preferred_element_type=jnp.float32)
+    return o.reshape(b, t, h, hd).astype(v.dtype)
+
+
 class Lfm2MoeForCausalLM(Layer):
     """The whole model as one `Layer`: its parameters by the names of
     `param_shapes`, its forward in `jax.numpy`.
@@ -234,7 +262,8 @@ class Lfm2MoeForCausalLM(Layer):
         return CacheSpec.per_head(
             kinds.count(ATTENTION), cfg.num_key_value_heads, cfg.head_dim,
             query_heads=cfg.num_attention_heads,
-            state_layers=kinds.count(CONV), state_shape=cfg.conv_state)
+            state_layers=kinds.count(CONV),
+            state_parts=(("conv", cfg.conv_state, None),))
 
     def pop_serve_counters(self):
         """The counters of the forward just traced, summed over the
@@ -244,9 +273,9 @@ class Lfm2MoeForCausalLM(Layer):
 
     def gen_caches(self, batch_size, dtype=None):
         """Dense caches with no token in them, in the order a forward takes
-        them: a (keys, values) pair for each attention layer, then one
-        state (zeros: what lies before a sequence) for each convolution
-        layer."""
+        them: a (keys, values) pair for each attention layer, then the
+        state's one part (zeros: what lies before a sequence), in a
+        tuple, for each convolution layer."""
         cfg = self.config
         dtype = dtype or self._w("model.embedding_norm.weight").dtype
         kinds = cfg.layer_types
@@ -254,7 +283,7 @@ class Lfm2MoeForCausalLM(Layer):
             (batch_size, 0, cfg.num_key_value_heads, cfg.head_dim), dtype))
         state = Tensor(jnp.zeros((batch_size,) + cfg.conv_state, dtype))
         return [(empty, empty)] * kinds.count(ATTENTION) \
-            + [state] * kinds.count(CONV)
+            + [(state,)] * kinds.count(CONV)
 
     # -- the two kinds of `op` ------------------------------------------------
     @jax.named_scope("short_conv")
@@ -291,7 +320,7 @@ class Lfm2MoeForCausalLM(Layer):
         layout and back, every launch (tests/test_tpu_compile.py)."""
         keep = self.config.conv_L_cache - 1
         d = u.shape[-1]
-        states, layer = view.slot_state, view.state_layer
+        (states,), layer = view.slot_state, view.state_layer
         state = states[layer]                            # [S, (L-1) * d]
         gates = u[:, 0] @ self._w(p + "in_proj.weight")
         z = gates[:, :d] * gates[:, 2 * d:]
@@ -303,7 +332,7 @@ class Lfm2MoeForCausalLM(Layer):
         new = jnp.concatenate([past[:, d:], z], axis=-1).astype(state.dtype)
         new = jnp.where(view.active[:, None], new, state)
         return out[:, None], view.updated(
-            slot_state=states.at[layer].set(new))
+            slot_state=(states.at[layer].set(new),))
 
     def _attention(self, u, pos, p, cache):
         cfg = self.config
@@ -331,44 +360,18 @@ class Lfm2MoeForCausalLM(Layer):
                 k = jnp.concatenate([cache[0]._value.astype(k.dtype), k], 1)
                 v = jnp.concatenate([cache[1]._value.astype(v.dtype), v], 1)
                 cache = (Tensor(k), Tensor(v))
-            o = self._causal(q, k, v, past)
+            o = grouped_causal_attention(q, k, v, past)
         return o.reshape(b, t, h * hd) @ self._w(p + "out_proj.weight"), \
             cache
-
-    def _causal(self, q, k, v, past):
-        """Causal attention of q ``[B, T, H, D]`` over k, v ``[B, total,
-        KH, D]``, `past` rows of which precede q's own: a prompt of whole
-        tiles on a TPU through the flash kernel, the key/value heads
-        repeated in front of it (8 MB a layer at 2,048 tokens; the kernel
-        file is not touched, so no other model's step lowers anew), else
-        one ``[B, H, T, total]`` array of scores a group of queries."""
-        from ...kernels import flash_attention as fa
-        b, t, h, hd = q.shape
-        kh = k.shape[2]
-        group = h // kh
-        if past == 0 and fa.is_eligible(q, q, q, None, 0.0, is_causal=True):
-            with jax.named_scope("prefill_flash_attention"):
-                return fa.flash_attention_bnhd(
-                    q, jnp.repeat(k, group, axis=2),
-                    jnp.repeat(v, group, axis=2), True,
-                    1.0 / math.sqrt(hd))
-        total = k.shape[1]
-        s = jnp.einsum("bqkgd,btkd->bkgqt", q.reshape(b, t, kh, group, hd),
-                       k, preferred_element_type=jnp.float32) \
-            / math.sqrt(hd)
-        keep = jnp.arange(total)[None, :] <= past + jnp.arange(t)[:, None]
-        prob = jax.nn.softmax(jnp.where(keep, s, -jnp.inf), axis=-1)
-        o = jnp.einsum("bkgqt,btkd->bqkgd", prob, v,
-                       preferred_element_type=jnp.float32)
-        return o.reshape(b, t, h, hd).astype(v.dtype)
 
     # -- the model ------------------------------------------------------------
     def forward(self, input_ids, position_ids=None, caches=None,
                 valid=None):
         """Logits ``[B, T, vocabulary]`` of ids ``[B, T]``; with `caches`
         (a `PagedCacheView` in a list, or `gen_caches`' layout: a (keys,
-        values) pair for each attention layer, then a state for each
-        convolution layer) also the caches after the call. `valid`
+        values) pair for each attention layer, then a state, its one part
+        in a tuple, for each convolution layer) also the caches after the
+        call. `valid`
         ``[B, T]`` bool marks the prompt inside its bucket (a prefix of
         each row): it keeps padding out of the expert blocks' counters,
         and the convolutions' states are taken where it ends (the logits
@@ -408,10 +411,10 @@ class Lfm2MoeForCausalLM(Layer):
                 else:
                     state = (jnp.zeros((b,) + cfg.conv_state, u.dtype)
                              if caches is None
-                             else caches[n_attn + len(states)]._value)
+                             else caches[n_attn + len(states)][0]._value)
                     a, state = self._short_conv(u, p + "conv.", state,
                                                 length)
-                    states.append(Tensor(state))
+                    states.append((Tensor(state),))
             else:
                 cache = view if paged else None if caches is None \
                     else caches[len(pairs)]

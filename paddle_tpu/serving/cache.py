@@ -173,25 +173,30 @@ class CacheSpec:
     `num_heads` a ``"kv"`` row holds (grouped queries: query head i reads
     key/value head ``i // (query_heads // num_heads)``); `num_heads`
     where not given.
-    state_layers / state_shape: a SECOND kind of state beside the paged
+    state_layers / state_parts: a SECOND kind of state beside the paged
     pool, for layers that are not attention and still keep something of
-    the past (a short convolution's last inputs): `state_layers` layers
-    each keep ONE fixed block of trailing shape `state_shape` a slot,
-    not paged: ``[state_layers, slots] + state_shape`` in the model's
-    dtype (`PagedKVCache.slot_state`), threaded through the layers by
-    `PagedCacheView` as the pools are. 0 layers: the model keeps none,
-    no buffer exists and no program has an argument for it.
+    the past (a short convolution's last inputs; a delta rule's matrix a
+    head): each of `state_layers` layers keeps, a slot, ONE fixed block of
+    EVERY part that `state_parts` names, ``(name, trailing shape, dtype)``
+    each (dtype None: the model's), not paged: an array a part, ``[state_
+    layers, slots] + shape`` (`PagedKVCache.slot_state`, a tuple in the
+    parts' order), threaded through the layers by `PagedCacheView` as the
+    pools are. Parts differ in shape AND type (bfloat16 inputs beside a
+    float32 matrix 28 x their size); one part is the one-entry case of
+    the same path. 0 layers: the model keeps none, no buffer exists and
+    no program has an argument for it.
 
-    THE RULE a slot's state is kept by (tests/test_lfm2_moe.py holds it):
-    a PREFILL WRITES its slot's state WHOLE, as it stands after the
-    prompt's true `length` (not its bucket's end; what lies before the
-    sequence is zeros, so prompts shorter than the state need no special
-    case), so a reused slot needs no clearing and an evicted request's
-    resume, which is a re-prefill of prompt + generated tokens, restores
-    the state by computing it; a DECODE launch shifts and writes the
-    state of ACTIVE slots only, where it lies. What a state cannot do
-    yet the engine refuses by name at construction: reuse of a prefix
-    (no state exists at a prefix's boundary), adapters, an int8 pool.
+    THE RULE a slot's state is kept by (tests/test_lfm2_moe.py and
+    tests/test_solar_open2.py hold it): a PREFILL WRITES every part of
+    its slot's state WHOLE, as it stands after the prompt's true `length`
+    (not its bucket's end; what lies before the sequence is zeros, so
+    prompts shorter than the state need no special case), so a reused
+    slot needs no clearing and an evicted request's resume, which is a
+    re-prefill of prompt + generated tokens, restores the state by
+    computing it; a DECODE launch moves the state of ACTIVE slots only
+    one token on, where it lies. What a state cannot do yet the engine
+    refuses by name at construction: reuse of a prefix (no state exists
+    at a prefix's boundary), adapters, an int8 pool.
 
     window_layers / window / window_parts: a THIRD kind, for attention
     layers that see only the last `window` tokens (position i attends j
@@ -228,12 +233,12 @@ class CacheSpec:
 
     __slots__ = ("kind", "num_layers", "parts", "widths", "num_heads",
                  "head_dim", "chunk_tokens", "min_width_slots",
-                 "query_heads", "state_layers", "state_shape",
+                 "query_heads", "state_layers", "state_parts",
                  "window_layers", "window", "window_parts")
 
     def __init__(self, kind, num_layers, parts, widths, num_heads,
                  head_dim, chunk_tokens=None, min_width_slots=None,
-                 query_heads=None, state_layers=0, state_shape=(),
+                 query_heads=None, state_layers=0, state_parts=(),
                  window_layers=0, window=0, window_parts=()):
         if kind not in ("kv", "latent"):
             raise ValueError(f"unknown cache kind {kind!r}")
@@ -253,7 +258,14 @@ class CacheSpec:
         self.min_width_slots = min_width_slots
         self.query_heads = query_heads
         self.state_layers = int(state_layers)
-        self.state_shape = tuple(int(n) for n in state_shape)
+        self.state_parts = tuple(
+            (str(name), tuple(int(n) for n in shape), dtype)
+            for name, shape, dtype in state_parts)
+        if bool(self.state_layers) != bool(self.state_parts):
+            raise ValueError(
+                f"{self.state_layers} state layers of "
+                f"{len(self.state_parts)} parts: a layer that keeps a "
+                "state names its parts, and parts need such a layer")
         self.window_layers = int(window_layers)
         self.window = int(window)
         self.window_parts = tuple(tuple(int(n) for n in p)
@@ -291,24 +303,32 @@ class CacheSpec:
                    (num_heads * head_dim, num_heads * value_dim), num_heads,
                    head_dim, **more)
 
-    def empty_prefill(self, dtype):
+    def state_arrays(self, leading, dtype):
+        """One zeroed array a state part, `leading` dimensions in front
+        of the part's own shape, in the part's dtype (`dtype` where the
+        part names none)."""
+        return tuple(jnp.zeros(tuple(leading) + shape, own or dtype)
+                     for _, shape, own in self.state_parts)
+
+    def empty_prefill(self, dtype, rows=1):
         """The caches a prefill hands the model: for each cached sublayer
         the two parts with no token in them yet, then the same for each
         window layer, then, for each layer that keeps a per-slot state,
-        that state as it is before a sequence: zeros. The model hands
-        back the same list after the prompt."""
+        the tuple of that state's parts as they are before a sequence:
+        zeros. The model hands back the same list after the prompt."""
         from ..framework.core import Tensor
         caches = []
         for parts, layers in ((self.parts, self.num_layers),
                               (self.window_parts, self.window_layers)):
             for _ in range(layers):
-                first = Tensor(jnp.zeros((1, 0) + parts[0], dtype))
+                first = Tensor(jnp.zeros((rows, 0) + parts[0], dtype))
                 # parts of one shape share the one empty tensor
                 caches.append((first, first if parts[1] == parts[0]
-                               else Tensor(jnp.zeros((1, 0) + parts[1],
+                               else Tensor(jnp.zeros((rows, 0) + parts[1],
                                                      dtype))))
         if self.state_layers:
-            caches += [Tensor(jnp.zeros((1,) + self.state_shape, dtype))] \
+            caches += [tuple(Tensor(part) for part in
+                             self.state_arrays((rows,), dtype))] \
                 * self.state_layers
         return caches
 
@@ -331,12 +351,12 @@ class PagedCacheView:
     (nn/functional/attention.resolve_paged_kernel), so a mid-run flag
     flip never re-keys a live engine's compiled decode step.
 
-    `slot_state` (``[state_layers, slots, ...]``, or None where the model
-    keeps none) is the slots' state that is not paged, with
-    `state_layer`, the index of the next layer that owns one: such a
-    layer reads and writes its own index and hands the view on through
-    `updated(slot_state=...)`, as an attention layer hands on the
-    pools.
+    `slot_state` (a tuple of one ``[state_layers, slots, ...]`` array a
+    state part, or None where the model keeps none) is the slots' state
+    that is not paged, with `state_layer`, the index of the next layer
+    that owns one: such a layer reads and writes its own index of every
+    part and hands the view on through `updated(slot_state=...)`, as an
+    attention layer hands on the pools.
 
     `window_pools` (the two ring pools of `CacheSpec`'s window layers,
     or None where the model has none) with `window_layer` and `window`
@@ -422,9 +442,10 @@ class PagedKVCache:
     HBM watermark admits ~2x the streams before `kv_exhausted`.
 
     A model whose `CacheSpec` describes a per-slot state gets it beside
-    the pools: `slot_state` ``[state_layers, num_slots] + state_shape``
-    in `state_dtype` (the model's; the pool's where not given), zeros,
-    not paged and never allocated from. One whose `CacheSpec` describes
+    the pools: `slot_state`, one array ``[state_layers, num_slots] +
+    shape`` a part, in the part's own dtype or, where it names none,
+    `state_dtype` (the model's; the pool's where not given), zeros, not
+    paged and never allocated from. One whose `CacheSpec` describes
     window layers gets their two ring pools (`window_pools`
     ``[window_layers, 1 + num_slots * ring, block_size, H * D]``, zeros,
     in the pool's dtype; never allocated from either: a slot's ring is
@@ -460,8 +481,8 @@ class PagedKVCache:
             self.v_scales = None
         self.slot_state = None
         if spec.state_layers:
-            self.slot_state = jnp.zeros(
-                (spec.state_layers, int(num_slots)) + spec.state_shape,
+            self.slot_state = spec.state_arrays(
+                (spec.state_layers, int(num_slots)),
                 self.dtype if state_dtype is None else state_dtype)
         self.window_pools = None
         if spec.window_layers:
@@ -474,17 +495,23 @@ class PagedKVCache:
 
     def buffers(self):
         """The cache's device buffers: the two pools, the int8 scale
-        side-tables where the pool is quantized, the slots' state where
-        the model keeps one, the window layers' two ring pools where it
-        has such layers."""
+        side-tables where the pool is quantized, the slots' state (an
+        array a part) where the model keeps one, the window layers' two
+        ring pools where it has such layers."""
         out = (self.k_pools, self.v_pools)
         if self.quantized:
             out += (self.k_scales, self.v_scales)
         if self.slot_state is not None:
-            out += (self.slot_state,)
+            out += self.slot_state
         if self.window_pools is not None:
             out += self.window_pools
         return out
+
+    def slot_state_bytes(self):
+        """{part: bytes} of the slots' state ({} where the model keeps
+        none)."""
+        return {name: int(part.nbytes) for (name, _, _), part in
+                zip(self.spec.state_parts, self.slot_state or ())}
 
 
 def pool_bytes_per_block(num_layers, num_heads, head_dim, block_size,

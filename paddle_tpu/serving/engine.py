@@ -148,6 +148,8 @@ __all__ = ["LLMEngine", "ServeStats"]
 _EST_WINDOW = 32
 
 _MIN_BUCKET = 8
+# the longest bucket that is a power of two (`LLMEngine._bucket_for`)
+_LINEAR_BUCKETS_FROM = 8192
 
 # WHAT CROSSES BETWEEN HOST AND DEVICE IN ONE PROGRAM CALL: one int32
 # array in, one int32 array out (floats by their bits); everything else a
@@ -695,7 +697,9 @@ class LLMEngine:
         unequal = spec.kind == "kv" and spec.parts[0] != spec.parts[1]
         for cannot, where in (
                 (spec.kind == "latent", "over a latent cache"),
-                (spec.state_layers, "beside a per-slot state"),
+                (spec.state_layers, "beside a per-slot state (parts: "
+                 + ", ".join(f"{name} {list(shape)}" for name, shape, _
+                             in spec.state_parts) + ")"),
                 (spec.window_layers or unequal, "over window layers' rings "
                  "or keys wider than their values")):
             for option, on in (
@@ -1624,8 +1628,27 @@ class LLMEngine:
         snap["block_size"] = self.block_size
         snap["attention_kernel"] = self._attn_kernel
         snap["kv_dtype"] = str(jnp.dtype(self._kv_dtype))
-        state = self.cache.slot_state
-        snap["slot_state_bytes"] = 0 if state is None else int(state.nbytes)
+        # the paged pools' bytes that requests hold right now (whole
+        # blocks, every cached layer, keys and values)
+        allocator = self.cache.allocator
+        snap["kv_bytes_held"] = (
+            int(self.cache.k_pools.nbytes + self.cache.v_pools.nbytes)
+            // self.cache.num_blocks
+            * (allocator.capacity - allocator.num_free))
+        # a per-slot state: its bytes, whole and by part, and what its
+        # layers did in the window (a prefill's scan runs over its whole
+        # bucket, the padding masked; a launch moves each active slot's
+        # state of each such layer one token on)
+        parts = self.cache.slot_state_bytes()
+        layers = self.cache.spec.state_layers
+        snap["slot_state_bytes"] = sum(parts.values())
+        snap["slot_state_bytes_by_part"] = parts
+        if layers:
+            snap["prefill_scan_tokens"] = \
+                layers * snap["prefill_bucket_tokens"]
+            snap["prefill_scan_padding_tokens"] = \
+                layers * snap["prefill_padding_tokens"]
+            snap["decode_state_updates"] = layers * snap["decode_tokens"]
         rings = self.cache.window_pools
         snap["window_ring_bytes"] = 0 if rings is None \
             else int(sum(pool.nbytes for pool in rings))
@@ -1660,6 +1683,14 @@ class LLMEngine:
     # ------------------------------------------------------------------
     @staticmethod
     def _bucket_for(n):
+        """The prefill bucket of a context of n tokens: the next power of
+        two up to `_LINEAR_BUCKETS_FROM`, and from there the next multiple
+        of half of it (8,192, 12,288, 16,384, ...): doubling a long
+        bucket pads a prompt just over it by as much again as it holds,
+        and a long prompt's prefill is most of what it costs."""
+        if n > _LINEAR_BUCKETS_FROM:
+            step = _LINEAR_BUCKETS_FROM // 2
+            return -(-int(n) // step) * step
         return max(_MIN_BUCKET, 1 << (int(n - 1)).bit_length())
 
     def _admit(self, req):
@@ -1868,7 +1899,8 @@ class LLMEngine:
         more = list(more)
         scales = (more.pop(0), more.pop(0)) if self._kv_quantized \
             else (None, None)
-        state = more.pop(0) if self.cache.slot_state is not None else None
+        state = self.cache.slot_state and tuple(
+            more.pop(0) for _ in self.cache.slot_state)
         rings = tuple(more) if self.cache.window_pools is not None else None
         return scales + (state, rings)
 
@@ -1890,7 +1922,7 @@ class LLMEngine:
         if view.k_scales is not None:
             out += (view.k_scales, view.v_scales)
         if view.slot_state is not None:
-            out += (view.slot_state,)
+            out += tuple(view.slot_state)
         if view.window_pools is not None:
             out += tuple(view.window_pools)
         return out
@@ -2397,7 +2429,8 @@ class LLMEngine:
                  # the pool's shape is the donated arguments' signature:
                  # an artifact traced for another layout never replays
                  tuple(self.cache.k_pools.shape)
-                 + tuple(getattr(self.cache.slot_state, "shape", ())),
+                 + tuple(n for part in self.cache.slot_state or ()
+                         for n in part.shape),
                  self.max_blocks_per_seq, str(self._dtype),
                  # the kernel tier re-keys the artifact: a blockwise
                  # executable must never replay as the pallas one, and an
@@ -2634,9 +2667,10 @@ class LLMEngine:
         slot, and its ids into the slot's row of `history` (ids below
         `length`, zeros above: what `_sync_slot` records on the host), so
         that the launches find both on the device. Where the
-        model keeps a per-slot state, the forward's caches end with one
-        state a layer that keeps one, as it stands after the prompt's
-        `length`: written WHOLE at the request's slot (`CacheSpec`'s
+        model keeps a per-slot state, the forward's caches end with the
+        tuple of the state's parts for each layer that keeps one, as they
+        stand after the prompt's `length`: written WHOLE at the request's
+        slot (`CacheSpec`'s
         rule: a reused slot needs no clearing). Returns ``(feedback,
         firsts, sampler, history) + the written buffers``."""
         k_scales, v_scales, slot_state, rings = self._split_more(more)
@@ -2652,10 +2686,13 @@ class LLMEngine:
             block_row, length, self.block_size, k_scales=k_scales,
             v_scales=v_scales))
         if slot_state is not None:
-            states = jnp.stack([c._value[0] for c in
-                                caches[len(paged) + len(windowed):]])
-            written += (slot_state.at[:, slot].set(
-                states.astype(slot_state.dtype)),)
+            # a tuple of parts a layer -> each part over the layers
+            layers = caches[len(paged) + len(windowed):]
+            written += tuple(
+                held.at[:, slot].set(jnp.stack(
+                    [layer[i]._value[0] for layer in layers])
+                    .astype(held.dtype))
+                for i, held in enumerate(slot_state))
         if rings is not None:
             # `CacheSpec`'s rule: the last `window` tokens before the
             # prompt's true length, into the slot's own ring

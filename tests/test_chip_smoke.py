@@ -202,6 +202,26 @@ class TestOneChipRehearsal:
             assert r["decode_compiles"] == 1
         assert recs[-1]["worst_logit_gap"] <= recs[-1]["logit_gap_slack"]
 
+    def test_serve_over_a_state_of_two_parts(self, kernels_interpreted,
+                                             monkeypatch):
+        """The state legs at a tiny size: one softmax layer and three
+        delta-rule layers, heads of 128 x 128 float32 (the update's kernel
+        in the interpreter, as on the chip), every expert chosen."""
+        from paddle_tpu.kernels import kda
+        monkeypatch.setattr(kda, "on_tpu", lambda: True)
+        sizes = dict(chip_smoke.STATE_SMOKE, vocab_size=128, hidden_size=64,
+                     moe_intermediate_size=32, num_attention_heads=2,
+                     num_key_value_heads=1, max_position_embeddings=64)
+        assert kda.update_form(8, 128, 128) == "pallas"
+        recs = chip_smoke.state_serve_phase(sizes, [8, 13, 16, 41],
+                                            max_new_tokens=6, seed=0)
+        assert [(r["phase"], r["attention_kernel"]) for r in recs] == [
+            ("serve_state", "blockwise"), ("serve_state", "pallas")]
+        for r in recs:
+            assert r["requests"] == 4 and r["tokens"] == 24
+            assert r["decode_compiles"] == 1
+        assert recs[-1]["worst_logit_gap"] <= recs[-1]["logit_gap_slack"]
+
     def test_grouped_matmul_leg(self, monkeypatch):
         """The leg at a tiny size, the kernel in the interpreter: uneven
         groups, an idle expert, a poisoned tail. A kernel that lets the

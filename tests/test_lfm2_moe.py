@@ -105,7 +105,9 @@ def test_parameter_names_and_shapes_are_the_references():
     spec = lfm.Lfm2MoeForCausalLM(cfg).cache_spec()
     assert (spec.kind, spec.num_layers, spec.num_heads, spec.query_heads,
             spec.head_dim) == ("kv", 2, 2, 8, 8)
-    assert (spec.state_layers, spec.state_shape) == (5, (2 * 64,))
+    # ONE part, in the model's dtype: the one-entry case of the parts
+    assert (spec.state_layers, spec.state_parts) == (
+        5, (("conv", (2 * 64,), None),))
 
 
 def test_full_forward_logits_agree_over_a_whole_sequence(model, weights):
@@ -131,7 +133,8 @@ def test_the_dense_caches_carry_state_and_keys_token_by_token(model,
                                  caches=caches)
         close(logits._value[0, 0], want[t])
     assert len(caches) == 2 + 5 and caches[0][0].shape[1] == 11
-    assert tuple(caches[-1].shape) == (1, 2 * 64)
+    (state,) = caches[-1]
+    assert tuple(state.shape) == (1, 2 * 64)
 
 
 # -- (b) the engine: logits, not tokens ---------------------------------------
@@ -185,6 +188,7 @@ def test_prefill_then_decode_gives_the_references_logits(model, weights):
     assert s["decode_compiles"] == 1 and s["prefill_compiles"] == 2
     assert s["prefill_tokens"] == 33 and s["prefill_bucket_tokens"] == 64
     assert s["slot_state_bytes"] == 5 * 1 * 2 * 64 * 4
+    assert s["slot_state_bytes_by_part"] == {"conv": s["slot_state_bytes"]}
     assert s["decode_routed_computed"] == s["decode_routed_held"] > 0
     assert s["prefill_routed_computed"] == s["prefill_routed_held"] \
         == 5 * 4 * 33
@@ -503,7 +507,9 @@ def test_the_products_and_the_kernels_are_counted_over_layers_and_calls(
     ({"kv_dtype": "int8"}, "kv_dtype='int8'")])
 def test_an_option_a_per_slot_state_lacks_is_refused_by_name(model, option,
                                                              named):
-    with pytest.raises(ValueError, match=named + ".*per-slot state"):
+    # the refusal names the option and the state's parts, kind by kind
+    with pytest.raises(ValueError, match=named + r".*per-slot state "
+                       r"\(parts: conv \[128\]\)"):
         LLMEngine(model, max_batch_size=2, block_size=4, max_context=32,
                   **option)
 
@@ -517,20 +523,49 @@ def test_the_cache_builds_and_threads_the_state_the_spec_describes(model):
     spec = model.cache_spec()
     cache = PagedKVCache(spec, 9, 4, jnp.float32, num_slots=3)
     assert cache.k_pools.shape == (2, 9, 4, 2 * 8)      # key/value heads' row
-    assert cache.slot_state.shape == (5, 3, 2 * 64)
+    (state,) = cache.slot_state
+    assert state.shape == (5, 3, 2 * 64) and state.dtype == jnp.float32
     assert [b.shape for b in cache.buffers()] == [
-        cache.k_pools.shape, cache.v_pools.shape, cache.slot_state.shape]
+        cache.k_pools.shape, cache.v_pools.shape, state.shape]
+    assert cache.slot_state_bytes() == {"conv": state.nbytes}
     plain = PagedKVCache(CacheSpec.per_head(2, 4, 8), 9, 4, jnp.float32)
     assert plain.slot_state is None and len(plain.buffers()) == 2
+    assert plain.slot_state_bytes() == {}
     view = PagedCacheView(cache.k_pools, cache.v_pools, 0, None, None, None,
                           4, slot_state=cache.slot_state)
-    after_conv = view.updated(slot_state=cache.slot_state + 1)
+    after_conv = view.updated(slot_state=(state + 1,))
     assert (after_conv.layer, after_conv.state_layer) == (0, 1)
     after_attn = after_conv.updated(cache.k_pools, cache.v_pools)
     assert (after_attn.layer, after_attn.state_layer) == (1, 1)
     assert after_attn.slot_state is after_conv.slot_state
     with pytest.raises(ValueError, match="not whole groups"):
         CacheSpec.per_head(2, 3, 8, query_heads=8)
+
+
+def test_a_state_of_several_parts_each_of_its_own_shape_and_type():
+    """The one-part state above is the one-entry case of this: an array
+    a part, ``[layers, slots] + shape``, a part that names a dtype keeps
+    it whatever the model's, `buffers()` holds them in the parts' order
+    and a spec names parts exactly where it has layers that keep them."""
+    from paddle_tpu.serving.cache import CacheSpec, PagedKVCache
+    parts = (("conv", (24,), None), ("delta", (2, 4, 4), jnp.float32))
+    spec = CacheSpec.per_head(1, 2, 8, state_layers=3, state_parts=parts)
+    cache = PagedKVCache(spec, 5, 4, jnp.bfloat16, num_slots=2)
+    conv, delta = cache.slot_state
+    assert (conv.shape, conv.dtype) == ((3, 2, 24), jnp.bfloat16)
+    assert (delta.shape, delta.dtype) == ((3, 2, 2, 4, 4), jnp.float32)
+    assert [b.shape for b in cache.buffers()[2:]] == [conv.shape,
+                                                      delta.shape]
+    assert cache.slot_state_bytes() == {"conv": 3 * 2 * 24 * 2,
+                                        "delta": 3 * 2 * 32 * 4}
+    (first, second), *_ = spec.empty_prefill(jnp.bfloat16)[1:]
+    assert (tuple(first.shape), tuple(second.shape)) == ((1, 24),
+                                                         (1, 2, 4, 4))
+    assert second._value.dtype == jnp.float32
+    for layers, named in ((3, ()), (0, parts)):
+        with pytest.raises(ValueError, match="names its parts"):
+            CacheSpec.per_head(1, 2, 8, state_layers=layers,
+                               state_parts=named)
 
 
 # -- (f) broken on purpose: each must fail the comparison ---------------------

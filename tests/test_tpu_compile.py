@@ -607,14 +607,14 @@ def test_a_decode_step_updates_the_pool_and_the_slots_state_where_they_lie(
         try:
             view = PagedCacheView(k_pools, v_pools, 0, tables, lens, active,
                                   BLOCK, kernel="pallas",
-                                  slot_state=slot_state)
+                                  slot_state=(slot_state,))
             logits, (view,) = model(paddle.Tensor(tokens[:, None]),
                                     caches=[view])
         finally:
             for p, v in zip(params, saved):
                 p._value = v
         assert (view.layer, view.state_layer) == (2, 2)
-        return logits._value, view.k_pools, view.v_pools, view.slot_state
+        return (logits._value, view.k_pools, view.v_pools) + view.slot_state
 
     sd = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
                                                    sharding=v5e)
@@ -852,3 +852,221 @@ def test_a_decode_step_updates_both_caches_where_they_lie(v5e, monkeypatch):
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= 2 * sum(
         math.prod(shape) for shape in pools + rings)
+
+
+# -- the delta rule of `serve_solar_longdoc_16k` (kernels/kda.py) -------------
+# 64 heads of 128 x 128 float32 a slot a KDA layer, 64 slots, 3 such layers;
+# prompts in buckets of 4,096 to 16,384 tokens.
+KDA_HEADS, KDA_WIDTH, KDA_SLOTS, KDA_LAYERS = 64, 128, 64, 3
+
+
+def _layouts(text, name):
+    """{(layout, opcode)} of the instructions that make an array `name`."""
+    return set(re.findall(
+        r"= " + re.escape(name) + r"\{([\d,]*)\S* ([\w-]+)\(", text))
+
+
+def _entry_instructions(text):
+    """[(the instruction as a device trace names it: no indent, no `ROOT`;
+    its `op_name`)] of the ENTRY computation of a compiled program's text:
+    the operations a device runs one after the other, each one event."""
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    out = []
+    for line in entry.splitlines()[2:]:
+        line = line.strip()
+        line = line[5:] if line.startswith("ROOT ") else line
+        scope = re.search(r'op_name="([^"]*)"', line)
+        out.append((line, scope.group(1) if scope else ""))
+    return out
+
+
+def _the_scan_metrics_find_the_outer_loop(text, loops=1):
+    """`solar.kda_prefill_time_share` and `solar.kda_prefill_roofline` find
+    the scan by a pattern over an XLA `while`'s result tuple (a trace
+    names an instruction and its shapes, never its `op_name`). Held HERE to
+    what the chip's compiler makes of the scan: the pattern of each
+    metric's file matches ONE operation a KDA layer of the program, the
+    loop over the spans under the scope `kda_chunk_scan`, and no other. A change of
+    `CHUNK`, of the layout or of the compiler's tuple order fails this
+    test where it would have made the metrics read nothing."""
+    import json
+    import os
+    metrics = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark", "metrics")
+    ops = _entry_instructions(text)
+    found = [line for line, scope in ops
+             if scope.endswith("/kda_chunk_scan/while")
+             and re.search(r"[\])}] while\(", line)]
+    assert len(found) == loops, [scope for _, scope in ops
+                                 if "while" in scope]
+    for name in ("solar.kda_prefill_time_share",
+                 "solar.kda_prefill_roofline"):
+        with open(os.path.join(metrics, name + ".json")) as f:
+            pattern = json.load(f)["args"]["pattern"]
+        assert [line for line, _ in ops if re.search(pattern, line)] \
+            == found, name
+
+
+@pytest.mark.parametrize("bucket", [4096, 16384])
+def test_the_kda_chunk_scan_at_the_cells_buckets(v5e, bucket):
+    """The chunked scan of a prompt as the prefill traces it (q, k, v in
+    bfloat16, the decays and the state float32): the chip's compiler takes
+    it, its loops are the two scans (spans, and a span's chunks), and what
+    it holds besides its operands is a span's worth, not the prompt's."""
+    from paddle_tpu.kernels import kda
+    sd = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                   sharding=v5e)
+    row = (1, bucket, KDA_HEADS, KDA_WIDTH)
+    compiled = jax.jit(kda.kda_chunk_scan).lower(
+        sd(row, jnp.bfloat16), sd(row, jnp.bfloat16), sd(row, jnp.bfloat16),
+        sd(row, jnp.float32), sd(row[:3], jnp.float32),
+        sd((1, KDA_HEADS, KDA_WIDTH, KDA_WIDTH), jnp.float32)).compile()
+    text = compiled.as_text()
+    _the_scan_metrics_find_the_outer_loop(text)
+    memory = compiled.memory_analysis()
+    # q, k, v, g in; o out; under a gigabyte of temporaries at 16,384
+    assert memory.temp_size_in_bytes < 2 ** 30
+
+
+def test_the_scan_metrics_find_the_loop_inside_a_models_prefill(v5e):
+    """The same, where the scan lies as the cell's prefill has it: behind
+    the model's own projections, convolutions, norms and gates and before
+    its output norm (their fusions decide the layouts the compiler gives
+    the loop's operands), a bucket of 4,096 through two KDA layers at the
+    cell's heads: one loop a layer, each found by the metrics' pattern."""
+    import paddle_tpu as paddle
+    from paddle_tpu.incubate.models.solar_open2 import (
+        KDA, SolarOpen2Config, SolarOpen2ForCausalLM)
+    model = SolarOpen2ForCausalLM(SolarOpen2Config(
+        vocab_size=512, hidden_size=512, moe_intermediate_size=128,
+        num_hidden_layers=2, layer_types=(KDA, KDA),
+        n_routed_experts=8, num_experts_per_tok=2))
+    params = model.parameters()
+    spec = model.cache_spec()
+    bucket = 4096
+
+    def prefill(values, ids, length):
+        saved = [p._value for p in params]
+        for p, v in zip(params, values):
+            p._value = v
+        try:
+            valid = jnp.arange(bucket)[None, :] < length[:, None]
+            logits, caches = model(paddle.Tensor(ids),
+                                   caches=spec.empty_prefill(jnp.bfloat16),
+                                   valid=valid)
+        finally:
+            for p, v in zip(params, saved):
+                p._value = v
+        return logits._value, [[part._value for part in state]
+                               for state in caches]
+
+    sd = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                   sharding=v5e)
+    text = jax.jit(prefill).lower(
+        [sd(p._value.shape, jnp.bfloat16) for p in params],
+        sd((1, bucket), jnp.int32), sd((1,), jnp.int32)).compile().as_text()
+    _the_scan_metrics_find_the_outer_loop(text, loops=2)
+
+
+def test_the_kda_update_kernel_at_the_cells_geometry(v5e, monkeypatch):
+    """The one-token update of 64 slots' states as the decode program
+    traces it: Mosaic takes the kernel (a head's columns broadcast along
+    the lanes), it is NAMED (`kda_decode_step`: what the device trace and
+    `solar.kda_decode_*` read), and the donated float32 states go out in
+    the buffer they came in: no instruction makes an array of their shape
+    but the parameter and the kernel's own result."""
+    from paddle_tpu.kernels import kda
+    monkeypatch.setattr(kda, "on_tpu", lambda: True)
+    assert kda.update_form(KDA_HEADS, KDA_WIDTH, KDA_WIDTH) == "pallas"
+    sd = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                   sharding=v5e)
+    token = (KDA_SLOTS, KDA_HEADS, KDA_WIDTH)
+    states = (KDA_LAYERS, KDA_SLOTS, KDA_HEADS, KDA_WIDTH, KDA_WIDTH)
+    compiled = jax.jit(
+        lambda q, k, v, g, beta, held, active: kda.kda_decode_step(
+            q, k, v, g, beta, held, 1, active),
+        donate_argnums=(5,)).lower(
+            sd(token, jnp.bfloat16), sd(token, jnp.bfloat16),
+            sd(token, jnp.bfloat16), sd(token, jnp.float32),
+            sd(token[:2], jnp.float32), sd(states, jnp.float32),
+            sd((KDA_SLOTS,), jnp.bool_)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "%kda_decode_step" in text
+    name = "f32[" + ",".join(map(str, states)) + "]"
+    assert {opcode for _, opcode in _layouts(text, name)} <= {
+        "parameter", "get-tuple-element", "custom-call", "bitcast"}
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 4 * math.prod(states)
+    assert memory.temp_size_in_bytes < 2 ** 24
+
+
+def test_a_decode_step_updates_the_pool_and_both_state_parts_where_they_lie(
+        v5e, monkeypatch):
+    """A model with KDA and softmax layers through ONE `PagedCacheView`,
+    as the engine's decode program threads it (weights as arguments): the
+    donated pools AND the two donated state parts (the convolutions'
+    inputs in bfloat16, the matrix states in float32) go out in the
+    buffers they came in. No instruction of any of their shapes is a
+    `copy`, a `concatenate` or a `pad`, and every one lies row-major, as
+    the parameter does: the `[S, 1, d]` trap the LFM2 model's docstring
+    records (a state turned into another layout and back, every launch)
+    shows as a second layout here. (The kept inputs may be staged through
+    the faster memory a layer at a time, `copy-start/-done` in their own
+    layout: that moves 28 MB, not 800, and changes no layout.)"""
+    import paddle_tpu as paddle
+    from paddle_tpu.incubate.models.solar_open2 import (
+        ATTENTION, KDA, SolarOpen2Config, SolarOpen2ForCausalLM)
+    from paddle_tpu.kernels import kda
+    from paddle_tpu.serving.cache import PagedCacheView
+    monkeypatch.setattr(paged_attention, "_on_tpu", lambda: True)
+    monkeypatch.setattr(kda, "on_tpu", lambda: True)
+    model = SolarOpen2ForCausalLM(SolarOpen2Config(
+        vocab_size=512, hidden_size=512, moe_intermediate_size=128,
+        num_hidden_layers=3, layer_types=(ATTENTION, KDA, KDA),
+        n_routed_experts=8, num_experts_per_tok=2))
+    params = model.parameters()
+    slots, table = KDA_SLOTS, 128                    # contexts of 2,048
+    pool = (1, 1 + slots * table, BLOCK, 8 * 128)
+    conv = (2, slots, 3 * 3 * KDA_HEADS * KDA_WIDTH)
+    delta = (2, slots, KDA_HEADS, KDA_WIDTH, KDA_WIDTH)
+
+    def decode(values, tokens, tables, lens, active, k_pools, v_pools,
+               kept, matrices):
+        saved = [p._value for p in params]
+        for p, v in zip(params, values):
+            p._value = v
+        try:
+            view = PagedCacheView(k_pools, v_pools, 0, tables, lens, active,
+                                  BLOCK, kernel="pallas",
+                                  slot_state=(kept, matrices))
+            logits, (view,) = model(paddle.Tensor(tokens[:, None]),
+                                    caches=[view])
+        finally:
+            for p, v in zip(params, saved):
+                p._value = v
+        assert (view.layer, view.state_layer) == (1, 2)
+        return (logits._value, view.k_pools, view.v_pools) + view.slot_state
+
+    sd = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                   sharding=v5e)
+    args = [[sd(p._value.shape, jnp.bfloat16) for p in params],
+            sd((slots,), jnp.int32), sd((slots, table), jnp.int32),
+            sd((slots,), jnp.int32), sd((slots,), jnp.bool_),
+            sd(pool, jnp.bfloat16), sd(pool, jnp.bfloat16),
+            sd(conv, jnp.bfloat16), sd(delta, jnp.float32)]
+    compiled = jax.jit(decode, donate_argnums=(5, 6, 7, 8)).lower(
+        *args).compile()
+    text = compiled.as_text()
+    assert "%kda_decode_step" in text and "paged_decode_attention" in text
+    for shape, dtype in ((pool, "bf16"), (conv, "bf16"), (delta, "f32")):
+        name = dtype + "[" + ",".join(map(str, shape)) + "]"
+        made = _layouts(text, name)
+        assert {layout for layout, _ in made} == {
+            ",".join(map(str, reversed(range(len(shape)))))}, (name, made)
+        opcodes = {opcode for _, opcode in made}
+        assert "parameter" in opcodes
+        assert not opcodes & {"copy", "concatenate", "pad"}, (name, opcodes)
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= 2 * (
+        2 * math.prod(pool) + math.prod(conv)) + 4 * math.prod(delta)
